@@ -393,9 +393,10 @@ def get_gpu_ids() -> List[int]:
 
 def get_tpu_ids() -> List[int]:
     """Chip indices the raylet granted the current task or actor (the
-    TPU-native `ray.get_gpu_ids`): DISJOINT across concurrent tasks on a
-    node — whole chips for integer demands, a shared chip index for
-    fractional ones. [] when nothing is reserved."""
+    TPU-native `ray.get_gpu_ids`): DISJOINT across processes on a node and
+    exactly the chips this worker's libtpu can see — the worker was spawned
+    for this grant. Whole chips always (a fractional demand takes one).
+    [] when nothing is reserved."""
     from ray_tpu.core.worker import current_worker
 
     w = current_worker() or _global_worker()

@@ -66,6 +66,10 @@ def _load_lib():
         return _lib
 
 
+def available() -> bool:
+    return _load_lib() is not None
+
+
 NIL = (1 << 64) - 1
 
 

@@ -37,6 +37,16 @@ CH_ERRORS = "errors"
 CH_CONTROL = "control"  # cluster-wide commands (global_gc, ...)
 CH_LOGS = "logs"        # worker stdout/stderr fan-out to drivers
 
+# A host stalls WHOLE while a process opens or closes its chips — every
+# process on it, the raylet's heartbeat thread included: ~8 s to open one
+# v5e chip, 4-7 s to close one, 12 s to close four (PERF.md, PR 21, finding
+# 3). Silence from a node that registered TPU is therefore weaker evidence
+# of death, and both silence bounds (quarantine, death) are this many times
+# `health_check_timeout_ms` for it: 30 s at the default, which covers a
+# close followed at once by the next holder's open. The price is that a
+# crashed TPU node is noticed that much later.
+_TPU_NODE_SILENCE_FACTOR = 3.0
+
 
 def _head_metrics() -> dict:
     """Lazy HA metric handles (util/metrics.py): shared names across the
@@ -1474,22 +1484,24 @@ class GcsServer:
                     n = self._nodes.get(nid, {})
                     if not n.get("alive"):
                         continue
-                    if now - last > timeout:
+                    k = (_TPU_NODE_SILENCE_FACTOR
+                         if n["resources_total"].get("TPU", 0) > 0 else 1.0)
+                    if now - last > k * timeout:
                         dead.append(nid)
-                    elif now - last > quarantine_s \
+                    elif now - last > k * quarantine_s \
                             and not n.get("quarantined"):
                         n["quarantined"] = True
                         self._node_quarantines += 1
                         self._dirty = True  # counters are snapshot state
                         self._bcast_dirty.add(nid.hex())
                         self._bcast_full_needed = True
-                        suspects.append(nid)
-            for nid in suspects:
+                        suspects.append((nid, k))
+            for nid, k in suspects:
                 logger.warning(
                     "node %s heartbeat delivery degraded (> %.1fs silent); "
                     "QUARANTINED — no new dispatch, replacement held until "
-                    "the %.1fs death bound", nid.hex()[:8], quarantine_s,
-                    timeout)
+                    "the %.1fs death bound", nid.hex()[:8], k * quarantine_s,
+                    k * timeout)
                 try:
                     _node_metrics()["quarantines"].inc()
                 except Exception:

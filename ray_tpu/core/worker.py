@@ -3569,14 +3569,19 @@ class CoreWorker:
                     self._fn_call_counts[key] = (
                         self._fn_call_counts.get(key, 0) + 1)
                     recycle = self._fn_call_counts[key] >= spec.max_calls
+            if self._tls.tpu_ids and self.mode == "worker":
+                # this process was started for its chip grant and holds the
+                # chips until it exits: one lease, then retire
+                recycle = True
             try:
                 self.raylet.notify("task_done", {
                     "worker_id": self.worker_id, "retiring": recycle})
             except OSError as e:
                 logger.debug("task_done notify lost (raylet down?): %s", e)
             if recycle:
-                logger.info("max_calls=%d reached for %s; recycling worker",
-                            spec.max_calls, spec.method_name)
+                logger.info("recycling worker after %s (max_calls=%d, "
+                            "tpu_ids=%s)", spec.method_name, spec.max_calls,
+                            self._tls.tpu_ids)
                 self.result_buffer.stop()
                 self.task_events.flush()
                 os._exit(0)

@@ -68,6 +68,10 @@ def _load_lib():
         return _lib
 
 
+def native_available() -> bool:
+    return _load_lib() is not None
+
+
 class TokenLoader:
     """Streams [batch, seq_len+1] int32 batches from a flat token file.
 

@@ -208,6 +208,18 @@ def run_servebench(quick: bool = True, *,
                    ) -> Dict[str, Any]:
     import jax
 
+    backend = jax.default_backend()
+    if with_latency and backend != "cpu":
+        # One process per chip: the engine rows below make THIS process the
+        # chip's owner, and the deployment round's replica would then run
+        # on a CPU worker (or hang on the chip) under the same "backend".
+        raise SystemExit(
+            f"servebench on backend={backend!r}: the in-process engine rows "
+            "and the Serve deployment round cannot share one run — this "
+            "process would hold the chip the replica needs. Run the engine "
+            "rows with --no-latency; drive a chip-owning replica through "
+            "Serve with chip_smoke.py (ray_tpu.serve.llm.LLMReplica, "
+            "resources={'TPU': 1}).")
     params, cfg, max_len = _bench_model(quick)
     devices = jax.devices()
     n_chips = len(devices)
@@ -224,7 +236,6 @@ def run_servebench(quick: bool = True, *,
     # speeds up ~2x. Validated only where decode IS weight-traffic-bound;
     # a compute-bound backend (CPU) pays dequant FLOPs instead. Record the
     # verdict for THIS backend rather than asserting the TPU story.
-    backend = jax.default_backend()
     quant_row = {
         **quant,
         "decode_tokens_per_s": quant_decode["decode_tokens_per_s"],

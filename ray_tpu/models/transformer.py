@@ -28,7 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.attention import attention
+from ray_tpu.ops.attention import (attention, attention_sharded,
+                                   uses_flash_kernel)
 from ray_tpu.ops.layers import apply_rotary, rms_norm, rotary_embedding, swiglu
 
 
@@ -269,6 +270,8 @@ def _attn_half(cfg: ModelConfig, mesh, x, p, cos, sin):
         attn = ulysses_attention_sharded(mesh, q, k, v, causal=True)
     elif sp_scheme:
         raise ValueError(f"unknown seq_parallel scheme {sp_scheme!r}")
+    elif mesh is not None and mesh.size > 1 and uses_flash_kernel(q):
+        attn = attention_sharded(mesh, q, k, v, causal=True)
     else:
         attn = attention(q, k, v, causal=True)
     attn = checkpoint_name(

@@ -18,13 +18,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+from ray_tpu.ops.pallas import _util
 
 
 def causal_attention_reference(q, k, v, sm_scale: Optional[float] = None,
@@ -48,6 +44,26 @@ def _repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
     return jnp.broadcast_to(k[:, :, None], (b, h, n_rep, s, d)).reshape(b, h * n_rep, s, d)
 
 
+def uses_flash_kernel(q: jax.Array) -> bool:
+    """Whether `attention` runs the Pallas flash kernel for this q
+    ([batch, heads, seq, head_dim]): on a TPU, at shapes that tile."""
+    return _util.on_tpu() and q.shape[-1] >= 128 and q.shape[-2] >= 128
+
+
+def attention_sharded(mesh, q: jax.Array, k: jax.Array, v: jax.Array, *,
+                      causal: bool = True) -> jax.Array:
+    """`attention` on a mesh of several chips. A Mosaic kernel cannot be
+    partitioned by the compiler ("wrap the call in a shard_map"), so each
+    device runs the kernel on its own block: batch over (dp, fsdp), heads
+    over tp — attention is independent per (batch, head), so no collective.
+    GQA stays aligned because q and kv heads split contiguously."""
+    spec = P(("dp", "fsdp"), "tp", None, None)
+    return jax.shard_map(
+        functools.partial(attention, causal=causal), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+    )(q, k, v)
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "sm_scale"))
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, sm_scale: Optional[float] = None) -> jax.Array:
@@ -61,7 +77,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     k = _repeat_kv(k, n_rep)
     v = _repeat_kv(v, n_rep)
     scale = sm_scale if sm_scale is not None else 1.0 / (q.shape[-1] ** 0.5)
-    if _on_tpu() and q.shape[-1] >= 128 and q.shape[-2] >= 128:
+    if uses_flash_kernel(q):
         from ray_tpu.ops.pallas.flash_attention import flash_attention_pallas
 
         b, h, sq, d = q.shape
@@ -96,7 +112,7 @@ def attention_packed(q: jax.Array, k: jax.Array, v: jax.Array, *,
     d = hd // n_heads
     sk = k.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
-    if _on_tpu() and d >= 128 and sq >= 128:
+    if _util.on_tpu() and d >= 128 and sq >= 128:
         from ray_tpu.ops.pallas.flash_attention import flash_attention_packed
 
         return flash_attention_packed(q, k, v, n_heads, n_kv_heads, scale,

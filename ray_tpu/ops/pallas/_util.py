@@ -6,10 +6,12 @@ import jax
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    """Whether the default device is a TPU. Device discovery that FAILS
+    raises: a worker that was promised a chip must not drift onto the
+    interpreter or a reference einsum because libtpu could not open it.
+    Every kernel's device branch reads this one function (tests that
+    compile for a described chip steer it here)."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def interpret_mode() -> bool:

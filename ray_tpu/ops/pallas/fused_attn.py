@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.layers import apply_rotary
-from ray_tpu.ops.pallas._util import on_tpu
+from ray_tpu.ops.pallas import _util
 
 
 def _repeat_kv(t: jax.Array, n_rep: int) -> jax.Array:
@@ -40,7 +40,7 @@ def _repeat_kv(t: jax.Array, n_rep: int) -> jax.Array:
 
 
 def _use_kernel(s: int, hd: int) -> bool:
-    return on_tpu() and hd >= 128 and s >= 128
+    return _util.on_tpu() and hd >= 128 and s >= 128
 
 
 def _core_fwd(q4, kr, vr, scale):

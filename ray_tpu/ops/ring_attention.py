@@ -27,7 +27,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ray_tpu.util.jax_compat import shard_map
 
 
 def _block_attend(q, k, v, q_off, k_off, causal, scale):
@@ -99,7 +98,7 @@ def ring_attention_sharded(mesh: Mesh, q, k, v, *, causal: bool = True,
     spec = P(("dp", "fsdp"), "tp", axis_name, None)
     fn = functools.partial(
         ring_attention, axis_name=axis_name, causal=causal, sm_scale=sm_scale)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops.attention import attention
-from ray_tpu.util.jax_compat import shard_map
 
 
 def _repeat_kv_to_multiple(t: jax.Array, sp: int) -> jax.Array:
@@ -84,7 +83,7 @@ def ulysses_attention_sharded(mesh: Mesh, q, k, v, *, causal: bool = True,
     fn = functools.partial(
         ulysses_attention, axis_name=axis_name, causal=causal,
         sm_scale=sm_scale)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)

@@ -46,16 +46,7 @@ def initialize_group(rank: int, world_size: int, *,
     Idempotent per group; re-initializing a different group raises.
     """
     global _initialized_group
-    import os
-
     import jax
-
-    # Respect JAX_PLATFORMS even when a sitecustomize pinned the platform
-    # via jax.config (config beats the env var; worker pools export
-    # JAX_PLATFORMS=cpu for CPU worker fleets).
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        jax.config.update("jax_platforms", plat)
 
     if _initialized_group is not None:
         if _initialized_group == group_name:

@@ -37,7 +37,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ray_tpu.models.transformer import (ModelConfig, _layer, init_params,
                                         maybe_remat)
 from ray_tpu.ops.layers import rms_norm, rotary_embedding
-from ray_tpu.util.jax_compat import shard_map
 
 
 def _stage_fn(cfg: ModelConfig, layers_local: Dict[str, jax.Array],
@@ -107,7 +106,7 @@ def pipeline_blocks(cfg: ModelConfig, mesh: Mesh, layer_params: Dict[str, Any],
         outputs = jnp.where(stage == n_stages - 1, outputs, 0)
         return jax.lax.psum(outputs, axis_name)
 
-    return shard_map(
+    return jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(axis_name), P(), P(), P()),
         out_specs=P(),

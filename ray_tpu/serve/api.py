@@ -371,6 +371,7 @@ class ServeController:
         self._versions: Dict[str, int] = {}
         self._version_cv = threading.Condition()
         self._probes: Dict[str, dict] = {}  # deployment -> {replica: ref}
+        self._constructed_keys: set = set()  # replicas the GCS has seen ALIVE
         self._shutdown = False
         import uuid
 
@@ -561,7 +562,58 @@ class ServeController:
             "_draining": carried_draining,
         }
         self._reconcile_one(name)
+        self._await_constructed(name)
         return True
+
+    def _constructed(self, r) -> Optional[bool]:
+        """Has the replica's constructor returned? True once the GCS has
+        reported its actor ALIVE, False if it died first, None while it is
+        still being placed or constructed. A replica that opens a chip,
+        builds a model and compiles can take minutes, and a call made on it
+        meanwhile fails after the core's actor-wait timeout — so nothing is
+        asked of it, and it is not judged, until then."""
+        from ray_tpu.core.api import _global_worker
+
+        key = _replica_key(r)
+        if key in self._constructed_keys:
+            return True
+        try:
+            info = _global_worker().get_actor_info(actor_id=r.actor_id)
+        except (OSError, RuntimeError, TimeoutError) as e:
+            logger.debug("replica state unknown (GCS away?): %s", e)
+            return None
+        state = info["state"] if info else "DEAD"
+        if state == "ALIVE":
+            self._constructed_keys.add(key)
+            return True
+        return False if state == "DEAD" else None
+
+    def _await_constructed(self, name: str) -> None:
+        """`serve.run` returns once the deployment's first replicas can
+        answer (reference serve.run blocks until the deployment is healthy).
+        A replica that dies in its constructor raises here instead of being
+        replaced in silence behind a handle whose requests then fail."""
+        from ray_tpu.core.api import _global_worker
+
+        t0 = told = time.monotonic()
+        first = list(self._replicas.get(name, []))
+        while not self._shutdown:
+            states = [(r, self._constructed(r)) for r in first]
+            for r, ok in states:
+                if ok is False:
+                    info = _global_worker().get_actor_info(actor_id=r.actor_id)
+                    raise RuntimeError(
+                        f"a replica of {name} died before it could serve: "
+                        f"{(info or {}).get('death_cause') or 'actor died'}")
+            if all(ok for _r, ok in states):
+                return
+            if time.monotonic() - told > 30.0:
+                told = time.monotonic()
+                logger.warning(
+                    "deployment %s: %d replica(s) still constructing after "
+                    "%.0fs", name, sum(ok is None for _r, ok in states),
+                    told - t0)
+            time.sleep(0.1)
 
     def reconfigure_deployment(self, name: str, user_config: Any) -> bool:
         """Lightweight update: push a new user_config into every live
@@ -687,10 +739,18 @@ class ServeController:
             self._probes.pop(name, None)
             return
         probes = self._probes.setdefault(name, {})
-        for r in replicas:
-            if r not in probes:
-                probes[r] = r.health.remote()
         dead = []
+        for r in replicas:
+            if r in probes:
+                continue
+            constructed = self._constructed(r)
+            if constructed:
+                probes[r] = r.health.remote()
+            elif constructed is False:  # died in its constructor
+                logger.warning("replica of %s died before it could serve; "
+                               "replacing", name)
+                dead.append(r)
+                self._kill_replica(name, r)
         for r in list(probes):
             if r not in replicas:  # replica already scaled away
                 probes.pop(r)
@@ -701,9 +761,9 @@ class ServeController:
             ref = probes.pop(r)
             try:
                 ray_tpu.get(ref)
-            except Exception:
-                logger.warning("replica of %s failed health check; "
-                               "replacing", name)
+            except Exception as e:
+                logger.warning("replica of %s failed health check (%r); "
+                               "replacing", name, e)
                 dead.append(r)
                 self._kill_replica(name, r)
         if dead:
@@ -767,6 +827,7 @@ class ServeController:
         return v
 
     def _kill_replica(self, name: str, r) -> None:
+        self._constructed_keys.discard(_replica_key(r))
         self._replica_def_version.pop(_replica_key(r), None)
         self._version_queries.pop(_replica_key(r), None)
         self._evict_stats_client(r)

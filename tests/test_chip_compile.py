@@ -1,0 +1,222 @@
+"""Ask the TPU's compiler about the kernels of the main path, at b1 widths.
+
+Nothing runs: the chip is described (`v5e:2x2`), not attached, so these say
+what Mosaic and XLA:TPU accept — tiling, VMEM, HBM — and nothing about
+results or speed. Interpret-mode tests cannot see any of that.
+
+The topology is described inside a module-scoped fixture (only one process
+may hold libtpu, and every xdist worker imports every test file), compiles
+happen in the test's own process, and all of them live in this one file.
+The code's device branches ask `ops.pallas._util.on_tpu()`; the tests steer
+that one function instead of adding an option to the program.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models import ModelConfig
+from ray_tpu.ops.pallas import _util
+
+B1 = ModelConfig.b1()  # d 2048, 16 layers, 16/8 heads of 128, d_ff 8192
+SEQ = 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """Describing a chip loads libtpu, which by default lets ONE process on
+    the machine do so (its lock file guards a real chip). Nothing is opened
+    here, and under xdist several workers may each run part of this file,
+    so the load is declared shareable for the length of this call."""
+    from jax.experimental import topologies
+
+    asked = {"TPU_LOG_DIR": "disabled", "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"}
+    was = {k: os.environ.get(k) for k in asked}
+    os.environ.update(asked)
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        for k, v in was.items():
+            os.environ.pop(k) if v is None else os.environ.update({k: v})
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def chip(one_chip, monkeypatch):
+    """Shapes on the described chip, with the kernels' device branch steered
+    to TPU and the persistent cache off (an entry written for a described
+    chip cannot be read back without one, and warns)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(_util, "on_tpu", lambda: True)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    yield shape
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _kernels(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+def test_flash_attention_fwd_bwd_compiles(chip):
+    from ray_tpu.ops.attention import attention
+
+    def loss(q, k, v):
+        return attention(q, k, v).astype(jnp.float32).sum()
+
+    q = chip((4, B1.n_heads, SEQ, B1.head_dim))  # [64, 2048, 128] per call
+    c = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).compile()
+    assert _kernels(c) >= 3  # forward, dq, dkv
+
+
+def test_fused_ffn_block_fwd_bwd_compiles(chip):
+    from ray_tpu.ops.pallas.fused_ffn import ffn_block
+
+    d, f = B1.d_model, B1.d_ff
+
+    def loss(x, nw, wg, wu, wd):
+        return ffn_block(x, nw, wg, wu, wd, B1.norm_eps).astype(jnp.float32).sum()
+
+    args = (chip((2, SEQ, d)), chip((d,)), chip((d, f)), chip((d, f)),
+            chip((f, d)))
+    c = jax.jit(jax.grad(loss, argnums=(0, 2, 3, 4))).lower(*args).compile()
+    assert _kernels(c) >= 1  # K3 (dW gate/up fused with the norm chain)
+
+
+@pytest.mark.parametrize("dims", [(2 * SEQ, B1.d_model), (B1.d_model, B1.d_ff)])
+def test_int8_quant_roundtrip_compiles(chip, dims):
+    from ray_tpu.ops.pallas.quant import dequantize_int8, quantize_int8
+
+    def roundtrip(x):
+        return dequantize_int8(*quantize_int8(x))
+
+    c = jax.jit(roundtrip).lower(chip(dims)).compile()
+    assert _kernels(c) == 2
+
+
+def test_adamw_leaf_update_compiles(chip):
+    from ray_tpu.ops.pallas.adamw import _leaf_update
+
+    leaf = (B1.n_layers, B1.d_model, B1.d_ff)  # the largest stacked leaf
+
+    def update(p, g, mu, nu, scalars):
+        return _leaf_update(p, g, mu, nu, scalars, b1=0.9, b2=0.95, eps=1e-8,
+                            wd=0.1)
+
+    c = jax.jit(update).lower(
+        chip(leaf), chip(leaf), chip(leaf, jnp.float32),
+        chip(leaf, jnp.float32), chip((1, 4), jnp.float32)).compile()
+    assert _kernels(c) == 1
+
+
+@pytest.mark.parametrize("attn_len", [64, 512])
+def test_decode_step_fused_compiles_at_b1_8_slots(chip, attn_len):
+    from ray_tpu.models.serving import decode_step_fused
+    from ray_tpu.models.transformer import init_params
+
+    slots, max_len = 8, 512
+    params = jax.eval_shape(lambda k: init_params(k, B1), jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(lambda s: chip(s.shape, s.dtype), params)
+    kv = chip((B1.n_layers, slots, B1.n_kv_heads, max_len, B1.head_dim))
+    ints = chip((slots,), jnp.int32)
+    c = decode_step_fused.lower(params, kv, kv, ints, ints, B1, attn_len).compile()
+    m = c.memory_analysis()
+    # weights + both caches in, caches updated in place (donated)
+    assert m.argument_size_in_bytes < 3 * 2**30
+    assert m.alias_size_in_bytes >= 2 * (kv.size * 2)
+
+
+def test_prefill_slots_compiles_at_b1(chip):
+    from ray_tpu.models.serving import prefill_slots
+    from ray_tpu.models.transformer import init_params
+
+    params = jax.eval_shape(lambda k: init_params(k, B1), jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(lambda s: chip(s.shape, s.dtype), params)
+    c = prefill_slots.lower(params, chip((4, 256), jnp.int32),
+                            chip((4,), jnp.int32), B1, 512).compile()
+    assert c.memory_analysis().argument_size_in_bytes < 3 * 2**30
+
+
+def _lower_b1_step(topo, *, chips, mesh, batch, seq, optimizer, fused):
+    """The whole b1 train step, as `chip_smoke.py` / `bench.py` build it,
+    lowered for `chips` described devices. `.compile()` is the question."""
+    import dataclasses
+
+    from ray_tpu.parallel import MeshConfig, make_mesh
+    from ray_tpu.train.step import batch_sharding, make_train_step
+
+    cfg = dataclasses.replace(B1, max_seq_len=seq, remat="dots", loss_chunk=0,
+                              fused_ffn=fused, fused_attn=fused)
+    mesh = make_mesh(MeshConfig(**mesh), topo.devices[:chips])
+    step_fn, init_fn, shardings = make_train_step(cfg, mesh, optimizer)
+    state = jax.tree_util.tree_map(
+        lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
+        jax.eval_shape(init_fn, jax.random.PRNGKey(0)), shardings)
+    b_sh = batch_sharding(mesh)
+    batch = {k: jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=b_sh[k])
+             for k in ("inputs", "targets")}
+    return step_fn.lower(state, batch)
+
+
+# The whole-program compiles below keep ~5 cores busy for ~10 s each, which
+# starves the timing-sensitive runtime tests that share a tier-1 run: they
+# are marked slow (`pytest -m slow tests/test_chip_compile.py`, ~40 s).
+@pytest.mark.slow
+@pytest.mark.parametrize("chips,mesh,fused", [
+    (1, {"dp": 1}, True),                       # chip_smoke's one-chip step
+    (4, {"dp": 1, "fsdp": 2, "tp": 2}, False),  # its four-chip step
+])
+def test_b1_train_step_compiles_and_fits(topo, chip, chips, mesh, fused):
+    from ray_tpu.train.step import default_optimizer
+
+    c = _lower_b1_step(topo, chips=chips, mesh=mesh, batch=2, seq=SEQ,
+                       optimizer=default_optimizer(), fused=fused).compile()
+    hlo = c.as_text()
+    assert _kernels(c) >= 4  # flash fwd, dq, dkv + FFN K3 (fused) / under shard_map
+    # a Mosaic kernel cannot be partitioned by the compiler: on a mesh the
+    # flash kernel runs per device under shard_map, between collectives
+    assert (hlo.count(" all-gather(") > 0) == (chips > 1)
+    m = c.memory_analysis()
+    # 1.14B params: f32 master + two moments + bf16 working copy, per chip
+    assert m.argument_size_in_bytes < 9.5e9 / chips * 1.05
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("what", [
+    # ROADMAP S3: FusedAdamW with PALLAS_LEAVES unlimited. Every leaf's kernel
+    # is accepted; the program is refused for HBM, "Used 16.29G" (nu is f32).
+    "fused_adamw_unlimited",
+    # ROADMAP S3: b1 at seq 8192, batch 1: "Used 19.01G".
+    "seq_8192",
+])
+def test_b1_step_the_compiler_refuses_for_hbm(topo, chip, monkeypatch, what):
+    from ray_tpu.ops.pallas import adamw
+    from ray_tpu.train.step import default_optimizer, fused_adamw_optimizer
+
+    if what == "fused_adamw_unlimited":
+        monkeypatch.setattr(adamw, "PALLAS_LEAVES", 10**9)
+        lowered = _lower_b1_step(topo, chips=1, mesh={"dp": 1}, batch=2, seq=SEQ,
+                                 optimizer=fused_adamw_optimizer(), fused=True)
+    else:
+        lowered = _lower_b1_step(topo, chips=1, mesh={"dp": 1}, batch=1, seq=8192,
+                                 optimizer=default_optimizer(), fused=True)
+    with pytest.raises(jax.errors.JaxRuntimeError,
+                       match=r"RESOURCE_EXHAUSTED.*Used \d+\.\d+G of 15\.75G hbm"):
+        lowered.compile()

@@ -25,7 +25,7 @@ def client_server():
         [sys.executable, "-m", "ray_tpu", "client-server",
          "--num-cpus", "4", "--resources", '{"TPU": 8}'],
         stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
-        env=env, cwd="/tmp")
+        env=env, cwd="/tmp", start_new_session=True)
     try:
         line = proc.stdout.readline().strip()
         assert line.startswith("ray://"), line
@@ -36,7 +36,13 @@ def client_server():
             proc.wait(timeout=10)
         except subprocess.TimeoutExpired:
             proc.kill()
-        subprocess.run(["pkill", "-f", "worker_main"], check=False)
+        # stray workers of THIS server only (its session): a `pkill -f
+        # worker_main` also killed the workers of every other xdist worker's
+        # cluster, which then failed with WorkerCrashedError
+        try:
+            os.killpg(proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass  # the whole session is gone already
 
 
 @pytest.fixture

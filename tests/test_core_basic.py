@@ -444,7 +444,8 @@ def test_max_calls_results_survive_recycling(ray_start_regular):
 def test_tpu_and_gpu_id_accessors(ray_start_regular):
     """get_gpu_ids() is always [] (TPU framework); get_tpu_ids() returns
     raylet-granted chip indices: DISJOINT across concurrent tasks, held
-    for an actor's lifetime, shared index for fractional demands."""
+    for an actor's lifetime, a whole index even for a fractional demand
+    (two processes cannot share a chip)."""
     import time
 
     assert ray_tpu.get_gpu_ids() == []
@@ -483,3 +484,252 @@ def test_tpu_and_gpu_id_accessors(ray_start_regular):
         return ray_tpu.get_tpu_ids()
 
     assert len(ray_tpu.get(frac.remote(), timeout=60)) == 1
+
+
+def _env_reporter():
+    """A function (pickled by value: workers cannot import this module) that
+    says how its worker was started — no jax involved."""
+
+    def report():
+        import os
+
+        keys = ("JAX_PLATFORMS", "TPU_VISIBLE_CHIPS", "RAY_TPU_WORKER_FORKED",
+                "JAX_COMPILATION_CACHE_DIR", "TPU_PROCESS_BOUNDS")
+        return {"pid": os.getpid(), "tpu_ids": ray_tpu.get_tpu_ids(),
+                **{k: os.environ.get(k) for k in keys}}
+
+    return report
+
+
+def _wait_for(cond, timeout=30.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, "condition not reached in time"
+        time.sleep(0.05)
+
+
+@pytest.mark.parametrize("operator_platform", [None, "cpu"])
+def test_tpu_lease_worker_is_cold_spawned_for_its_grant(monkeypatch,
+                                                        operator_platform):
+    """A lease that demands TPU gets a worker STARTED for that grant: cold
+    (never a template fork), the granted chips and no others visible to
+    libtpu, JAX_PLATFORMS left to the operator (none set -> `tpu`, so jax
+    raises without a chip instead of computing on the CPU), a fixed compile
+    cache. A lease without TPU still forks warm with JAX_PLATFORMS=cpu."""
+    import os
+
+    from ray_tpu.core import api
+    from ray_tpu.core.config import reset_config
+
+    if operator_platform is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", operator_platform)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    reset_config()
+    ray_tpu.init(num_cpus=4, resources={"TPU": 2})
+    env_report = _env_reporter()
+    try:
+        pool = api._node.raylet._worker_pool
+
+        @ray_tpu.remote(num_tpus=1)
+        class Holder:
+            def report(self):
+                return env_report()
+
+        cold_before = pool.stats()["registered_cold"]
+        h = Holder.remote()
+        rep = ray_tpu.get(h.report.remote(), timeout=60)
+        assert rep["RAY_TPU_WORKER_FORKED"] is None
+        assert pool.stats()["registered_cold"] == cold_before + 1
+        assert len(rep["tpu_ids"]) == 1
+        assert rep["TPU_VISIBLE_CHIPS"] == str(rep["tpu_ids"][0])
+        assert rep["TPU_PROCESS_BOUNDS"] == "1,1,1"  # 1 chip of the node's 2
+        assert rep["JAX_PLATFORMS"] == (operator_platform or "tpu")
+        assert rep["JAX_COMPILATION_CACHE_DIR"].endswith(".jax_compile_cache")
+
+        @ray_tpu.remote
+        def plain():
+            return env_report()
+
+        cpu = ray_tpu.get(plain.remote(), timeout=60)
+        assert cpu["RAY_TPU_WORKER_FORKED"] == "1"
+        assert cpu["JAX_PLATFORMS"] == "cpu"
+        assert cpu["TPU_VISIBLE_CHIPS"] is None and cpu["tpu_ids"] == []
+        ray_tpu.kill(h)
+    finally:
+        ray_tpu.shutdown()
+        reset_config()
+
+
+def test_tpu_worker_serves_one_lease_and_chip_frees_after_exit(
+        ray_start_regular):
+    """A TPU task's process is never handed a lease without that grant: it
+    retires after its task. Chips go back to the free list only once the
+    holding PROCESS is gone, and a lease that finds none waits."""
+    import os
+    import subprocess
+
+    from ray_tpu.core import api
+    from ray_tpu.core.raylet import WorkerHandle
+
+    raylet = api._node.raylet
+    env_report = _env_reporter()
+
+    @ray_tpu.remote(num_tpus=1)
+    def on_chip():
+        return env_report()
+
+    @ray_tpu.remote
+    def plain():
+        return env_report()["pid"]
+
+    first = ray_tpu.get(on_chip.remote(), timeout=60)
+    later = ray_tpu.get([plain.remote() for _ in range(8)], timeout=60)
+    assert first["pid"] not in later
+    _wait_for(lambda: not os.path.exists(f"/proc/{first['pid']}"))
+    _wait_for(lambda: len(raylet._free_chips) == 8)
+
+    # the grant outlives the lease until the process has exited
+    with raylet._lock:
+        ids = raylet._assign_tpus(8.0)
+    assert ids == list(range(8))
+    waiting = on_chip.remote()  # resources fit, no chip is free: it waits
+    proc = subprocess.Popen(["sleep", "1.5"])
+    holder = WorkerHandle(worker_id=None, conn=None, address="", pid=proc.pid,
+                          proc=proc, tpu_grant=ids)
+    raylet._release_chips_on_exit(holder)
+    assert holder.tpu_grant is None
+    time.sleep(0.5)
+    assert proc.poll() is None and raylet._free_chips == []
+    done, _ = ray_tpu.wait([waiting], timeout=0.1)
+    assert not done
+    got = ray_tpu.get(waiting, timeout=60)  # runs once the holder is gone
+    assert proc.poll() is not None
+    assert len(got["tpu_ids"]) == 1
+
+
+def _stall_tpu_spawns(monkeypatch, fail=False):
+    """TPU workers spawned from here on never register (`sleep`), or do not
+    spawn at all: the lease stays granted with its worker 'starting'."""
+    import subprocess
+
+    real = subprocess.Popen
+
+    def popen(argv, *a, env=None, **kw):
+        if env is not None and env.get("TPU_VISIBLE_CHIPS") is not None:
+            if fail:
+                raise OSError(12, "Cannot allocate memory")
+            argv = ["sleep", "60"]
+        return real(argv, *a, env=env, **kw)
+
+    monkeypatch.setattr(subprocess, "Popen", popen)
+
+
+def _all_chips_back(raylet):
+    _wait_for(lambda: len(raylet._free_chips) == 8)
+    assert raylet._tpu_leases == []
+    assert raylet.resources_available["TPU"] == 8.0
+
+
+@pytest.mark.parametrize("how", ["cancel", "reap_job", "kill_actor", "fence"])
+def test_lease_with_a_starting_worker_can_be_taken_back(
+        ray_start_regular, monkeypatch, how):
+    """Between the grant and the worker's registration (~10 s on a chip) a
+    TPU lease is in neither the queue nor `_workers`. A cancel, a job reap,
+    an actor kill and a fence must still find it: the starting process is
+    killed, the charge undone, and the chips freed once it is gone."""
+    import os
+
+    from ray_tpu.core import api
+    from ray_tpu.core.exceptions import TaskCancelledError
+
+    raylet = api._node.raylet
+    _stall_tpu_spawns(monkeypatch)
+
+    @ray_tpu.remote(num_tpus=2, max_retries=0)
+    def on_chip():
+        return 1
+
+    @ray_tpu.remote(num_tpus=2)
+    class Holder:
+        def ok(self):
+            return True
+
+    if how == "kill_actor":
+        target = Holder.remote()
+    else:
+        ref = on_chip.remote()
+    _wait_for(lambda: any(l.proc is not None for l in raylet._tpu_leases))
+    lease = raylet._tpu_leases[0]
+    assert lease.tpu_ids == [0, 1] and raylet._free_chips == list(range(2, 8))
+    pid = lease.proc.pid
+    if how == "cancel":
+        ray_tpu.cancel(ref)
+        with pytest.raises(TaskCancelledError):
+            ray_tpu.get(ref, timeout=30)
+    elif how == "reap_job":
+        out = raylet.rpc_reap_job(None, 0, {"job_id": lease.spec.job_id.binary()})
+        assert out["queued_cancelled"] == 1
+    elif how == "kill_actor":
+        ray_tpu.kill(target)
+    else:
+        freed = []
+        real = raylet._release_chips_after
+        monkeypatch.setattr(
+            raylet, "_release_chips_after", lambda proc, ids: (
+                freed.append((proc, ids, list(raylet._free_chips))),
+                real(proc, ids)))
+        raylet._do_self_fence("test")
+        # held chips were not declared free ahead of their process's exit
+        assert freed == [(lease.proc, [0, 1], list(range(2, 8)))]
+        with pytest.raises(Exception):  # its owner hears the worker died
+            ray_tpu.get(ref, timeout=30)
+    _wait_for(lambda: not os.path.exists(f"/proc/{pid}")
+              or open(f"/proc/{pid}/stat").read().split()[2] == "Z")
+    _all_chips_back(raylet)
+
+
+def test_failed_spawn_fails_the_tpu_lease(ray_start_regular, monkeypatch):
+    """The task was popped, charged and given chips before its worker is
+    spawned; a spawn that raises must not lose all three and hang the
+    owner."""
+    from ray_tpu.core import api
+
+    _stall_tpu_spawns(monkeypatch, fail=True)
+
+    @ray_tpu.remote(num_tpus=1, max_retries=0)
+    def on_chip():
+        return 1
+
+    with pytest.raises(Exception, match="(?i)worker|died|crash"):
+        ray_tpu.get(on_chip.remote(), timeout=30)
+    _all_chips_back(api._node.raylet)
+
+
+def test_group_removed_under_a_live_actor_leaks_nothing(ray_start_regular):
+    """A trainer kills its workers and removes their placement group in one
+    breath: the bundle comes back while the actor is still charged inside
+    it. What remained returns with the bundle, the charge when the worker
+    is gone — it used to be dropped, and the node lost the chips for good."""
+    from ray_tpu.core.placement_group import (placement_group,
+                                              remove_placement_group)
+
+    @ray_tpu.remote
+    class Holder:
+        def ok(self):
+            return True
+
+    before = ray_tpu.available_resources()
+    pg = placement_group([{"TPU": 4.0, "CPU": 1.0}], strategy="STRICT_PACK")
+    assert pg.ready(timeout=30)
+    h = Holder.options(placement_group=pg, placement_group_bundle_index=0,
+                       num_cpus=1, resources={"TPU": 4.0}).remote()
+    assert ray_tpu.get(h.ok.remote(), timeout=60)
+    ray_tpu.kill(h)
+    remove_placement_group(pg)
+    _wait_for(lambda: ray_tpu.available_resources().get("TPU") == before["TPU"]
+              and ray_tpu.available_resources().get("CPU") == before["CPU"])
+    again = placement_group([{"TPU": 8.0}], strategy="STRICT_PACK")
+    assert again.ready(timeout=30)
+    remove_placement_group(again)

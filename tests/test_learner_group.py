@@ -164,11 +164,6 @@ def test_vtrace_family_mesh_matches_single_device():
                                    rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="meshed multi_transform + jitted polyak numerics diverge from "
-           "the single-device path on jax<0.5 (sharded-update fusion "
-           "differences beyond test tolerance)")
 def test_continuous_family_mesh_matches_single_device():
     """DDPG (continuous actor-critic family) on the mesh backend: the
     combined actor+critic loss with multi_transform optimizers and the

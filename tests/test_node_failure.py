@@ -287,6 +287,48 @@ def test_node_death_detection_latency_bounded(fast_health):
         cluster.shutdown()
 
 
+def test_node_with_chips_is_given_time_for_a_chip_open(fast_health):
+    """Opening or closing a chip stalls its whole host for seconds (8-12 s
+    measured on a v5e host), heartbeats included: silence from a node that
+    registered TPU is judged against three times the bound, so at defaults
+    a chip open neither quarantines the node nor gets it declared dead."""
+    cluster = Cluster()
+    cluster.add_node(num_cpus=2, resources={"keep": 1})
+    plain = cluster.add_node(num_cpus=2)
+    chips = cluster.add_node(num_cpus=2, resources={"TPU": 1})
+    cluster.connect()
+    removed, quarantined = {}, {}
+
+    def on_nodes(msg):
+        seen = {"removed": removed, "quarantined": quarantined}.get(
+            msg.get("event"))
+        if seen is not None:
+            seen.setdefault(msg["node_id"].hex(), time.monotonic())
+
+    try:
+        from ray_tpu.core.worker import current_worker
+
+        current_worker().subscribe_channel("nodes", on_nodes)
+        time.sleep(0.3)  # at least one healthy heartbeat round first
+        t0 = time.monotonic()
+        rpc.install_fault_injector("drop:heartbeat", seed=FAULT_SEED)
+        timeout_s = get_config().health_check_timeout_ms / 1000.0
+        deadline = time.monotonic() + 3 * timeout_s * 2 + 2
+        while chips.node_id.hex() not in removed:
+            assert time.monotonic() < deadline, "silent TPU node never died"
+            time.sleep(0.05)
+        plain_s = removed[plain.node_id.hex()] - t0
+        chips_s = removed[chips.node_id.hex()] - t0
+        assert plain_s <= timeout_s * 1.5 + 0.7, plain_s
+        # a stall as long as the plain death bound costs it nothing ...
+        assert quarantined[chips.node_id.hex()] - t0 > timeout_s
+        # ... and it is declared dead at three times the bound
+        assert 3 * timeout_s - 0.5 < chips_s <= 3 * timeout_s * 1.5 + 0.7, chips_s
+    finally:
+        rpc.clear_fault_injector()
+        cluster.shutdown()
+
+
 def test_actor_restarts_on_replacement_node(fast_health):
     """The actor's node dies; the only capacity for it is the autoscaler's
     REPLACEMENT node (survivors hold no 'fleet'), so the restart must land

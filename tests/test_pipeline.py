@@ -17,16 +17,6 @@ from ray_tpu.models.transformer import init_params, loss_fn
 from ray_tpu.parallel import MeshConfig, make_virtual_mesh
 from ray_tpu.parallel.pipeline import make_pp_train_step, pp_loss_fn
 
-# The pipeline forward runs in a PARTIAL-manual shard_map (manual over pp
-# only, dp/fsdp/tp stay auto-sharded). On jax builds without the top-level
-# jax.shard_map API (< 0.5), that partial-manual region lowers to a
-# PartitionId instruction the CPU SPMD partitioner rejects
-# ("PartitionId ... is not supported for SPMD partitioning").
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map needs jax>=0.5 (old XLA SPMD "
-           "partitioner rejects PartitionId in partial-auto regions)")
-
 
 def _batch(cfg, b=4, s=64, seed=0):
     rng = np.random.default_rng(seed)

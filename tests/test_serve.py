@@ -315,6 +315,85 @@ def test_replica_health_check_restart(serve_cluster):
         raise AssertionError((serve.status(), seen))
 
 
+def test_run_waits_for_a_slow_constructor(serve_cluster):
+    """`serve.run` returns once the first replica's constructor has (a
+    replica that opens a chip and builds a model takes a while), so the
+    first request never waits out the core's actor-wait timeout on it, and
+    the controller has not replaced it meanwhile."""
+    @serve.deployment
+    class Slow:
+        def __init__(self):
+            import os
+
+            time.sleep(3.0)
+            self.pid = os.getpid()
+
+        def __call__(self, _):
+            return self.pid
+
+    t0 = time.monotonic()
+    handle = serve.run(Slow.bind())
+    assert time.monotonic() - t0 >= 3.0
+    t0 = time.monotonic()
+    pid = ray_tpu.get(handle.remote(None), timeout=30)
+    assert time.monotonic() - t0 < 2.0
+    time.sleep(2.5)  # a few health rounds
+    assert ray_tpu.get(handle.remote(None), timeout=30) == pid
+    assert serve.status()["Slow"]["replicas"] == 1
+
+
+def test_run_raises_when_the_constructor_does(serve_cluster):
+    @serve.deployment
+    class Broken:
+        def __init__(self):
+            raise ValueError("no weights here")
+
+        def __call__(self, _):
+            return 1
+
+    with pytest.raises(Exception, match="died before it could serve"):
+        serve.run(Broken.bind())
+    serve.delete("Broken")
+
+
+@pytest.mark.parametrize("state,probed,kept", [
+    (None, False, True),    # still constructing: not asked, not judged
+    (True, True, True),     # constructor returned: probed as ever
+    (False, False, False),  # died in its constructor: replaced
+])
+def test_health_check_asks_nothing_of_a_constructing_replica(
+        monkeypatch, state, probed, kept):
+    """The controller's health probe is an actor call, and a call on an
+    actor still in its constructor fails after the core's 60 s actor-wait
+    timeout: probing a cold TPU replica (chip open + weights + compiles)
+    used to get a healthy replica killed."""
+    ctl = object.__new__(serve.api.ServeController._cls)
+    calls = []
+
+    class Replica:
+        actor_id = b"r1"
+
+        class health:
+            @staticmethod
+            def remote():
+                calls.append("health")
+                return "ref"
+
+    r = Replica()
+    ctl._replicas = {"d": [r]}
+    ctl._probes = {}
+    ctl._versions = {}
+    killed = []
+    monkeypatch.setattr(ctl, "_constructed", lambda _r: state)
+    monkeypatch.setattr(ctl, "_kill_replica", lambda _n, x: killed.append(x))
+    monkeypatch.setattr(ctl, "_bump_version", lambda _n: None)
+    monkeypatch.setattr(ray_tpu, "wait", lambda refs, **kw: ([], refs))
+    ctl._health_check("d")
+    assert bool(calls) == probed
+    assert (ctl._replicas["d"] == [r]) == kept
+    assert (killed == []) == kept
+
+
 @pytest.mark.slow
 def test_handle_closed_loop_throughput(ray_start_regular):
     """Thread-free data plane throughput: >=1k req/s closed-loop through the
