@@ -41,6 +41,7 @@ from ray_tpu.core.api import (
     available_resources,
     get_runtime_context,
     timeline,
+    timeline_info,
 )
 from ray_tpu.core.object_ref import ObjectRef, ObjectRefGenerator
 from ray_tpu.core.actor import ActorClass, ActorHandle
@@ -76,6 +77,7 @@ __all__ = [
     "available_resources",
     "get_runtime_context",
     "timeline",
+    "timeline_info",
     "ObjectRef",
     "ObjectRefGenerator",
     "push",

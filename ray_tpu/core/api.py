@@ -24,6 +24,9 @@ logger = logging.getLogger(__name__)
 _worker = None
 _node = None
 _init_lock = threading.RLock()
+# what `timeline()` / `timeline_info()` read last from the local cluster
+# this process hosted, kept by `shutdown()`: its GCS span ring dies with it
+_last_timeline: Optional[dict] = None
 
 
 def _global_worker():
@@ -135,8 +138,17 @@ def init(
 
 
 def shutdown() -> None:
-    global _worker, _node
+    global _worker, _node, _last_timeline
     with _init_lock:
+        if _worker is not None and _node is not None:
+            # this process hosts the head: read the session's spans back
+            # before the GCS ring goes (a driver that merely attached to a
+            # remote cluster keeps nothing: that ring lives on)
+            try:
+                _last_timeline = {"info": timeline_info(timeout=3),
+                                  "events": timeline(timeout=3)}
+            except Exception:  # teardown: a half-dead cluster may raise
+                pass
         if _worker is not None:
             try:
                 _worker.shutdown()
@@ -406,20 +418,37 @@ def get_tpu_ids() -> List[int]:
     return list(ids)
 
 
-def timeline() -> List[dict]:
+def timeline(timeout: float = 10) -> List[dict]:
     """Cluster-wide chrome-trace events: this process's spans plus the
     worker spans aggregated in the GCS (reference `ray.timeline()`,
-    _private/state.py:851)."""
+    _private/state.py:851). With no cluster: the last session of a local
+    cluster this process hosted, as `shutdown()` kept it (else only the
+    local ring)."""
     from ray_tpu.util.tracing import get_events
 
+    if not is_initialized():
+        return list(_last_timeline["events"]) if _last_timeline \
+            else get_events()
     events = get_events()
     try:
         w = _global_worker()
         w.flush_profile_events()
-        remote = w.gcs.call("get_profile_events", timeout=10)
+        remote = w.gcs.call("get_profile_events", timeout=timeout)
         # dedupe by origin worker id (pids collide across hosts)
         local_src = w.worker_id.binary().hex()
         events = events + [e for e in remote if e.get("_src") != local_src]
     except Exception:
         pass
     return events
+
+
+def timeline_info(timeout: float = 10) -> dict:
+    """The GCS's account of the spans behind `timeline()`: `spans_dropped`
+    (rings that overflowed before shipping), `spans_evicted` (fell off the
+    GCS ring), `spans_buffered`, `traces`, `traces_evicted`. All zero means
+    the timeline is whole. With no cluster: the kept last session's."""
+    if not is_initialized():
+        return dict(_last_timeline["info"]) if _last_timeline else {}
+    stats = _global_worker().gcs.call("gcs_stats", timeout=timeout)
+    return {k: v for k, v in (stats.get("tracing") or {}).items()
+            if k != "stage_latency_us"}

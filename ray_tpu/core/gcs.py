@@ -361,6 +361,7 @@ class GcsServer:
         self._traces: "OrderedDict[str, List[dict]]" = OrderedDict()
         self._traces_evicted = 0
         self._spans_dropped = 0       # worker-side ring overflow, summed
+        self._spans_evicted = 0       # fell off _profile_events' left edge
         self._span_clock_offsets: Dict[str, float] = {}  # src -> offset_us
         self._stage_lat_us: Dict[str, List[float]] = {}
 
@@ -2007,6 +2008,7 @@ class GcsServer:
                 "traces_evicted": self._traces_evicted,
                 "spans_buffered": len(self._profile_events),
                 "spans_dropped": self._spans_dropped,
+                "spans_evicted": self._spans_evicted,
                 "clock_sources": len(self._span_clock_offsets),
                 "stage_latency_us": stage_lat,
             }
@@ -2458,6 +2460,7 @@ class GcsServer:
         per-stage latency windows."""
         self._profile_events.extend(events)
         if len(self._profile_events) > 100_000:
+            self._spans_evicted += len(self._profile_events) - 100_000
             self._profile_events = self._profile_events[-100_000:]
         max_traces = max(1, get_config().tracing_max_traces)
         for e in events:

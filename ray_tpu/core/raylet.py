@@ -924,7 +924,7 @@ class Raylet:
         """Flush locally recorded spans (lease spans, mostly) to the GCS on
         the heartbeat cadence via the task_events_batch channel — the raylet
         process has no TaskEventBuffer, so it ships its own tracing ring."""
-        if not self.ship_spans or not tracing.enabled():
+        if not self.ship_spans:
             return
         fresh, self._spans_sent, spans_dropped = tracing.drain(self._spans_sent)
         spans_dropped += self._spans_dropped_pending

@@ -37,7 +37,8 @@ class TaskEventBuffer:
         # spans the tracing ring dropped but whose count failed delivery —
         # re-shipped with the next flush so truncation stays honest
         self._spans_dropped_pending = 0
-        # NTP-style clock offset vs the GCS (tracing_enabled only):
+        # NTP-style clock offset vs the GCS (only while this process ships
+        # spans that belong to a trace, or with tracing_enabled):
         # offset_us = t1 - (t0 + t2) / 2 from one clock_probe round-trip,
         # re-estimated every tracing_clock_probe_period_s and shipped with
         # each flush for merge-time cross-node alignment
@@ -126,14 +127,15 @@ class TaskEventBuffer:
             "spans_dropped": spans_dropped,
             "profile_events": [{**e, "_src": src} for e in fresh],
         }
-        if tracing.enabled():
-            now = _time.monotonic()
-            if (self._clock_offset_us is None or now >= self._clock_probe_at):
-                self._clock_probe_at = now + max(
-                    1.0, _get_config().tracing_clock_probe_period_s)
-                self._probe_clock()
-            if self._clock_offset_us is not None:
-                payload["clock_offset_us"] = self._clock_offset_us
+        now = _time.monotonic()
+        if ((self._clock_offset_us is None or now >= self._clock_probe_at)
+                and (tracing.enabled()
+                     or any("trace_id" in e for e in fresh))):
+            self._clock_probe_at = now + max(
+                1.0, _get_config().tracing_clock_probe_period_s)
+            self._probe_clock()
+        if self._clock_offset_us is not None:
+            payload["clock_offset_us"] = self._clock_offset_us
         # try_notify reports a down link (plain notify swallows it); fakes
         # and raw clients in tests surface failure by raising instead
         gcs = self._worker.gcs

@@ -610,14 +610,17 @@ class CoreWorker:
             logger.debug("task event record failed", exc_info=True)
 
     def _stamp_trace_ctx(self, spec: TaskSpec) -> float:
-        """Tracing-enabled only: mint the submit-stage span id and stamp
-        (trace_id, submit span_id) into the spec BEFORE it serializes, so
-        the raylet's lease span and the executor's run/result spans parent
-        under this submission. Returns the submit-span start stamp (0.0
-        when tracing is off — the hot path pays one config read)."""
-        if not tracing.enabled():
-            return 0.0
+        """A trace context propagates whenever one exists: under an
+        ambient context (or with `tracing_enabled`, which lets a
+        context-less submit root a trace of its own) mint the submit-stage
+        span id and stamp (trace_id, submit span_id) into the spec BEFORE
+        it serializes, so the raylet's lease span and the executor's
+        run/result spans parent under this submission. Returns the
+        submit-span start stamp; 0.0 — one thread-local read, nothing
+        minted — when there is no context and the switch is off."""
         ctx = tracing.current_ctx()
+        if ctx is None and not tracing.enabled():
+            return 0.0
         # no ambient trace -> this submission roots its own (detached: the
         # thread's TLS stays clean so unrelated submissions don't coalesce
         # into one giant trace)
@@ -3405,7 +3408,7 @@ class CoreWorker:
         # adopt the submitter's trace context: the execute/result spans —
         # and any task this task submits — join the same causal tree
         prev_ctx = tracing.current_ctx()
-        traced = spec.trace_ctx is not None and tracing.enabled()
+        traced = spec.trace_ctx is not None
         if traced:
             tracing.set_ctx(spec.trace_ctx)
             d_us = self._task_dispatch_us.pop(spec.task_id, None)

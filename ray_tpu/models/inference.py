@@ -100,31 +100,38 @@ def prefill(params: Dict, tokens: jax.Array, cfg: ModelConfig,
     pad = jnp.zeros((s, max_len - s), bool)
     mask = jnp.concatenate([causal, pad], axis=1)
 
+    # named scopes are metadata only: they name the phases of the program
+    # in a device trace (`cache_write`, `attention`, `mlp`, `head`)
     def body(x, lp):
         lp = _deq_tree(lp, cfg.dtype)
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = _project_qkv(cfg, lp, h, cos, sin)
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        k_cache = jnp.zeros((b, cfg.n_kv_heads, max_len, hd), cfg.dtype)
-        v_cache = jnp.zeros((b, cfg.n_kv_heads, max_len, hd), cfg.dtype)
-        k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(cfg.dtype), (0, 0, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(cfg.dtype), (0, 0, 0, 0))
-        attn = _masked_attention(q, k_cache, v_cache, mask)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * hd)
-        x = x + (attn @ lp["wo"]).astype(x.dtype)
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + _mlp(cfg, lp, h2).astype(x.dtype)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            q, k, v = _project_qkv(cfg, lp, h, cos, sin)
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        with jax.named_scope("cache_write"):
+            k_cache = jnp.zeros((b, cfg.n_kv_heads, max_len, hd), cfg.dtype)
+            v_cache = jnp.zeros((b, cfg.n_kv_heads, max_len, hd), cfg.dtype)
+            k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(cfg.dtype), (0, 0, 0, 0))
+            v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(cfg.dtype), (0, 0, 0, 0))
+        with jax.named_scope("attention"):
+            attn = _masked_attention(q, k_cache, v_cache, mask)
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * hd)
+            x = x + (attn @ lp["wo"]).astype(x.dtype)
+        with jax.named_scope("mlp"):
+            h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            x = x + _mlp(cfg, lp, h2).astype(x.dtype)
         return x, (k_cache, v_cache)
 
     x, (k_all, v_all) = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = lm_head_weights(params, cfg)
-    if logits_index is None:
-        sel = x[:, -1]
-    else:
-        sel = jnp.take_along_axis(
-            x, logits_index[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = (sel @ head.astype(cfg.dtype)).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = lm_head_weights(params, cfg)
+        if logits_index is None:
+            sel = x[:, -1]
+        else:
+            sel = jnp.take_along_axis(
+                x, logits_index[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        logits = (sel @ head.astype(cfg.dtype)).astype(jnp.float32)
     cache = {"k": k_all, "v": v_all, "length": jnp.asarray(s, jnp.int32)}
     return logits, cache
 

@@ -31,6 +31,15 @@ Device waits happen OUTSIDE the bookkeeping lock: `submit()`, `progress()`
 and `result()` stay responsive while a step is in flight (`_step_lock`
 serializes steppers; `_lock` only guards host-side state).
 
+The engine records what it does in the program's own timeline
+(`util/tracing.py`, category `engine`, read back by `ray_tpu.timeline()`):
+per request `engine.queue` -> `engine.prefill` -> `engine.decode`,
+contiguous, emitted when the request finishes, under the trace context its
+`submit()` ran in (a Serve replica's request thread has one; standalone use
+has none and the spans carry no ids); per step `engine.step` with children
+`engine.prefill_dispatch` and `engine.wait_device`, so a step's host time
+is its duration less its device waits.
+
 Serve wires it through `LLMDeployment` (serve replicas each host an
 engine; the replica lifecycle hooks `__serve_start__`/`__serve_stop__`
 start and stop a background driver thread so the engine steps itself and
@@ -55,6 +64,7 @@ from ray_tpu.models.inference import (_gqa_decode_attention, _masked_attention,
 from ray_tpu.models.transformer import (ModelConfig, _deq_tree,
                                         _embed_lookup, lm_head_weights)
 from ray_tpu.ops.layers import rms_norm, rotary_embedding
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -235,21 +245,25 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
     x = _embed_lookup(params["embed"], tokens[:, None], cfg.dtype)  # [B,1,d]
     mask = jnp.arange(attn_len)[None, :] < lengths[:, None]  # [B, attn_len]
 
+    # named scopes are metadata only: they name the step's phases in a
+    # device trace (`attention`, `mlp`, `cache_write`, `head`)
     def body(x, inputs):
         lp, k_cache, v_cache = inputs  # read-only [B, kvh, max_len, hd]
         lp = _deq_tree(lp, cfg.dtype)
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = _project_qkv(cfg, lp, h, cos, sin)
-        q = q.transpose(0, 2, 1, 3)  # [B, h, 1, hd]
-        k_cur = k.transpose(0, 2, 1, 3)[:, :, 0].astype(cfg.dtype)  # [B,kvh,hd]
-        v_cur = v.transpose(0, 2, 1, 3)[:, :, 0].astype(cfg.dtype)
-        attn = _gqa_decode_attention(
-            q, k_cache[:, :, :attn_len], v_cache[:, :, :attn_len],
-            k_cur, v_cur, mask)
-        attn = attn.reshape(B, 1, cfg.n_heads * hd)
-        x = x + (attn @ lp["wo"]).astype(x.dtype)
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + _mlp(cfg, lp, h2).astype(x.dtype)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            q, k, v = _project_qkv(cfg, lp, h, cos, sin)
+            q = q.transpose(0, 2, 1, 3)  # [B, h, 1, hd]
+            k_cur = k.transpose(0, 2, 1, 3)[:, :, 0].astype(cfg.dtype)  # [B,kvh,hd]
+            v_cur = v.transpose(0, 2, 1, 3)[:, :, 0].astype(cfg.dtype)
+            attn = _gqa_decode_attention(
+                q, k_cache[:, :, :attn_len], v_cache[:, :, :attn_len],
+                k_cur, v_cur, mask)
+            attn = attn.reshape(B, 1, cfg.n_heads * hd)
+            x = x + (attn @ lp["wo"]).astype(x.dtype)
+        with jax.named_scope("mlp"):
+            h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            x = x + _mlp(cfg, lp, h2).astype(x.dtype)
         return x, (k_cur, v_cur)
 
     x, (k_cur, v_cur) = jax.lax.scan(body, x, (params["layers"], k_all, v_all))
@@ -261,11 +275,13 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
     wr = jax.vmap(jax.vmap(jax.vmap(write_row, in_axes=(0, 0, None)),  # kvh
                            in_axes=(0, 0, 0)),                         # B
                   in_axes=(0, 0, None))                                # L
-    k_all = wr(k_all, k_cur[:, :, :, None], lengths)
-    v_all = wr(v_all, v_cur[:, :, :, None], lengths)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x[:, 0] @ lm_head_weights(params, cfg)).astype(jnp.float32)
-    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("cache_write"):
+        k_all = wr(k_all, k_cur[:, :, :, None], lengths)
+        v_all = wr(v_all, v_cur[:, :, :, None], lengths)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = (x[:, 0] @ lm_head_weights(params, cfg)).astype(jnp.float32)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return k_all, v_all, lengths + 1, nxt
 
 
@@ -284,6 +300,15 @@ class _Request:
     generated: List[int] = dataclasses.field(default_factory=list)
     slot: int = -1
     done: bool = False
+    # the request's stages (tracing.now_us() stamps) and the trace context
+    # `submit()` ran in; they become its three spans in `_maybe_finish`
+    trace_ctx: Optional[Tuple[str, str]] = None
+    t_submit: float = 0.0
+    t_admit: float = 0.0
+    t_first: float = 0.0
+    waited_for_slot: bool = False  # an admission pass found no slot free
+    bucket: int = 0                # of the prefill that admitted it,
+    batch: int = 0                 # and how many requests rode that call
 
 
 class ContinuousBatchingEngine:
@@ -331,6 +356,8 @@ class ContinuousBatchingEngine:
         self._driver: Optional[threading.Thread] = None
         self._driver_stop = False
         self._driver_error: Optional[BaseException] = None
+        self._attn_len = 0  # attention bucket of the last dispatched decode
+        tracing.record_compiles()
 
     # ------------------------------------------------------------- requests
     def submit(self, prompt: List[int], *, max_new_tokens: int = 32) -> int:
@@ -341,7 +368,9 @@ class ContinuousBatchingEngine:
                 f"prompt length {len(prompt)} must be < max_len-1 = "
                 f"{self.max_len - 1}")
         with self._lock:
-            req = _Request(self._next_id, list(prompt), max_new_tokens)
+            req = _Request(self._next_id, list(prompt), max_new_tokens,
+                           trace_ctx=tracing.current_ctx(),
+                           t_submit=tracing.now_us())
             self._next_id += 1
             self._waiting.append(req)
             self._cv.notify_all()
@@ -359,6 +388,23 @@ class ContinuousBatchingEngine:
                 self._slot_pos[req.slot] = 0
                 req.slot = -1
             self._finished[req.request_id] = req
+            self._record_request(req)
+
+    @staticmethod
+    def _record_request(req: _Request) -> None:
+        """The finished request's three stages as spans of its trace."""
+        tid, parent = req.trace_ctx or (None, None)
+        for name, t0, t1, args in (
+                ("engine.queue", req.t_submit, req.t_admit,
+                 {"waited_for_slot": req.waited_for_slot}),
+                ("engine.prefill", req.t_admit, req.t_first,
+                 {"bucket": req.bucket, "batch": req.batch,
+                  "prompt_len": len(req.prompt)}),
+                ("engine.decode", req.t_first, tracing.now_us(),
+                 {"tokens": len(req.generated) - 1})):
+            tracing.add_complete(name, "engine", t0, t1 - t0, trace_id=tid,
+                                 parent_id=parent,
+                                 request_id=req.request_id, **args)
 
     # ----------------------------------------------------------------- step
     @staticmethod
@@ -375,30 +421,47 @@ class ContinuousBatchingEngine:
             return self._step_inner()
 
     def _step_inner(self) -> int:
-        with self._lock:
-            admissions = self._collect_admissions()
-        for bucket, reqs in admissions:
-            self._dispatch_prefill(bucket, reqs)      # device enqueue only
-        with self._lock:
-            prev = self._pending
-            self._pending = self._dispatch_decode()   # device enqueue only
-        self._drain_pending_first()                   # device wait, no _lock
-        self._reap(prev)                              # device wait, no _lock
-        with self._lock:
-            return len(self._active) + len(self._waiting)
+        # the span opens under `_step_lock`: lock wait is not counted
+        with tracing.span("engine.step", "engine") as did:
+            with self._lock:
+                admissions = self._collect_admissions()
+                did["waiting"] = len(self._waiting)  # left without a slot
+            for bucket, reqs in admissions:
+                with tracing.span("engine.prefill_dispatch", "engine",
+                                  bucket=bucket, batch=len(reqs)):
+                    self._dispatch_prefill(bucket, reqs)  # device enqueue only
+            with self._lock:
+                prev = self._pending
+                self._pending = self._dispatch_decode()   # device enqueue only
+            did.update(
+                admitted=sum(len(reqs) for _, reqs in admissions),
+                prefill_batches=len(admissions),
+                active=len(self._pending[1]) if self._pending else 0,
+                attn_len=self._attn_len if self._pending else 0)
+            self._drain_pending_first()                   # device wait, no _lock
+            self._reap(prev)                              # device wait, no _lock
+            with self._lock:
+                return len(self._active) + len(self._waiting)
 
     def _collect_admissions(self):
         """Pop waiting requests into free slots, grouped by prompt bucket
         (one batched prefill per bucket). Caller holds `_lock`."""
         by_bucket: Dict[int, List[_Request]] = {}
+        now = tracing.now_us()
         while self._waiting and self._free:
             req = self._waiting.pop(0)
             slot = self._free.pop()
             req.slot = slot
+            req.t_admit = now
             self._active[slot] = req
             self._slot_pos[slot] = len(req.prompt)
             bucket = _bucket_len(len(req.prompt), self.max_len)
             by_bucket.setdefault(bucket, []).append(req)
+        for req in self._waiting:
+            req.waited_for_slot = True
+        for bucket, reqs in by_bucket.items():
+            for req in reqs:
+                req.bucket, req.batch = bucket, len(reqs)
         return sorted(by_bucket.items())
 
     def _dispatch_prefill(self, bucket: int, reqs: List[_Request]) -> None:
@@ -434,6 +497,7 @@ class ContinuousBatchingEngine:
         attn_len = _attn_bucket(
             max(self._slot_pos[s] for s in self._active), self.max_len)
         slot_map = dict(self._active)
+        self._attn_len = attn_len
         self.k, self.v, self.lengths, tokens_out = decode_step_fused(
             self.params, self.k, self.v, self.lengths, self.tokens,
             self.cfg, attn_len)
@@ -449,9 +513,12 @@ class ContinuousBatchingEngine:
             return
         batches, self._pending_first = self._pending_first, []
         for first_dev, entries in batches:
-            first = self._to_host(first_dev)  # device wait — no _lock held
+            with tracing.span("engine.wait_device", "engine", what="first"):
+                first = self._to_host(first_dev)  # device wait — no _lock held
+            now = tracing.now_us()
             with self._lock:
                 for row, req in entries:
+                    req.t_first = now
                     req.generated.append(int(first[row]))
                     self._maybe_finish(req)
                 self._cv.notify_all()
@@ -462,7 +529,8 @@ class ContinuousBatchingEngine:
         if prev is None:
             return
         tokens_dev, slot_map = prev
-        nxt = self._to_host(tokens_dev)  # device wait — no _lock held
+        with tracing.span("engine.wait_device", "engine", what="decode"):
+            nxt = self._to_host(tokens_dev)  # device wait — no _lock held
         with self._lock:
             for slot, req in slot_map.items():
                 if req.done:

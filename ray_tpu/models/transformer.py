@@ -344,6 +344,10 @@ def lm_head_weights(params: Dict[str, Any], cfg: ModelConfig) -> jax.Array:
     return head.astype(cfg.dtype)
 
 
+# named scopes are metadata only: `forward` (here) and `head_loss` (loss_fn)
+# name the phases of a train step in a device trace; the backward pass shows
+# as their transposes, `transpose(jvp(forward))`
+@jax.named_scope("forward")
 def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
                               cfg: ModelConfig,
                               positions: Optional[jax.Array] = None, mesh=None):
@@ -395,6 +399,7 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
         unroll=cfg.scan_unroll)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, aux_total
+
 
 
 def forward_with_aux(params: Dict[str, Any], tokens: jax.Array, cfg: ModelConfig,
@@ -484,11 +489,14 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
                 f"{targets.shape[-1]} (note {{'tokens'}} batches lose one "
                 f"position to the shift)")
         x, moe_aux = forward_features_with_aux(params, inputs, cfg, mesh=mesh)
-        loss = chunked_token_nll(x, lm_head_weights(params, cfg), targets,
-                                 mask, cfg.loss_chunk)
+        with jax.named_scope("head_loss"):
+            loss = chunked_token_nll(x, lm_head_weights(params, cfg),
+                                     targets, mask, cfg.loss_chunk)
     else:
-        logits, moe_aux = forward_with_aux(params, inputs, cfg, mesh=mesh)
-        loss = token_nll(logits, targets, mask)
+        x, moe_aux = forward_features_with_aux(params, inputs, cfg, mesh=mesh)
+        with jax.named_scope("head_loss"):
+            logits = (x @ lm_head_weights(params, cfg)).astype(jnp.float32)
+            loss = token_nll(logits, targets, mask)
     if cfg.n_experts > 0:
         loss = loss + cfg.moe_aux_weight * moe_aux
     return loss, {"loss": loss, "ntokens": targets.size, "moe_aux": moe_aux}

@@ -1215,16 +1215,14 @@ class DeploymentHandle:
                _deadline_ts: Optional[float] = None, **kwargs):
         _serve_metrics()["requests"].inc(tags={"deployment": self._name})
         # Route span: joins the caller's trace (e.g. the proxy's ingress
-        # span) or roots a fresh one. Its span id rides the request as
-        # trace_ctx so EVERY replica attempt — including failover retries —
-        # parents under this one routing decision.
-        route_ctx = None
-        t_route = 0.0
-        if tracing.enabled():
-            amb = tracing.current_ctx()
-            route_ctx = (amb[0] if amb else tracing.new_id(),
-                         tracing.new_id())
-            t_route = tracing.now_us()
+        # span) or, for a bare handle call / the gRPC ingress, roots the
+        # request's own — always, whatever `tracing_enabled` says: the
+        # trace_id IS the Serve request id. Its span id rides the request
+        # as trace_ctx so EVERY replica attempt — including failover
+        # retries — parents under this one routing decision.
+        amb = tracing.current_ctx()
+        route_ctx = (amb[0] if amb else tracing.new_id(), tracing.new_id())
+        t_route = tracing.now_us()
         deadline_ts, timeout_s = self._resolve_deadline(
             _timeout_s, _deadline_ts)
         with self._lock:
@@ -1237,7 +1235,8 @@ class DeploymentHandle:
                         f"deployment {self._name} has no replicas")
         self._ensure_refresher()
         if getattr(self, "_stream", False):
-            return self._submit_stream(args, kwargs, deadline_ts)
+            return self._submit_stream(args, kwargs, deadline_ts,
+                                       route_ctx, t_route)
 
         replica, key = self._pick_replica()
         if replica is None:
@@ -1264,17 +1263,16 @@ class DeploymentHandle:
                 _global_worker().fulfill_promise(req.promise, error=e)
                 req._deregister()
                 raise
-        if route_ctx is not None:
-            amb = tracing.current_ctx()
-            tracing.add_complete(
-                f"route::{self._name}", "serve_route",
-                t_route, tracing.now_us() - t_route,
-                trace_id=route_ctx[0], span_id=route_ctx[1],
-                parent_id=amb[1] if amb else "",
-                deployment=self._name)
+        tracing.add_complete(
+            f"route::{self._name}", "serve_route",
+            t_route, tracing.now_us() - t_route,
+            trace_id=route_ctx[0], span_id=route_ctx[1],
+            parent_id=amb[1] if amb else "",
+            deployment=self._name)
         return req.promise
 
-    def _submit_stream(self, args, kwargs, deadline_ts: float):
+    def _submit_stream(self, args, kwargs, deadline_ts: float,
+                       route_ctx, t_route: float):
         """Streaming call (reference handle.options(stream=True)): the
         replica method returns a generator; items arrive as a dynamic-
         return stream consumable while the replica still runs. Failover
@@ -1287,13 +1285,7 @@ class DeploymentHandle:
         budget = _serve_cfg().request_retry_budget if self._idempotent else 0
         tried: set = set()
         last_err: Optional[Exception] = None
-        route_ctx = None
-        t_route = 0.0
-        if tracing.enabled():
-            amb = tracing.current_ctx()
-            route_ctx = (amb[0] if amb else tracing.new_id(),
-                         tracing.new_id())
-            t_route = tracing.now_us()
+        amb = tracing.current_ctx()
         for attempt in range(budget + 1):
             replica, key = self._pick_replica(tried)
             if replica is None:
@@ -1317,14 +1309,12 @@ class DeploymentHandle:
                 raise
             _global_worker().add_done_callback(
                 gen._gen_ref, lambda k=key: self._dec(k))
-            if route_ctx is not None:
-                amb = tracing.current_ctx()
-                tracing.add_complete(
-                    f"route::{self._name}", "serve_route",
-                    t_route, tracing.now_us() - t_route,
-                    trace_id=route_ctx[0], span_id=route_ctx[1],
-                    parent_id=amb[1] if amb else "",
-                    deployment=self._name, stream=True)
+            tracing.add_complete(
+                f"route::{self._name}", "serve_route",
+                t_route, tracing.now_us() - t_route,
+                trace_id=route_ctx[0], span_id=route_ctx[1],
+                parent_id=amb[1] if amb else "",
+                deployment=self._name, stream=True)
             return gen
         raise last_err  # budget spent
 
@@ -1387,7 +1377,7 @@ class _RouterRequest:
             base_s=cfg.retry_backoff_base_ms / 1000.0,
             cap_s=cfg.retry_backoff_cap_ms / 1000.0)
         self.promise = _global_worker().create_promise()
-        self.trace_ctx = None  # (trace_id, route span id) when tracing is on
+        self.trace_ctx = None  # (trace_id, route span id), set by remote()
         self.current_ref = None  # latest replica attempt (cancellation target)
         with _inflight_lock:
             _inflight_requests[self.promise.id] = self

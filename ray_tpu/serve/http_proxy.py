@@ -33,6 +33,7 @@ from __future__ import annotations
 import asyncio
 import json
 import logging
+import re
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -68,6 +69,10 @@ def _error_status(e: BaseException) -> int:
 
 class _BadRequest(Exception):
     pass
+
+
+# an incoming X-Request-Id that may serve as the request's trace_id
+_REQUEST_ID = re.compile(r"[A-Za-z0-9._-]{1,64}")
 
 
 class AsyncHTTPProxy:
@@ -145,14 +150,15 @@ class AsyncHTTPProxy:
 
     @staticmethod
     def _response(status: int, body: bytes, content_type: str,
-                  close: bool) -> bytes:
+                  close: bool, request_id: str = "") -> bytes:
         reason = {200: "OK", 400: "Bad Request", 404: "Not Found",
                   500: "Internal Server Error", 503: "Service Unavailable",
                   504: "Gateway Timeout"}.get(status, "")
         return (f"HTTP/1.1 {status} {reason}\r\n"
                 f"Content-Type: {content_type}\r\n"
                 f"Content-Length: {len(body)}\r\n"
-                f"Connection: {'close' if close else 'keep-alive'}\r\n"
+                + (f"X-Request-Id: {request_id}\r\n" if request_id else "")
+                + f"Connection: {'close' if close else 'keep-alive'}\r\n"
                 "\r\n").encode("latin1") + body
 
     @staticmethod
@@ -263,6 +269,7 @@ class AsyncHTTPProxy:
         from ray_tpu.serve.edge_util import await_ref, fetch_value
 
         t0 = time.monotonic()
+        t_ing = tracing.now_us()  # the ingress span covers the parse too
         try:
             name, method, payload, stream, subpath, query, timeout_s = \
                 self._parse_target(req)
@@ -285,15 +292,16 @@ class AsyncHTTPProxy:
             await writer.drain()
             return
         self._inflight += 1
-        # Ingress span roots the request's trace. The ids are minted HERE
-        # (explicitly, not via thread-local start_trace): _dispatch is a
-        # coroutine, and thread-local context must never span an await — it
-        # is adopted only inside the synchronous submit windows below.
-        ing_ctx = None
-        t_ing = 0.0
-        if tracing.enabled():
-            ing_ctx = (tracing.new_id(), tracing.new_id())
-            t_ing = tracing.now_us()
+        # Ingress span roots the request's trace — for EVERY request,
+        # whatever `tracing_enabled` says: the trace_id is the Serve
+        # request id (a well-formed incoming X-Request-Id is adopted as
+        # it). The ids are minted HERE (explicitly, not via thread-local
+        # start_trace): _dispatch is a coroutine, and thread-local context
+        # must never span an await — it is adopted only inside the
+        # synchronous submit windows below.
+        rid = req["headers"].get("x-request-id", "")
+        ing_ctx = (rid if _REQUEST_ID.fullmatch(rid) else tracing.new_id(),
+                   tracing.new_id())
         # no requests.inc here: the handle's remote() counts it (this
         # process), exactly as the edge always has
         try:
@@ -340,7 +348,7 @@ class AsyncHTTPProxy:
                                             timeout_s + _EDGE_GRACE_S)
                     body, ctype = self._encode_result(out)
                     writer.write(self._response(200, body, ctype,
-                                                req["close"]))
+                                                req["close"], ing_ctx[0]))
                     await writer.drain()
                 except (ConnectionError, asyncio.CancelledError):
                     # client went away while the request was in flight:
@@ -353,7 +361,7 @@ class AsyncHTTPProxy:
         except _BadRequest as e:
             writer.write(self._response(
                 400, json.dumps({"error": str(e)}).encode(),
-                "application/json", req["close"]))
+                "application/json", req["close"], ing_ctx[0]))
             await writer.drain()
         except Exception as e:
             _serve_metrics()["errors"].inc(tags={"deployment": name})
@@ -363,18 +371,17 @@ class AsyncHTTPProxy:
             # deserialized-from-the-replica form)
             writer.write(self._response(
                 _error_status(e), _error_payload(e),
-                "application/json", req["close"]))
+                "application/json", req["close"], ing_ctx[0]))
             await writer.drain()
         finally:
             self._inflight -= 1
             _serve_metrics()["latency"].observe(
                 time.monotonic() - t0, tags={"deployment": name})
-            if ing_ctx is not None:
-                tracing.add_complete(
-                    f"ingress::{name}", "serve_ingress",
-                    t_ing, tracing.now_us() - t_ing,
-                    trace_id=ing_ctx[0], span_id=ing_ctx[1], parent_id="",
-                    deployment=name, method=req.get("method", ""))
+            tracing.add_complete(
+                f"ingress::{name}", "serve_ingress",
+                t_ing, tracing.now_us() - t_ing,
+                trace_id=ing_ctx[0], span_id=ing_ctx[1], parent_id="",
+                deployment=name, method=req.get("method", ""), call=method)
 
     async def _dispatch_stream(self, name: str, method: str, payload: Any,
                                req: dict, writer,
@@ -415,7 +422,8 @@ class AsyncHTTPProxy:
             "HTTP/1.1 200 OK\r\n"
             "Content-Type: application/x-ndjson\r\n"
             "Transfer-Encoding: chunked\r\n"
-            f"Connection: {'close' if req['close'] else 'keep-alive'}\r\n"
+            + (f"X-Request-Id: {trace_ctx[0]}\r\n" if trace_ctx else "")
+            + f"Connection: {'close' if req['close'] else 'keep-alive'}\r\n"
             "\r\n").encode("latin1"))
         await writer.drain()
 

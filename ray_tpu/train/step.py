@@ -134,6 +134,9 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh,
     optimizer (`ops.pallas.adamw.FusedAdamW`-style: `.apply(grads, state,
     params) -> (new_params, new_state)`) that updates params in one memory
     pass instead of returning deltas."""
+    from ray_tpu.util import tracing
+
+    tracing.record_compiles()  # `xla.compile` spans name this step
     optimizer = optimizer or default_optimizer()
     fused = hasattr(optimizer, "apply")
     sh = state_shardings(cfg, mesh, optimizer, rules)
@@ -142,16 +145,20 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh,
     def step(state: TrainState, batch: Dict[str, jax.Array]):
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, batch, cfg, mesh)
-        if fused:
-            new_params, new_opt = optimizer.apply(grads, state.opt_state,
-                                                  state.params)
-        else:
-            updates, new_opt = optimizer.update(grads, state.opt_state,
-                                                state.params)
-            new_params = optax.apply_updates(state.params, updates)
+        # (the phases before this one are named in models/transformer.py:
+        # `forward`, `head_loss`, and their transposes for the backward)
+        with jax.named_scope("optimizer"):
+            if fused:
+                new_params, new_opt = optimizer.apply(
+                    grads, state.opt_state, state.params)
+            else:
+                updates, new_opt = optimizer.update(grads, state.opt_state,
+                                                    state.params)
+                new_params = optax.apply_updates(state.params, updates)
+            grad_norm = optax.global_norm(grads)
         metrics = {
             "loss": loss,
-            "grad_norm": optax.global_norm(grads),
+            "grad_norm": grad_norm,
             "step": state.step,
         }
         return TrainState(new_params, new_opt, state.step + 1), metrics

@@ -29,18 +29,19 @@ def enable_otel_tracing(tracer_provider: Optional[Any] = None) -> None:
     tracer = provider.get_tracer("ray_tpu")
 
     def hook(event: dict) -> None:
-        # translate the chrome-trace X event (perf_counter us) into a
-        # real-time-anchored OTel span
-        import time
-
-        end_ns = time.time_ns()
-        start_ns = end_ns - int(event["dur"] * 1000)
+        # the chrome-trace X event's own times: `ts` is epoch-anchored
+        # microseconds (util/tracing.py), so the OTel span starts and ends
+        # when the framework span did, not when this hook happened to run
+        start_ns = int(event["ts"] * 1000)
         span = tracer.start_span(event["name"], start_time=start_ns)
         span.set_attribute("category", event.get("cat", ""))
+        for k in ("trace_id", "span_id", "parent_id"):
+            if k in event:
+                span.set_attribute(k, event[k])
         for k, v in (event.get("args") or {}).items():
             if isinstance(v, (str, int, float, bool)):
                 span.set_attribute(k, v)
-        span.end(end_time=end_ns)
+        span.end(end_time=start_ns + int(event["dur"] * 1000))
 
     _state["hook"] = hook
     tracing.add_span_hook(hook)

@@ -21,22 +21,36 @@ Two properties make multi-process merges meaningful:
   dropped count to the TaskEventBuffer so it rides the next flush and the
   GCS-side truncation accounting stays honest.
 
-Trace context (the distributed half, gated on `tracing_enabled`): a
-thread-local `(trace_id, parent_span_id)` pair. `span()` records both ids
-plus its own fresh span_id on the event and re-parents nested spans under
-itself; `ctx_scope()` adopts a context that crossed a process boundary
-(TaskSpec.trace_ctx), making driver submit -> raylet lease -> worker
-execute -> result delivery one causal tree under a single trace_id.
+THE RULE: a trace context propagates whenever one exists;
+`tracing_enabled` only decides whether a context-less task submit mints
+one. A Serve ingress always mints the request's context, so every request
+is one trace with default settings; a plain task submitted with no ambient
+context and the switch off pays one thread-local read and mints nothing.
+
+Trace context (the distributed half): a thread-local
+`(trace_id, parent_span_id)` pair. `span()` records both ids plus its own
+fresh span_id on the event and re-parents nested spans under itself;
+`ctx_scope()` adopts a context that crossed a process boundary
+(TaskSpec.trace_ctx), making ingress -> route -> submit -> raylet lease ->
+worker execute -> engine -> result delivery one causal tree under a single
+trace_id.
+
+One clock with the device trace: `span()` also enters
+`jax.profiler.TraceAnnotation(name)` once jax is loaded in the process (it
+never imports jax itself). Outside a profiler session that is a flag test;
+inside one the program's spans sit on the host plane of the same
+`.xplane.pb` as the device ops.
 """
 
 from __future__ import annotations
 
-import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager
+from random import getrandbits as _getrandbits
 from typing import Deque, List, Optional, Tuple
 
 _events: Deque[dict] = deque()
@@ -54,6 +68,12 @@ _total = 0            # events ever appended (drain cursors index into this)
 _span_hooks: List = []
 
 _tls = threading.local()
+# ring bound, read from the config once per process (0 = not read yet;
+# clear() forgets it, so a test that resets the config resets this too)
+_limit = 0
+# jax.profiler.TraceAnnotation once jax is loaded here, else None
+_annotation = None
+_compiles_recorded = False
 
 
 def add_span_hook(fn) -> None:
@@ -79,15 +99,18 @@ def now_us() -> float:
 
 # --------------------------------------------------------------- trace ctx
 def enabled() -> bool:
-    """Whether distributed trace-context propagation is on (default off:
-    local spans still record, but no ids are minted or shipped on specs)."""
+    """Whether a task submitted with NO ambient context mints a trace of
+    its own (default off). An existing context propagates either way."""
     from ray_tpu.core.config import get_config
 
     return get_config().tracing_enabled
 
 
 def new_id() -> str:
-    return os.urandom(8).hex()
+    """16 hex digits. From the process's PRNG (seeded from the OS, reseeded
+    in a forked child), not `os.urandom`: every Serve request mints a
+    handful, and a system call each costs microseconds in a sandbox."""
+    return "%016x" % _getrandbits(64)
 
 
 def current_ctx() -> Optional[Tuple[str, str]]:
@@ -125,14 +148,15 @@ def ctx_scope(ctx: Optional[Tuple[str, str]]):
 
 def _append(event: dict) -> None:
     """Caller must NOT hold _lock. Ring-bounded append + hook fanout."""
-    global _dropped, _total
-    from ray_tpu.core.config import get_config
+    global _dropped, _total, _limit
+    if not _limit:
+        from ray_tpu.core.config import get_config
 
-    limit = max(1, get_config().tracing_max_buffer_size)
+        _limit = max(1, get_config().tracing_max_buffer_size)
     with _lock:
         _events.append(event)
         _total += 1
-        while len(_events) > limit:
+        while len(_events) > _limit:
             _events.popleft()
             _dropped += 1
         # hooks observe completed SPANS only (the OTel bridge reads "dur")
@@ -144,8 +168,24 @@ def _append(event: dict) -> None:
             pass
 
 
+def _trace_annotation():
+    """jax.profiler.TraceAnnotation once jax is (fully) loaded, else None."""
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        _annotation = getattr(getattr(sys.modules["jax"], "profiler", None),
+                              "TraceAnnotation", None)
+    return _annotation
+
+
+def open_span_name() -> Optional[str]:
+    """Name of the innermost `span()` open on this thread, or None."""
+    return getattr(_tls, "open", None)
+
+
 @contextmanager
 def span(name: str, category: str = "task", **args):
+    """Record the block as one complete span. Yields the span's `args`
+    dict: what is only known at the end of the block can be set on it."""
     start = _now_us()
     ctx = getattr(_tls, "ctx", None)
     sid = prev = None
@@ -153,10 +193,19 @@ def span(name: str, category: str = "task", **args):
         sid = new_id()
         prev = ctx
         _tls.ctx = (ctx[0], sid)  # nested spans parent under this one
+    outer = getattr(_tls, "open", None)
+    _tls.open = name
+    ann = _annotation or _trace_annotation()
+    if ann is not None:
+        ann = ann(name)
+        ann.__enter__()
     try:
-        yield
+        yield args
     finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
         end = _now_us()
+        _tls.open = outer
         if sid is not None:
             _tls.ctx = prev
         event = {
@@ -192,12 +241,30 @@ def add_complete(name: str, category: str, start_us: float, dur_us: float,
     _append(event)
 
 
-def instant(name: str, category: str = "event", **args) -> None:
-    _append({
-        "name": name, "cat": category, "ph": "i", "ts": _now_us(),
-        "pid": os.getpid(), "tid": threading.get_ident() % 100000,
-        "s": "p", "args": args,
-    })
+def record_compiles() -> None:
+    """Install, once per process, the `jax.monitoring` listener that turns
+    every trace / lower / backend-compile event into an `xla.compile` span
+    named by the jitted function (`fun_name`; else the innermost open
+    program span), so `ray_tpu timeline` says which step recompiled.
+    Called by code that imports jax anyway (the engine, `make_train_step`)."""
+    global _compiles_recorded
+    if _compiles_recorded:
+        return
+    _compiles_recorded = True
+    from jax import monitoring
+
+    def on_duration(event: str, secs: float, **kw) -> None:
+        if not event.startswith("/jax/core/compile/"):
+            return
+        ctx = getattr(_tls, "ctx", None) or (None, None)
+        dur = secs * 1e6
+        add_complete(
+            "xla.compile", "compile", _now_us() - dur, dur,
+            trace_id=ctx[0], parent_id=ctx[1],
+            event=event.rsplit("/", 1)[-1],
+            fun_name=str(kw.get("fun_name") or open_span_name() or ""))
+
+    monitoring.register_event_duration_secs_listener(on_duration)
 
 
 def get_events() -> List[dict]:
@@ -233,15 +300,10 @@ def recent_events(window_s: float) -> List[dict]:
                 if e.get("ts", 0) + e.get("dur", 0) >= floor]
 
 
-def dump(path: str, extra_events: Optional[List[dict]] = None) -> None:
-    events = get_events() + list(extra_events or [])
-    with open(path, "w") as f:
-        json.dump({"traceEvents": events}, f)
-
-
 def clear() -> None:
-    global _dropped, _total
+    global _dropped, _total, _limit
     with _lock:
         _events.clear()
         _dropped = 0
         _total = 0
+        _limit = 0

@@ -1,0 +1,266 @@
+"""The engine's own spans (models/serving.py -> util/tracing.py): request
+stages, step spans with their device waits, the request's trace carried
+from the HTTP ingress into the engine with default settings, compile spans
+by program name, and the named scopes of the train and serve programs."""
+
+import json
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import ray_tpu
+from ray_tpu.models import ModelConfig, init_params
+from ray_tpu.models.serving import (ContinuousBatchingEngine, LLMDeployment,
+                                    decode_step_fused, prefill_slots)
+from ray_tpu.util import timeline, tracing
+
+CFG = ModelConfig.tiny()
+PARAMS = init_params(jax.random.PRNGKey(0), CFG)
+STAGES = ("engine.queue", "engine.prefill", "engine.decode")
+
+
+@pytest.fixture(autouse=True)
+def _clean_ring():
+    tracing.clear()
+    tracing.set_ctx(None)
+    yield
+    tracing.clear()
+    tracing.set_ctx(None)
+
+
+def _engine_spans():
+    return [e for e in tracing.get_events() if e.get("cat") == "engine"]
+
+
+def _stages_by_request(spans):
+    out = {}
+    for e in spans:
+        if e["name"] in STAGES:
+            out.setdefault(e["args"]["request_id"], {})[e["name"]] = e
+    return out
+
+
+def test_every_request_has_three_contiguous_stages():
+    eng = ContinuousBatchingEngine(PARAMS, CFG, num_slots=4, max_len=64)
+    asked = {eng.submit([1 + i, 2, 3], max_new_tokens=n): n
+             for i, n in enumerate((5, 1, 8))}
+    eng.run_until_done()
+    by_req = _stages_by_request(_engine_spans())
+    assert set(by_req) == set(asked)
+    for rid, n in asked.items():
+        q, p, d = (by_req[rid][s] for s in STAGES)
+        assert q["dur"] >= 0 and p["dur"] > 0 and d["dur"] >= 0
+        # in order and contiguous: each stage starts where the last ended
+        assert q["ts"] + q["dur"] == pytest.approx(p["ts"], abs=1e-3)
+        assert p["ts"] + p["dur"] == pytest.approx(d["ts"], abs=1e-3)
+        assert d["args"]["tokens"] == n - 1   # the first came from prefill
+        assert p["args"]["prompt_len"] == 3 and p["args"]["bucket"] == 8
+        assert p["args"]["batch"] == 3        # one prefill admitted all three
+        assert q["args"]["waited_for_slot"] is False
+        # standalone use: no context on the submitting thread, no ids
+        assert "trace_id" not in q
+
+
+def test_one_slot_makes_two_of_three_wait_for_it():
+    eng = ContinuousBatchingEngine(PARAMS, CFG, num_slots=1, max_len=64)
+    rids = [eng.submit([7, i + 1], max_new_tokens=3) for i in range(3)]
+    eng.run_until_done()
+    by_req = _stages_by_request(_engine_spans())
+    waited = [by_req[r]["engine.queue"]["args"]["waited_for_slot"]
+              for r in rids]
+    assert waited == [False, True, True]
+    # who waited for a slot queued for at least the first request's service
+    first_done = (by_req[rids[0]]["engine.decode"]["ts"]
+                  + by_req[rids[0]]["engine.decode"]["dur"])
+    for r in rids[1:]:
+        q = by_req[r]["engine.queue"]
+        assert q["ts"] + q["dur"] >= first_done - 1e-3
+    steps = [e for e in _engine_spans() if e["name"] == "engine.step"]
+    assert max(s["args"]["waiting"] for s in steps) == 2
+
+
+def test_step_spans_count_the_slots_they_dispatched():
+    eng = ContinuousBatchingEngine(PARAMS, CFG, num_slots=4, max_len=64)
+    asked = [6, 3, 9, 2, 4]
+    for i, n in enumerate(asked):
+        eng.submit([i + 1, 5], max_new_tokens=n)
+    eng.run_until_done()
+    spans = _engine_spans()
+    steps = [e for e in spans if e["name"] == "engine.step"]
+    decode_tokens = sum(e["args"]["tokens"] for e in spans
+                        if e["name"] == "engine.decode")
+    assert decode_tokens == sum(asked) - len(asked)
+    # `active` is the slots in each dispatched decode. With one step of
+    # lookahead a retiring request is dispatched once more before the host
+    # sees its last token: one junk slot-step per request on top of the
+    # decode tokens produced (module docstring of models/serving.py).
+    assert sum(s["args"]["active"] for s in steps) == \
+        decode_tokens + len(asked)
+    assert sum(s["args"]["admitted"] for s in steps) == len(asked)
+    assert sum(s["args"]["prefill_batches"] for s in steps) == len(
+        [e for e in spans if e["name"] == "engine.prefill_dispatch"])
+    assert all(s["args"]["attn_len"] == 64 for s in steps
+               if s["args"]["active"])
+    # every device wait lies inside a step: host time = step - its waits
+    waits = [e for e in spans if e["name"] == "engine.wait_device"]
+    assert waits and {w["args"]["what"] for w in waits} == {"first", "decode"}
+    for w in waits:
+        assert any(s["ts"] <= w["ts"] and
+                   w["ts"] + w["dur"] <= s["ts"] + s["dur"] + 1e-3
+                   for s in steps if s["tid"] == w["tid"])
+
+
+def test_compiles_are_spans_named_by_program():
+    eng = ContinuousBatchingEngine(PARAMS, CFG, num_slots=3, max_len=32)
+    eng.generate([1, 2, 3], max_new_tokens=2)   # 3 slots x 32: new shapes
+    compiles = [e for e in tracing.get_events() if e["name"] == "xla.compile"]
+    assert all(e["cat"] == "compile" and e["dur"] >= 0 for e in compiles)
+    by_fun = {}
+    for e in compiles:
+        by_fun.setdefault(e["args"]["fun_name"], set()).add(e["args"]["event"])
+    step = set().union(*(v for k, v in by_fun.items()
+                         if "decode_step_fused" in k))
+    assert {"jaxpr_trace_duration", "jaxpr_to_mlir_module_duration",
+            "backend_compile_duration"} <= step, by_fun
+    assert any("prefill_slots" in k for k in by_fun), sorted(by_fun)
+
+
+def test_span_sits_on_the_profilers_host_plane(tmp_path):
+    """One clock with the device trace: inside a profiler session the
+    program's spans are TraceAnnotations of the same `.xplane.pb`."""
+    import glob
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing.span("engine.test_annotation", "engine"):
+            jnp.ones((8, 8)).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    names = {e.name for p in data.planes if p.name.startswith("/host:")
+             for line in p.lines for e in line.events}
+    assert "engine.test_annotation" in names
+
+
+def _scopes(lowered):
+    """Path components of every op name in a lowering's debug locations."""
+    names = re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True))
+    return {part for n in names for part in n.split("/")}
+
+
+def test_named_scopes_reach_the_lowered_programs():
+    from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+    from ray_tpu.train.step import default_optimizer, make_train_step
+
+    mesh = make_mesh(MeshConfig(), jax.devices()[:1])
+    step_fn, init_fn, _ = make_train_step(CFG, mesh, default_optimizer(1e-3))
+    state = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    train = _scopes(step_fn.lower(state, {"inputs": toks, "targets": toks}))
+    # under value_and_grad the forward pass is the scopes' jvp and the
+    # backward pass their transpose
+    assert {"jvp(forward)", "jvp(head_loss)", "transpose(jvp(forward))",
+            "transpose(jvp(head_loss))", "optimizer"} <= train, sorted(train)
+
+    L, kvh, hd = CFG.n_layers, CFG.n_kv_heads, CFG.head_dim
+    cache = jax.ShapeDtypeStruct((L, 4, kvh, 64, hd), CFG.dtype)
+    i4 = jax.ShapeDtypeStruct((4,), jnp.int32)
+    serve_scopes = {"cache_write", "attention", "mlp", "head"}
+    assert serve_scopes <= _scopes(decode_step_fused.lower(
+        PARAMS, cache, cache, i4, i4, CFG, 64))
+    assert serve_scopes <= _scopes(prefill_slots.lower(
+        PARAMS, jax.ShapeDtypeStruct((2, 16), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.int32), CFG, 64))
+
+
+def test_one_trace_from_http_ingress_to_last_token(ray_start_regular):
+    """Default config, no switch: one trace_id links ingress:: -> route::
+    -> submit:: -> task::handle_request -> engine.queue/prefill/decode,
+    parent links resolve, and an incoming X-Request-Id IS the trace id. The
+    stream method's body runs lazily on the worker thread: that `submit()`
+    still sees the request's context is what this test is for."""
+    import http.client
+
+    from ray_tpu import serve
+
+    assert not tracing.enabled()
+    D = serve.deployment(LLMDeployment(PARAMS, CFG, num_slots=2, max_len=64))
+    try:
+        serve.run(D.bind())
+        _, port = serve.start_http_proxy()
+        rids = ["req-0001.a_b", None]
+        got_ids = []
+        for rid in rids:
+            conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+            headers = {"Content-Type": "application/json"}
+            if rid:
+                headers["X-Request-Id"] = rid
+            conn.request("POST", "/LLMDeployment/stream?stream=1",
+                         body=json.dumps({"prompt": [5, 17, 400, 3],
+                                          "max_new_tokens": 5}),
+                         headers=headers)
+            resp = conn.getresponse()
+            assert resp.status == 200
+            got_ids.append(resp.getheader("X-Request-Id"))
+            assert len([ln for ln in resp.read().splitlines() if ln]) == 5
+            conn.close()
+        assert got_ids[0] == rids[0] and re.fullmatch(r"[0-9a-f]{16}",
+                                                      got_ids[1])
+
+        want = {"ingress::LLMDeployment", "route::LLMDeployment",
+                "submit::handle_request", "task::handle_request",
+                "result::handle_request", *STAGES}
+        traces = {}
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline:
+            traces = timeline.group_by_trace(
+                e for e in ray_tpu.timeline() if e.get("trace_id") in got_ids)
+            if all(want <= {s["name"] for s in traces.get(t, [])}
+                   for t in got_ids):
+                break
+            time.sleep(0.3)
+        for t in got_ids:
+            spans = {s["name"]: s for s in traces[t]}
+            assert want <= set(spans), (t, sorted(spans))
+            chain = timeline.validate_chain(traces[t])
+            assert chain["complete"] and chain["processes"] >= 2, chain
+            assert spans["ingress::LLMDeployment"]["parent_id"] == ""
+            assert (spans["route::LLMDeployment"]["parent_id"]
+                    == spans["ingress::LLMDeployment"]["span_id"])
+            assert (spans["submit::handle_request"]["parent_id"]
+                    == spans["route::LLMDeployment"]["span_id"])
+            # the engine's stages hang off the replica task's context
+            assert (spans["engine.queue"]["parent_id"]
+                    == spans["submit::handle_request"]["span_id"])
+            order = [spans[n]["ts"] for n in (
+                "ingress::LLMDeployment", "route::LLMDeployment",
+                "task::handle_request", *STAGES)]
+            assert order == sorted(order), order
+            assert spans["engine.decode"]["args"]["tokens"] == 4
+        assert timeline.validate_chains(
+            [s for t in got_ids for s in traces[t]], got_ids)
+    finally:
+        serve.shutdown()
+
+
+def test_bare_handle_call_roots_its_own_trace(ray_start_regular):
+    from ray_tpu import serve
+
+    @serve.deployment
+    def echo_ctx(_payload):
+        return tracing.current_ctx()
+
+    try:
+        handle = serve.run(echo_ctx.bind())
+        ctx = ray_tpu.get(handle.remote({}), timeout=60)
+        assert ctx is not None
+        route = next(e for e in tracing.get_events()
+                     if e["name"] == "route::echo_ctx"
+                     and e["trace_id"] == ctx[0])
+        assert route["parent_id"] == ""   # no ingress: the route span roots it
+    finally:
+        serve.shutdown()

@@ -5,6 +5,7 @@ context adoption across process boundaries, and the end-to-end
 submit -> lease -> dispatch -> execute -> result chain for a real task."""
 
 import json
+import os
 import time
 
 import pytest
@@ -286,6 +287,157 @@ def test_tracing_default_off_mints_nothing(ray_start_regular):
 
     assert ray_tpu.get(untraced_noop.remote(), timeout=60) == 1
     assert all("trace_id" not in e for e in tracing.get_events())
+
+
+def test_context_less_submit_stamps_nothing(ray_start_regular, monkeypatch):
+    """The hot path PR 19 protected: switch off, no ambient context -> the
+    spec carries no trace_ctx and no id is minted (one thread-local read)."""
+    from ray_tpu.core.api import _global_worker
+
+    minted = []
+    real = tracing.new_id
+    monkeypatch.setattr(tracing, "new_id",
+                        lambda: minted.append(1) or real())
+
+    class _Spec:
+        trace_ctx = None
+
+    spec = _Spec()
+    assert not tracing.enabled() and tracing.current_ctx() is None
+    assert _global_worker()._stamp_trace_ctx(spec) == 0.0
+    assert spec.trace_ctx is None and not minted
+
+    @ray_tpu.remote
+    def noop():
+        return 1
+
+    assert ray_tpu.get(noop.remote(), timeout=60) == 1
+    assert not minted
+    # ...and with an ambient context the same call stamps it
+    with tracing.ctx_scope(("t" * 16, "p" * 16)):
+        assert _global_worker()._stamp_trace_ctx(spec) > 0.0
+    assert spec.trace_ctx[0] == "t" * 16 and len(minted) == 1
+
+
+def test_ambient_context_propagates_with_switch_off(ray_start_regular):
+    """THE RULE: a context propagates whenever one exists. With default
+    settings a task submitted under an ambient context carries it; its
+    worker-side `task::` span joins the trace, parented under the submit
+    span, and a task IT submits stays in the trace too."""
+    assert not tracing.enabled()
+
+    @ray_tpu.remote
+    def inner_ctx():
+        from ray_tpu.util import tracing as t
+
+        return t.current_ctx()
+
+    @ray_tpu.remote
+    def outer_ctx():
+        from ray_tpu.util import tracing as t
+
+        return t.current_ctx(), ray_tpu.get(inner_ctx.remote(), timeout=30)
+
+    trace_id = tracing.new_id()
+    with tracing.ctx_scope((trace_id, "")):
+        ref = outer_ctx.remote()
+    got_outer, got_inner = ray_tpu.get(ref, timeout=60)
+    assert got_outer[0] == trace_id and got_inner[0] == trace_id
+
+    spans = []
+    deadline = time.monotonic() + 20
+    while time.monotonic() < deadline:
+        spans = [e for e in ray_tpu.timeline()
+                 if e.get("trace_id") == trace_id]
+        if sum(e["name"].startswith("task::") for e in spans) >= 2:
+            break
+        time.sleep(0.3)
+    by_name = {e["name"]: e for e in spans}
+    submit, task = by_name["submit::outer_ctx"], by_name["task::outer_ctx"]
+    assert submit["parent_id"] == "" and task["parent_id"] == submit["span_id"]
+    assert task["pid"] != submit["pid"]  # it crossed a process boundary
+    # the nested submission parents under the outer execution span
+    assert by_name["submit::inner_ctx"]["parent_id"] == task["span_id"]
+    assert timeline.validate_chain(spans)["complete"], spans
+
+
+def test_timeline_survives_shutdown_of_a_local_cluster():
+    """The head's GCS span ring dies with the cluster: `shutdown()` of the
+    process that hosts it keeps `timeline()`'s last result, and
+    `timeline()` with no cluster returns that session, worker spans
+    included, with the GCS's account of what was lost (nothing)."""
+    ray_tpu.init(num_cpus=2)
+    try:
+        @ray_tpu.remote
+        def kept_after_shutdown():
+            return 1
+
+        assert ray_tpu.get(kept_after_shutdown.remote(), timeout=60) == 1
+        deadline = time.monotonic() + 20
+        while time.monotonic() < deadline:  # the worker ships on a timer
+            if any(e["name"] == "task::kept_after_shutdown"
+                   for e in ray_tpu.timeline()):
+                break
+            time.sleep(0.2)
+    finally:
+        ray_tpu.shutdown()
+    assert not ray_tpu.is_initialized()
+    kept = [e for e in ray_tpu.timeline()
+            if e["name"] == "task::kept_after_shutdown"]
+    assert len(kept) == 1 and kept[0]["pid"] != os.getpid()
+    info = ray_tpu.timeline_info()
+    assert info["spans_dropped"] == 0 and info["spans_evicted"] == 0
+    assert info["spans_buffered"] >= 1
+
+
+def test_span_yields_its_args_and_names_the_open_span():
+    assert tracing.open_span_name() is None
+    with tracing.span("outer", "test", a=1) as args:
+        assert tracing.open_span_name() == "outer"
+        with tracing.span("inner", "test"):
+            assert tracing.open_span_name() == "inner"
+        assert tracing.open_span_name() == "outer"
+        args["b"] = 2  # known only at the end of the block
+    assert tracing.open_span_name() is None
+    outer = next(e for e in tracing.get_events() if e["name"] == "outer")
+    assert outer["args"] == {"a": 1, "b": 2}
+
+
+def test_otel_hook_keeps_the_spans_own_times_and_ids():
+    """The bridge stamps an OTel span with the framework span's own start
+    and end (not "now") and carries the trace ids as attributes."""
+    from ray_tpu.util.otel import disable_otel_tracing, enable_otel_tracing
+
+    done = []
+
+    class _Span:
+        def __init__(self, name, start_time):
+            self.name, self.start_time, self.attributes = name, start_time, {}
+
+        def set_attribute(self, k, v):
+            self.attributes[k] = v
+
+        def end(self, end_time=None):
+            self.end_time = end_time
+            done.append(self)
+
+    class _Provider:
+        def get_tracer(self, name):
+            class _Tracer:
+                def start_span(self, name, start_time=None):
+                    return _Span(name, start_time)
+            return _Tracer()
+
+    enable_otel_tracing(_Provider())
+    try:
+        tracing.add_complete("old", "test", 1_000_000.0, 2_500.0,
+                             trace_id="t1", span_id="s1", parent_id="p1")
+    finally:
+        disable_otel_tracing()
+    (s,) = [d for d in done if d.name == "old"]
+    assert s.start_time == 1_000_000_000 and s.end_time == 1_002_500_000
+    assert (s.attributes["trace_id"], s.attributes["span_id"],
+            s.attributes["parent_id"]) == ("t1", "s1", "p1")
 
 
 def test_rpc_latency_histogram_exported(ray_start_regular):
