@@ -11,10 +11,16 @@ of dynamic batch).
 Engine = pure-JAX step functions + a host-side slot manager. The decode
 loop is built to run at device speed:
 
-  * `decode_step_fused` donates the K/V/length buffers (the cache update
-    is in-place — no per-step reallocation of [L, slots, kvh, max_len, hd])
-    and fuses greedy sampling on-device, so only a [slots] int32 token
-    array ever crosses to the host;
+  * `decode_step_fused` donates the K/V/length buffers (no per-step
+    reallocation of [L, slots, kvh, max_len, hd]) and fuses greedy sampling
+    on-device, so only a [slots] int32 token array ever crosses to the
+    host. Donation aliases the buffers; it does not say what the step does
+    in between. A row write through a ONE-row window made XLA:TPU relayout
+    the whole cache before and after it (`copy.58/61/64/65`, two per buffer
+    per step, 12.9 GB of HBM traffic a step at 24 x 32 x 8 x 1024 x 128
+    bf16), so `_write_rows` writes tile-aligned blocks of rows, which keep
+    the default layout. `tests/test_chip_compile.py` asks the chip's
+    compiler; buffer pointers on the CPU alias either way;
   * attention reads a power-of-2 *bucket* of the cache (compiled once per
     bucket) instead of all max_len rows, so short sequences pay for the
     cache they use;
@@ -212,22 +218,57 @@ def decode_slots(params: Dict, k_all: jax.Array, v_all: jax.Array,
     return logits, k_new, v_new
 
 
+def _write_rows(cache: jax.Array, rows: jax.Array,
+                lengths: jax.Array) -> jax.Array:
+    """The decode step's cache write: `rows[:, b]` goes to row `lengths[b]`
+    of slot b in every layer and kv head. cache [L, B, kvh, max_len, hd],
+    rows [L, B, kvh, hd], lengths [B] -> cache.
+
+    Written as a read-modify-write of the tile-aligned block of R rows that
+    holds the position, one slot at a time, R being the rows one HBM tile
+    of the cache's dtype packs (8 / 16 / 32 for 4- / 2- / 1-byte elements).
+    A window of ONE row makes XLA:TPU's layout assignment put the window's
+    dimensions minor-most, and bridge that to the default layout of the
+    donated parameter and the aliased output with a copy of the whole cache
+    before and after the write (`copy.58/61/64/65` up to PR 26); a
+    whole-tile window keeps the default layout and the update stays in place.
+
+    A slot whose position is at or past max_len (an idle slot keeps
+    counting) writes nothing."""
+    L, B, kvh, max_len, hd = cache.shape
+    R = min(32 // cache.dtype.itemsize, max_len)
+    row_ids = jnp.arange(R)[:, None]
+
+    def write_slot(b, cache):
+        pos = lengths[b]
+        # clamped by hand: XLA would clamp a block that overhangs max_len
+        # silently, and the row would land one block off
+        start = jnp.minimum(pos // R * R, max_len - R)
+        at = (0, b, 0, start, 0)
+        block = jax.lax.dynamic_slice(cache, at, (L, 1, kvh, R, hd))
+        new = jax.lax.dynamic_slice(rows, (0, b, 0, 0), (L, 1, kvh, hd))
+        block = jnp.where(row_ids == pos - start, new[:, :, :, None], block)
+        return jax.lax.dynamic_update_slice(cache, block, at)
+
+    return jax.lax.fori_loop(0, B, write_slot, cache)
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
                    donate_argnums=(1, 2, 3))
 def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
                       lengths: jax.Array, tokens: jax.Array,
                       cfg: ModelConfig, attn_len: int):
     """The hot decode step: one token for every slot, greedy sampling fused
-    on device, K/V/length buffers DONATED so the cache row-write is a true
-    in-place scatter (no [L, B, kvh, max_len, hd] reallocation per step).
+    on device, K/V/length buffers DONATED and updated in place (no
+    [L, B, kvh, max_len, hd] reallocation, and no copy of one either:
+    `_write_rows` says what that takes on the TPU).
 
-    Structure matters for the donation to be real: the caches enter the
-    layer scan as READ-ONLY xs — a scan that carries the cache through its
-    ys gets double-buffered by XLA even when the final output aliases the
-    input. Attention therefore splits into (cache window) + (current
+    The caches are READ-ONLY inside the layer scan — a scan that carries
+    the cache through its ys gets double-buffered by XLA even when the
+    final output aliases the input. Each layer reads its attention window
+    out of the whole cache, attention splits into (cache window) + (current
     token's own K/V, which is not written yet — STRICT mask `< lengths`),
-    and the per-layer K/V rows are written afterwards in one donated
-    scatter outside the scan.
+    and the per-layer K/V rows are written afterwards, outside the scan.
 
     `attn_len` is the static attention window (a power-of-2 bucket >= every
     active position): XLA compiles one executable per bucket and short
@@ -245,20 +286,26 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
     x = _embed_lookup(params["embed"], tokens[:, None], cfg.dtype)  # [B,1,d]
     mask = jnp.arange(attn_len)[None, :] < lengths[:, None]  # [B, attn_len]
 
+    win = (1, B, cfg.n_kv_heads, attn_len, hd)
+
     # named scopes are metadata only: they name the step's phases in a
     # device trace (`attention`, `mlp`, `cache_write`, `head`)
     def body(x, inputs):
-        lp, k_cache, v_cache = inputs  # read-only [B, kvh, max_len, hd]
+        lp, layer = inputs
         lp = _deq_tree(lp, cfg.dtype)
+        # the layer's attention window, read straight out of the whole
+        # (loop-invariant) cache: one dynamic_slice fuses into the attention
+        # fusions; a scan over the caches followed by `[:, :, :attn_len]`
+        # made XLA:TPU copy the layer's whole [B, kvh, max_len, hd] first
+        k_win = jax.lax.dynamic_slice(k_all, (layer, 0, 0, 0, 0), win)[0]
+        v_win = jax.lax.dynamic_slice(v_all, (layer, 0, 0, 0, 0), win)[0]
         with jax.named_scope("attention"):
             h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
             q, k, v = _project_qkv(cfg, lp, h, cos, sin)
             q = q.transpose(0, 2, 1, 3)  # [B, h, 1, hd]
             k_cur = k.transpose(0, 2, 1, 3)[:, :, 0].astype(cfg.dtype)  # [B,kvh,hd]
             v_cur = v.transpose(0, 2, 1, 3)[:, :, 0].astype(cfg.dtype)
-            attn = _gqa_decode_attention(
-                q, k_cache[:, :, :attn_len], v_cache[:, :, :attn_len],
-                k_cur, v_cur, mask)
+            attn = _gqa_decode_attention(q, k_win, v_win, k_cur, v_cur, mask)
             attn = attn.reshape(B, 1, cfg.n_heads * hd)
             x = x + (attn @ lp["wo"]).astype(x.dtype)
         with jax.named_scope("mlp"):
@@ -266,18 +313,11 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
             x = x + _mlp(cfg, lp, h2).astype(x.dtype)
         return x, (k_cur, v_cur)
 
-    x, (k_cur, v_cur) = jax.lax.scan(body, x, (params["layers"], k_all, v_all))
-    # k_cur/v_cur [L, B, kvh, hd] -> one donated row-scatter per cache
-    def write_row(cache, new, pos):
-        # cache [max_len, hd] <- new [1, hd] at row pos
-        return jax.lax.dynamic_update_slice(cache, new, (pos, 0))
-
-    wr = jax.vmap(jax.vmap(jax.vmap(write_row, in_axes=(0, 0, None)),  # kvh
-                           in_axes=(0, 0, 0)),                         # B
-                  in_axes=(0, 0, None))                                # L
+    x, (k_cur, v_cur) = jax.lax.scan(
+        body, x, (params["layers"], jnp.arange(cfg.n_layers)))
     with jax.named_scope("cache_write"):
-        k_all = wr(k_all, k_cur[:, :, :, None], lengths)
-        v_all = wr(v_all, v_cur[:, :, :, None], lengths)
+        k_all = _write_rows(k_all, k_cur, lengths)
+        v_all = _write_rows(v_all, v_cur, lengths)
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = (x[:, 0] @ lm_head_weights(params, cfg)).astype(jnp.float32)

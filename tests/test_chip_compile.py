@@ -11,7 +11,9 @@ The code's device branches ask `ops.pallas._util.on_tpu()`; the tests steer
 that one function instead of adding an option to the program.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +77,41 @@ def _kernels(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _param_shapes(chip, cfg):
+    """The model's parameter tree as shapes on the described chip."""
+    from ray_tpu.models.transformer import init_params
+
+    params = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
+    return jax.tree_util.tree_map(lambda s: chip(s.shape, s.dtype), params)
+
+
+# `%name = dtype[dims]{layout} opcode(`: an instruction with one array result
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([a-z][\w\-]*)\(", re.M)
+
+
+def _whole_cache_relayouts(compiled, cache) -> list:
+    """Names of the compiled program's `copy` and `transpose` instructions
+    whose result has as many elements as one `cache`: each reads and writes
+    the whole cache once, whatever the step then does in place."""
+    return [name for name, dims, opcode in _INSTRUCTION.findall(compiled.as_text())
+            if opcode in ("copy", "transpose") and dims
+            and math.prod(map(int, dims.split(","))) == cache.size]
+
+
+def _assert_cache_stays_put(compiled, cache, temp_limit=256 * 2**20):
+    assert _whole_cache_relayouts(compiled, cache) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
+
+
+def _one_layer_bytes(cache) -> int:
+    """[B, kvh, max_len, hd] of one cache: a decode step that holds a
+    temporary this large has copied a layer's cache to read a window of it
+    (as the buckets under max_len did up to PR 27: 24 such copies per
+    buffer per step are one more pass over the whole cache)."""
+    return cache.size * cache.dtype.itemsize // cache.shape[0]
+
+
 def test_flash_attention_fwd_bwd_compiles(chip):
     from ray_tpu.ops.attention import attention
 
@@ -129,11 +166,9 @@ def test_adamw_leaf_update_compiles(chip):
 @pytest.mark.parametrize("attn_len", [64, 512])
 def test_decode_step_fused_compiles_at_b1_8_slots(chip, attn_len):
     from ray_tpu.models.serving import decode_step_fused
-    from ray_tpu.models.transformer import init_params
 
     slots, max_len = 8, 512
-    params = jax.eval_shape(lambda k: init_params(k, B1), jax.random.PRNGKey(0))
-    params = jax.tree_util.tree_map(lambda s: chip(s.shape, s.dtype), params)
+    params = _param_shapes(chip, B1)
     kv = chip((B1.n_layers, slots, B1.n_kv_heads, max_len, B1.head_dim))
     ints = chip((slots,), jnp.int32)
     c = decode_step_fused.lower(params, kv, kv, ints, ints, B1, attn_len).compile()
@@ -141,14 +176,64 @@ def test_decode_step_fused_compiles_at_b1_8_slots(chip, attn_len):
     # weights + both caches in, caches updated in place (donated)
     assert m.argument_size_in_bytes < 3 * 2**30
     assert m.alias_size_in_bytes >= 2 * (kv.size * 2)
+    _assert_cache_stays_put(c, kv, _one_layer_bytes(kv))
+
+
+# InternLM2-1.8B as the serving cell runs it (perfbench/configs/internlm2-1.8b.json)
+INTERNLM2 = ModelConfig(vocab_size=92544, d_model=2048, n_layers=24, n_heads=16,
+                        n_kv_heads=8, d_ff=8192, rope_theta=1e6)
+CELL_SLOTS, CELL_MAX_LEN = 32, 1024
+
+
+def _cell_cache(chip):
+    return chip((INTERNLM2.n_layers, CELL_SLOTS, INTERNLM2.n_kv_heads,
+                 CELL_MAX_LEN, INTERNLM2.head_dim))
+
+
+@pytest.mark.parametrize("attn_len", [64, 512, 1024])
+def test_decode_step_keeps_the_cache_layout(chip, attn_len):
+    """The donated caches alias their outputs either way; what the compiler
+    does in between is the question. With the one-row window the step wrote
+    its new K/V rows through up to PR 26, XLA:TPU prefers a cache layout
+    with the window's dimensions minor-most ({4,2,0,3,1}), while parameters
+    and aliased outputs are pinned to the default one, and this test fails
+    on that parent with `copy.58`, `copy.61` (parameter -> {4,2,0,3,1}),
+    `copy.64`, `copy.65` (-> the outputs), each bf16[24,32,8,1024,128],
+    1.61 GB read and written, and `temp` 1.616 GB: 12.9 GB of HBM traffic in
+    every step, 63% of the serving cell's device time (ledger, PR 26). The
+    tile-aligned block write (`serving._write_rows`) leaves two in-place
+    `dynamic-update-slice` in the default layout. With that alone the
+    buckets under max_len still fail here, on `temp` 0.068 GB: one layer's
+    [32,8,1024,128] copied to read `[:, :, :attn_len]` of it; the window
+    read by one `dynamic_slice` leaves 0.0006 GB."""
+    from ray_tpu.models.serving import decode_step_fused
+
+    params = _param_shapes(chip, INTERNLM2)
+    kv = _cell_cache(chip)
+    ints = chip((CELL_SLOTS,), jnp.int32)
+    c = decode_step_fused.lower(params, kv, kv, ints, ints, INTERNLM2,
+                                attn_len).compile()
+    assert c.memory_analysis().alias_size_in_bytes >= 2 * (kv.size * 2)
+    _assert_cache_stays_put(c, kv, _one_layer_bytes(kv))
+
+
+def test_write_slots_keeps_the_cache_layout(chip):
+    """Admission's scatter of whole prefix rows [L, nb, kvh, max_len, hd]
+    is in place on the default layout (true before PR 27 too: a guard). Its
+    temporary is a copy of the rows it writes (nb x 50 MB), not of a cache."""
+    from ray_tpu.models.serving import _write_slots
+
+    kv, nb = _cell_cache(chip), 4
+    rows = chip((kv.shape[0], nb) + kv.shape[2:])
+    ints, few = chip((CELL_SLOTS,), jnp.int32), chip((nb,), jnp.int32)
+    c = _write_slots.lower(kv, kv, ints, ints, few, rows, rows, few, few).compile()
+    _assert_cache_stays_put(c, kv)
 
 
 def test_prefill_slots_compiles_at_b1(chip):
     from ray_tpu.models.serving import prefill_slots
-    from ray_tpu.models.transformer import init_params
 
-    params = jax.eval_shape(lambda k: init_params(k, B1), jax.random.PRNGKey(0))
-    params = jax.tree_util.tree_map(lambda s: chip(s.shape, s.dtype), params)
+    params = _param_shapes(chip, B1)
     c = prefill_slots.lower(params, chip((4, 256), jnp.int32),
                             chip((4,), jnp.int32), B1, 512).compile()
     assert c.memory_analysis().argument_size_in_bytes < 3 * 2**30

@@ -3,10 +3,11 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ray_tpu.models import ModelConfig, init_params
 from ray_tpu.models.inference import generate
-from ray_tpu.models.serving import ContinuousBatchingEngine
+from ray_tpu.models.serving import ContinuousBatchingEngine, _write_rows
 
 CFG = ModelConfig.tiny()
 PARAMS = init_params(jax.random.PRNGKey(0), CFG)
@@ -67,8 +68,6 @@ def test_bucketed_prefill_and_validation():
     prompt = list(range(20, 31))
     assert eng.generate(prompt, max_new_tokens=6) == _reference(prompt, 6)
 
-    import pytest
-
     with pytest.raises(ValueError, match="prompt length"):
         eng.submit(list(range(MAX_LEN)))
     with pytest.raises(ValueError, match="empty"):
@@ -120,11 +119,17 @@ def test_int8_quantized_engine_quality_and_memory():
 
 
 def test_decode_step_donation_clean():
-    """PR 16 acceptance: the fused decode step donates the K/V/length
-    buffers, so steady-state stepping must not reallocate the caches —
-    buffer identity stays within the initial donated set and the number of
-    live cache-shaped device arrays is stable. Tokens and lengths must stay
-    on device between steps (no implicit host sync in the step path)."""
+    """The fused decode step donates the K/V/length buffers: steady-state
+    stepping hands back the buffers it was given (pointers stay within the
+    initial donated set, the number of live cache-shaped arrays is stable),
+    and tokens and lengths stay on device between steps.
+
+    What this cannot see: what the program does BETWEEN the aliased input
+    and output. On the CPU the pointers alias whether or not the compiled
+    step relayouts the whole cache around its row write, as XLA:TPU did up
+    to PR 26 (four copies of 1.6 GB a step, all inside aliased buffers).
+    That is asked of the chip's compiler, in `tests/test_chip_compile.py`
+    (`test_decode_step_keeps_the_cache_layout`)."""
     eng = ContinuousBatchingEngine(PARAMS, CFG, num_slots=2, max_len=MAX_LEN)
     eng.submit([5, 17, 400, 3], max_new_tokens=60)
     eng.step()  # prefill dispatch
@@ -142,6 +147,50 @@ def test_decode_step_donation_clean():
         assert isinstance(eng.lengths, jax.Array)
         now_live = sum(1 for a in jax.live_arrays() if a.shape == cache_shape)
         assert now_live <= n_live  # no per-step full-cache reallocation
+
+
+def _write_rows_one_row_window(cache, rows, lengths):
+    """The row write as it was up to PR 26, kept as the plain reference:
+    one `dynamic_update_slice` of a [1, hd] window per (layer, slot, head).
+    A position past the end is clamped to the last row."""
+    def write_row(c, new, pos):  # c [max_len, hd] <- new [1, hd] at row pos
+        return jax.lax.dynamic_update_slice(c, new, (pos, 0))
+
+    wr = jax.vmap(jax.vmap(jax.vmap(write_row, in_axes=(0, 0, None)),  # kvh
+                           in_axes=(0, 0, 0)),                         # B
+                  in_axes=(0, 0, None))                                # L
+    return wr(cache, rows[:, :, :, None], lengths)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("max_len", [64, 40, 12])
+def test_write_rows_matches_the_one_row_window(max_len, dtype):
+    """The step's block write (read the tile-aligned R rows around the
+    position, select the new row in, write the block back) against the
+    one-row window it replaced: bit-identical caches for every slot whose
+    position is inside the cache, at the block edges and the cache's end,
+    for `max_len` a multiple of R, not a multiple (40) and under R (12);
+    a slot past the end (an idle slot keeps counting) writes nothing."""
+    R = 32 // jnp.dtype(dtype).itemsize
+    L, kvh, hd = 3, 2, 8
+    inside = sorted({p for p in (0, R - 1, R, 2 * R - 1, max_len - 2,
+                                 max_len - 1) if 0 <= p < max_len})
+    past = [max_len, max_len + 1, max_len + R, 10 * max_len]
+    lengths = jnp.asarray(inside + past, jnp.int32)
+    B = len(inside) + len(past)
+    kc, kr = jax.random.split(jax.random.PRNGKey(max_len))
+    cache = (jax.random.normal(kc, (L, B, kvh, max_len, hd)) + 3.0).astype(dtype)
+    rows = (jax.random.normal(kr, (L, B, kvh, hd)) - 3.0).astype(dtype)
+
+    got = np.asarray(jax.jit(_write_rows)(cache, rows, lengths), np.float32)
+    ref = np.asarray(_write_rows_one_row_window(cache, rows, lengths), np.float32)
+    n = len(inside)
+    np.testing.assert_array_equal(got[:, :n], ref[:, :n])
+    want, new = np.array(cache, np.float32), np.asarray(rows, np.float32)
+    for b, pos in enumerate(inside):
+        want[:, b, :, pos] = new[:, b]
+    np.testing.assert_array_equal(got, want)  # slots past the end untouched
 
 
 def test_progress_and_submit_not_blocked_during_step():
