@@ -1,0 +1,462 @@
+"""A decoder whose layers are of several kinds (the Kimi-Linear family):
+KDA linear-attention mixers (`ops/kda.py`) and latent-attention mixers
+without positions (`ops/mla.py`) in one stack, a leading dense SwiGLU layer,
+then a dropless sigmoid-routed top-k expert layer with a shared expert
+(`ops/moe.py:dropless_moe`) that is told which experts it holds. Every
+block is `x += Mixer(RMSNorm(x)); x += FFN(RMSNorm(x))`; untied head.
+
+The layers are held as a LIST of per-layer dicts and the stack is unrolled
+(nine layers in the benchmark's cut): a `lax.scan` needs one body, and
+here the body changes from layer to layer; per-layer leaves also let the
+decode step donate and rewrite each layer's state in place.
+
+Three call modes over the same weights:
+
+`forward`       whole sequence -> logits (tests, offline scoring);
+`prefill`       a right-padded bucket [nb, s] with true lengths -> logits at
+                the last true position and the state each request leaves:
+                per KDA layer S [nb, H, dk, dv] float32 and the convolution
+                tail [nb, K-1, 3 H dk]; per MLA layer the latent rows
+                [nb, s, rank + rope]. Positions past `true_len` leave S and
+                the tail untouched;
+`decode_step`   one token for every slot from the slots' state, greedy
+                sampling on device, state DONATED and rewritten in place.
+
+`HybridCache` is this model's implementation of the engine's per-slot state
+interface (`models/serving.py`, "the cache interface").
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any, Dict, List, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.ops import kda, mla
+from ray_tpu.ops.attention import causal_attention_blocked
+from ray_tpu.ops.cache import write_rows
+from ray_tpu.ops.layers import rms_norm, swiglu
+from ray_tpu.ops.moe import dropless_moe, route_top_k
+
+F32 = jnp.float32
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridConfig:
+    vocab_size: int = 512
+    d_model: int = 64
+    n_layers: int = 4
+    kda_layers: Tuple[int, ...] = (1, 2, 4)   # counted from 1; the rest are MLA
+    first_dense: int = 1                      # leading layers with a dense FFN
+    # KDA mixer
+    kda_heads: int = 2
+    kda_head_dim: int = 16
+    conv_kernel: int = 4
+    kda_rank: int = 16                        # low rank of the decay and gate
+    kda_chunk: int = 64
+    # MLA mixer
+    n_heads: int = 2
+    kv_lora_rank: int = 32
+    qk_nope_dim: int = 16
+    qk_rope_dim: int = 8
+    v_head_dim: int = 16
+    # FFN
+    d_ff: int = 128
+    d_expert: int = 32
+    n_experts: int = 8                        # the router's width
+    experts_held: Tuple[int, ...] = tuple(range(8))
+    top_k: int = 2
+    n_shared: int = 1
+    route_scale: float = 2.446
+    renormalize: bool = True
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.float32
+    # the most tokens one prefill call takes (bounds its activations and the
+    # number of (batch, bucket) programs): admission batches are split to it
+    prefill_tokens: int = 4096
+
+    @staticmethod
+    def tiny_hybrid() -> "HybridConfig":
+        """dense layer + KDA, KDA, MLA, KDA; 8 experts, top-2, one shared."""
+        return HybridConfig()
+
+    def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
+        return tuple(("kda" if i in self.kda_layers else "mla",
+                      "dense" if i <= self.first_dense else "moe")
+                     for i in range(1, self.n_layers + 1))
+
+    @property
+    def latent_width(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    def make_cache(self, num_slots: int, max_len: int) -> "HybridCache":
+        """This model's per-slot state for `ContinuousBatchingEngine`."""
+        return HybridCache(self, num_slots, max_len)
+
+
+# ---------------------------------------------------------------- params
+
+
+def init_params(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
+    """Scaled-normal weights in cfg.dtype; small NON-ZERO values for the
+    router's correction bias, `A_log` and `dt_bias` (fla's ranges: A in
+    [1, 16], softplus(dt_bias) in [0.001, 0.1]), so that leaving one of
+    them out of the computation changes the result. Pure: jit it to build a
+    large model in one program."""
+    d, dt = cfg.d_model, cfg.dtype
+    H, dk, r = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_rank
+    counter = iter(range(1 << 20))
+
+    def w(shape, fan_in):
+        key = jax.random.fold_in(rng, next(counter))
+        return (jax.random.normal(key, shape, F32) * fan_in ** -0.5).astype(dt)
+
+    def uniform(shape, lo, hi):
+        key = jax.random.fold_in(rng, next(counter))
+        return jax.random.uniform(key, shape, F32, lo, hi)
+
+    def swiglu_w(width, lead=()):
+        return {"w_gate": w(lead + (d, width), d), "w_up": w(lead + (d, width), d),
+                "w_down": w(lead + (width, d), width)}
+
+    layers: List[Dict[str, Any]] = []
+    for mixer, ffn in cfg.layer_kinds():
+        p: Dict[str, Any] = {"mixer_norm": jnp.ones((d,), dt),
+                             "ffn_norm": jnp.ones((d,), dt)}
+        if mixer == "kda":
+            step = jnp.exp(uniform((H * dk,), np.log(1e-3), np.log(1e-1)))
+            p["kda"] = {
+                "w_qkv": w((d, 3 * H * dk), d),
+                "conv": w((cfg.conv_kernel, 3 * H * dk), cfg.conv_kernel),
+                "w_f1": w((d, r), d), "w_f2": w((r, H * dk), r),
+                "A_log": jnp.log(uniform((H,), 1.0, 16.0)),
+                "dt_bias": step + jnp.log(-jnp.expm1(-step)),  # softplus^-1
+                "w_b": w((d, H), d),
+                "w_g1": w((d, r), d), "w_g2": w((r, H * dk), r),
+                "o_norm": jnp.ones((dk,), dt), "wo": w((H * dk, d), H * dk)}
+        else:
+            nh, dn, dr, dv = (cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim,
+                              cfg.v_head_dim)
+            p["mla"] = {
+                "wq": w((d, nh * (dn + dr)), d),
+                "w_kva": w((d, cfg.latent_width), d),
+                "kv_norm": jnp.ones((cfg.kv_lora_rank,), dt),
+                "w_kvb": w((cfg.kv_lora_rank, nh * (dn + dv)), cfg.kv_lora_rank),
+                "wo": w((nh * dv, d), nh * dv)}
+        if ffn == "dense":
+            p["ffn"] = swiglu_w(cfg.d_ff)
+        else:
+            p["moe"] = {
+                "router": w((d, cfg.n_experts), d),
+                "bias": uniform((cfg.n_experts,), -0.05, 0.05),
+                **swiglu_w(cfg.d_expert, (len(cfg.experts_held),)),
+                "shared": swiglu_w(cfg.d_expert * cfg.n_shared)}
+        layers.append(p)
+    return {"embed": (jax.random.normal(jax.random.fold_in(rng, next(counter)),
+                                        (cfg.vocab_size, d), F32) * 0.02).astype(dt),
+            "final_norm": jnp.ones((d,), dt), "layers": layers,
+            "lm_head": (jax.random.normal(jax.random.fold_in(rng, next(counter)),
+                                          (d, cfg.vocab_size), F32) * 0.02).astype(dt)}
+
+
+# ---------------------------------------------------------------- pieces
+
+
+def _kda_inputs(cfg: HybridConfig, p, h, y):
+    """From the normed residual h [..., d] and the convolved, SiLU'd
+    projections y [..., 3 H dk]: q, k, v [..., H, dk], the log decay g
+    [..., H, dk] and beta [..., H], all float32."""
+    H, dk = cfg.kda_heads, cfg.kda_head_dim
+    lead = h.shape[:-1]
+    q, k, v = (a.reshape(lead + (H, dk)) for a in jnp.split(y, 3, axis=-1))
+    q = kda.l2_norm(q) * dk ** -0.5
+    k = kda.l2_norm(k)
+    f = ((h @ p["w_f1"]) @ p["w_f2"]).astype(F32) + p["dt_bias"]
+    g = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(f.reshape(lead + (H, dk)))
+    beta = jax.nn.sigmoid((h @ p["w_b"]).astype(F32))
+    return q, k, v.astype(F32), g, beta
+
+
+def _kda_output(cfg: HybridConfig, p, h, o):
+    """o [..., H, dv] float32 -> the mixer's output [..., d]: per-head
+    RMSNorm, the sigmoid gate, the output projection."""
+    gate = jax.nn.sigmoid(((h @ p["w_g1"]) @ p["w_g2"]).astype(F32))
+    o = rms_norm(o, p["o_norm"], cfg.norm_eps).reshape(gate.shape) * gate
+    return o.astype(h.dtype) @ p["wo"]
+
+
+def _mla_latent(cfg: HybridConfig, p, h):
+    """h [..., d] -> (q [..., H, dn + dr], latent row [..., rank + dr])."""
+    q = (h @ p["wq"]).reshape(h.shape[:-1] + (cfg.n_heads, -1))
+    ckr = h @ p["w_kva"]
+    c = rms_norm(ckr[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
+    return q, jnp.concatenate([c, ckr[..., cfg.kv_lora_rank:]], axis=-1)
+
+
+def _normed(cfg: HybridConfig, x, w):
+    """The float32 residual x, normed: (in float32 for the router, in the
+    weights' type for the matrix products)."""
+    h32 = rms_norm(x, w, cfg.norm_eps)
+    return h32, h32.astype(cfg.dtype)
+
+
+def _ffn(cfg: HybridConfig, p, h32, h, valid):
+    """h [T, d] (and h32, the same in float32) -> (FFN output [T, d],
+    assignments landed, experts touched, the experts each token chose
+    [T, k] or None for a dense layer). `valid` [T] bool: tokens that are no
+    padding and no idle slot; the others are routed nowhere (their rows of
+    the result are not used)."""
+    if "ffn" in p:
+        f = p["ffn"]
+        zero = jnp.zeros((), jnp.int32)
+        return (swiglu(h @ f["w_gate"], h @ f["w_up"]) @ f["w_down"], zero, zero,
+                None)
+    m = p["moe"]
+    with jax.named_scope("moe"):
+        # routed on the float32 activations, computed on the rounded ones
+        idx, w = route_top_k(h32, m["router"], m["bias"], cfg.top_k,
+                             cfg.route_scale, cfg.renormalize)
+        y, landed, touched = dropless_moe(
+            h, idx, w, m["w_gate"], m["w_up"], m["w_down"], cfg.experts_held,
+            cfg.n_experts, valid)
+    with jax.named_scope("shared_expert"):
+        s = m["shared"]
+        y = y + swiglu(h @ s["w_gate"], h @ s["w_up"]) @ s["w_down"]
+    return y, landed, touched, idx
+
+
+# ---------------------------------------------------------------- sequence
+
+
+def _sequence(params, tokens, true_len, cfg: HybridConfig):
+    """tokens [b, s] right-padded to true_len [b] -> (features after the
+    final norm [b, s, d], state rows as `prefill` returns them)."""
+    b, s = tokens.shape
+    K = cfg.conv_kernel
+    # the residual stream is float32 (weights and matmul inputs keep the
+    # configuration's type): in bf16 its rounding at every add reaches 1%
+    # after a few layers, and a router with near-ties (8 of 256 by score)
+    # then picks another expert for a quarter of the tokens
+    x = params["embed"][tokens].astype(F32)
+    valid = jnp.arange(s)[None, :] < true_len[:, None]               # [b, s]
+    S_rows, conv_rows, latent_rows, routing = [], [], [], []
+    for p, (mixer, _) in zip(params["layers"], cfg.layer_kinds()):
+        _, h = _normed(cfg, x, p["mixer_norm"])
+        if mixer == "kda":
+            with jax.named_scope("kda"):
+                m = p["kda"]
+                qkv = h @ m["w_qkv"]
+                y = jax.nn.silu(kda.short_conv(qkv.astype(F32), m["conv"].astype(F32)))
+                q, k, v, g, beta = _kda_inputs(cfg, m, h, y)
+                # padding leaves the state alone: no decay, no write
+                g = jnp.where(valid[..., None, None], g, 0.0)
+                beta = jnp.where(valid[..., None], beta, 0.0)
+                o, S = kda.kda_chunked(q, k, v, g, beta, chunk=cfg.kda_chunk)
+                x = x + _kda_output(cfg, m, h, o).astype(F32)
+                S_rows.append(S)
+                conv_rows.append(kda.conv_tail(qkv, true_len, K))
+        else:
+            with jax.named_scope("mla"):
+                m = p["mla"]
+                q, latent = _mla_latent(cfg, m, h)
+                k, v = mla.mla_expand(latent, m["w_kvb"], cfg.n_heads,
+                                      cfg.kv_lora_rank, cfg.qk_nope_dim,
+                                      cfg.v_head_dim)
+                attn = causal_attention_blocked(
+                    q, k, v, sm_scale=(cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5)
+                x = x + (attn.reshape(b, s, -1) @ m["wo"]).astype(F32)
+                latent_rows.append(latent)
+        h32, h = _normed(cfg, x, p["ffn_norm"])
+        y, _, _, chosen = _ffn(cfg, p, h32.reshape(b * s, -1),
+                               h.reshape(b * s, -1), valid.reshape(b * s))
+        x = x + y.reshape(b, s, -1).astype(F32)
+        if chosen is not None:
+            routing.append(chosen.reshape(b, s, -1))
+    _, x = _normed(cfg, x, params["final_norm"])
+    rows = {"S": S_rows, "conv": conv_rows,
+            "latent": jnp.stack(latent_rows) if latent_rows else
+            jnp.zeros((0, b, s, cfg.latent_width), cfg.dtype)}
+    return x, rows, routing
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def forward(params, tokens, cfg: HybridConfig):
+    """tokens [b, s] -> logits [b, s, vocab] float32."""
+    full = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
+    x, _, _ = _sequence(params, tokens, full, cfg)
+    return (x @ params["lm_head"]).astype(F32)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "with_routing"))
+def prefill(params, tokens, true_len, cfg: HybridConfig,
+            with_routing: bool = False):
+    """-> (logits at the last true position [nb, vocab] float32, state rows
+    {"S": [per KDA layer [nb, H, dk, dv]], "conv": [per KDA layer
+    [nb, K-1, 3 H dk]], "latent": [MLA layers, nb, s, rank + rope]}).
+    `with_routing` adds "routing" [expert layers, nb, s, k]: the experts
+    every position chose (for a comparison that has to tell a near-tie in
+    the router from an error; the engine never asks for it)."""
+    x, rows, routing = _sequence(params, tokens, true_len, cfg)
+    if with_routing:
+        rows["routing"] = jnp.stack(routing)
+    last = jnp.take_along_axis(x, (true_len - 1)[:, None, None], axis=1)[:, 0]
+    with jax.named_scope("head"):
+        return (last @ params["lm_head"]).astype(F32), rows
+
+
+# ---------------------------------------------------------------- decode
+
+
+def _decode(params, state, lengths, tokens, active, cfg: HybridConfig,
+            attn_len: int):
+    """One token for every slot. Returns (state, logits [B, vocab] float32,
+    [assignments landed, experts touched] summed over the expert layers,
+    the experts every slot chose [expert layers, B, k])."""
+    x = params["embed"][tokens].astype(F32)           # [B, d], float32 residual
+    mask = jnp.arange(attn_len)[None, :] < lengths[:, None]          # strict
+    S_new, conv_new, latent_cur, routing = [], [], [], []
+    landed = touched = jnp.zeros((), jnp.int32)
+    i_kda = i_mla = 0
+    for p, (mixer, _) in zip(params["layers"], cfg.layer_kinds()):
+        _, h = _normed(cfg, x, p["mixer_norm"])
+        if mixer == "kda":
+            with jax.named_scope("kda"):
+                m = p["kda"]
+                y, tail = kda.short_conv_step(h @ m["w_qkv"],
+                                              state["conv"][i_kda], m["conv"])
+                q, k, v, g, beta = _kda_inputs(cfg, m, h, jax.nn.silu(y))
+                S, o = kda.kda_step(state["S"][i_kda], q, k, v, g, beta)
+                x = x + _kda_output(cfg, m, h, o).astype(F32)
+                S_new.append(S)
+                conv_new.append(tail)
+                i_kda += 1
+        else:
+            with jax.named_scope("mla"):
+                m = p["mla"]
+                q, cur = _mla_latent(cfg, m, h)
+                cur = cur.astype(cfg.dtype)
+                attn = mla.mla_decode_absorbed(
+                    q, state["latent"][i_mla, :, :, :attn_len], cur, mask,
+                    m["w_kvb"], cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.v_head_dim)
+                x = x + (attn.reshape(x.shape[0], -1).astype(cfg.dtype)
+                         @ m["wo"]).astype(F32)
+                latent_cur.append(cur)
+                i_mla += 1
+        h32, h = _normed(cfg, x, p["ffn_norm"])
+        y, n_landed, n_touched, chosen = _ffn(cfg, p, h32, h, active)
+        x = x + y.astype(F32)
+        landed, touched = landed + n_landed, touched + n_touched
+        if chosen is not None:
+            routing.append(chosen)
+    latent = state["latent"]
+    if latent_cur:
+        with jax.named_scope("state_write"):
+            # the tile-aligned row write, with one "kv head"
+            latent = write_rows(latent, jnp.stack(latent_cur)[:, :, None], lengths)
+    with jax.named_scope("head"):
+        _, x = _normed(cfg, x, params["final_norm"])
+        logits = (x @ params["lm_head"]).astype(F32)
+    return ({"S": S_new, "conv": conv_new, "latent": latent}, logits,
+            jnp.stack([landed, touched]), routing)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
+                   donate_argnums=(1,))
+def decode_logits(params, state, lengths, tokens, active, cfg: HybridConfig,
+                  attn_len: int):
+    """The decode step for callers that need logits: the body of
+    `decode_step` over the same slot state (DONATED) and the same `active`
+    mask, returning (state, logits [B, vocab], the experts every slot chose
+    [expert layers, B, k]) instead of sampling."""
+    state, logits, _, routing = _decode(params, state, lengths, tokens, active,
+                                        cfg, attn_len)
+    return state, logits, jnp.stack(routing)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
+                   donate_argnums=(1, 2))
+def decode_step(params, state, lengths, tokens, active, cfg: HybridConfig,
+                attn_len: int):
+    """The hot decode step: state and lengths DONATED, greedy sampling on
+    device. `active` [B] bool marks the slots that serve a request; an idle
+    slot still computes (static shapes) but is routed to no expert. Returns
+    (state, lengths + 1, next tokens [B], report [B + 2] = the next tokens
+    followed by the two expert-layer counters: ONE array crosses to the
+    host per step)."""
+    state, logits, counters, _ = _decode(params, state, lengths, tokens, active,
+                                         cfg, attn_len)
+    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return state, lengths + 1, nxt, jnp.concatenate([nxt, counters])
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _prefill_first(params, tokens, true_len, cfg: HybridConfig):
+    logits, rows = prefill(params, tokens, true_len, cfg)
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32), rows
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _write_state(state, lengths, tokens, slots, rows, true_len, first):
+    """Admission: a prefill's state rows into the DONATED slot state (a
+    slot's recurrent state is REPLACED, which is its reset). `slots`
+    entries equal to the number of slots are batch padding and are
+    dropped. `tokens` is not donated (the step in flight reads it)."""
+    with jax.named_scope("state_write"):
+        put = lambda whole, part: whole.at[slots].set(part, mode="drop")
+        bucket = rows["latent"].shape[2]
+        state = {"S": [put(a, r) for a, r in zip(state["S"], rows["S"])],
+                 "conv": [put(a, r) for a, r in zip(state["conv"], rows["conv"])],
+                 "latent": state["latent"].at[:, slots, :, :bucket].set(
+                     rows["latent"][:, :, None], mode="drop")}
+    return (state, lengths.at[slots].set(true_len, mode="drop"),
+            tokens.at[slots].set(first, mode="drop"))
+
+
+class HybridCache:
+    """Per-slot state of the hybrid model for `ContinuousBatchingEngine`:
+    per KDA layer the state S [slots, H, dk, dv] float32 and the convolution
+    tail [slots, K-1, 3 H dk]; for the MLA layers the latent rows
+    [layers, slots, 1, max_len, rank + rope]."""
+
+    counters = ("expert_assignments", "experts_touched")
+
+    def __init__(self, cfg: HybridConfig, num_slots: int, max_len: int):
+        self.cfg, self.num_slots, self.max_len = cfg, num_slots, max_len
+        kinds = [m for m, _ in cfg.layer_kinds()]
+        self.n_kda, self.n_mla = kinds.count("kda"), kinds.count("mla")
+        H, dk = cfg.kda_heads, cfg.kda_head_dim
+        self.state = {
+            "S": [jnp.zeros((num_slots, H, dk, dk), F32) for _ in range(self.n_kda)],
+            "conv": [jnp.zeros((num_slots, cfg.conv_kernel - 1, 3 * H * dk), cfg.dtype)
+                     for _ in range(self.n_kda)],
+            # one "kv head", so that `ops.cache.write_rows` takes it as is
+            "latent": jnp.zeros((self.n_mla, num_slots, 1, max_len,
+                                 cfg.latent_width), cfg.dtype)}
+        self.prefill_args = {"state_layers": self.n_kda, "latent_layers": self.n_mla}
+
+    def max_prefill_batch(self, bucket: int) -> int:
+        return max(1, min(4, self.cfg.prefill_tokens // bucket))
+
+    def prefill(self, params, tokens, lens):
+        return _prefill_first(params, tokens, lens, self.cfg)
+
+    def write(self, lengths, tokens, slots, rows, lens, first):
+        self.state, lengths, tokens = _write_state(
+            self.state, lengths, tokens, slots, rows, lens, first)
+        return lengths, tokens
+
+    def decode(self, params, lengths, tokens, attn_len, active_slots):
+        active = np.zeros((self.num_slots,), bool)
+        active[list(active_slots)] = True
+        self.state, lengths, nxt, report = decode_step(
+            params, self.state, lengths, tokens, active, self.cfg, attn_len)
+        return lengths, nxt, report
+
+    def step_args(self, n_active: int, live_rows: int) -> Dict[str, int]:
+        """What one decode step moved, known on the host at dispatch."""
+        return {"state_slots": n_active if self.n_kda else 0,
+                "latent_rows": live_rows if self.n_mla else 0}

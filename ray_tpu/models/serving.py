@@ -18,7 +18,7 @@ loop is built to run at device speed:
     in between. A row write through a ONE-row window made XLA:TPU relayout
     the whole cache before and after it (`copy.58/61/64/65`, two per buffer
     per step, 12.9 GB of HBM traffic a step at 24 x 32 x 8 x 1024 x 128
-    bf16), so `_write_rows` writes tile-aligned blocks of rows, which keep
+    bf16), so `ops.cache.write_rows` writes tile-aligned blocks of rows, which keep
     the default layout. `tests/test_chip_compile.py` asks the chip's
     compiler; buffer pointers on the CPU alias either way;
   * attention reads a power-of-2 *bucket* of the cache (compiled once per
@@ -69,6 +69,7 @@ from ray_tpu.models.inference import (_gqa_decode_attention, _masked_attention,
                                       _mlp, _project_qkv)
 from ray_tpu.models.transformer import (ModelConfig, _deq_tree,
                                         _embed_lookup, lm_head_weights)
+from ray_tpu.ops.cache import write_rows as _write_rows
 from ray_tpu.ops.layers import rms_norm, rotary_embedding
 from ray_tpu.util import tracing
 
@@ -218,41 +219,6 @@ def decode_slots(params: Dict, k_all: jax.Array, v_all: jax.Array,
     return logits, k_new, v_new
 
 
-def _write_rows(cache: jax.Array, rows: jax.Array,
-                lengths: jax.Array) -> jax.Array:
-    """The decode step's cache write: `rows[:, b]` goes to row `lengths[b]`
-    of slot b in every layer and kv head. cache [L, B, kvh, max_len, hd],
-    rows [L, B, kvh, hd], lengths [B] -> cache.
-
-    Written as a read-modify-write of the tile-aligned block of R rows that
-    holds the position, one slot at a time, R being the rows one HBM tile
-    of the cache's dtype packs (8 / 16 / 32 for 4- / 2- / 1-byte elements).
-    A window of ONE row makes XLA:TPU's layout assignment put the window's
-    dimensions minor-most, and bridge that to the default layout of the
-    donated parameter and the aliased output with a copy of the whole cache
-    before and after the write (`copy.58/61/64/65` up to PR 26); a
-    whole-tile window keeps the default layout and the update stays in place.
-
-    A slot whose position is at or past max_len (an idle slot keeps
-    counting) writes nothing."""
-    L, B, kvh, max_len, hd = cache.shape
-    R = min(32 // cache.dtype.itemsize, max_len)
-    row_ids = jnp.arange(R)[:, None]
-
-    def write_slot(b, cache):
-        pos = lengths[b]
-        # clamped by hand: XLA would clamp a block that overhangs max_len
-        # silently, and the row would land one block off
-        start = jnp.minimum(pos // R * R, max_len - R)
-        at = (0, b, 0, start, 0)
-        block = jax.lax.dynamic_slice(cache, at, (L, 1, kvh, R, hd))
-        new = jax.lax.dynamic_slice(rows, (0, b, 0, 0), (L, 1, kvh, hd))
-        block = jnp.where(row_ids == pos - start, new[:, :, :, None], block)
-        return jax.lax.dynamic_update_slice(cache, block, at)
-
-    return jax.lax.fori_loop(0, B, write_slot, cache)
-
-
 @functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
                    donate_argnums=(1, 2, 3))
 def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
@@ -325,6 +291,61 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
     return k_all, v_all, lengths + 1, nxt
 
 
+class DenseKVCache:
+    """The dense block's per-slot state: keys and values of every position,
+    `k`, `v` [layers, slots, kv_heads, max_len, head_dim]. One of the two
+    implementations of the engine's cache interface, and the engine's
+    default; a configuration of another kind of model brings its own through
+    `cfg.make_cache(num_slots, max_len)`. What the interface asks is in
+    ARCHITECTURE.md, "The engine's cache interface":
+
+        state                       the device arrays, a tree the model owns
+        prefill(params, tokens, lens) -> (first tokens [nb], state rows)
+        write(lengths, tokens, slots, rows, lens, first) -> (lengths, tokens)
+        decode(params, lengths, tokens, attn_len, active) ->
+            (lengths, next tokens [B], report [B + len(counters)])
+        max_prefill_batch(bucket)   None = any
+        counters                    names of what `report` carries behind
+                                    the tokens (summed into `engine.step`)
+        step_args(n_active, live_rows), prefill_args   span arguments
+
+    It calls the module's own jitted `prefill_slots`, `_write_slots` and
+    `decode_step_fused`, so the dense model compiles to the programs it
+    always compiled to."""
+
+    counters: Tuple[str, ...] = ()
+    prefill_args: Dict[str, int] = {}
+
+    def __init__(self, cfg: ModelConfig, num_slots: int, max_len: int):
+        self.cfg, self.max_len = cfg, max_len
+        shape = (cfg.n_layers, num_slots, cfg.n_kv_heads, max_len, cfg.head_dim)
+        self.state = {"k": jnp.zeros(shape, cfg.dtype),
+                      "v": jnp.zeros(shape, cfg.dtype)}
+
+    def max_prefill_batch(self, bucket: int) -> Optional[int]:
+        return None
+
+    def prefill(self, params, tokens, lens):
+        first, k_rows, v_rows = prefill_slots(params, tokens, lens, self.cfg,
+                                              self.max_len)
+        return first, (k_rows, v_rows)
+
+    def write(self, lengths, tokens, slots, rows, lens, first):
+        s = self.state
+        s["k"], s["v"], lengths, tokens = _write_slots(
+            s["k"], s["v"], lengths, tokens, slots, rows[0], rows[1], lens, first)
+        return lengths, tokens
+
+    def decode(self, params, lengths, tokens, attn_len, active_slots):
+        s = self.state
+        s["k"], s["v"], lengths, nxt = decode_step_fused(
+            params, s["k"], s["v"], lengths, tokens, self.cfg, attn_len)
+        return lengths, nxt, nxt
+
+    def step_args(self, n_active: int, live_rows: int) -> Dict[str, int]:
+        return {}
+
+
 def _pow2(n: int) -> int:
     b = 1
     while b < n:
@@ -361,7 +382,7 @@ class ContinuousBatchingEngine:
     when tokens land and wakes the driver thread when work arrives.
     """
 
-    def __init__(self, params: Dict, cfg: ModelConfig, *, num_slots: int = 4,
+    def __init__(self, params: Dict, cfg, *, num_slots: int = 4,
                  max_len: int = 512, eos_token: Optional[int] = None,
                  quantize_weights: bool = False):
         if quantize_weights:
@@ -371,9 +392,10 @@ class ContinuousBatchingEngine:
         self.num_slots = num_slots
         self.max_len = max_len
         self.eos_token = eos_token
-        L, kvh, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-        self.k = jnp.zeros((L, num_slots, kvh, max_len, hd), cfg.dtype)
-        self.v = jnp.zeros((L, num_slots, kvh, max_len, hd), cfg.dtype)
+        # the model's per-slot state; the engine owns slots, lengths, tokens
+        # a configuration that is not the dense model's brings its own
+        self.cache = cfg.make_cache(num_slots, max_len) \
+            if hasattr(cfg, "make_cache") else DenseKVCache(cfg, num_slots, max_len)
         self.lengths = jnp.zeros((num_slots,), jnp.int32)
         self.tokens = jnp.zeros((num_slots,), jnp.int32)
         self._free = list(range(num_slots))
@@ -397,7 +419,26 @@ class ContinuousBatchingEngine:
         self._driver_stop = False
         self._driver_error: Optional[BaseException] = None
         self._attn_len = 0  # attention bucket of the last dispatched decode
+        self._step_args: Dict[str, int] = {}  # the cache's, of that decode
         tracing.record_compiles()
+
+    # the dense cache's two arrays by their old names (callers that warm or
+    # inspect them: the benchmark's replica, tests)
+    @property
+    def k(self) -> jax.Array:
+        return self.cache.state["k"]
+
+    @k.setter
+    def k(self, value: jax.Array) -> None:
+        self.cache.state["k"] = value
+
+    @property
+    def v(self) -> jax.Array:
+        return self.cache.state["v"]
+
+    @v.setter
+    def v(self, value: jax.Array) -> None:
+        self.cache.state["v"] = value
 
     # ------------------------------------------------------------- requests
     def submit(self, prompt: List[int], *, max_new_tokens: int = 32) -> int:
@@ -430,8 +471,7 @@ class ContinuousBatchingEngine:
             self._finished[req.request_id] = req
             self._record_request(req)
 
-    @staticmethod
-    def _record_request(req: _Request) -> None:
+    def _record_request(self, req: _Request) -> None:
         """The finished request's three stages as spans of its trace."""
         tid, parent = req.trace_ctx or (None, None)
         for name, t0, t1, args in (
@@ -439,7 +479,7 @@ class ContinuousBatchingEngine:
                  {"waited_for_slot": req.waited_for_slot}),
                 ("engine.prefill", req.t_admit, req.t_first,
                  {"bucket": req.bucket, "batch": req.batch,
-                  "prompt_len": len(req.prompt)}),
+                  "prompt_len": len(req.prompt), **self.cache.prefill_args}),
                 ("engine.decode", req.t_first, tracing.now_us(),
                  {"tokens": len(req.generated) - 1})):
             tracing.add_complete(name, "engine", t0, t1 - t0, trace_id=tid,
@@ -477,9 +517,12 @@ class ContinuousBatchingEngine:
                 admitted=sum(len(reqs) for _, reqs in admissions),
                 prefill_batches=len(admissions),
                 active=len(self._pending[1]) if self._pending else 0,
-                attn_len=self._attn_len if self._pending else 0)
+                attn_len=self._attn_len if self._pending else 0,
+                **(self._step_args if self._pending else {}))
             self._drain_pending_first()                   # device wait, no _lock
-            self._reap(prev)                              # device wait, no _lock
+            # the model's counters ride the token array, so they are those
+            # of the step reaped here: the one dispatched a step earlier
+            did.update(self._reap(prev))                  # device wait, no _lock
             with self._lock:
                 return len(self._active) + len(self._waiting)
 
@@ -510,20 +553,25 @@ class ContinuousBatchingEngine:
         padded to a power of 2 (padding rows scatter to an out-of-range
         slot and are dropped) so XLA compiles per (nb, bucket), not per
         admission count. First tokens stay on device until bookkeeping."""
-        rows = [r.prompt + [0] * (bucket - len(r.prompt)) for r in reqs]
-        lens = [len(r.prompt) for r in reqs]
-        slots = [r.slot for r in reqs]
-        for _ in range(_pow2(len(reqs)) - len(reqs)):
-            rows.append([0] * bucket)
-            lens.append(1)
-            slots.append(self.num_slots)  # out of range -> dropped
-        first, k_rows, v_rows = prefill_slots(
-            self.params, jnp.asarray(rows, jnp.int32),
-            jnp.asarray(lens, jnp.int32), self.cfg, self.max_len)
-        self.k, self.v, self.lengths, self.tokens = _write_slots(
-            self.k, self.v, self.lengths, self.tokens,
-            jnp.asarray(slots, jnp.int32), k_rows, v_rows,
-            jnp.asarray(lens, jnp.int32), first)
+        most = self.cache.max_prefill_batch(bucket)
+        if most is not None and len(reqs) > most:  # the model bounds a call
+            for i in range(0, len(reqs), most):
+                self._dispatch_prefill(bucket, reqs[i:i + most])
+            return
+        nb = _pow2(len(reqs))
+        # filled as ONE numpy array: `jnp.asarray` of nested Python lists
+        # converts element by element (12-45 ms for a 4096-token prompt,
+        # with the device idle behind it)
+        rows = np.zeros((nb, bucket), np.int32)
+        lens = np.ones((nb,), np.int32)
+        slots = np.full((nb,), self.num_slots, np.int32)  # out of range -> dropped
+        for i, r in enumerate(reqs):
+            rows[i, :len(r.prompt)] = r.prompt
+            lens[i], slots[i] = len(r.prompt), r.slot
+        lens = jnp.asarray(lens)
+        first, state_rows = self.cache.prefill(self.params, jnp.asarray(rows), lens)
+        self.lengths, self.tokens = self.cache.write(
+            self.lengths, self.tokens, jnp.asarray(slots), state_rows, lens, first)
         self._pending_first.append(
             (first, [(i, r) for i, r in enumerate(reqs)]))
 
@@ -538,13 +586,13 @@ class ContinuousBatchingEngine:
             max(self._slot_pos[s] for s in self._active), self.max_len)
         slot_map = dict(self._active)
         self._attn_len = attn_len
-        self.k, self.v, self.lengths, tokens_out = decode_step_fused(
-            self.params, self.k, self.v, self.lengths, self.tokens,
-            self.cfg, attn_len)
-        self.tokens = tokens_out
+        self._step_args = self.cache.step_args(
+            len(slot_map), sum(self._slot_pos[s] for s in slot_map))
+        self.lengths, self.tokens, report = self.cache.decode(
+            self.params, self.lengths, self.tokens, attn_len, slot_map)
         for s in slot_map:
             self._slot_pos[s] += 1
-        return tokens_out, slot_map
+        return report, slot_map
 
     def _drain_pending_first(self) -> None:
         """Sync admissions' on-device first tokens (deferred from dispatch
@@ -563,14 +611,16 @@ class ContinuousBatchingEngine:
                     self._maybe_finish(req)
                 self._cv.notify_all()
 
-    def _reap(self, prev) -> None:
+    def _reap(self, prev) -> Dict[str, int]:
         """Sync + bookkeep a previously dispatched step's tokens. Runs
-        while the NEXT step computes on device (one-step lookahead)."""
+        while the NEXT step computes on device (one-step lookahead).
+        Returns the model's counters of that step (`cache.counters`: they
+        sit behind the tokens in the one array that is synced)."""
         if prev is None:
-            return
-        tokens_dev, slot_map = prev
+            return {}
+        report_dev, slot_map = prev
         with tracing.span("engine.wait_device", "engine", what="decode"):
-            nxt = self._to_host(tokens_dev)  # device wait — no _lock held
+            nxt = self._to_host(report_dev)  # device wait — no _lock held
         with self._lock:
             for slot, req in slot_map.items():
                 if req.done:
@@ -578,6 +628,8 @@ class ContinuousBatchingEngine:
                 req.generated.append(int(nxt[slot]))
                 self._maybe_finish(req)
             self._cv.notify_all()
+        return {name: int(nxt[self.num_slots + i])
+                for i, name in enumerate(self.cache.counters)}
 
     def run_until_done(self, max_steps: int = 10_000) -> None:
         for _ in range(max_steps):
@@ -731,7 +783,7 @@ class ContinuousBatchingEngine:
                 return  # nothing left anywhere; request never finished
 
 
-def LLMDeployment(params, cfg: ModelConfig, *, num_slots: int = 4,
+def LLMDeployment(params, cfg, *, num_slots: int = 4,
                   max_len: int = 512, eos_token: Optional[int] = None,
                   quantize_weights: bool = False):
     """A serve-ready callable class hosting one engine per replica.

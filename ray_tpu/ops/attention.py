@@ -126,3 +126,25 @@ def attention_packed(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                      _repeat_kv(v4, n_rep),
                                      sm_scale=scale, causal=causal)
     return out.transpose(0, 2, 1, 3).reshape(b, sq, hd)
+
+
+def causal_attention_blocked(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                             sm_scale: float, q_block: int = 512) -> jax.Array:
+    """Causal attention whose query/key width differs from its value width
+    (latent attention: 192 against 128; the flash kernel and `attention`
+    above assume one `d`). q, k [b, s, h, dk], v [b, s, h, dv] ->
+    [b, s, h, dv]. Query rows go `q_block` at a time, each block against the
+    keys up to its own end only, so the scores never exceed
+    [b, h, q_block, s] and the causal half above the diagonal blocks is
+    never computed. Softmax statistics in float32."""
+    s = q.shape[1]
+    blk = min(q_block, s)
+    outs = []
+    for start in range(0, s, blk):
+        end = min(start + blk, s)
+        sc = jnp.einsum("bqhd,bkhd->bhqk", q[:, start:end], k[:, :end]
+                        ).astype(jnp.float32) * sm_scale
+        ok = (start + jnp.arange(end - start))[:, None] >= jnp.arange(end)[None, :]
+        pr = jax.nn.softmax(jnp.where(ok[None, None], sc, -1e30), axis=-1)
+        outs.append(jnp.einsum("bhqk,bkhd->bqhd", pr.astype(v.dtype), v[:, :end]))
+    return jnp.concatenate(outs, axis=1)

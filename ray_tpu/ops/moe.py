@@ -7,6 +7,13 @@ When the "expert" logical axis is sharded over a mesh axis, XLA compiles
 the dispatch/combine einsums into all-to-alls over ICI — no manual
 collectives. Static capacity keeps every shape compile-time constant
 (XLA-friendly; overflowing tokens are dropped, the standard trade).
+
+Two expert layers live here, SPLIT on purpose (PR 28): `moe_ffn` /
+`top2_gating` is the train path's gate (softmax, top-2, a capacity that
+drops, an auxiliary loss, dense dispatch tensors that shard over a mesh
+axis); `dropless_moe` is the serving path's layer (sigmoid scores, top-k by
+score + bias, no capacity, told which experts it holds). They share no
+tensor shape and no gate, so neither is written in terms of the other.
 """
 
 from __future__ import annotations
@@ -18,7 +25,9 @@ import jax.numpy as jnp
 
 
 def top2_gating(router_logits: jax.Array, capacity: int):
-    """Build dispatch/combine tensors.
+    """The capacity-dropping gate of the TRAIN path (`moe_ffn`): builds
+    dispatch/combine tensors [T, E, C]; a token past an expert's capacity is
+    dropped. The layer that never drops, for serving, is `dropless_moe`.
 
     router_logits: [T, E]. Returns (dispatch [T,E,C] bool-ish float,
     combine [T,E,C] float, aux_loss scalar).
@@ -90,3 +99,105 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_gate: jax.Array,
     # combine back: [T, d]
     out = jnp.einsum("tec,ecd->td", combine.astype(x.dtype), expert_out)
     return out.reshape(B, S, d), aux
+
+
+# row counts a small batch's grouped product is tried at before the whole
+# T k (`dropless_moe`); a batch of more rows than _TIERED_UP_TO (a prefill)
+# is tried at 5/4 of the share that lands here under even routing
+_ROW_TIERS = (128, 256)
+_TIERED_UP_TO = 1024
+
+
+def _row_tiers(rows: int, held: int, n_experts: int):
+    """The row counts `dropless_moe` runs its grouped products at, smallest
+    first; the last is always all `rows`, so nothing is ever dropped."""
+    if rows <= _TIERED_UP_TO:
+        tiers = [r for r in _ROW_TIERS if r < rows]
+    else:
+        even = -(-rows * held // n_experts)
+        tiers = [r for r in (-(-(even * 5 // 4 + 256) // 256) * 256,) if r < rows]
+    return tiers + [rows]
+
+
+def route_top_k(x: jax.Array, router_w: jax.Array, bias: jax.Array, top_k: int,
+                scale: float, renormalize: bool = True):
+    """Sigmoid-scored top-k routing over ALL experts: x [T, d], router_w
+    [d, E], bias [E] (the per-expert correction: it chooses, it does not
+    weigh). Returns (expert ids [T, k] int32, weights [T, k] float32)."""
+    # float32 at full precision: the choice of the k-th expert hangs on
+    # differences of a few thousandths between neighbouring scores
+    s = jax.nn.sigmoid(jnp.matmul(x.astype(jnp.float32),
+                                  router_w.astype(jnp.float32),
+                                  precision="highest"))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if renormalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), w * scale
+
+
+def dropless_moe(x: jax.Array, idx: jax.Array, w: jax.Array,
+                 w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                 held: Tuple[int, ...], n_experts: int,
+                 valid: Optional[jax.Array] = None):
+    """The held experts' part of a dropless expert layer.
+
+    x [T, d]; `idx`, `w` [T, k] are `route_top_k`'s choice over ALL
+    `n_experts` experts and its weights; `held` names the global ids of the
+    experts whose SwiGLU weights are stacked in w_gate / w_up [Eh, d, f] and
+    w_down [Eh, f, d] (Eh = len(held)). Every (token, expert) assignment
+    that lands on a held expert is computed, however skewed the routing: the
+    assignments are sorted by held expert and go through one grouped matrix
+    product per projection (`jax.lax.ragged_dot`: on the TPU a native
+    grouped matmul that visits only the touched groups). Assignments to
+    experts held elsewhere sort to the end, belong to no group and add
+    nothing: on one chip the layer runs without its exchange. `valid` [T]
+    bool, if given, marks the tokens that are real (no padding, no idle
+    slot): the others are routed nowhere and their rows of y are zero.
+
+    Returns (y [T, d] in x's dtype, assignments that landed here (int32),
+    held experts with at least one token (int32))."""
+    T, d = x.shape
+    top_k, Eh = idx.shape[-1], len(held)
+    local = jnp.full((n_experts,), Eh, jnp.int32).at[jnp.asarray(held)].set(
+        jnp.arange(Eh, dtype=jnp.int32))
+    group = local[idx]                              # Eh = not held here
+    if valid is not None:
+        group = jnp.where(valid[:, None], group, Eh)
+    group = group.reshape(T * top_k)
+    order = jnp.argsort(group, stable=True)
+    token = (order // top_k).astype(jnp.int32)
+    sizes = jnp.bincount(group, length=Eh + 1)[:Eh].astype(jnp.int32)
+    landed = jnp.sum(sizes)
+    # row j of token t sits at inverse[t, j] of the sorted assignments
+    inverse = jnp.zeros((T * top_k,), jnp.int32).at[order].set(
+        jnp.arange(T * top_k, dtype=jnp.int32)).reshape(T, top_k)
+
+    def experts(rows: int):
+        """The held experts' SwiGLU over the first `rows` sorted assignments
+        (the landed ones come first), weighed and summed per token."""
+        def run(_):
+            head = x[token[:rows]]                  # [rows, d], sorted by expert
+            act = jax.nn.silu(jax.lax.ragged_dot(head, w_gate, sizes)) \
+                * jax.lax.ragged_dot(head, w_up, sizes)
+            out = jax.lax.ragged_dot(act.astype(x.dtype), w_down, sizes)
+            # back to token order by a gather; an assignment that did not
+            # land here reads the zero row behind the last
+            out = jnp.concatenate([out, jnp.zeros((1, d), out.dtype)])
+            picked = out[jnp.where(inverse < landed, jnp.minimum(inverse, rows), rows)]
+            return jnp.sum(picked.astype(jnp.float32) * w[..., None], axis=1)
+        return run
+
+    # A grouped product costs each touched expert one row TILE of work, and
+    # the tile is as tall as the row count allows (measured on a v5e, 64
+    # experts of 2304 x 1024, 128 landed rows: 1.07 ms at 512 rows, 0.77 at
+    # 256, 0.65 at 128). A decode step offers T k rows of which about
+    # held / all land here, so it runs the smallest tier that holds what
+    # landed; a prefill gathers, multiplies and weighs 5/16 of its T k rows
+    # instead of all. The whole row count stays the last tier: nothing is
+    # dropped.
+    tiers = _row_tiers(T * top_k, Eh, n_experts)
+    y = jax.lax.switch(sum((landed > r).astype(jnp.int32) for r in tiers[:-1]),
+                       [experts(r) for r in tiers], None) \
+        if len(tiers) > 1 else experts(T * top_k)(None)
+    return y.astype(x.dtype), landed, jnp.sum(sizes > 0).astype(jnp.int32)

@@ -20,14 +20,20 @@ from __future__ import annotations
 class LLMReplica:
     def __init__(self, preset: str = "tiny", *, seed: int = 0,
                  num_slots: int = 4, max_len: int = 256):
-        """`preset` names a `ModelConfig` static constructor (`tiny`, `b1`)."""
+        """`preset` names a static constructor of `ModelConfig` (`tiny`,
+        `b1`: the dense block) or of `HybridConfig` (`tiny_hybrid`: KDA and
+        MLA mixers, dropless experts); the engine is the same class."""
         import jax
 
-        from ray_tpu.models import ModelConfig, init_params
+        from ray_tpu.models import ModelConfig, hybrid, init_params
         from ray_tpu.models.serving import ContinuousBatchingEngine
 
-        self.cfg = getattr(ModelConfig, preset)()
-        self.params = init_params(jax.random.PRNGKey(seed), self.cfg)
+        if hasattr(ModelConfig, preset):
+            self.cfg, init = getattr(ModelConfig, preset)(), init_params
+        else:
+            self.cfg = getattr(hybrid.HybridConfig, preset)()
+            init = hybrid.init_params
+        self.params = init(jax.random.PRNGKey(seed), self.cfg)
         self.engine = ContinuousBatchingEngine(
             self.params, self.cfg, num_slots=num_slots, max_len=max_len)
 

@@ -243,7 +243,7 @@ def add_complete(name: str, category: str, start_us: float, dur_us: float,
 
 def record_compiles() -> None:
     """Install, once per process, the `jax.monitoring` listener that turns
-    every trace / lower / backend-compile event into an `xla.compile` span
+    every program's trace / lower / backend-compile event into an `xla.compile` span
     named by the jitted function (`fun_name`; else the innermost open
     program span), so `ray_tpu timeline` says which step recompiled.
     Called by code that imports jax anyway (the engine, `make_train_step`)."""
@@ -258,11 +258,29 @@ def record_compiles() -> None:
             return
         ctx = getattr(_tls, "ctx", None) or (None, None)
         dur = secs * 1e6
-        add_complete(
-            "xla.compile", "compile", _now_us() - dur, dur,
-            trace_id=ctx[0], parent_id=ctx[1],
+        span = dict(
+            name="xla.compile", category="compile", start_us=_now_us() - dur,
+            dur_us=dur, trace_id=ctx[0], parent_id=ctx[1],
             event=event.rsplit("/", 1)[-1],
             fun_name=str(kw.get("fun_name") or open_span_name() or ""))
+        pending = getattr(_tls, "pending_traces", None)
+        if pending is None:
+            pending = _tls.pending_traces = {}
+        if span["event"] == "jaxpr_trace_duration":
+            # jax reports a trace for every jitted function it meets INSIDE
+            # a program too (each `jnp` call: a thousand for a small model,
+            # enough to overflow the ring before a flush), and for the loop
+            # conditions it traces while lowering; the program's own is the
+            # one named like the module that is lowered next: hold them
+            pending[span["fun_name"]] = span
+            return
+        if span["event"] == "jaxpr_to_mlir_module_duration":
+            name = span["fun_name"]
+            own = pending.get(name[4:-1] if name.startswith("jit(") else name)
+            pending.clear()
+            if own is not None:
+                add_complete(**own)
+        add_complete(**span)
 
     monitoring.register_event_duration_secs_listener(on_duration)
 

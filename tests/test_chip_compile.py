@@ -201,7 +201,7 @@ def test_decode_step_keeps_the_cache_layout(chip, attn_len):
     `copy.64`, `copy.65` (-> the outputs), each bf16[24,32,8,1024,128],
     1.61 GB read and written, and `temp` 1.616 GB: 12.9 GB of HBM traffic in
     every step, 63% of the serving cell's device time (ledger, PR 26). The
-    tile-aligned block write (`serving._write_rows`) leaves two in-place
+    tile-aligned block write (`ops.cache.write_rows`) leaves two in-place
     `dynamic-update-slice` in the default layout. With that alone the
     buckets under max_len still fail here, on `temp` 0.068 GB: one layer's
     [32,8,1024,128] copied to read `[:, :, :attn_len]` of it; the window
@@ -228,6 +228,47 @@ def test_write_slots_keeps_the_cache_layout(chip):
     ints, few = chip((CELL_SLOTS,), jnp.int32), chip((nb,), jnp.int32)
     c = _write_slots.lower(kv, kv, ints, ints, few, rows, rows, few, few).compile()
     _assert_cache_stays_put(c, kv)
+
+
+@pytest.mark.parametrize("attn_len", [1024, 8192])
+def test_hybrid_decode_step_keeps_its_state_in_place(chip, attn_len):
+    """The hybrid model's decode step at the benchmark cell's real shapes
+    (Kimi-Linear widths, 9 layers, 64 held experts, 64 slots x 8192): every
+    leaf of the slot state (KDA S, convolution tails, latent rows: 2.18 GB)
+    aliases its output, and the step holds no copy of the latent cache. Two
+    earlier forms failed here: a latent cache kept as [layers, slots,
+    max_len, 576] and reshaped around `write_rows` (`copy.265` / `copy.267`
+    of the whole 1.2 GB cache, `temp` 1.38 GB), and scores taken as
+    "bhc,blc->bhl" against a [slots, window, 576] slice (the window
+    re-laid out with the positions minor-most: `temp` 1.30 GB at 8192)."""
+    import json
+
+    from ray_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    import sys
+    sys.path.insert(0, root)
+    from perfbench.lib import hybrid_model
+
+    with open(os.path.join(root, "perfbench", "configs",
+                           "kimi-linear-48b-a3b.1of4.json")) as f:
+        conf = json.load(f)
+    cfg = hybrid_model.model_config(conf)
+    slots, max_len = conf["run"]["num_slots"], conf["run"]["max_len"]
+    as_shapes = lambda tree: jax.tree_util.tree_map(
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = as_shapes(jax.eval_shape(lambda k: hybrid.init_params(k, cfg),
+                                      jax.random.PRNGKey(0)))
+    state = as_shapes(jax.eval_shape(
+        lambda: hybrid.HybridCache(cfg, slots, max_len).state))
+    ints = chip((slots,), jnp.int32)
+    c = hybrid.decode_step.lower(params, state, ints, ints,
+                                 chip((slots,), jnp.bool_), cfg, attn_len).compile()
+    state_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(state))
+    assert state_bytes > 2.1e9
+    assert c.memory_analysis().alias_size_in_bytes >= state_bytes
+    _assert_cache_stays_put(c, state["latent"])
 
 
 def test_prefill_slots_compiles_at_b1(chip):

@@ -128,6 +128,40 @@ def test_compiles_are_spans_named_by_program():
     assert any("prefill_slots" in k for k in by_fun), sorted(by_fun)
 
 
+def test_a_programs_own_trace_survives_and_the_inner_ones_do_not():
+    """jax reports a trace for every jitted function it meets inside a
+    program (each `jnp` call, each nested jit); `record_compiles` keeps the
+    one named like the module lowered next, and a program compiles to
+    exactly one trace, one lower and one compile span."""
+    tracing.record_compiles()
+
+    @jax.jit
+    def inner_program(x):
+        return jnp.tanh(x) * 2.0
+
+    @jax.jit
+    def outer_program(x):
+        return jnp.sum(inner_program(jnp.sin(x)) + jnp.where(x > 0, x, 0.0))
+
+    x7, x3 = jnp.ones((7,)), jnp.ones((3,))   # programs of their own: before
+    tracing.clear()
+    outer_program(x7).block_until_ready()
+    compiles = [e["args"] for e in tracing.get_events() if e["name"] == "xla.compile"]
+    traces = [a["fun_name"] for a in compiles if a["event"] == "jaxpr_trace_duration"]
+    assert traces == ["outer_program"], traces
+    own = [a["event"] for a in compiles if "outer_program" in a["fun_name"]]
+    assert sorted(own) == ["backend_compile_duration", "jaxpr_to_mlir_module_duration",
+                           "jaxpr_trace_duration"], compiles
+    assert not any("inner_program" in a["fun_name"] or a["fun_name"] in ("sin", "tanh")
+                   for a in compiles), compiles
+    # a second, separate program after it is kept as well
+    inner_program(x3).block_until_ready()
+    traces = [e["args"]["fun_name"] for e in tracing.get_events()
+              if e["name"] == "xla.compile"
+              and e["args"]["event"] == "jaxpr_trace_duration"]
+    assert traces == ["outer_program", "inner_program"], traces
+
+
 def test_span_sits_on_the_profilers_host_plane(tmp_path):
     """One clock with the device trace: inside a profiler session the
     program's spans are TraceAnnotations of the same `.xplane.pb`."""
