@@ -456,7 +456,8 @@ class HybridCache:
             params, self.state, lengths, tokens, active, self.cfg, attn_len)
         return lengths, nxt, report
 
-    def step_args(self, n_active: int, live_rows: int) -> Dict[str, int]:
+    def step_args(self, n_active: int, live_rows: int,
+                  attn_len: int) -> Dict[str, int]:
         """What one decode step moved, known on the host at dispatch."""
         return {"state_slots": n_active if self.n_kda else 0,
                 "latent_rows": live_rows if self.n_mla else 0}

@@ -22,8 +22,10 @@ loop is built to run at device speed:
     the default layout. `tests/test_chip_compile.py` asks the chip's
     compiler; buffer pointers on the CPU alias either way;
   * attention reads a power-of-2 *bucket* of the cache (compiled once per
-    bucket) instead of all max_len rows, so short sequences pay for the
-    cache they use;
+    bucket) instead of all max_len rows, and on the TPU, within the bucket,
+    only each slot's blocks of rows that hold tokens
+    (`ops.pallas.decode_attention`; an idle slot has length 0 and costs
+    nothing), so a step pays for the cache in use;
   * `step()` runs one step of *lookahead*: it dispatches step N+1 before
     syncing step N's tokens, so host bookkeeping (EOS/finish/admit, slot
     accounting) overlaps device compute — at the cost of one junk slot-step
@@ -71,6 +73,7 @@ from ray_tpu.models.transformer import (ModelConfig, _deq_tree,
                                         _embed_lookup, lm_head_weights)
 from ray_tpu.ops.cache import write_rows as _write_rows
 from ray_tpu.ops.layers import rms_norm, rotary_embedding
+from ray_tpu.ops.pallas import decode_attention
 from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
@@ -156,6 +159,11 @@ def _write_slots(k_all: jax.Array, v_all: jax.Array, lengths: jax.Array,
     return k_all, v_all, lengths, tokens
 
 
+def _zero_lengths(lengths: jax.Array, retired: jax.Array) -> jax.Array:
+    """lengths [B] with the `retired` [B] (bool) slots' set to 0: idle."""
+    return jnp.where(retired, 0, lengths)
+
+
 def _bucket_len(n: int, max_len: int) -> int:
     b = 8
     while b < n:
@@ -231,47 +239,69 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
 
     The caches are READ-ONLY inside the layer scan — a scan that carries
     the cache through its ys gets double-buffered by XLA even when the
-    final output aliases the input. Each layer reads its attention window
-    out of the whole cache, attention splits into (cache window) + (current
-    token's own K/V, which is not written yet — STRICT mask `< lengths`),
-    and the per-layer K/V rows are written afterwards, outside the scan.
+    final output aliases the input. Each layer reads its rows out of the
+    whole cache, attention splits into (cache rows) + (current token's own
+    K/V, which is not written yet — STRICT mask `< lengths`), and the
+    per-layer K/V rows are written afterwards, outside the scan.
 
     `attn_len` is the static attention window (a power-of-2 bucket >= every
-    active position): XLA compiles one executable per bucket and short
-    sequences stop paying O(max_len) attention.
+    active position): XLA compiles one executable per bucket. On the TPU it
+    is only the upper bound of the kernel's grid: each slot pays for the
+    blocks of rows it holds (`ops.pallas.decode_attention`), an idle slot
+    for none. On the CPU path every slot pays for the window.
 
-    Returns (k_all, v_all, lengths+1, next_tokens [B] int32) — the caller
-    keeps everything on device; only `next_tokens` is ever synced, one
-    step late. `tokens` is NOT donated (the lookahead pipeline reads step
-    N's token buffer after step N+1 is dispatched).
+    A slot with length 0 is IDLE: it computes its self term alone (finite
+    garbage nobody reads) and stays at 0. The engine zeroes a retired slot's
+    length (`ContinuousBatchingEngine._retire_slots`).
+
+    Returns (k_all, v_all, lengths + 1 where a slot holds something,
+    next_tokens [B] int32) — the caller keeps everything on device; only
+    `next_tokens` is ever synced, one step late. `tokens` is NOT donated
+    (the lookahead pipeline reads step N's token buffer after step N+1 is
+    dispatched).
     """
     B = tokens.shape[0]
     hd = cfg.head_dim
     rep = cfg.n_heads // cfg.n_kv_heads
     cos, sin = rotary_embedding(lengths[:, None], hd, cfg.rope_theta)
     x = _embed_lookup(params["embed"], tokens[:, None], cfg.dtype)  # [B,1,d]
-    mask = jnp.arange(attn_len)[None, :] < lengths[:, None]  # [B, attn_len]
-
-    win = (1, B, cfg.n_kv_heads, attn_len, hd)
+    # one algorithm, two executions, chosen by what the code can see (as
+    # `ops.attention.uses_flash_kernel` chooses): on a TPU at shapes that
+    # tile, a kernel that reads each slot's live rows out of the whole
+    # cache; elsewhere the einsums over the window of all slots
+    kernel = decode_attention.uses_decode_kernel(k_all, attn_len)
+    if kernel:
+        blocks = decode_attention.live_blocks(lengths, attn_len)
+    else:
+        mask = jnp.arange(attn_len)[None, :] < lengths[:, None]  # [B, attn_len]
+        win = (1, B, cfg.n_kv_heads, attn_len, hd)
 
     # named scopes are metadata only: they name the step's phases in a
     # device trace (`attention`, `mlp`, `cache_write`, `head`)
     def body(x, inputs):
         lp, layer = inputs
         lp = _deq_tree(lp, cfg.dtype)
-        # the layer's attention window, read straight out of the whole
-        # (loop-invariant) cache: one dynamic_slice fuses into the attention
-        # fusions; a scan over the caches followed by `[:, :, :attn_len]`
-        # made XLA:TPU copy the layer's whole [B, kvh, max_len, hd] first
-        k_win = jax.lax.dynamic_slice(k_all, (layer, 0, 0, 0, 0), win)[0]
-        v_win = jax.lax.dynamic_slice(v_all, (layer, 0, 0, 0, 0), win)[0]
         with jax.named_scope("attention"):
             h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            q, k, v = _project_qkv(cfg, lp, h, cos, sin)
-            q = q.transpose(0, 2, 1, 3)  # [B, h, 1, hd]
-            k_cur = k.transpose(0, 2, 1, 3)[:, :, 0].astype(cfg.dtype)  # [B,kvh,hd]
-            v_cur = v.transpose(0, 2, 1, 3)[:, :, 0].astype(cfg.dtype)
-            attn = _gqa_decode_attention(q, k_win, v_win, k_cur, v_cur, mask)
+            q, k, v = _project_qkv(cfg, lp, h, cos, sin)  # [B, 1, heads, hd]
+            k_cur = k[:, 0].astype(cfg.dtype)  # [B, kvh, hd]
+            v_cur = v[:, 0].astype(cfg.dtype)
+            if kernel:
+                # the cache goes in WHOLE, the layer as a scalar: a sliced
+                # window cannot fuse into a Mosaic call and would be copied
+                attn = decode_attention.gqa_decode_attention(
+                    q[:, 0].reshape(B, cfg.n_kv_heads, rep, hd), k_cur, v_cur,
+                    k_all, v_all, layer, blocks, attn_len)
+            else:
+                # the layer's window, read straight out of the whole
+                # (loop-invariant) cache: one dynamic_slice fuses into the
+                # attention fusions; a scan over the caches followed by
+                # `[:, :, :attn_len]` made XLA:TPU copy the layer's whole
+                # [B, kvh, max_len, hd] first
+                k_win = jax.lax.dynamic_slice(k_all, (layer, 0, 0, 0, 0), win)[0]
+                v_win = jax.lax.dynamic_slice(v_all, (layer, 0, 0, 0, 0), win)[0]
+                attn = _gqa_decode_attention(q.transpose(0, 2, 1, 3), k_win,
+                                             v_win, k_cur, v_cur, mask)
             attn = attn.reshape(B, 1, cfg.n_heads * hd)
             x = x + (attn @ lp["wo"]).astype(x.dtype)
         with jax.named_scope("mlp"):
@@ -288,7 +318,7 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = (x[:, 0] @ lm_head_weights(params, cfg)).astype(jnp.float32)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return k_all, v_all, lengths + 1, nxt
+    return k_all, v_all, lengths + (lengths > 0), nxt
 
 
 class DenseKVCache:
@@ -307,7 +337,8 @@ class DenseKVCache:
         max_prefill_batch(bucket)   None = any
         counters                    names of what `report` carries behind
                                     the tokens (summed into `engine.step`)
-        step_args(n_active, live_rows), prefill_args   span arguments
+        step_args(n_active, live_rows, attn_len), prefill_args
+                                    span arguments
 
     It calls the module's own jitted `prefill_slots`, `_write_slots` and
     `decode_step_fused`, so the dense model compiles to the programs it
@@ -342,8 +373,13 @@ class DenseKVCache:
             params, s["k"], s["v"], lengths, tokens, self.cfg, attn_len)
         return lengths, nxt, nxt
 
-    def step_args(self, n_active: int, live_rows: int) -> Dict[str, int]:
-        return {}
+    def step_args(self, n_active: int, live_rows: int,
+                  attn_len: int) -> Dict[str, int]:
+        """The rows that hold a token, which the step has to read, beside the
+        window of every slot to the deepest bucket, which it read up to
+        PR 28 (and still reads on the CPU path)."""
+        return {"live_rows": live_rows,
+                "window_rows": self.state["k"].shape[1] * attn_len}
 
 
 def _pow2(n: int) -> int:
@@ -421,6 +457,14 @@ class ContinuousBatchingEngine:
         self._attn_len = 0  # attention bucket of the last dispatched decode
         self._step_args: Dict[str, int] = {}  # the cache's, of that decode
         tracing.record_compiles()
+        # slots freed since the last step's end; their device lengths go to
+        # 0 there (`_retire_slots`). Compiled HERE, ahead of time: a caller
+        # that warms the step programs knows nothing of this one, and a
+        # compiled executable cannot compile again under load whatever the
+        # placement of the `lengths` it is handed
+        self._retired: List[int] = []
+        self._zero_lengths = jax.jit(_zero_lengths).lower(
+            self.lengths, jax.ShapeDtypeStruct((num_slots,), jnp.bool_)).compile()
 
     # the dense cache's two arrays by their old names (callers that warm or
     # inspect them: the benchmark's replica, tests)
@@ -466,6 +510,7 @@ class ContinuousBatchingEngine:
             if req.slot >= 0:
                 self._active.pop(req.slot, None)
                 self._free.append(req.slot)
+                self._retired.append(req.slot)
                 self._slot_pos[req.slot] = 0
                 req.slot = -1
             self._finished[req.request_id] = req
@@ -523,6 +568,7 @@ class ContinuousBatchingEngine:
             # the model's counters ride the token array, so they are those
             # of the step reaped here: the one dispatched a step earlier
             did.update(self._reap(prev))                  # device wait, no _lock
+            self._retire_slots()                          # device enqueue only
             with self._lock:
                 return len(self._active) + len(self._waiting)
 
@@ -587,12 +633,26 @@ class ContinuousBatchingEngine:
         slot_map = dict(self._active)
         self._attn_len = attn_len
         self._step_args = self.cache.step_args(
-            len(slot_map), sum(self._slot_pos[s] for s in slot_map))
+            len(slot_map), sum(self._slot_pos[s] for s in slot_map), attn_len)
         self.lengths, self.tokens, report = self.cache.decode(
             self.params, self.lengths, self.tokens, attn_len, slot_map)
         for s in slot_map:
             self._slot_pos[s] += 1
         return report, slot_map
+
+    def _retire_slots(self) -> None:
+        """Set the device length of every slot freed in this step to 0, so
+        that the decode step stops reading its rows (an idle slot has
+        length 0; the step dispatched above still read them: harmless).
+        Runs in the stepper, after the step's own dispatches and before the
+        next step's admissions, so it never lands after the `write` that
+        gives a freed slot its next prompt."""
+        with self._lock:
+            freed, self._retired = self._retired, []
+        if freed:
+            retired = np.zeros((self.num_slots,), bool)
+            retired[freed] = True
+            self.lengths = self._zero_lengths(self.lengths, retired)
 
     def _drain_pending_first(self) -> None:
         """Sync admissions' on-device first tokens (deferred from dispatch

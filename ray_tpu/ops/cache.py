@@ -21,8 +21,10 @@ def write_rows(cache: jax.Array, rows: jax.Array,
     before and after the write (`copy.58/61/64/65` up to PR 26); a
     whole-tile window keeps the default layout and the update stays in place.
 
-    A slot whose position is at or past max_len (an idle slot keeps
-    counting) writes nothing."""
+    A slot whose position is at or past max_len writes nothing. An idle
+    slot of the dense engine has length 0 and stays there (its row 0 is
+    rewritten every step and replaced whole at the next admission); the
+    hybrid model's idle slots count on from 0."""
     L, B, kvh, max_len, hd = cache.shape
     R = min(32 // cache.dtype.itemsize, max_len)
     row_ids = jnp.arange(R)[:, None]

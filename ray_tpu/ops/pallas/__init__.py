@@ -3,7 +3,8 @@
 The reference (hellofinch/ray) ships no kernels of its own — GPU math is
 delegated to torch/NCCL (SURVEY.md §2.4). On TPU the equivalent hot-path
 ownership is these Mosaic kernels: fused RMSNorm, flash attention with
-online softmax, blockwise cross-entropy, and int8 quantization.
+online softmax, length-aware decode attention over the slot cache,
+blockwise cross-entropy, and int8 quantization.
 
 Every kernel runs under `interpret=True` off-TPU so the full test suite
 exercises kernel math on the CI CPU mesh.

@@ -163,6 +163,19 @@ def test_adamw_leaf_update_compiles(chip):
     assert _kernels(c) == 1
 
 
+def _assert_decode_step_reads_live_rows_in_place(c, kv, n_kernels=1):
+    """The decode step on the chip: the length-aware attention kernel is in
+    it (ONE Mosaic call, inside the layer scan; Mosaic has then accepted its
+    blocks and its VMEM, or `.compile()` would have raised), both donated
+    caches alias their outputs, no instruction relays a whole cache out, and
+    the step's temporaries stay under one layer of one cache: the kernel
+    takes the caches WHOLE, so nothing stands between the donated parameter
+    and its consumer for XLA:TPU to copy."""
+    assert _kernels(c) == n_kernels
+    assert c.memory_analysis().alias_size_in_bytes >= 2 * (kv.size * 2)
+    _assert_cache_stays_put(c, kv, _one_layer_bytes(kv))
+
+
 @pytest.mark.parametrize("attn_len", [64, 512])
 def test_decode_step_fused_compiles_at_b1_8_slots(chip, attn_len):
     from ray_tpu.models.serving import decode_step_fused
@@ -172,11 +185,9 @@ def test_decode_step_fused_compiles_at_b1_8_slots(chip, attn_len):
     kv = chip((B1.n_layers, slots, B1.n_kv_heads, max_len, B1.head_dim))
     ints = chip((slots,), jnp.int32)
     c = decode_step_fused.lower(params, kv, kv, ints, ints, B1, attn_len).compile()
-    m = c.memory_analysis()
     # weights + both caches in, caches updated in place (donated)
-    assert m.argument_size_in_bytes < 3 * 2**30
-    assert m.alias_size_in_bytes >= 2 * (kv.size * 2)
-    _assert_cache_stays_put(c, kv, _one_layer_bytes(kv))
+    assert c.memory_analysis().argument_size_in_bytes < 3 * 2**30
+    _assert_decode_step_reads_live_rows_in_place(c, kv)
 
 
 # InternLM2-1.8B as the serving cell runs it (perfbench/configs/internlm2-1.8b.json)
@@ -205,7 +216,11 @@ def test_decode_step_keeps_the_cache_layout(chip, attn_len):
     `dynamic-update-slice` in the default layout. With that alone the
     buckets under max_len still fail here, on `temp` 0.068 GB: one layer's
     [32,8,1024,128] copied to read `[:, :, :attn_len]` of it; the window
-    read by one `dynamic_slice` leaves 0.0006 GB."""
+    read by one `dynamic_slice` leaves 0.0006 GB. Since PR 29 the step reads
+    each slot's live rows through a Mosaic kernel (the `chip` fixture steers
+    the kernel branch on) that takes the whole caches and the layer index:
+    a window sliced out first could not fuse into the call and would be
+    copied, [32,8,attn_len,128] twice a layer."""
     from ray_tpu.models.serving import decode_step_fused
 
     params = _param_shapes(chip, INTERNLM2)
@@ -213,8 +228,25 @@ def test_decode_step_keeps_the_cache_layout(chip, attn_len):
     ints = chip((CELL_SLOTS,), jnp.int32)
     c = decode_step_fused.lower(params, kv, kv, ints, ints, INTERNLM2,
                                 attn_len).compile()
-    assert c.memory_analysis().alias_size_in_bytes >= 2 * (kv.size * 2)
-    _assert_cache_stays_put(c, kv, _one_layer_bytes(kv))
+    _assert_decode_step_reads_live_rows_in_place(c, kv)
+
+
+def test_decode_step_off_the_kernels_shapes_keeps_the_cache_layout(chip):
+    """A window that does not tile into the kernel's blocks (a max_len that
+    is no multiple of them) takes the einsums over one `dynamic_slice` of
+    the whole cache, as every window did up to PR 28: no Mosaic call, and
+    still no copy of a cache or of a layer of one."""
+    from ray_tpu.models.serving import decode_step_fused
+    from ray_tpu.ops.pallas import decode_attention
+
+    max_len = 1000
+    kv = chip((INTERNLM2.n_layers, CELL_SLOTS, INTERNLM2.n_kv_heads, max_len,
+               INTERNLM2.head_dim))
+    assert not decode_attention.uses_decode_kernel(kv, max_len)
+    ints = chip((CELL_SLOTS,), jnp.int32)
+    c = decode_step_fused.lower(_param_shapes(chip, INTERNLM2), kv, kv, ints,
+                                ints, INTERNLM2, max_len).compile()
+    _assert_decode_step_reads_live_rows_in_place(c, kv, n_kernels=0)
 
 
 def test_write_slots_keeps_the_cache_layout(chip):

@@ -104,6 +104,15 @@ def test_step_spans_count_the_slots_they_dispatched():
         [e for e in spans if e["name"] == "engine.prefill_dispatch"])
     assert all(s["args"]["attn_len"] == 64 for s in steps
                if s["args"]["active"])
+    # the dense cache's own arguments: the rows that hold a token, which
+    # the step has to read, beside the window of every slot it replaces.
+    # Every prompt here has 2 tokens, so a slot's k-th dispatch holds 1 + k
+    # rows: each request adds 2 + 3 + ... + (asked + 1)
+    dispatched = [s["args"] for s in steps if s["args"]["active"]]
+    assert all(a["window_rows"] == 4 * 64 for a in dispatched)
+    assert all(0 < a["live_rows"] <= a["window_rows"] for a in dispatched)
+    assert sum(a["live_rows"] for a in dispatched) == sum(
+        sum(range(2, n + 2)) for n in asked)
     # every device wait lies inside a step: host time = step - its waits
     waits = [e for e in spans if e["name"] == "engine.wait_device"]
     assert waits and {w["args"]["what"] for w in waits} == {"first", "decode"}

@@ -299,3 +299,70 @@ def test_driver_mode_concurrent_generates():
         assert [5, 6] + streamed == _reference([5, 6], 5)
     finally:
         eng.stop_driver()
+
+
+def _device_lengths(eng):
+    return np.asarray(eng.lengths).tolist()
+
+
+def test_a_retired_slot_has_length_zero_on_the_next_step():
+    """An idle slot has length 0 (the decode step reads none of its rows
+    and leaves it at 0); the engine zeroes a slot's device length at the end
+    of the step that frees it."""
+    eng = ContinuousBatchingEngine(PARAMS, CFG, num_slots=3, max_len=MAX_LEN)
+    short = eng.submit([1, 2, 3], max_new_tokens=3)
+    eng.submit([4, 5, 6, 7, 8], max_new_tokens=12)
+    seen_freed = False
+    for _ in range(20):
+        left = eng.step()
+        lengths = _device_lengths(eng)
+        for slot in range(eng.num_slots):
+            # busy: the host's shadow position IS the device length; idle
+            # (never used, or freed in this very step): 0, and it stays 0
+            want = eng._slot_pos[slot] if slot in eng._active else 0
+            assert lengths[slot] == want, (slot, lengths, eng._slot_pos)
+        if eng.result(short) is not None and left:
+            seen_freed = True  # one retired, one still decoding beside it
+        if not left:
+            break
+    assert seen_freed and _device_lengths(eng) == [0, 0, 0]
+
+
+def test_a_slot_freed_and_readmitted_at_once_gets_the_new_prompts_length():
+    """The retire program is dispatched at the end of the step that frees a
+    slot, the next prompt's rows in the step after: the zero never lands on
+    the new request."""
+    eng = ContinuousBatchingEngine(PARAMS, CFG, num_slots=1, max_len=MAX_LEN)
+    first = eng.submit([1, 2, 3], max_new_tokens=2)
+    prompt = list(range(20, 31))
+    second = eng.submit(prompt, max_new_tokens=6)  # waits for the one slot
+    while eng.result(first) is None:
+        eng.step()
+    assert _device_lengths(eng) == [0] and 0 not in eng._active
+    eng.step()  # admits `second` into the slot freed a step ago
+    assert 0 in eng._active
+    assert _device_lengths(eng) == [eng._slot_pos[0]] and eng._slot_pos[0] >= len(prompt)
+    eng.run_until_done()
+    assert eng.result(second) == _reference(prompt, 6)
+    assert _device_lengths(eng) == [0]
+
+
+def test_mixed_batch_answers_are_those_of_the_tree_before_the_idle_length_rule():
+    """Greedy answers of a batch that joins, retires and re-admits while
+    others decode and slots sit idle: token for token what the parent of
+    PR 29 gave on this (CPU, einsum) path, recorded there."""
+    prompts = [[1, 2, 3], [100, 200, 300, 400, 17], [7], list(range(20, 31)),
+               [9, 8], [33] * 20, [5, 17, 400, 3]]
+    news = [10, 3, 6, 12, 1, 7, 5]
+    parent = [[200, 444, 312, 428, 335, 261, 261, 261, 261, 99], [60, 340, 382],
+              [122, 408, 122, 408, 205, 205],
+              [396, 479, 479, 479, 479, 479, 479, 479, 479, 136, 479, 479], [134],
+              [390, 77, 442, 375, 411, 436, 390], [100, 100, 100, 394, 478]]
+    eng = ContinuousBatchingEngine(PARAMS, CFG, num_slots=4, max_len=MAX_LEN)
+    rids = []
+    for i, (p, n) in enumerate(zip(prompts, news)):
+        rids.append(eng.submit(p, max_new_tokens=n))
+        if i % 2:
+            eng.step()
+    eng.run_until_done()
+    assert [eng.result(r)[len(p):] for r, p in zip(rids, prompts)] == parent
