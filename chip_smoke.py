@@ -125,7 +125,7 @@ def train_loop(config):
         dev = dev[:config["chips"]]
         out["device_count"] = len(dev)
 
-    # Does block_until_ready block here? (bench.py times around it.)
+    # Does block_until_ready block here? (perfbench times around it.)
     n = 256 if rehearsal else 8192
     x = jnp.ones((n, n), jnp.bfloat16)
     f = jax.jit(lambda a: (a @ a) * 1e-4)
@@ -447,7 +447,7 @@ def run_train(args, chips: int, mesh: dict, label: str,
     return rep
 
 
-TRAIN_BATCH, TRAIN_STEPS = 2, 5  # bench.py's b1: 2 sequences of 2048; it fits
+TRAIN_BATCH, TRAIN_STEPS = 2, 5  # b1 at 2 sequences of 2048; it fits
 PROMPT_LENS = (16, 40, 97, 150, 223, 300)
 NEW_TOKENS = 32
 TIE_MARGIN = 1e-2  # logit gap under which the engine may take the other token

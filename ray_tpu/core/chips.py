@@ -1,6 +1,6 @@
 """What a worker that was spawned for a chip grant is told about the chip:
 which chips its libtpu may open, and where its compiled programs are kept.
-Imports nothing heavy — the raylet, `bench.py` and `chip_smoke.py`'s parent
+Imports nothing heavy — the raylet and `chip_smoke.py`'s parent
 (which must stay off jax) all call it."""
 
 from __future__ import annotations

@@ -16,29 +16,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.transformer import (ModelConfig, _deq_tree,
-                                        _embed_lookup, lm_head_weights)
-from ray_tpu.ops.layers import apply_rotary, rms_norm, rotary_embedding, swiglu
-
-
-def _project_qkv(cfg: ModelConfig, p, x, cos, sin):
-    b, s, _ = x.shape
-    hd = cfg.head_dim
-    q = (x @ p["wq"]).reshape(b, s, cfg.n_heads, hd)
-    k = (x @ p["wk"]).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (x @ p["wv"]).reshape(b, s, cfg.n_kv_heads, hd)
-    q = apply_rotary(q, cos, sin)
-    k = apply_rotary(k, cos, sin)
-    return q, k, v
-
-
-def _mlp(cfg: ModelConfig, p, h):
-    if cfg.n_experts > 0:
-        from ray_tpu.ops.moe import moe_ffn
-
-        out, _ = moe_ffn(h, p["router"], p["w_gate"], p["w_up"], p["w_down"],
-                         cfg.capacity_factor)
-        return out
-    return swiglu(h @ p["w_gate"], h @ p["w_up"]) @ p["w_down"]
+                                        _embed_lookup, _mlp, _project_qkv,
+                                        lm_head_weights)
+from ray_tpu.ops.layers import rms_norm, rotary_embedding
 
 
 def _gqa_decode_attention(q, k_cache, v_cache, k_cur, v_cur, mask):
@@ -105,8 +85,7 @@ def prefill(params: Dict, tokens: jax.Array, cfg: ModelConfig,
     def body(x, lp):
         lp = _deq_tree(lp, cfg.dtype)
         with jax.named_scope("attention"):
-            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            q, k, v = _project_qkv(cfg, lp, h, cos, sin)
+            q, k, v = _project_qkv(cfg, lp, x, cos, sin)
             q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         with jax.named_scope("cache_write"):
             k_cache = jnp.zeros((b, cfg.n_kv_heads, max_len, hd), cfg.dtype)
@@ -118,8 +97,7 @@ def prefill(params: Dict, tokens: jax.Array, cfg: ModelConfig,
             attn = attn.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * hd)
             x = x + (attn @ lp["wo"]).astype(x.dtype)
         with jax.named_scope("mlp"):
-            h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            x = x + _mlp(cfg, lp, h2).astype(x.dtype)
+            x = x + _mlp(cfg, lp, x)[0].astype(x.dtype)
         return x, (k_cache, v_cache)
 
     x, (k_all, v_all) = jax.lax.scan(body, x, params["layers"])
@@ -151,8 +129,7 @@ def decode_step(params: Dict, cache: Dict, token: jax.Array,
     def body(x, inputs):
         lp, k_cache, v_cache = inputs
         lp = _deq_tree(lp, cfg.dtype)
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = _project_qkv(cfg, lp, h, cos, sin)
+        q, k, v = _project_qkv(cfg, lp, x, cos, sin)
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         k_cache = jax.lax.dynamic_update_slice(
             k_cache, k.astype(cfg.dtype), (0, 0, pos, 0))
@@ -161,8 +138,7 @@ def decode_step(params: Dict, cache: Dict, token: jax.Array,
         attn = _masked_attention(q, k_cache, v_cache, mask)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, 1, cfg.n_heads * hd)
         x = x + (attn @ lp["wo"]).astype(x.dtype)
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + _mlp(cfg, lp, h2).astype(x.dtype)
+        x = x + _mlp(cfg, lp, x)[0].astype(x.dtype)
         return x, (k_cache, v_cache)
 
     x, (k_all, v_all) = jax.lax.scan(

@@ -67,10 +67,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models.inference import (_gqa_decode_attention, _masked_attention,
-                                      _mlp, _project_qkv)
+from ray_tpu.models.inference import _gqa_decode_attention
 from ray_tpu.models.transformer import (ModelConfig, _deq_tree,
-                                        _embed_lookup, lm_head_weights)
+                                        _embed_lookup, _mlp, _project_qkv,
+                                        lm_head_weights)
 from ray_tpu.ops.cache import write_rows as _write_rows
 from ray_tpu.ops.layers import rms_norm, rotary_embedding
 from ray_tpu.ops.pallas import decode_attention
@@ -180,53 +180,6 @@ def _attn_bucket(pos: int, max_len: int) -> int:
     return min(b, max_len)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def decode_slots(params: Dict, k_all: jax.Array, v_all: jax.Array,
-                 lengths: jax.Array, tokens: jax.Array, cfg: ModelConfig):
-    """One decode step over all slots with per-slot positions (legacy
-    entry: returns host-visible logits and NON-donated caches — the engine
-    uses `decode_step_fused`; this stays for callers that need logits).
-
-    k_all/v_all: [L, B, kvh, max_len, hd]; lengths [B] (current position per
-    slot); tokens [B] (last sampled token per slot). Returns (logits [B, V],
-    new k_all, new v_all). Inactive slots compute garbage harmlessly.
-    """
-    B = tokens.shape[0]
-    hd = cfg.head_dim
-    max_len = k_all.shape[-2]
-    cos, sin = rotary_embedding(lengths[:, None], hd, cfg.rope_theta)  # [B,1,hd/2]
-    x = _embed_lookup(params["embed"], tokens[:, None], cfg.dtype)  # [B,1,d]
-    mask = jnp.arange(max_len)[None, None, :] <= lengths[:, None, None]  # [B,1,L]
-
-    def write_row(cache, new, pos):
-        # cache [kvh, max_len, hd] <- new [kvh, 1, hd] at position pos
-        return jax.lax.dynamic_update_slice(cache, new, (0, pos, 0))
-
-    def attend_mask(q, kc, vc, m):
-        # per-row mask variant of _masked_attention: m [1, max_len]
-        return _masked_attention(q[None], kc[None], vc[None], m)[0]
-
-    def body(x, inputs):
-        lp, k_cache, v_cache = inputs  # caches [B, kvh, max_len, hd]
-        lp = _deq_tree(lp, cfg.dtype)
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = _project_qkv(cfg, lp, h, cos, sin)
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        k_cache = jax.vmap(write_row)(k_cache, k.astype(cfg.dtype), lengths)
-        v_cache = jax.vmap(write_row)(v_cache, v.astype(cfg.dtype), lengths)
-        attn = jax.vmap(attend_mask)(q, k_cache, v_cache, mask)
-        attn = attn.transpose(0, 2, 1, 3).reshape(B, 1, cfg.n_heads * hd)
-        x = x + (attn @ lp["wo"]).astype(x.dtype)
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + _mlp(cfg, lp, h2).astype(x.dtype)
-        return x, (k_cache, v_cache)
-
-    x, (k_new, v_new) = jax.lax.scan(body, x, (params["layers"], k_all, v_all))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x[:, 0] @ lm_head_weights(params, cfg)).astype(jnp.float32)
-    return logits, k_new, v_new
-
-
 @functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
                    donate_argnums=(1, 2, 3))
 def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
@@ -282,8 +235,7 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
         lp, layer = inputs
         lp = _deq_tree(lp, cfg.dtype)
         with jax.named_scope("attention"):
-            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            q, k, v = _project_qkv(cfg, lp, h, cos, sin)  # [B, 1, heads, hd]
+            q, k, v = _project_qkv(cfg, lp, x, cos, sin)  # [B, 1, heads, hd]
             k_cur = k[:, 0].astype(cfg.dtype)  # [B, kvh, hd]
             v_cur = v[:, 0].astype(cfg.dtype)
             if kernel:
@@ -305,8 +257,7 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
             attn = attn.reshape(B, 1, cfg.n_heads * hd)
             x = x + (attn @ lp["wo"]).astype(x.dtype)
         with jax.named_scope("mlp"):
-            h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            x = x + _mlp(cfg, lp, h2).astype(x.dtype)
+            x = x + _mlp(cfg, lp, x)[0].astype(x.dtype)
         return x, (k_cur, v_cur)
 
     x, (k_cur, v_cur) = jax.lax.scan(

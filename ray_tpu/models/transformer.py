@@ -26,7 +26,6 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import (attention, attention_sharded,
                                    uses_flash_kernel)
@@ -45,23 +44,12 @@ class ModelConfig:
     rope_theta: float = 500000.0
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    # "none" | "full" | "dots" (selective) | "dots_sans_qkv" (dots minus the
-    # q/k/v saves — fits bigger models) | "dots_plus_attn" (dots plus the
-    # attention kernel output — no flash-fwd rerun in backward)
-    remat: str = "full"
+    remat: str = "full"          # "none" | "full" | "dots" (see maybe_remat)
     loss_chunk: int = 0          # >0: chunked cross-entropy (seq chunk size)
-    use_ring_attention: bool = False  # set when mesh sp > 1
     # sequence-parallel scheme when sp > 1: "ring" (K/V rotation via
     # ppermute) or "ulysses" (head<->seq all-to-all); "" = dense attention.
-    # use_ring_attention=True is kept as an alias for seq_parallel="ring".
     seq_parallel: str = ""
     tie_embeddings: bool = False
-    scan_unroll: int = 1         # lax.scan unroll over layers
-    # concatenate wq|wk|wv and w_gate|w_up at trace time so each pair of
-    # projections is one MXU matmul (params stay separate leaves — the
-    # concat is a per-layer 16 MB re-layout XLA schedules off the critical
-    # path; the backward then emits one fused dx/dW per group)
-    fused_proj: bool = False
     # Mixture of Experts: n_experts > 0 replaces the dense FFN with a
     # top-2-gated MoE (ops/moe.py); experts shard over the "expert" axis.
     n_experts: int = 0
@@ -227,34 +215,43 @@ def _embed_lookup(emb: Any, tokens: jax.Array, dtype) -> jax.Array:
     return emb[tokens].astype(dtype)
 
 
-def _attn_half(cfg: ModelConfig, mesh, x, p, cos, sin):
-    """Attention sub-block: x + Wo(attn(rotary(qkv(rmsnorm(x)))))."""
-    p = _deq_tree(p, cfg.dtype)
-    b, s, d = x.shape
+def _project_qkv(cfg: ModelConfig, p, x, cos, sin):
+    """rmsnorm(x) -> q [b, s, heads, hd], k, v [b, s, kv_heads, hd], q and k
+    rotated. The one spelling of the block's projections: training, prefill,
+    the reference decode and the engine's decode step call it; what they do
+    with q, k and v (the mixer) is their own."""
+    b, s, _ = x.shape
     hd = cfg.head_dim
-
     h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-    nq_d, nkv_d = cfg.n_heads * hd, cfg.n_kv_heads * hd
-    if cfg.fused_proj:
-        qkv = h @ jnp.concatenate([p["wq"], p["wk"], p["wv"]], axis=1)
-        q, k, v = (qkv[..., :nq_d], qkv[..., nq_d:nq_d + nkv_d],
-                   qkv[..., nq_d + nkv_d:])
-    else:
-        q, k, v = h @ p["wq"], h @ p["wk"], h @ p["wv"]
+    q, k, v = h @ p["wq"], h @ p["wk"], h @ p["wv"]
     q = q.reshape(b, s, cfg.n_heads, hd)
     k = k.reshape(b, s, cfg.n_kv_heads, hd)
     v = v.reshape(b, s, cfg.n_kv_heads, hd)
-    q, k, v = (checkpoint_name(t, n) for t, n in
-               ((q, "qkv_q"), (k, "qkv_k"), (v, "qkv_v")))
     q = apply_rotary(q, cos, sin)
     k = apply_rotary(k, cos, sin)
-    sp_scheme = cfg.seq_parallel or ("ring" if cfg.use_ring_attention else "")
-    # [b, heads, s, hd]. (A packed [b, s, h*hd] path through
-    # ops.attention_packed avoids these transposes, but measured ~1%
-    # SLOWER end-to-end at b1 shapes on v5e: the per-head strided block
-    # DMA costs more than the dense transposes it removes.)
+    return q, k, v
+
+
+def _mlp(cfg: ModelConfig, p, x):
+    """rmsnorm(x) -> the FFN's output (no residual) and the experts'
+    auxiliary loss (None for the dense FFN; only training reads it)."""
+    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+    if cfg.n_experts > 0:
+        from ray_tpu.ops.moe import moe_ffn
+
+        return moe_ffn(h, p["router"], p["w_gate"], p["w_up"], p["w_down"],
+                       cfg.capacity_factor)
+    return swiglu(h @ p["w_gate"], h @ p["w_up"]) @ p["w_down"], None
+
+
+def _attn_half(cfg: ModelConfig, mesh, x, p, cos, sin):
+    """Attention sub-block: x + Wo(attn(rotary(qkv(rmsnorm(x)))))."""
+    p = _deq_tree(p, cfg.dtype)
+    b, s, _ = x.shape
+    q, k, v = _project_qkv(cfg, p, x, cos, sin)
+    # [b, heads, s, hd]
     q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-    if sp_scheme == "ring":
+    if cfg.seq_parallel == "ring":
         from ray_tpu.ops.ring_attention import ring_attention_sharded
 
         rep = cfg.n_heads // cfg.n_kv_heads
@@ -262,76 +259,41 @@ def _attn_half(cfg: ModelConfig, mesh, x, p, cos, sin):
             k = jnp.repeat(k, rep, axis=1)
             v = jnp.repeat(v, rep, axis=1)
         attn = ring_attention_sharded(mesh, q, k, v, causal=True)
-    elif sp_scheme == "ulysses":
+    elif cfg.seq_parallel == "ulysses":
         # GQA expansion happens inside the kernel, after the all-to-all —
         # KV heads cross ICI unexpanded
         from ray_tpu.ops.ulysses import ulysses_attention_sharded
 
         attn = ulysses_attention_sharded(mesh, q, k, v, causal=True)
-    elif sp_scheme:
-        raise ValueError(f"unknown seq_parallel scheme {sp_scheme!r}")
+    elif cfg.seq_parallel:
+        raise ValueError(f"unknown seq_parallel scheme {cfg.seq_parallel!r}")
     elif mesh is not None and mesh.size > 1 and uses_flash_kernel(q):
         attn = attention_sharded(mesh, q, k, v, causal=True)
     else:
         attn = attention(q, k, v, causal=True)
-    attn = checkpoint_name(
-        attn.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * hd), "attn_out")
-    return x + checkpoint_name((attn @ p["wo"]).astype(x.dtype), "attn_proj")
+    attn = attn.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.head_dim)
+    return x + (attn @ p["wo"]).astype(x.dtype)
 
 
 def _layer(cfg: ModelConfig, mesh, x, layer_params, cos, sin):
     """One transformer block. x: [b, s, d] (s possibly sp-sharded)."""
     x = _attn_half(cfg, mesh, x, layer_params, cos, sin)
-    p = _deq_tree(layer_params, cfg.dtype)
-
-    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-    if cfg.n_experts > 0:
-        from ray_tpu.ops.moe import moe_ffn
-
-        out, aux = moe_ffn(h, p["router"], p["w_gate"], p["w_up"],
-                           p["w_down"], cfg.capacity_factor)
-        x = x + out.astype(x.dtype)
-        return x, aux
-    if cfg.fused_proj:
-        gu = h @ jnp.concatenate([p["w_gate"], p["w_up"]], axis=1)
-        gate, up = gu[..., :cfg.d_ff], gu[..., cfg.d_ff:]
-    else:
-        gate, up = h @ p["w_gate"], h @ p["w_up"]
-    h = swiglu(checkpoint_name(gate, "ffn_gate"),
-               checkpoint_name(up, "ffn_up"))
-    x = x + checkpoint_name((h @ p["w_down"]).astype(x.dtype), "ffn_down")
-    return x, jnp.zeros((), jnp.float32)
+    out, aux = _mlp(cfg, _deq_tree(layer_params, cfg.dtype), x)
+    return (x + out.astype(x.dtype),
+            jnp.zeros((), jnp.float32) if aux is None else aux)
 
 
 def maybe_remat(layer_fn, cfg: ModelConfig):
     """Wrap a layer body per cfg.remat: "full" recomputes everything in the
     backward pass; "dots" keeps matmul outputs resident and recomputes only
     the cheap elementwise/norm ops — most of full remat's memory win at a
-    fraction of its recompute FLOPs; "dots_sans_qkv" additionally drops the
-    q/k/v projections from the saved set (recomputing them costs ~2% of a
-    step — they're re-derived from the layer input the scan already keeps),
-    which is the difference between dots fitting or not for the ~1.2B
-    config on one 16G chip."""
+    fraction of its recompute FLOPs."""
     if cfg.remat == "full":
         return jax.checkpoint(layer_fn)
     if cfg.remat == "dots":
         return jax.checkpoint(
             layer_fn,
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    if cfg.remat == "dots_sans_qkv":
-        return jax.checkpoint(
-            layer_fn,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                "attn_proj", "ffn_gate", "ffn_up", "ffn_down"))
-    if cfg.remat == "dots_plus_attn":
-        # dots + the attention kernel output: the backward then never
-        # re-runs the flash forward kernel or the rotary/transpose chain —
-        # worth ~3% step time for one extra [b, s, d_model] save per layer.
-        return jax.checkpoint(
-            layer_fn,
-            policy=jax.checkpoint_policies.save_from_both_policies(
-                jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-                jax.checkpoint_policies.save_only_these_names("attn_out")))
     if cfg.remat != "none":
         raise ValueError(f"unknown remat mode {cfg.remat!r}")
     return layer_fn
@@ -353,9 +315,9 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
                               positions: Optional[jax.Array] = None, mesh=None):
     """tokens [b, s] -> (features [b, s, d] after final norm, moe_aux scalar).
 
-    `mesh` is required when any sequence-parallel scheme is active
-    (`cfg.seq_parallel` or `cfg.use_ring_attention` — the sp shard_map needs
-    it); everything else is pure sharding-annotation-driven SPMD.
+    `mesh` is required when a sequence-parallel scheme is active
+    (`cfg.seq_parallel`: the sp shard_map needs it); everything else is
+    pure sharding-annotation-driven SPMD.
     """
     if positions is None:
         positions = jnp.arange(tokens.shape[1])
@@ -366,7 +328,7 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
     if cfg.fused_attn and not cfg.fused_ffn:
         raise ValueError("fused_attn requires fused_ffn")
     if cfg.fused_ffn:
-        if cfg.n_experts > 0 or cfg.seq_parallel or cfg.use_ring_attention:
+        if cfg.n_experts > 0 or cfg.seq_parallel:
             raise ValueError("fused_ffn supports the dense, non-sp path only")
         from ray_tpu.ops.pallas.fused_ffn import ffn_block
 
@@ -395,11 +357,9 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
             return (x, aux + layer_aux), None
 
     (x, aux_total), _ = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), params["layers"],
-        unroll=cfg.scan_unroll)
+        body, (x, jnp.zeros((), jnp.float32)), params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, aux_total
-
 
 
 def forward_with_aux(params: Dict[str, Any], tokens: jax.Array, cfg: ModelConfig,
@@ -482,19 +442,17 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
     the sp axis for sequence parallelism. Optional {"loss_mask": [b, s]}.
     """
     inputs, targets, mask = split_batch(batch)
-    if cfg.loss_chunk:
-        if targets.shape[-1] % cfg.loss_chunk != 0:
-            raise ValueError(
-                f"loss_chunk={cfg.loss_chunk} must divide the target length "
-                f"{targets.shape[-1]} (note {{'tokens'}} batches lose one "
-                f"position to the shift)")
-        x, moe_aux = forward_features_with_aux(params, inputs, cfg, mesh=mesh)
-        with jax.named_scope("head_loss"):
+    if cfg.loss_chunk and targets.shape[-1] % cfg.loss_chunk != 0:
+        raise ValueError(
+            f"loss_chunk={cfg.loss_chunk} must divide the target length "
+            f"{targets.shape[-1]} (note {{'tokens'}} batches lose one "
+            f"position to the shift)")
+    x, moe_aux = forward_features_with_aux(params, inputs, cfg, mesh=mesh)
+    with jax.named_scope("head_loss"):
+        if cfg.loss_chunk:
             loss = chunked_token_nll(x, lm_head_weights(params, cfg),
                                      targets, mask, cfg.loss_chunk)
-    else:
-        x, moe_aux = forward_features_with_aux(params, inputs, cfg, mesh=mesh)
-        with jax.named_scope("head_loss"):
+        else:
             logits = (x @ lm_head_weights(params, cfg)).astype(jnp.float32)
             loss = token_nll(logits, targets, mask)
     if cfg.n_experts > 0:

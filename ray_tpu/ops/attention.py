@@ -97,37 +97,6 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return causal_attention_reference(q, k, v, sm_scale=scale, causal=causal)
 
 
-@functools.partial(jax.jit, static_argnames=("n_heads", "n_kv_heads",
-                                             "causal", "sm_scale"))
-def attention_packed(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                     n_heads: int, n_kv_heads: int, causal: bool = True,
-                     sm_scale: Optional[float] = None) -> jax.Array:
-    """Attention over packed [batch, seq, heads*head_dim] tensors — the
-    layout the q/k/v projections produce and the output projection consumes.
-
-    On TPU this runs the packed flash kernel (no [b,s,h,d]<->[b,h,s,d]
-    transposes, GQA k/v never expanded); elsewhere it falls back to the
-    reference einsum via free reshapes."""
-    b, sq, hd = q.shape
-    d = hd // n_heads
-    sk = k.shape[1]
-    scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
-    if _util.on_tpu() and d >= 128 and sq >= 128:
-        from ray_tpu.ops.pallas.flash_attention import flash_attention_packed
-
-        return flash_attention_packed(q, k, v, n_heads, n_kv_heads, scale,
-                                      causal, min(1024, sq), min(1024, sk),
-                                      min(1024, sq), min(512, sk))
-    q4 = q.reshape(b, sq, n_heads, d).transpose(0, 2, 1, 3)
-    k4 = k.reshape(b, sk, n_kv_heads, d).transpose(0, 2, 1, 3)
-    v4 = v.reshape(b, sk, n_kv_heads, d).transpose(0, 2, 1, 3)
-    n_rep = n_heads // n_kv_heads
-    out = causal_attention_reference(q4, _repeat_kv(k4, n_rep),
-                                     _repeat_kv(v4, n_rep),
-                                     sm_scale=scale, causal=causal)
-    return out.transpose(0, 2, 1, 3).reshape(b, sq, hd)
-
-
 def causal_attention_blocked(q: jax.Array, k: jax.Array, v: jax.Array, *,
                              sm_scale: float, q_block: int = 512) -> jax.Array:
     """Causal attention whose query/key width differs from its value width

@@ -37,10 +37,10 @@ _NEG_INF = -1e30
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
-                sq: int, sk: int, qdim: int = 1, kdim: int = 2):
-    i_q = pl.program_id(qdim)
-    i_k = pl.program_id(kdim)
-    n_k = pl.num_programs(kdim)
+                sq: int, sk: int):
+    i_q = pl.program_id(1)
+    i_k = pl.program_id(2)
+    n_k = pl.num_programs(2)
 
     @pl.when(i_k == 0)
     def _init():
@@ -180,17 +180,12 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    sm_scale, causal, block_q, block_k, sq, sk,
-                    kdim: int = 1, qdim: int = 2, n_qb: int | None = None):
-    """When n_qb is given (packed GQA layout), the innermost grid axis
-    enumerates e = r * n_qb + i_q over the n_rep query heads sharing this
-    key/value head — dk/dv accumulate across all of them."""
-    i_k = pl.program_id(kdim)
-    e = pl.program_id(qdim)
-    n_e = pl.num_programs(qdim)
-    i_q = e if n_qb is None else e % n_qb
+                    sm_scale, causal, block_q, block_k, sq, sk):
+    i_k = pl.program_id(1)
+    i_q = pl.program_id(2)
+    n_q = pl.num_programs(2)
 
-    @pl.when(e == 0)
+    @pl.when(i_q == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -215,7 +210,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(e == n_e - 1)
+    @pl.when(i_q == n_q - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -223,11 +218,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc, *,
-                   sm_scale, causal, block_q, block_k, sq, sk,
-                   qdim: int = 1, kdim: int = 2):
-    i_q = pl.program_id(qdim)
-    i_k = pl.program_id(kdim)
-    n_k = pl.num_programs(kdim)
+                   sm_scale, causal, block_q, block_k, sq, sk):
+    i_q = pl.program_id(1)
+    i_k = pl.program_id(2)
+    n_k = pl.num_programs(2)
 
     @pl.when(i_k == 0)
     def _init():
@@ -362,189 +356,3 @@ def _vjp_bwd(sm_scale, causal, block_q, block_k, block_q_bwd, block_k_bwd,
 
 
 flash_attention_pallas.defvjp(_vjp_fwd, _vjp_bwd)
-
-
-# ------------------------------------------------------- packed layout
-# Same kernels over [batch, seq, heads*head_dim] operands — the layout the
-# q/k/v projections naturally produce and the output projection consumes.
-# The head axis becomes a grid dimension whose index maps pick the head's
-# column slice, so the [b,s,h,d]<->[b,h,s,d] transposes disappear, and GQA
-# is an index-map division (each group of n_rep query heads reads the same
-# k/v head) instead of a materialized jnp.repeat.
-
-
-def _flash_fwd_packed(q, k, v, n_heads, n_kv, sm_scale, causal,
-                      block_q, block_k):
-    b, sq, hd = q.shape
-    d = hd // n_heads
-    sk = k.shape[1]
-    n_rep = n_heads // n_kv
-    bq = min(block_q, sq)
-    bk = min(block_k, sk)
-    grid = (b, n_heads, cdiv(sq, bq), cdiv(sk, bk))
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=bq, block_k=bk, sq=sq, sk=sk,
-                          qdim=2, kdim=3),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, h, i, j: (b, i, h),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, h, i, j: (b, j, h // n_rep),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, h, i, j: (b, j, h // n_rep),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, h, i, j: (b, i, h),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 8, bq), lambda b, h, i, j: (b, h, i),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, 8 * n_heads, sq), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-        ],
-        interpret=interpret_mode(),
-    )(q, k, v)
-
-
-def _flash_bwd_packed(q, k, v, o, lse, do, n_heads, n_kv, sm_scale, causal,
-                      block_q, block_k):
-    b, sq, hd = q.shape
-    d = hd // n_heads
-    sk = k.shape[1]
-    n_rep = n_heads // n_kv
-    bq = min(block_q, sq)
-    bk = min(block_k, sk)
-    n_qb = cdiv(sq, bq)
-    n_kb = cdiv(sk, bk)
-
-    # Δ = per-head rowsum(dO ⊙ O) in the [b, 8*heads, sq] broadcast layout.
-    prod = (do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
-        b, sq, n_heads, d).sum(-1)                       # [b, sq, h]
-    delta = jnp.broadcast_to(
-        prod.transpose(0, 2, 1)[:, :, None, :],          # [b, h, 1, sq]
-        (b, n_heads, 8, sq)).reshape(b, 8 * n_heads, sq)
-
-    kw = dict(sm_scale=sm_scale, causal=causal, block_q=bq, block_k=bk,
-              sq=sq, sk=sk)
-    rowspec_q = pl.BlockSpec((1, 8, bq), lambda b, h, i, j: (b, h, i),
-                             memory_space=pltpu.VMEM)
-
-    # dk/dv: one pass per kv head; the innermost axis enumerates
-    # e = r * n_qb + i_q over this kv head's n_rep query heads.
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **kw, kdim=2, qdim=3, n_qb=n_qb),
-        grid=(b, n_kv, n_kb, n_rep * n_qb),
-        in_specs=[
-            pl.BlockSpec((1, bq, d),
-                         lambda b, g, jk, e: (b, e % n_qb, g * n_rep + e // n_qb),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, g, jk, e: (b, jk, g),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, g, jk, e: (b, jk, g),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, d),
-                         lambda b, g, jk, e: (b, e % n_qb, g * n_rep + e // n_qb),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 8, bq),
-                         lambda b, g, jk, e: (b, g * n_rep + e // n_qb, e % n_qb),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 8, bq),
-                         lambda b, g, jk, e: (b, g * n_rep + e // n_qb, e % n_qb),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, g, jk, e: (b, jk, g),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, g, jk, e: (b, jk, g),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, sk, n_kv * d), k.dtype),
-            jax.ShapeDtypeStruct((b, sk, n_kv * d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        interpret=interpret_mode(),
-    )(q, k, v, do, lse, delta)
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **kw, qdim=2, kdim=3),
-        grid=(b, n_heads, n_qb, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, h, i, j: (b, i, h),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, h, i, j: (b, j, h // n_rep),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, h, i, j: (b, j, h // n_rep),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, d), lambda b, h, i, j: (b, i, h),
-                         memory_space=pltpu.VMEM),
-            rowspec_q,
-            rowspec_q,
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, h, i, j: (b, i, h),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b, sq, hd), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret_mode(),
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
-def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
-                           n_heads: int, n_kv_heads: int,
-                           sm_scale: float | None = None, causal: bool = True,
-                           block_q: int = 1024, block_k: int = 1024,
-                           block_q_bwd: int | None = 1024,
-                           block_k_bwd: int | None = 512) -> jax.Array:
-    """Flash attention over packed [batch, seq, heads*head_dim] tensors.
-
-    q: [b, s, n_heads*d]; k/v: [b, s, n_kv_heads*d]. Returns [b, s,
-    n_heads*d]. Avoids the head transpose entirely and keeps GQA k/v
-    unexpanded (the kernel's index maps route n_rep query heads to one
-    kv head)."""
-    if q.shape[-1] % n_heads or n_heads % n_kv_heads:
-        raise ValueError(
-            f"packed width {q.shape[-1]} must divide by n_heads={n_heads}, "
-            f"which must divide by n_kv_heads={n_kv_heads}")
-    d = q.shape[-1] // n_heads
-    if k.shape[-1] != n_kv_heads * d:
-        raise ValueError(f"k width {k.shape[-1]} != n_kv_heads*head_dim "
-                         f"{n_kv_heads * d}")
-    scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
-    out, _ = _flash_fwd_packed(q, k, v, n_heads, n_kv_heads, scale, causal,
-                               block_q, block_k)
-    return out
-
-
-def _vjp_fwd_packed(q, k, v, n_heads, n_kv_heads, sm_scale, causal,
-                    block_q, block_k, block_q_bwd, block_k_bwd):
-    d = q.shape[-1] // n_heads
-    scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
-    out, lse = _flash_fwd_packed(q, k, v, n_heads, n_kv_heads, scale, causal,
-                                 block_q, block_k)
-    return out, (q, k, v, out, lse)
-
-
-def _vjp_bwd_packed(n_heads, n_kv_heads, sm_scale, causal, block_q, block_k,
-                    block_q_bwd, block_k_bwd, res, g):
-    q, k, v, out, lse = res
-    d = q.shape[-1] // n_heads
-    scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
-    return _flash_bwd_packed(q, k, v, out, lse, g, n_heads, n_kv_heads,
-                             scale, causal,
-                             block_q_bwd or block_q, block_k_bwd or block_k)
-
-
-flash_attention_packed.defvjp(_vjp_fwd_packed, _vjp_bwd_packed)

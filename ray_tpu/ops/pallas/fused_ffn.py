@@ -1,21 +1,36 @@
 """Fused FFN block (rmsnorm -> gate/up -> swiglu -> down + residual) with a
-hand-written Pallas backward.
+hand-written backward.
 
 Why: BASELINE.md's r04 decomposition pinned the b1 MFU gap on backward-pass
 elementwise HBM traffic under dots remat — XLA's backward materializes the
 swiglu recompute, d_swiglu, and the re-normed hidden states as separate HBM
-round-trips between the dW/dx matmuls. Here the backward is four Pallas
-matmul kernels whose prologues/epilogues compute those elementwise chains
-on tiles already resident in VMEM:
+round-trips between the dW/dx matmuls. The backward here is written out by
+hand in four steps; each was measured as a Pallas kernel against its XLA
+expression (v5e, b1 shapes: batch 2 x 2048, d=2048, dff=8192; step time
+against the all-XLA custom backward's 243.2 ms), and each is what won:
 
-  K1  dW_down = swiglu(gate, up)^T @ dy          (swiglu fused as prologue)
-  K2  d_s = dy @ W_down^T ->                     (never hits HBM)
-      dgate = d_s * up * silu'(gate), dup = d_s * silu(gate)
-  K3  dW_gate = h^T @ dgate, dW_up = h^T @ dup   (h = x*rstd*nw recomputed
-                                                  as prologue, never stored)
-  (dh = dgate @ Wg^T + dup @ Wu^T and the rmsnorm VJP stay XLA — see the
-   note at the call-site: a Pallas variant re-read the weight panels per
-   row block and lost more than its fusion saved.)
+  1  dW_down = swiglu(gate, up)^T @ dy                              XLA
+     (Pallas, swiglu as the prologue: +16.0 ms at 512^3 tiles, +18.6 ms
+      with full-d N blocks: the retile removed the gate/up panel re-reads
+      and multiplied the dy panel's)
+  2  d_s = dy @ W_down^T; dgate = d_s * up * silu'(gate),
+     dup = d_s * silu(gate)                                         XLA
+     (Pallas, d_s never in HBM: +8.9 ms)
+  3  dW_gate = h^T @ dgate, dW_up = h^T @ dup                       Pallas
+     (h = x*rstd*nw recomputed as the prologue and never stored, two dots
+      sharing one operand panel: -6.3 ms against XLA's
+      materialize-then-matmul)
+  4  dh = dgate @ Wg^T + dup @ Wu^T and the rmsnorm VJP             XLA
+     (Pallas re-read the weight panels per row block and lost more than
+      its fusion saved; see the note at the call site)
+
+The custom_vjp itself is the main win: saving gate/up and hand-writing the
+backward beats autodiff under dots remat by ~7 ms with every step on XLA.
+
+Step 3's kernel runs where (T, d, dff) tile by its blocks; where they do not
+(a width that leaves 256 modulo 512) the same step is its XLA expression,
+which is also what the tests compare the kernel with. The code chooses from
+the shapes; nothing is set.
 
 The forward stays plain XLA (it already runs at ~93% of ideal). Residuals
 saved — x, rstd, gate, up — are the same set the `dots` remat policy keeps,
@@ -37,28 +52,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.pallas._util import interpret_mode
 
-# Tile sizes: 512 keeps the MXU busy with full 128-lane tiles while the
-# double-buffered operands of the widest kernel (K4's [d, bk] weight tiles)
-# stay inside the ~16 MB VMEM budget.
-_BM = 512
-_BN = 512
-_BK = 512
-
-# Per-kernel toggles (trace-time): each Pallas kernel has a semantically
-# identical XLA fallback in _vjp_bwd, so step-time attribution is a flag
-# flip + re-jit. Measured on v5e at b1 shapes (batch 2 x 2048, d=2048,
-# dff=8192), step time vs the all-XLA custom backward's 243.2 ms:
-#   K1 pallas +16.0 ms at 512^3 tiles and +18.6 ms with full-d N blocks
-#   (the retile removed the gate/up panel re-reads but multiplied the dy
-#   panel re-reads; both lose to XLA), K2 pallas +8.9 ms,
-#   K3 pallas -6.3 ms (the h-recompute prologue + two dots sharing one
-#   operand panel beat XLA's materialize-then-matmul).
-# Defaults = the measured winners. NOTE the custom_vjp itself is the main
-# win: saving gate/up and hand-writing the backward beats autodiff under
-# dots remat by ~7 ms even with every kernel on XLA.
-USE_K1 = False
-USE_K2 = False
-USE_K3 = True
+# Tile size of every block dimension: 512 keeps the MXU busy with full
+# 128-lane tiles while the double-buffered operands and the two f32
+# accumulators stay inside the ~16 MB VMEM budget.
+_BLOCK = 512
 
 
 def _silu(x):
@@ -71,51 +68,6 @@ def _dsilu(x):
 
 
 # ------------------------------------------------------------------ kernels
-
-
-def _dw_down_kernel(gate_ref, up_ref, dy_ref, out_ref, acc_ref):
-    """out[dff, d] += swiglu(gate, up)[t, dff]^T @ dy[t, d]; grid (i, k),
-    k (= token blocks) innermost, full-d output rows per block."""
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    g = gate_ref[:].astype(jnp.float32)
-    u = up_ref[:].astype(jnp.float32)
-    s = (_silu(g) * u).astype(dy_ref.dtype)          # [bk, bm]
-    acc_ref[:] += jax.lax.dot_general(
-        s, dy_ref[:], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # [bm, bn]
-
-    @pl.when(k == pl.num_programs(1) - 1)
-    def _():
-        out_ref[:] = acc_ref[:].astype(out_ref.dtype)
-
-
-def _dgateup_kernel(dy_ref, wd_ref, gate_ref, up_ref, dgate_ref, dup_ref,
-                    acc_ref):
-    """d_s = dy[t, d] @ W_down[dff, d]^T accumulated over d blocks (k
-    innermost); at the last k step the swiglu VJP runs on the VMEM tile and
-    only dgate/dup are written — d_s never exists in HBM."""
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    acc_ref[:] += jax.lax.dot_general(
-        dy_ref[:], wd_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)           # [bm, bn]
-
-    @pl.when(k == pl.num_programs(2) - 1)
-    def _():
-        ds = acc_ref[:]
-        g = gate_ref[:].astype(jnp.float32)
-        u = up_ref[:].astype(jnp.float32)
-        dgate_ref[:] = (ds * u * _dsilu(g)).astype(dgate_ref.dtype)
-        dup_ref[:] = (ds * _silu(g)).astype(dup_ref.dtype)
 
 
 def _dw_gateup_kernel(x_ref, rstd_ref, nw_ref, dgate_ref, dup_ref,
@@ -181,79 +133,26 @@ def _vjp_bwd(eps, res, dy):
     dy2d = dy.reshape(-1, d)
     T = x2d.shape[0]
     dff = wg.shape[1]
-    interp = interpret_mode()
 
-    bm, bn, bk = min(_BM, dff), min(_BN, d), min(_BK, T)
-    # The tiling constraint belongs to the Pallas kernels only: with every
-    # USE_K* flag turned off the backward is pure XLA and accepts any
-    # (T, d, dff) — rejecting non-tiling shapes at trace time used to break
-    # the all-XLA configuration for no reason. (USE_K3 defaults on, so the
-    # guard still fires out of the box.)
-    if (USE_K1 or USE_K2 or USE_K3) and (T % bk or dff % bm or d % bn):
-        raise ValueError(f"fused_ffn: shapes ({T}, {d}, {dff}) must tile by "
-                         f"({bk}, {bn}, {bm}) when a Pallas kernel "
-                         f"(USE_K1/K2/K3) is enabled")
+    # 1: dW_down [dff, d]
+    s_act = (_silu(gate.astype(jnp.float32))
+             * up.astype(jnp.float32)).astype(gate.dtype)
+    dwd = jax.lax.dot_general(
+        s_act, dy2d, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(wd.dtype)
 
-    # K1: dW_down [dff, d]. Full-d N blocks: the gate/up operand panels
-    # are fetched exactly once (the 512x512x512 variant re-read them per
-    # N block — +16 ms; this layout's only repeat is dy, dff/bm x 16 MB).
-    if not USE_K1:
-        s_act = (_silu(gate.astype(jnp.float32))
-                 * up.astype(jnp.float32)).astype(gate.dtype)
-        dwd = jax.lax.dot_general(
-            s_act, dy2d, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(wd.dtype)
-    else:
-      bm1, bk1 = min(256, dff), min(_BK, T)
-      dwd = pl.pallas_call(
-        _dw_down_kernel,
-        grid=(dff // bm1, T // bk1),
-        in_specs=[
-            pl.BlockSpec((bk1, bm1), lambda i, k: (k, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bk1, bm1), lambda i, k: (k, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bk1, d), lambda i, k: (k, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((bm1, d), lambda i, k: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((dff, d), wd.dtype),
-        scratch_shapes=[pltpu.VMEM((bm1, d), jnp.float32)],
-        interpret=interp,
-      )(gate, up, dy2d)
+    # 2: dgate/dup [T, dff]
+    ds = jax.lax.dot_general(dy2d, wd, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    gf = gate.astype(jnp.float32)
+    uf = up.astype(jnp.float32)
+    dgate = (ds * uf * _dsilu(gf)).astype(gate.dtype)
+    dup = (ds * _silu(gf)).astype(up.dtype)
 
-    # K2: dgate/dup [T, dff]
-    bm2, bn2, bk2 = min(_BM, T), min(_BN, dff), min(_BK, d)
-    if not USE_K2:
-        ds = jax.lax.dot_general(dy2d, wd, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        gf = gate.astype(jnp.float32)
-        uf = up.astype(jnp.float32)
-        dgate = (ds * uf * _dsilu(gf)).astype(gate.dtype)
-        dup = (ds * _silu(gf)).astype(up.dtype)
-    else:
-      dgate, dup = pl.pallas_call(
-        _dgateup_kernel,
-        grid=(T // bm2, dff // bn2, d // bk2),
-        in_specs=[
-            pl.BlockSpec((bm2, bk2), lambda i, j, k: (i, k), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bn2, bk2), lambda i, j, k: (j, k), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bm2, bn2), lambda i, j, k: (i, j), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bm2, bn2), lambda i, j, k: (i, j), memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm2, bn2), lambda i, j, k: (i, j), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bm2, bn2), lambda i, j, k: (i, j), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((T, dff), gate.dtype),
-            jax.ShapeDtypeStruct((T, dff), up.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((bm2, bn2), jnp.float32)],
-        interpret=interp,
-      )(dy2d, wd, gate, up)
-
-    # K3: dW_gate/dW_up [d, dff]
-    bm3, bn3, bk3 = min(_BM, d), min(_BN, dff), min(_BK, T)
-    if not USE_K3:
+    # 3: dW_gate/dW_up [d, dff]: the kernel where the shapes tile by its
+    # blocks, the same step in XLA where they do not
+    bm, bn, bk = min(_BLOCK, d), min(_BLOCK, dff), min(_BLOCK, T)
+    if d % bm or dff % bn or T % bk:
         h = (x2d.astype(jnp.float32) * rstd
              * nw.astype(jnp.float32)).astype(x2d.dtype)
         dwg = jax.lax.dot_general(h, dgate, (((0,), (0,)), ((), ())),
@@ -261,30 +160,30 @@ def _vjp_bwd(eps, res, dy):
         dwu = jax.lax.dot_general(h, dup, (((0,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32).astype(wu.dtype)
     else:
-      dwg, dwu = pl.pallas_call(
-        _dw_gateup_kernel,
-        grid=(d // bm3, dff // bn3, T // bk3),
-        in_specs=[
-            pl.BlockSpec((bk3, bm3), lambda i, j, k: (k, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bk3, 1), lambda i, j, k: (k, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bm3), lambda i, j, k: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bk3, bn3), lambda i, j, k: (k, j), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bk3, bn3), lambda i, j, k: (k, j), memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm3, bn3), lambda i, j, k: (i, j), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bm3, bn3), lambda i, j, k: (i, j), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((d, dff), wg.dtype),
-            jax.ShapeDtypeStruct((d, dff), wu.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((bm3, bn3), jnp.float32),
-                        pltpu.VMEM((bm3, bn3), jnp.float32)],
-        interpret=interp,
-      )(x2d, rstd, nw.reshape(1, -1), dgate, dup)
+        dwg, dwu = pl.pallas_call(
+            _dw_gateup_kernel,
+            grid=(d // bm, dff // bn, T // bk),
+            in_specs=[
+                pl.BlockSpec((bk, bm), lambda i, j, k: (k, i), memory_space=pltpu.VMEM),
+                pl.BlockSpec((bk, 1), lambda i, j, k: (k, 0), memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, bm), lambda i, j, k: (0, i), memory_space=pltpu.VMEM),
+                pl.BlockSpec((bk, bn), lambda i, j, k: (k, j), memory_space=pltpu.VMEM),
+                pl.BlockSpec((bk, bn), lambda i, j, k: (k, j), memory_space=pltpu.VMEM),
+            ],
+            out_specs=[
+                pl.BlockSpec((bm, bn), lambda i, j, k: (i, j), memory_space=pltpu.VMEM),
+                pl.BlockSpec((bm, bn), lambda i, j, k: (i, j), memory_space=pltpu.VMEM),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((d, dff), wg.dtype),
+                jax.ShapeDtypeStruct((d, dff), wu.dtype),
+            ],
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
+                            pltpu.VMEM((bm, bn), jnp.float32)],
+            interpret=interpret_mode(),
+        )(x2d, rstd, nw.reshape(1, -1), dgate, dup)
 
-    # Step 4 — dh matmuls + rmsnorm VJP — stays XLA: a measured Pallas
+    # 4: dh matmuls + rmsnorm VJP stay XLA: a measured Pallas
     # variant (full-d N blocks so the VJP row-reduction fits one tile) had
     # to re-read the [d, dff] weight panels once per 128-row block, ~2 GB
     # of extra HBM traffic per layer, and lost more than the elementwise

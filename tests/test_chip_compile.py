@@ -313,7 +313,7 @@ def test_prefill_slots_compiles_at_b1(chip):
 
 
 def _lower_b1_step(topo, *, chips, mesh, batch, seq, optimizer, fused):
-    """The whole b1 train step, as `chip_smoke.py` / `bench.py` build it,
+    """The whole b1 train step, as `chip_smoke.py` builds it,
     lowered for `chips` described devices. `.compile()` is the question."""
     import dataclasses
 

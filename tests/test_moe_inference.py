@@ -63,9 +63,17 @@ def test_moe_model_trains_sharded():
     assert losses[-1] < losses[0], losses
 
 
-def test_prefill_decode_matches_full_forward():
-    """Greedy decode via KV cache must match argmax over full forward."""
-    cfg = ModelConfig.tiny()
+@pytest.mark.parametrize("cfg", [
+    ModelConfig.tiny(),
+    dataclasses.replace(ModelConfig.tiny(), n_kv_heads=1),         # 4:1 grouped
+    dataclasses.replace(ModelConfig.tiny(), tie_embeddings=True),
+    # capacity = tokens: nothing is dropped, whatever the number of tokens
+    dataclasses.replace(ModelConfig.tiny_moe(), capacity_factor=4.0),
+], ids=["tiny", "heads_4to1", "tied_embeddings", "tiny_moe"])
+def test_prefill_decode_matches_full_forward(cfg):
+    """Greedy decode via KV cache must match argmax over full forward: the
+    one spelling of the projections and the FFN (`transformer._project_qkv`,
+    `_mlp`), seen from the training forward, prefill and the decode step."""
     params = init_params(jax.random.PRNGKey(0), cfg)
     prompt = jax.random.randint(jax.random.PRNGKey(1), (2, 10), 0, cfg.vocab_size)
 

@@ -149,35 +149,46 @@ def test_ulysses_attention_gqa():
                                atol=2e-4)
 
 
-def test_remat_modes_agree():
-    """All remat policies ("none"/"full"/"dots"/"dots_sans_qkv"/
-    "dots_plus_attn") and fused_proj produce the same loss and grads —
-    they only trade recompute for saved-activation memory."""
+@pytest.mark.parametrize("remat", ["none", "full", "dots"])
+def test_remat_modes_agree(remat):
+    """Every value `remat` takes gives the loss and the gradients of the
+    plain forward (logits, then `token_nll`, no checkpoint anywhere): the
+    policies only trade recompute for saved-activation memory."""
     import dataclasses
 
     import numpy as np
 
-    from ray_tpu.models.transformer import ModelConfig, init_params, loss_fn
+    from ray_tpu.models.transformer import (ModelConfig, forward, init_params,
+                                            loss_fn, token_nll)
 
     base = ModelConfig.tiny()
     params = init_params(jax.random.PRNGKey(0), base)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 65), 0,
                                 base.vocab_size)
-    batch = {"tokens": tokens}
 
-    def vg(cfg):
-        return jax.value_and_grad(
-            lambda p: loss_fn(p, batch, cfg, None)[0])(params)
+    def plain(p):
+        return token_nll(forward(p, tokens[:, :-1], base), tokens[:, 1:])
 
-    (loss0, g0) = vg(base)
-    for variant in (dataclasses.replace(base, remat="full"),
-                    dataclasses.replace(base, remat="dots"),
-                    dataclasses.replace(base, remat="dots_sans_qkv"),
-                    dataclasses.replace(base, remat="dots_plus_attn"),
-                    dataclasses.replace(base, remat="dots", fused_proj=True),
-                    dataclasses.replace(base, remat="none", scan_unroll=2)):
-        loss1, g1 = vg(variant)
-        np.testing.assert_allclose(loss0, loss1, rtol=1e-5)
-        for a, b in zip(jax.tree_util.tree_leaves(g0),
-                        jax.tree_util.tree_leaves(g1)):
-            np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
+    cfg = dataclasses.replace(base, remat=remat)
+    loss0, g0 = jax.value_and_grad(plain)(params)
+    loss1, g1 = jax.value_and_grad(
+        lambda p: loss_fn(p, {"tokens": tokens}, cfg, None)[0])(params)
+    np.testing.assert_allclose(loss0, loss1, rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(g0),
+                    jax.tree_util.tree_leaves(g1)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("remat", ["dots_plus_attn", "selective", ""])
+def test_unknown_remat_value_raises(remat):
+    """`remat` takes three values; a policy that lost its measurement and
+    left with its code is an unknown string like any other."""
+    import dataclasses
+
+    from ray_tpu.models.transformer import ModelConfig, init_params, loss_fn
+
+    cfg = dataclasses.replace(ModelConfig.tiny(), remat=remat)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.zeros((1, 9), jnp.int32)
+    with pytest.raises(ValueError, match="unknown remat mode"):
+        loss_fn(params, {"tokens": tokens}, cfg)

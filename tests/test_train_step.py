@@ -1,5 +1,9 @@
 """Sharded train-step tests on the 8-device virtual CPU mesh."""
 
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +13,8 @@ from ray_tpu.models import ModelConfig, count_params, init_params, loss_fn
 from ray_tpu.parallel import MeshConfig, make_virtual_mesh
 from ray_tpu.train import make_train_step, batch_sharding
 from ray_tpu.train.step import default_optimizer
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _batch(rng, cfg, batch=4, seq=64):
@@ -49,7 +55,7 @@ def test_train_step_sharded(mesh_cfg):
 
 def test_train_step_with_sequence_parallel():
     cfg = ModelConfig.tiny()
-    cfg = ModelConfig(**{**cfg.__dict__, "use_ring_attention": True})
+    cfg = ModelConfig(**{**cfg.__dict__, "seq_parallel": "ring"})
     mesh = make_virtual_mesh(8, MeshConfig(dp=2, fsdp=1, tp=2, sp=2))
     step_fn, init_fn, sh = make_train_step(cfg, mesh, default_optimizer(1e-3))
     state = init_fn(jax.random.PRNGKey(0))
@@ -104,6 +110,34 @@ def test_chunked_loss_matches_dense():
         loss_fn(params, batch, dataclasses.replace(cfg, loss_chunk=7))
 
 
+def test_chunked_nll_matches_dense_with_mask_and_odd_vocab():
+    """`chunked_token_nll` (the head and the softmax a chunk at a time) is
+    `token_nll` over the whole logits: value and the gradients of features
+    and head, under a loss mask, at a vocabulary that is no multiple of 128."""
+    from ray_tpu.models.transformer import chunked_token_nll, token_nll
+
+    b, s, d, vocab = 2, 24, 16, 300
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    x = jax.random.normal(ks[0], (b, s, d), jnp.float32)
+    head = jax.random.normal(ks[1], (d, vocab), jnp.float32) * d ** -0.5
+    targets = jax.random.randint(ks[2], (b, s), 0, vocab)
+    mask = jax.random.bernoulli(ks[3], 0.6, (b, s))
+
+    def dense(x, head):
+        return token_nll((x @ head).astype(jnp.float32), targets, mask)
+
+    def chunked(x, head):
+        return chunked_token_nll(x, head, targets, mask, 8)
+
+    (l_d, g_d), (l_c, g_c) = (jax.value_and_grad(f, argnums=(0, 1))(x, head)
+                              for f in (dense, chunked))
+    np.testing.assert_allclose(float(l_d), float(l_c), rtol=1e-5)
+    for a, bb in zip(g_d, g_c):
+        np.testing.assert_allclose(a, bb, rtol=1e-4, atol=1e-6)
+    # a masked position moves nothing
+    assert not np.asarray(g_c[0])[~np.asarray(mask)].any()
+
+
 def test_selective_remat_matches_full():
     """remat='dots' (selective checkpoint policy) is numerically identical."""
     import dataclasses
@@ -147,3 +181,49 @@ def test_hybrid_dcn_mesh_train_step():
     batch = jax.device_put(batch, {k: batch_sharding(mesh)[k] for k in batch})
     state, metrics = step_fn(state, batch)
     assert np.isfinite(float(metrics["loss"]))
+
+def test_llama3_8b_sharding_lowers_on_virtual_v5e64():
+    """AOT shape-level proof: the full llama3_8b train step traces and
+    lowers (GSPMD shardings attached) over a 64-device mesh laid out
+    fsdp=16 x tp=4 — no weights materialized, subprocess so the
+    64-device CPU platform doesn't leak into other tests."""
+    script = r"""
+import os
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=64")
+import jax
+jax.config.update("jax_platforms", "cpu")
+import dataclasses
+import jax.numpy as jnp
+from ray_tpu.models import ModelConfig
+from ray_tpu.parallel import MeshConfig, make_virtual_mesh
+from ray_tpu.train import make_train_step, batch_sharding
+from ray_tpu.train.step import default_optimizer, state_shardings
+
+assert len(jax.devices()) == 64, jax.devices()
+cfg = dataclasses.replace(ModelConfig.llama3_8b(), max_seq_len=4096,
+                          remat="dots", loss_chunk=512)
+mesh = make_virtual_mesh(64, MeshConfig(dp=1, fsdp=16, tp=4, sp=1))
+optimizer = default_optimizer()
+step_fn, init_fn, sh = make_train_step(cfg, mesh, optimizer)
+
+# shape-level state on the real shardings — nothing materialized
+state_shape = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+import numpy as np
+n_params = sum(int(np.prod(x.shape))
+               for x in jax.tree_util.tree_leaves(state_shape.params))
+assert n_params > 8.0e9, n_params
+
+tokens = jax.ShapeDtypeStruct((16, 4096), jnp.int32)
+batch = {"inputs": tokens, "targets": tokens}
+lowered = step_fn.lower(state_shape, batch)
+text = lowered.as_text()
+assert "sharding" in text  # GSPMD annotations attached
+print("LOWERED_OK", n_params)
+"""
+    out = subprocess.run(
+        [sys.executable, "-c", script], cwd=REPO, capture_output=True,
+        text=True, timeout=900,
+        env={**os.environ, "PYTHONPATH": REPO})
+    assert "LOWERED_OK" in out.stdout, (out.stdout[-2000:], out.stderr[-2000:])
