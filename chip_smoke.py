@@ -211,7 +211,8 @@ def train_loop(config):
     hlo = compiled.as_text()
     out["tpu_custom_calls"] = hlo.count('custom_call_target="tpu_custom_call"')
     out["collectives"] = {op: hlo.count(f" {op}(") for op in (
-        "all-gather", "all-reduce", "reduce-scatter", "all-to-all")}
+        "all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+        "collective-permute-start")}
     ma = compiled.memory_analysis()
     out["memory_analysis"] = {"argument_bytes": ma.argument_size_in_bytes,
                               "temp_bytes": ma.temp_size_in_bytes}

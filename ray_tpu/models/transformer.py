@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional
 
 import jax
@@ -30,6 +31,8 @@ import jax.numpy as jnp
 from ray_tpu.ops.attention import (attention, attention_sharded,
                                    uses_flash_kernel)
 from ray_tpu.ops.layers import apply_rotary, rms_norm, rotary_embedding, swiglu
+from ray_tpu.parallel import fsdp
+from ray_tpu.parallel.mesh import DEFAULT_RULES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -283,6 +286,30 @@ def _layer(cfg: ModelConfig, mesh, x, layer_params, cos, sin):
             jnp.zeros((), jnp.float32) if aux is None else aux)
 
 
+def _exchanged_dims(cfg: ModelConfig, mesh, batch: int) -> Dict[str, int]:
+    """The weights of one layer whose gradient's reduction over `fsdp` the
+    program spells itself (parallel/fsdp.py) and does not leave to the
+    partitioner, each with the dimension the rules shard over `fsdp` (the
+    stacked leaf's, less the leading `layers`): every sharded one of the
+    plain dense block, on a mesh whose `fsdp` axis is larger than 1 and
+    whose (dp, fsdp) split the batch evenly. The expert layer (its `expert`
+    axis IS `fsdp`), the sequence-parallel schemes and the fused blocks (one
+    chip only) keep the partitioner's program: none, as without a mesh."""
+    if (fsdp.axis_size(mesh) == 1 or cfg.n_experts or cfg.seq_parallel
+            or cfg.fused_ffn or batch % math.prod(fsdp.batch_split(mesh))):
+        return {}
+    dims = {k: fsdp.sharded_dim(DEFAULT_RULES.spec(axes))
+            for k, axes in param_logical_axes(cfg)["layers"].items()}
+    return {k: d - 1 for k, d in dims.items() if d is not None}
+
+
+def grad_exchanges_per_layer(cfg: ModelConfig, mesh, batch: int) -> int:
+    """How many of a layer's weight gradients this program exchanges over
+    `fsdp` itself: 7 for the dense block on a mesh with fsdp > 1, else 0
+    (the train step's `xla.compile` spans carry it)."""
+    return len(_exchanged_dims(cfg, mesh, batch))
+
+
 def maybe_remat(layer_fn, cfg: ModelConfig):
     """Wrap a layer body per cfg.remat: "full" recomputes everything in the
     backward pass; "dots" keeps matmul outputs resident and recomputes only
@@ -317,7 +344,9 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
 
     `mesh` is required when a sequence-parallel scheme is active
     (`cfg.seq_parallel`: the sp shard_map needs it); everything else is
-    pure sharding-annotation-driven SPMD.
+    pure sharding-annotation-driven SPMD, but for the sum of the dense
+    block's weight gradients over `fsdp` (`_exchanged_dims`), which takes
+    the mesh too and without it is the partitioner's.
     """
     if positions is None:
         positions = jnp.arange(tokens.shape[1])
@@ -349,7 +378,14 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
                           lp["w_down"], cfg.norm_eps)
             return (x, aux), None
     else:
-        layer_fn = maybe_remat(functools.partial(_layer, cfg, mesh), cfg)
+        layer = functools.partial(_layer, cfg, mesh)
+        dims = _exchanged_dims(cfg, mesh, x.shape[0])
+        if dims:
+            def layer(x, lp, cos, sin):
+                lp = {k: fsdp.ExchangedWeight(v, dims[k], mesh) if k in dims
+                      else v for k, v in lp.items()}
+                return _layer(cfg, mesh, x, lp, cos, sin)
+        layer_fn = maybe_remat(layer, cfg)
 
         def body(carry, lp):
             x, aux = carry

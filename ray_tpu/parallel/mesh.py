@@ -15,7 +15,9 @@ Collectives (`psum`, `all_gather`, `ppermute`, `reduce_scatter`) are then
 emitted by XLA from sharding annotations — no collective library calls in
 user code. Parameters/activations carry *logical* axis names which
 `AxisRules` maps to mesh axes (the flax `logical_axis_rules` idea, re-built
-standalone).
+standalone). The one collective the program writes out is in the module
+beside this one, `parallel/fsdp.py`: the weight gradients' sum over `fsdp`,
+which the TPU compiler overlaps with compute only as explicit permutes.
 """
 
 from __future__ import annotations

@@ -4,9 +4,14 @@ Where the reference's TorchTrainer wraps user loops around torch DDP/FSDP
 (`python/ray/train/torch/config.py:69`, `train_loop_utils.py:92-101`), the
 TPU-native step is one jitted function whose parallelism is entirely in the
 in/out shardings: dp×fsdp shard the batch, fsdp shards parameters ZeRO-3
-style (XLA inserts the all-gathers/reduce-scatters), tp shards heads/mlp,
-sp runs ring attention. No collective calls appear below — the compiler
-emits them over ICI/DCN from the sharding annotations.
+style (XLA inserts the all-gathers), tp shards heads/mlp, sp runs ring
+attention. No collective calls appear below — the compiler emits them over
+ICI/DCN from the sharding annotations. One reduction the model spells
+itself: with fsdp > 1 the dense block's weight gradients are summed over
+fsdp by `parallel/fsdp.py` (permutes that run behind the backward's
+matmuls; the partitioner's all-reduce-and-slice blocks the compute stream).
+The step's `xla.compile` spans say which form it has
+(`grad_exchanges_per_layer`).
 """
 
 from __future__ import annotations
@@ -22,9 +27,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.transformer import (
     ModelConfig,
+    grad_exchanges_per_layer,
     init_params,
     loss_fn,
     param_logical_axes,
+    split_batch,
 )
 from ray_tpu.parallel.mesh import AxisRules, DEFAULT_RULES, logical_sharding
 
@@ -143,6 +150,12 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh,
     b_sh = batch_sharding(mesh)
 
     def step(state: TrainState, batch: Dict[str, jax.Array]):
+        # which form of the gradients' reduction over fsdp this program has
+        # is a fact of its compile: on its `xla.compile` spans
+        tracing.note_compile(
+            "step", fsdp=mesh.shape.get("fsdp", 1), tp=mesh.shape.get("tp", 1),
+            grad_exchanges_per_layer=grad_exchanges_per_layer(
+                cfg, mesh, split_batch(batch)[0].shape[0]))
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, batch, cfg, mesh)
         # (the phases before this one are named in models/transformer.py:

@@ -51,7 +51,7 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from random import getrandbits as _getrandbits
-from typing import Deque, List, Optional, Tuple
+from typing import Deque, Dict, List, Optional, Tuple
 
 _events: Deque[dict] = deque()
 _lock = threading.Lock()
@@ -241,6 +241,16 @@ def add_complete(name: str, category: str, start_us: float, dur_us: float,
     _append(event)
 
 
+_compile_args: Dict[str, dict] = {}
+
+
+def note_compile(fun_name: str, **args) -> None:
+    """Facts of the program being traced (call it from inside the jitted
+    function `fun_name`: it runs once per trace) that its `xla.compile`
+    spans then carry beside `event` and `fun_name`."""
+    _compile_args[fun_name] = args
+
+
 def record_compiles() -> None:
     """Install, once per process, the `jax.monitoring` listener that turns
     every program's trace / lower / backend-compile event into an `xla.compile` span
@@ -258,11 +268,13 @@ def record_compiles() -> None:
             return
         ctx = getattr(_tls, "ctx", None) or (None, None)
         dur = secs * 1e6
+        fun_name = str(kw.get("fun_name") or open_span_name() or "")
+        bare = fun_name[4:-1] if fun_name.startswith("jit(") else fun_name
         span = dict(
+            _compile_args.get(bare, {}),
             name="xla.compile", category="compile", start_us=_now_us() - dur,
             dur_us=dur, trace_id=ctx[0], parent_id=ctx[1],
-            event=event.rsplit("/", 1)[-1],
-            fun_name=str(kw.get("fun_name") or open_span_name() or ""))
+            event=event.rsplit("/", 1)[-1], fun_name=fun_name)
         pending = getattr(_tls, "pending_traces", None)
         if pending is None:
             pending = _tls.pending_traces = {}
@@ -275,8 +287,7 @@ def record_compiles() -> None:
             pending[span["fun_name"]] = span
             return
         if span["event"] == "jaxpr_to_mlir_module_duration":
-            name = span["fun_name"]
-            own = pending.get(name[4:-1] if name.startswith("jit(") else name)
+            own = pending.get(bare)
             pending.clear()
             if own is not None:
                 add_complete(**own)

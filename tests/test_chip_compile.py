@@ -11,6 +11,7 @@ The code's device branches ask `ops.pallas._util.on_tpu()`; the tests steer
 that one function instead of adding an option to the program.
 """
 
+import dataclasses
 import math
 import os
 import re
@@ -312,15 +313,14 @@ def test_prefill_slots_compiles_at_b1(chip):
     assert c.memory_analysis().argument_size_in_bytes < 3 * 2**30
 
 
-def _lower_b1_step(topo, *, chips, mesh, batch, seq, optimizer, fused):
-    """The whole b1 train step, as `chip_smoke.py` builds it,
-    lowered for `chips` described devices. `.compile()` is the question."""
-    import dataclasses
-
+def _lower_b1_step(topo, *, chips, mesh, batch, seq, optimizer, fused, cfg=B1):
+    """The whole b1 train step, as `chip_smoke.py` builds it (or another
+    `cfg`'s, as the training cells build theirs), lowered for `chips`
+    described devices. `.compile()` is the question."""
     from ray_tpu.parallel import MeshConfig, make_mesh
     from ray_tpu.train.step import batch_sharding, make_train_step
 
-    cfg = dataclasses.replace(B1, max_seq_len=seq, remat="dots", loss_chunk=0,
+    cfg = dataclasses.replace(cfg, max_seq_len=seq, remat="dots", loss_chunk=0,
                               fused_ffn=fused, fused_attn=fused)
     mesh = make_mesh(MeshConfig(**mesh), topo.devices[:chips])
     step_fn, init_fn, shardings = make_train_step(cfg, mesh, optimizer)
@@ -331,6 +331,119 @@ def _lower_b1_step(topo, *, chips, mesh, batch, seq, optimizer, fused):
     batch = {k: jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=b_sh[k])
              for k in ("inputs", "targets")}
     return step_fn.lower(state, batch)
+
+
+# Mistral-7B-v0.3 widths, as the training cells run them
+# (perfbench/configs/mistral-7b-v0.3.4chip.json: 22 layers, fsdp 2 x tp 2)
+MISTRAL = ModelConfig(vocab_size=32768, d_model=4096, n_layers=22, n_heads=32,
+                      n_kv_heads=8, d_ff=14336, rope_theta=1e6)
+_COLLECTIVE = re.compile(r"all-reduce|all-gather|reduce-scatter|all-to-all|"
+                         r"collective-permute|collective-broadcast")
+
+
+def _computations(hlo: str) -> dict:
+    """name -> lines of each computation of a compiled module's text."""
+    out, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            name = head.group(1)
+            out[name] = []
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+def _is_matmul(comps: dict, line: str) -> bool:
+    """A fusion whose computation (or one it calls: the all-gather a matmul
+    carries inside runs in a nested one) holds a convolution."""
+    called = re.search(r" fusion\(.*calls=%([\w.\-]+)", line)
+    body = "\n".join(comps.get(called.group(1), [])) if called else ""
+    return " convolution(" in body or any(
+        " convolution(" in "\n".join(comps.get(c, []))
+        for c in re.findall(r"calls=%([\w.\-]+)", body))
+
+
+def _assert_grad_exchange_runs_behind_the_backward(c, temp_limit=None):
+    """The compiled fsdp 2 x tp 2 step at the cell's widths. In the backward
+    `while` body: no `all-reduce-scatter` fusion (the partitioner's form of
+    the weight gradients' reduction over fsdp: the whole [4096,7168]
+    gradient padded, all-reduced and sliced ON the compute stream, 0.76 ms
+    each, seven a layer) and no all-reduce over the fsdp pairs {{0,2},{1,3}}
+    of anything larger than a norm vector; instead seven
+    `collective-permute-start` ... `-done` pairs of exact shards
+    ([1,2048,7168] x 2, [1,7168,2048], [1,2048,2048] x 2, [1,2048,512] x 2),
+    each with a matmul fusion scheduled between its start and its done."""
+    comps = _computations(c.as_text())
+    bodies = [lines for lines in comps.values()
+              if sum(" collective-permute-start(" in l for l in lines) >= 7]
+    assert len(bodies) == 1  # the backward body; the forward's has none
+    body = bodies[0]
+    assert not [l for l in body if "all-reduce-scatter" in l]
+    for l in body:
+        if " all-reduce(" in l and "{{0,2},{1,3}}" in l:
+            dims = re.search(r"= \(?\w+\[([\d,]*)\]", l).group(1)
+            assert math.prod(map(int, dims.split(","))) <= MISTRAL.d_model, l
+    starts = {re.match(r"\s*%([\w.\-]+) =", l).group(1): i
+              for i, l in enumerate(body) if " collective-permute-start(" in l}
+    assert len(starts) == 7
+    shards = sorted(re.search(r"= \(\w+\[([\d,]*)\]", body[i]).group(1)
+                    for i in starts.values())
+    assert shards == sorted(["1,2048,7168"] * 2 + ["1,7168,2048"]
+                            + ["1,2048,2048"] * 2 + ["1,2048,512"] * 2)
+    hidden = 0
+    for name, i in starts.items():
+        done = next(j for j, l in enumerate(body)
+                    if f"collective-permute-done(%{name})" in l)
+        hidden += any(_is_matmul(comps, l) for l in body[i + 1:done])
+    assert hidden == 7, hidden
+    # the forward keeps the partitioner's program: weights gathered inside
+    # the matmuls that use them, four tp all-reduces a layer in all
+    assert sum(" all-reduce(" in l and "[1,2048,4096]" in l
+               for lines in comps.values() for l in lines) >= 4
+    if temp_limit:
+        assert c.memory_analysis().temp_size_in_bytes < temp_limit
+
+
+def test_grad_exchange_compiles_behind_the_backward_matmuls(topo, chip):
+    """Two layers of the 4-chip cell's step (ten seconds; the whole 22 are
+    the slow case below)."""
+    from ray_tpu.train.step import default_optimizer
+
+    c = _lower_b1_step(topo, chips=4, mesh={"dp": 1, "fsdp": 2, "tp": 2},
+                       batch=2, seq=SEQ, optimizer=default_optimizer(),
+                       fused=False, cfg=dataclasses.replace(MISTRAL, n_layers=2)
+                       ).compile()
+    _assert_grad_exchange_runs_behind_the_backward(c)
+
+
+@pytest.mark.slow  # ten more seconds of five cores: see the note below
+def test_one_chip_step_has_no_collective(topo, chip):
+    """The 1-chip cell's step (fused blocks, two layers here): `fsdp` is 1,
+    so the layer takes its weights as they are and the compiled program
+    names no collective at all. (Tier-1 holds the same for the lowered
+    program: `tests/test_engine_spans.py`, the one-device case.)"""
+    from ray_tpu.train.step import default_optimizer
+
+    c = _lower_b1_step(topo, chips=1, mesh={"dp": 1}, batch=2, seq=SEQ,
+                       optimizer=default_optimizer(), fused=True,
+                       cfg=dataclasses.replace(MISTRAL, n_layers=2)).compile()
+    assert not _COLLECTIVE.search(c.as_text())
+    assert _kernels(c) >= 4
+
+
+@pytest.mark.slow
+def test_four_chip_cell_step_compiles_and_fits(topo, chip):
+    """The whole `mistral7b-train-4chip` step: the same backward body, and
+    `temp` within half a GB of the 7,835,362,816 B the partitioner's own
+    reduction needed (PERF.md section 6, PR 30): the unreduced gradients in
+    flight are one layer's."""
+    from ray_tpu.train.step import default_optimizer
+
+    c = _lower_b1_step(topo, chips=4, mesh={"dp": 1, "fsdp": 2, "tp": 2},
+                       batch=2, seq=SEQ, optimizer=default_optimizer(),
+                       fused=False, cfg=MISTRAL).compile()
+    _assert_grad_exchange_runs_behind_the_backward(c, temp_limit=8.34e9)
 
 
 # The whole-program compiles below keep ~5 cores busy for ~10 s each, which

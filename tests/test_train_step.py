@@ -53,6 +53,57 @@ def test_train_step_sharded(mesh_cfg):
     assert int(jax.device_get(state.step)) == 5
 
 
+@pytest.mark.parametrize("mesh_cfg,n", [
+    (MeshConfig(dp=1, fsdp=2, tp=2), 4),
+    (MeshConfig(dp=1, fsdp=4, tp=2), 8),
+    (MeshConfig(dp=2, fsdp=2, tp=2), 8),
+], ids=["fsdp2xtp2", "fsdp4xtp2", "dp2xfsdp2xtp2"])
+def test_grad_exchange_over_fsdp_matches_one_device(mesh_cfg, n, monkeypatch):
+    """On a mesh with fsdp > 1 the program sums each layer's weight
+    gradients over `fsdp` itself (parallel/fsdp.py: a ring of permutes of
+    exact shards). In float32 the loss and EVERY gradient leaf agree with
+    one device's `value_and_grad(loss_fn)` to sums of two or four terms,
+    and five optimizer steps give the losses of the partitioner's own
+    reduction (the same mesh with the exchange switched off here)."""
+    from ray_tpu.models import transformer
+    from ray_tpu.train.step import state_shardings
+
+    cfg = ModelConfig.tiny()
+    mesh = make_virtual_mesh(n, mesh_cfg)
+    assert transformer.grad_exchanges_per_layer(cfg, mesh, 8) == 7
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    batch = _batch(jax.random.PRNGKey(1), cfg, batch=8, seq=64)
+    grad = lambda m: jax.jit(jax.value_and_grad(
+        lambda p, b: loss_fn(p, b, cfg, m)[0]))
+    want_loss, want = grad(None)(params, batch)
+
+    b_sh = batch_sharding(mesh)
+    on_mesh = jax.device_put(batch, {k: b_sh[k] for k in batch})
+    p_sh = state_shardings(cfg, mesh, default_optimizer()).params
+    lowered = grad(mesh).lower(jax.device_put(params, p_sh), on_mesh)
+    assert "collective_permute" in lowered.as_text()
+    got_loss, got = lowered.compile()(jax.device_put(params, p_sh), on_mesh)
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=2e-6)
+    jax.tree_util.tree_map_with_path(
+        lambda path, g, w: np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-6 * float(jnp.abs(w).max()),
+            err_msg=jax.tree_util.keystr(path)), got, want)
+    assert got["layers"]["wo"].sharding.spec == p_sh["layers"]["wo"].spec
+
+    def five_losses():
+        step_fn, init_fn, _ = make_train_step(cfg, mesh, default_optimizer(1e-3))
+        state, losses = init_fn(jax.random.PRNGKey(0)), []
+        for _ in range(5):
+            state, metrics = step_fn(state, on_mesh)
+            losses.append(float(metrics["loss"]))
+        return losses
+
+    ours = five_losses()
+    monkeypatch.setattr(transformer, "_exchanged_dims", lambda *a: {})
+    np.testing.assert_allclose(ours, five_losses(), rtol=1e-5)
+    assert ours[-1] < ours[0]
+
+
 def test_train_step_with_sequence_parallel():
     cfg = ModelConfig.tiny()
     cfg = ModelConfig(**{**cfg.__dict__, "seq_parallel": "ring"})
