@@ -3,6 +3,9 @@
     python chip_smoke.py              # one chip: train phase, then serve phase
     python chip_smoke.py --chips 4    # four chips: sharded train vs one device,
                                       # then four one-chip replicas
+    python chip_smoke.py --model jamba  # one chip, one minute: the Jamba stack
+                                      # (scanned runs of Mamba and attention
+                                      # layers) against its float32 reference
 
 Drives the main path once through the entry points a user calls:
 `ray_tpu.init` -> `JaxTrainer` / `serve.run` + HTTP proxy -> a worker that was
@@ -642,11 +645,119 @@ def run_four_chips(args) -> dict:
     return sharded
 
 
+# --------------------------------------------------------------------------
+# --model jamba: one prompt pass and eight decode steps of the full-width
+# Jamba stack through its slot state, against the plain float32 reference
+
+# Largest relative error of a logits row the check accepts: the benchmark's
+# limit for its cell (perfbench/traffic/chat-burst-open-loop.json says where
+# the sound and the int8 readings lie: 0.040-0.058 against 0.23-0.27)
+JAMBA_LOGITS_REL_ERR = 0.115
+
+
+def jamba_check(seed: int, rehearsal: bool) -> dict:
+    """Runs in a worker that holds the chip: weights from the seed at the
+    published widths (`perfbench/configs/jamba2-3b.json`; a rehearsal takes
+    `HybridConfig.tiny_runs()`), two prompts of 150 and 229 tokens in one
+    prompt pass of 2 x 256, their state written into slots 3 and 200 of 256,
+    eight tokens decoded teacher-forced through the donated slot state; the
+    logits of both prompts' last positions and of every decoded position
+    against the reference's full forward."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench.lib import jamba_model
+    from perfbench.lib.manifest import load_py
+    from ray_tpu.models import hybrid
+
+    rep = _device_report()
+    _require_chip(rep, rehearsal)
+    if rehearsal:  # the CPU backend shows every virtual device
+        rep["device_count"] = 1
+    root = os.path.dirname(os.path.abspath(__file__))
+    ref = load_py(os.path.join(root, "perfbench", "references", "jamba.py"))
+    with open(os.path.join(root, "perfbench", "configs", "jamba2-3b.json")) as f:
+        c = json.load(f)
+    if rehearsal:
+        c.update(hidden_size=64, intermediate_size=128, num_hidden_layers=8,
+                 num_attention_heads=4, attn_layer_offset=2, attn_layer_period=4,
+                 mamba_dt_rank=8, vocab_size=512, torch_dtype="float32")
+    cfg = jamba_model.model_config(c)
+    slots, max_len, bucket, steps = (8, 512, 256, 8) if rehearsal else (256, 1024, 256, 8)
+    t0 = time.time()
+    params = jamba_model.make_params(cfg, seed)
+    cache = cfg.make_cache(slots, max_len)
+    jax.block_until_ready((params, cache.state))
+    rng = np.random.default_rng(seed)
+    lens, at = [150, 229], [3, slots - 56 if not rehearsal else 5]
+    whole = rng.integers(1, cfg.vocab_size, (2, bucket + steps)).astype(np.int32)
+    prompt = np.zeros((2, bucket), np.int32)
+    for j, n in enumerate(lens):
+        prompt[j, :n] = whole[j, :n]
+    lens_d = jnp.asarray(lens, jnp.int32)
+    t1 = time.time()
+    logits, rows = hybrid.prefill(params, jnp.asarray(prompt), lens_d, cfg)
+    got = {(j, n - 1): np.asarray(logits[j]) for j, n in enumerate(lens)}
+    lengths, tokens = cache.write(
+        jnp.zeros((slots,), jnp.int32), jnp.zeros((slots,), jnp.int32),
+        jnp.asarray(at, jnp.int32), rows, lens_d, jnp.zeros((2,), jnp.int32))
+    t2 = time.time()
+    for t in range(steps):
+        toks = np.zeros((slots,), np.int32)
+        for j, n in enumerate(lens):
+            toks[at[j]] = whole[j, n + t]
+        cache.state, logits, _ = hybrid.decode_logits(
+            params, cache.state, lengths, jnp.asarray(toks), None, cfg, 256)
+        lengths = lengths + (lengths > 0)
+        logits = np.asarray(logits)
+        for j, n in enumerate(lens):
+            got[(j, n + t)] = logits[at[j]]
+    t3 = time.time()
+    # the hot step, sampling on device: timed over the second ten of twenty
+    step_ms = []
+    for i in range(20):
+        a = time.perf_counter()
+        lengths, tokens, _ = cache.decode(params, lengths, tokens, 256, ())
+        jax.block_until_ready(tokens)
+        step_ms.append(1e3 * (time.perf_counter() - a))
+    errs = {}
+    for j, n in enumerate(lens):
+        toks = np.zeros((1, bucket + steps), np.int32)
+        toks[0, :n + steps] = whole[j, :n + steps]
+        want = jax.jit(lambda p, t: ref.logits(p, t, c))(params, jnp.asarray(toks))[0]
+        for (jj, pos), row in got.items():
+            if jj == j:
+                errs[f"{j}:{pos}"] = float(ref.rel_err(jnp.asarray(row), want[pos]))
+    return {**rep, "params": int(sum(a.size for a in jax.tree_util.tree_leaves(params))),
+            "init_s": t1 - t0, "prefill_and_write_s": t2 - t1,
+            "eight_decode_logits_s": t3 - t2,
+            "decode_step_ms_p50": float(np.median(step_ms[10:])),
+            "logits_rel_err_max": max(errs.values()), "logits_rel_err": errs,
+            "peak_bytes": int((jax.devices()[0].memory_stats() or {}).get(
+                "peak_bytes_in_use", 0))}
+
+
+def run_jamba(args) -> dict:
+    task = ray_tpu.remote(resources={"TPU": 1}, num_cpus=0)(jamba_check)
+    rep = ray_tpu.get(task.remote(args.seed, args.cpu_rehearsal), timeout=1500)
+    check_device(rep, "jamba", 1, args.cpu_rehearsal)
+    say("jamba", **{k: v for k, v in rep.items() if k != "logits_rel_err"})
+    say("jamba", logits_rel_err=rep["logits_rel_err"])
+    require(rep["logits_rel_err_max"] < (1e-4 if args.cpu_rehearsal
+                                         else JAMBA_LOGITS_REL_ERR),
+            f"the Jamba stack's logits are off the reference's by "
+            f"{rep['logits_rel_err_max']:.4g} (relative)")
+    return rep
+
+
 def main() -> None:
     global TAG
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--model", choices=("b1", "jamba"), default="b1",
+                    help="jamba: only the Jamba stack against its reference")
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="tiny model on the CPU; proves nothing about the chip")
     args = ap.parse_args()
@@ -674,7 +785,9 @@ def main() -> None:
 
     ray_tpu.init(num_cpus=8, resources={"TPU": args.chips})
     try:
-        if args.chips == 4:
+        if args.model == "jamba":
+            rep = run_jamba(args)
+        elif args.chips == 4:
             rep = run_four_chips(args)
         else:
             rep = run_train(args, 1, {"dp": 1}, "train")

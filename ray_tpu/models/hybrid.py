@@ -1,14 +1,26 @@
-"""A decoder whose layers are of several kinds (the Kimi-Linear family):
-KDA linear-attention mixers (`ops/kda.py`) and latent-attention mixers
-without positions (`ops/mla.py`) in one stack, a leading dense SwiGLU layer,
-then a dropless sigmoid-routed top-k expert layer with a shared expert
-(`ops/moe.py:dropless_moe`) that is told which experts it holds. Every
-block is `x += Mixer(RMSNorm(x)); x += FFN(RMSNorm(x))`; untied head.
+"""A decoder whose layers are of several kinds. Every block is
+`x += Mixer(RMSNorm(x)); x += FFN(RMSNorm(x))` over a float32 residual
+stream. The mixers: KDA linear attention (`ops/kda.py`), latent attention
+without positions (`ops/mla.py`), Mamba-1's selective scan (`ops/mamba.py`)
+and plain grouped-query attention without positions; the FFNs: dense SwiGLU
+and a dropless sigmoid-routed top-k expert layer with a shared expert
+(`ops/moe.py:dropless_moe`) that is told which experts it holds.
 
-The layers are held as a LIST of per-layer dicts and the stack is unrolled
-(nine layers in the benchmark's cut): a `lax.scan` needs one body, and
-here the body changes from layer to layer; per-layer leaves also let the
-decode step donate and rewrite each layer's state in place.
+Two ways to hold and run the stack, by what the configuration lists:
+
+- `kda_layers` (the Kimi-Linear family: KDA and MLA mixers, a leading dense
+  layer, then expert layers; untied head): the layers are a LIST of
+  per-layer dicts, `params["layers"]`, and the stack is unrolled (nine
+  layers in the benchmark's cut); per-layer leaves let the decode step
+  donate and rewrite each layer's state in place.
+- `mamba_layers` / `attn_layers` (the Jamba family: Mamba and attention
+  mixers, dense FFNs, head tied to the embedding): 28 layers unrolled would
+  compile for minutes, so the stack is held and run as RUNS of like layers,
+  `params["runs"]`, each run one `lax.scan` over weights stacked on a leading
+  axis. A run's recurrent state is stacked the same way and travels through
+  the decode step's scan as a CARRY that each layer reads and rewrites in
+  place (a scan that took it as xs and gave it back as ys would hold it
+  twice).
 
 Three call modes over the same weights:
 
@@ -22,8 +34,9 @@ Three call modes over the same weights:
 `decode_step`   one token for every slot from the slots' state, greedy
                 sampling on device, state DONATED and rewritten in place.
 
-`HybridCache` is this model's implementation of the engine's per-slot state
-interface (`models/serving.py`, "the cache interface").
+`HybridCache` (list form) and `RunsCache` (runs form) are this family's
+implementations of the engine's per-slot state interface
+(`models/serving.py`, "the cache interface").
 """
 
 from __future__ import annotations
@@ -36,11 +49,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops import kda, mla
+from ray_tpu.models.inference import _gqa_decode_attention
+from ray_tpu.ops import kda, mamba, mla
 from ray_tpu.ops.attention import causal_attention_blocked
 from ray_tpu.ops.cache import write_rows
 from ray_tpu.ops.layers import rms_norm, swiglu
 from ray_tpu.ops.moe import dropless_moe, route_top_k
+from ray_tpu.ops.pallas import decode_attention
 
 F32 = jnp.float32
 
@@ -78,24 +93,63 @@ class HybridConfig:
     # the most tokens one prefill call takes (bounds its activations and the
     # number of (batch, bucket) programs): admission batches are split to it
     prefill_tokens: int = 4096
+    # the runs form: Mamba-1 and plain-attention mixers (layers counted from
+    # 1, as `kda_layers`); a configuration that lists any is held and run as
+    # scanned runs, its FFNs are all dense and its head is the embedding
+    mamba_layers: Tuple[int, ...] = ()
+    attn_layers: Tuple[int, ...] = ()
+    d_inner: int = 128
+    d_state: int = 16
+    dt_rank: int = 8
+    mamba_chunk: int = 128
+    n_kv_heads: int = 1
+    head_dim: int = 16
 
     @staticmethod
     def tiny_hybrid() -> "HybridConfig":
         """dense layer + KDA, KDA, MLA, KDA; 8 experts, top-2, one shared."""
         return HybridConfig()
 
+    @staticmethod
+    def tiny_runs() -> "HybridConfig":
+        """Mamba x 2, attention, Mamba x 3, attention, Mamba: five runs."""
+        return HybridConfig(n_layers=8, kda_layers=(), first_dense=8,
+                            mamba_layers=(1, 2, 4, 5, 6, 8), attn_layers=(3, 7),
+                            n_heads=4, norm_eps=1e-6, mamba_chunk=16)
+
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
-        return tuple(("kda" if i in self.kda_layers else "mla",
-                      "dense" if i <= self.first_dense else "moe")
+        def mixer(i):
+            return ("kda" if i in self.kda_layers else
+                    "mamba" if i in self.mamba_layers else
+                    "attn" if i in self.attn_layers else "mla")
+        return tuple((mixer(i), "dense" if i <= self.first_dense else "moe")
                      for i in range(1, self.n_layers + 1))
+
+    @property
+    def scanned(self) -> bool:
+        return bool(self.mamba_layers or self.attn_layers)
+
+    def runs(self) -> Tuple[Tuple[str, int], ...]:
+        """The runs form's stack: (mixer, how many like layers in a row)."""
+        kinds = self.layer_kinds()
+        if any(k not in (("mamba", "dense"), ("attn", "dense")) for k in kinds):
+            raise ValueError("a stack of scanned runs holds Mamba and attention "
+                             f"mixers over dense FFNs only, not {sorted(set(kinds))}")
+        out: List[Tuple[str, int]] = []
+        for mixer, _ in kinds:
+            if out and out[-1][0] == mixer:
+                out[-1] = (mixer, out[-1][1] + 1)
+            else:
+                out.append((mixer, 1))
+        return tuple(out)
 
     @property
     def latent_width(self) -> int:
         return self.kv_lora_rank + self.qk_rope_dim
 
-    def make_cache(self, num_slots: int, max_len: int) -> "HybridCache":
+    def make_cache(self, num_slots: int, max_len: int):
         """This model's per-slot state for `ContinuousBatchingEngine`."""
-        return HybridCache(self, num_slots, max_len)
+        return (RunsCache if self.scanned else HybridCache)(self, num_slots, max_len)
 
 
 # ---------------------------------------------------------------- params
@@ -106,7 +160,9 @@ def init_params(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
     router's correction bias, `A_log` and `dt_bias` (fla's ranges: A in
     [1, 16], softplus(dt_bias) in [0.001, 0.1]), so that leaving one of
     them out of the computation changes the result. Pure: jit it to build a
-    large model in one program."""
+    large model in one program. The runs form: `_init_runs`."""
+    if cfg.scanned:
+        return _init_runs(rng, cfg)
     d, dt = cfg.d_model, cfg.dtype
     H, dk, r = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_rank
     counter = iter(range(1 << 20))
@@ -161,6 +217,58 @@ def init_params(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
             "final_norm": jnp.ones((d,), dt), "layers": layers,
             "lm_head": (jax.random.normal(jax.random.fold_in(rng, next(counter)),
                                           (d, cfg.vocab_size), F32) * 0.02).astype(dt)}
+
+
+def _init_runs(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
+    """The runs form's weights: one dict per run, every leaf stacked on a
+    leading axis of the run's length (made stacked: no copy of a 3B model
+    is ever stacked from per-layer pieces). Mamba's published initial
+    ranges, all non-zero so that leaving one out changes the result:
+    `A_log` = log(1..d_state) per state column, `dt_bias` the inverse
+    softplus of exp(uniform[ln 1e-3, ln 1e-1]), `D` = 1 + noise, the
+    convolution's bias uniform in +-K^-1/2; the three inner norms ones. No
+    `lm_head`: the head is the embedding."""
+    d, dt = cfg.d_model, cfg.dtype
+    di, n, r, K = cfg.d_inner, cfg.d_state, cfg.dt_rank, cfg.conv_kernel
+    counter = iter(range(1 << 20))
+
+    def key():
+        return jax.random.fold_in(rng, next(counter))
+
+    def w(shape, fan_in):
+        return (jax.random.normal(key(), shape, F32) * fan_in ** -0.5).astype(dt)
+
+    runs: List[Dict[str, Any]] = []
+    for mixer, k in cfg.runs():
+        p: Dict[str, Any] = {
+            "mixer_norm": jnp.ones((k, d), dt), "ffn_norm": jnp.ones((k, d), dt),
+            "ffn": {"w_gate": w((k, d, cfg.d_ff), d), "w_up": w((k, d, cfg.d_ff), d),
+                    "w_down": w((k, cfg.d_ff, d), cfg.d_ff)}}
+        if mixer == "mamba":
+            step = jnp.exp(jax.random.uniform(key(), (k, di), F32,
+                                              np.log(1e-3), np.log(1e-1)))
+            p["mamba"] = {
+                "w_in": w((k, d, 2 * di), d), "conv": w((k, K, di), K),
+                "conv_bias": jax.random.uniform(key(), (k, di), F32,
+                                                -K ** -0.5, K ** -0.5),
+                "w_x": w((k, di, r + 2 * n), di),
+                "dt_norm": jnp.ones((k, r), dt), "b_norm": jnp.ones((k, n), dt),
+                "c_norm": jnp.ones((k, n), dt),
+                "w_dt": w((k, r, di), r),
+                "dt_bias": step + jnp.log(-jnp.expm1(-step)),  # softplus^-1
+                # [d_state, d_inner]: channels minor (`ops/mamba.py`)
+                "A_log": jnp.broadcast_to(
+                    jnp.log(jnp.arange(1, n + 1, dtype=F32))[None, :, None], (k, n, di)),
+                "D": 1.0 + 0.1 * jax.random.normal(key(), (k, di), F32),
+                "w_out": w((k, di, d), di)}
+        else:
+            H, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+            p["attn"] = {"wq": w((k, d, H * hd), d), "wk": w((k, d, kvh * hd), d),
+                         "wv": w((k, d, kvh * hd), d), "wo": w((k, H * hd, d), H * hd)}
+        runs.append(p)
+    return {"embed": (jax.random.normal(key(), (cfg.vocab_size, d), F32)
+                      * 0.02).astype(dt),
+            "final_norm": jnp.ones((d,), dt), "runs": runs}
 
 
 # ---------------------------------------------------------------- pieces
@@ -229,12 +337,75 @@ def _ffn(cfg: HybridConfig, p, h32, h, valid):
     return y, landed, touched, idx
 
 
+def _dense_ffn(cfg: HybridConfig, lp, x):
+    """x += SwiGLU(RMSNorm(x)) of one layer of a run (float32 residual)."""
+    with jax.named_scope("mlp"):
+        _, h = _normed(cfg, x, lp["ffn_norm"])
+        f = lp["ffn"]
+        return x + (swiglu(h @ f["w_gate"], h @ f["w_up"]) @ f["w_down"]).astype(F32)
+
+
+def _mamba_inputs(cfg: HybridConfig, m, u):
+    """From the convolved, SiLU'd u [..., di] float32: the step size dt
+    [..., di] after its softplus and the maps B, C [..., n], float32; each of
+    the three through an RMSNorm of its own."""
+    r, n = cfg.dt_rank, cfg.d_state
+    # the two small products keep their float32 sums: dt feeds an exponent
+    # that compounds over the positions, and B and C scale the whole state
+    low, B, C = jnp.split(jnp.dot(u.astype(cfg.dtype), m["w_x"],
+                                  preferred_element_type=F32), (r, r + n), axis=-1)
+    low = rms_norm(low, m["dt_norm"], cfg.norm_eps).astype(cfg.dtype)
+    B = rms_norm(B, m["b_norm"], cfg.norm_eps)
+    C = rms_norm(C, m["c_norm"], cfg.norm_eps)
+    dt = jnp.dot(low, m["w_dt"], preferred_element_type=F32) + m["dt_bias"]
+    return jax.nn.softplus(dt), B, C
+
+
+def _mamba_seq(cfg: HybridConfig, m, h, valid, true_len):
+    """h [b, s, d] (normed) -> (the mixer's output [b, s, d], the state after
+    the last true position [b, n, di] float32, the convolution tail
+    [b, K-1, di])."""
+    u_pre, z = jnp.split(h @ m["w_in"], 2, axis=-1)
+    u = jax.nn.silu(kda.short_conv(u_pre.astype(F32), m["conv"].astype(F32))
+                    + m["conv_bias"])
+    dt, B, C = _mamba_inputs(cfg, m, u)
+    with jax.named_scope("scan"):
+        y, state = mamba.selective_scan(u, dt, -jnp.exp(m["A_log"]), B, C, m["D"],
+                                        None, valid, cfg.mamba_chunk)
+    out = (y * jax.nn.silu(z.astype(F32))).astype(cfg.dtype) @ m["w_out"]
+    return out, state, kda.conv_tail(u_pre, true_len, cfg.conv_kernel)
+
+
+def _mamba_step(cfg: HybridConfig, m, h, ssm, layer, slots, busy, tail):
+    """One token: h [B, d]; ssm the run's stacked state [k, B, n, di], of
+    which `layer` advances in place (`mamba.selective_step_slots`); tail
+    [B, K-1, di] -> (output [B, d], ssm, tail)."""
+    u_pre, z = jnp.split(h @ m["w_in"], 2, axis=-1)
+    y, tail = kda.short_conv_step(u_pre, tail, m["conv"])
+    u = jax.nn.silu(y + m["conv_bias"])
+    dt, B, C = _mamba_inputs(cfg, m, u)
+    with jax.named_scope("scan"):
+        ssm, y = mamba.selective_step_slots(
+            ssm, layer, slots, busy, u, dt, -jnp.exp(m["A_log"]), B, C, m["D"])
+    return (y * jax.nn.silu(z.astype(F32))).astype(cfg.dtype) @ m["w_out"], ssm, tail
+
+
+def _attn_qkv(cfg: HybridConfig, a, h):
+    """h [..., d] -> q [..., H, hd], k, v [..., kvh, hd]; no positions."""
+    lead, hd = h.shape[:-1], cfg.head_dim
+    return ((h @ a["wq"]).reshape(lead + (cfg.n_heads, hd)),
+            (h @ a["wk"]).reshape(lead + (cfg.n_kv_heads, hd)),
+            (h @ a["wv"]).reshape(lead + (cfg.n_kv_heads, hd)))
+
+
 # ---------------------------------------------------------------- sequence
 
 
 def _sequence(params, tokens, true_len, cfg: HybridConfig):
     """tokens [b, s] right-padded to true_len [b] -> (features after the
     final norm [b, s, d], state rows as `prefill` returns them)."""
+    if cfg.scanned:
+        return _sequence_runs(params, tokens, true_len, cfg)
     b, s = tokens.shape
     K = cfg.conv_kernel
     # the residual stream is float32 (weights and matmul inputs keep the
@@ -283,12 +454,62 @@ def _sequence(params, tokens, true_len, cfg: HybridConfig):
     return x, rows, routing
 
 
+def _sequence_runs(params, tokens, true_len, cfg: HybridConfig):
+    """`_sequence` for the runs form: every run one `lax.scan` over its
+    stacked weights. State rows: per Mamba run "ssm" [k, b, n, di] float32
+    and "conv" [k, b, K-1, di]; "k", "v" [attention layers, b, kvh, s, hd]."""
+    b, s = tokens.shape
+    H, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    x = params["embed"][tokens].astype(F32)
+    valid = jnp.arange(s)[None, :] < true_len[:, None]               # [b, s]
+
+    def mamba_layer(x, lp):
+        with jax.named_scope("mamba"):
+            _, h = _normed(cfg, x, lp["mixer_norm"])
+            out, state, tail = _mamba_seq(cfg, lp["mamba"], h, valid, true_len)
+            x = x + out.astype(F32)
+        return _dense_ffn(cfg, lp, x), (state, tail)
+
+    def attn_layer(x, lp):
+        with jax.named_scope("attention"):
+            _, h = _normed(cfg, x, lp["mixer_norm"])
+            q, k, v = _attn_qkv(cfg, lp["attn"], h)
+            attn = causal_attention_blocked(
+                q, jnp.repeat(k, H // kvh, axis=2), jnp.repeat(v, H // kvh, axis=2),
+                sm_scale=hd ** -0.5)
+            x = x + (attn.reshape(b, s, H * hd) @ lp["attn"]["wo"]).astype(F32)
+        return _dense_ffn(cfg, lp, x), (jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2))
+
+    rows = {"ssm": [], "conv": [], "k": [], "v": []}
+    for rp, (mixer, _) in zip(params["runs"], cfg.runs()):
+        if mixer == "mamba":
+            x, (state, tail) = jax.lax.scan(mamba_layer, x, rp)
+            rows["ssm"].append(state)
+            rows["conv"].append(tail)
+        else:
+            x, (k, v) = jax.lax.scan(attn_layer, x, rp)
+            rows["k"].append(k)
+            rows["v"].append(v)
+    for name in ("k", "v"):
+        rows[name] = jnp.concatenate(rows[name]) if rows[name] else \
+            jnp.zeros((0, b, kvh, s, hd), cfg.dtype)
+    _, x = _normed(cfg, x, params["final_norm"])
+    return x, rows, []
+
+
+def _head(params, x):
+    """Features -> logits float32: the untied head, or the embedding."""
+    if "lm_head" in params:
+        return (x @ params["lm_head"]).astype(F32)
+    return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(F32)
+
+
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def forward(params, tokens, cfg: HybridConfig):
     """tokens [b, s] -> logits [b, s, vocab] float32."""
     full = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
     x, _, _ = _sequence(params, tokens, full, cfg)
-    return (x @ params["lm_head"]).astype(F32)
+    return _head(params, x)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "with_routing"))
@@ -296,7 +517,8 @@ def prefill(params, tokens, true_len, cfg: HybridConfig,
             with_routing: bool = False):
     """-> (logits at the last true position [nb, vocab] float32, state rows
     {"S": [per KDA layer [nb, H, dk, dv]], "conv": [per KDA layer
-    [nb, K-1, 3 H dk]], "latent": [MLA layers, nb, s, rank + rope]}).
+    [nb, K-1, 3 H dk]], "latent": [MLA layers, nb, s, rank + rope]}; the
+    runs form's rows: `_sequence_runs`).
     `with_routing` adds "routing" [expert layers, nb, s, k]: the experts
     every position chose (for a comparison that has to tell a near-tie in
     the router from an error; the engine never asks for it)."""
@@ -305,7 +527,7 @@ def prefill(params, tokens, true_len, cfg: HybridConfig,
         rows["routing"] = jnp.stack(routing)
     last = jnp.take_along_axis(x, (true_len - 1)[:, None, None], axis=1)[:, 0]
     with jax.named_scope("head"):
-        return (last @ params["lm_head"]).astype(F32), rows
+        return _head(params, last), rows
 
 
 # ---------------------------------------------------------------- decode
@@ -364,6 +586,88 @@ def _decode(params, state, lengths, tokens, active, cfg: HybridConfig,
             jnp.stack([landed, touched]), routing)
 
 
+def _decode_runs(params, state, lengths, tokens, cfg: HybridConfig,
+                 attn_len: int):
+    """`_decode` for the runs form -> (state, logits [B, vocab] float32). A
+    Mamba run's stacked state is the scan's CARRY: layer i reads its slice
+    and writes it back in place. The K/V cache is read-only inside the scans
+    (the current token's row joins the softmax as a term of its own, STRICT
+    mask) and every attention layer's row is written once, afterwards. A
+    slot of length 0 is idle: its attention reads nothing and, on the TPU,
+    its recurrent state is neither read nor written (elsewhere it runs on
+    over whatever token the slot holds); it is replaced at admission."""
+    B = tokens.shape[0]
+    H, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    x = params["embed"][tokens].astype(F32)
+    k_all, v_all = state["k"], state["v"]
+    # as the dense step chooses (`models/serving.py:decode_step_fused`): on a
+    # TPU at shapes that tile, the kernel over each slot's live rows
+    kernel = decode_attention.uses_decode_kernel(k_all, attn_len)
+    if kernel:
+        blocks = decode_attention.live_blocks(lengths, attn_len)
+    else:
+        mask = jnp.arange(attn_len)[None, :] < lengths[:, None]
+
+    busy = lengths > 0
+    slots = mamba.live_slots(lengths) \
+        if state["ssm"] and mamba.uses_step_kernel(state["ssm"][0]) else None
+
+    def mamba_layer(carry, inputs):
+        x, ssm, conv = carry
+        lp, i = inputs
+        with jax.named_scope("mamba"):
+            _, h = _normed(cfg, x, lp["mixer_norm"])
+            out, ssm, t_new = _mamba_step(
+                cfg, lp["mamba"], h, ssm, i, slots, busy,
+                jax.lax.dynamic_index_in_dim(conv, i, 0, keepdims=False))
+            x = x + out.astype(F32)
+        with jax.named_scope("state_write"):
+            conv = jax.lax.dynamic_update_index_in_dim(conv, t_new, i, 0)
+        return (_dense_ffn(cfg, lp, x), ssm, conv), None
+
+    def attn_layer(x, inputs):
+        lp, layer = inputs
+        with jax.named_scope("attention"):
+            _, h = _normed(cfg, x, lp["mixer_norm"])
+            q, k_cur, v_cur = _attn_qkv(cfg, lp["attn"], h)
+            k_cur, v_cur = k_cur.astype(cfg.dtype), v_cur.astype(cfg.dtype)
+            if kernel:
+                attn = decode_attention.gqa_decode_attention(
+                    q.reshape(B, kvh, H // kvh, hd), k_cur, v_cur, k_all, v_all,
+                    layer, blocks, attn_len)
+            else:
+                win = (1, B, kvh, attn_len, hd)
+                attn = _gqa_decode_attention(
+                    q[:, :, None],
+                    jax.lax.dynamic_slice(k_all, (layer, 0, 0, 0, 0), win)[0],
+                    jax.lax.dynamic_slice(v_all, (layer, 0, 0, 0, 0), win)[0],
+                    k_cur, v_cur, mask)
+            x = x + (attn.reshape(B, H * hd) @ lp["attn"]["wo"]).astype(F32)
+        return _dense_ffn(cfg, lp, x), (k_cur, v_cur)
+
+    ssm_new, conv_new, k_cur, v_cur = [], [], [], []
+    held, n_attn = iter(zip(state["ssm"], state["conv"])), 0
+    for rp, (mixer, k) in zip(params["runs"], cfg.runs()):
+        if mixer == "mamba":
+            (x, ssm, conv), _ = jax.lax.scan(mamba_layer, (x, *next(held)),
+                                             (rp, jnp.arange(k)))
+            ssm_new.append(ssm)
+            conv_new.append(conv)
+        else:
+            x, (kc, vc) = jax.lax.scan(attn_layer, x, (rp, n_attn + jnp.arange(k)))
+            k_cur.append(kc)
+            v_cur.append(vc)
+            n_attn += k
+    if k_cur:
+        with jax.named_scope("state_write"):
+            k_all = write_rows(k_all, jnp.concatenate(k_cur), lengths)
+            v_all = write_rows(v_all, jnp.concatenate(v_cur), lengths)
+    with jax.named_scope("head"):
+        _, x = _normed(cfg, x, params["final_norm"])
+        logits = _head(params, x)
+    return {"ssm": ssm_new, "conv": conv_new, "k": k_all, "v": v_all}, logits
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
                    donate_argnums=(1,))
 def decode_logits(params, state, lengths, tokens, active, cfg: HybridConfig,
@@ -371,7 +675,11 @@ def decode_logits(params, state, lengths, tokens, active, cfg: HybridConfig,
     """The decode step for callers that need logits: the body of
     `decode_step` over the same slot state (DONATED) and the same `active`
     mask, returning (state, logits [B, vocab], the experts every slot chose
-    [expert layers, B, k]) instead of sampling."""
+    [expert layers, B, k]) instead of sampling. The runs form has no
+    routing ([0, B, 0]) and takes no `active`."""
+    if cfg.scanned:
+        state, logits = _decode_runs(params, state, lengths, tokens, cfg, attn_len)
+        return state, logits, jnp.zeros((0, tokens.shape[0], 0), jnp.int32)
     state, logits, _, routing = _decode(params, state, lengths, tokens, active,
                                         cfg, attn_len)
     return state, logits, jnp.stack(routing)
@@ -386,7 +694,12 @@ def decode_step(params, state, lengths, tokens, active, cfg: HybridConfig,
     slot still computes (static shapes) but is routed to no expert. Returns
     (state, lengths + 1, next tokens [B], report [B + 2] = the next tokens
     followed by the two expert-layer counters: ONE array crosses to the
-    host per step)."""
+    host per step). The runs form keeps the dense engine's rule for idle
+    slots (length 0 stays 0) and has no counters: its report is the tokens."""
+    if cfg.scanned:
+        state, logits = _decode_runs(params, state, lengths, tokens, cfg, attn_len)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return state, lengths + (lengths > 0), nxt, nxt
     state, logits, counters, _ = _decode(params, state, lengths, tokens, active,
                                          cfg, attn_len)
     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -405,6 +718,8 @@ def _write_state(state, lengths, tokens, slots, rows, true_len, first):
     slot's recurrent state is REPLACED, which is its reset). `slots`
     entries equal to the number of slots are batch padding and are
     dropped. `tokens` is not donated (the step in flight reads it)."""
+    if "ssm" in state:
+        return _write_runs(state, lengths, tokens, slots, rows, true_len, first)
     with jax.named_scope("state_write"):
         put = lambda whole, part: whole.at[slots].set(part, mode="drop")
         bucket = rows["latent"].shape[2]
@@ -412,6 +727,20 @@ def _write_state(state, lengths, tokens, slots, rows, true_len, first):
                  "conv": [put(a, r) for a, r in zip(state["conv"], rows["conv"])],
                  "latent": state["latent"].at[:, slots, :, :bucket].set(
                      rows["latent"][:, :, None], mode="drop")}
+    return (state, lengths.at[slots].set(true_len, mode="drop"),
+            tokens.at[slots].set(first, mode="drop"))
+
+
+def _write_runs(state, lengths, tokens, slots, rows, true_len, first):
+    """`_write_state` for the runs form: slots on the second axis of every
+    stacked leaf; the K/V rows of the prompt's bucket."""
+    with jax.named_scope("state_write"):
+        put = lambda whole, part: whole.at[:, slots].set(part, mode="drop")
+        bucket = rows["k"].shape[3]
+        kv = lambda whole, part: whole.at[:, slots, :, :bucket].set(part, mode="drop")
+        state = {"ssm": [put(a, r) for a, r in zip(state["ssm"], rows["ssm"])],
+                 "conv": [put(a, r) for a, r in zip(state["conv"], rows["conv"])],
+                 "k": kv(state["k"], rows["k"]), "v": kv(state["v"], rows["v"])}
     return (state, lengths.at[slots].set(true_len, mode="drop"),
             tokens.at[slots].set(first, mode="drop"))
 
@@ -461,3 +790,40 @@ class HybridCache:
         """What one decode step moved, known on the host at dispatch."""
         return {"state_slots": n_active if self.n_kda else 0,
                 "latent_rows": live_rows if self.n_mla else 0}
+
+
+class RunsCache(HybridCache):
+    """Per-slot state of the runs form: per Mamba run the SSM state
+    [k, slots, d_state, d_inner] float32 (channels minor, `ops/mamba.py`) and
+    the convolution tail [k, slots, K-1, d_inner]; for the attention layers
+    K and V [layers, slots, kv_heads, max_len, head_dim], written by
+    `ops.cache.write_rows` and read by `ops.pallas.decode_attention` like the
+    dense cache. Every leaf is donated whole to each call. The entry points
+    are `HybridCache`'s: the jitted programs branch on the configuration."""
+
+    counters = ()
+
+    def __init__(self, cfg: HybridConfig, num_slots: int, max_len: int):
+        self.cfg, self.num_slots, self.max_len = cfg, num_slots, max_len
+        runs = cfg.runs()
+        self.n_mamba = sum(k for m, k in runs if m == "mamba")
+        self.n_attn = sum(k for m, k in runs if m == "attn")
+        kv = (self.n_attn, num_slots, cfg.n_kv_heads, max_len, cfg.head_dim)
+        self.state = {
+            "ssm": [jnp.zeros((k, num_slots, cfg.d_state, cfg.d_inner), F32)
+                    for m, k in runs if m == "mamba"],
+            "conv": [jnp.zeros((k, num_slots, cfg.conv_kernel - 1, cfg.d_inner),
+                               cfg.dtype) for m, k in runs if m == "mamba"],
+            "k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype)}
+        self.prefill_args = {"state_layers": self.n_mamba, "kv_layers": self.n_attn}
+
+    def max_prefill_batch(self, bucket: int) -> int:
+        return max(1, min(8, self.cfg.prefill_tokens // bucket))
+
+    def step_args(self, n_active: int, live_rows: int,
+                  attn_len: int) -> Dict[str, int]:
+        """`state_slots`: the busy slots, whose recurrent state the step
+        needs; `kv_rows`: the positions they hold, which every attention
+        layer reads."""
+        return {"state_slots": n_active if self.n_mamba else 0,
+                "kv_rows": live_rows if self.n_attn else 0}

@@ -22,7 +22,8 @@ class LLMReplica:
                  num_slots: int = 4, max_len: int = 256):
         """`preset` names a static constructor of `ModelConfig` (`tiny`,
         `b1`: the dense block) or of `HybridConfig` (`tiny_hybrid`: KDA and
-        MLA mixers, dropless experts); the engine is the same class."""
+        MLA mixers, dropless experts; `tiny_runs`: Mamba and attention mixers
+        as scanned runs); the engine is the same class."""
         import jax
 
         from ray_tpu.models import ModelConfig, hybrid, init_params

@@ -304,6 +304,71 @@ def test_hybrid_decode_step_keeps_its_state_in_place(chip, attn_len):
     _assert_cache_stays_put(c, state["latent"])
 
 
+def _jamba(chip):
+    """(cfg, parameter shapes, slot-state shapes, slots) of the Jamba cell."""
+    import json
+    import sys
+
+    from ray_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from perfbench.lib import jamba_model
+
+    with open(os.path.join(root, "perfbench", "configs", "jamba2-3b.json")) as f:
+        conf = json.load(f)
+    cfg = jamba_model.model_config(conf)
+    slots, max_len = conf["run"]["num_slots"], conf["run"]["max_len"]
+    as_shapes = lambda tree: jax.tree_util.tree_map(
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = as_shapes(jax.eval_shape(lambda k: hybrid.init_params(k, cfg),
+                                      jax.random.PRNGKey(0)))
+    state = as_shapes(jax.eval_shape(lambda: cfg.make_cache(slots, max_len).state))
+    return cfg, params, state, slots
+
+
+def test_jamba_decode_step_carries_its_state_in_place(chip):
+    """The runs form's decode step at the benchmark cell's real shapes
+    (Jamba2-3B whole: 28 layers as five scanned runs, 256 slots x 1024): the
+    stacked SSM state of every run (2.39 GB float32) rides the scan as a
+    carry, through the `selective_step` kernel that aliases it, and aliases
+    its output, so the step holds no second copy (`temp` 2.6 MB; a scan that
+    takes the state as xs and returns it as ys would hold 2.4 GB more), and
+    both attention layers read the K/V cache through the live-rows kernel at
+    ONE kv head and a head ratio of 20."""
+    from ray_tpu.models import hybrid
+
+    cfg, params, state, slots = _jamba(chip)
+    ints = chip((slots,), jnp.int32)
+    c = hybrid.decode_step.lower(params, state, ints, ints,
+                                 chip((slots,), jnp.bool_), cfg, 1024).compile()
+    state_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(state))
+    assert 2.6e9 < state_bytes < 2.7e9
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < 64e6
+    text = c.as_text()
+    assert "gqa_decode_attention" in text and "selective_step" in text
+    _assert_cache_stays_put(c, state["k"])
+    for ssm in state["ssm"]:   # the kernel's aliased operand is not copied
+        assert _whole_cache_relayouts(c, ssm) == []
+
+
+def test_jamba_prompt_pass_runs_the_scan_kernel(chip):
+    """A prompt pass of 4 x 1023 (the longest bucket; the scan pads it to
+    eight chunks of 128): Mosaic takes the `selective_scan` kernel at the
+    published widths (blocks of 1024 of 5120 channels, 16 state columns on
+    the sublanes), one call per Mamba run's scan body."""
+    from ray_tpu.models import hybrid
+
+    cfg, params, _, _ = _jamba(chip)
+    c = hybrid._prefill_first.lower(params, chip((4, 1023), jnp.int32),
+                                    chip((4,), jnp.int32), cfg).compile()
+    assert c.as_text().count("selective_scan") >= 3
+    assert c.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 def test_prefill_slots_compiles_at_b1(chip):
     from ray_tpu.models.serving import prefill_slots
 
