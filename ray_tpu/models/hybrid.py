@@ -752,6 +752,7 @@ class HybridCache:
     [layers, slots, 1, max_len, rank + rope]."""
 
     counters = ("expert_assignments", "experts_touched")
+    idle_args: Dict[str, int] = {}
 
     def __init__(self, cfg: HybridConfig, num_slots: int, max_len: int):
         self.cfg, self.num_slots, self.max_len = cfg, num_slots, max_len
@@ -802,6 +803,7 @@ class RunsCache(HybridCache):
     are `HybridCache`'s: the jitted programs branch on the configuration."""
 
     counters = ()
+    idle_args = {"written_slots": 0}
 
     def __init__(self, cfg: HybridConfig, num_slots: int, max_len: int):
         self.cfg, self.num_slots, self.max_len = cfg, num_slots, max_len
@@ -824,6 +826,8 @@ class RunsCache(HybridCache):
                   attn_len: int) -> Dict[str, int]:
         """`state_slots`: the busy slots, whose recurrent state the step
         needs; `kv_rows`: the positions they hold, which every attention
-        layer reads."""
+        layer reads; `written_slots`: the slots whose block of K/V rows
+        `write_rows` moves, the busy ones again."""
         return {"state_slots": n_active if self.n_mamba else 0,
-                "kv_rows": live_rows if self.n_attn else 0}
+                "kv_rows": live_rows if self.n_attn else 0,
+                "written_slots": n_active if self.n_attn else 0}

@@ -19,8 +19,11 @@ loop is built to run at device speed:
     the whole cache before and after it (`copy.58/61/64/65`, two per buffer
     per step, 12.9 GB of HBM traffic a step at 24 x 32 x 8 x 1024 x 128
     bf16), so `ops.cache.write_rows` writes tile-aligned blocks of rows, which keep
-    the default layout. `tests/test_chip_compile.py` asks the chip's
-    compiler; buffer pointers on the CPU alias either way;
+    the default layout: on the TPU one Pallas call a cache, aliased to its
+    output, that moves the busy slots' blocks and no idle slot's (up to
+    PR 32 a loop of 64 dependent updates over every slot, 1.2 ms a step).
+    `tests/test_chip_compile.py` asks the chip's compiler; buffer pointers
+    on the CPU alias either way;
   * attention reads a power-of-2 *bucket* of the cache (compiled once per
     bucket) instead of all max_len rows, and on the TPU, within the bucket,
     only each slot's blocks of rows that hold tokens
@@ -188,7 +191,8 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
     """The hot decode step: one token for every slot, greedy sampling fused
     on device, K/V/length buffers DONATED and updated in place (no
     [L, B, kvh, max_len, hd] reallocation, and no copy of one either:
-    `_write_rows` says what that takes on the TPU).
+    `_write_rows` says what that takes on the TPU, and moves only the
+    blocks of the slots that hold something).
 
     The caches are READ-ONLY inside the layer scan — a scan that carries
     the cache through its ys gets double-buffered by XLA even when the
@@ -204,8 +208,8 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
     for none. On the CPU path every slot pays for the window.
 
     A slot with length 0 is IDLE: it computes its self term alone (finite
-    garbage nobody reads) and stays at 0. The engine zeroes a retired slot's
-    length (`ContinuousBatchingEngine._retire_slots`).
+    garbage nobody reads), writes no row and stays at 0. The engine zeroes a
+    retired slot's length (`ContinuousBatchingEngine._retire_slots`).
 
     Returns (k_all, v_all, lengths + 1 where a slot holds something,
     next_tokens [B] int32) — the caller keeps everything on device; only
@@ -290,6 +294,8 @@ class DenseKVCache:
                                     the tokens (summed into `engine.step`)
         step_args(n_active, live_rows, attn_len), prefill_args
                                     span arguments
+        idle_args                   those of a step that dispatched no
+                                    decode
 
     It calls the module's own jitted `prefill_slots`, `_write_slots` and
     `decode_step_fused`, so the dense model compiles to the programs it
@@ -297,6 +303,7 @@ class DenseKVCache:
 
     counters: Tuple[str, ...] = ()
     prefill_args: Dict[str, int] = {}
+    idle_args: Dict[str, int] = {"written_slots": 0}
 
     def __init__(self, cfg: ModelConfig, num_slots: int, max_len: int):
         self.cfg, self.max_len = cfg, max_len
@@ -328,9 +335,12 @@ class DenseKVCache:
                   attn_len: int) -> Dict[str, int]:
         """The rows that hold a token, which the step has to read, beside the
         window of every slot to the deepest bucket, which it read up to
-        PR 28 (and still reads on the CPU path)."""
+        PR 28 (and still reads on the CPU path); and the slots whose block
+        of rows `ops.cache.write_rows` moves, the busy ones, where the loop
+        it replaces on the TPU moved every slot's."""
         return {"live_rows": live_rows,
-                "window_rows": self.state["k"].shape[1] * attn_len}
+                "window_rows": self.state["k"].shape[1] * attn_len,
+                "written_slots": n_active}
 
 
 def _pow2(n: int) -> int:
@@ -514,7 +524,8 @@ class ContinuousBatchingEngine:
                 prefill_batches=len(admissions),
                 active=len(self._pending[1]) if self._pending else 0,
                 attn_len=self._attn_len if self._pending else 0,
-                **(self._step_args if self._pending else {}))
+                **(self._step_args if self._pending
+                   else self.cache.idle_args))
             self._drain_pending_first()                   # device wait, no _lock
             # the model's counters ride the token array, so they are those
             # of the step reaped here: the one dispatched a step earlier

@@ -164,15 +164,38 @@ def test_adamw_leaf_update_compiles(chip):
     assert _kernels(c) == 1
 
 
-def _assert_decode_step_reads_live_rows_in_place(c, kv, n_kernels=1):
+def _count(compiled, cache, opcode) -> int:
+    """Instructions `opcode` of the compiled program whose result has the
+    cache's dimensions."""
+    dims = ",".join(map(str, cache.shape))
+    return sum(1 for _, d, op in _INSTRUCTION.findall(compiled.as_text())
+               if op == opcode and d == dims)
+
+
+def _loops(compiled) -> int:
+    return len(re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = .* while\(",
+                          compiled.as_text(), re.M))
+
+
+def _assert_decode_step_reads_live_rows_in_place(c, kv, n_kernels=1,
+                                                 write_kernel=True):
     """The decode step on the chip: the length-aware attention kernel is in
     it (ONE Mosaic call, inside the layer scan; Mosaic has then accepted its
     blocks and its VMEM, or `.compile()` would have raised), both donated
     caches alias their outputs, no instruction relays a whole cache out, and
     the step's temporaries stay under one layer of one cache: the kernel
     takes the caches WHOLE, so nothing stands between the donated parameter
-    and its consumer for XLA:TPU to copy."""
-    assert _kernels(c) == n_kernels
+    and its consumer for XLA:TPU to copy. Since PR 33 the row write is a
+    Mosaic call of its own per cache (`ops.cache.write_rows`), each a
+    custom call whose result IS the cache: the layer scan is then the
+    step's only loop (the parent had two more, over the slots), and no
+    `dynamic-update-slice` of a cache's shape is left. Off the write
+    kernel's shapes the loops and their updates are what remains."""
+    n_writes = 2 if write_kernel else 0
+    assert _kernels(c) == n_kernels + n_writes
+    assert _count(c, kv, "custom-call") == n_writes
+    assert _count(c, kv, "dynamic-update-slice") == 2 - n_writes
+    assert _loops(c) == 1 + (2 - n_writes)
     assert c.memory_analysis().alias_size_in_bytes >= 2 * (kv.size * 2)
     _assert_cache_stays_put(c, kv, _one_layer_bytes(kv))
 
@@ -213,8 +236,9 @@ def test_decode_step_keeps_the_cache_layout(chip, attn_len):
     `copy.64`, `copy.65` (-> the outputs), each bf16[24,32,8,1024,128],
     1.61 GB read and written, and `temp` 1.616 GB: 12.9 GB of HBM traffic in
     every step, 63% of the serving cell's device time (ledger, PR 26). The
-    tile-aligned block write (`ops.cache.write_rows`) leaves two in-place
-    `dynamic-update-slice` in the default layout. With that alone the
+    tile-aligned block write (`ops.cache.write_rows`) left two in-place
+    `dynamic-update-slice` in the default layout, each in a loop over the
+    slots (since PR 33 one aliased Mosaic call each). With that alone the
     buckets under max_len still fail here, on `temp` 0.068 GB: one layer's
     [32,8,1024,128] copied to read `[:, :, :attn_len]` of it; the window
     read by one `dynamic_slice` leaves 0.0006 GB. Since PR 29 the step reads
@@ -238,6 +262,7 @@ def test_decode_step_off_the_kernels_shapes_keeps_the_cache_layout(chip):
     the whole cache, as every window did up to PR 28: no Mosaic call, and
     still no copy of a cache or of a layer of one."""
     from ray_tpu.models.serving import decode_step_fused
+    from ray_tpu.ops import cache as cache_ops
     from ray_tpu.ops.pallas import decode_attention
 
     max_len = 1000
@@ -247,7 +272,9 @@ def test_decode_step_off_the_kernels_shapes_keeps_the_cache_layout(chip):
     ints = chip((CELL_SLOTS,), jnp.int32)
     c = decode_step_fused.lower(_param_shapes(chip, INTERNLM2), kv, kv, ints,
                                 ints, INTERNLM2, max_len).compile()
-    _assert_decode_step_reads_live_rows_in_place(c, kv, n_kernels=0)
+    assert not cache_ops.uses_write_kernel(kv)  # 1000 rows: 62.5 blocks of 16
+    _assert_decode_step_reads_live_rows_in_place(c, kv, n_kernels=0,
+                                                 write_kernel=False)
 
 
 def test_write_slots_keeps_the_cache_layout(chip):
@@ -273,10 +300,17 @@ def test_hybrid_decode_step_keeps_its_state_in_place(chip, attn_len):
     max_len, 576] and reshaped around `write_rows` (`copy.265` / `copy.267`
     of the whole 1.2 GB cache, `temp` 1.38 GB), and scores taken as
     "bhc,blc->bhl" against a [slots, window, 576] slice (the window
-    re-laid out with the positions minor-most: `temp` 1.30 GB at 8192)."""
+    re-laid out with the positions minor-most: `temp` 1.30 GB at 8192). A
+    third fails here since PR 33: the latent rows written by the Mosaic
+    `write_rows`. Mosaic takes a full-width block of 576 lanes, but XLA:TPU
+    keeps this array with the positions minor-most and bridges to the
+    call's row-major operand and back (`copy.498` / `copy.509` of the whole
+    cache), so the shape rule leaves a last dimension that is no multiple
+    of 128 on the loop: one in-place `dynamic-update-slice`."""
     import json
 
     from ray_tpu.models import hybrid
+    from ray_tpu.ops import cache as cache_ops
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     import sys
@@ -301,6 +335,8 @@ def test_hybrid_decode_step_keeps_its_state_in_place(chip, attn_len):
                       for a in jax.tree_util.tree_leaves(state))
     assert state_bytes > 2.1e9
     assert c.memory_analysis().alias_size_in_bytes >= state_bytes
+    assert not cache_ops.uses_write_kernel(state["latent"])
+    assert _count(c, state["latent"], "dynamic-update-slice") == 1
     _assert_cache_stays_put(c, state["latent"])
 
 
@@ -335,8 +371,10 @@ def test_jamba_decode_step_carries_its_state_in_place(chip):
     its output, so the step holds no second copy (`temp` 2.6 MB; a scan that
     takes the state as xs and returns it as ys would hold 2.4 GB more), and
     both attention layers read the K/V cache through the live-rows kernel at
-    ONE kv head and a head ratio of 20."""
+    ONE kv head and a head ratio of 20, and their rows are written by the
+    `write_rows` kernel, a grid of 256 slots over blocks [2, 1, 16, 128]."""
     from ray_tpu.models import hybrid
+    from ray_tpu.ops import cache as cache_ops
 
     cfg, params, state, slots = _jamba(chip)
     ints = chip((slots,), jnp.int32)
@@ -350,6 +388,9 @@ def test_jamba_decode_step_carries_its_state_in_place(chip):
     assert mem.temp_size_in_bytes < 64e6
     text = c.as_text()
     assert "gqa_decode_attention" in text and "selective_step" in text
+    assert cache_ops.uses_write_kernel(state["k"])
+    assert _count(c, state["k"], "custom-call") == 2   # K's and V's write
+    assert _count(c, state["k"], "dynamic-update-slice") == 0
     _assert_cache_stays_put(c, state["k"])
     for ssm in state["ssm"]:   # the kernel's aliased operand is not copied
         assert _whole_cache_relayouts(c, ssm) == []

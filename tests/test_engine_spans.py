@@ -122,6 +122,34 @@ def test_step_spans_count_the_slots_they_dispatched():
                    for s in steps if s["tid"] == w["tid"])
 
 
+@pytest.mark.parametrize("cache", ["dense", "runs"])
+def test_step_spans_count_the_slots_whose_rows_the_step_wrote(cache):
+    """`written_slots` on `engine.step`: the slots whose block of rows the
+    step's `ops.cache.write_rows` moves, which are the busy slots at
+    dispatch (on the TPU; the loop of the CPU path visits every slot), for
+    the dense cache and for the runs cache; 0 on a step that dispatched no
+    decode (one more call of the stepper once every request is done: it
+    reaps the last junk slot-step and finds nothing busy)."""
+    if cache == "dense":
+        cfg, params = CFG, PARAMS
+    else:
+        from ray_tpu.models import hybrid
+        cfg = hybrid.HybridConfig.tiny_runs()
+        params = hybrid.init_params(jax.random.PRNGKey(0), cfg)
+    eng = ContinuousBatchingEngine(params, cfg, num_slots=4, max_len=64)
+    for i, n in enumerate([5, 2, 4]):
+        eng.submit([i + 1, 5], max_new_tokens=n)
+    eng.run_until_done()
+    eng.step()
+    steps = [e["args"] for e in _engine_spans() if e["name"] == "engine.step"]
+    assert all(a["written_slots"] == a["active"] for a in steps)
+    assert {a["written_slots"] for a in steps} == {0, 1, 2, 3}
+    assert steps[-1]["written_slots"] == 0 and steps[-1]["attn_len"] == 0
+    # the other arguments of the cache stay those of a dispatched step
+    own = "live_rows" if cache == "dense" else "kv_rows"
+    assert all((own in a) == (a["active"] > 0) for a in steps)
+
+
 def test_compiles_are_spans_named_by_program():
     # another file's test in this worker's process may have compiled the same
     # `prefill_slots` (it does not depend on the number of slots): a cache

@@ -162,35 +162,100 @@ def _write_rows_one_row_window(cache, rows, lengths):
     return wr(cache, rows[:, :, :, None], lengths)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-@pytest.mark.parametrize("max_len", [64, 40, 12])
-def test_write_rows_matches_the_one_row_window(max_len, dtype):
-    """The step's block write (read the tile-aligned R rows around the
-    position, select the new row in, write the block back) against the
-    one-row window it replaced: bit-identical caches for every slot whose
-    position is inside the cache, at the block edges and the cache's end,
-    for `max_len` a multiple of R, not a multiple (40) and under R (12);
-    a slot past the end (an idle slot keeps counting) writes nothing."""
+def _check_write_rows(monkeypatch, execution, dims, max_len, dtype, fill):
+    """`write_rows` through one of its two executions against the one-row
+    window: bit-identical for every slot that holds something, every other
+    slot untouched. "kernel" steers the device branch (`_util.on_tpu`) and
+    interprets the Pallas call; where the shape rule leaves a shape on the
+    loop, that is asserted and the loop is what runs."""
+    from ray_tpu.ops import cache as cache_ops
+    from ray_tpu.ops.pallas import _util
+
+    L, kvh, hd = dims
     R = 32 // jnp.dtype(dtype).itemsize
-    L, kvh, hd = 3, 2, 8
-    inside = sorted({p for p in (0, R - 1, R, 2 * R - 1, max_len - 2,
+    inside = sorted({p for p in (0, 1, R - 1, R, 2 * R - 1, max_len - 2,
                                  max_len - 1) if 0 <= p < max_len})
     past = [max_len, max_len + 1, max_len + R, 10 * max_len]
-    lengths = jnp.asarray(inside + past, jnp.int32)
-    B = len(inside) + len(past)
+    positions = {"ragged": inside + past + [0],  # idle slots first and last
+                 "all_idle": [0] * 5,
+                 "all_busy": [p for p in inside if p > 0]}[fill]
+    lengths = jnp.asarray(positions, jnp.int32)
+    B = len(positions)
     kc, kr = jax.random.split(jax.random.PRNGKey(max_len))
     cache = (jax.random.normal(kc, (L, B, kvh, max_len, hd)) + 3.0).astype(dtype)
     rows = (jax.random.normal(kr, (L, B, kvh, hd)) - 3.0).astype(dtype)
 
+    if execution == "kernel":
+        monkeypatch.setattr(_util, "on_tpu", lambda: True)
+        monkeypatch.setattr(_util, "interpret_mode", lambda: True)
+        tiles = max_len % R == 0 and hd % 128 == 0
+        assert cache_ops.uses_write_kernel(cache) == tiles
+    else:
+        assert not cache_ops.uses_write_kernel(cache)  # the CPU here
     got = np.asarray(jax.jit(_write_rows)(cache, rows, lengths), np.float32)
     ref = np.asarray(_write_rows_one_row_window(cache, rows, lengths), np.float32)
-    n = len(inside)
-    np.testing.assert_array_equal(got[:, :n], ref[:, :n])
+    written = [b for b, pos in enumerate(positions) if 0 < pos < max_len]
+    np.testing.assert_array_equal(got[:, written], ref[:, written])
     want, new = np.array(cache, np.float32), np.asarray(rows, np.float32)
-    for b, pos in enumerate(inside):
-        want[:, b, :, pos] = new[:, b]
-    np.testing.assert_array_equal(got, want)  # slots past the end untouched
+    for b in written:
+        want[:, b, :, positions[b]] = new[:, b]
+    # a slot of length 0 and a slot at or past the end: untouched
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("execution", ["loop", "kernel"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("max_len", [64, 40, 12])
+def test_write_rows_matches_the_one_row_window(max_len, dtype, execution,
+                                               monkeypatch):
+    """The step's block write (read the tile-aligned R rows around the
+    position, select the new row in, write the block back) against the
+    one-row window it replaced, through the loop AND through the kernel:
+    bit-identical caches for every slot whose position is inside the cache,
+    at the block edges and the cache's end, for `max_len` a multiple of R,
+    not a multiple (40 in bf16) and under R (12; both loop only, by the
+    shape rule); a slot past the end (the hybrid model's idle slots keep
+    counting) writes nothing, and a slot of length 0 holds nothing and
+    writes nothing."""
+    _check_write_rows(monkeypatch, execution, (3, 2, 128), max_len, dtype,
+                      "ragged")
+
+
+@pytest.mark.parametrize("execution", ["loop", "kernel"])
+@pytest.mark.parametrize("fill", ["ragged", "all_idle", "all_busy"])
+@pytest.mark.parametrize("dims", [(3, 2, 128), (2, 1, 128), (2, 1, 72)],
+                         ids=["dense", "one_kv_head", "latent_lanes"])
+def test_write_rows_at_every_callers_shape_and_fill(dims, fill, execution,
+                                                    monkeypatch):
+    """The three callers' shapes in small (the dense step's; the runs
+    form's ONE kv head of 128; the hybrid step's latent rows, one "kv head"
+    whose last dimension is no multiple of 128 lanes and so stays on the
+    loop), with idle slots among the busy ones, every slot idle (the kernel
+    then holds one block and passes it through) and every slot busy."""
+    _check_write_rows(monkeypatch, execution, dims, 64, jnp.bfloat16, fill)
+
+
+def test_the_write_kernel_is_chosen_by_what_the_code_can_see(monkeypatch):
+    """Platform and shape, as `decode_attention.uses_decode_kernel` chooses:
+    no option, no model's name. The three cells' caches, then the shapes the
+    rule leaves on the loop."""
+    from ray_tpu.ops import cache as cache_ops
+    from ray_tpu.ops.pallas import _util
+
+    bf16 = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16)
+    chat, burst, longgen = (bf16(24, 32, 8, 1024, 128), bf16(2, 256, 1, 1024, 128),
+                            bf16(2, 64, 1, 8192, 576))
+    assert not cache_ops.uses_write_kernel(chat)  # the CPU here
+    monkeypatch.setattr(_util, "on_tpu", lambda: True)
+    assert cache_ops.uses_write_kernel(chat)
+    assert cache_ops.uses_write_kernel(burst)
+    assert not cache_ops.uses_write_kernel(longgen)  # 4.5 lane tiles
+    assert not cache_ops.uses_write_kernel(bf16(24, 32, 8, 1000, 128))  # 62.5 blocks
+    assert not cache_ops.uses_write_kernel(bf16(2, 4, 2, 8, 128))  # under R
+    assert cache_ops.uses_write_kernel(
+        jax.ShapeDtypeStruct((2, 4, 2, 8, 128), jnp.float32))  # R is 8 there
+    assert not cache_ops.uses_write_kernel(bf16(96, 4, 16, 1024, 128))  # 4 x 6 MB
 
 
 def test_progress_and_submit_not_blocked_during_step():
