@@ -3,6 +3,8 @@
     python chip_smoke.py              # one chip: train phase, then serve phase
     python chip_smoke.py --chips 4    # four chips: sharded train vs one device,
                                       # then four one-chip replicas
+    python chip_smoke.py --model pangu  # one chip: the openPangu-Ultra-MoE cut,
+                                        # prompt pass + verify steps + module
     python chip_smoke.py --model jamba  # one chip, one minute: the Jamba stack
                                       # (scanned runs of Mamba and attention
                                       # layers) against its float32 reference
@@ -751,13 +753,162 @@ def run_jamba(args) -> dict:
     return rep
 
 
+# --------------------------------------------------------------------------
+# --model pangu: one prompt pass and eight verified positions of the
+# full-width openPangu-Ultra-MoE cut through its slot state, the prediction
+# module with them, against the plain float32 reference
+
+
+def pangu_check(seed: int, rehearsal: bool) -> dict:
+    """Runs in a worker that holds the chip: weights from the seed at the
+    published widths (`perfbench/configs/openpangu-ultra-moe-718b.1of32.json`;
+    a rehearsal takes `HybridConfig.tiny_rotary()`), two prompts of 1500 and
+    1999 tokens in one prompt pass of 2 x 2048, their latent rows (the
+    module's with them) written into slots 3 and 20 of 32, then the verify
+    step, two positions a slot, teacher-forced through the donated slot
+    state, the slots advanced by 2, 1, 2, 1, 2 (a draft that held, a refused
+    one whose row is overwritten): the main logits and the module's of both
+    prompts' last positions and of every verified position against the
+    reference's full forward under the program's choice of experts. Limits:
+    the benchmark cell's own (`perfbench/traffic/longctx-open-loop.json`)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench.lib import pangu_model
+    from perfbench.lib.manifest import load_py
+    from ray_tpu.models import hybrid
+
+    rep = _device_report()
+    _require_chip(rep, rehearsal)
+    if rehearsal:  # the CPU backend shows every virtual device
+        rep["device_count"] = 1
+    root = os.path.dirname(os.path.abspath(__file__))
+    ref = load_py(os.path.join(root, "perfbench", "references",
+                               "openpangu_ultra_moe.py"))
+    with open(os.path.join(root, "perfbench", "configs",
+                           "openpangu-ultra-moe-718b.1of32.json")) as f:
+        c = json.load(f)
+    with open(os.path.join(root, "perfbench", "traffic",
+                           "longctx-open-loop.json")) as f:
+        limits = json.load(f)["limits"]
+    if rehearsal:
+        c.update(hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+                 num_hidden_layers=3, num_attention_heads=8, kv_lora_rank=32,
+                 q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                 v_head_dim=16, num_experts_per_tok=2, vocab_size=512,
+                 torch_dtype="float32",
+                 experts_held={"of": 8, "first": 0, "count": 8})
+    cfg = pangu_model.model_config(c)
+    slots, max_len, bucket, lens, at = (8, 256, 64, [37, 50], [3, 5]) if rehearsal \
+        else (32, 8192, 2048, [1500, 1999], [3, 20])
+    keeps, steps = (2, 1, 2, 1, 2), 8
+    total = bucket + (64 if rehearsal else 128)   # the reference goes 128 rows at a time
+    t0 = time.time()
+    params = pangu_model.make_params(cfg, seed)
+    cache = cfg.make_cache(slots, max_len)
+    jax.block_until_ready((params, cache.state))
+    rng = np.random.default_rng(seed)
+    whole = rng.integers(1, cfg.vocab_size, (2, total)).astype(np.int32)
+    prompt = np.zeros((2, bucket), np.int32)
+    for j, n in enumerate(lens):
+        prompt[j, :n] = whole[j, :n]
+    lens_d = jnp.asarray(lens, jnp.int32)
+    t1 = time.time()
+    # teacher-forced: the module at a prompt's last position is fed `whole`'s
+    # next token, as the reference will be, not this pass's own choice
+    logits, rows = hybrid.prefill(
+        params, jnp.asarray(prompt), lens_d, cfg, with_routing=True,
+        first=jnp.asarray([whole[j, n] for j, n in enumerate(lens)], jnp.int32))
+    routing = np.asarray(rows.pop("routing"))
+    module = np.asarray(rows.pop("mtp_logits"))
+    got = {(j, n - 1): np.asarray(logits[j]) for j, n in enumerate(lens)}
+    got_mtp = {(j, n - 1): module[j] for j, n in enumerate(lens)}
+    chose = [{p: routing[:, j, p] for p in range(n)} for j, n in enumerate(lens)]
+    lengths, tokens = cache.write(
+        jnp.zeros((slots,), jnp.int32), jnp.zeros((slots,), jnp.int32),
+        jnp.asarray(at, jnp.int32), rows, lens_d, jnp.zeros((2,), jnp.int32))
+    active = np.zeros((slots,), bool)
+    active[at] = True
+    pos = list(lens)
+    attn_len = 64 if rehearsal else 2048
+    t2 = time.time()
+    for keep in keeps:
+        toks = np.zeros((slots, 2), np.int32)
+        nxt = np.zeros((slots, 2), np.int32)
+        for j in range(2):
+            toks[at[j]] = whole[j, pos[j]:pos[j] + 2]
+            nxt[at[j]] = whole[j, pos[j] + 1:pos[j] + 3]
+        cache.state, main, module, picked = hybrid.verify_logits(
+            params, cache.state, lengths, jnp.asarray(toks), jnp.asarray(nxt),
+            jnp.asarray(active), cfg, attn_len)
+        main, module, picked = (np.asarray(a) for a in (main, module, picked))
+        for j in range(2):
+            for a in range(2):
+                got[(j, pos[j] + a)] = main[at[j], a]
+                got_mtp[(j, pos[j] + a)] = module[at[j], a]
+                chose[j][pos[j] + a] = picked[:, at[j], a]
+            pos[j] += keep
+        lengths = lengths + keep * jnp.asarray(active, jnp.int32)
+    t3 = time.time()
+    # the hot step, drafting and sampling on device: the second ten of twenty
+    step_ms = []
+    for i in range(20):
+        a = time.perf_counter()
+        lengths, tokens, report = cache.decode(params, lengths, tokens, attn_len, at)
+        jax.block_until_ready(report)
+        step_ms.append(1e3 * (time.perf_counter() - a))
+    errs, errs_mtp, margin = {}, {}, 0.0
+    layers, k = routing.shape[0], routing.shape[-1]
+    routed = jax.jit(lambda p, t, r: ref.logits_routed(p, t, c, r))
+    for j in range(2):
+        forced = np.full((layers, 1, total, k), -1, np.int32)
+        for p, picked in chose[j].items():
+            forced[:, 0, p] = picked
+        want, want_mtp, worst = routed(params, jnp.asarray(whole[j:j + 1]),
+                                       jnp.asarray(forced))
+        margin = max(margin, float(worst))
+        for (jj, p), row in got.items():
+            if jj == j:
+                errs[f"{j}:{p}"] = float(ref.rel_err(jnp.asarray(row), want[0, p]))
+        for (jj, p), row in got_mtp.items():
+            if jj == j:
+                errs_mtp[f"{j}:{p}"] = float(ref.rel_err(jnp.asarray(row), want_mtp[0, p]))
+    assert pos[0] == lens[0] + steps
+    return {**rep, "params": int(sum(a.size for a in jax.tree_util.tree_leaves(params))),
+            "init_s": t1 - t0, "prefill_and_write_s": t2 - t1,
+            "verify_steps_s": t3 - t2,
+            "decode_step_ms_p50": float(np.median(step_ms[10:])),
+            "logits_rel_err_max": max(errs.values()),
+            "mtp_logits_rel_err_max": max(errs_mtp.values()),
+            "route_margin_max": margin, "logits_rel_err": errs,
+            "limits": limits,
+            "peak_bytes": int((jax.devices()[0].memory_stats() or {}).get(
+                "peak_bytes_in_use", 0))}
+
+
+def run_pangu(args) -> dict:
+    task = ray_tpu.remote(resources={"TPU": 1}, num_cpus=0)(pangu_check)
+    rep = ray_tpu.get(task.remote(args.seed, args.cpu_rehearsal), timeout=3000)
+    check_device(rep, "pangu", 1, args.cpu_rehearsal)
+    say("pangu", **{k: v for k, v in rep.items() if k != "logits_rel_err"})
+    say("pangu", logits_rel_err=rep["logits_rel_err"])
+    for name, key in (("logits_rel_err_max", "prefill_logits_rel_err"),
+                      ("mtp_logits_rel_err_max", "mtp_logits_rel_err"),
+                      ("route_margin_max", "route_margin_max")):
+        limit = 1e-4 if args.cpu_rehearsal else rep["limits"][key]
+        require(rep[name] < limit,
+                f"the openPangu stack's {name} is {rep[name]:.4g}, limit {limit:g}")
+    return rep
+
+
 def main() -> None:
     global TAG
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
-    ap.add_argument("--model", choices=("b1", "jamba"), default="b1",
-                    help="jamba: only the Jamba stack against its reference")
+    ap.add_argument("--model", choices=("b1", "jamba", "pangu"), default="b1",
+                    help="jamba / pangu: only that stack against its reference")
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="tiny model on the CPU; proves nothing about the chip")
     args = ap.parse_args()
@@ -787,6 +938,8 @@ def main() -> None:
     try:
         if args.model == "jamba":
             rep = run_jamba(args)
+        elif args.model == "pangu":
+            rep = run_pangu(args)
         elif args.chips == 4:
             rep = run_four_chips(args)
         else:

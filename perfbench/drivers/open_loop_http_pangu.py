@@ -1,0 +1,70 @@
+"""Driver of `open_loop` traffic against the openPangu-Ultra-MoE cut:
+`drivers.open_loop_http` with `lib.pangu_replica.PanguBenchReplica` in the
+replica's place. The path (HTTP stream -> proxy -> router -> replica ->
+`ContinuousBatchingEngine`), the load generator and the record are that
+driver's own (`_drive`).
+
+The check of every new cell first tries it on the PARENT's program with
+this PR's benchmark files laid over it, and that run has to fail soon and
+cleanly. A program whose engine yields one token a slot and step and whose
+latent attention has no positions (one older than
+`ray_tpu/ops/pallas/mla_decode.py`) cannot run the cell: the driver says so
+and exits at once, before any process of the cluster exists (as
+`open_loop_http_jamba` does for its cell; without it the replica's
+constructor fails inside the cluster, after its workers were spawned)."""
+
+from __future__ import annotations
+
+import asyncio
+import os
+import time
+
+from perfbench.drivers.open_loop_http import _drive
+from perfbench.lib import traffic as traffic_mod
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def run(ctx) -> dict:
+    if not os.path.isfile(os.path.join(ROOT, "ray_tpu", "ops", "pallas",
+                                       "mla_decode.py")):
+        raise SystemExit("perfbench: this program has no ray_tpu/ops/pallas/"
+                         "mla_decode.py: it cannot serve the rotary latent "
+                         "attention and the drafting step of this cell")
+    import ray_tpu
+    from perfbench.lib.pangu_replica import PanguBenchReplica
+    from ray_tpu import serve
+
+    cell, tr, config = ctx["cell"], ctx["traffic"], ctx["config"]
+    run_cfg, seconds = config["run"], float(ctx["seconds"])
+    rule = traffic_mod.slot_rule(tr, run_cfg["num_slots"])
+    print(f"[traffic] rate {tr['rate_per_s']}/s; slot rule: mean busy slots "
+          f"{rule['mean_busy_slots']:.2f} + 3 sigma = {rule['needs_slots']:.2f} "
+          f"of {run_cfg['num_slots']} (highest rate by the rule "
+          f"{rule['max_rate_per_s']:.2f}/s) {'ok' if rule['ok'] else 'BROKEN'}",
+          flush=True)
+    schedule = traffic_mod.open_loop(tr, ctx["seed"], seconds, config["vocab_size"])
+    for r in schedule:
+        r["timeout_s"] = tr["request_timeout_s"]
+
+    os.environ.update(run_cfg.get("serve_env", {}))
+    ray_tpu.init(num_cpus=8, resources={"TPU": cell["chips"]})
+    try:
+        t_ask = time.time()
+        D = serve.deployment(
+            PanguBenchReplica, name="LLM", num_replicas=1,
+            max_concurrent_queries=run_cfg["max_concurrent_queries"],
+            ray_actor_options={"resources": {"TPU": 1}, "num_cpus": 0})
+        serve.run(D.bind({k: ctx[k] for k in (
+            "config", "traffic", "seed", "rehearsal", "out_dir", "control",
+            "reference_file")}))
+        _, port = serve.start_http_proxy()
+        rec = asyncio.run(_drive("127.0.0.1", port, schedule, ctx, seconds))
+        # this model's further numbers (`lib.pangu_replica.compare_with_reference`)
+        for name in ("mtp_logits_rel_err", "route_margin_max"):
+            rec["compared"].append((name, rec["check"][name], tr["limits"][name]))
+        serve.shutdown()
+    finally:
+        ray_tpu.shutdown()
+    rec["t_ask"] = t_ask
+    return rec

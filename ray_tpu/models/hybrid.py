@@ -1,10 +1,21 @@
 """A decoder whose layers are of several kinds. Every block is
 `x += Mixer(RMSNorm(x)); x += FFN(RMSNorm(x))` over a float32 residual
-stream. The mixers: KDA linear attention (`ops/kda.py`), latent attention
-without positions (`ops/mla.py`), Mamba-1's selective scan (`ops/mamba.py`)
-and plain grouped-query attention without positions; the FFNs: dense SwiGLU
-and a dropless sigmoid-routed top-k expert layer with a shared expert
-(`ops/moe.py:dropless_moe`) that is told which experts it holds.
+stream, or with `sandwich_norm` `x += RMSNorm(Mixer(RMSNorm(x)))` and the
+same around the FFN: four norms a layer. The mixers: KDA linear attention
+(`ops/kda.py`), latent attention (`ops/mla.py`) in two kinds, without
+positions and with a full-rank query (Kimi-Linear's) or rotary with a
+low-rank query (`rope_theta`, `q_lora_rank`: openPangu-Ultra-MoE's),
+Mamba-1's selective scan (`ops/mamba.py`) and plain grouped-query attention
+without positions; the FFNs: dense SwiGLU and a dropless sigmoid-routed
+top-k expert layer with a shared expert (`ops/moe.py:dropless_moe`) that is
+told which experts it holds.
+
+A list-form configuration with `n_predict` 1 carries a multi-token
+prediction module (DeepSeek-V3's form): `h' = W_p [RMSNorm(h_i) ;
+RMSNorm(Emb(t_(i+1)))]`, one expert-layer block with latent rows of its
+own, a final norm, the main model's head: logits for t_(i+2). The decode
+step runs it as a self-drafter: it verifies `[last token, draft]` through
+the main layers, keeps one or two tokens, and drafts the next.
 
 Two ways to hold and run the stack, by what the configuration lists:
 
@@ -79,6 +90,10 @@ class HybridConfig:
     qk_nope_dim: int = 16
     qk_rope_dim: int = 8
     v_head_dim: int = 16
+    q_lora_rank: int = 0                      # 0: a full-rank query
+    rope_theta: float = 0.0                   # 0: no positions
+    sandwich_norm: bool = False               # a norm behind mixer and FFN too
+    n_predict: int = 0                        # multi-token prediction modules
     # FFN
     d_ff: int = 128
     d_expert: int = 32
@@ -109,6 +124,15 @@ class HybridConfig:
     def tiny_hybrid() -> "HybridConfig":
         """dense layer + KDA, KDA, MLA, KDA; 8 experts, top-2, one shared."""
         return HybridConfig()
+
+    @staticmethod
+    def tiny_rotary() -> "HybridConfig":
+        """dense layer + 2 expert layers, every mixer rotary MLA with a
+        low-rank query, sandwich norms, one prediction module; a vocabulary
+        small enough that a draft is sometimes right."""
+        return HybridConfig(vocab_size=16, n_layers=3, kda_layers=(), n_heads=8,
+                            q_lora_rank=24, rope_theta=1e4,
+                            sandwich_norm=True, n_predict=1, route_scale=2.5)
 
     @staticmethod
     def tiny_runs() -> "HybridConfig":
@@ -145,7 +169,10 @@ class HybridConfig:
 
     @property
     def latent_width(self) -> int:
-        return self.kv_lora_rank + self.qk_rope_dim
+        """Lanes a latent row `[c, k_r]` is stored in: whole tiles of 128 (576
+        values in 640). The cache then stays row-major on the TPU, where its
+        row write and the decode's attention run as kernels over live rows."""
+        return -(-(self.kv_lora_rank + self.qk_rope_dim) // 128) * 128
 
     def make_cache(self, num_slots: int, max_len: int):
         """This model's per-slot state for `ContinuousBatchingEngine`."""
@@ -179,10 +206,12 @@ def init_params(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
         return {"w_gate": w(lead + (d, width), d), "w_up": w(lead + (d, width), d),
                 "w_down": w(lead + (width, d), width)}
 
-    layers: List[Dict[str, Any]] = []
-    for mixer, ffn in cfg.layer_kinds():
+    def layer(mixer, ffn):
         p: Dict[str, Any] = {"mixer_norm": jnp.ones((d,), dt),
                              "ffn_norm": jnp.ones((d,), dt)}
+        if cfg.sandwich_norm:
+            p["mixer_post_norm"] = jnp.ones((d,), dt)
+            p["ffn_post_norm"] = jnp.ones((d,), dt)
         if mixer == "kda":
             step = jnp.exp(uniform((H * dk,), np.log(1e-3), np.log(1e-1)))
             p["kda"] = {
@@ -197,9 +226,13 @@ def init_params(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
         else:
             nh, dn, dr, dv = (cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim,
                               cfg.v_head_dim)
+            qr = cfg.q_lora_rank
+            query = {"wq": w((d, nh * (dn + dr)), d)} if not qr else {
+                "w_qa": w((d, qr), d), "q_norm": jnp.ones((qr,), dt),
+                "w_qb": w((qr, nh * (dn + dr)), qr)}
             p["mla"] = {
-                "wq": w((d, nh * (dn + dr)), d),
-                "w_kva": w((d, cfg.latent_width), d),
+                **query,
+                "w_kva": w((d, cfg.kv_lora_rank + dr), d),
                 "kv_norm": jnp.ones((cfg.kv_lora_rank,), dt),
                 "w_kvb": w((cfg.kv_lora_rank, nh * (dn + dv)), cfg.kv_lora_rank),
                 "wo": w((nh * dv, d), nh * dv)}
@@ -211,12 +244,23 @@ def init_params(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
                 "bias": uniform((cfg.n_experts,), -0.05, 0.05),
                 **swiglu_w(cfg.d_expert, (len(cfg.experts_held),)),
                 "shared": swiglu_w(cfg.d_expert * cfg.n_shared)}
-        layers.append(p)
-    return {"embed": (jax.random.normal(jax.random.fold_in(rng, next(counter)),
-                                        (cfg.vocab_size, d), F32) * 0.02).astype(dt),
-            "final_norm": jnp.ones((d,), dt), "layers": layers,
-            "lm_head": (jax.random.normal(jax.random.fold_in(rng, next(counter)),
-                                          (d, cfg.vocab_size), F32) * 0.02).astype(dt)}
+        return p
+
+    layers = [layer(mixer, ffn) for mixer, ffn in cfg.layer_kinds()]
+    params = {
+        "embed": (jax.random.normal(jax.random.fold_in(rng, next(counter)),
+                                    (cfg.vocab_size, d), F32) * 0.02).astype(dt),
+        "final_norm": jnp.ones((d,), dt), "layers": layers,
+        "lm_head": (jax.random.normal(jax.random.fold_in(rng, next(counter)),
+                                      (d, cfg.vocab_size), F32) * 0.02).astype(dt)}
+    if cfg.n_predict:
+        if cfg.n_predict != 1 or cfg.kda_layers:
+            raise ValueError("one prediction module, over MLA layers only")
+        # embedding and head are the main model's
+        params["mtp"] = {"h_norm": jnp.ones((d,), dt), "e_norm": jnp.ones((d,), dt),
+                         "proj": w((2 * d, d), 2 * d), "layer": layer("mla", "moe"),
+                         "final_norm": jnp.ones((d,), dt)}
+    return params
 
 
 def _init_runs(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
@@ -297,12 +341,32 @@ def _kda_output(cfg: HybridConfig, p, h, o):
     return o.astype(h.dtype) @ p["wo"]
 
 
-def _mla_latent(cfg: HybridConfig, p, h):
-    """h [..., d] -> (q [..., H, dn + dr], latent row [..., rank + dr])."""
-    q = (h @ p["wq"]).reshape(h.shape[:-1] + (cfg.n_heads, -1))
+def _mla_latent(cfg: HybridConfig, p, h, positions):
+    """h [..., s, d] at positions [..., s] -> (q [..., s, H, dn + dr], latent
+    row [..., s, latent_width]): `q_r` and the one shared `k_r` rotated
+    where the configuration has positions, the row padded to its lanes."""
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_dim
+    if "w_qa" in p:   # the low-rank query
+        h_q = rms_norm(h @ p["w_qa"], p["q_norm"], cfg.norm_eps) @ p["w_qb"]
+    else:
+        h_q = h @ p["wq"]
+    q = h_q.reshape(h.shape[:-1] + (cfg.n_heads, -1))
     ckr = h @ p["w_kva"]
-    c = rms_norm(ckr[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
-    return q, jnp.concatenate([c, ckr[..., cfg.kv_lora_rank:]], axis=-1)
+    c, k_r = rms_norm(ckr[..., :r], p["kv_norm"], cfg.norm_eps), ckr[..., r:]
+    if cfg.rope_theta:
+        q = jnp.concatenate(
+            [q[..., :dn], mla.rotate(q[..., dn:], positions, cfg.rope_theta)], -1)
+        k_r = mla.rotate(k_r[..., None, :], positions, cfg.rope_theta)[..., 0, :]
+    pad = jnp.zeros(h.shape[:-1] + (cfg.latent_width - ckr.shape[-1],), h.dtype)
+    return q, jnp.concatenate([c, k_r, pad], axis=-1)
+
+
+def _residual(cfg: HybridConfig, p, post_norm: str, x, y):
+    """x + y on the float32 stream; with sandwich norms x + RMSNorm(y)."""
+    y = y.astype(F32)
+    if cfg.sandwich_norm:
+        y = rms_norm(y, p[post_norm], cfg.norm_eps)
+    return x + y
 
 
 def _normed(cfg: HybridConfig, x, w):
@@ -335,6 +399,20 @@ def _ffn(cfg: HybridConfig, p, h32, h, valid):
         s = m["shared"]
         y = y + swiglu(h @ s["w_gate"], h @ s["w_up"]) @ s["w_down"]
     return y, landed, touched, idx
+
+
+def _ffn_half(cfg: HybridConfig, p, x, valid):
+    """The second half of a list-form block: x [..., d] float32 ->
+    (x + FFN(RMSNorm(x)), assignments landed, experts touched, the experts
+    chosen [..., k] or None for a dense layer); `valid` [...] as `_ffn`."""
+    lead, d = x.shape[:-1], x.shape[-1]
+    h32, h = _normed(cfg, x, p["ffn_norm"])
+    y, landed, touched, chosen = _ffn(cfg, p, h32.reshape(-1, d), h.reshape(-1, d),
+                                      valid.reshape(-1))
+    if chosen is not None:
+        chosen = chosen.reshape(lead + (-1,))
+    return (_residual(cfg, p, "ffn_post_norm", x, y.reshape(x.shape)), landed,
+            touched, chosen)
 
 
 def _dense_ffn(cfg: HybridConfig, lp, x):
@@ -401,9 +479,25 @@ def _attn_qkv(cfg: HybridConfig, a, h):
 # ---------------------------------------------------------------- sequence
 
 
+def _mla_seq_layer(cfg: HybridConfig, p, x, valid, positions):
+    """One layer with an MLA mixer over a whole sequence: x [b, s, d] float32
+    -> (x, latent rows [b, s, latent_width], the experts chosen or None)."""
+    b, s, _ = x.shape
+    _, h = _normed(cfg, x, p["mixer_norm"])
+    with jax.named_scope("mla"):
+        m = p["mla"]
+        q, latent = _mla_latent(cfg, m, h, positions)
+        attn = mla.mla_prefill_attention(q, latent, m["w_kvb"], cfg.kv_lora_rank,
+                                         cfg.qk_nope_dim, cfg.v_head_dim)
+        x = _residual(cfg, p, "mixer_post_norm", x, attn.reshape(b, s, -1) @ m["wo"])
+    x, _, _, chosen = _ffn_half(cfg, p, x, valid)
+    return x, latent, chosen
+
+
 def _sequence(params, tokens, true_len, cfg: HybridConfig):
-    """tokens [b, s] right-padded to true_len [b] -> (features after the
-    final norm [b, s, d], state rows as `prefill` returns them)."""
+    """tokens [b, s] right-padded to true_len [b] -> (the stack's last hidden
+    rows BEFORE the final norm [b, s, d] float32, state rows as `prefill`
+    returns them, the experts chosen per expert layer)."""
     if cfg.scanned:
         return _sequence_runs(params, tokens, true_len, cfg)
     b, s = tokens.shape
@@ -414,10 +508,14 @@ def _sequence(params, tokens, true_len, cfg: HybridConfig):
     # then picks another expert for a quarter of the tokens
     x = params["embed"][tokens].astype(F32)
     valid = jnp.arange(s)[None, :] < true_len[:, None]               # [b, s]
+    positions = jnp.arange(s)
     S_rows, conv_rows, latent_rows, routing = [], [], [], []
     for p, (mixer, _) in zip(params["layers"], cfg.layer_kinds()):
-        _, h = _normed(cfg, x, p["mixer_norm"])
-        if mixer == "kda":
+        if mixer == "mla":
+            x, latent, chosen = _mla_seq_layer(cfg, p, x, valid, positions)
+            latent_rows.append(latent)
+        else:
+            _, h = _normed(cfg, x, p["mixer_norm"])
             with jax.named_scope("kda"):
                 m = p["kda"]
                 qkv = h @ m["w_qkv"]
@@ -427,31 +525,34 @@ def _sequence(params, tokens, true_len, cfg: HybridConfig):
                 g = jnp.where(valid[..., None, None], g, 0.0)
                 beta = jnp.where(valid[..., None], beta, 0.0)
                 o, S = kda.kda_chunked(q, k, v, g, beta, chunk=cfg.kda_chunk)
-                x = x + _kda_output(cfg, m, h, o).astype(F32)
+                x = _residual(cfg, p, "mixer_post_norm", x, _kda_output(cfg, m, h, o))
                 S_rows.append(S)
                 conv_rows.append(kda.conv_tail(qkv, true_len, K))
-        else:
-            with jax.named_scope("mla"):
-                m = p["mla"]
-                q, latent = _mla_latent(cfg, m, h)
-                k, v = mla.mla_expand(latent, m["w_kvb"], cfg.n_heads,
-                                      cfg.kv_lora_rank, cfg.qk_nope_dim,
-                                      cfg.v_head_dim)
-                attn = causal_attention_blocked(
-                    q, k, v, sm_scale=(cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5)
-                x = x + (attn.reshape(b, s, -1) @ m["wo"]).astype(F32)
-                latent_rows.append(latent)
-        h32, h = _normed(cfg, x, p["ffn_norm"])
-        y, _, _, chosen = _ffn(cfg, p, h32.reshape(b * s, -1),
-                               h.reshape(b * s, -1), valid.reshape(b * s))
-        x = x + y.reshape(b, s, -1).astype(F32)
+            x, _, _, chosen = _ffn_half(cfg, p, x, valid)
         if chosen is not None:
-            routing.append(chosen.reshape(b, s, -1))
-    _, x = _normed(cfg, x, params["final_norm"])
+            routing.append(chosen)
     rows = {"S": S_rows, "conv": conv_rows,
             "latent": jnp.stack(latent_rows) if latent_rows else
             jnp.zeros((0, b, s, cfg.latent_width), cfg.dtype)}
     return x, rows, routing
+
+
+def _mtp_sequence(params, hidden, following, true_len, cfg: HybridConfig):
+    """The prediction module over a whole sequence: hidden [b, s, d] float32
+    (the main stack's, before its final norm) and `following` [b, s], the
+    token AFTER each position -> (features after the module's final norm
+    [b, s, d]: through the head, logits for the token two ahead; the
+    module's latent rows [b, s, latent_width]; the experts it chose)."""
+    m = params["mtp"]
+    s = hidden.shape[1]
+    valid = jnp.arange(s)[None, :] < true_len[:, None]
+    with jax.named_scope("mtp"):
+        e = params["embed"][following].astype(F32)
+        both = jnp.concatenate([rms_norm(hidden, m["h_norm"], cfg.norm_eps),
+                                rms_norm(e, m["e_norm"], cfg.norm_eps)], axis=-1)
+        x = (both.astype(cfg.dtype) @ m["proj"]).astype(F32)
+        x, latent, chosen = _mla_seq_layer(cfg, m["layer"], x, valid, jnp.arange(s))
+        return _normed(cfg, x, m["final_norm"])[1], latent, chosen
 
 
 def _sequence_runs(params, tokens, true_len, cfg: HybridConfig):
@@ -493,7 +594,6 @@ def _sequence_runs(params, tokens, true_len, cfg: HybridConfig):
     for name in ("k", "v"):
         rows[name] = jnp.concatenate(rows[name]) if rows[name] else \
             jnp.zeros((0, b, kvh, s, hd), cfg.dtype)
-    _, x = _normed(cfg, x, params["final_norm"])
     return x, rows, []
 
 
@@ -504,85 +604,169 @@ def _head(params, x):
     return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(F32)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def forward(params, tokens, cfg: HybridConfig):
-    """tokens [b, s] -> logits [b, s, vocab] float32."""
+def _following(tokens, true_len, first):
+    """The token after each position of a right-padded prompt: the prompt
+    shifted by one, `first` [b] (the token the model answered with) behind
+    its last true position."""
+    at = jnp.arange(tokens.shape[1])[None, :] == (true_len - 1)[:, None]
+    return jnp.where(at, first[:, None], jnp.roll(tokens, -1, axis=1))
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "with_mtp"))
+def forward(params, tokens, cfg: HybridConfig, with_mtp: bool = False):
+    """tokens [b, s] -> logits [b, s, vocab] float32. `with_mtp`: beside
+    them the prediction module's [b, s - 1, vocab]: from position i's hidden
+    row and token i + 1, logits for token i + 2."""
     full = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
     x, _, _ = _sequence(params, tokens, full, cfg)
-    return _head(params, x)
+    with jax.named_scope("head"):
+        logits = _head(params, _normed(cfg, x, params["final_norm"])[1])
+    if not with_mtp:
+        return logits
+    feats, _, _ = _mtp_sequence(params, x[:, :-1], tokens[:, 1:], full - 1, cfg)
+    with jax.named_scope("head"):
+        return logits, _head(params, feats)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "with_routing"))
 def prefill(params, tokens, true_len, cfg: HybridConfig,
-            with_routing: bool = False):
+            with_routing: bool = False, first=None):
     """-> (logits at the last true position [nb, vocab] float32, state rows
     {"S": [per KDA layer [nb, H, dk, dv]], "conv": [per KDA layer
-    [nb, K-1, 3 H dk]], "latent": [MLA layers, nb, s, rank + rope]}; the
-    runs form's rows: `_sequence_runs`).
+    [nb, K-1, 3 H dk]], "latent": [MLA layers, nb, s, latent_width]}; the
+    runs form's rows: `_sequence_runs`). A configuration with a prediction
+    module: its latent rows are the last of "latent" (it has seen the
+    prompt shifted by one, the model's own first token behind it), and
+    "draft" [nb] is its guess at the SECOND token of the answer.
     `with_routing` adds "routing" [expert layers, nb, s, k]: the experts
     every position chose (for a comparison that has to tell a near-tie in
-    the router from an error; the engine never asks for it)."""
+    the router from an error; the engine never asks for it), with the
+    module's layer last, and the module's logits at the last true position
+    as "mtp_logits" [nb, vocab]. `first` [nb], where the caller knows the
+    token each answer began with (a teacher-forced comparison), is fed to
+    the module in place of the model's own choice."""
     x, rows, routing = _sequence(params, tokens, true_len, cfg)
+    pick = lambda a: jnp.take_along_axis(a, (true_len - 1)[:, None, None], axis=1)[:, 0]
+    with jax.named_scope("head"):
+        logits = _head(params, _normed(cfg, pick(x), params["final_norm"])[1])
+    if cfg.n_predict and not cfg.scanned:
+        if first is None:
+            first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        feats, latent, chosen = _mtp_sequence(
+            params, x, _following(tokens, true_len, first), true_len, cfg)
+        with jax.named_scope("head"):
+            mtp_logits = _head(params, pick(feats))
+        rows["latent"] = jnp.concatenate([rows["latent"], latent[None]])
+        rows["draft"] = jnp.argmax(mtp_logits, axis=-1).astype(jnp.int32)
+        routing = routing + [chosen]
+        if with_routing:
+            rows["mtp_logits"] = mtp_logits
     if with_routing:
         rows["routing"] = jnp.stack(routing)
-    last = jnp.take_along_axis(x, (true_len - 1)[:, None, None], axis=1)[:, 0]
-    with jax.named_scope("head"):
-        return _head(params, last), rows
+    return logits, rows
 
 
 # ---------------------------------------------------------------- decode
 
 
+def _mla_step_layer(cfg: HybridConfig, p, x, latent, layer, lengths, positions,
+                    active, attn_len, walk):
+    """One layer with an MLA mixer, Q new positions a slot: x [B, Q, d]
+    float32 against `latent[layer]`, read-only -> (x, the positions' own
+    latent rows [B, Q, latent_width], assignments landed, experts touched,
+    the experts chosen [B, Q, k] or None)."""
+    B, Q, _ = x.shape
+    _, h = _normed(cfg, x, p["mixer_norm"])
+    with jax.named_scope("mla"):
+        m = p["mla"]
+        q, cur = _mla_latent(cfg, m, h, positions)
+        cur = cur.astype(cfg.dtype)
+        attn = mla.mla_decode_absorbed(
+            q, latent, layer, cur, lengths, attn_len, m["w_kvb"],
+            cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.v_head_dim, walk)
+        x = _residual(cfg, p, "mixer_post_norm", x,
+                      attn.reshape(B, Q, -1).astype(cfg.dtype) @ m["wo"])
+    x, landed, touched, chosen = _ffn_half(
+        cfg, p, x, jnp.broadcast_to(active[:, None], (B, Q)))
+    return x, cur, landed, touched, chosen
+
+
 def _decode(params, state, lengths, tokens, active, cfg: HybridConfig,
-            attn_len: int):
-    """One token for every slot. Returns (state, logits [B, vocab] float32,
-    [assignments landed, experts touched] summed over the expert layers,
-    the experts every slot chose [expert layers, B, k])."""
-    x = params["embed"][tokens].astype(F32)           # [B, d], float32 residual
-    mask = jnp.arange(attn_len)[None, :] < lengths[:, None]          # strict
+            attn_len: int, following=None):
+    """Q new positions for every slot: tokens [B, Q] at positions
+    `lengths[b] + 0 .. Q-1` (Q is 1, or 2 where a prediction module's draft
+    rides behind the last token). Returns (state with the new rows written,
+    logits [B, Q, vocab] float32, the prediction module's logits [B, Q,
+    vocab] or None, [assignments landed, experts touched] summed over the
+    expert layers, the experts chosen [expert layers, B, Q, k], the module's
+    last).
+
+    The module sees the main stack's hidden rows and the token AFTER each
+    position: `following` [B, Q], or the main model's own greedy choice
+    where none is given. The latent rows are read-only until every layer
+    has run and are written once, the module's with them. A slot that is
+    not `active` has length 0, reads nothing and writes nothing."""
+    B, Q = tokens.shape
+    x = params["embed"][tokens].astype(F32)        # [B, Q, d], float32 residual
+    positions = lengths[:, None] + jnp.arange(Q)[None, :]
+    latent = state["latent"]
+    walk = mla.decode_walk(latent, lengths, attn_len, cfg.n_heads) \
+        if latent.shape[0] else None
     S_new, conv_new, latent_cur, routing = [], [], [], []
     landed = touched = jnp.zeros((), jnp.int32)
-    i_kda = i_mla = 0
+    i_kda = 0
     for p, (mixer, _) in zip(params["layers"], cfg.layer_kinds()):
-        _, h = _normed(cfg, x, p["mixer_norm"])
-        if mixer == "kda":
+        if mixer == "mla":
+            x, cur, n_landed, n_touched, chosen = _mla_step_layer(
+                cfg, p, x, latent, len(latent_cur), lengths, positions, active,
+                attn_len, walk)
+            latent_cur.append(cur)
+        else:
+            _, h = _normed(cfg, x[:, 0], p["mixer_norm"])  # one position only
             with jax.named_scope("kda"):
                 m = p["kda"]
                 y, tail = kda.short_conv_step(h @ m["w_qkv"],
                                               state["conv"][i_kda], m["conv"])
                 q, k, v, g, beta = _kda_inputs(cfg, m, h, jax.nn.silu(y))
                 S, o = kda.kda_step(state["S"][i_kda], q, k, v, g, beta)
-                x = x + _kda_output(cfg, m, h, o).astype(F32)
+                x = _residual(cfg, p, "mixer_post_norm", x,
+                              _kda_output(cfg, m, h, o)[:, None])
                 S_new.append(S)
                 conv_new.append(tail)
                 i_kda += 1
-        else:
-            with jax.named_scope("mla"):
-                m = p["mla"]
-                q, cur = _mla_latent(cfg, m, h)
-                cur = cur.astype(cfg.dtype)
-                attn = mla.mla_decode_absorbed(
-                    q, state["latent"][i_mla, :, :, :attn_len], cur, mask,
-                    m["w_kvb"], cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.v_head_dim)
-                x = x + (attn.reshape(x.shape[0], -1).astype(cfg.dtype)
-                         @ m["wo"]).astype(F32)
-                latent_cur.append(cur)
-                i_mla += 1
-        h32, h = _normed(cfg, x, p["ffn_norm"])
-        y, n_landed, n_touched, chosen = _ffn(cfg, p, h32, h, active)
-        x = x + y.astype(F32)
+            x, n_landed, n_touched, chosen = _ffn_half(cfg, p, x, active[:, None])
         landed, touched = landed + n_landed, touched + n_touched
         if chosen is not None:
             routing.append(chosen)
-    latent = state["latent"]
+    with jax.named_scope("head"):
+        logits = _head(params, _normed(cfg, x, params["final_norm"])[1])
+    mtp_logits = None
+    if cfg.n_predict:
+        with jax.named_scope("mtp"):
+            m = params["mtp"]
+            if following is None:
+                following = jnp.argmax(logits, axis=-1)
+            e = params["embed"][following].astype(F32)
+            both = jnp.concatenate([rms_norm(x, m["h_norm"], cfg.norm_eps),
+                                    rms_norm(e, m["e_norm"], cfg.norm_eps)], -1)
+            y = (both.astype(cfg.dtype) @ m["proj"]).astype(F32)
+            y, cur, n_landed, n_touched, chosen = _mla_step_layer(
+                cfg, m["layer"], y, latent, len(latent_cur), lengths, positions,
+                active, attn_len, walk)
+            latent_cur.append(cur)
+            routing.append(chosen)
+            landed, touched = landed + n_landed, touched + n_touched
+            with jax.named_scope("head"):
+                mtp_logits = _head(params, _normed(cfg, y, m["final_norm"])[1])
     if latent_cur:
         with jax.named_scope("state_write"):
-            # the tile-aligned row write, with one "kv head"
-            latent = write_rows(latent, jnp.stack(latent_cur)[:, :, None], lengths)
-    with jax.named_scope("head"):
-        _, x = _normed(cfg, x, params["final_norm"])
-        logits = (x @ params["lm_head"]).astype(F32)
-    return ({"S": S_new, "conv": conv_new, "latent": latent}, logits,
+            # the tile-aligned row write, with one "kv head"; position a of a
+            # slot that holds nothing is still nothing (0, not a)
+            rows = jnp.stack(latent_cur)                   # [L, B, Q, W]
+            for a in range(Q):
+                latent = write_rows(latent, rows[:, :, a, None],
+                                    jnp.where(lengths > 0, lengths + a, 0))
+    return ({"S": S_new, "conv": conv_new, "latent": latent}, logits, mtp_logits,
             jnp.stack([landed, touched]), routing)
 
 
@@ -676,13 +860,30 @@ def decode_logits(params, state, lengths, tokens, active, cfg: HybridConfig,
     `decode_step` over the same slot state (DONATED) and the same `active`
     mask, returning (state, logits [B, vocab], the experts every slot chose
     [expert layers, B, k]) instead of sampling. The runs form has no
-    routing ([0, B, 0]) and takes no `active`."""
+    routing ([0, B, 0]) and takes no `active`. A configuration that drafts
+    is checked through `verify_logits`."""
     if cfg.scanned:
         state, logits = _decode_runs(params, state, lengths, tokens, cfg, attn_len)
         return state, logits, jnp.zeros((0, tokens.shape[0], 0), jnp.int32)
-    state, logits, _, routing = _decode(params, state, lengths, tokens, active,
-                                        cfg, attn_len)
-    return state, logits, jnp.stack(routing)
+    state, logits, _, _, routing = _decode(params, state, lengths, tokens[:, None],
+                                           active, cfg, attn_len)
+    return state, logits[:, 0], jnp.stack(routing)[:, :, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
+                   donate_argnums=(1,))
+def verify_logits(params, state, lengths, tokens, following, active,
+                  cfg: HybridConfig, attn_len: int):
+    """`decode_logits` for a configuration that drafts: the step's body over
+    the slot state (DONATED) for the TWO positions `tokens` [B, 2] of every
+    active slot, the module fed `following` [B, 2] (the token after each)
+    -> (state with both positions' rows written, logits [B, 2, vocab], the
+    module's logits [B, 2, vocab], the experts chosen [expert layers + 1,
+    B, 2, k], the module's layer last). The caller advances `lengths` by 1
+    or 2: a row past it is overwritten, as after a refused draft."""
+    new, logits, mtp_logits, _, routing = _decode(
+        params, state, lengths, tokens, active, cfg, attn_len, following)
+    return {**new, "draft": state["draft"]}, logits, mtp_logits, jnp.stack(routing)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
@@ -691,19 +892,48 @@ def decode_step(params, state, lengths, tokens, active, cfg: HybridConfig,
                 attn_len: int):
     """The hot decode step: state and lengths DONATED, greedy sampling on
     device. `active` [B] bool marks the slots that serve a request; an idle
-    slot still computes (static shapes) but is routed to no expert. Returns
-    (state, lengths + 1, next tokens [B], report [B + 2] = the next tokens
-    followed by the two expert-layer counters: ONE array crosses to the
-    host per step). The runs form keeps the dense engine's rule for idle
-    slots (length 0 stays 0) and has no counters: its report is the tokens."""
+    slot still computes (static shapes) but is routed to no expert, and its
+    length stays 0. Returns (state, lengths + what each active slot kept,
+    next tokens [B], report): ONE array crosses to the host per step, the
+    tokens each slot yielded [B x T] slot-major (-1 where it yielded fewer
+    than T: the count beside the tokens) followed by the counters.
+
+    Without a prediction module T is 1. With one (T = 2) the step verifies
+    `[tokens[b], state["draft"][b]]` through the main layers: the model's
+    own choice after the last token is kept; if the draft was that choice,
+    so is the choice after the draft. Either way the tokens are exactly the
+    ones a step at a time would have chosen. The module then drafts from
+    the last kept position, and the rows both positions wrote stay: `lengths`
+    alone says what is live, and a row past it is overwritten. Counters
+    behind the tokens: [assignments landed, experts touched, drafts
+    proposed, drafts accepted].
+
+    The runs form keeps the dense engine's rule for idle slots (length 0
+    stays 0) and has no counters: its report is the tokens."""
     if cfg.scanned:
         state, logits = _decode_runs(params, state, lengths, tokens, cfg, attn_len)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return state, lengths + (lengths > 0), nxt, nxt
-    state, logits, counters, _ = _decode(params, state, lengths, tokens, active,
-                                         cfg, attn_len)
-    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return state, lengths + 1, nxt, jnp.concatenate([nxt, counters])
+    if not cfg.n_predict:
+        state, logits, _, counters, _ = _decode(
+            params, state, lengths, tokens[:, None], active, cfg, attn_len)
+        nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+        return (state, lengths + active.astype(jnp.int32), nxt,
+                jnp.concatenate([nxt, counters]))
+    draft = state["draft"]
+    state, logits, mtp_logits, counters, _ = _decode(
+        params, state, lengths, jnp.stack([tokens, draft], axis=1), active, cfg,
+        attn_len)
+    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)              # [B, 2]
+    accepted = (nxt[:, 0] == draft) & active
+    last = jnp.where(accepted, nxt[:, 1], nxt[:, 0])
+    # the module's row at the last kept position guesses the token after `last`
+    guess = jnp.argmax(mtp_logits, axis=-1).astype(jnp.int32)
+    state["draft"] = jnp.where(accepted, guess[:, 1], guess[:, 0])
+    kept = jnp.stack([nxt[:, 0], jnp.where(accepted, nxt[:, 1], -1)], axis=1)
+    drafts = jnp.stack([jnp.sum(active), jnp.sum(accepted)]).astype(jnp.int32)
+    return (state, lengths + jnp.where(active, 1 + accepted.astype(jnp.int32), 0),
+            last, jnp.concatenate([kept.reshape(-1), counters, drafts]))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -723,7 +953,8 @@ def _write_state(state, lengths, tokens, slots, rows, true_len, first):
     with jax.named_scope("state_write"):
         put = lambda whole, part: whole.at[slots].set(part, mode="drop")
         bucket = rows["latent"].shape[2]
-        state = {"S": [put(a, r) for a, r in zip(state["S"], rows["S"])],
+        state = {**{k: put(state[k], rows[k]) for k in state if k == "draft"},
+                 "S": [put(a, r) for a, r in zip(state["S"], rows["S"])],
                  "conv": [put(a, r) for a, r in zip(state["conv"], rows["conv"])],
                  "latent": state["latent"].at[:, slots, :, :bucket].set(
                      rows["latent"][:, :, None], mode="drop")}
@@ -748,25 +979,33 @@ def _write_runs(state, lengths, tokens, slots, rows, true_len, first):
 class HybridCache:
     """Per-slot state of the hybrid model for `ContinuousBatchingEngine`:
     per KDA layer the state S [slots, H, dk, dv] float32 and the convolution
-    tail [slots, K-1, 3 H dk]; for the MLA layers the latent rows
-    [layers, slots, 1, max_len, rank + rope]."""
+    tail [slots, K-1, 3 H dk]; for the MLA layers, a prediction module's
+    last, the latent rows [layers, slots, 1, max_len, latent_width]; with a
+    module, the token it drafted for each slot [slots]."""
 
-    counters = ("expert_assignments", "experts_touched")
     idle_args: Dict[str, int] = {}
 
     def __init__(self, cfg: HybridConfig, num_slots: int, max_len: int):
         self.cfg, self.num_slots, self.max_len = cfg, num_slots, max_len
         kinds = [m for m, _ in cfg.layer_kinds()]
         self.n_kda, self.n_mla = kinds.count("kda"), kinds.count("mla")
+        self.n_latent = self.n_mla + cfg.n_predict
         H, dk = cfg.kda_heads, cfg.kda_head_dim
         self.state = {
             "S": [jnp.zeros((num_slots, H, dk, dk), F32) for _ in range(self.n_kda)],
             "conv": [jnp.zeros((num_slots, cfg.conv_kernel - 1, 3 * H * dk), cfg.dtype)
                      for _ in range(self.n_kda)],
             # one "kv head", so that `ops.cache.write_rows` takes it as is
-            "latent": jnp.zeros((self.n_mla, num_slots, 1, max_len,
+            "latent": jnp.zeros((self.n_latent, num_slots, 1, max_len,
                                  cfg.latent_width), cfg.dtype)}
-        self.prefill_args = {"state_layers": self.n_kda, "latent_layers": self.n_mla}
+        self.prefill_args = {"state_layers": self.n_kda,
+                             "latent_layers": self.n_latent}
+        self.counters: Tuple[str, ...] = ("expert_assignments", "experts_touched")
+        # the most tokens one step yields a slot: a draft that holds is a second
+        self.step_tokens = 1 + cfg.n_predict
+        if cfg.n_predict:
+            self.state["draft"] = jnp.zeros((num_slots,), jnp.int32)
+            self.counters += ("draft_proposed", "draft_accepted")
 
     def max_prefill_batch(self, bucket: int) -> int:
         return max(1, min(4, self.cfg.prefill_tokens // bucket))
@@ -788,9 +1027,15 @@ class HybridCache:
 
     def step_args(self, n_active: int, live_rows: int,
                   attn_len: int) -> Dict[str, int]:
-        """What one decode step moved, known on the host at dispatch."""
+        """What one decode step moved, known on the host at dispatch:
+        `latent_rows` the live rows of the busy slots, which the attention
+        has to read, beside `window_rows`, every slot's window to the
+        deepest bucket, which the einsum form reads; `written_slots` the
+        slots whose block of rows the row write moves."""
         return {"state_slots": n_active if self.n_kda else 0,
-                "latent_rows": live_rows if self.n_mla else 0}
+                "latent_rows": live_rows if self.n_latent else 0,
+                "window_rows": self.num_slots * attn_len if self.n_latent else 0,
+                "written_slots": n_active if self.n_latent else 0}
 
 
 class RunsCache(HybridCache):
@@ -803,6 +1048,7 @@ class RunsCache(HybridCache):
     are `HybridCache`'s: the jitted programs branch on the configuration."""
 
     counters = ()
+    step_tokens = 1
     idle_args = {"written_slots": 0}
 
     def __init__(self, cfg: HybridConfig, num_slots: int, max_len: int):

@@ -288,7 +288,14 @@ class DenseKVCache:
         prefill(params, tokens, lens) -> (first tokens [nb], state rows)
         write(lengths, tokens, slots, rows, lens, first) -> (lengths, tokens)
         decode(params, lengths, tokens, attn_len, active) ->
-            (lengths, next tokens [B], report [B + len(counters)])
+            (lengths, next tokens [B], report [B x step_tokens + len(counters)])
+        step_tokens                 the most tokens one step yields a slot
+                                    (2 where the model drafts one and it
+                                    holds). `report` opens with each slot's
+                                    tokens of this step, slot-major, -1
+                                    where it yielded fewer: how many a slot
+                                    holds is its count, 1 for a cache that
+                                    never drafts
         max_prefill_batch(bucket)   None = any
         counters                    names of what `report` carries behind
                                     the tokens (summed into `engine.step`)
@@ -302,6 +309,7 @@ class DenseKVCache:
     always compiled to."""
 
     counters: Tuple[str, ...] = ()
+    step_tokens = 1
     prefill_args: Dict[str, int] = {}
     idle_args: Dict[str, int] = {"written_slots": 0}
 
@@ -465,7 +473,10 @@ class ContinuousBatchingEngine:
     def _maybe_finish(self, req: _Request) -> None:
         hit_eos = self.eos_token is not None and req.generated and \
             req.generated[-1] == self.eos_token
-        out_of_room = len(req.prompt) + len(req.generated) >= self.max_len - 1
+        # a step writes up to `step_tokens` rows past the tokens a request
+        # holds, and one more step is in flight when it finishes
+        out_of_room = len(req.prompt) + len(req.generated) >= \
+            self.max_len + 1 - 2 * self.cache.step_tokens
         if len(req.generated) >= req.max_new_tokens or hit_eos or out_of_room:
             req.done = True
             if req.slot >= 0:
@@ -526,10 +537,12 @@ class ContinuousBatchingEngine:
                 attn_len=self._attn_len if self._pending else 0,
                 **(self._step_args if self._pending
                    else self.cache.idle_args))
-            self._drain_pending_first()                   # device wait, no _lock
+            firsts = self._drain_pending_first()          # device wait, no _lock
             # the model's counters ride the token array, so they are those
-            # of the step reaped here: the one dispatched a step earlier
-            did.update(self._reap(prev))                  # device wait, no _lock
+            # of the step reaped here: the one dispatched a step earlier;
+            # `tokens_out`: what the requests received inside this span
+            reaped = self._reap(prev)                     # device wait, no _lock
+            did.update(reaped, tokens_out=firsts + reaped.get("tokens_out", 0))
             self._retire_slots()                          # device enqueue only
             with self._lock:
                 return len(self._active) + len(self._waiting)
@@ -598,8 +611,8 @@ class ContinuousBatchingEngine:
             len(slot_map), sum(self._slot_pos[s] for s in slot_map), attn_len)
         self.lengths, self.tokens, report = self.cache.decode(
             self.params, self.lengths, self.tokens, attn_len, slot_map)
-        for s in slot_map:
-            self._slot_pos[s] += 1
+        for s in slot_map:  # an upper bound until the step's count is reaped
+            self._slot_pos[s] += self.cache.step_tokens
         return report, slot_map
 
     def _retire_slots(self) -> None:
@@ -616,11 +629,12 @@ class ContinuousBatchingEngine:
             retired[freed] = True
             self.lengths = self._zero_lengths(self.lengths, retired)
 
-    def _drain_pending_first(self) -> None:
+    def _drain_pending_first(self) -> int:
         """Sync admissions' on-device first tokens (deferred from dispatch
-        so prefill overlaps the decode step queued behind it)."""
+        so prefill overlaps the decode step queued behind it). Returns how
+        many requests got theirs."""
         if not self._pending_first:
-            return
+            return 0
         batches, self._pending_first = self._pending_first, []
         for first_dev, entries in batches:
             with tracing.span("engine.wait_device", "engine", what="first"):
@@ -632,26 +646,39 @@ class ContinuousBatchingEngine:
                     req.generated.append(int(first[row]))
                     self._maybe_finish(req)
                 self._cv.notify_all()
+        return sum(len(entries) for _, entries in batches)
 
     def _reap(self, prev) -> Dict[str, int]:
-        """Sync + bookkeep a previously dispatched step's tokens. Runs
-        while the NEXT step computes on device (one-step lookahead).
-        Returns the model's counters of that step (`cache.counters`: they
-        sit behind the tokens in the one array that is synced)."""
+        """Sync + bookkeep a previously dispatched step's tokens: as many
+        for each slot as the step yielded it (`cache.step_tokens` at most;
+        the ones past a request's end are dropped). Runs while the NEXT step
+        computes on device (one-step lookahead). Returns the model's
+        counters of that step (`cache.counters`: they sit behind the tokens
+        in the one array that is synced) and `tokens_out`, the tokens the
+        requests received."""
         if prev is None:
             return {}
         report_dev, slot_map = prev
         with tracing.span("engine.wait_device", "engine", what="decode"):
             nxt = self._to_host(report_dev)  # device wait — no _lock held
+        most = self.cache.step_tokens
+        yielded = nxt[:self.num_slots * most].reshape(self.num_slots, most)
+        out = 0
         with self._lock:
             for slot, req in slot_map.items():
-                if req.done:
-                    continue  # finished at dispatch+1; this token is junk
-                req.generated.append(int(nxt[slot]))
-                self._maybe_finish(req)
+                kept = [int(t) for t in yielded[slot] if t >= 0]
+                if self._active.get(slot) is req:  # the bound, made exact
+                    self._slot_pos[slot] -= most - len(kept)
+                for t in kept:
+                    if req.done:
+                        break  # finished at dispatch+1, or on the token before
+                    req.generated.append(t)
+                    out += 1
+                    self._maybe_finish(req)
             self._cv.notify_all()
-        return {name: int(nxt[self.num_slots + i])
-                for i, name in enumerate(self.cache.counters)}
+        return {"tokens_out": out,
+                **{name: int(nxt[self.num_slots * most + i])
+                   for i, name in enumerate(self.cache.counters)}}
 
     def run_until_done(self, max_steps: int = 10_000) -> None:
         for _ in range(max_steps):

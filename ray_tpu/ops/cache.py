@@ -30,7 +30,8 @@ def uses_write_kernel(cache: jax.Array) -> bool:
     XLA:TPU keeps such an array with the positions minor-most, and would
     copy the whole cache to the kernel's row-major operand and back
     (`copy.498` / `copy.509` at [2, 64, 1, 8192, 576], asked of the
-    described compiler, PR 33)."""
+    described compiler, PR 33): `HybridConfig.latent_width` therefore stores
+    latent rows in whole tiles."""
     L, _, kvh, max_len, hd = cache.shape
     R = _tile_rows(cache)
     return (_util.on_tpu() and hd % 128 == 0 and max_len % R == 0

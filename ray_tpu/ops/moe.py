@@ -106,6 +106,16 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_gate: jax.Array,
 # is tried at 5/4 of the share that lands here under even routing
 _ROW_TIERS = (128, 256)
 _TIERED_UP_TO = 1024
+# the most bytes the LAST tier may gather ([T k, d] sorted rows, and as much
+# again for what the experts give back). The last tier is what keeps the
+# layer dropless under ANY routing, and a program's memory is the worst of
+# its branches: a prompt pass of 8191 tokens at d 7680 would reserve two
+# buffers of 1 GB for the routing that sends every assignment to the 8
+# experts of 256 held here (the described v5e compiler: `temp` 4.76 GB
+# against 3.57, beside 11.9 GB of weights and slots). Past it the last tier
+# runs each held expert over all T tokens instead (`every_expert`: as
+# dropless, slower, [T, f] at a time, no gather)
+_GATHERED_BYTES = 256 * 2**20
 
 
 def _row_tiers(rows: int, held: int, n_experts: int):
@@ -188,6 +198,22 @@ def dropless_moe(x: jax.Array, idx: jax.Array, w: jax.Array,
             return jnp.sum(picked.astype(jnp.float32) * w[..., None], axis=1)
         return run
 
+    def every_expert(_):
+        """The same sums for any routing at all, without gathering a row:
+        each held expert's SwiGLU over ALL T tokens, one expert after
+        another, weighed by what the token gave that expert (mostly 0)."""
+        chose = group.reshape(T, top_k)
+
+        def one(y, e):
+            gate, up, down, j = e
+            weight = jnp.sum(jnp.where(chose == j, w, 0.0), axis=1)
+            out = (jax.nn.silu(x @ gate) * (x @ up)).astype(x.dtype) @ down
+            return y + out.astype(jnp.float32) * weight[:, None], None
+
+        y, _ = jax.lax.scan(one, jnp.zeros((T, d), jnp.float32),
+                            (w_gate, w_up, w_down, jnp.arange(Eh)))
+        return y
+
     # A grouped product costs each touched expert one row TILE of work, and
     # the tile is as tall as the row count allows (measured on a v5e, 64
     # experts of 2304 x 1024, 128 landed rows: 1.07 ms at 512 rows, 0.77 at
@@ -197,7 +223,9 @@ def dropless_moe(x: jax.Array, idx: jax.Array, w: jax.Array,
     # instead of all. The whole row count stays the last tier: nothing is
     # dropped.
     tiers = _row_tiers(T * top_k, Eh, n_experts)
+    runs = [experts(r) for r in tiers]
+    if T * top_k * d * x.dtype.itemsize > _GATHERED_BYTES:
+        runs[-1] = every_expert
     y = jax.lax.switch(sum((landed > r).astype(jnp.int32) for r in tiers[:-1]),
-                       [experts(r) for r in tiers], None) \
-        if len(tiers) > 1 else experts(T * top_k)(None)
+                       runs, None) if len(tiers) > 1 else runs[0](None)
     return y.astype(x.dtype), landed, jnp.sum(sizes > 0).astype(jnp.int32)

@@ -61,7 +61,7 @@ def uses_decode_kernel(cache: jax.Array, attn_len: int) -> bool:
             and attn_len % rows == 0)
 
 
-def live_blocks(lengths: jax.Array, attn_len: int):
+def live_blocks(lengths: jax.Array, attn_len: int, rows: int = 0):
     """The kernel's walk over the cache, as five [B] int32 arrays indexed by
     grid step i of the slot axis: `order` (the slot served: busy slots
     first, so that one block's arithmetic hides the next one's DMA), `rows`
@@ -69,8 +69,8 @@ def live_blocks(lengths: jax.Array, attn_len: int):
     `clip(j, lo[i], hi[i])` of slot `src[i]`. A busy slot walks its own
     blocks up to the last that holds a row; the idle ones behind them stay
     on the last busy slot's last block, which is already in VMEM.
-    Loop-invariant over the layers: computed once a step, outside the scan."""
-    rows = block_rows(attn_len)
+    Loop-invariant over the layers: computed once a step. `rows`: another block height."""
+    rows = rows or block_rows(attn_len)
     B = lengths.shape[0]
     live = lengths > 0
     order = jnp.argsort(~live, stable=True)

@@ -294,19 +294,20 @@ def test_write_slots_keeps_the_cache_layout(chip):
 def test_hybrid_decode_step_keeps_its_state_in_place(chip, attn_len):
     """The hybrid model's decode step at the benchmark cell's real shapes
     (Kimi-Linear widths, 9 layers, 64 held experts, 64 slots x 8192): every
-    leaf of the slot state (KDA S, convolution tails, latent rows: 2.18 GB)
-    aliases its output, and the step holds no copy of the latent cache. Two
-    earlier forms failed here: a latent cache kept as [layers, slots,
-    max_len, 576] and reshaped around `write_rows` (`copy.265` / `copy.267`
-    of the whole 1.2 GB cache, `temp` 1.38 GB), and scores taken as
-    "bhc,blc->bhl" against a [slots, window, 576] slice (the window
-    re-laid out with the positions minor-most: `temp` 1.30 GB at 8192). A
-    third fails here since PR 33: the latent rows written by the Mosaic
-    `write_rows`. Mosaic takes a full-width block of 576 lanes, but XLA:TPU
-    keeps this array with the positions minor-most and bridges to the
-    call's row-major operand and back (`copy.498` / `copy.509` of the whole
-    cache), so the shape rule leaves a last dimension that is no multiple
-    of 128 on the loop: one in-place `dynamic-update-slice`."""
+    leaf of the slot state (KDA S, convolution tails, latent rows: 2.32 GB)
+    aliases its output, and the step holds no copy of the latent cache. Three
+    earlier forms failed here, all over rows stored in their 576 lanes: a
+    latent cache kept as [layers, slots, max_len, 576] and reshaped around
+    `write_rows` (`copy.265` / `copy.267` of the whole 1.2 GB cache, `temp`
+    1.38 GB), scores taken as "bhc,blc->bhl" against a [slots, window, 576]
+    slice (the window re-laid out with the positions minor-most: `temp`
+    1.30 GB at 8192), and the Mosaic `write_rows` on a 576-lane block
+    (XLA:TPU kept that array positions-minor and bridged to the call's
+    row-major operand and back: `copy.498` / `copy.509` of the whole cache).
+    Since PR 34 the row is stored in 640 lanes (`HybridConfig.latent_width`)
+    and the step holds two Mosaic calls over it: the row write and the
+    absorbed decode over live rows (`mla_decode_attention`), one each a
+    layer."""
     import json
 
     from ray_tpu.models import hybrid
@@ -333,11 +334,14 @@ def test_hybrid_decode_step_keeps_its_state_in_place(chip, attn_len):
                                  chip((slots,), jnp.bool_), cfg, attn_len).compile()
     state_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree_util.tree_leaves(state))
-    assert state_bytes > 2.1e9
+    assert state_bytes > 2.3e9
     assert c.memory_analysis().alias_size_in_bytes >= state_bytes
-    assert not cache_ops.uses_write_kernel(state["latent"])
-    assert _count(c, state["latent"], "dynamic-update-slice") == 1
-    _assert_cache_stays_put(c, state["latent"])
+    latent = state["latent"]
+    assert latent.shape == (2, slots, 1, max_len, 640)
+    assert cache_ops.uses_write_kernel(latent)
+    assert "mla_decode_attention" in c.as_text()
+    assert _count(c, latent, "dynamic-update-slice") == 0
+    _assert_cache_stays_put(c, latent)
 
 
 def _jamba(chip):
@@ -408,6 +412,79 @@ def test_jamba_prompt_pass_runs_the_scan_kernel(chip):
                                     chip((4,), jnp.int32), cfg).compile()
     assert c.as_text().count("selective_scan") >= 3
     assert c.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def _pangu(chip):
+    """(cfg, parameter shapes, slot-state shapes, slots) of the openPangu cell."""
+    import json
+    import sys
+
+    from ray_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from perfbench.lib import pangu_model
+
+    with open(os.path.join(root, "perfbench", "configs",
+                           "openpangu-ultra-moe-718b.1of32.json")) as f:
+        conf = json.load(f)
+    cfg = pangu_model.model_config(conf)
+    slots, max_len = conf["run"]["num_slots"], conf["run"]["max_len"]
+    as_shapes = lambda tree: jax.tree_util.tree_map(
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = as_shapes(jax.eval_shape(lambda k: hybrid.init_params(k, cfg),
+                                      jax.random.PRNGKey(0)))
+    state = as_shapes(jax.eval_shape(lambda: cfg.make_cache(slots, max_len).state))
+    return cfg, params, state, slots
+
+
+def test_pangu_verify_step_reads_live_rows_in_place(chip):
+    """The drafting decode step at the benchmark cell's real shapes
+    (openPangu-Ultra-MoE widths: 128 heads, a cut of 1 dense + 5 expert
+    layers + the prediction module, 8 held experts, 32 slots x 8192): the
+    latent rows of 7 MLA layers, stored in 640 lanes (2.35 GB), alias their
+    output and stay row-major: Mosaic takes the `mla_decode_attention`
+    kernel at 256 query rows x 640 lanes, once a layer, and both new
+    positions' rows are written by the `write_rows` kernel; the step holds
+    no copy of the cache (`temp` 36 MB)."""
+    from ray_tpu.models import hybrid
+    from ray_tpu.ops import cache as cache_ops
+
+    cfg, params, state, slots = _pangu(chip)
+    ints = chip((slots,), jnp.int32)
+    c = hybrid.decode_step.lower(params, state, ints, ints,
+                                 chip((slots,), jnp.bool_), cfg, 8192).compile()
+    latent = state["latent"]
+    assert latent.shape == (7, 32, 1, 8192, 640)
+    state_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(state))
+    assert 2.3e9 < state_bytes < 2.4e9
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < 128e6
+    text = c.as_text()
+    assert text.count("mla_decode_attention") >= 7
+    assert cache_ops.uses_write_kernel(latent)
+    assert _count(c, latent, "custom-call") == 2       # one write a position
+    assert _count(c, latent, "dynamic-update-slice") == 0
+    _assert_cache_stays_put(c, latent)
+
+
+def test_pangu_prompt_pass_fits_beside_the_weights(chip):
+    """One prompt of the longest bucket (1 x 8191) through 7 MLA layers at
+    128 heads: a group of heads at a time (`ops.mla.mla_prefill_attention`)
+    and the expert layer's last tier without a gather of all T x 8 rows
+    (`ops.moe.dropless_moe`), so that its temporaries stay under 4 GB
+    beside 9.55 GB of weights and 2.35 GB of slots (all heads' scores at
+    once and the gathered tier held 7.5 GB, which no chip has left)."""
+    from ray_tpu.models import hybrid
+
+    cfg, params, _, _ = _pangu(chip)
+    c = hybrid._prefill_first.lower(params, chip((1, 8191), jnp.int32),
+                                    chip((1,), jnp.int32), cfg).compile()
+    mem = c.memory_analysis()
+    assert mem.temp_size_in_bytes < 4.0e9
+    assert 9.5e9 < mem.argument_size_in_bytes < 9.6e9
 
 
 def test_prefill_slots_compiles_at_b1(chip):

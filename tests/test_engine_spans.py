@@ -254,6 +254,64 @@ def test_span_sits_on_the_profilers_host_plane(tmp_path):
     assert "engine.test_annotation" in names
 
 
+def test_a_drafting_steps_spans_say_what_it_proposed_kept_and_yielded():
+    """`engine.step` of a configuration with a prediction module: the
+    device's `draft_proposed` (a draft for every busy slot of the step reaped
+    in that span), `draft_accepted`, the expert-layer counters and, from the
+    host at dispatch, `latent_rows` beside `window_rows` and
+    `written_slots`; `tokens_out` on EVERY step, and over the run it is
+    exactly the tokens the requests received; `engine.prefill` carries the
+    latent layers, the module's with them."""
+    from ray_tpu.models import hybrid
+
+    cfg = hybrid.HybridConfig.tiny_rotary()
+    params = hybrid.init_params(jax.random.PRNGKey(0), cfg)
+    eng = ContinuousBatchingEngine(params, cfg, num_slots=4, max_len=64)
+    asked = [7, 4, 9, 5, 6]
+    ids = [eng.submit([i + 1, 5, 3], max_new_tokens=n) for i, n in enumerate(asked)]
+    eng.run_until_done()
+    eng.step()
+    got = [len(eng.result(i)) - 3 for i in ids]
+    assert got == asked
+    spans = _engine_spans()
+    steps = [e["args"] for e in spans if e["name"] == "engine.step"]
+    assert all("tokens_out" in a for a in steps)
+    assert sum(a["tokens_out"] for a in steps) == sum(asked)
+    dispatched = [a for a in steps if a["active"]]
+    for key in ("latent_rows", "window_rows", "written_slots", "state_slots"):
+        assert all(key in a for a in dispatched), key
+    assert all(a["window_rows"] == 4 * 64 for a in dispatched)
+    assert all(0 < a["latent_rows"] <= a["window_rows"] for a in dispatched)
+    assert all(a["written_slots"] == a["active"] for a in dispatched)
+    reaped = [a for a in steps if "draft_proposed" in a]
+    for key in ("draft_accepted", "expert_assignments", "experts_touched"):
+        assert all(key in a for a in reaped), key
+    # a draft for every busy slot of every dispatched step, junk steps too
+    assert sum(a["draft_proposed"] for a in reaped) == \
+        sum(a["active"] for a in dispatched)
+    assert all(0 <= a["draft_accepted"] <= a["draft_proposed"] for a in reaped)
+    # a request's tokens: its first from the prompt pass, then 1 + accepted
+    # a step; what the device kept is at least what the requests received
+    kept = sum(a["draft_proposed"] + a["draft_accepted"] for a in reaped)
+    assert kept >= sum(asked) - len(asked)
+    prefills = [e["args"] for e in spans if e["name"] == "engine.prefill"]
+    assert len(prefills) == len(asked)
+    assert all(a["latent_layers"] == 4 and a["state_layers"] == 0 for a in prefills)
+
+
+def test_every_cache_says_how_many_tokens_a_step_yields():
+    """`tokens_out` is the engine's own, whatever the cache: the dense
+    model's steps carry it too and it adds up to what was received."""
+    eng = ContinuousBatchingEngine(PARAMS, CFG, num_slots=2, max_len=64)
+    asked = [4, 6, 3]
+    for i, n in enumerate(asked):
+        eng.submit([i + 1, 5], max_new_tokens=n)
+    eng.run_until_done()
+    steps = [e["args"] for e in _engine_spans() if e["name"] == "engine.step"]
+    assert sum(a["tokens_out"] for a in steps) == sum(asked)
+    assert eng.cache.step_tokens == 1
+
+
 def _scopes(lowered):
     """Path components of every op name in a lowering's debug locations."""
     names = re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True))
@@ -283,6 +341,24 @@ def test_named_scopes_reach_the_lowered_programs():
     assert serve_scopes <= _scopes(prefill_slots.lower(
         PARAMS, jax.ShapeDtypeStruct((2, 16), jnp.int32),
         jax.ShapeDtypeStruct((2,), jnp.int32), CFG, 64))
+
+
+def test_named_scopes_of_the_drafting_model_in_all_call_modes():
+    """`mla`, `moe`, `shared_expert`, `mtp`, `state_write`, `head` name the
+    phases of the forward, the prompt pass and the verify step alike."""
+    from ray_tpu.models import hybrid
+
+    cfg = hybrid.HybridConfig.tiny_rotary()
+    params = hybrid.init_params(jax.random.PRNGKey(0), cfg)
+    toks = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    i2 = jax.ShapeDtypeStruct((2,), jnp.int32)
+    want = {"mla", "moe", "shared_expert", "mtp", "head"}
+    assert want <= _scopes(hybrid.forward.lower(params, toks, cfg, with_mtp=True))
+    assert want <= _scopes(hybrid._prefill_first.lower(params, toks, i2, cfg))
+    cache = cfg.make_cache(4, 64)
+    i4 = jax.ShapeDtypeStruct((4,), jnp.int32)
+    assert want | {"state_write"} <= _scopes(hybrid.decode_step.lower(
+        params, cache.state, i4, i4, jax.ShapeDtypeStruct((4,), jnp.bool_), cfg, 64))
 
 
 def test_one_trace_from_http_ingress_to_last_token(ray_start_regular):

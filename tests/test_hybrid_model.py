@@ -170,10 +170,11 @@ def test_mla_absorbed_is_expanded(params):
     p = params["layers"][2]["mla"]
     h = jax.random.normal(jax.random.PRNGKey(7), (1, 40, CFG.d_model))
     want = ref._mla(h, p, C, q_block=40)[0, -1]
-    q, latent = hybrid._mla_latent(CFG, p, h)
-    window = jnp.pad(latent[:, None, :39], ((0, 0), (0, 0), (0, 25), (0, 0)))  # bucket 64
+    q, latent = hybrid._mla_latent(CFG, p, h, jnp.arange(40))
+    # one layer's cache of one slot, one "kv head", bucket 64
+    cache = jnp.pad(latent[None, :, None, :39], ((0, 0),) * 3 + ((0, 25), (0, 0)))
     got = mla.mla_decode_absorbed(
-        q[:, -1], window, latent[:, -1], (jnp.arange(64) < 39)[None],
+        q[:, -1:], cache, 0, latent[:, -1:], jnp.asarray([39]), 64,
         p["w_kvb"], CFG.kv_lora_rank, CFG.qk_nope_dim, CFG.v_head_dim)
     assert rel(got.reshape(-1) @ p["wo"], want) < TOL
 

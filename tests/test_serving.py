@@ -229,9 +229,9 @@ def test_write_rows_matches_the_one_row_window(max_len, dtype, execution,
 def test_write_rows_at_every_callers_shape_and_fill(dims, fill, execution,
                                                     monkeypatch):
     """The three callers' shapes in small (the dense step's; the runs
-    form's ONE kv head of 128; the hybrid step's latent rows, one "kv head"
-    whose last dimension is no multiple of 128 lanes and so stays on the
-    loop), with idle slots among the busy ones, every slot idle (the kernel
+    form's ONE kv head of 128; one "kv head" whose last dimension is no
+    multiple of 128 lanes and so stays on the loop, as the hybrid step's
+    latent rows did before they were stored in whole tiles), with idle slots among the busy ones, every slot idle (the kernel
     then holds one block and passes it through) and every slot busy."""
     _check_write_rows(monkeypatch, execution, dims, 64, jnp.bfloat16, fill)
 
