@@ -72,10 +72,9 @@ import numpy as np
 
 from ray_tpu.models.inference import _gqa_decode_attention
 from ray_tpu.models.transformer import (ModelConfig, _deq_tree,
-                                        _embed_lookup, _mlp, _project_qkv,
-                                        lm_head_weights)
+                                        _embed_lookup, _mlp, lm_head_weights)
 from ray_tpu.ops.cache import write_rows as _write_rows
-from ray_tpu.ops.layers import rms_norm, rotary_embedding
+from ray_tpu.ops.layers import apply_rotary, rms_norm, rotary_embedding
 from ray_tpu.ops.pallas import decode_attention
 from ray_tpu.util import tracing
 
@@ -183,6 +182,32 @@ def _attn_bucket(pos: int, max_len: int) -> int:
     return min(b, max_len)
 
 
+def _one_row_qkv(cfg: ModelConfig, p, x, cos, sin):
+    """The decode step's projections: x [B, 1, d] -> q [B, kvh, rep, hd] (the
+    grouped layout both attention paths take), k, v [B, kvh, hd], q and k
+    rotated. The mathematics is `transformer._project_qkv`'s; the spelling is
+    the decode step's own because at ONE row a slot a product is bound by
+    its weight's bytes, so the weight's layout decides. Left to fuse the
+    rotation's float32 convert and head split into the product, XLA:TPU lays
+    the product's output heads-major and then wants the layer's `wq` / `wk`
+    transposed: sliced out of the stack, written down and copied, every
+    layer of every step (`tests/test_chip_compile.py` names the ops). Behind
+    the barrier the products stay flat bf16 [B, heads * hd] and read their
+    weight in place, out of the stack, as `wv`, `wo` and the FFN's do. At
+    the 64-4096 rows of training and prefill the same copy is noise and the
+    fusions the barrier forbids are wanted: they keep `_project_qkv`."""
+    B = x.shape[0]
+    rep = cfg.n_heads // cfg.n_kv_heads
+    h = rms_norm(x, p["attn_norm"], cfg.norm_eps)[:, 0]
+    q, k, v = h @ p["wq"], h @ p["wk"], h @ p["wv"]
+    q, k = jax.lax.optimization_barrier((q, k))
+    # the tables [B, 1, hd/2] broadcast over (kvh, rep) as over (seq, heads)
+    q = apply_rotary(q.reshape(B, cfg.n_kv_heads, rep, cfg.head_dim), cos, sin)
+    k = apply_rotary(k.reshape(B, 1, cfg.n_kv_heads, cfg.head_dim), cos, sin)
+    v = v.reshape(B, cfg.n_kv_heads, cfg.head_dim)
+    return q, k[:, 0].astype(cfg.dtype), v.astype(cfg.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
                    donate_argnums=(1, 2, 3))
 def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
@@ -219,7 +244,6 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
     """
     B = tokens.shape[0]
     hd = cfg.head_dim
-    rep = cfg.n_heads // cfg.n_kv_heads
     cos, sin = rotary_embedding(lengths[:, None], hd, cfg.rope_theta)
     x = _embed_lookup(params["embed"], tokens[:, None], cfg.dtype)  # [B,1,d]
     # one algorithm, two executions, chosen by what the code can see (as
@@ -239,15 +263,12 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
         lp, layer = inputs
         lp = _deq_tree(lp, cfg.dtype)
         with jax.named_scope("attention"):
-            q, k, v = _project_qkv(cfg, lp, x, cos, sin)  # [B, 1, heads, hd]
-            k_cur = k[:, 0].astype(cfg.dtype)  # [B, kvh, hd]
-            v_cur = v[:, 0].astype(cfg.dtype)
+            q, k_cur, v_cur = _one_row_qkv(cfg, lp, x, cos, sin)
             if kernel:
                 # the cache goes in WHOLE, the layer as a scalar: a sliced
                 # window cannot fuse into a Mosaic call and would be copied
                 attn = decode_attention.gqa_decode_attention(
-                    q[:, 0].reshape(B, cfg.n_kv_heads, rep, hd), k_cur, v_cur,
-                    k_all, v_all, layer, blocks, attn_len)
+                    q, k_cur, v_cur, k_all, v_all, layer, blocks, attn_len)
             else:
                 # the layer's window, read straight out of the whole
                 # (loop-invariant) cache: one dynamic_slice fuses into the
@@ -256,8 +277,9 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
                 # [B, kvh, max_len, hd] first
                 k_win = jax.lax.dynamic_slice(k_all, (layer, 0, 0, 0, 0), win)[0]
                 v_win = jax.lax.dynamic_slice(v_all, (layer, 0, 0, 0, 0), win)[0]
-                attn = _gqa_decode_attention(q.transpose(0, 2, 1, 3), k_win,
-                                             v_win, k_cur, v_cur, mask)
+                attn = _gqa_decode_attention(
+                    q.reshape(B, cfg.n_heads, 1, hd), k_win, v_win, k_cur,
+                    v_cur, mask)
             attn = attn.reshape(B, 1, cfg.n_heads * hd)
             x = x + (attn @ lp["wo"]).astype(x.dtype)
         with jax.named_scope("mlp"):

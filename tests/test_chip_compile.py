@@ -100,6 +100,34 @@ def _whole_cache_relayouts(compiled, cache) -> list:
             and math.prod(map(int, dims.split(","))) == cache.size]
 
 
+# `%name = type opcode(`, the type an array's or a tuple's
+_ANY_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\((.*)$", re.M)
+
+
+def _weight_relayouts_in_the_scan(compiled, params) -> list:
+    """Names of the instructions that write one layer of a stacked weight
+    down, among the TOP-LEVEL instructions of the layer scan's body (the one
+    `while`'s): a `copy`, a `transpose`, a slice, or a fusion other than a
+    `kOutput` one (a product's: inside it a fused slice rightly lives, and
+    reads the stack in place), whose result has the dimensions `[1, d, n]`
+    or `[d, n]` of a matrix leaf `[L, d, n]` of `params["layers"]`. Each is
+    a pass over a weight the step did not have to make: at one row a slot
+    a product is bound by its weight's bytes."""
+    hlo = compiled.as_text()
+    body = re.search(r" while\(.*body=%([\w.\-]+)", hlo).group(1)
+    layer = {",".join(map(str, dims)) for w in params["layers"].values()
+             if len(w.shape) == 3 for dims in (w.shape[1:], (1,) + w.shape[1:])}
+    found = []
+    for name, result, opcode, rest in _ANY_INSTRUCTION.findall(
+            "\n".join(_computations(hlo)[body])):
+        writes = (opcode in ("copy", "transpose", "slice", "dynamic-slice")
+                  or opcode == "fusion" and "kind=kOutput" not in rest)
+        if writes and layer & set(re.findall(r"\w+\[([\d,]+)\]", result)):
+            found.append(name)
+    return found
+
+
 def _assert_cache_stays_put(compiled, cache, temp_limit=256 * 2**20):
     assert _whole_cache_relayouts(compiled, cache) == []
     assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
@@ -212,6 +240,7 @@ def test_decode_step_fused_compiles_at_b1_8_slots(chip, attn_len):
     # weights + both caches in, caches updated in place (donated)
     assert c.memory_analysis().argument_size_in_bytes < 3 * 2**30
     _assert_decode_step_reads_live_rows_in_place(c, kv)
+    assert _weight_relayouts_in_the_scan(c, params) == []
 
 
 # InternLM2-1.8B as the serving cell runs it (perfbench/configs/internlm2-1.8b.json)
@@ -245,7 +274,18 @@ def test_decode_step_keeps_the_cache_layout(chip, attn_len):
     each slot's live rows through a Mosaic kernel (the `chip` fixture steers
     the kernel branch on) that takes the whole caches and the layer index:
     a window sliced out first could not fuse into the call and would be
-    copied, [32,8,attn_len,128] twice a layer."""
+    copied, [32,8,attn_len,128] twice a layer.
+
+    The weights stay put too (PR 35): every stacked weight of the scan is
+    read by the product that uses it. The parent of PR 35 fails the last
+    line with `constant_dynamic-slice_fusion.4` + `copy.45
+    bf16[1,2048,2048]{1,2,0}` (`wq`) and `constant_dynamic-slice_fusion.5` +
+    `copy.48 bf16[1,2048,1024]{1,2,0}` (`wk`): with the rotation's float32
+    convert and head split fused into those two products XLA:TPU laid
+    their output heads-major and wanted the weight transposed, so each
+    layer's was sliced out of the stack, written down and copied, 0.72 ms
+    of a 5.86 ms step (ledger, PR 34). `serving._one_row_qkv` says what
+    keeps them flat."""
     from ray_tpu.models.serving import decode_step_fused
 
     params = _param_shapes(chip, INTERNLM2)
@@ -254,6 +294,23 @@ def test_decode_step_keeps_the_cache_layout(chip, attn_len):
     c = decode_step_fused.lower(params, kv, kv, ints, ints, INTERNLM2,
                                 attn_len).compile()
     _assert_decode_step_reads_live_rows_in_place(c, kv)
+    assert _weight_relayouts_in_the_scan(c, params) == []
+
+
+def test_the_weight_helper_names_what_the_parent_of_pr35_compiled(chip, monkeypatch):
+    """The same step around the shared `_project_qkv`, as up to PR 34 (the
+    spelling is kept in `tests/test_serving.py`): the helper finds `wq` and
+    `wk`, each sliced out of its stack and copied, and nothing else."""
+    from test_serving import _decode_step_with, _project_qkv_as_the_parent_of_pr35
+
+    params = _param_shapes(chip, INTERNLM2)
+    kv = _cell_cache(chip)
+    ints = chip((CELL_SLOTS,), jnp.int32)
+    c = _decode_step_with(monkeypatch, _project_qkv_as_the_parent_of_pr35).lower(
+        params, kv, kv, ints, ints, INTERNLM2, 512).compile()
+    found = _weight_relayouts_in_the_scan(c, params)
+    assert sorted(n.split(".")[0] for n in found) == [
+        "constant_dynamic-slice_fusion"] * 2 + ["copy"] * 2, found
 
 
 def test_decode_step_off_the_kernels_shapes_keeps_the_cache_layout(chip):

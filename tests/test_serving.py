@@ -149,6 +149,69 @@ def test_decode_step_donation_clean():
         assert now_live <= n_live  # no per-step full-cache reallocation
 
 
+def _project_qkv_as_the_parent_of_pr35(cfg, p, x, cos, sin):
+    """What `decode_step_fused`'s body composed up to PR 34, in the layout
+    `serving._one_row_qkv` returns: the shared `_project_qkv` on [B, 1, d],
+    the one position dropped, the query heads grouped by kv head."""
+    from ray_tpu.models.transformer import _project_qkv
+
+    q, k, v = _project_qkv(cfg, p, x, cos, sin)  # [B, 1, heads, hd]
+    q = q[:, 0].reshape(x.shape[0], cfg.n_kv_heads, -1, cfg.head_dim)
+    return q, k[:, 0].astype(cfg.dtype), v[:, 0].astype(cfg.dtype)
+
+
+def _decode_step_with(monkeypatch, qkv):
+    """`decode_step_fused`'s own text, traced anew around `qkv` (jax keeps
+    traces by the function's identity: a function of its own)."""
+    from ray_tpu.models import serving
+
+    def step(*args):
+        monkeypatch.setattr(serving, "_one_row_qkv", qkv)
+        return serving.decode_step_fused.__wrapped__(*args)
+
+    return jax.jit(step, static_argnums=(5, 6))
+
+
+@pytest.mark.parametrize("weights", ["bf16", "int8"])
+def test_decode_step_equals_the_one_composing_project_qkv(monkeypatch, weights):
+    """The decode step spells its projections itself (`_one_row_qkv`: flat
+    bf16 products behind a barrier, so that on the chip each reads its
+    weight in place). Same mathematics as the shared `_project_qkv` the
+    parent composed: next tokens, lengths and BOTH caches (the written K/V
+    rows among them) equal bit for bit on the CPU path, over four steps at
+    ragged lengths with idle slots, with bf16 weights and with the
+    int8-dequantised weights the benchmark's control runs."""
+    import dataclasses
+
+    from ray_tpu.models import serving
+
+    cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    if weights == "int8":
+        params = serving.quantize_model_params(params, cfg)
+    shape = (cfg.n_layers, 6, cfg.n_kv_heads, MAX_LEN, cfg.head_dim)
+
+    def run(qkv):
+        step = _decode_step_with(monkeypatch, qkv)
+        k = jax.random.normal(jax.random.PRNGKey(1), shape).astype(cfg.dtype)
+        v = jax.random.normal(jax.random.PRNGKey(2), shape).astype(cfg.dtype)
+        lengths = jnp.asarray([5, 0, 33, 17, 0, 1], jnp.int32)
+        tokens = jnp.asarray([3, 9, 100, 7, 0, 42], jnp.int32)
+        seen = []
+        for _ in range(4):
+            k, v, lengths, tokens = step(params, k, v, lengths, tokens, cfg, MAX_LEN)
+            seen.append(np.asarray(tokens))
+        return [np.asarray(a.astype(jnp.float32)) for a in (k, v)] + [
+            np.asarray(lengths), np.stack(seen)]
+
+    ours = run(serving._one_row_qkv)
+    parents = run(_project_qkv_as_the_parent_of_pr35)
+    assert ours[2].tolist() == [9, 0, 37, 21, 0, 5]
+    assert len({tuple(t) for t in ours[3].T}) > 1  # not one token everywhere
+    for a, b in zip(ours, parents):
+        np.testing.assert_array_equal(a, b)
+
+
 def _write_rows_one_row_window(cache, rows, lengths):
     """The row write as it was up to PR 26, kept as the plain reference:
     one `dynamic_update_slice` of a [1, hd] window per (layer, slot, head).
