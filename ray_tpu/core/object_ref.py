@@ -18,7 +18,10 @@ class ObjectRef:
     # releases it on GC (reference RemoveLocalReference). Only instances
     # created through a counting path (task returns, put, deserialization)
     # set it; ad-hoc internal ObjectRef(...) constructions never release.
-    __slots__ = ("id", "owner_address", "_call_site", "_counted")
+    # _arrived_us: set on a dynamic return's ref alone, by the owner when the
+    # item is reported (`tracing.now_us()`); never pickled.
+    __slots__ = ("id", "owner_address", "_call_site", "_counted",
+                 "_arrived_us")
 
     def __init__(self, object_id: ObjectID, owner_address: Optional[str] = None, call_site: str = ""):
         self.id = object_id

@@ -1856,6 +1856,7 @@ class CoreWorker:
                 st.local_refs += 1
                 ref = ObjectRef(oid, owner_address=self.address)
                 ref._counted = True
+                ref._arrived_us = tracing.now_us()  # for the relay's lag
                 rec["refs"].append(ref)
                 fire = self._drain_dynamic_waiters(rec)
             self._obj_cv.notify_all()
@@ -3602,10 +3603,27 @@ class CoreWorker:
                 "a num_returns='dynamic' task must return a generator or "
                 f"iterator, got {type(value).__name__}")
         item_refs: List[ObjectRef] = []
-        for i, item in enumerate(value):
-            oid_i = ObjectID.for_dynamic_return(spec.task_id, i)
-            self._report_dynamic(spec, self._build_result_entry(oid_i, item))
-            item_refs.append(ObjectRef(oid_i, owner_address=spec.owner_address))
+        # ONE `stream::<method>` span when the loop ends, under the task's
+        # context like `task::` (which closed when the method RETURNED the
+        # generator): per item two clock reads, never a span
+        clock = time.perf_counter
+        t_open = tracing.now_us()
+        report_s = 0.0
+        try:
+            for i, item in enumerate(value):
+                t_item = clock()
+                oid_i = ObjectID.for_dynamic_return(spec.task_id, i)
+                self._report_dynamic(spec, self._build_result_entry(oid_i, item))
+                item_refs.append(ObjectRef(oid_i, owner_address=spec.owner_address))
+                report_s += clock() - t_item
+        finally:
+            ctx = tracing.current_ctx() or (None, None)
+            tracing.add_complete(
+                f"stream::{spec.method_name}", "task_stream",
+                t_open, tracing.now_us() - t_open,
+                trace_id=ctx[0], parent_id=ctx[1],
+                task_id=spec.task_id.binary().hex(), items=len(item_refs),
+                report_us_sum=int(1e6 * report_s))
         return ObjectRefGenerator(item_refs, done=True)
 
     def _build_result_entry(self, oid: ObjectID, value) -> tuple:

@@ -983,8 +983,6 @@ class HybridCache:
     last, the latent rows [layers, slots, 1, max_len, latent_width]; with a
     module, the token it drafted for each slot [slots]."""
 
-    idle_args: Dict[str, int] = {}
-
     def __init__(self, cfg: HybridConfig, num_slots: int, max_len: int):
         self.cfg, self.num_slots, self.max_len = cfg, num_slots, max_len
         kinds = [m for m, _ in cfg.layer_kinds()]
@@ -1028,14 +1026,12 @@ class HybridCache:
     def step_args(self, n_active: int, live_rows: int,
                   attn_len: int) -> Dict[str, int]:
         """What one decode step moved, known on the host at dispatch:
-        `latent_rows` the live rows of the busy slots, which the attention
-        has to read, beside `window_rows`, every slot's window to the
-        deepest bucket, which the einsum form reads; `written_slots` the
-        slots whose block of rows the row write moves."""
+        `state_slots` the busy slots whose KDA state it needs, `latent_rows`
+        the live rows of the busy slots, which the attention has to read
+        (of the span's `num_slots x attn_len`, which the einsum form reads;
+        the row write moves the blocks of the span's `active` slots)."""
         return {"state_slots": n_active if self.n_kda else 0,
-                "latent_rows": live_rows if self.n_latent else 0,
-                "window_rows": self.num_slots * attn_len if self.n_latent else 0,
-                "written_slots": n_active if self.n_latent else 0}
+                "latent_rows": live_rows if self.n_latent else 0}
 
 
 class RunsCache(HybridCache):
@@ -1049,7 +1045,6 @@ class RunsCache(HybridCache):
 
     counters = ()
     step_tokens = 1
-    idle_args = {"written_slots": 0}
 
     def __init__(self, cfg: HybridConfig, num_slots: int, max_len: int):
         self.cfg, self.num_slots, self.max_len = cfg, num_slots, max_len
@@ -1072,8 +1067,7 @@ class RunsCache(HybridCache):
                   attn_len: int) -> Dict[str, int]:
         """`state_slots`: the busy slots, whose recurrent state the step
         needs; `kv_rows`: the positions they hold, which every attention
-        layer reads; `written_slots`: the slots whose block of K/V rows
-        `write_rows` moves, the busy ones again."""
+        layer reads (`write_rows` moves the K/V blocks of the span's
+        `active` slots)."""
         return {"state_slots": n_active if self.n_mamba else 0,
-                "kv_rows": live_rows if self.n_attn else 0,
-                "written_slots": n_active if self.n_attn else 0}
+                "kv_rows": live_rows if self.n_attn else 0}

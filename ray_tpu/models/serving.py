@@ -47,9 +47,14 @@ The engine records what it does in the program's own timeline
 per request `engine.queue` -> `engine.prefill` -> `engine.decode`,
 contiguous, emitted when the request finishes, under the trace context its
 `submit()` ran in (a Serve replica's request thread has one; standalone use
-has none and the spans carry no ids); per step `engine.step` with children
-`engine.prefill_dispatch` and `engine.wait_device`, so a step's host time
-is its duration less its device waits.
+has none and the spans carry no ids), and, from the thread that consumes a
+driven `generate_stream`, ONE `engine.stream` when it ends (tokens, wakes,
+how long a reaped token waited for its thread: never a span per token);
+per step `engine.step` with children `engine.prefill_dispatch` and
+`engine.wait_device`, so a step's host time is its duration less its device
+waits, and of that `lock_wait_us` went to entering `_lock` and
+`bookkeep_us` to handing tokens out under it; between two steps of the
+driver thread `engine.between_steps`, so the two tile that thread's life.
 
 Serve wires it through `LLMDeployment` (serve replicas each host an
 engine; the replica lifecycle hooks `__serve_start__`/`__serve_stop__`
@@ -322,9 +327,8 @@ class DenseKVCache:
         counters                    names of what `report` carries behind
                                     the tokens (summed into `engine.step`)
         step_args(n_active, live_rows, attn_len), prefill_args
-                                    span arguments
-        idle_args                   those of a step that dispatched no
-                                    decode
+                                    span arguments (of a step that
+                                    dispatched a decode; of a prompt pass)
 
     It calls the module's own jitted `prefill_slots`, `_write_slots` and
     `decode_step_fused`, so the dense model compiles to the programs it
@@ -333,7 +337,6 @@ class DenseKVCache:
     counters: Tuple[str, ...] = ()
     step_tokens = 1
     prefill_args: Dict[str, int] = {}
-    idle_args: Dict[str, int] = {"written_slots": 0}
 
     def __init__(self, cfg: ModelConfig, num_slots: int, max_len: int):
         self.cfg, self.max_len = cfg, max_len
@@ -363,14 +366,11 @@ class DenseKVCache:
 
     def step_args(self, n_active: int, live_rows: int,
                   attn_len: int) -> Dict[str, int]:
-        """The rows that hold a token, which the step has to read, beside the
-        window of every slot to the deepest bucket, which it read up to
-        PR 28 (and still reads on the CPU path); and the slots whose block
-        of rows `ops.cache.write_rows` moves, the busy ones, where the loop
-        it replaces on the TPU moved every slot's."""
-        return {"live_rows": live_rows,
-                "window_rows": self.state["k"].shape[1] * attn_len,
-                "written_slots": n_active}
+        """The rows that hold a token, which the step has to read (of the
+        span's `num_slots x attn_len` it read up to PR 28, and still reads
+        on the CPU path); the slots whose block of rows
+        `ops.cache.write_rows` moves are the span's `active`."""
+        return {"live_rows": live_rows}
 
 
 def _pow2(n: int) -> int:
@@ -394,6 +394,7 @@ class _Request:
     t_submit: float = 0.0
     t_admit: float = 0.0
     t_first: float = 0.0
+    t_reap: float = 0.0  # of the reap that appended its newest tokens
     waited_for_slot: bool = False  # an admission pass found no slot free
     bucket: int = 0                # of the prefill that admitted it,
     batch: int = 0                 # and how many requests rode that call
@@ -446,6 +447,10 @@ class ContinuousBatchingEngine:
         self._driver_stop = False
         self._driver_error: Optional[BaseException] = None
         self._attn_len = 0  # attention bucket of the last dispatched decode
+        # of the step in progress (the stepper's own, under `_step_lock`):
+        # seconds waited to enter `_lock`, and held it to hand out tokens
+        self._lock_wait_s = 0.0
+        self._bookkeep_s = 0.0
         self._step_args: Dict[str, int] = {}  # the cache's, of that decode
         tracing.record_compiles()
         # slots freed since the last step's end; their device lengths go to
@@ -542,14 +547,20 @@ class ContinuousBatchingEngine:
     def _step_inner(self) -> int:
         # the span opens under `_step_lock`: lock wait is not counted
         with tracing.span("engine.step", "engine") as did:
+            clock = time.perf_counter
+            self._lock_wait_s = self._bookkeep_s = 0.0
+            t_ask = clock()
             with self._lock:
+                self._lock_wait_s += clock() - t_ask
                 admissions = self._collect_admissions()
                 did["waiting"] = len(self._waiting)  # left without a slot
             for bucket, reqs in admissions:
                 with tracing.span("engine.prefill_dispatch", "engine",
                                   bucket=bucket, batch=len(reqs)):
                     self._dispatch_prefill(bucket, reqs)  # device enqueue only
+            t_ask = clock()
             with self._lock:
+                self._lock_wait_s += clock() - t_ask
                 prev = self._pending
                 self._pending = self._dispatch_decode()   # device enqueue only
             did.update(
@@ -557,17 +568,25 @@ class ContinuousBatchingEngine:
                 prefill_batches=len(admissions),
                 active=len(self._pending[1]) if self._pending else 0,
                 attn_len=self._attn_len if self._pending else 0,
-                **(self._step_args if self._pending
-                   else self.cache.idle_args))
+                **(self._step_args if self._pending else {}))
             firsts = self._drain_pending_first()          # device wait, no _lock
             # the model's counters ride the token array, so they are those
             # of the step reaped here: the one dispatched a step earlier;
             # `tokens_out`: what the requests received inside this span
             reaped = self._reap(prev)                     # device wait, no _lock
+            # the reaped step's device array goes HERE, inside the span: left
+            # to the frame's end it is freed behind the span's back (0.4-0.5
+            # ms a step on the chip, under neither of the driver's two spans)
+            del prev
             did.update(reaped, tokens_out=firsts + reaped.get("tokens_out", 0))
             self._retire_slots()                          # device enqueue only
+            t_ask = clock()
             with self._lock:
-                return len(self._active) + len(self._waiting)
+                self._lock_wait_s += clock() - t_ask
+                left = len(self._active) + len(self._waiting)
+            did.update(lock_wait_us=int(1e6 * self._lock_wait_s),
+                       bookkeep_us=int(1e6 * self._bookkeep_s))
+            return left
 
     def _collect_admissions(self):
         """Pop waiting requests into free slots, grouped by prompt bucket
@@ -644,7 +663,9 @@ class ContinuousBatchingEngine:
         Runs in the stepper, after the step's own dispatches and before the
         next step's admissions, so it never lands after the `write` that
         gives a freed slot its next prompt."""
+        t_ask = time.perf_counter()
         with self._lock:
+            self._lock_wait_s += time.perf_counter() - t_ask
             freed, self._retired = self._retired, []
         if freed:
             retired = np.zeros((self.num_slots,), bool)
@@ -662,12 +683,16 @@ class ContinuousBatchingEngine:
             with tracing.span("engine.wait_device", "engine", what="first"):
                 first = self._to_host(first_dev)  # device wait — no _lock held
             now = tracing.now_us()
+            t_ask = time.perf_counter()
             with self._lock:
+                t_in = time.perf_counter()
                 for row, req in entries:
-                    req.t_first = now
+                    req.t_first = req.t_reap = now
                     req.generated.append(int(first[row]))
                     self._maybe_finish(req)
                 self._cv.notify_all()
+                self._lock_wait_s += t_in - t_ask
+                self._bookkeep_s += time.perf_counter() - t_in
         return sum(len(entries) for _, entries in batches)
 
     def _reap(self, prev) -> Dict[str, int]:
@@ -686,7 +711,10 @@ class ContinuousBatchingEngine:
         most = self.cache.step_tokens
         yielded = nxt[:self.num_slots * most].reshape(self.num_slots, most)
         out = 0
+        now = tracing.now_us()  # ONE stamp a reap: what a stream's lag is from
+        t_ask = time.perf_counter()
         with self._lock:
+            t_in = time.perf_counter()
             for slot, req in slot_map.items():
                 kept = [int(t) for t in yielded[slot] if t >= 0]
                 if self._active.get(slot) is req:  # the bound, made exact
@@ -694,10 +722,13 @@ class ContinuousBatchingEngine:
                 for t in kept:
                     if req.done:
                         break  # finished at dispatch+1, or on the token before
+                    req.t_reap = now
                     req.generated.append(t)
                     out += 1
                     self._maybe_finish(req)
             self._cv.notify_all()
+            self._lock_wait_s += t_in - t_ask
+            self._bookkeep_s += time.perf_counter() - t_in
         return {"tokens_out": out,
                 **{name: int(nxt[self.num_slots * most + i])
                    for i, name in enumerate(self.cache.counters)}}
@@ -737,12 +768,24 @@ class ContinuousBatchingEngine:
                     or self._pending_first)
 
     def _drive(self) -> None:
+        """`engine.step` and `engine.between_steps` tile this thread's life:
+        the second is what passes from one `step()` returning to the next
+        being called (the wait for `_lock` behind the streaming threads the
+        reap woke, and the sleep when there is no work: `slept_us`, no
+        cost)."""
         while True:
-            with self._lock:
-                while not self._driver_stop and not self._has_work():
-                    self._cv.wait(0.1)
-                if self._driver_stop:
-                    return
+            with tracing.span("engine.between_steps", "engine") as gap:
+                slept = 0.0
+                with self._lock:
+                    gap["had_work"] = self._has_work()
+                    while not self._driver_stop and not self._has_work():
+                        t_sleep = time.perf_counter()
+                        self._cv.wait(0.1)
+                        slept += time.perf_counter() - t_sleep
+                    stop = self._driver_stop
+                gap["slept_us"] = int(1e6 * slept)
+            if stop:
+                return
             try:
                 self.step()
             except Exception as e:  # surface to waiters instead of hanging
@@ -789,17 +832,19 @@ class ContinuousBatchingEngine:
             return self._result_locked(self._finished[request_id])
 
     def _progress_locked(self, request_id: int):
+        """(tokens so far, done, the stamp of the reap that appended the
+        newest of them)."""
         req = self._finished.get(request_id)
         if req is not None:
             toks = list(req.generated)
             if (self.eos_token is not None and toks
                     and toks[-1] == self.eos_token):
                 toks.pop()
-            return toks, True
+            return toks, True, req.t_reap
         for req in list(self._active.values()) + self._waiting:
             if req.request_id == request_id:
-                return list(req.generated), req.done
-        return [], True  # unknown id
+                return list(req.generated), req.done, req.t_reap
+        return [], True, 0.0  # unknown id
 
     def progress(self, request_id: int):
         """(tokens generated so far, done) — readable while decoding, for
@@ -807,7 +852,7 @@ class ContinuousBatchingEngine:
         streamed output always equals the non-streamed suffix. Takes only
         the bookkeeping lock: never blocks behind a device wait."""
         with self._lock:
-            return self._progress_locked(request_id)
+            return self._progress_locked(request_id)[:2]
 
     def generate(self, prompt: List[int], *, max_new_tokens: int = 32,
                  timeout: Optional[float] = None) -> List[int]:
@@ -825,23 +870,12 @@ class ContinuousBatchingEngine:
         """Generator yielding tokens AS DECODED (continuous batching keeps
         serving other slots between yields) — the engine half of
         Serve token streaming (reference vLLM-style streaming generate)."""
+        t_submit = tracing.now_us()
         rid = self.submit(prompt, max_new_tokens=max_new_tokens)
-        emitted = 0
         if self._driver is not None:
-            while True:
-                with self._lock:
-                    while True:
-                        toks, done = self._progress_locked(rid)
-                        if len(toks) > emitted or done:
-                            break
-                        if self._driver_error is not None:
-                            raise self._driver_error
-                        self._cv.wait(0.2)
-                while emitted < len(toks):  # yield OUTSIDE the lock
-                    yield int(toks[emitted])
-                    emitted += 1
-                if done:
-                    return
+            yield from self._stream_from_driver(rid, t_submit)
+            return
+        emitted = 0
         while True:
             active = self.step()
             toks, done = self.progress(rid)
@@ -852,6 +886,48 @@ class ContinuousBatchingEngine:
                 return
             if active == 0:
                 return  # nothing left anywhere; request never finished
+
+    def _stream_from_driver(self, rid: int, t_submit: float):
+        """The consumer's side of a stream. One `engine.stream` span when the
+        generator ends (exhausted, closed or raised), from this thread and
+        under its trace context, `submit()` to the last `yield` returning;
+        what a token cost on its way out is integers on this frame: `wakes`
+        (returns from `_cv.wait` + the first pass), `deliver_lag_us_sum` (a
+        batch of tokens in hand, less the stamp of the reap that appended
+        it) and `lock_us_sum` (asking for `_lock` to releasing it; the
+        re-acquire inside `_cv.wait` is in the lag, not here)."""
+        clock = time.perf_counter
+        ctx = tracing.current_ctx() or (None, None)
+        emitted, wakes = 0, 1
+        lag_sum = lock_s = 0.0
+        try:
+            while True:
+                t_ask = clock()
+                with self._lock:
+                    while True:
+                        toks, done, t_reap = self._progress_locked(rid)
+                        if len(toks) > emitted or done:
+                            break
+                        if self._driver_error is not None:
+                            raise self._driver_error
+                        lock_s += clock() - t_ask
+                        self._cv.wait(0.2)
+                        t_ask = clock()
+                        wakes += 1
+                lock_s += clock() - t_ask
+                if len(toks) > emitted:
+                    lag_sum += tracing.now_us() - t_reap
+                while emitted < len(toks):  # yield OUTSIDE the lock
+                    emitted += 1  # first: a close at the yield counts it
+                    yield int(toks[emitted - 1])
+                if done:
+                    return
+        finally:
+            tracing.add_complete(
+                "engine.stream", "engine", t_submit, tracing.now_us() - t_submit,
+                trace_id=ctx[0], parent_id=ctx[1], request_id=rid,
+                tokens=emitted, wakes=wakes, deliver_lag_us_sum=int(lag_sum),
+                lock_us_sum=int(1e6 * lock_s))
 
 
 def LLMDeployment(params, cfg, *, num_slots: int = 4,

@@ -395,7 +395,18 @@ class AsyncHTTPProxy:
         model), so there is NO thread-per-live-stream and no stream cap.
         The request deadline bounds the WHOLE stream: when it expires
         mid-stream, a typed error chunk + clean terminator go out instead
-        of the connection hanging on a stalled replica."""
+        of the connection hanging on a stalled replica.
+
+        One `relay::<deployment>` span a streamed request, 200 header to
+        terminator, under the ingress context: `items`, what an item cost
+        once it was there (`fetch_us_sum`, `write_us_sum` for write + drain),
+        `first_write_ts` (epoch us of the first chunk's drain returning: on
+        one host comparable with the end of the replica's `engine.prefill`)
+        and `arrive_lag_us_sum` (chunk written, less the stamp
+        `rpc_report_dynamic_return` put on the item's ref when it arrived).
+        Per item clock reads and adds, never a span. The gRPC ingress and a
+        bare `handle.remote(stream=True)` consumer relay nothing and get no
+        `relay::`."""
         from ray_tpu.serve.config import get_serve_config
         from ray_tpu.serve.edge_util import (await_next_stream_item,
                                              fetch_value)
@@ -427,42 +438,66 @@ class AsyncHTTPProxy:
             "\r\n").encode("latin1"))
         await writer.drain()
 
+        clock = time.perf_counter
+        t_relay = tracing.now_us()
+        items = 0
+        fetch_s = write_s = first_write_ts = lag_sum = 0.0
         # Once chunked 200 headers are out, an HTTP 500 can never follow —
         # writing one mid-body would corrupt framing and desync keep-alive.
         # Errors become a final error chunk + a CLEAN chunk terminator.
         try:
-            while True:
-                if time.time() >= deadline_ts:
-                    from ray_tpu.core.exceptions import RequestTimeoutError
+            try:
+                while True:
+                    if time.time() >= deadline_ts:
+                        from ray_tpu.core.exceptions import RequestTimeoutError
 
-                    raise RequestTimeoutError(
-                        "stream exceeded its request deadline")
-                if not gen._done:
-                    await await_next_stream_item(self._loop, gen,
-                                                 _remaining())
-                try:
-                    ref = next(gen)
-                except StopIteration:
-                    break
-                item = await fetch_value(self._loop, self._pool, ref,
-                                         _remaining())
-                if isinstance(item, (bytes, bytearray, memoryview)):
-                    chunk = bytes(item)
-                elif isinstance(item, str):
-                    chunk = item.encode()
-                else:
-                    chunk = json.dumps(item).encode() + b"\n"
-                writer.write(f"{len(chunk):x}\r\n".encode() + chunk + b"\r\n")
-                await writer.drain()
-        except Exception as e:
-            from ray_tpu.serve.api import _serve_metrics
+                        raise RequestTimeoutError(
+                            "stream exceeded its request deadline")
+                    if not gen._done:
+                        await await_next_stream_item(self._loop, gen,
+                                                     _remaining())
+                    try:
+                        ref = next(gen)
+                    except StopIteration:
+                        break
+                    t_fetch = clock()
+                    item = await fetch_value(self._loop, self._pool, ref,
+                                             _remaining())
+                    t_write = clock()
+                    if isinstance(item, (bytes, bytearray, memoryview)):
+                        chunk = bytes(item)
+                    elif isinstance(item, str):
+                        chunk = item.encode()
+                    else:
+                        chunk = json.dumps(item).encode() + b"\n"
+                    writer.write(
+                        f"{len(chunk):x}\r\n".encode() + chunk + b"\r\n")
+                    await writer.drain()
+                    t_done, now = clock(), tracing.now_us()
+                    fetch_s += t_write - t_fetch
+                    write_s += t_done - t_write
+                    first_write_ts = first_write_ts or now
+                    lag_sum += now - getattr(ref, "_arrived_us", now)
+                    items += 1
+            except Exception as e:
+                from ray_tpu.serve.api import _serve_metrics
 
-            _serve_metrics()["errors"].inc(tags={"deployment": name})
-            err = json.dumps({"error": str(e),
-                              "type": type(e).__name__}).encode() + b"\n"
-            writer.write(f"{len(err):x}\r\n".encode() + err + b"\r\n")
-        writer.write(b"0\r\n\r\n")
-        await writer.drain()
+                _serve_metrics()["errors"].inc(tags={"deployment": name})
+                err = json.dumps({"error": str(e),
+                                  "type": type(e).__name__}).encode() + b"\n"
+                writer.write(f"{len(err):x}\r\n".encode() + err + b"\r\n")
+            writer.write(b"0\r\n\r\n")
+            await writer.drain()
+        finally:
+            trace_id, parent = trace_ctx or (None, None)
+            tracing.add_complete(
+                f"relay::{name}", "serve_relay", t_relay,
+                tracing.now_us() - t_relay, trace_id=trace_id,
+                parent_id=parent, deployment=name, items=items,
+                fetch_us_sum=int(1e6 * fetch_s),
+                write_us_sum=int(1e6 * write_s),
+                first_write_ts=first_write_ts,
+                arrive_lag_us_sum=int(lag_sum))
 
     def stop(self) -> None:
         try:

@@ -33,7 +33,18 @@ fresh span_id on the event and re-parents nested spans under itself;
 `ctx_scope()` adopts a context that crossed a process boundary
 (TaskSpec.trace_ctx), making ingress -> route -> submit -> raylet lease ->
 worker execute -> engine -> result delivery one causal tree under a single
-trace_id.
+trace_id. For a streamed Serve request the chain is `ingress:: -> route::
+-> submit:: -> task:: -> stream:: -> engine.queue / prefill / decode /
+stream -> relay::`: `task::` of a streaming method ends when the generator
+is RETURNED; the loop that runs it is `stream::` (core/worker.py), the
+thread that consumes the engine's tokens emits `engine.stream`
+(models/serving.py), and the proxy's loop that writes them `relay::`
+(serve/http_proxy.py).
+
+THE COST RULE: never a span per token. What a token costs on its way is
+counted on a frame that already exists (clock reads and integer adds) and
+leaves as arguments of ONE span when its stream ends; a step gets one span
+beside its own (`engine.between_steps`), a request three.
 
 One clock with the device trace: `span()` also enters
 `jax.profiler.TraceAnnotation(name)` once jax is loaded in the process (it

@@ -5,6 +5,7 @@ by program name, and the named scopes of the train and serve programs."""
 
 import json
 import re
+import threading
 import time
 
 import jax
@@ -104,13 +105,13 @@ def test_step_spans_count_the_slots_they_dispatched():
         [e for e in spans if e["name"] == "engine.prefill_dispatch"])
     assert all(s["args"]["attn_len"] == 64 for s in steps
                if s["args"]["active"])
-    # the dense cache's own arguments: the rows that hold a token, which
-    # the step has to read, beside the window of every slot it replaces.
+    # the dense cache's own argument: the rows that hold a token, which
+    # the step has to read, of the slots x `attn_len` window it replaces.
     # Every prompt here has 2 tokens, so a slot's k-th dispatch holds 1 + k
     # rows: each request adds 2 + 3 + ... + (asked + 1)
     dispatched = [s["args"] for s in steps if s["args"]["active"]]
-    assert all(a["window_rows"] == 4 * 64 for a in dispatched)
-    assert all(0 < a["live_rows"] <= a["window_rows"] for a in dispatched)
+    assert all(0 < a["live_rows"] <= 4 * a["attn_len"] for a in dispatched)
+    assert all(a["live_rows"] >= a["active"] for a in dispatched)
     assert sum(a["live_rows"] for a in dispatched) == sum(
         sum(range(2, n + 2)) for n in asked)
     # every device wait lies inside a step: host time = step - its waits
@@ -124,12 +125,13 @@ def test_step_spans_count_the_slots_they_dispatched():
 
 @pytest.mark.parametrize("cache", ["dense", "runs"])
 def test_step_spans_count_the_slots_whose_rows_the_step_wrote(cache):
-    """`written_slots` on `engine.step`: the slots whose block of rows the
-    step's `ops.cache.write_rows` moves, which are the busy slots at
-    dispatch (on the TPU; the loop of the CPU path visits every slot), for
-    the dense cache and for the runs cache; 0 on a step that dispatched no
-    decode (one more call of the stepper once every request is done: it
-    reaps the last junk slot-step and finds nothing busy)."""
+    """`active` on `engine.step` is also the slots whose block of rows the
+    step's `ops.cache.write_rows` moves: the busy slots at dispatch (on the
+    TPU; the loop of the CPU path visits every slot), for the dense cache
+    and for the runs cache; 0, with `attn_len` 0, on a step that dispatched
+    no decode (one more call of the stepper once every request is done: it
+    reaps the last junk slot-step and finds nothing busy). No cache repeats
+    either under a name of its own."""
     if cache == "dense":
         cfg, params = CFG, PARAMS
     else:
@@ -142,9 +144,10 @@ def test_step_spans_count_the_slots_whose_rows_the_step_wrote(cache):
     eng.run_until_done()
     eng.step()
     steps = [e["args"] for e in _engine_spans() if e["name"] == "engine.step"]
-    assert all(a["written_slots"] == a["active"] for a in steps)
-    assert {a["written_slots"] for a in steps} == {0, 1, 2, 3}
-    assert steps[-1]["written_slots"] == 0 and steps[-1]["attn_len"] == 0
+    assert not any({"written_slots", "window_rows"} & set(a) for a in steps)
+    assert {a["active"] for a in steps} == {0, 1, 2, 3}
+    assert steps[-1]["active"] == 0 and steps[-1]["attn_len"] == 0
+    assert all((a["attn_len"] == 64) == (a["active"] > 0) for a in steps)
     # the other arguments of the cache stay those of a dispatched step
     own = "live_rows" if cache == "dense" else "kv_rows"
     assert all((own in a) == (a["active"] > 0) for a in steps)
@@ -236,30 +239,34 @@ def test_train_steps_compile_spans_say_which_reduction_ran(mesh_cfg, n, exchange
             mesh_cfg.get("fsdp", 1), mesh_cfg.get("tp", 1), exchanges), a
 
 
-def test_span_sits_on_the_profilers_host_plane(tmp_path):
+@pytest.mark.parametrize("name", ["engine.step", "engine.between_steps"])
+def test_span_sits_on_the_profilers_host_plane(tmp_path, name):
     """One clock with the device trace: inside a profiler session the
-    program's spans are TraceAnnotations of the same `.xplane.pb`."""
+    program's spans are TraceAnnotations of the same `.xplane.pb`; the two
+    that tile the driver thread's life are `tracing.span()` blocks for that."""
     import glob
 
+    eng = ContinuousBatchingEngine(PARAMS, CFG, num_slots=2, max_len=64)
     jax.profiler.start_trace(str(tmp_path))
     try:
-        with tracing.span("engine.test_annotation", "engine"):
-            jnp.ones((8, 8)).block_until_ready()
+        eng.start_driver()
+        assert len(list(eng.generate_stream([1, 2, 3], max_new_tokens=3))) == 3
+        eng.stop_driver()
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
     data = jax.profiler.ProfileData.from_file(path)
     names = {e.name for p in data.planes if p.name.startswith("/host:")
              for line in p.lines for e in line.events}
-    assert "engine.test_annotation" in names
+    assert name in names
 
 
 def test_a_drafting_steps_spans_say_what_it_proposed_kept_and_yielded():
     """`engine.step` of a configuration with a prediction module: the
     device's `draft_proposed` (a draft for every busy slot of the step reaped
     in that span), `draft_accepted`, the expert-layer counters and, from the
-    host at dispatch, `latent_rows` beside `window_rows` and
-    `written_slots`; `tokens_out` on EVERY step, and over the run it is
+    host at dispatch, `latent_rows` (of `num_slots x attn_len`) beside
+    `active`; `tokens_out` on EVERY step, and over the run it is
     exactly the tokens the requests received; `engine.prefill` carries the
     latent layers, the module's with them."""
     from ray_tpu.models import hybrid
@@ -278,11 +285,11 @@ def test_a_drafting_steps_spans_say_what_it_proposed_kept_and_yielded():
     assert all("tokens_out" in a for a in steps)
     assert sum(a["tokens_out"] for a in steps) == sum(asked)
     dispatched = [a for a in steps if a["active"]]
-    for key in ("latent_rows", "window_rows", "written_slots", "state_slots"):
+    for key in ("latent_rows", "attn_len", "active", "state_slots"):
         assert all(key in a for a in dispatched), key
-    assert all(a["window_rows"] == 4 * 64 for a in dispatched)
-    assert all(0 < a["latent_rows"] <= a["window_rows"] for a in dispatched)
-    assert all(a["written_slots"] == a["active"] for a in dispatched)
+    assert all(a["attn_len"] == 64 for a in dispatched)
+    assert all(0 < a["latent_rows"] <= 4 * a["attn_len"] for a in dispatched)
+    assert all(a["latent_rows"] >= a["active"] for a in dispatched)
     reaped = [a for a in steps if "draft_proposed" in a]
     for key in ("draft_accepted", "expert_assignments", "experts_touched"):
         assert all(key in a for a in reaped), key
@@ -361,14 +368,192 @@ def test_named_scopes_of_the_drafting_model_in_all_call_modes():
         params, cache.state, i4, i4, jax.ShapeDtypeStruct((4,), jnp.bool_), cfg, 64))
 
 
-def test_one_trace_from_http_ingress_to_last_token(ray_start_regular):
-    """Default config, no switch: one trace_id links ingress:: -> route::
-    -> submit:: -> task::handle_request -> engine.queue/prefill/decode,
-    parent links resolve, and an incoming X-Request-Id IS the trace id. The
-    stream method's body runs lazily on the worker thread: that `submit()`
-    still sees the request's context is what this test is for."""
+
+def _driver_spans():
+    """The driver thread's `engine.step` and `engine.between_steps`, in
+    time order (one engine a test: one driver thread)."""
+    spans = [e for e in _engine_spans()
+             if e["name"] in ("engine.step", "engine.between_steps")]
+    assert len({e["tid"] for e in spans}) == 1
+    return sorted(spans, key=lambda e: e["ts"])
+
+
+def test_step_and_between_steps_tile_the_driver_thread():
+    """Every `engine.between_steps` starts where the previous `engine.step`
+    of the thread ended and ends where the next starts: no holes, no
+    overlap; `slept_us` is the part of it with nothing to do."""
+    eng = ContinuousBatchingEngine(PARAMS, CFG, num_slots=4, max_len=64)
+    eng.start_driver()
+    t_start = tracing.now_us()
+    rids = [eng.submit([i + 1, 2, 3], max_new_tokens=n)
+            for i, n in enumerate((6, 3, 9))]
+    for rid in rids:
+        eng.wait(rid, timeout=120)
+    eng.stop_driver()
+    t_stop = tracing.now_us()
+    spans = _driver_spans()
+    names = [e["name"] for e in spans]
+    assert names[0] == names[-1] == "engine.between_steps"
+    # strictly alternating: between, step, between, ..., between
+    assert names[0::2] == ["engine.between_steps"] * len(names[0::2])
+    assert names[1::2] == ["engine.step"] * len(names[1::2]) and names[1::2]
+    for a, b in zip(spans, spans[1:]):
+        hole = b["ts"] - (a["ts"] + a["dur"])
+        assert -1.0 <= hole < 5e3, (hole, a["name"], b["name"])
+    for g in spans[0::2]:
+        assert 0 <= g["args"]["slept_us"] <= g["dur"], g
+        assert isinstance(g["args"]["had_work"], bool)
+    # the two tile the thread's life, from its start to its stop
+    covered = sum(e["dur"] for e in spans)
+    assert 0.9 * covered <= t_stop - t_start
+    assert covered >= 0.9 * (spans[-1]["ts"] + spans[-1]["dur"] - spans[0]["ts"])
+    # stepping by hand records none
+    tracing.clear()
+    eng.generate([1, 2, 3], max_new_tokens=3)
+    assert not [e for e in _engine_spans() if e["name"] == "engine.between_steps"]
+
+
+def test_an_idle_engines_between_steps_is_all_sleep():
+    """With nothing to do the driver sleeps in `_cv.wait`: `slept_us` is
+    within a few ms of the span (on a machine that runs other tests beside
+    this one a woken thread may wait tens of ms for a processor: the best of
+    the idle gaps is held to 5 ms, each to 100), `had_work` is False, and
+    the work that ends the sleep is not counted as a cost of the gap."""
+    eng = ContinuousBatchingEngine(PARAMS, CFG, num_slots=2, max_len=64)
+    eng.start_driver()
+    time.sleep(0.5)
+    eng.generate([1, 2, 3], max_new_tokens=2)
+    time.sleep(0.5)
+    eng.stop_driver()
+    gaps = [e for e in _driver_spans() if e["name"] == "engine.between_steps"]
+    idle = [g for g in gaps if g["args"]["slept_us"] > 200e3]
+    assert len(idle) == 2, [(g["dur"], g["args"]) for g in gaps]
+    awake = [g["dur"] - g["args"]["slept_us"] for g in idle]
+    assert all(g["args"]["had_work"] is False for g in idle)
+    assert all(0 <= a <= 100e3 for a in awake) and min(awake) <= 5e3, awake
+
+
+def test_step_spans_count_the_wait_for_the_lock_and_the_bookkeeping():
+    """`lock_wait_us`: the summed wait to ENTER `_lock` over the step's
+    acquisitions; `bookkeep_us`: the time HOLDING it while tokens are handed
+    to the requests. A thread that holds `_lock` for 20 ms across a step
+    shows up in the first, whole."""
+    eng = ContinuousBatchingEngine(PARAMS, CFG, num_slots=2, max_len=64)
+    eng.submit([1, 2, 3], max_new_tokens=4)
+    eng.step()
+    eng.step()
+    holding, calling = threading.Event(), threading.Event()
+
+    def hold():
+        with eng._lock:
+            holding.set()
+            calling.wait(30)
+            time.sleep(0.02)
+
+    t = threading.Thread(target=hold)
+    t.start()
+    assert holding.wait(30)
+    tracing.clear()
+    calling.set()
+    eng.step()
+    t.join()
+    eng.run_until_done()
+    held, *rest = [e for e in _engine_spans() if e["name"] == "engine.step"]
+    assert held["args"]["lock_wait_us"] >= 19_000
+    assert rest and all(s["args"]["lock_wait_us"] < 19_000 for s in rest)
+    for s in [held] + rest:
+        a = s["args"]
+        assert 0 <= a["lock_wait_us"] <= s["dur"] + 1
+        assert 0 <= a["bookkeep_us"] <= s["dur"] + 1
+    # handing tokens out takes time: some step that reaped any counted it
+    assert any(s["args"]["bookkeep_us"] > 0 for s in rest
+               if s["args"]["tokens_out"])
+
+
+def test_one_stream_span_per_streamed_request_from_the_consumers_thread():
+    """`engine.stream`: emitted by the thread that consumes the generator,
+    when it ends, under that thread's trace context; what it counted adds up
+    with what the generator yielded. A generator closed early emits it too."""
+    eng = ContinuousBatchingEngine(PARAMS, CFG, num_slots=2, max_len=64)
+    eng.start_driver()
+    asked, got = {0: 7, 1: 3, 2: 5}, {}
+
+    def consume(i):
+        ctx = tracing.start_trace()
+        toks = list(eng.generate_stream([i + 1, 2, 3], max_new_tokens=asked[i]))
+        got[i] = (ctx[0], threading.get_ident() % 100000, len(toks))
+
+    threads = [threading.Thread(target=consume, args=(i,)) for i in asked]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+    early = eng.generate_stream([9, 2, 3], max_new_tokens=20)
+    assert len([next(early), next(early)]) == 2
+    early.close()
+    eng.stop_driver()
+    streams = [e for e in _engine_spans() if e["name"] == "engine.stream"]
+    assert len(streams) == 4
+    by_trace = {e["trace_id"]: e for e in streams if "trace_id" in e}
+    assert len(by_trace) == 3
+    for i, (trace_id, tid, n) in got.items():
+        e = by_trace[trace_id]
+        a = e["args"]
+        assert n == asked[i] == a["tokens"] and e["tid"] == tid
+        # a batch's lag is under the span's length, and there is at most
+        # one batch a token
+        assert 1 <= a["wakes"] and 0 <= a["deliver_lag_us_sum"] <= n * e["dur"]
+        assert 0 <= a["lock_us_sum"] <= e["dur"]
+        # it outlasts the engine's own three stages of the same request
+        decode = next(d for d in _engine_spans() if d["name"] == "engine.decode"
+                      and d["args"]["request_id"] == a["request_id"])
+        assert e["ts"] <= decode["ts"] and \
+            e["ts"] + e["dur"] >= decode["ts"] + decode["dur"] - 1.0
+    (closed,) = [e for e in streams if "trace_id" not in e]
+    assert closed["args"]["tokens"] == 2 and closed["args"]["wakes"] >= 1
+
+
+def _stream_over_http(port, n, rid=None):
+    """POST one streamed request of `n` tokens; (its X-Request-Id, lines)."""
     import http.client
 
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+    headers = {"Content-Type": "application/json"}
+    if rid:
+        headers["X-Request-Id"] = rid
+    conn.request("POST", "/LLMDeployment/stream?stream=1",
+                 body=json.dumps({"prompt": [5, 17, 400, 3],
+                                  "max_new_tokens": n}), headers=headers)
+    resp = conn.getresponse()
+    assert resp.status == 200
+    got = resp.getheader("X-Request-Id")
+    lines = [ln for ln in resp.read().splitlines() if ln]
+    conn.close()
+    return got, lines
+
+
+def _traces_once_they_hold(trace_ids, want, settled=lambda traces: True):
+    """{trace_id: spans} from the GCS once every trace holds `want`."""
+    traces = {}
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline:
+        traces = timeline.group_by_trace(
+            e for e in ray_tpu.timeline() if e.get("trace_id") in trace_ids)
+        if all(want <= {s["name"] for s in traces.get(t, [])}
+               for t in trace_ids) and settled(traces):
+            break
+        time.sleep(0.3)
+    return traces
+
+
+def test_one_trace_from_http_ingress_to_last_token(ray_start_regular):
+    """Default config, no switch: one trace_id links ingress:: -> route::
+    -> submit:: -> task::handle_request -> stream::handle_request ->
+    engine.queue/prefill/decode/stream -> relay::, parent links resolve, and
+    an incoming X-Request-Id IS the trace id. The stream method's body runs
+    lazily on the worker thread: that `submit()` still sees the request's
+    context is what this test is for. The three spans of the token's way out
+    agree on how many tokens went by."""
     from ray_tpu import serve
 
     assert not tracing.enabled()
@@ -379,34 +564,17 @@ def test_one_trace_from_http_ingress_to_last_token(ray_start_regular):
         rids = ["req-0001.a_b", None]
         got_ids = []
         for rid in rids:
-            conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
-            headers = {"Content-Type": "application/json"}
-            if rid:
-                headers["X-Request-Id"] = rid
-            conn.request("POST", "/LLMDeployment/stream?stream=1",
-                         body=json.dumps({"prompt": [5, 17, 400, 3],
-                                          "max_new_tokens": 5}),
-                         headers=headers)
-            resp = conn.getresponse()
-            assert resp.status == 200
-            got_ids.append(resp.getheader("X-Request-Id"))
-            assert len([ln for ln in resp.read().splitlines() if ln]) == 5
-            conn.close()
+            got, lines = _stream_over_http(port, 5, rid)
+            got_ids.append(got)
+            assert len(lines) == 5
         assert got_ids[0] == rids[0] and re.fullmatch(r"[0-9a-f]{16}",
                                                       got_ids[1])
 
         want = {"ingress::LLMDeployment", "route::LLMDeployment",
                 "submit::handle_request", "task::handle_request",
-                "result::handle_request", *STAGES}
-        traces = {}
-        deadline = time.monotonic() + 30
-        while time.monotonic() < deadline:
-            traces = timeline.group_by_trace(
-                e for e in ray_tpu.timeline() if e.get("trace_id") in got_ids)
-            if all(want <= {s["name"] for s in traces.get(t, [])}
-                   for t in got_ids):
-                break
-            time.sleep(0.3)
+                "result::handle_request", "stream::handle_request",
+                "relay::LLMDeployment", "engine.stream", *STAGES}
+        traces = _traces_once_they_hold(got_ids, want)
         for t in got_ids:
             spans = {s["name"]: s for s in traces[t]}
             assert want <= set(spans), (t, sorted(spans))
@@ -425,8 +593,67 @@ def test_one_trace_from_http_ingress_to_last_token(ray_start_regular):
                 "task::handle_request", *STAGES)]
             assert order == sorted(order), order
             assert spans["engine.decode"]["args"]["tokens"] == 4
+            # the token's way out: one span each at the consumer, the
+            # replica and the proxy, all five tokens through each
+            for name in ("stream::handle_request", "relay::LLMDeployment",
+                         "engine.stream"):
+                assert len([s for s in traces[t] if s["name"] == name]) == 1
+            consumer, replica, relay = (spans[n]["args"] for n in (
+                "engine.stream", "stream::handle_request",
+                "relay::LLMDeployment"))
+            assert consumer["tokens"] == replica["items"] == relay["items"] == 5
+            assert (spans["engine.stream"]["parent_id"]
+                    == spans["stream::handle_request"]["parent_id"]
+                    == spans["submit::handle_request"]["span_id"])
+            assert (spans["relay::LLMDeployment"]["parent_id"]
+                    == spans["ingress::LLMDeployment"]["span_id"])
+            assert (replica["task_id"]
+                    == spans["task::handle_request"]["args"]["task_id"])
+            # `task::` ended when the method returned its generator; the
+            # loop that ran it is the `stream::` span behind it
+            task, stream = spans["task::handle_request"], \
+                spans["stream::handle_request"]
+            assert task["ts"] + task["dur"] <= stream["ts"] + 1e3
+            assert 0 < replica["report_us_sum"] <= stream["dur"]
+            prefill = spans["engine.prefill"]
+            assert relay["first_write_ts"] >= prefill["ts"] + prefill["dur"] - 1e3
+            # every item's ref carried its arrival stamp: an item is fetched
+            # and written inside its lag, and lies there under the span's length
+            assert relay["fetch_us_sum"] + relay["write_us_sum"] - 5 \
+                <= relay["arrive_lag_us_sum"] \
+                <= 5 * spans["relay::LLMDeployment"]["dur"]
+            for key in ("fetch_us_sum", "write_us_sum"):
+                assert 0 < relay[key] <= spans["relay::LLMDeployment"]["dur"]
         assert timeline.validate_chains(
             [s for t in got_ids for s in traces[t]], got_ids)
+    finally:
+        serve.shutdown()
+
+
+def test_no_span_is_recorded_per_token(ray_start_regular):
+    """A 64-token stream leaves as many spans under its trace id as a
+    4-token one: the token's way out is counted, never spanned."""
+    from ray_tpu import serve
+
+    D = serve.deployment(LLMDeployment(PARAMS, CFG, num_slots=2, max_len=128))
+    try:
+        serve.run(D.bind())
+        _, port = serve.start_http_proxy()
+        _stream_over_http(port, 2)   # the first call's one-off spans
+        (short, a), (long, b) = (_stream_over_http(port, n) for n in (4, 64))
+        assert (len(a), len(b)) == (4, 64)
+        want = {"ingress::LLMDeployment", "result::handle_request",
+                "relay::LLMDeployment", "stream::handle_request",
+                "engine.stream", *STAGES}
+        traces = _traces_once_they_hold(
+            [short, long], want,
+            lambda tr: len(tr[short]) == len(tr[long]))
+        names = {t: sorted(s["name"] for s in traces[t]) for t in (short, long)}
+        assert names[short] == names[long], names
+        relay = {t: next(s["args"] for s in traces[t]
+                         if s["name"] == "relay::LLMDeployment")
+                 for t in (short, long)}
+        assert (relay[short]["items"], relay[long]["items"]) == (4, 64)
     finally:
         serve.shutdown()
 
