@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from ray_tpu.ops.attention import (attention, attention_sharded,
                                    uses_flash_kernel)
 from ray_tpu.ops.layers import apply_rotary, rms_norm, rotary_embedding, swiglu
-from ray_tpu.parallel import fsdp
+from ray_tpu.parallel import fsdp, tp
 from ray_tpu.parallel.mesh import DEFAULT_RULES
 
 
@@ -218,15 +218,19 @@ def _embed_lookup(emb: Any, tokens: jax.Array, dtype) -> jax.Array:
     return emb[tokens].astype(dtype)
 
 
-def _project_qkv(cfg: ModelConfig, p, x, cos, sin):
+def _project_qkv(cfg: ModelConfig, p, x, cos, sin, rows_mesh=None):
     """rmsnorm(x) -> q [b, s, heads, hd], k, v [b, s, kv_heads, hd], q and k
     rotated. The one spelling of the block's projections: training, prefill,
     the reference decode and the engine's decode step call it; what they do
-    with q, k and v (the mixer) is their own."""
+    with q, k and v (the mixer) is their own. `rows_mesh`: the mesh over
+    whose `tp` axis x's rows ride sharded (`_rows_mesh`), else None."""
     b, s, _ = x.shape
     hd = cfg.head_dim
     h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-    q, k, v = h @ p["wq"], h @ p["wk"], h @ p["wv"]
+    if rows_mesh is None:
+        q, k, v = h @ p["wq"], h @ p["wk"], h @ p["wv"]
+    else:  # one gather of the rows serves the three products
+        q, k, v = tp.gather_matmul(h, (p["wq"], p["wk"], p["wv"]), rows_mesh)
     q = q.reshape(b, s, cfg.n_heads, hd)
     k = k.reshape(b, s, cfg.n_kv_heads, hd)
     v = v.reshape(b, s, cfg.n_kv_heads, hd)
@@ -235,23 +239,32 @@ def _project_qkv(cfg: ModelConfig, p, x, cos, sin):
     return q, k, v
 
 
-def _mlp(cfg: ModelConfig, p, x):
+def _mlp(cfg: ModelConfig, p, x, rows_mesh=None):
     """rmsnorm(x) -> the FFN's output (no residual) and the experts'
-    auxiliary loss (None for the dense FFN; only training reads it)."""
+    auxiliary loss (None for the dense FFN; only training reads it).
+    `rows_mesh` as in `_project_qkv`."""
     h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     if cfg.n_experts > 0:
         from ray_tpu.ops.moe import moe_ffn
 
         return moe_ffn(h, p["router"], p["w_gate"], p["w_up"], p["w_down"],
                        cfg.capacity_factor)
-    return swiglu(h @ p["w_gate"], h @ p["w_up"]) @ p["w_down"], None
+    if rows_mesh is None:
+        return swiglu(h @ p["w_gate"], h @ p["w_up"]) @ p["w_down"], None
+    # nothing between the gather and the scatter looks across rows: the
+    # whole sequence stays a tuple of chunks, in each rank's own order
+    gate, up = tp.gather_matmul(h, (p["w_gate"], p["w_up"]), rows_mesh,
+                                chunks=True)
+    return tp.matmul_scatter(tuple(map(swiglu, gate, up)), p["w_down"],
+                             rows_mesh), None
 
 
 def _attn_half(cfg: ModelConfig, mesh, x, p, cos, sin):
     """Attention sub-block: x + Wo(attn(rotary(qkv(rmsnorm(x)))))."""
     p = _deq_tree(p, cfg.dtype)
     b, s, _ = x.shape
-    q, k, v = _project_qkv(cfg, p, x, cos, sin)
+    rows_mesh = _rows_mesh(cfg, mesh, b, s)
+    q, k, v = _project_qkv(cfg, p, x, cos, sin, rows_mesh)
     # [b, heads, s, hd]
     q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
     if cfg.seq_parallel == "ring":
@@ -275,13 +288,16 @@ def _attn_half(cfg: ModelConfig, mesh, x, p, cos, sin):
     else:
         attn = attention(q, k, v, causal=True)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.head_dim)
-    return x + (attn @ p["wo"]).astype(x.dtype)
+    out = (attn @ p["wo"] if rows_mesh is None
+           else tp.matmul_scatter(attn, p["wo"], rows_mesh))
+    return x + out.astype(x.dtype)
 
 
 def _layer(cfg: ModelConfig, mesh, x, layer_params, cos, sin):
     """One transformer block. x: [b, s, d] (s possibly sp-sharded)."""
     x = _attn_half(cfg, mesh, x, layer_params, cos, sin)
-    out, aux = _mlp(cfg, _deq_tree(layer_params, cfg.dtype), x)
+    out, aux = _mlp(cfg, _deq_tree(layer_params, cfg.dtype), x,
+                    _rows_mesh(cfg, mesh, *x.shape[:2]))
     return (x + out.astype(x.dtype),
             jnp.zeros((), jnp.float32) if aux is None else aux)
 
@@ -303,6 +319,31 @@ def _exchanged_dims(cfg: ModelConfig, mesh, batch: int) -> Dict[str, int]:
     return {k: d - 1 for k, d in dims.items() if d is not None}
 
 
+def _rows_mesh(cfg: ModelConfig, mesh, batch: int, seq: int):
+    """The mesh, where the residual stream's rows ride sharded over its `tp`
+    axis between the block's products and each product carries its gather
+    or scatter as ring permutes behind it (parallel/tp.py); else None, and
+    the `tp` reductions are the partitioner's all-reduces: without a mesh or
+    with `tp` 1, where `tp` does not divide the sequence, and wherever the
+    layer's weights do not come exchanged (`_exchanged_dims`: fsdp 1, the
+    expert layer, the sequence-parallel schemes, the fused blocks). The
+    only form read on the chip is the one whose products carry the weights'
+    shards round fsdp's ring as well; the rows over `tp` alone read no
+    faster than the partitioner's program (PERF.md section 6, PR 38)."""
+    if (tp.axis_size(mesh) == 1 or seq % tp.axis_size(mesh)
+            or not _exchanged_dims(cfg, mesh, batch)):
+        return None
+    return mesh
+
+
+def tp_exchanges_per_layer(cfg: ModelConfig, mesh, batch: int, seq: int) -> int:
+    """How many gathers and scatters over `tp` a layer's forward moves as
+    ring permutes behind its products: 4 (before qkv, behind `wo`, before
+    gate | up, behind `w_down`) where `_rows_mesh` says so, else 0 (the
+    train step's `xla.compile` spans carry it)."""
+    return 0 if _rows_mesh(cfg, mesh, batch, seq) is None else 4
+
+
 def grad_exchanges_per_layer(cfg: ModelConfig, mesh, batch: int) -> int:
     """How many of a layer's weight gradients this program exchanges over
     `fsdp` itself: 7 for the dense block on a mesh with fsdp > 1, else 0
@@ -318,9 +359,11 @@ def maybe_remat(layer_fn, cfg: ModelConfig):
     if cfg.remat == "full":
         return jax.checkpoint(layer_fn)
     if cfg.remat == "dots":
-        return jax.checkpoint(
-            layer_fn,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        # (a product of parallel/tp.py is a matmul's result under its name)
+        keep = jax.checkpoint_policies
+        return jax.checkpoint(layer_fn, policy=keep.save_from_both_policies(
+            keep.dots_with_no_batch_dims_saveable,
+            keep.save_only_these_names(tp.SAVED)))
     if cfg.remat != "none":
         raise ValueError(f"unknown remat mode {cfg.remat!r}")
     return layer_fn
@@ -345,12 +388,16 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
     `mesh` is required when a sequence-parallel scheme is active
     (`cfg.seq_parallel`: the sp shard_map needs it); everything else is
     pure sharding-annotation-driven SPMD, but for the sum of the dense
-    block's weight gradients over `fsdp` (`_exchanged_dims`), which takes
-    the mesh too and without it is the partitioner's.
+    block's weight gradients over `fsdp` (`_exchanged_dims`) and its gathers
+    and scatters over `tp` (`_rows_mesh`), which take the mesh too and
+    without it are the partitioner's.
     """
     if positions is None:
         positions = jnp.arange(tokens.shape[1])
     x = _embed_lookup(params["embed"], tokens, cfg.dtype)  # gather: [b, s, d]
+    rows_mesh = _rows_mesh(cfg, mesh, *tokens.shape)
+    if rows_mesh is not None:
+        x = tp.shard_rows(x, rows_mesh)
     cos, sin = rotary_embedding(positions, cfg.head_dim, cfg.rope_theta)
     cos, sin = cos[None], sin[None]  # add batch dim
 
@@ -395,6 +442,8 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
     (x, aux_total), _ = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    if rows_mesh is not None:
+        x = tp.whole_rows(x, rows_mesh)  # the head's program is the partitioner's
     return x, aux_total
 
 

@@ -11,11 +11,20 @@ matmuls between (PERF.md section 6, PR 31: 397 -> 338 ms a step).
 So a layer's weights go into the block as `ExchangedWeight`s. `x @ w` is
 then the same product, gathered and partitioned by the compiler as before
 (forward and `dx` are the partitioner's program, untouched); only its `dw`
-is ours. A `shard_map` manual over `fsdp` alone (`tp`, `dp` and the rest
-stay the partitioner's) makes every rank's partial `dw`, of its own rows of
-the batch, one shard-sized chunk at a time, and sums the chunks around a
-ring of n - 1 permutes, each rank ending with its own shard. Sums are in
-the gradient's dtype over the same ranks as the partitioner's.
+is ours. On a mesh with `tp` > 1 as well the products themselves are
+`parallel/tp.py`'s, which reads `w` and `dim` off the same object, calls
+`weight_grad` for the `dw` and `ring_products` for the products: the
+weight's shards go round the same ring INSIDE the product, a rank
+multiplying by its own shard while its neighbour's travels (the partitioner
+gathers one weight at a time, each where the one before is first used).
+
+A `shard_map` manual over `fsdp` alone (`tp`, `dp` and the rest stay the
+partitioner's) makes every rank's partial `dw`, of its own rows of the
+batch, one shard-sized chunk at a time, and sums the chunks around a ring of
+n - 1 permutes, each rank ending with its own shard. Those sums are in the
+gradient's dtype over the same ranks as the partitioner's. A product's sum
+over a dimension that `fsdp` shards is taken whole in float32 and rounded
+once, as the product by the gathered weight is (`ring_products`).
 """
 
 from __future__ import annotations
@@ -69,19 +78,113 @@ def _matmul_fwd(x, w, dim, mesh):
 def _matmul_bwd(dim, mesh, res, dy):
     x, w = res
     dx = jax.lax.dot_general(dy, w, (((dy.ndim - 1,), (1,)), ((), ())))
-    # [dp, fsdp, rows, width]: each rank's own rows of the batch
-    by_rank = lambda a: a.reshape(*batch_split(mesh), -1, a.shape[-1])
-    dw = jax.shard_map(
-        lambda x, dy: _reduce_scatter_dw(x[:, 0], dy[:, 0], dim), mesh=mesh,
-        axis_names={AXIS}, in_specs=(P(None, AXIS), P(None, AXIS)),
-        out_specs=P(*[None] * (1 + dim), AXIS))(by_rank(x), by_rank(dy))
-    return dx, dw.sum(0).astype(w.dtype)
+    return dx, weight_grad(x, dy, dim, mesh).astype(w.dtype)
+
+
+def weight_grad(x: jax.Array, dy: jax.Array, dim: int, mesh) -> jax.Array:
+    """dw [k, n] of `x @ w` (x [batch, ..., k], dy [batch, ..., n], batch
+    split over (dp, fsdp)), summed over `fsdp` by the ring below and left
+    sharded over it on `dim`. Also called from inside `parallel/tp.py`'s
+    products (`_region`)."""
+    dw = _region(
+        lambda x, dy, r: _reduce_scatter_dw(x[:, 0], dy[:, 0], dim, r[0]),
+        mesh, (P(None, AXIS), P(None, AXIS), P(AXIS)),
+        P(*[None] * (1 + dim), AXIS))(
+            _by_rank(x, mesh), _by_rank(dy, mesh), jnp.arange(axis_size(mesh)))
+    return dw.sum(0)
 
 
 _matmul.defvjp(_matmul_fwd, _matmul_bwd)
 
 
-def _reduce_scatter_dw(x: jax.Array, dy: jax.Array, dim: int) -> jax.Array:
+def _ring(n: int) -> list:
+    """The permute's pairs: every rank hands on to the next."""
+    return [(i, (i + 1) % n) for i in range(n)]
+
+
+def _by_rank(a: jax.Array, mesh) -> jax.Array:
+    """[batch, ..., width] -> [dp, fsdp, rows, width]: each rank's own rows."""
+    return a.reshape(*batch_split(mesh), -1, a.shape[-1])
+
+
+def _region(body, mesh, in_specs, out_specs):
+    """`body` manual over `fsdp` alone; inside one of parallel/tp.py's
+    products (manual over `tp` already) the region nests in theirs, on the
+    mesh of the context. A rank's index comes in as data there: `axis_index`
+    does not lower in a region that nests in another. (No result is the same
+    on every rank and nothing differentiates through a region, so the check
+    of what varies over `fsdp` has nothing to find; it costs a sixth of the
+    four-chip step's time to trace and lower, and compiles to the same text.)"""
+    nested = not jax.sharding.get_abstract_mesh().empty
+    return jax.shard_map(body, mesh=None if nested else mesh, axis_names={AXIS},
+                         in_specs=in_specs, out_specs=out_specs, check_vma=False)
+
+
+def ring_products(groups, ws, dim: int, transposed: bool, mesh) -> list:
+    """For each group of operands (one a weight, [batch, ..., width], batch
+    split over (dp, fsdp)): the sum over the weights of operand @ w, or of
+    operand @ w^T with `transposed`, for weights [k, n] sharded over `fsdp`
+    on `dim`, their shards going round the ring while the products run: a
+    rank multiplies by its own shards first, its neighbour's travelling
+    behind that, then by each as it arrives; one round serves every group.
+    A shard that cuts the output yields its own columns of it. A shard that
+    cuts the dimension a product sums over yields a partial sum, of the
+    operand's matching columns: the partials are taken and added in float32
+    and rounded once, as the one product by the gathered weight is.
+
+    For the FIRST product of a layer's backward above all: the partitioner's
+    gather of a weight can start only inside an earlier product of the same
+    loop body, so there it runs alone on the compute stream (29 MB for
+    `w_down` at Mistral-7B widths)."""
+    n = axis_size(mesh)
+    summed = 1 if transposed else 0   # the weight's dimension a product sums over
+    ring = _ring(n)
+
+    def body(groups, ws, r):
+        groups, r = [[x[:, 0] for x in group] for group in groups], r[0]
+        size = ws[0].shape[dim]
+        outs = [None] * len(groups)
+        for j in range(n):
+            arriving = ([jax.lax.ppermute(w, AXIS, ring) for w in ws]
+                        if j < n - 1 else None)
+            at = ((r - j) % n) * size
+            for t, group in enumerate(groups):
+                if dim == summed:
+                    parts = [_dot(jax.lax.dynamic_slice_in_dim(x, at, size, 2),
+                                  w, summed, jnp.float32)
+                             for x, w in zip(group, ws)]
+                    outs[t] = parts if j == 0 else [
+                        acc + part for acc, part in zip(outs[t], parts)]
+                else:
+                    part = sum(_dot(x, w, summed) for x, w in zip(group, ws))
+                    if j == 0:
+                        outs[t] = jnp.zeros((*part.shape[:2], n * size), part.dtype)
+                    outs[t] = jax.lax.dynamic_update_slice_in_dim(
+                        outs[t], part, at, 2)
+            ws = arriving
+        if dim == summed:
+            outs = [sum(acc.astype(group[0].dtype) for acc in accs)
+                    for accs, group in zip(outs, groups)]
+        return [out[:, None] for out in outs]
+
+    rows = P(None, AXIS)
+    outs = _region(
+        body, mesh, ([[rows] * len(ws)] * len(groups),
+                     [P(*[None] * dim, AXIS)] * len(ws), P(AXIS)),
+        [rows] * len(groups))(
+            [[_by_rank(x, mesh) for x in group] for group in groups], list(ws),
+            jnp.arange(n))
+    return [out.reshape(*group[0].shape[:-1], out.shape[-1])
+            for out, group in zip(outs, groups)]
+
+
+def _dot(x: jax.Array, w: jax.Array, summed: int, dtype=None) -> jax.Array:
+    """x [dp, rows, width] times w, summing over w's dimension `summed`."""
+    return jax.lax.dot_general(x, w, (((2,), (summed,)), ((), ())),
+                               preferred_element_type=dtype)
+
+
+def _reduce_scatter_dw(x: jax.Array, dy: jax.Array, dim: int, r) -> jax.Array:
     """Sum over `fsdp` of dw = x^T dy (x [dp, rows, k], dy [dp, rows, n],
     this rank's rows), rank r keeping chunk r of dw's dimension `dim`: a
     ring of n - 1 steps. In step t rank r hands the running sum of chunk
@@ -93,10 +196,9 @@ def _reduce_scatter_dw(x: jax.Array, dy: jax.Array, dim: int) -> jax.Array:
     keeps. (A whole dw cut in two read 374 ms a step, and a sum kept out of
     the product 358, against 338 this way.)"""
     n = jax.lax.axis_size(AXIS)
-    r = jax.lax.axis_index(AXIS)
     cut = (x, dy)[dim]
     size = cut.shape[-1] // n
-    ring = [(i, (i + 1) % n) for i in range(n)]
+    ring = _ring(n)
 
     def chunk(j):
         part = jax.lax.dynamic_slice_in_dim(cut, (j % n) * size, size, 2)

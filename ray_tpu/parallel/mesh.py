@@ -15,9 +15,14 @@ Collectives (`psum`, `all_gather`, `ppermute`, `reduce_scatter`) are then
 emitted by XLA from sharding annotations — no collective library calls in
 user code. Parameters/activations carry *logical* axis names which
 `AxisRules` maps to mesh axes (the flax `logical_axis_rules` idea, re-built
-standalone). The one collective the program writes out is in the module
-beside this one, `parallel/fsdp.py`: the weight gradients' sum over `fsdp`,
-which the TPU compiler overlaps with compute only as explicit permutes.
+standalone). Two collectives the program writes out itself, in the modules
+beside this one, because the TPU compiler overlaps them with compute only
+as explicit permutes: `parallel/fsdp.py`, the weight gradients' sum over
+`fsdp`, and, where `tp` > 1 as well, `parallel/tp.py`, the dense block's
+gathers and scatters over `tp` (the residual stream rides sequence-sharded
+between the products; the partitioner's form is four blocking all-reduces a
+layer), with the weights' shards going round `fsdp`'s ring inside those
+products.
 """
 
 from __future__ import annotations

@@ -6,12 +6,13 @@ TPU-native step is one jitted function whose parallelism is entirely in the
 in/out shardings: dp×fsdp shard the batch, fsdp shards parameters ZeRO-3
 style (XLA inserts the all-gathers), tp shards heads/mlp, sp runs ring
 attention. No collective calls appear below — the compiler emits them over
-ICI/DCN from the sharding annotations. One reduction the model spells
-itself: with fsdp > 1 the dense block's weight gradients are summed over
-fsdp by `parallel/fsdp.py` (permutes that run behind the backward's
-matmuls; the partitioner's all-reduce-and-slice blocks the compute stream).
-The step's `xla.compile` spans say which form it has
-(`grad_exchanges_per_layer`).
+ICI/DCN from the sharding annotations. Two the model spells itself, as
+permutes that run behind matmuls where the partitioner's all-reduce blocks
+the compute stream: with fsdp > 1 the dense block's weight gradients are
+summed over fsdp by `parallel/fsdp.py`, and with tp > 1 its gathers and
+scatters over tp ride inside the products (`parallel/tp.py`). The step's
+`xla.compile` spans say which forms it has (`grad_exchanges_per_layer`,
+`tp_exchanges_per_layer`).
 """
 
 from __future__ import annotations
@@ -32,6 +33,7 @@ from ray_tpu.models.transformer import (
     loss_fn,
     param_logical_axes,
     split_batch,
+    tp_exchanges_per_layer,
 )
 from ray_tpu.parallel.mesh import AxisRules, DEFAULT_RULES, logical_sharding
 
@@ -150,12 +152,16 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh,
     b_sh = batch_sharding(mesh)
 
     def step(state: TrainState, batch: Dict[str, jax.Array]):
-        # which form of the gradients' reduction over fsdp this program has
-        # is a fact of its compile: on its `xla.compile` spans
+        # which form of the gradients' reduction over fsdp and of the block's
+        # reductions over tp this program has is a fact of its compile: on
+        # its `xla.compile` spans
+        inputs = split_batch(batch)[0]
         tracing.note_compile(
             "step", fsdp=mesh.shape.get("fsdp", 1), tp=mesh.shape.get("tp", 1),
             grad_exchanges_per_layer=grad_exchanges_per_layer(
-                cfg, mesh, split_batch(batch)[0].shape[0]))
+                cfg, mesh, inputs.shape[0]),
+            tp_exchanges_per_layer=tp_exchanges_per_layer(
+                cfg, mesh, *inputs.shape))
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, batch, cfg, mesh)
         # (the phases before this one are named in models/transformer.py:

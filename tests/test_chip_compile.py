@@ -604,7 +604,44 @@ def _is_matmul(comps: dict, line: str) -> bool:
         for c in re.findall(r"calls=%([\w.\-]+)", body))
 
 
-def _assert_grad_exchange_runs_behind_the_backward(c, temp_limit=None):
+_FSDP_PAIRS = "{{0,2},{2,0},{1,3},{3,1}}"   # fsdp 2 x tp 2: the ranks that share tp
+_TP_PAIRS = "{{0,1},{1,0},{2,3},{3,2}}"     # ... and those that share fsdp
+
+
+def _scan_bodies(comps: dict) -> tuple:
+    """(forward, backward): the two `while` bodies of the layers' scan; the
+    backward's holds more exchanges (its own and the weight gradients')."""
+    bodies = [lines for lines in comps.values()
+              if sum(" collective-permute-start(" in l for l in lines) >= 4]
+    assert len(bodies) == 2, len(bodies)
+    return tuple(sorted(bodies, key=lambda lines: sum(
+        " collective-permute-start(" in l for l in lines)))
+
+
+def _permutes(comps: dict, body: list, pairs: str) -> dict:
+    """instruction name -> (shard's dims, what is scheduled between the start
+    and its done that covers the transfer), for the body's permutes over
+    `pairs`: "matmul" (a matmul fusion), else "kernel" (a Mosaic kernel: the
+    attention forward that remat runs again), else None."""
+    out = {}
+    for i, l in enumerate(body):
+        if " collective-permute-start(" in l and pairs in l:
+            name = re.match(r"\s*%([\w.\-]+) =", l).group(1)
+            done = next(j for j, d in enumerate(body)
+                        if f"collective-permute-done(%{name})" in d)
+            between = body[i + 1:done]
+            cover = ("matmul" if any(_is_matmul(comps, m) for m in between)
+                     else "kernel" if any("tpu_custom_call" in m for m in between)
+                     else None)
+            out[name] = (re.search(r"= \(\w+\[([\d,]*)\]", l).group(1), cover)
+    return out
+
+
+_WEIGHT_SHARDS = sorted(["2048,512"] * 2 + ["2048,2048"] * 2 + ["2048,7168"] * 2
+                        + ["7168,2048"])  # wk wv | wq wo | w_gate w_up | w_down
+
+
+def _assert_grad_exchange_runs_behind_the_backward(comps):
     """The compiled fsdp 2 x tp 2 step at the cell's widths. In the backward
     `while` body: no `all-reduce-scatter` fusion (the partitioner's form of
     the weight gradients' reduction over fsdp: the whole [4096,7168]
@@ -614,47 +651,91 @@ def _assert_grad_exchange_runs_behind_the_backward(c, temp_limit=None):
     `collective-permute-start` ... `-done` pairs of exact shards
     ([1,2048,7168] x 2, [1,7168,2048], [1,2048,2048] x 2, [1,2048,512] x 2),
     each with a matmul fusion scheduled between its start and its done."""
-    comps = _computations(c.as_text())
-    bodies = [lines for lines in comps.values()
-              if sum(" collective-permute-start(" in l for l in lines) >= 7]
-    assert len(bodies) == 1  # the backward body; the forward's has none
-    body = bodies[0]
+    _, body = _scan_bodies(comps)
     assert not [l for l in body if "all-reduce-scatter" in l]
     for l in body:
         if " all-reduce(" in l and "{{0,2},{1,3}}" in l:
             dims = re.search(r"= \(?\w+\[([\d,]*)\]", l).group(1)
             assert math.prod(map(int, dims.split(","))) <= MISTRAL.d_model, l
-    starts = {re.match(r"\s*%([\w.\-]+) =", l).group(1): i
-              for i, l in enumerate(body) if " collective-permute-start(" in l}
-    assert len(starts) == 7
-    shards = sorted(re.search(r"= \(\w+\[([\d,]*)\]", body[i]).group(1)
-                    for i in starts.values())
-    assert shards == sorted(["1,2048,7168"] * 2 + ["1,7168,2048"]
-                            + ["1,2048,2048"] * 2 + ["1,2048,512"] * 2)
-    hidden = 0
-    for name, i in starts.items():
-        done = next(j for j, l in enumerate(body)
-                    if f"collective-permute-done(%{name})" in l)
-        hidden += any(_is_matmul(comps, l) for l in body[i + 1:done])
-    assert hidden == 7, hidden
-    # the forward keeps the partitioner's program: weights gathered inside
-    # the matmuls that use them, four tp all-reduces a layer in all
+    exchanges = [v for v in _permutes(comps, body, _FSDP_PAIRS).values()
+                 if v[0].count(",") == 2]
+    assert sorted(dims for dims, _ in exchanges) == sorted(
+        ["1,2048,7168"] * 2 + ["1,7168,2048"] + ["1,2048,2048"] * 2
+        + ["1,2048,512"] * 2)
+    assert all(cover == "matmul" for _, cover in exchanges), exchanges
+
+
+def _assert_weights_ride_the_fsdp_ring(comps):
+    """Since PR 38 the products of the tp route carry their weights' gathers
+    over fsdp too (`fsdp.ring_products`): each scan body sends the seven
+    weights' shards ([2048,512] x 2, [2048,2048] x 2, [2048,7168] x 2,
+    [7168,2048]) round the fsdp pairs as permutes, started at the head of the
+    body with matmul fusions between start and done (the backward's first,
+    `w_down`'s, behind the attention kernel that remat runs again), and NO
+    `all-gather` is left in either body: the partitioner gathered them one at
+    a time, each started where the one before was first used, and once the tp
+    all-reduces had left the compute stream the step waited for them."""
+    for body, first in zip(_scan_bodies(comps), ("matmul", "kernel")):
+        assert not [l for l in body if " all-gather(" in l]
+        shards = [v for v in _permutes(comps, body, _FSDP_PAIRS).values()
+                  if v[0].count(",") == 1]
+        assert sorted(dims for dims, _ in shards) == _WEIGHT_SHARDS, shards
+        covers = [cover for _, cover in shards]
+        assert covers.count("matmul") >= 6 and set(covers) <= {"matmul", first}
+
+
+def _assert_no_tp_all_reduce_in_the_layers(comps):
+    """Neither scan body is left a blocking `all-reduce` of the residual
+    [1,2048,4096] (the partitioner's Megatron form had two in each, over the
+    tp pairs, on the compute stream); the head's own, once a step outside
+    the scan, stay the partitioner's."""
+    for body in _scan_bodies(comps):
+        assert not [l for l in body
+                    if " all-reduce(" in l and "[1,2048,4096]" in l]
     assert sum(" all-reduce(" in l and "[1,2048,4096]" in l
-               for lines in comps.values() for l in lines) >= 4
-    if temp_limit:
-        assert c.memory_analysis().temp_size_in_bytes < temp_limit
+               for lines in comps.values() for l in lines) <= 2
 
 
-def test_grad_exchange_compiles_behind_the_backward_matmuls(topo, chip):
-    """Two layers of the 4-chip cell's step (ten seconds; the whole 22 are
-    the slow case below)."""
+def _assert_tp_exchanges_run_behind_the_products(comps):
+    """The residual stream's rows ride [1,1024,4096] a rank between the
+    products; every gather and scatter over the tp pairs is a
+    `collective-permute-start` ... `-done` of such a shard: four in the
+    forward body (before qkv, behind `wo`, before gate | up, behind
+    `w_down`), six in the backward's (their four transposes and the two
+    re-gathers of the normalised rows for `dw`; the forward's sums are kept
+    by name, not sent again). Each has a matmul fusion scheduled between its
+    start and its done, but the gather of dy at the backward body's head,
+    which travels behind the attention kernel that remat runs again."""
+    for body, n, other in zip(_scan_bodies(comps), (4, 6), ("matmul", "kernel")):
+        moved = _permutes(comps, body, _TP_PAIRS)
+        assert [dims for dims, _ in moved.values()] == ["1,1024,4096"] * n, moved
+        covers = [cover for _, cover in moved.values()]
+        assert covers.count("matmul") >= n - 1, moved
+        assert set(covers) <= {"matmul", other}, moved
+
+
+_CELL_STEP_ASSERTIONS = {
+    "grad_exchange_behind_the_backward": _assert_grad_exchange_runs_behind_the_backward,
+    "no_tp_all_reduce_in_the_layers": _assert_no_tp_all_reduce_in_the_layers,
+    "tp_exchanges_behind_the_products": _assert_tp_exchanges_run_behind_the_products,
+    "weights_ride_the_fsdp_ring": _assert_weights_ride_the_fsdp_ring,
+}
+_TWO_LAYERS = {}  # the compiled text of one compile, for the cases below
+
+
+@pytest.mark.parametrize("what", list(_CELL_STEP_ASSERTIONS))
+def test_four_chip_cell_step_exchanges_behind_matmuls(topo, chip, what):
+    """Two layers of the 4-chip cell's step (twenty seconds, compiled once
+    for the four cases; the whole 22 are the slow case below)."""
     from ray_tpu.train.step import default_optimizer
 
-    c = _lower_b1_step(topo, chips=4, mesh={"dp": 1, "fsdp": 2, "tp": 2},
-                       batch=2, seq=SEQ, optimizer=default_optimizer(),
-                       fused=False, cfg=dataclasses.replace(MISTRAL, n_layers=2)
-                       ).compile()
-    _assert_grad_exchange_runs_behind_the_backward(c)
+    if not _TWO_LAYERS:
+        c = _lower_b1_step(topo, chips=4, mesh={"dp": 1, "fsdp": 2, "tp": 2},
+                           batch=2, seq=SEQ, optimizer=default_optimizer(),
+                           fused=False, cfg=dataclasses.replace(MISTRAL, n_layers=2)
+                           ).compile()
+        _TWO_LAYERS["comps"] = _computations(c.as_text())
+    _CELL_STEP_ASSERTIONS[what](_TWO_LAYERS["comps"])
 
 
 @pytest.mark.slow  # ten more seconds of five cores: see the note below
@@ -674,7 +755,7 @@ def test_one_chip_step_has_no_collective(topo, chip):
 
 @pytest.mark.slow
 def test_four_chip_cell_step_compiles_and_fits(topo, chip):
-    """The whole `mistral7b-train-4chip` step: the same backward body, and
+    """The whole `mistral7b-train-4chip` step: the same two scan bodies, and
     `temp` within half a GB of the 7,835,362,816 B the partitioner's own
     reduction needed (PERF.md section 6, PR 30): the unreduced gradients in
     flight are one layer's."""
@@ -683,7 +764,10 @@ def test_four_chip_cell_step_compiles_and_fits(topo, chip):
     c = _lower_b1_step(topo, chips=4, mesh={"dp": 1, "fsdp": 2, "tp": 2},
                        batch=2, seq=SEQ, optimizer=default_optimizer(),
                        fused=False, cfg=MISTRAL).compile()
-    _assert_grad_exchange_runs_behind_the_backward(c, temp_limit=8.34e9)
+    comps = _computations(c.as_text())
+    for check in _CELL_STEP_ASSERTIONS.values():
+        check(comps)
+    assert c.memory_analysis().temp_size_in_bytes < 8.34e9
 
 
 # The whole-program compiles below keep ~5 cores busy for ~10 s each, which

@@ -206,14 +206,20 @@ def test_a_programs_own_trace_survives_and_the_inner_ones_do_not():
     assert traces == ["outer_program", "inner_program"], traces
 
 
-@pytest.mark.parametrize("mesh_cfg,n,exchanges", [
-    ({"dp": 1, "fsdp": 2, "tp": 2}, 4, 7), ({"dp": 1}, 1, 0),
-    ({"dp": 2, "fsdp": 1, "tp": 2}, 4, 0)], ids=["fsdp2xtp2", "one_device", "dp2xtp2"])
-def test_train_steps_compile_spans_say_which_reduction_ran(mesh_cfg, n, exchanges):
+@pytest.mark.parametrize("mesh_cfg,n,seq,exchanges,tp_exchanges", [
+    ({"dp": 1, "fsdp": 2, "tp": 2}, 4, 16, 7, 4), ({"dp": 1}, 1, 16, 0, 0),
+    ({"dp": 2, "fsdp": 1, "tp": 2}, 4, 16, 0, 0),
+    ({"dp": 1, "fsdp": 2, "tp": 2}, 4, 15, 7, 0)],
+    ids=["fsdp2xtp2", "one_device", "dp2xtp2", "fsdp2xtp2_odd_seq"])
+def test_train_steps_compile_spans_say_which_reduction_ran(
+        mesh_cfg, n, seq, exchanges, tp_exchanges):
     """Whether the program spells the weight gradients' exchange over fsdp
-    itself (parallel/fsdp.py) is a fact of its compile: the train step's
-    trace, lower and compile spans carry the mesh's `fsdp` and `tp` and how
-    many of a layer's weights go that way (7 on fsdp 2 x tp 2, else 0)."""
+    itself (parallel/fsdp.py), and the block's gathers and scatters over tp
+    (parallel/tp.py), is a fact of its compile: the train step's trace, lower
+    and compile spans carry the mesh's `fsdp` and `tp`, how many of a layer's
+    weights go the first way (7 on fsdp 2 x tp 2, else 0) and how many of its
+    forward's transfers over tp the second (4 where fsdp > 1 as well and
+    tp > 1 divides the sequence, else 0)."""
     from ray_tpu.models import ModelConfig
     from ray_tpu.parallel.mesh import MeshConfig, make_mesh
     from ray_tpu.train import batch_sharding, make_train_step
@@ -222,21 +228,24 @@ def test_train_steps_compile_spans_say_which_reduction_ran(mesh_cfg, n, exchange
     mesh = make_mesh(MeshConfig(**mesh_cfg), jax.devices()[:n])
     step_fn, init_fn, _ = make_train_step(cfg, mesh)
     state = init_fn(jax.random.PRNGKey(0))
-    tokens = jnp.zeros((4, 17), jnp.int32)
+    tokens = jnp.zeros((4, seq + 1), jnp.int32)
     batch = jax.device_put({"inputs": tokens[:, :-1], "targets": tokens[:, 1:]},
                            batch_sharding(mesh))
     tracing.clear()
     lowered = step_fn.lower(state, batch)
-    # the exchange is in the program exactly where the span says it is
-    assert ("collective_permute" in lowered.as_text()) == (exchanges > 0)
+    # the exchanges are in the program exactly where the span says they are
+    assert ("collective_permute" in lowered.as_text()) == (
+        exchanges + tp_exchanges > 0)
     lowered.compile()
     spans = [e["args"] for e in tracing.get_events()
              if e["name"] == "xla.compile" and "step" in e["args"]["fun_name"]]
     assert {"jaxpr_trace_duration", "jaxpr_to_mlir_module_duration",
             "backend_compile_duration"} <= {a["event"] for a in spans}, spans
     for a in spans:
-        assert (a["fsdp"], a["tp"], a["grad_exchanges_per_layer"]) == (
-            mesh_cfg.get("fsdp", 1), mesh_cfg.get("tp", 1), exchanges), a
+        assert (a["fsdp"], a["tp"], a["grad_exchanges_per_layer"],
+                a["tp_exchanges_per_layer"]) == (
+            mesh_cfg.get("fsdp", 1), mesh_cfg.get("tp", 1), exchanges,
+            tp_exchanges), a
 
 
 @pytest.mark.parametrize("name", ["engine.step", "engine.between_steps"])

@@ -22,6 +22,16 @@ def _batch(rng, cfg, batch=4, seq=64):
     return {"inputs": tokens[:, :-1], "targets": tokens[:, 1:]}
 
 
+def _five_losses(cfg, mesh, batch):
+    """The losses of five optimizer steps from one seeded state."""
+    step_fn, init_fn, _ = make_train_step(cfg, mesh, default_optimizer(1e-3))
+    state, losses = init_fn(jax.random.PRNGKey(0)), []
+    for _ in range(5):
+        state, metrics = step_fn(state, batch)
+        losses.append(float(metrics["loss"]))
+    return losses
+
+
 def test_loss_decreases_single_device():
     cfg = ModelConfig.tiny()
     rng = jax.random.PRNGKey(0)
@@ -90,18 +100,112 @@ def test_grad_exchange_over_fsdp_matches_one_device(mesh_cfg, n, monkeypatch):
             err_msg=jax.tree_util.keystr(path)), got, want)
     assert got["layers"]["wo"].sharding.spec == p_sh["layers"]["wo"].spec
 
-    def five_losses():
-        step_fn, init_fn, _ = make_train_step(cfg, mesh, default_optimizer(1e-3))
-        state, losses = init_fn(jax.random.PRNGKey(0)), []
-        for _ in range(5):
-            state, metrics = step_fn(state, on_mesh)
-            losses.append(float(metrics["loss"]))
-        return losses
-
-    ours = five_losses()
+    ours = _five_losses(cfg, mesh, on_mesh)
     monkeypatch.setattr(transformer, "_exchanged_dims", lambda *a: {})
-    np.testing.assert_allclose(ours, five_losses(), rtol=1e-5)
+    np.testing.assert_allclose(ours, _five_losses(cfg, mesh, on_mesh), rtol=1e-5)
     assert ours[-1] < ours[0]
+
+
+@pytest.mark.parametrize("mesh_cfg,n", [
+    (MeshConfig(dp=1, fsdp=1, tp=2), 2),
+    (MeshConfig(dp=1, fsdp=2, tp=2), 4),
+    (MeshConfig(dp=1, fsdp=4, tp=2), 8),
+    (MeshConfig(dp=2, fsdp=2, tp=2), 8),
+    (MeshConfig(dp=1, fsdp=2, tp=4), 8),
+], ids=["tp2", "fsdp2xtp2", "fsdp4xtp2", "dp2xfsdp2xtp2", "fsdp2xtp4"])
+def test_tp_exchange_matches_one_device(mesh_cfg, n, monkeypatch):
+    """On a mesh with tp > 1 and fsdp > 1 the residual stream rides
+    sequence-sharded over `tp` between the dense block's products, and each
+    gather and scatter goes as ring permutes behind its product
+    (parallel/tp.py; a ring of three steps at tp 4), the weights' shards
+    round fsdp's ring inside it. With fsdp 1 the weights do not come
+    exchanged and the program is the partitioner's. In float32 the loss and
+    EVERY gradient leaf agree with one device's `value_and_grad(loss_fn)`,
+    the layers' gradient leaves come in the parameter shardings, and five
+    optimizer steps give the losses of the partitioner's all-reduces (the
+    same mesh with the route switched off here)."""
+    import dataclasses
+
+    from ray_tpu.models import transformer
+    from ray_tpu.train.step import state_shardings
+
+    cfg = dataclasses.replace(ModelConfig.tiny(), n_kv_heads=mesh_cfg.tp)
+    mesh = make_virtual_mesh(n, mesh_cfg)
+    ours = mesh_cfg.fsdp > 1
+    assert transformer.tp_exchanges_per_layer(cfg, mesh, 8, 64) == 4 * ours
+    assert transformer.grad_exchanges_per_layer(cfg, mesh, 8) == 7 * ours
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    batch = _batch(jax.random.PRNGKey(1), cfg, batch=8, seq=64)
+    grad = lambda m: jax.jit(jax.value_and_grad(
+        lambda p, b: loss_fn(p, b, cfg, m)[0]))
+    want_loss, want = grad(None)(params, batch)
+
+    b_sh = batch_sharding(mesh)
+    on_mesh = jax.device_put(batch, {k: b_sh[k] for k in batch})
+    p_sh = state_shardings(cfg, mesh, default_optimizer()).params
+    lowered = grad(mesh).lower(jax.device_put(params, p_sh), on_mesh)
+    # four exchanges a layer forward, six backward, over the tp pairs
+    assert (lowered.as_text().count("collective_permute") >= 10) == ours
+    got_loss, got = lowered.compile()(jax.device_put(params, p_sh), on_mesh)
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=2e-6)
+    jax.tree_util.tree_map_with_path(
+        lambda path, g, w: np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-6 * float(jnp.abs(w).max()),
+            err_msg=jax.tree_util.keystr(path)), got, want)
+    # (the layers' leaves: the products' own `dw`; the rest is the compiler's)
+    jax.tree_util.tree_map_with_path(
+        lambda path, g, h: np.testing.assert_equal(
+            g.sharding.is_equivalent_to(h, g.ndim), True,
+            err_msg=jax.tree_util.keystr(path)), got["layers"], p_sh["layers"])
+
+    losses = _five_losses(cfg, mesh, on_mesh)
+    monkeypatch.setattr(transformer, "_rows_mesh", lambda *a: None)
+    np.testing.assert_allclose(losses, _five_losses(cfg, mesh, on_mesh), rtol=1e-5)
+    assert losses[-1] < losses[0]
+
+
+@pytest.mark.parametrize("why", ["seq_not_divisible", "ring", "experts", "fsdp1"])
+def test_tp_exchange_stays_off_where_the_block_is_not_the_plain_one(why, monkeypatch):
+    """Where `tp` does not divide the sequence, or a sequence-parallel
+    scheme or the expert layer runs, or the mesh has fsdp 1 (the weights do
+    not come exchanged), the program is the parent's to the
+    letter: the lowered text is the one with `parallel/tp.py`'s products made
+    unreachable, and it names none of them."""
+    import dataclasses
+
+    from ray_tpu.models import transformer
+    from ray_tpu.parallel import tp
+
+    cfg, seq = ModelConfig.tiny(), 64
+    mesh_cfg = MeshConfig(dp=2, fsdp=2, tp=2)
+    if why == "seq_not_divisible":
+        seq = 63
+    elif why == "ring":
+        cfg = dataclasses.replace(cfg, seq_parallel="ring")
+        mesh_cfg = MeshConfig(dp=2, fsdp=1, tp=2, sp=2)
+    elif why == "experts":
+        cfg = ModelConfig.tiny_moe()
+    else:
+        mesh_cfg = MeshConfig(dp=4, fsdp=1, tp=2)
+    mesh = make_virtual_mesh(8, mesh_cfg)
+    assert transformer.tp_exchanges_per_layer(cfg, mesh, 8, seq) == 0
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    tokens = jax.ShapeDtypeStruct((8, seq), jnp.int32)
+    batch = {"inputs": tokens, "targets": tokens}
+
+    def text():
+        return jax.jit(jax.value_and_grad(
+            lambda p, b: loss_fn(p, b, cfg, mesh)[0])).lower(params, batch).as_text()
+
+    ours = text()
+
+    def unreachable(*a, **k):
+        raise AssertionError("the tp route was taken")
+
+    for name in ("gather_matmul", "matmul_scatter", "shard_rows", "whole_rows"):
+        monkeypatch.setattr(tp, name, unreachable)
+    monkeypatch.setattr(transformer, "_rows_mesh", lambda *a: None)
+    assert ours == text()
 
 
 def test_train_step_with_sequence_parallel():
