@@ -5,10 +5,12 @@ same around the FFN: four norms a layer. The mixers: KDA linear attention
 (`ops/kda.py`), latent attention (`ops/mla.py`) in two kinds, without
 positions and with a full-rank query (Kimi-Linear's) or rotary with a
 low-rank query (`rope_theta`, `q_lora_rank`: openPangu-Ultra-MoE's),
-Mamba-1's selective scan (`ops/mamba.py`) and plain grouped-query attention
-without positions; the FFNs: dense SwiGLU and a dropless sigmoid-routed
-top-k expert layer with a shared expert (`ops/moe.py:dropless_moe`) that is
-told which experts it holds.
+Mamba-1's selective scan (`ops/mamba.py`), plain grouped-query attention
+without positions and EVA attention (`ops/eva.py`: the query's own window
+of `eva_window` positions exactly, beside one learned summary a chunk of
+`eva_chunk` positions of every earlier window; EvaByte's); the FFNs: dense
+SwiGLU and a dropless sigmoid-routed top-k expert layer with a shared expert
+(`ops/moe.py:dropless_moe`) that is told which experts it holds.
 
 A list-form configuration with `n_predict` 1 carries a multi-token
 prediction module (DeepSeek-V3's form): `h' = W_p [RMSNorm(h_i) ;
@@ -32,6 +34,14 @@ Two ways to hold and run the stack, by what the configuration lists:
   the decode step's scan as a CARRY that each layer reads and rewrites in
   place (a scan that took it as xs and gave it back as ys would hold it
   twice).
+- `eva_layers` (the EvaByte family: every mixer EVA attention with rotary
+  positions, dense FFNs, norms that scale by 1 + w, an untied head of
+  `n_pred_heads` x vocabulary columns of which serving reads the first
+  vocabulary's): the runs form with ONE run. Its prompt pass walks the
+  sequence a WINDOW at a time, every window through all layers (a layer of
+  window w needs only the summaries the same layer left for the windows
+  before w), so its activations are those of `eva_window` positions
+  whatever the prompt's length.
 
 Three call modes over the same weights:
 
@@ -45,8 +55,15 @@ Three call modes over the same weights:
 `decode_step`   one token for every slot from the slots' state, greedy
                 sampling on device, state DONATED and rewritten in place.
 
-`HybridCache` (list form) and `RunsCache` (runs form) are this family's
-implementations of the engine's per-slot state interface
+A slot's state is of three kinds. An attention keeps a row a position for
+ever (K/V, or a latent row); a recurrent mixer keeps a state of fixed size;
+EVA keeps a table of two regions: the open window's K/V rows, which the
+slot REUSES every `eva_window` positions (row n % W; stale rows are masked
+by the length, never cleared), and a summary a closed chunk, a row every
+`eva_chunk` positions, of which a query sees those of closed windows only.
+
+`HybridCache` (list form), `RunsCache` and `EvaCache` (runs form) are this
+family's implementations of the engine's per-slot state interface
 (`models/serving.py`, "the cache interface").
 """
 
@@ -61,12 +78,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.inference import _gqa_decode_attention
-from ray_tpu.ops import kda, mamba, mla
+from ray_tpu.ops import eva, kda, mamba, mla
 from ray_tpu.ops.attention import causal_attention_blocked
 from ray_tpu.ops.cache import write_rows
 from ray_tpu.ops.layers import rms_norm, swiglu
 from ray_tpu.ops.moe import dropless_moe, route_top_k
-from ray_tpu.ops.pallas import decode_attention
+from ray_tpu.ops.pallas import decode_attention, eva_decode
 
 F32 = jnp.float32
 
@@ -119,6 +136,13 @@ class HybridConfig:
     mamba_chunk: int = 128
     n_kv_heads: int = 1
     head_dim: int = 16
+    # EVA mixers (the runs form, one run: every layer is listed); they take
+    # `rope_theta`, `n_heads` = `n_kv_heads` and `head_dim`
+    eva_layers: Tuple[int, ...] = ()
+    eva_window: int = 2048
+    eva_chunk: int = 16
+    n_pred_heads: int = 1                     # heads of vocab_size columns each
+    norm_unit_offset: bool = False            # every RMSNorm scales by 1 + w
 
     @staticmethod
     def tiny_hybrid() -> "HybridConfig":
@@ -141,22 +165,41 @@ class HybridConfig:
                             mamba_layers=(1, 2, 4, 5, 6, 8), attn_layers=(3, 7),
                             n_heads=4, norm_eps=1e-6, mamba_chunk=16)
 
+    @staticmethod
+    def tiny_eva() -> "HybridConfig":
+        """Three EVA layers, windows of 32 positions in chunks of 4, two
+        prediction heads."""
+        return HybridConfig(vocab_size=64, n_layers=3, kda_layers=(), first_dense=3,
+                            eva_layers=(1, 2, 3), eva_window=32, eva_chunk=4,
+                            n_heads=4, n_kv_heads=4, rope_theta=1e5,
+                            n_pred_heads=2, norm_unit_offset=True)
+
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
         def mixer(i):
             return ("kda" if i in self.kda_layers else
                     "mamba" if i in self.mamba_layers else
-                    "attn" if i in self.attn_layers else "mla")
+                    "attn" if i in self.attn_layers else
+                    "eva" if i in self.eva_layers else "mla")
         return tuple((mixer(i), "dense" if i <= self.first_dense else "moe")
                      for i in range(1, self.n_layers + 1))
 
     @property
     def scanned(self) -> bool:
-        return bool(self.mamba_layers or self.attn_layers)
+        return bool(self.mamba_layers or self.attn_layers or self.eva_layers)
 
     def runs(self) -> Tuple[Tuple[str, int], ...]:
         """The runs form's stack: (mixer, how many like layers in a row)."""
         kinds = self.layer_kinds()
-        if any(k not in (("mamba", "dense"), ("attn", "dense")) for k in kinds):
+        if self.eva_layers:
+            # the prompt pass walks windows, each through the whole stack
+            if set(kinds) != {("eva", "dense")} or self.n_kv_heads != self.n_heads \
+                    or self.eva_window % self.eva_chunk:
+                raise ValueError(
+                    "EVA mixers make ONE run over dense FFNs, a key head a "
+                    "query head, whole chunks a window; not "
+                    f"{sorted(set(kinds))}, {self.n_heads}:{self.n_kv_heads} heads, "
+                    f"{self.eva_window} / {self.eva_chunk}")
+        elif any(k not in (("mamba", "dense"), ("attn", "dense")) for k in kinds):
             raise ValueError("a stack of scanned runs holds Mamba and attention "
                              f"mixers over dense FFNs only, not {sorted(set(kinds))}")
         out: List[Tuple[str, int]] = []
@@ -176,7 +219,9 @@ class HybridConfig:
 
     def make_cache(self, num_slots: int, max_len: int):
         """This model's per-slot state for `ContinuousBatchingEngine`."""
-        return (RunsCache if self.scanned else HybridCache)(self, num_slots, max_len)
+        kind = EvaCache if self.eva_layers else RunsCache if self.scanned \
+            else HybridCache
+        return kind(self, num_slots, max_len)
 
 
 # ---------------------------------------------------------------- params
@@ -271,7 +316,12 @@ def _init_runs(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
     `A_log` = log(1..d_state) per state column, `dt_bias` the inverse
     softplus of exp(uniform[ln 1e-3, ln 1e-1]), `D` = 1 + noise, the
     convolution's bias uniform in +-K^-1/2; the three inner norms ones. No
-    `lm_head`: the head is the embedding."""
+    `lm_head`: the head is the embedding. An EVA run: `phi` and `mu`, the
+    two vectors a head that make a chunk's summary, uniform in +-1 and
+    +-0.5 (non-zero, of the keys' own size, so that dropping either is an
+    error a test sees), norm weights around zero (they scale by 1 + w) and
+    an untied `lm_head` [d, n_pred_heads x vocab_size], head j's columns at
+    [j vocab_size, (j + 1) vocab_size)."""
     d, dt = cfg.d_model, cfg.dtype
     di, n, r, K = cfg.d_inner, cfg.d_state, cfg.dt_rank, cfg.conv_kernel
     counter = iter(range(1 << 20))
@@ -282,10 +332,15 @@ def _init_runs(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
     def w(shape, fan_in):
         return (jax.random.normal(key(), shape, F32) * fan_in ** -0.5).astype(dt)
 
+    def norm(shape):
+        if not cfg.norm_unit_offset:
+            return jnp.ones(shape, dt)
+        return jax.random.uniform(key(), shape, F32, -0.1, 0.1).astype(dt)
+
     runs: List[Dict[str, Any]] = []
     for mixer, k in cfg.runs():
         p: Dict[str, Any] = {
-            "mixer_norm": jnp.ones((k, d), dt), "ffn_norm": jnp.ones((k, d), dt),
+            "mixer_norm": norm((k, d)), "ffn_norm": norm((k, d)),
             "ffn": {"w_gate": w((k, d, cfg.d_ff), d), "w_up": w((k, d, cfg.d_ff), d),
                     "w_down": w((k, cfg.d_ff, d), cfg.d_ff)}}
         if mixer == "mamba":
@@ -307,12 +362,19 @@ def _init_runs(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
                 "w_out": w((k, di, d), di)}
         else:
             H, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-            p["attn"] = {"wq": w((k, d, H * hd), d), "wk": w((k, d, kvh * hd), d),
-                         "wv": w((k, d, kvh * hd), d), "wo": w((k, H * hd, d), H * hd)}
+            p[mixer] = {"wq": w((k, d, H * hd), d), "wk": w((k, d, kvh * hd), d),
+                        "wv": w((k, d, kvh * hd), d), "wo": w((k, H * hd, d), H * hd)}
+            if mixer == "eva":
+                p["eva"]["phi"] = jax.random.uniform(key(), (k, H, hd), F32, -1.0, 1.0)
+                p["eva"]["mu"] = jax.random.uniform(key(), (k, H, hd), F32, -0.5, 0.5)
         runs.append(p)
-    return {"embed": (jax.random.normal(key(), (cfg.vocab_size, d), F32)
-                      * 0.02).astype(dt),
-            "final_norm": jnp.ones((d,), dt), "runs": runs}
+    params = {"embed": (jax.random.normal(key(), (cfg.vocab_size, d), F32)
+                        * 0.02).astype(dt),
+              "final_norm": norm((d,)), "runs": runs}
+    if cfg.eva_layers:
+        params["lm_head"] = (jax.random.normal(
+            key(), (d, cfg.n_pred_heads * cfg.vocab_size), F32) * 0.02).astype(dt)
+    return params
 
 
 # ---------------------------------------------------------------- pieces
@@ -372,7 +434,7 @@ def _residual(cfg: HybridConfig, p, post_norm: str, x, y):
 def _normed(cfg: HybridConfig, x, w):
     """The float32 residual x, normed: (in float32 for the router, in the
     weights' type for the matrix products)."""
-    h32 = rms_norm(x, w, cfg.norm_eps)
+    h32 = rms_norm(x, w, cfg.norm_eps, cfg.norm_unit_offset)
     return h32, h32.astype(cfg.dtype)
 
 
@@ -476,6 +538,21 @@ def _attn_qkv(cfg: HybridConfig, a, h):
             (h @ a["wv"]).reshape(lead + (cfg.n_kv_heads, hd)))
 
 
+def _eva_qkv(cfg: HybridConfig, a, h, positions):
+    """h [..., s, d] at positions [..., s] -> q, k, v [..., s, H, hd] in the
+    configuration's type, q and k rotated over the whole head width."""
+    # behind the barrier the three products stay flat and read their weight
+    # in place (`serving._one_row_qkv` says why): left to fuse the rotation's
+    # head split into them, XLA:TPU wants `wq`, `wk`, `wv` transposed, and
+    # copies all three STACKS ahead of the prompt pass's loops (0.8 GB)
+    lead, hd = h.shape[:-1], cfg.head_dim
+    q, k, v = (t.reshape(lead + (cfg.n_heads, hd)) for t in
+               jax.lax.optimization_barrier((h @ a["wq"], h @ a["wk"], h @ a["wv"])))
+    return (mla.rotate(q, positions, cfg.rope_theta),
+            mla.rotate(k, positions, cfg.rope_theta).astype(cfg.dtype),
+            v.astype(cfg.dtype))
+
+
 # ---------------------------------------------------------------- sequence
 
 
@@ -559,6 +636,8 @@ def _sequence_runs(params, tokens, true_len, cfg: HybridConfig):
     """`_sequence` for the runs form: every run one `lax.scan` over its
     stacked weights. State rows: per Mamba run "ssm" [k, b, n, di] float32
     and "conv" [k, b, K-1, di]; "k", "v" [attention layers, b, kvh, s, hd]."""
+    if cfg.eva_layers:
+        return _sequence_eva(params, tokens, true_len, cfg)
     b, s = tokens.shape
     H, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     x = params["embed"][tokens].astype(F32)
@@ -597,8 +676,99 @@ def _sequence_runs(params, tokens, true_len, cfg: HybridConfig):
     return x, rows, []
 
 
-def _head(params, x):
-    """Features -> logits float32: the untied head, or the embedding."""
+def _sequence_eva(params, tokens, true_len, cfg: HybridConfig,
+                  last_only: bool = False):
+    """`_sequence` for a stack of EVA layers: ONE `lax.scan` over the
+    sequence's windows (the tokens padded to whole windows), each window
+    through all layers (a scan over the stacked weights). Layer l of window
+    w reads the summaries layer l left for the windows before w, out of a
+    table the outer scan carries, and adds its own W / C. With `last_only`
+    (the prompt pass) the hidden row at `true_len - 1` is carried too,
+    returned alone [b, 1, d], and the windows' hidden rows are dropped as
+    they go. State rows, all
+    [layers, b, H, rows, hd] and zero wherever a row is not live (positions
+    past `true_len` leave nothing): "win_k", "win_v" the rows of the window
+    that position `true_len` lies in (none if it opens one); "sum_k",
+    "sum_v" a row a CLOSED chunk; "ck", "cv" the open chunk's rows again,
+    from its row 0 (the decode step makes the chunk's summary of them when
+    it closes)."""
+    b, s = tokens.shape
+    W, C, H, hd = cfg.eva_window, cfg.eva_chunk, cfg.n_heads, cfg.head_dim
+    n_win = -(-s // W)
+    per = W // C                                  # summaries a window
+    rp = params["runs"][0]
+    L = rp["mixer_norm"].shape[0]
+    toks = jnp.pad(tokens, ((0, 0), (0, n_win * W - s))).reshape(b, n_win, W)
+
+    def window(carry, inputs):
+        sum_k, sum_v, win_k, win_v, last = carry
+        toks, w = inputs
+        positions = w * W + jnp.arange(W)
+
+        def layer(x, inputs):
+            lp, seen_k, seen_v = inputs
+            with jax.named_scope("eva"):
+                _, h = _normed(cfg, x, lp["mixer_norm"])
+                a = lp["eva"]
+                q, k, v = _eva_qkv(cfg, a, h, positions)
+                rows_k, rows_v = jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2)
+                with jax.named_scope("summarise"):
+                    new_k, new_v = eva.summarise(rows_k, rows_v, a["phi"], a["mu"],
+                                                 C, hd ** -0.5)
+                with jax.named_scope("attend"):
+                    attn = eva.window_attention(q, k, v, seen_k, seen_v, w * per,
+                                                hd ** -0.5)
+                x = x + (attn.reshape(b, W, H * hd) @ a["wo"]).astype(F32)
+            return _dense_ffn(cfg, lp, x), (new_k, new_v, rows_k, rows_v)
+
+        x, (new_k, new_v, rows_k, rows_v) = jax.lax.scan(
+            layer, params["embed"][toks].astype(F32), (rp, sum_k, sum_v))
+        at = (0, 0, 0, w * per, 0)
+        sum_k = jax.lax.dynamic_update_slice(sum_k, new_k, at)
+        sum_v = jax.lax.dynamic_update_slice(sum_v, new_v, at)
+        opens = (true_len // W == w)[None, :, None, None, None]
+        carry = (sum_k, sum_v, jnp.where(opens, rows_k, win_k),
+                 jnp.where(opens, rows_v, win_v))
+        if not last_only:
+            return carry + (last,), x
+        here = jnp.take_along_axis(
+            x, jnp.clip(true_len - 1 - w * W, 0, W - 1)[:, None, None], axis=1)
+        return carry + (jnp.where(((true_len - 1) // W == w)[:, None, None],
+                                  here, last),), None
+
+    table = jnp.zeros((L, b, H, n_win * per, hd), cfg.dtype)
+    rows = jnp.zeros((L, b, H, W, hd), cfg.dtype)
+    (sum_k, sum_v, win_k, win_v, last), x = jax.lax.scan(
+        window, (table, table, rows, rows, jnp.zeros((b, 1, cfg.d_model), F32)),
+        (jnp.moveaxis(toks, 1, 0), jnp.arange(n_win)))
+    x = last if last_only else jnp.moveaxis(x, 0, 1).reshape(b, n_win * W, -1)[:, :s]
+
+    def live(a, n):   # rows [.., b, H, r, hd] of which the first n [b] stay
+        return jnp.where((jnp.arange(a.shape[3])[None, :] < n[:, None]
+                          )[None, :, None, :, None], a, 0)
+
+    # the open chunk's rows, from the window's: C rows from the chunk's first
+    first = (true_len // C * C) % W
+    chunk = lambda a: jnp.take_along_axis(
+        a, (first[:, None] + jnp.arange(C)[None, :])[None, :, None, :, None], axis=3)
+    state = {"win_k": live(win_k, true_len % W), "win_v": live(win_v, true_len % W),
+             "sum_k": live(sum_k, true_len // C), "sum_v": live(sum_v, true_len // C),
+             "ck": live(chunk(win_k), true_len % C),
+             "cv": live(chunk(win_v), true_len % C)}
+    return x, state, []
+
+
+def _head(params, x, cfg: HybridConfig = None, all_heads: bool = False):
+    """Features -> logits float32: the untied head, or the embedding. A head
+    of several predictions (`n_pred_heads`: head j at position i scores the
+    token at i + 1 + j, its columns at [j V, (j + 1) V)) gives the first
+    head's V columns, read alone, the sum float32, or with `all_heads` all
+    [..., n_pred_heads, V]."""
+    if cfg is not None and cfg.n_pred_heads > 1:
+        V = cfg.vocab_size
+        w = params["lm_head"] if all_heads else params["lm_head"][:, :V]
+        logits = jnp.dot(x, w, preferred_element_type=F32)
+        return logits.reshape(x.shape[:-1] + (-1, V)) if all_heads else logits
     if "lm_head" in params:
         return (x @ params["lm_head"]).astype(F32)
     return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(F32)
@@ -612,15 +782,18 @@ def _following(tokens, true_len, first):
     return jnp.where(at, first[:, None], jnp.roll(tokens, -1, axis=1))
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "with_mtp"))
-def forward(params, tokens, cfg: HybridConfig, with_mtp: bool = False):
+@functools.partial(jax.jit, static_argnames=("cfg", "with_mtp", "all_heads"))
+def forward(params, tokens, cfg: HybridConfig, with_mtp: bool = False,
+            all_heads: bool = False):
     """tokens [b, s] -> logits [b, s, vocab] float32. `with_mtp`: beside
     them the prediction module's [b, s - 1, vocab]: from position i's hidden
-    row and token i + 1, logits for token i + 2."""
+    row and token i + 1, logits for token i + 2. `all_heads` (a head of
+    `n_pred_heads` predictions): [b, s, n_pred_heads, vocab]."""
     full = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
     x, _, _ = _sequence(params, tokens, full, cfg)
     with jax.named_scope("head"):
-        logits = _head(params, _normed(cfg, x, params["final_norm"])[1])
+        logits = _head(params, _normed(cfg, x, params["final_norm"])[1], cfg,
+                       all_heads)
     if not with_mtp:
         return logits
     feats, _, _ = _mtp_sequence(params, x[:, :-1], tokens[:, 1:], full - 1, cfg)
@@ -645,10 +818,15 @@ def prefill(params, tokens, true_len, cfg: HybridConfig,
     as "mtp_logits" [nb, vocab]. `first` [nb], where the caller knows the
     token each answer began with (a teacher-forced comparison), is fed to
     the module in place of the model's own choice."""
-    x, rows, routing = _sequence(params, tokens, true_len, cfg)
     pick = lambda a: jnp.take_along_axis(a, (true_len - 1)[:, None, None], axis=1)[:, 0]
+    if cfg.eva_layers:
+        x, rows, routing = _sequence_eva(params, tokens, true_len, cfg, last_only=True)
+        last = x[:, 0]
+    else:
+        x, rows, routing = _sequence(params, tokens, true_len, cfg)
+        last = pick(x)
     with jax.named_scope("head"):
-        logits = _head(params, _normed(cfg, pick(x), params["final_norm"])[1])
+        logits = _head(params, _normed(cfg, last, params["final_norm"])[1], cfg)
     if cfg.n_predict and not cfg.scanned:
         if first is None:
             first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -852,6 +1030,81 @@ def _decode_runs(params, state, lengths, tokens, cfg: HybridConfig,
     return {"ssm": ssm_new, "conv": conv_new, "k": k_all, "v": v_all}, logits
 
 
+def _decode_eva(params, state, lengths, tokens, cfg: HybridConfig,
+                attn_len: int):
+    """`_decode_runs` for a stack of EVA layers -> (state, logits [B, vocab]
+    float32 of the first prediction head, [chunks closed, windows closed]).
+    A busy slot's token stands at position n = its length, in window n // W.
+    Inside the scan the tables are read-only: each layer attends the slot's
+    n % W live rows of the window region and the summaries of the n // W
+    closed windows, its own row a term of its own (on the TPU a kernel over
+    the live rows of the whole table, `ops/pallas/eva_decode.py`). Then every
+    layer's row is written once, to row n % W (the region turns over: row 0
+    again at n = W, the stale rows behind it masked by the length) and into
+    the open chunk's rows; a slot whose chunk this position CLOSES
+    ((n + 1) % C == 0) writes the chunk's summary to row W + n // C in the
+    same step. The step at n % W == 0 so reads W / C more summaries and no
+    window row. Slots close chunks and windows each at its own step; a slot
+    of length 0 is idle and reads and writes nothing."""
+    B = tokens.shape[0]
+    W, C, H, hd = cfg.eva_window, cfg.eva_chunk, cfg.n_heads, cfg.head_dim
+    x = params["embed"][tokens].astype(F32)
+    k_all, v_all = state["ek"], state["ev"]
+    busy = lengths > 0
+    window_rows, summary_rows = lengths % W, lengths // W * (W // C)
+    kernel = eva_decode.uses_decode_kernel(k_all, W, C)
+    if kernel:
+        blocks = eva_decode.live_blocks(lengths, W, C, attn_len)
+    else:   # the window region and as many summaries as `attn_len` can show
+        read = (1, B, H, W + min(k_all.shape[3] - W, -(-attn_len // C)), hd)
+
+    def layer(x, inputs):
+        lp, i = inputs
+        with jax.named_scope("eva"):
+            _, h = _normed(cfg, x, lp["mixer_norm"])
+            a = lp["eva"]
+            q, k_cur, v_cur = (t[:, 0] for t in _eva_qkv(
+                cfg, a, h[:, None], lengths[:, None]))
+            with jax.named_scope("attend"):
+                if kernel:
+                    attn = eva_decode.eva_decode_attention(
+                        q, k_cur, v_cur, k_all, v_all, i, blocks, W, C, attn_len)
+                else:
+                    attn = eva.decode_attention(
+                        q, k_cur, v_cur,
+                        jax.lax.dynamic_slice(k_all, (i, 0, 0, 0, 0), read)[0],
+                        jax.lax.dynamic_slice(v_all, (i, 0, 0, 0, 0), read)[0],
+                        window_rows, summary_rows, W, hd ** -0.5)
+            x = x + (attn.reshape(B, H * hd).astype(cfg.dtype) @ a["wo"]).astype(F32)
+        return _dense_ffn(cfg, lp, x), (k_cur, v_cur)
+
+    rp = params["runs"][0]
+    x, (k_cur, v_cur) = jax.lax.scan(
+        layer, x, (rp, jnp.arange(rp["mixer_norm"].shape[0])))
+    closes = busy & ((lengths + 1) % C == 0)
+    with jax.named_scope("eva"):
+        with jax.named_scope("write"):
+            k_all = write_rows(k_all, k_cur, window_rows, busy)
+            v_all = write_rows(v_all, v_cur, window_rows, busy)
+            mine = ((jnp.arange(C)[None, :] == (lengths % C)[:, None])
+                    & busy[:, None])[None, :, None, :, None]
+            ck = jnp.where(mine, k_cur[:, :, :, None], state["ck"])
+            cv = jnp.where(mine, v_cur[:, :, :, None], state["cv"])
+        with jax.named_scope("summarise"):
+            sum_k, sum_v = jax.vmap(
+                lambda k, v, phi, mu: eva.summarise(k, v, phi, mu, C, hd ** -0.5))(
+                    ck, cv, rp["eva"]["phi"], rp["eva"]["mu"])
+        with jax.named_scope("write"):
+            at = W + lengths // C
+            k_all = write_rows(k_all, sum_k[:, :, :, 0], at, closes)
+            v_all = write_rows(v_all, sum_v[:, :, :, 0], at, closes)
+    with jax.named_scope("head"):
+        logits = _head(params, _normed(cfg, x, params["final_norm"])[1], cfg)
+    closed = jnp.stack([jnp.sum(closes), jnp.sum(busy & ((lengths + 1) % W == 0))])
+    return ({"ek": k_all, "ev": v_all, "ck": ck, "cv": cv}, logits,
+            closed.astype(jnp.int32))
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
                    donate_argnums=(1,))
 def decode_logits(params, state, lengths, tokens, active, cfg: HybridConfig,
@@ -863,7 +1116,12 @@ def decode_logits(params, state, lengths, tokens, active, cfg: HybridConfig,
     routing ([0, B, 0]) and takes no `active`. A configuration that drafts
     is checked through `verify_logits`."""
     if cfg.scanned:
-        state, logits = _decode_runs(params, state, lengths, tokens, cfg, attn_len)
+        if cfg.eva_layers:
+            state, logits, _ = _decode_eva(params, state, lengths, tokens, cfg,
+                                           attn_len)
+        else:
+            state, logits = _decode_runs(params, state, lengths, tokens, cfg,
+                                         attn_len)
         return state, logits, jnp.zeros((0, tokens.shape[0], 0), jnp.int32)
     state, logits, _, _, routing = _decode(params, state, lengths, tokens[:, None],
                                            active, cfg, attn_len)
@@ -909,7 +1167,13 @@ def decode_step(params, state, lengths, tokens, active, cfg: HybridConfig,
     proposed, drafts accepted].
 
     The runs form keeps the dense engine's rule for idle slots (length 0
-    stays 0) and has no counters: its report is the tokens."""
+    stays 0) and has no counters: its report is the tokens. A stack of EVA
+    layers counts the [chunks, windows] its slots closed behind them."""
+    if cfg.eva_layers:
+        state, logits, closed = _decode_eva(params, state, lengths, tokens, cfg,
+                                            attn_len)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return state, lengths + (lengths > 0), nxt, jnp.concatenate([nxt, closed])
     if cfg.scanned:
         state, logits = _decode_runs(params, state, lengths, tokens, cfg, attn_len)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -950,6 +1214,8 @@ def _write_state(state, lengths, tokens, slots, rows, true_len, first):
     dropped. `tokens` is not donated (the step in flight reads it)."""
     if "ssm" in state:
         return _write_runs(state, lengths, tokens, slots, rows, true_len, first)
+    if "ek" in state:
+        return _write_eva(state, lengths, tokens, slots, rows, true_len, first)
     with jax.named_scope("state_write"):
         put = lambda whole, part: whole.at[slots].set(part, mode="drop")
         bucket = rows["latent"].shape[2]
@@ -972,6 +1238,24 @@ def _write_runs(state, lengths, tokens, slots, rows, true_len, first):
         state = {"ssm": [put(a, r) for a, r in zip(state["ssm"], rows["ssm"])],
                  "conv": [put(a, r) for a, r in zip(state["conv"], rows["conv"])],
                  "k": kv(state["k"], rows["k"]), "v": kv(state["v"], rows["v"])}
+    return (state, lengths.at[slots].set(true_len, mode="drop"),
+            tokens.at[slots].set(first, mode="drop"))
+
+
+def _write_eva(state, lengths, tokens, slots, rows, true_len, first):
+    """`_write_state` for a stack of EVA layers: the open window's rows over
+    the table's window region, the prompt's summaries over the first rows of
+    its summary region, the open chunk's rows. What the prompt left no row
+    for arrives as zeros and is stale either way."""
+    with jax.named_scope("eva"), jax.named_scope("write"):
+        W, n_sum = rows["win_k"].shape[3], rows["sum_k"].shape[3]
+        table = lambda whole, win, summ: whole.at[:, slots, :, :W].set(
+            win, mode="drop").at[:, slots, :, W:W + n_sum].set(summ, mode="drop")
+        put = lambda whole, part: whole.at[:, slots].set(part, mode="drop")
+        state = {"ek": table(state["ek"], rows["win_k"], rows["sum_k"]),
+                 "ev": table(state["ev"], rows["win_v"], rows["sum_v"]),
+                 "ck": put(state["ck"], rows["ck"]),
+                 "cv": put(state["cv"], rows["cv"])}
     return (state, lengths.at[slots].set(true_len, mode="drop"),
             tokens.at[slots].set(first, mode="drop"))
 
@@ -1023,15 +1307,15 @@ class HybridCache:
             params, self.state, lengths, tokens, active, self.cfg, attn_len)
         return lengths, nxt, report
 
-    def step_args(self, n_active: int, live_rows: int,
-                  attn_len: int) -> Dict[str, int]:
-        """What one decode step moved, known on the host at dispatch:
-        `state_slots` the busy slots whose KDA state it needs, `latent_rows`
-        the live rows of the busy slots, which the attention has to read
-        (of the span's `num_slots x attn_len`, which the einsum form reads;
-        the row write moves the blocks of the span's `active` slots)."""
-        return {"state_slots": n_active if self.n_kda else 0,
-                "latent_rows": live_rows if self.n_latent else 0}
+    def step_args(self, positions: List[int], attn_len: int) -> Dict[str, int]:
+        """What one decode step moved, known on the host at dispatch, from
+        the busy slots' `positions`: `state_slots` the busy slots whose KDA
+        state it needs, `latent_rows` the live rows of the busy slots, which
+        the attention has to read (of the span's `num_slots x attn_len`,
+        which the einsum form reads; the row write moves the blocks of the
+        span's `active` slots)."""
+        return {"state_slots": len(positions) if self.n_kda else 0,
+                "latent_rows": sum(positions) if self.n_latent else 0}
 
 
 class RunsCache(HybridCache):
@@ -1041,7 +1325,9 @@ class RunsCache(HybridCache):
     K and V [layers, slots, kv_heads, max_len, head_dim], written by
     `ops.cache.write_rows` and read by `ops.pallas.decode_attention` like the
     dense cache. Every leaf is donated whole to each call. The entry points
-    are `HybridCache`'s: the jitted programs branch on the configuration."""
+    are `HybridCache`'s: the jitted programs branch on the configuration.
+    Both kinds of state here are the old two (a row a position for ever, a
+    state of fixed size); a run of EVA layers keeps a third: `EvaCache`."""
 
     counters = ()
     step_tokens = 1
@@ -1063,11 +1349,52 @@ class RunsCache(HybridCache):
     def max_prefill_batch(self, bucket: int) -> int:
         return max(1, min(8, self.cfg.prefill_tokens // bucket))
 
-    def step_args(self, n_active: int, live_rows: int,
-                  attn_len: int) -> Dict[str, int]:
+    def step_args(self, positions: List[int], attn_len: int) -> Dict[str, int]:
         """`state_slots`: the busy slots, whose recurrent state the step
         needs; `kv_rows`: the positions they hold, which every attention
         layer reads (`write_rows` moves the K/V blocks of the span's
         `active` slots)."""
-        return {"state_slots": n_active if self.n_mamba else 0,
-                "kv_rows": live_rows if self.n_attn else 0}
+        return {"state_slots": len(positions) if self.n_mamba else 0,
+                "kv_rows": sum(positions) if self.n_attn else 0}
+
+
+class EvaCache(RunsCache):
+    """Per-slot state of a stack of EVA layers, the third kind: neither a
+    row a position for ever nor a state of fixed size. Per layer and slot a
+    table "ek", "ev" [layers, slots, H, W + max_len / C, hd] of two regions:
+    rows [0, W) hold the open window's keys and values, position n at row
+    n % W, so a slot REUSES the region every W positions (what lies past
+    n % W is stale and masked by the length, never cleared); rows from W on
+    hold a summary a closed chunk, one more every C positions, of which the
+    n // W x W / C of closed windows are visible. `max_len` positions cost
+    W + max_len / C rows, not max_len. Beside them "ck", "cv"
+    [layers, slots, H, C, hd]: the open chunk's rows once more, from its
+    row 0, which the step that closes the chunk summarises. Written by
+    `ops.cache.write_rows` at the rows the step names, read by
+    `ops.pallas.eva_decode` over the live rows."""
+
+    counters = ("chunks_closed", "windows_closed")
+
+    def __init__(self, cfg: HybridConfig, num_slots: int, max_len: int):
+        self.cfg, self.num_slots, self.max_len = cfg, num_slots, max_len
+        cfg.runs()    # one run of EVA layers, or an error
+        L, H, hd = cfg.n_layers, cfg.n_heads, cfg.head_dim
+        table = (L, num_slots, H, cfg.eva_window + -(-max_len // cfg.eva_chunk), hd)
+        chunk = (L, num_slots, H, cfg.eva_chunk, hd)
+        self.state = {"ek": jnp.zeros(table, cfg.dtype), "ev": jnp.zeros(table, cfg.dtype),
+                      "ck": jnp.zeros(chunk, cfg.dtype), "cv": jnp.zeros(chunk, cfg.dtype)}
+        self.prefill_args = {"eva_layers": L}
+
+    def prompt_bucket(self, n: int) -> int:
+        """Whole windows: the prompt pass walks them, so a prompt pays for
+        the windows it has and not for a power of two."""
+        W = self.cfg.eva_window
+        return -(-n // W) * W
+
+    def step_args(self, positions: List[int], attn_len: int) -> Dict[str, int]:
+        """The rows a layer reads for the busy slots, summed: `window_rows`
+        of the open windows (n % W a slot; its own row is not in the table
+        yet), `summary_rows` of the closed windows' chunks (n // W x W / C)."""
+        W, C = self.cfg.eva_window, self.cfg.eva_chunk
+        return {"window_rows": sum(n % W for n in positions),
+                "summary_rows": sum(n // W * (W // C) for n in positions)}

@@ -324,11 +324,15 @@ class DenseKVCache:
                                     holds is its count, 1 for a cache that
                                     never drafts
         max_prefill_batch(bucket)   None = any
+        prompt_bucket(n)            optional: the padded length a prompt of
+                                    n tokens is passed at (a cache without
+                                    one gets `_bucket_len`: a power of two)
         counters                    names of what `report` carries behind
                                     the tokens (summed into `engine.step`)
-        step_args(n_active, live_rows, attn_len), prefill_args
+        step_args(positions, attn_len), prefill_args
                                     span arguments (of a step that
-                                    dispatched a decode; of a prompt pass)
+                                    dispatched a decode, from the busy
+                                    slots' positions; of a prompt pass)
 
     It calls the module's own jitted `prefill_slots`, `_write_slots` and
     `decode_step_fused`, so the dense model compiles to the programs it
@@ -364,13 +368,12 @@ class DenseKVCache:
             params, s["k"], s["v"], lengths, tokens, self.cfg, attn_len)
         return lengths, nxt, nxt
 
-    def step_args(self, n_active: int, live_rows: int,
-                  attn_len: int) -> Dict[str, int]:
+    def step_args(self, positions: List[int], attn_len: int) -> Dict[str, int]:
         """The rows that hold a token, which the step has to read (of the
         span's `num_slots x attn_len` it read up to PR 28, and still reads
         on the CPU path); the slots whose block of rows
         `ops.cache.write_rows` moves are the span's `active`."""
-        return {"live_rows": live_rows}
+        return {"live_rows": sum(positions)}
 
 
 def _pow2(n: int) -> int:
@@ -424,6 +427,9 @@ class ContinuousBatchingEngine:
         # a configuration that is not the dense model's brings its own
         self.cache = cfg.make_cache(num_slots, max_len) \
             if hasattr(cfg, "make_cache") else DenseKVCache(cfg, num_slots, max_len)
+        # a cache may name its own prompt buckets (EVA: whole windows)
+        self._prompt_bucket = getattr(
+            self.cache, "prompt_bucket", lambda n: _bucket_len(n, max_len))
         self.lengths = jnp.zeros((num_slots,), jnp.int32)
         self.tokens = jnp.zeros((num_slots,), jnp.int32)
         self._free = list(range(num_slots))
@@ -600,7 +606,7 @@ class ContinuousBatchingEngine:
             req.t_admit = now
             self._active[slot] = req
             self._slot_pos[slot] = len(req.prompt)
-            bucket = _bucket_len(len(req.prompt), self.max_len)
+            bucket = self._prompt_bucket(len(req.prompt))
             by_bucket.setdefault(bucket, []).append(req)
         for req in self._waiting:
             req.waited_for_slot = True
@@ -649,7 +655,7 @@ class ContinuousBatchingEngine:
         slot_map = dict(self._active)
         self._attn_len = attn_len
         self._step_args = self.cache.step_args(
-            len(slot_map), sum(self._slot_pos[s] for s in slot_map), attn_len)
+            [self._slot_pos[s] for s in slot_map], attn_len)
         self.lengths, self.tokens, report = self.cache.decode(
             self.params, self.lengths, self.tokens, attn_len, slot_map)
         for s in slot_map:  # an upper bound until the step's count is reaped

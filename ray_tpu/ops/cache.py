@@ -38,11 +38,15 @@ def uses_write_kernel(cache: jax.Array) -> bool:
             and 4 * L * kvh * R * hd * cache.dtype.itemsize <= _VMEM_BLOCKS_BYTES)
 
 
-def write_rows(cache: jax.Array, rows: jax.Array,
-               lengths: jax.Array) -> jax.Array:
+def write_rows(cache: jax.Array, rows: jax.Array, lengths: jax.Array,
+               writes: jax.Array = None) -> jax.Array:
     """The decode step's cache write: `rows[:, b]` goes to row `lengths[b]`
     of slot b in every layer and kv head. cache [L, B, kvh, max_len, hd],
-    rows [L, B, kvh, hd], lengths [B] -> cache.
+    rows [L, B, kvh, hd], lengths [B] -> cache. A cache whose rows are not
+    its positions (EVA's window region turns over, its summaries are a row
+    a chunk) passes the ROW in `lengths`' place and says which slots write
+    one: `writes` [B] bool (row 0 can then be written; without it a slot
+    writes iff its row is not 0).
 
     Written as a read-modify-write of the tile-aligned block of R rows that
     holds the position. A window of ONE row makes XLA:TPU's layout
@@ -64,11 +68,11 @@ def write_rows(cache: jax.Array, rows: jax.Array,
     the dense engine and of the runs form has length 0 and stays there; the
     hybrid model's idle slots count on from 0 and write rows nobody reads."""
     if uses_write_kernel(cache):
-        return _write_rows_kernel(cache, rows, lengths)
-    return _write_rows_loop(cache, rows, lengths)
+        return _write_rows_kernel(cache, rows, lengths, writes)
+    return _write_rows_loop(cache, rows, lengths, writes)
 
 
-def _write_rows_loop(cache, rows, lengths):
+def _write_rows_loop(cache, rows, lengths, writes=None):
     L, B, kvh, max_len, hd = cache.shape
     R = min(_tile_rows(cache), max_len)
     row_ids = jnp.arange(R)[:, None]
@@ -81,15 +85,16 @@ def _write_rows_loop(cache, rows, lengths):
         at = (0, b, 0, start, 0)
         block = jax.lax.dynamic_slice(cache, at, (L, 1, kvh, R, hd))
         new = jax.lax.dynamic_slice(rows, (0, b, 0, 0), (L, 1, kvh, hd))
-        # a row id is never negative: a slot of length 0 selects nothing
-        row = jnp.where(pos > 0, pos - start, -1)
+        # a row id is never negative: a slot that does not write (by
+        # default: of length 0) selects nothing
+        row = jnp.where(pos > 0 if writes is None else writes[b], pos - start, -1)
         block = jnp.where(row_ids == row, new[:, :, :, None], block)
         return jax.lax.dynamic_update_slice(cache, block, at)
 
     return jax.lax.fori_loop(0, B, write_slot, cache)
 
 
-def _walk(lengths: jax.Array, max_len: int, R: int):
+def _walk(lengths: jax.Array, max_len: int, R: int, writes=None):
     """The kernel's walk over the slots, as three [B] int32 arrays indexed
     by grid step (the idea of `decode_attention.live_blocks`): `src`, the
     slot whose block the step holds, the slots that write first and the
@@ -98,7 +103,9 @@ def _walk(lengths: jax.Array, max_len: int, R: int):
     a step that writes nothing. The pipeline moves a block only when its
     index changes, so a step that repeats one costs no DMA."""
     B = lengths.shape[0]
-    writes = (lengths > 0) & (lengths < max_len)
+    if writes is None:
+        writes = lengths > 0
+    writes = writes & (lengths < max_len)
     order = jnp.argsort(~writes, stable=True)
     step = jnp.arange(B)
     n = jnp.sum(writes)
@@ -122,7 +129,7 @@ def _kernel(src_ref, blk_ref, row_ref, rows_ref, cache_ref, out_ref):
         out_ref[...] = jnp.where(at, rows_ref[...][:, :, None, :], block)
 
 
-def _write_rows_kernel(cache, rows, lengths):
+def _write_rows_kernel(cache, rows, lengths, writes=None):
     L, B, kvh, max_len, hd = cache.shape
     R = _tile_rows(cache)
     new = pl.BlockSpec((L, None, kvh, hd),
@@ -143,4 +150,4 @@ def _write_rows_kernel(cache, rows, lengths):
             dimension_semantics=("arbitrary",)),
         name="write_rows",
         interpret=_util.interpret_mode(),
-    )(*_walk(lengths, max_len, R), rows, cache)
+    )(*_walk(lengths, max_len, R, writes), rows, cache)

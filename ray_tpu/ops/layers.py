@@ -11,13 +11,18 @@ import jax
 import jax.numpy as jnp
 
 
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-5) -> jax.Array:
-    """RMSNorm: x * w / sqrt(mean(x^2)). Computed in fp32, cast back."""
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-5,
+             unit_offset: bool = False) -> jax.Array:
+    """RMSNorm: x * w / sqrt(mean(x^2)). Computed in fp32, cast back.
+    `unit_offset`: the scale is (1 + w), the weight held around zero."""
     dtype = x.dtype
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     out = xf * jax.lax.rsqrt(var + eps)
-    return (out * weight.astype(jnp.float32)).astype(dtype)
+    scale = weight.astype(jnp.float32)
+    if unit_offset:
+        scale = 1.0 + scale
+    return (out * scale).astype(dtype)
 
 
 def rotary_embedding(positions: jax.Array, head_dim: int,

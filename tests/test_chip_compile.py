@@ -544,6 +544,88 @@ def test_pangu_prompt_pass_fits_beside_the_weights(chip):
     assert 9.5e9 < mem.argument_size_in_bytes < 9.6e9
 
 
+def _eva(chip):
+    """(cfg, parameter shapes, slot-state shapes, slots) of the EvaByte cell."""
+    import json
+    import sys
+
+    from ray_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from perfbench.lib import eva_model
+
+    with open(os.path.join(root, "perfbench", "configs",
+                           "evabyte-6.5b.1of4.json")) as f:
+        conf = json.load(f)
+    cfg = eva_model.model_config(conf)
+    slots, max_len = conf["run"]["num_slots"], conf["run"]["max_len"]
+    as_shapes = lambda tree: jax.tree_util.tree_map(
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = as_shapes(jax.eval_shape(lambda k: hybrid.init_params(k, cfg),
+                                      jax.random.PRNGKey(0)))
+    state = as_shapes(jax.eval_shape(lambda: cfg.make_cache(slots, max_len).state))
+    return cfg, params, state, slots
+
+
+def test_eva_decode_step_reads_live_rows_in_place(chip):
+    """The EVA decode step at the benchmark cell's real shapes (EvaByte
+    widths: 32 heads of 128 with keys of their own, 8 layers, 16 slots x
+    32768): both tables [8, 16, 32, 2048 + 2048, 128] (8.59 GB) alias their
+    output and no copy of either exists (`temp` 2 MB): Mosaic takes the
+    `eva_decode_attention` kernel at blocks of 256 rows x 32 heads (2 MB of
+    K and of V a step, under a raised VMEM limit) once a layer, and the
+    position's row and the closed chunk's summary are written by the
+    `write_rows` kernel at rows the step names, two calls a table."""
+    from ray_tpu.models import hybrid
+    from ray_tpu.ops import cache as cache_ops
+
+    cfg, params, state, slots = _eva(chip)
+    ints = chip((slots,), jnp.int32)
+    c = hybrid.decode_step.lower(params, state, ints, ints,
+                                 chip((slots,), jnp.bool_), cfg, 16384).compile()
+    table = state["ek"]
+    assert table.shape == (8, 16, 32, 4096, 128)
+    state_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(state))
+    assert 8.6e9 < state_bytes < 8.7e9
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < 64e6
+    assert "eva_decode_attention" in c.as_text()
+    assert cache_ops.uses_write_kernel(table)
+    assert _count(c, table, "custom-call") == 4   # K's and V's row and summary
+    assert _count(c, table, "dynamic-update-slice") == 0
+    _assert_cache_stays_put(c, table)
+
+
+def test_eva_prompt_pass_fits_beside_the_tables(chip):
+    """The longest prompt bucket (1 x 28,672: 14 windows) walks the windows,
+    each through all 8 layers, so its temporaries are those of ONE window
+    (under 1.6 GB beside 11.88 GB of weights and tables; 2.9 GB with every
+    window's hidden rows kept and the three projections' stacks copied
+    transposed ahead of the loops), and the admission write puts the rows
+    into the donated tables in place."""
+    from ray_tpu.models import hybrid
+
+    cfg, params, state, slots = _eva(chip)
+    one, toks = chip((1,), jnp.int32), chip((1, 28672), jnp.int32)
+    c = hybrid._prefill_first.lower(params, toks, one, cfg).compile()
+    mem = c.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.6e9
+    assert 3.2e9 < mem.argument_size_in_bytes < 3.3e9
+    assert _loops(c) >= 2
+    rows = jax.tree_util.tree_map(
+        lambda a: chip(a.shape, a.dtype),
+        jax.eval_shape(lambda p, t, n: hybrid._prefill_first(p, t, n, cfg),
+                       params, toks, one)[1])
+    assert rows["sum_k"].shape == (8, 1, 32, 1792, 128)
+    ints = chip((slots,), jnp.int32)
+    w = hybrid._write_state.lower(state, ints, ints, one, rows, one, one).compile()
+    assert w.memory_analysis().temp_size_in_bytes < 256e6
+    assert _whole_cache_relayouts(w, state["ek"]) == []
+
+
 def test_prefill_slots_compiles_at_b1(chip):
     from ray_tpu.models.serving import prefill_slots
 

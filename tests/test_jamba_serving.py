@@ -98,12 +98,12 @@ def test_an_inner_norm_left_out_fails(params, tokens, want, which, monkeypatch):
     three Mamba runs share one traced body)."""
     real, calls = hybrid.rms_norm, [0]
 
-    def skipping(x, w, eps):
+    def skipping(x, w, eps, *rest):
         if x.shape[-1] in (CFG.dt_rank, CFG.d_state):
             calls[0] += 1
             if (calls[0] - 1) % 3 == which:
                 return x
-        return real(x, w, eps)
+        return real(x, w, eps, *rest)
 
     monkeypatch.setattr(hybrid, "rms_norm", skipping)
     got = hybrid.forward.__wrapped__(params, tokens, CFG)   # traced anew
