@@ -31,7 +31,8 @@ from ray_tpu.core.exceptions import ObjectStoreFullError
 from ray_tpu.core.object_store import (SharedObjectStore,
                                        sweep_stale_spill_dirs)
 from ray_tpu.core.scheduler import NodeView, SchedulingPolicy
-from ray_tpu.core.chips import chip_visibility_env, default_compile_cache_dir
+from ray_tpu.core.chips import (chip_holders, chip_visibility_env,
+                                default_compile_cache_dir)
 from ray_tpu.core.runtime_env_manager import env_key as _env_key
 from ray_tpu.core.task_spec import TaskSpec, TaskType
 from ray_tpu.util import tracing
@@ -250,6 +251,9 @@ class Raylet:
             range(int(self.resources_total.get("TPU", 0))))
         # leases granted chips whose worker has not registered yet
         self._tpu_leases: List[_TpuLease] = []
+        # every process spawned for a chip grant, until it has been seen
+        # gone: `stop()` returns only once none of them holds a chip
+        self._chip_procs: List[subprocess.Popen] = []
         # TPU actor specs the GCS placed here, waiting for free chips
         self._tpu_waiting_actors: deque = deque()
         self._start_time = time.time()
@@ -688,6 +692,7 @@ class Raylet:
                         w.proc.kill()
                     except OSError:
                         pass  # exited between wait and kill
+        self._wait_for_chip_holders()
         if self._gcs:
             self._gcs.close()
         # snapshot under the lock: concurrent _peer() dials install into
@@ -1163,6 +1168,9 @@ class Raylet:
             argv = prefix + argv
         proc = subprocess.Popen(argv, env=env)
         with self._lock:
+            if tpu_lease is not None:
+                self._chip_procs = [p for p in self._chip_procs
+                                    if p.poll() is None] + [proc]
             if tpu_lease is not None and tpu_lease in self._tpu_leases:
                 tpu_lease.proc = proc
             elif tpu_lease is not None:
@@ -1871,6 +1879,28 @@ class Raylet:
             threading.Thread(target=release, daemon=True,
                              name="tpu-chip-release").start()
 
+    def _wait_for_chip_holders(self, bound_s: float = 30.0) -> None:
+        """The end of `stop()`: every process that was spawned for a chip
+        grant (a worker that holds its grant, one that is retiring after its
+        lease, one still starting) is gone before the session's end is
+        reported, so whoever opens the chips next does not race a backend
+        that is still closing: a worker with four chips and 10 GB on each
+        outlives SIGKILL by seconds. Bounded as `_release_chips_after` is."""
+        with self._lock:
+            procs, self._chip_procs = self._chip_procs, []
+        deadline = time.monotonic() + bound_s
+        for proc in procs:
+            try:
+                if proc.poll() is None:
+                    proc.kill()  # asked to exit 2 s ago, or retiring
+                proc.wait(timeout=max(0.0, deadline - time.monotonic()))
+            except subprocess.TimeoutExpired:
+                logger.warning("TPU worker pid %d still alive %.0f s after "
+                               "the session's end: its chips may be busy",
+                               proc.pid, bound_s)
+            except OSError:
+                pass  # already reaped
+
     def _release_chips(self, ids: List[int]) -> None:
         with self._lock:
             self._free_chips = sorted(set(self._free_chips) | set(ids))
@@ -1891,6 +1921,13 @@ class Raylet:
         a CPU environment, and a backend opened in a parent does not
         survive into a child). A spawn that raises fails the lease — its
         task was popped and charged and would otherwise hang its owner."""
+        held = self._foreign_chip_holders(lease.tpu_ids)
+        if held:
+            # off the scheduler's thread: the lease stays on record, granted
+            # and not started, where a cancel or a fence finds it
+            threading.Thread(target=self._start_once_chips_open, daemon=True,
+                             args=(lease, held), name="tpu-chip-wait").start()
+            return
         renv = lease.spec.runtime_env
         try:
             self._spawn_worker(_env_key(renv), renv, tpu_lease=lease)
@@ -1898,6 +1935,52 @@ class Raylet:
             logger.warning("could not spawn a worker for chips %s: %s",
                            lease.tpu_ids, e)
             self._fail_tpu_lease(lease, f"worker spawn failed: {e}")
+
+    def _foreign_chip_holders(self, tpu_ids: List[int]) -> Dict[str, int]:
+        """{device node: pid} of `tpu_ids`' nodes that a process this raylet
+        did not spawn holds open (`chips.chip_holders`): the worker of a
+        session that has just ended and is still handing its chips back,
+        or another program on the host. A neighbour's chips are not asked
+        about: a grant of some of a host's chips waits for those alone."""
+        with self._lock:
+            ours = {p.pid for p in self._chip_procs + self._starting}
+            ours.update(w.pid for w in self._workers.values())
+        return chip_holders(tpu_ids, ours)
+
+    # how long a granted lease waits for a foreign process to give its chips
+    # back before it fails, and how often it looks
+    _CHIP_WAIT_S, _CHIP_POLL_S = 60.0, 0.2
+
+    def _start_once_chips_open(self, lease: _TpuLease,
+                               held: Dict[str, int]) -> None:
+        """`lease`'s chips are free in this raylet's books but `held` open
+        by a process it did not spawn: a worker started now would die in
+        libtpu (`Device or resource busy`) inside the user's code. Look
+        again until the holder is gone, then start the lease and say on
+        stderr (this process is the driver's) how long that took; after
+        `_CHIP_WAIT_S` fail the lease, naming the holder."""
+        t0 = time.monotonic()
+        node, pid = next(iter(held.items()))
+        while held and time.monotonic() - t0 < self._CHIP_WAIT_S:
+            if self._shutdown.wait(self._CHIP_POLL_S):
+                return
+            with self._lock:
+                if lease not in self._tpu_leases:
+                    return  # taken back meanwhile, and settled by the taker
+            held = self._foreign_chip_holders(lease.tpu_ids)
+            if held:
+                node = next(iter(held))
+                pid = held[node] or pid  # exiting: pid 0; keep the name it had
+        waited = time.monotonic() - t0
+        who = f"pid {pid}" if pid else "a process that is exiting"
+        if held:
+            self._fail_tpu_lease(
+                lease, f"chips {lease.tpu_ids} are not free: {who} still "
+                f"holds {node} open after {waited:.0f} s")
+            return
+        print(f"[chips] waited {waited:.1f} s for {who} to release {node}",
+              file=sys.stderr, flush=True)
+        self._start_tpu_lease(lease)
 
     def _take_tpu_leases(self, match) -> List[_TpuLease]:
         """Take the leases `match` picks off the record (their worker has
@@ -1936,7 +2019,7 @@ class Raylet:
             except OSError as e:
                 logger.warning("actor_failed notify lost (GCS down?): %s", e)
         else:
-            self._notify_owner_worker_died(lease.spec)
+            self._notify_owner_worker_died(lease.spec, reason)
 
     # Bounded scheduling scan: _schedule runs on every task completion, so
     # an unbounded drain is O(queue) work per completion — O(n^2) for a

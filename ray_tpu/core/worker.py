@@ -2128,8 +2128,10 @@ class CoreWorker:
                 f"task {spec.method_name} was killed by the memory monitor "
                 f"under node memory pressure (retries exhausted)"))
         else:
+            why = payload.get("reason")
             err_blob = serialization.dumps(WorkerCrashedError(
-                f"worker died while running {spec.method_name}"))
+                f"worker died while running {spec.method_name}"
+                + (f": {why}" if why else "")))
         for oid in spec.return_object_ids():
             with self._obj_lock:
                 st = self._objects.get(oid)

@@ -966,7 +966,7 @@ def _decode_runs(params, state, lengths, tokens, cfg: HybridConfig,
     # TPU at shapes that tile, the kernel over each slot's live rows
     kernel = decode_attention.uses_decode_kernel(k_all, attn_len)
     if kernel:
-        blocks = decode_attention.live_blocks(lengths, attn_len)
+        items = decode_attention.live_items(lengths, attn_len)
     else:
         mask = jnp.arange(attn_len)[None, :] < lengths[:, None]
 
@@ -996,7 +996,7 @@ def _decode_runs(params, state, lengths, tokens, cfg: HybridConfig,
             if kernel:
                 attn = decode_attention.gqa_decode_attention(
                     q.reshape(B, kvh, H // kvh, hd), k_cur, v_cur, k_all, v_all,
-                    layer, blocks, attn_len)
+                    layer, items, attn_len)
             else:
                 win = (1, B, kvh, attn_len, hd)
                 attn = _gqa_decode_attention(

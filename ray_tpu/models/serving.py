@@ -233,9 +233,9 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
 
     `attn_len` is the static attention window (a power-of-2 bucket >= every
     active position): XLA compiles one executable per bucket. On the TPU it
-    is only the upper bound of the kernel's grid: each slot pays for the
-    blocks of rows it holds (`ops.pallas.decode_attention`), an idle slot
-    for none. On the CPU path every slot pays for the window.
+    only bounds the kernel's list of items: each slot pays for the blocks
+    of rows it holds (`ops.pallas.decode_attention`), an idle slot for
+    none. On the CPU path every slot pays for the window.
 
     A slot with length 0 is IDLE: it computes its self term alone (finite
     garbage nobody reads), writes no row and stays at 0. The engine zeroes a
@@ -257,7 +257,7 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
     # cache; elsewhere the einsums over the window of all slots
     kernel = decode_attention.uses_decode_kernel(k_all, attn_len)
     if kernel:
-        blocks = decode_attention.live_blocks(lengths, attn_len)
+        items = decode_attention.live_items(lengths, attn_len)
     else:
         mask = jnp.arange(attn_len)[None, :] < lengths[:, None]  # [B, attn_len]
         win = (1, B, cfg.n_kv_heads, attn_len, hd)
@@ -273,7 +273,7 @@ def decode_step_fused(params: Dict, k_all: jax.Array, v_all: jax.Array,
                 # the cache goes in WHOLE, the layer as a scalar: a sliced
                 # window cannot fuse into a Mosaic call and would be copied
                 attn = decode_attention.gqa_decode_attention(
-                    q, k_cur, v_cur, k_all, v_all, layer, blocks, attn_len)
+                    q, k_cur, v_cur, k_all, v_all, layer, items, attn_len)
             else:
                 # the layer's window, read straight out of the whole
                 # (loop-invariant) cache: one dynamic_slice fuses into the

@@ -205,6 +205,27 @@ def _loops(compiled) -> int:
                           compiled.as_text(), re.M))
 
 
+def _assert_attention_walks_its_items(c, n_calls=1):
+    """The compiled text says which form of `gqa_decode_attention` is in:
+    ONE call a layer body (the scan's `while` body holds one; a run of one
+    layer is inlined, so Jamba's two attention layers are two calls), whose
+    Mosaic module (the call's `body`, base64) starts and awaits its own
+    copies inside a loop over the live (slot, block) items and carries
+    neither a grid's `iteration_bounds` nor a pipelined block's
+    `window_params`: up to PR 44 it was a grid of (slots, blocks of the
+    window), a step each whether or not a block held a row."""
+    import base64
+
+    calls = [l for l in c.as_text().splitlines() if "gqa_decode_attention" in l
+             and 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == n_calls, len(calls)
+    for call in calls:
+        module = base64.b64decode(re.search(r'"body":"([^"]+)"', call).group(1))
+        assert b"dma_start" in module and b"dma_wait" in module
+        assert b"while" in module
+        assert b"iteration_bounds" not in module and b"window_params" not in module
+
+
 def _assert_decode_step_reads_live_rows_in_place(c, kv, n_kernels=1,
                                                  write_kernel=True):
     """The decode step on the chip: the length-aware attention kernel is in
@@ -294,6 +315,7 @@ def test_decode_step_keeps_the_cache_layout(chip, attn_len):
     c = decode_step_fused.lower(params, kv, kv, ints, ints, INTERNLM2,
                                 attn_len).compile()
     _assert_decode_step_reads_live_rows_in_place(c, kv)
+    _assert_attention_walks_its_items(c)
     assert _weight_relayouts_in_the_scan(c, params) == []
 
 
@@ -449,6 +471,7 @@ def test_jamba_decode_step_carries_its_state_in_place(chip):
     assert mem.temp_size_in_bytes < 64e6
     text = c.as_text()
     assert "gqa_decode_attention" in text and "selective_step" in text
+    _assert_attention_walks_its_items(c, n_calls=2)
     assert cache_ops.uses_write_kernel(state["k"])
     assert _count(c, state["k"], "custom-call") == 2   # K's and V's write
     assert _count(c, state["k"], "dynamic-update-slice") == 0

@@ -609,6 +609,212 @@ def test_tpu_worker_serves_one_lease_and_chip_frees_after_exit(
     assert len(got["tpu_ids"]) == 1
 
 
+class _SlowToDie:
+    """A stand-in for a worker's `Popen` whose process takes `seconds` to
+    go once it is told to, whatever the signal: a SIGKILLed worker with
+    four chips open is still closing them when `kill()` has returned."""
+
+    def __init__(self, seconds):
+        import subprocess
+        import sys
+
+        self._proc = subprocess.Popen([sys.executable, "-c", (
+            "import signal, sys, time\n"
+            f"signal.signal(signal.SIGTERM, lambda *a: (time.sleep({seconds}), sys.exit(0)))\n"
+            "print('up', flush=True)\ntime.sleep(60)")], stdout=subprocess.PIPE)
+        assert self._proc.stdout.readline().strip() == b"up"
+        self.pid = self._proc.pid
+        self.poll, self.wait = self._proc.poll, self._proc.wait
+        self.terminate = self._proc.terminate
+
+    def kill(self):
+        pass  # the signal is sent; the process is not gone yet
+
+    def really_kill(self):
+        self._proc.kill()
+        self._proc.wait()
+
+
+@pytest.mark.parametrize("holds_chips", [True, False])
+def test_stop_returns_once_the_chip_holders_are_gone(holds_chips):
+    """`shutdown()` returning means the chips can be opened: `stop()` waits
+    for a worker that was spawned for a chip grant until its PROCESS is
+    gone (here 3 s after SIGTERM, past the 2 s grace and the SIGKILL),
+    within its bound. A worker without chips keeps the old rule: 2 s of
+    grace, a SIGKILL, and no further wait."""
+    from ray_tpu.core import api
+    from ray_tpu.core.ids import WorkerID
+    from ray_tpu.core.raylet import WorkerHandle
+
+    ray_tpu.init(num_cpus=2, resources={"TPU": 8})
+    raylet = api._node.raylet
+    proc = _SlowToDie(3)
+    try:
+        wid = WorkerID.from_random()
+        with raylet._lock:
+            raylet._workers[wid] = WorkerHandle(
+                worker_id=wid, conn=None, address="", pid=proc.pid, proc=proc,
+                tpu_grant=[0] if holds_chips else None)
+            if holds_chips:  # as `_launch_worker` records a lease's process
+                raylet._chip_procs.append(proc)
+        t0 = time.monotonic()
+        ray_tpu.shutdown()
+        took = time.monotonic() - t0
+        if holds_chips:
+            assert proc.poll() is not None, "stop() returned before the holder was gone"
+            assert 2.9 < took < 10
+        else:
+            assert proc.poll() is None and took < 2.9
+    finally:
+        ray_tpu.shutdown()
+        proc.really_kill()
+
+
+def _hold_open(path):
+    """A child process that holds `path` open until it is killed."""
+    import subprocess
+    import sys
+
+    p = subprocess.Popen([sys.executable, "-c", (
+        f"import time; f = open({str(path)!r}); print('up', flush=True); "
+        "time.sleep(60)")], stdout=subprocess.PIPE)
+    assert p.stdout.readline().strip() == b"up"
+    return p
+
+
+@pytest.fixture
+def fake_dev(tmp_path, monkeypatch):
+    """A directory standing for `/dev`, with eight accel nodes."""
+    from ray_tpu.core import chips
+
+    for i in range(8):
+        (tmp_path / f"accel{i}").touch()
+    monkeypatch.setattr(chips, "_DEV", str(tmp_path))
+    return tmp_path
+
+
+@pytest.mark.parametrize("groups,tpu_ids,want", [
+    (["vfio", "0", "1", "2", "3"], [2], ["vfio/2"]),
+    (["vfio", "7", "11", "9"], [0, 2], ["vfio/7", "vfio/11"]),  # i-th group, by number
+    (["vfio", "0"], [0, 1], ["vfio/0"]),
+    ([], [0], []),
+])
+def test_a_chip_maps_to_its_device_node(tmp_path, monkeypatch, groups, tpu_ids, want):
+    from ray_tpu.core import chips
+
+    if groups:
+        (tmp_path / "vfio").mkdir()
+    for g in groups:
+        (tmp_path / "vfio" / g).touch()
+    monkeypatch.setattr(chips, "_DEV", str(tmp_path))
+    assert chips.chip_nodes(tpu_ids) == [str(tmp_path / w) for w in want]
+    assert chips.chip_holders(tpu_ids) == {}
+
+
+def test_the_probe_names_who_holds_a_chip(fake_dev):
+    from ray_tpu.core import chips
+
+    holder = _hold_open(fake_dev / "accel2")
+    try:
+        node = str(fake_dev / "accel2")
+        assert chips.chip_holders([2]) == {node: holder.pid}
+        assert chips.chip_holders([0, 1, 2, 3]) == {node: holder.pid}
+        assert chips.chip_holders([0, 1, 3]) == {}           # a neighbour's chip
+        assert chips.chip_holders([2], ours={holder.pid}) == {}  # our own child
+        with open(node):  # this process's own descriptor is not a holder
+            assert chips.chip_holders([2], ours={holder.pid}) == {}
+    finally:
+        holder.kill()
+        holder.wait()
+    assert chips.chip_holders([2]) == {}
+
+
+def test_the_probe_sees_a_holder_that_is_exiting(tmp_path, monkeypatch):
+    """A process that is exiting lists no descriptor and is still closing
+    the device: a vfio group then refuses to be opened (EBUSY), and reads
+    as held by a pid that is not known. A group some process lists is not
+    opened, and an accel node never is."""
+    import errno
+    import os
+
+    from ray_tpu.core import chips
+
+    (tmp_path / "vfio").mkdir()
+    for name in ("vfio/vfio", "vfio/0", "vfio/1", "vfio/2", "accel0"):
+        (tmp_path / name).touch()
+    monkeypatch.setattr(chips, "_DEV", str(tmp_path))
+    real, opened = os.open, []
+
+    def busy_open(path, *a, **kw):
+        if str(path).startswith(str(tmp_path)):
+            opened.append(os.path.relpath(path, tmp_path))
+            if path.endswith("vfio/2"):
+                raise OSError(errno.EBUSY, "Device or resource busy")
+        return real(path, *a, **kw)
+
+    holder = _hold_open(tmp_path / "vfio" / "1")
+    try:
+        monkeypatch.setattr(os, "open", busy_open)
+        assert chips.chip_holders([0, 1, 2]) == {
+            str(tmp_path / "vfio/1"): holder.pid, str(tmp_path / "vfio/2"): 0}
+        assert opened == ["vfio/0", "vfio/2"]
+    finally:
+        holder.kill()
+        holder.wait()
+
+
+@pytest.mark.parametrize("held,starts", [("accel0", "after_the_holder"),
+                                         ("accel5", "at_once"),
+                                         ("accel0", "never")])
+def test_a_lease_waits_for_a_foreign_holder_of_its_chips(
+        ray_start_regular, fake_dev, monkeypatch, capfd, held, starts):
+    """Chips that are free in the raylet's books but held open by a process
+    it did not spawn (the worker of a session that has just ended): the
+    lease is granted and NOT started while the holder lives, starts within
+    a second of its exit and says so on stderr; a held chip outside the
+    grant delays nothing; past the bound the lease fails, naming the pid."""
+    from ray_tpu.core import api
+    from ray_tpu.core.exceptions import WorkerCrashedError
+
+    raylet = api._node.raylet
+    if starts == "never":
+        monkeypatch.setattr(raylet, "_CHIP_WAIT_S", 1.0)
+    env_report = _env_reporter()
+
+    @ray_tpu.remote(num_tpus=1, max_retries=0)
+    def on_chip():
+        return env_report()
+
+    holder = _hold_open(fake_dev / held)
+    try:
+        ref = on_chip.remote()
+        if starts == "at_once":
+            assert ray_tpu.get(ref, timeout=60)["tpu_ids"] == [0]
+            assert "[chips] waited" not in capfd.readouterr().err
+            return
+        _wait_for(lambda: raylet._tpu_leases)
+        if starts == "never":
+            with pytest.raises(WorkerCrashedError, match=f"pid {holder.pid} still "
+                               f"holds {fake_dev / held} open"):
+                ray_tpu.get(ref, timeout=30)
+            _all_chips_back(raylet)
+            return
+        time.sleep(1.0)
+        lease = raylet._tpu_leases[0]
+        assert lease.tpu_ids == [0] and lease.proc is None  # granted, not started
+        holder.kill()
+        holder.wait()
+        t0 = time.monotonic()
+        _wait_for(lambda: not raylet._tpu_leases or raylet._tpu_leases[0].proc)
+        assert time.monotonic() - t0 < 1.0
+        assert ray_tpu.get(ref, timeout=60)["tpu_ids"] == [0]
+        err = capfd.readouterr().err
+        assert f"for pid {holder.pid} to release {fake_dev / held}" in err
+    finally:
+        holder.kill()
+        holder.wait()
+
+
 def _stall_tpu_spawns(monkeypatch, fail=False):
     """TPU workers spawned from here on never register (`sleep`), or do not
     spawn at all: the lease stays granted with its worker 'starting'."""

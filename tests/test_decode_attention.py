@@ -1,5 +1,6 @@
-"""The length-aware decode attention kernel (interpret mode on the CPU)
-against `_gqa_decode_attention`, the CPU path and the kernel's reference."""
+"""The length-aware decode attention kernel (interpret mode on the CPU, its
+copies and semaphores the interpreter's) against `_gqa_decode_attention`,
+the CPU path and the kernel's reference."""
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,7 @@ def small_blocks(monkeypatch):
     monkeypatch.setattr(decode_attention, "_BLOCK_ROWS", ROWS)
 
 
-def _inputs(lengths, rep, dtype, seed=0):
+def _inputs(lengths, rep, dtype, seed=0, KVH=KVH):
     B = len(lengths)
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     normal = lambda k, dims: jax.random.normal(k, dims, jnp.float32).astype(dtype)
@@ -32,7 +33,7 @@ def _kernel(q, k_cur, v_cur, k_all, v_all, lengths, attn_len):
     lengths = jnp.asarray(lengths, jnp.int32)
     return decode_attention.gqa_decode_attention(
         q, k_cur, v_cur, k_all, v_all, jnp.asarray(LAYER),
-        decode_attention.live_blocks(lengths, attn_len), attn_len)
+        decode_attention.live_items(lengths, attn_len), attn_len)
 
 
 def _reference(q, k_cur, v_cur, k_all, v_all, lengths, attn_len):
@@ -90,6 +91,69 @@ def test_an_idle_slot_gives_its_self_term(rep):
         np.testing.assert_array_equal(
             np.asarray(out[b], np.float32),
             np.broadcast_to(np.asarray(v_cur[b], np.float32)[:, None], out[b].shape))
+
+
+# the dense step's heads (InternLM2: 8 kv heads x 2 queries each) and the runs
+# form's (Jamba: ONE kv head x 20, padded to 24 sublanes); a whole tile of
+# queries a kv head, and a ratio that divides no tile
+HEADS = [(8, 2), (1, 20), (2, 8), (2, 3)]
+BATCHES = {
+    "ragged": [0, 1, ROWS - 1, ROWS, ROWS + 1, MAX_LEN],
+    "nothing_busy": [0, 0, 0, 0],
+    "everything_busy_and_full": [MAX_LEN] * 4,
+    "idle_between_busy": [0, 40, 0, 0, ROWS + 1, 0],
+}
+
+
+@pytest.mark.parametrize("kvh,rep", HEADS)
+@pytest.mark.parametrize("batch", list(BATCHES))
+def test_the_walk_at_both_callers_heads(batch, kvh, rep):
+    """One call walks every (slot, block) item that holds a row: against the
+    einsums, with NaN planted in every row at and past a length (an idle
+    slot's whole window), so a row that must not be read shows; an idle
+    slot's output is its self term, whichever slots around it are busy."""
+    lengths = BATCHES[batch]
+    q, k_cur, v_cur, k_all, v_all = _inputs(lengths, rep, jnp.bfloat16,
+                                            seed=3, KVH=kvh)
+    got = _kernel(q, k_cur, v_cur, _plant_nan(k_all, lengths),
+                  _plant_nan(v_all, lengths), lengths, MAX_LEN)
+    want = _reference(q, k_cur, v_cur, k_all, v_all, lengths, MAX_LEN)
+    assert got.shape == want.shape == (len(lengths), kvh, rep, HD)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+    for b, n in enumerate(lengths):
+        if n == 0:
+            np.testing.assert_array_equal(
+                np.asarray(got[b], np.float32),
+                np.broadcast_to(np.asarray(v_cur[b], np.float32)[:, None],
+                                got[b].shape))
+
+
+@pytest.mark.parametrize("lengths,attn_len,rows,want", [
+    # exactly the blocks that hold rows: 3 of slot 2, 1 of slot 4, 4 of slot 5
+    ([0, 0, 70, 0, 1, MAX_LEN, 0], MAX_LEN, 0,
+     [(2, 0), (2, 1), (2, 2), (4, 0), (5, 0), (5, 1), (5, 2), (5, 3)]),
+    ([0, 0, 0, 0], MAX_LEN, 0, []),
+    ([MAX_LEN] * 3, MAX_LEN, 0, [(b, j) for b in range(3) for j in range(4)]),
+    # a slot deeper than the window walks the window's blocks alone
+    ([MAX_LEN, 0, ROWS], 64, 0, [(0, 0), (0, 1), (2, 0)]),
+    # a block edge: `rows` rows are one block, one more row is two
+    ([ROWS - 1, ROWS, ROWS + 1], MAX_LEN, 0, [(0, 0), (1, 0), (2, 0), (2, 1)]),
+    # another block height
+    ([70, 0, 64], MAX_LEN, 64, [(0, 0), (0, 1), (2, 0)]),
+])
+def test_live_items_list_the_blocks_that_hold_rows(lengths, attn_len, rows, want):
+    """Slots ascending, a slot's blocks ascending, an idle slot nowhere; the
+    count; and each slot's rows inside the window."""
+    slot, block, count, held = (np.asarray(a) for a in decode_attention.live_items(
+        jnp.asarray(lengths, jnp.int32), attn_len, rows))
+    n_max = len(lengths) * (attn_len // (rows or ROWS))
+    assert slot.shape == block.shape == (n_max,) and count.shape == (1,)
+    assert count[0] == len(want)
+    assert list(zip(slot[:count[0]].tolist(), block[:count[0]].tolist())) == want
+    assert held.tolist() == [min(n, attn_len) for n in lengths]
+    # what lies past the count is never read, and is a valid index all the same
+    assert ((0 <= slot) & (slot < len(lengths))).all() and (block[count[0]:] == 0).all()
 
 
 def test_live_blocks_repeat_what_is_already_fetched():
