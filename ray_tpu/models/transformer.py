@@ -218,6 +218,14 @@ def _embed_lookup(emb: Any, tokens: jax.Array, dtype) -> jax.Array:
     return emb[tokens].astype(dtype)
 
 
+def _norm(x, scale, eps: float):
+    """`rms_norm(x, scale)`; in training on a mesh the scale may come as an
+    `fsdp.UnreducedScale` (each rank's own copy: `_exchanged_dims`)."""
+    if isinstance(scale, fsdp.UnreducedScale):
+        return scale.apply(functools.partial(rms_norm, eps=eps), x)
+    return rms_norm(x, scale, eps)
+
+
 def _project_qkv(cfg: ModelConfig, p, x, cos, sin, rows_mesh=None):
     """rmsnorm(x) -> q [b, s, heads, hd], k, v [b, s, kv_heads, hd], q and k
     rotated. The one spelling of the block's projections: training, prefill,
@@ -226,7 +234,7 @@ def _project_qkv(cfg: ModelConfig, p, x, cos, sin, rows_mesh=None):
     whose `tp` axis x's rows ride sharded (`_rows_mesh`), else None."""
     b, s, _ = x.shape
     hd = cfg.head_dim
-    h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
+    h = _norm(x, p["attn_norm"], cfg.norm_eps)
     if rows_mesh is None:
         q, k, v = h @ p["wq"], h @ p["wk"], h @ p["wv"]
     else:  # one gather of the rows serves the three products
@@ -243,7 +251,7 @@ def _mlp(cfg: ModelConfig, p, x, rows_mesh=None):
     """rmsnorm(x) -> the FFN's output (no residual) and the experts'
     auxiliary loss (None for the dense FFN; only training reads it).
     `rows_mesh` as in `_project_qkv`."""
-    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+    h = _norm(x, p["mlp_norm"], cfg.norm_eps)
     if cfg.n_experts > 0:
         from ray_tpu.ops.moe import moe_ffn
 
@@ -302,6 +310,9 @@ def _layer(cfg: ModelConfig, mesh, x, layer_params, cos, sin):
             jnp.zeros((), jnp.float32) if aux is None else aux)
 
 
+_NORM_SCALES = ("attn_norm", "mlp_norm")  # a layer's replicated leaves
+
+
 def _exchanged_dims(cfg: ModelConfig, mesh, batch: int) -> Dict[str, int]:
     """The weights of one layer whose gradient's reduction over `fsdp` the
     program spells itself (parallel/fsdp.py) and does not leave to the
@@ -351,6 +362,20 @@ def grad_exchanges_per_layer(cfg: ModelConfig, mesh, batch: int) -> int:
     return len(_exchanged_dims(cfg, mesh, batch))
 
 
+def norm_grad_reductions_in_layers(cfg: ModelConfig, mesh, batch: int) -> int:
+    """How many of a layer's norm scales have their gradient summed over the
+    ranks that split the residual's rows INSIDE the layers' backward, an
+    all-reduce of the partitioner's on the compute stream: both on a mesh
+    that splits the rows (`dp`, `fsdp`, `sp`), but 0 where the layer's weights
+    come exchanged (`_exchanged_dims`): there the scales ride once a rank
+    (`fsdp.scale_by_rank`) and the sums are taken once a step behind the
+    scan (the train step's `xla.compile` spans carry it)."""
+    if mesh is None or _exchanged_dims(cfg, mesh, batch):
+        return 0
+    split = math.prod(mesh.shape.get(a, 1) for a in (*fsdp.BATCH_AXES, "sp"))
+    return len(_NORM_SCALES) if split > 1 else 0
+
+
 def maybe_remat(layer_fn, cfg: ModelConfig):
     """Wrap a layer body per cfg.remat: "full" recomputes everything in the
     backward pass; "dots" keeps matmul outputs resident and recomputes only
@@ -388,9 +413,12 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
     `mesh` is required when a sequence-parallel scheme is active
     (`cfg.seq_parallel`: the sp shard_map needs it); everything else is
     pure sharding-annotation-driven SPMD, but for the sum of the dense
-    block's weight gradients over `fsdp` (`_exchanged_dims`) and its gathers
-    and scatters over `tp` (`_rows_mesh`), which take the mesh too and
-    without it are the partitioner's.
+    block's weight gradients over `fsdp` (`_exchanged_dims`), its gathers
+    and scatters over `tp` (`_rows_mesh`) and, wherever the first holds, the
+    sums of its two norm scales' gradients over the ranks that split the
+    rows, taken once a step behind the scan
+    (`norm_grad_reductions_in_layers`): all take the mesh too and without
+    it are the partitioner's.
     """
     if positions is None:
         positions = jnp.arange(tokens.shape[1])
@@ -401,6 +429,7 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
     cos, sin = rotary_embedding(positions, cfg.head_dim, cfg.rope_theta)
     cos, sin = cos[None], sin[None]  # add batch dim
 
+    layers = params["layers"]
     if cfg.fused_attn and not cfg.fused_ffn:
         raise ValueError("fused_attn requires fused_ffn")
     if cfg.fused_ffn:
@@ -428,9 +457,16 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
         layer = functools.partial(_layer, cfg, mesh)
         dims = _exchanged_dims(cfg, mesh, x.shape[0])
         if dims:
+            # the norm scales ride once a rank of the axes that split x's
+            # rows, so their gradients leave the scan as partial sums
+            seq_axis = None if rows_mesh is None else tp.AXIS
+            layers = {k: fsdp.scale_by_rank(v, mesh, seq_axis)
+                      if k in _NORM_SCALES else v for k, v in layers.items()}
+
             def layer(x, lp, cos, sin):
                 lp = {k: fsdp.ExchangedWeight(v, dims[k], mesh) if k in dims
-                      else v for k, v in lp.items()}
+                      else fsdp.UnreducedScale(v, mesh, seq_axis)
+                      if k in _NORM_SCALES else v for k, v in lp.items()}
                 return _layer(cfg, mesh, x, lp, cos, sin)
         layer_fn = maybe_remat(layer, cfg)
 
@@ -440,7 +476,7 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
             return (x, aux + layer_aux), None
 
     (x, aux_total), _ = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), params["layers"])
+        body, (x, jnp.zeros((), jnp.float32)), layers)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if rows_mesh is not None:
         x = tp.whole_rows(x, rows_mesh)  # the head's program is the partitioner's

@@ -25,15 +25,25 @@ n - 1 permutes, each rank ending with its own shard. Those sums are in the
 gradient's dtype over the same ranks as the partitioner's. A product's sum
 over a dimension that `fsdp` shards is taken whole in float32 and rounded
 once, as the product by the gathered weight is (`ring_products`).
+
+The two norm scales of a layer are replicated leaves, and each rank makes a
+partial `dw` of its own rows: left to the partitioner that is one more
+all-reduce in the backward's scan body, of 8 KB and on the compute stream,
+where every chip waits for the slowest (13 ms of a 319 ms step at those
+widths). Nothing in the layer needs the sum. So a scale goes into the scan
+once a rank (`scale_by_rank`) and into the block as an `UnreducedScale`,
+whose cotangent is each rank's own partial sum; the sum over the ranks is the
+broadcast's transpose, once a step behind the scan, in float32.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 AXIS = "fsdp"
 BATCH_AXES = ("dp", AXIS)  # the batch dimension's mesh axes, outermost first
@@ -64,6 +74,41 @@ class ExchangedWeight:
 
     def __rmatmul__(self, x: jax.Array) -> jax.Array:
         return _matmul(x, self.w, self.dim, self.mesh)
+
+
+def scale_by_rank(w: jax.Array, mesh, seq_axis) -> jax.Array:
+    """A stacked norm scale [layers, d] -> [layers, ranks, d] in float32,
+    one row for each rank of the axes that split the rows of the residual
+    [batch over (dp, fsdp), seq over `seq_axis` (or whole: None), d], each
+    rank holding its own. The transpose is the sum of the ranks' partial
+    gradients, in float32 and rounded once to the leaf's dtype (the
+    partitioner's all-reduce adds the rounded partials)."""
+    axes = BATCH_AXES + ((seq_axis,) if seq_axis else ())
+    ranks = math.prod(mesh.shape[a] for a in axes)
+    rows = jnp.broadcast_to(w.astype(jnp.float32)[:, None],
+                            (w.shape[0], ranks, w.shape[1]))
+    return jax.lax.with_sharding_constraint(
+        rows, NamedSharding(mesh, P(None, axes)))
+
+
+class UnreducedScale:
+    """Stands where a norm's scale [d] stands in `norm(x, scale)`; `rows`
+    [ranks, d] is one layer's slice of `scale_by_rank`'s stack. x is seen as
+    [ranks of the batch, rows, ranks of the sequence, rows, d] and each
+    rank's rows are scaled by its own copy, so the cotangent of `rows` is
+    each rank's partial sum over its own rows of x and no collective stands
+    where the norm runs."""
+
+    def __init__(self, rows: jax.Array, mesh, seq_axis):
+        self.rows, self.mesh, self.seq_axis = rows, mesh, seq_axis
+
+    def apply(self, norm, x: jax.Array) -> jax.Array:
+        """norm(x, scale) -> [batch, seq, d], sharded as x is."""
+        b, s, d = x.shape
+        nb = math.prod(batch_split(self.mesh))
+        ns = self.mesh.shape[self.seq_axis] if self.seq_axis else 1
+        by_rank = x.reshape(nb, b // nb, ns, s // ns, d)
+        return norm(by_rank, self.rows.reshape(nb, 1, ns, 1, d)).reshape(b, s, d)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))

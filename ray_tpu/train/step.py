@@ -10,9 +10,12 @@ ICI/DCN from the sharding annotations. Two the model spells itself, as
 permutes that run behind matmuls where the partitioner's all-reduce blocks
 the compute stream: with fsdp > 1 the dense block's weight gradients are
 summed over fsdp by `parallel/fsdp.py`, and with tp > 1 its gathers and
-scatters over tp ride inside the products (`parallel/tp.py`). The step's
+scatters over tp ride inside the products (`parallel/tp.py`). A third the
+model moves: there the two norm scales' gradients leave the layers' scan as
+each rank's partial sums and are all-reduced once a step, where the
+partitioner's reduction waits for every chip once a layer. The step's
 `xla.compile` spans say which forms it has (`grad_exchanges_per_layer`,
-`tp_exchanges_per_layer`).
+`tp_exchanges_per_layer`, `norm_grad_reductions_in_layers`).
 """
 
 from __future__ import annotations
@@ -31,6 +34,7 @@ from ray_tpu.models.transformer import (
     grad_exchanges_per_layer,
     init_params,
     loss_fn,
+    norm_grad_reductions_in_layers,
     param_logical_axes,
     split_batch,
     tp_exchanges_per_layer,
@@ -152,16 +156,18 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh,
     b_sh = batch_sharding(mesh)
 
     def step(state: TrainState, batch: Dict[str, jax.Array]):
-        # which form of the gradients' reduction over fsdp and of the block's
-        # reductions over tp this program has is a fact of its compile: on
-        # its `xla.compile` spans
+        # which form of the gradients' reduction over fsdp, of the block's
+        # reductions over tp and of the norm scales' this program has is a
+        # fact of its compile: on its `xla.compile` spans
         inputs = split_batch(batch)[0]
         tracing.note_compile(
             "step", fsdp=mesh.shape.get("fsdp", 1), tp=mesh.shape.get("tp", 1),
             grad_exchanges_per_layer=grad_exchanges_per_layer(
                 cfg, mesh, inputs.shape[0]),
             tp_exchanges_per_layer=tp_exchanges_per_layer(
-                cfg, mesh, *inputs.shape))
+                cfg, mesh, *inputs.shape),
+            norm_grad_reductions_in_layers=norm_grad_reductions_in_layers(
+                cfg, mesh, inputs.shape[0]))
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, batch, cfg, mesh)
         # (the phases before this one are named in models/transformer.py:

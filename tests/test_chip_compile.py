@@ -752,16 +752,13 @@ def _assert_grad_exchange_runs_behind_the_backward(comps):
     the weight gradients' reduction over fsdp: the whole [4096,7168]
     gradient padded, all-reduced and sliced ON the compute stream, 0.76 ms
     each, seven a layer) and no all-reduce over the fsdp pairs {{0,2},{1,3}}
-    of anything larger than a norm vector; instead seven
+    at all; instead seven
     `collective-permute-start` ... `-done` pairs of exact shards
     ([1,2048,7168] x 2, [1,7168,2048], [1,2048,2048] x 2, [1,2048,512] x 2),
     each with a matmul fusion scheduled between its start and its done."""
     _, body = _scan_bodies(comps)
     assert not [l for l in body if "all-reduce-scatter" in l]
-    for l in body:
-        if " all-reduce(" in l and "{{0,2},{1,3}}" in l:
-            dims = re.search(r"= \(?\w+\[([\d,]*)\]", l).group(1)
-            assert math.prod(map(int, dims.split(","))) <= MISTRAL.d_model, l
+    assert not [l for l in body if " all-reduce(" in l and "{{0,2},{1,3}}" in l]
     exchanges = [v for v in _permutes(comps, body, _FSDP_PAIRS).values()
                  if v[0].count(",") == 2]
     assert sorted(dims for dims, _ in exchanges) == sorted(
@@ -819,11 +816,36 @@ def _assert_tp_exchanges_run_behind_the_products(comps):
         assert set(covers) <= {"matmul", other}, moved
 
 
+_ALL_REDUCE = re.compile(r" all-reduce(-start)?\(")
+
+
+def _assert_no_all_reduce_in_the_layers(comps):
+    """Neither scan body holds an all-reduce of any kind. The last one was
+    the two norm scales' gradients': a replicated [4096] leaf, each chip's
+    partial sum of its own rows, all-reduced over all four chips in the
+    backward's body, 8 KB a layer on the compute stream, where every chip
+    waited for the slowest (13.3 ms of a 319 ms step; PERF.md section 6,
+    PR 46). The scales ride once a rank now (`fsdp.scale_by_rank`), the
+    scan stacks the partial sums, and the sum over the ranks stands ONCE in
+    the module, outside the bodies: a [layers, 4096] operand a leaf (the
+    compiler makes one all-reduce of the two, `final_norm`'s with them)."""
+    bodies = _scan_bodies(comps)
+    for body in bodies:
+        assert not [l for l in body if _ALL_REDUCE.search(l)]
+    sums = [dims for lines in comps.values()
+            if not any(lines is body for body in bodies)
+            for l in lines if _ALL_REDUCE.search(l)
+            for dims in re.findall(rf"\w+\[(\d+),{MISTRAL.d_model}\]",
+                                   _ALL_REDUCE.split(l)[0])]
+    assert len(sums) == 2 and len(set(sums)) == 1, sums
+
+
 _CELL_STEP_ASSERTIONS = {
     "grad_exchange_behind_the_backward": _assert_grad_exchange_runs_behind_the_backward,
     "no_tp_all_reduce_in_the_layers": _assert_no_tp_all_reduce_in_the_layers,
     "tp_exchanges_behind_the_products": _assert_tp_exchanges_run_behind_the_products,
     "weights_ride_the_fsdp_ring": _assert_weights_ride_the_fsdp_ring,
+    "no_all_reduce_in_the_layers": _assert_no_all_reduce_in_the_layers,
 }
 _TWO_LAYERS = {}  # the compiled text of one compile, for the cases below
 
@@ -831,7 +853,7 @@ _TWO_LAYERS = {}  # the compiled text of one compile, for the cases below
 @pytest.mark.parametrize("what", list(_CELL_STEP_ASSERTIONS))
 def test_four_chip_cell_step_exchanges_behind_matmuls(topo, chip, what):
     """Two layers of the 4-chip cell's step (twenty seconds, compiled once
-    for the four cases; the whole 22 are the slow case below)."""
+    for the five cases; the whole 22 are the slow case below)."""
     from ray_tpu.train.step import default_optimizer
 
     if not _TWO_LAYERS:

@@ -206,20 +206,24 @@ def test_a_programs_own_trace_survives_and_the_inner_ones_do_not():
     assert traces == ["outer_program", "inner_program"], traces
 
 
-@pytest.mark.parametrize("mesh_cfg,n,seq,exchanges,tp_exchanges", [
-    ({"dp": 1, "fsdp": 2, "tp": 2}, 4, 16, 7, 4), ({"dp": 1}, 1, 16, 0, 0),
-    ({"dp": 2, "fsdp": 1, "tp": 2}, 4, 16, 0, 0),
-    ({"dp": 1, "fsdp": 2, "tp": 2}, 4, 15, 7, 0)],
+@pytest.mark.parametrize("mesh_cfg,n,seq,exchanges,tp_exchanges,norm_sums", [
+    ({"dp": 1, "fsdp": 2, "tp": 2}, 4, 16, 7, 4, 0), ({"dp": 1}, 1, 16, 0, 0, 0),
+    ({"dp": 2, "fsdp": 1, "tp": 2}, 4, 16, 0, 0, 2),
+    ({"dp": 1, "fsdp": 2, "tp": 2}, 4, 15, 7, 0, 0)],
     ids=["fsdp2xtp2", "one_device", "dp2xtp2", "fsdp2xtp2_odd_seq"])
 def test_train_steps_compile_spans_say_which_reduction_ran(
-        mesh_cfg, n, seq, exchanges, tp_exchanges):
+        mesh_cfg, n, seq, exchanges, tp_exchanges, norm_sums):
     """Whether the program spells the weight gradients' exchange over fsdp
     itself (parallel/fsdp.py), and the block's gathers and scatters over tp
     (parallel/tp.py), is a fact of its compile: the train step's trace, lower
     and compile spans carry the mesh's `fsdp` and `tp`, how many of a layer's
     weights go the first way (7 on fsdp 2 x tp 2, else 0) and how many of its
     forward's transfers over tp the second (4 where fsdp > 1 as well and
-    tp > 1 divides the sequence, else 0)."""
+    tp > 1 divides the sequence, else 0), and how many of its two norm
+    scales' gradients are still summed across the chips inside the layers'
+    backward (2 on a mesh that splits the rows, but 0 wherever the weights
+    come exchanged: there the partial sums leave the scan and are summed
+    once a step; 0 on one device, where nothing is summed)."""
     from ray_tpu.models import ModelConfig
     from ray_tpu.parallel.mesh import MeshConfig, make_mesh
     from ray_tpu.train import batch_sharding, make_train_step
@@ -243,9 +247,10 @@ def test_train_steps_compile_spans_say_which_reduction_ran(
             "backend_compile_duration"} <= {a["event"] for a in spans}, spans
     for a in spans:
         assert (a["fsdp"], a["tp"], a["grad_exchanges_per_layer"],
-                a["tp_exchanges_per_layer"]) == (
+                a["tp_exchanges_per_layer"],
+                a["norm_grad_reductions_in_layers"]) == (
             mesh_cfg.get("fsdp", 1), mesh_cfg.get("tp", 1), exchanges,
-            tp_exchanges), a
+            tp_exchanges, norm_sums), a
 
 
 @pytest.mark.parametrize("name", ["engine.step", "engine.between_steps"])

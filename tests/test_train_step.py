@@ -63,6 +63,21 @@ def test_train_step_sharded(mesh_cfg):
     assert int(jax.device_get(state.step)) == 5
 
 
+def _assert_norm_scale_gradients_come_whole(cfg, mesh, got, p_sh):
+    """The two norm scales' gradients ride through the scan once a rank
+    where the weights come exchanged (their sum over the ranks is taken
+    behind it: none is left in the layers), and come back as the leaves
+    are: [layers, d], replicated."""
+    from ray_tpu.models import transformer
+
+    assert transformer.norm_grad_reductions_in_layers(cfg, mesh, 8) == 0
+    for k in ("attn_norm", "mlp_norm"):
+        g = got["layers"][k]
+        assert g.shape == (cfg.n_layers, cfg.d_model) and g.dtype == cfg.dtype
+        assert g.sharding.is_equivalent_to(p_sh["layers"][k], g.ndim), k
+        assert g.sharding.is_fully_replicated, k
+
+
 @pytest.mark.parametrize("mesh_cfg,n", [
     (MeshConfig(dp=1, fsdp=2, tp=2), 4),
     (MeshConfig(dp=1, fsdp=4, tp=2), 8),
@@ -99,6 +114,7 @@ def test_grad_exchange_over_fsdp_matches_one_device(mesh_cfg, n, monkeypatch):
             np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-6 * float(jnp.abs(w).max()),
             err_msg=jax.tree_util.keystr(path)), got, want)
     assert got["layers"]["wo"].sharding.spec == p_sh["layers"]["wo"].spec
+    _assert_norm_scale_gradients_come_whole(cfg, mesh, got, p_sh)
 
     ours = _five_losses(cfg, mesh, on_mesh)
     monkeypatch.setattr(transformer, "_exchanged_dims", lambda *a: {})
@@ -157,11 +173,70 @@ def test_tp_exchange_matches_one_device(mesh_cfg, n, monkeypatch):
         lambda path, g, h: np.testing.assert_equal(
             g.sharding.is_equivalent_to(h, g.ndim), True,
             err_msg=jax.tree_util.keystr(path)), got["layers"], p_sh["layers"])
+    _assert_norm_scale_gradients_come_whole(cfg, mesh, got, p_sh)
 
     losses = _five_losses(cfg, mesh, on_mesh)
     monkeypatch.setattr(transformer, "_rows_mesh", lambda *a: None)
     np.testing.assert_allclose(losses, _five_losses(cfg, mesh, on_mesh), rtol=1e-5)
     assert losses[-1] < losses[0]
+
+
+@pytest.mark.parametrize("dtype", ["bf16_scales", "bf16"])
+@pytest.mark.parametrize("mesh_cfg,n", [
+    (MeshConfig(dp=1, fsdp=2, tp=2), 4), (MeshConfig(dp=2, fsdp=2, tp=1), 4),
+], ids=["fsdp2xtp2", "dp2xfsdp2"])
+def test_norm_scale_gradients_in_bf16_are_no_further_from_float32(
+        mesh_cfg, n, dtype, monkeypatch):
+    """The sum of the ranks' partial norm-scale gradients is no less exact
+    than the partitioner's all-reduce in the layers was (the same mesh with
+    the scales left as they are, switched here): it is taken in float32 and
+    rounded once to the leaf's dtype, where that one adds partials already
+    rounded. Against one device's float32 gradients at the same weights:
+    `bf16_scales` (a float32 program whose two norm leaves alone are
+    bfloat16, so the partials are exact and the sum is all that rounds):
+    every element within half a bfloat16 step, and no further on average;
+    `bf16` (the whole program in bfloat16, as the four-chip cell runs it:
+    its own rounding, the same on both sides, is nearly all of the distance
+    and moves single elements either way, so the two means tie to a tenth
+    or so; a rank left out or a coarser sum would read many times further):
+    within a quarter."""
+    import dataclasses
+
+    from ray_tpu.models import transformer
+    from ray_tpu.train.step import state_shardings
+
+    scales = transformer._NORM_SCALES
+    cfg32 = dataclasses.replace(ModelConfig.tiny(), n_kv_heads=2)
+    cfg = (cfg32 if dtype == "bf16_scales"
+           else dataclasses.replace(cfg32, dtype=jnp.bfloat16))
+    mesh = make_virtual_mesh(n, mesh_cfg)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    # scales away from one, as a trained model's are
+    for k, key in zip(scales, jax.random.split(jax.random.PRNGKey(2))):
+        params["layers"][k] = (1 + 0.1 * jax.random.normal(
+            key, params["layers"][k].shape)).astype(jnp.bfloat16)
+    batch = _batch(jax.random.PRNGKey(1), cfg, batch=8, seq=64)
+    grad = lambda m, c: jax.jit(jax.grad(lambda p, b: loss_fn(p, b, c, m)[0]))
+    want = grad(None, cfg32)(jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.float32), params), batch)["layers"]
+
+    b_sh = batch_sharding(mesh)
+    on_mesh = jax.device_put(batch, {k: b_sh[k] for k in batch})
+    placed = jax.device_put(
+        params, state_shardings(cfg, mesh, default_optimizer()).params)
+    ours = grad(mesh, cfg)(placed, on_mesh)["layers"]
+    monkeypatch.setattr(transformer, "_NORM_SCALES", ())
+    theirs = grad(mesh, cfg)(placed, on_mesh)["layers"]
+    for k in scales:
+        assert ours[k].dtype == theirs[k].dtype == jnp.bfloat16
+        far = lambda got: np.abs(np.asarray(got[k].astype(jnp.float32))
+                                 - np.asarray(want[k]))
+        if dtype == "bf16":
+            assert far(ours).mean() <= 1.25 * far(theirs).mean(), k
+            continue
+        half_step = 2.0 ** -8 * np.abs(np.asarray(want[k])) * 1.01 + 1e-9
+        assert (far(ours) <= half_step).all(), k
+        assert far(ours).mean() <= far(theirs).mean(), k
 
 
 @pytest.mark.parametrize("why", ["seq_not_divisible", "ring", "experts", "fsdp1"])
