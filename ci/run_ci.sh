@@ -65,7 +65,9 @@
 #                    tokens/s + slot sweep + w8a16 parity + batched prefill
 #                    + p50/p99 under the storm load generator; fails on any
 #                    missing artifact row (regression FLOORS live in
-#                    tests/test_envelope.py, machine-calibrated).
+#                    tests/test_envelope.py, machine-calibrated). Then the
+#                    envelope's own wall-clock floors, alone:
+#                    tests/test_envelope.py::test_envelope_floors (slow).
 #  11. trainstorm  : RL fleet chaos (quick profile): serve-deployed rollout
 #                    replicas -> checkpointed learner actor, weight-epoch-
 #                    fenced broadcasts, under composed chaos (seeded replica
@@ -282,6 +284,11 @@ run_servebench() {
   timeout -k 10 600 env JAX_PLATFORMS=cpu python -m ray_tpu.models.servebench \
     --json /tmp/ray_tpu_servebench_ci.json \
     || { echo "servebench failed"; exit 1; }
+  # the envelope's wall-clock rate floors (r05 / r06 / r14, the raw-bytes
+  # lane): nothing else may share the machine while they are read
+  timeout -k 10 300 env JAX_PLATFORMS=cpu python -m pytest \
+    tests/test_envelope.py::test_envelope_floors -q -m slow \
+    || { echo "envelope floors failed"; exit 1; }
 }
 
 run_trainstorm() {

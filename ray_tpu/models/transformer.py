@@ -3,15 +3,13 @@
 Functional JAX, TPU-first:
   - parameters are a plain pytree with *logical axis* annotations
     (`param_logical_axes`) mapped to mesh axes by `ray_tpu.parallel.AxisRules`
-    — dp/fsdp/tp/sp shardings are data, not code;
+    — dp/fsdp/tp shardings are data, not code;
   - layers are stacked on a leading axis and iterated with `lax.scan`
     (one compiled layer body regardless of depth — fast compiles, and
     `jax.checkpoint` on the body gives per-layer rematerialization);
   - bfloat16 activations/weights with fp32 RMSNorm statistics and fp32
     logits for the softmax-cross-entropy;
-  - attention is the pallas flash kernel on TPU; with sequence parallelism
-    (mesh sp>1) it switches to ring attention (K/V ppermute rotation) or
-    Ulysses (head<->seq all-to-all) over the sp axis per cfg.seq_parallel.
+  - attention is the pallas flash kernel on TPU.
 
 The reference has no model zoo of its own (it delegates to torch; SURVEY
 §2.4) — this model is the equivalent of the torch models its Train/RLlib
@@ -49,9 +47,6 @@ class ModelConfig:
     dtype: Any = jnp.bfloat16
     remat: str = "full"          # "none" | "full" | "dots" (see maybe_remat)
     loss_chunk: int = 0          # >0: chunked cross-entropy (seq chunk size)
-    # sequence-parallel scheme when sp > 1: "ring" (K/V rotation via
-    # ppermute) or "ulysses" (head<->seq all-to-all); "" = dense attention.
-    seq_parallel: str = ""
     tie_embeddings: bool = False
     # Mixture of Experts: n_experts > 0 replaces the dense FFN with a
     # top-2-gated MoE (ops/moe.py); experts shard over the "expert" axis.
@@ -63,7 +58,7 @@ class ModelConfig:
     # chains into the matmuls; remat then covers only the attention half
     # (the block saves its own dots-policy-equivalent residuals, and a
     # custom_vjp inside jax.checkpoint would re-run its forward matmuls).
-    # Dense-FFN, non-sequence-parallel path only.
+    # Dense-FFN path only.
     fused_ffn: bool = False
     # Fused attention backward (ops/pallas/fused_attn.py): the attention
     # half runs as a custom_vjp saving post-rotary q/k, v, the flash
@@ -275,23 +270,7 @@ def _attn_half(cfg: ModelConfig, mesh, x, p, cos, sin):
     q, k, v = _project_qkv(cfg, p, x, cos, sin, rows_mesh)
     # [b, heads, s, hd]
     q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-    if cfg.seq_parallel == "ring":
-        from ray_tpu.ops.ring_attention import ring_attention_sharded
-
-        rep = cfg.n_heads // cfg.n_kv_heads
-        if rep > 1:
-            k = jnp.repeat(k, rep, axis=1)
-            v = jnp.repeat(v, rep, axis=1)
-        attn = ring_attention_sharded(mesh, q, k, v, causal=True)
-    elif cfg.seq_parallel == "ulysses":
-        # GQA expansion happens inside the kernel, after the all-to-all —
-        # KV heads cross ICI unexpanded
-        from ray_tpu.ops.ulysses import ulysses_attention_sharded
-
-        attn = ulysses_attention_sharded(mesh, q, k, v, causal=True)
-    elif cfg.seq_parallel:
-        raise ValueError(f"unknown seq_parallel scheme {cfg.seq_parallel!r}")
-    elif mesh is not None and mesh.size > 1 and uses_flash_kernel(q):
+    if mesh is not None and mesh.size > 1 and uses_flash_kernel(q):
         attn = attention_sharded(mesh, q, k, v, causal=True)
     else:
         attn = attention(q, k, v, causal=True)
@@ -302,7 +281,7 @@ def _attn_half(cfg: ModelConfig, mesh, x, p, cos, sin):
 
 
 def _layer(cfg: ModelConfig, mesh, x, layer_params, cos, sin):
-    """One transformer block. x: [b, s, d] (s possibly sp-sharded)."""
+    """One transformer block. x: [b, s, d]."""
     x = _attn_half(cfg, mesh, x, layer_params, cos, sin)
     out, aux = _mlp(cfg, _deq_tree(layer_params, cfg.dtype), x,
                     _rows_mesh(cfg, mesh, *x.shape[:2]))
@@ -320,10 +299,10 @@ def _exchanged_dims(cfg: ModelConfig, mesh, batch: int) -> Dict[str, int]:
     stacked leaf's, less the leading `layers`): every sharded one of the
     plain dense block, on a mesh whose `fsdp` axis is larger than 1 and
     whose (dp, fsdp) split the batch evenly. The expert layer (its `expert`
-    axis IS `fsdp`), the sequence-parallel schemes and the fused blocks (one
-    chip only) keep the partitioner's program: none, as without a mesh."""
-    if (fsdp.axis_size(mesh) == 1 or cfg.n_experts or cfg.seq_parallel
-            or cfg.fused_ffn or batch % math.prod(fsdp.batch_split(mesh))):
+    axis IS `fsdp`) and the fused blocks (one chip only) keep the
+    partitioner's program: none, as without a mesh."""
+    if (fsdp.axis_size(mesh) == 1 or cfg.n_experts or cfg.fused_ffn
+            or batch % math.prod(fsdp.batch_split(mesh))):
         return {}
     dims = {k: fsdp.sharded_dim(DEFAULT_RULES.spec(axes))
             for k, axes in param_logical_axes(cfg)["layers"].items()}
@@ -337,10 +316,10 @@ def _rows_mesh(cfg: ModelConfig, mesh, batch: int, seq: int):
     the `tp` reductions are the partitioner's all-reduces: without a mesh or
     with `tp` 1, where `tp` does not divide the sequence, and wherever the
     layer's weights do not come exchanged (`_exchanged_dims`: fsdp 1, the
-    expert layer, the sequence-parallel schemes, the fused blocks). The
-    only form read on the chip is the one whose products carry the weights'
-    shards round fsdp's ring as well; the rows over `tp` alone read no
-    faster than the partitioner's program (PERF.md section 6, PR 38)."""
+    expert layer, the fused blocks). The only form read on the chip is the
+    one whose products carry the weights' shards round fsdp's ring as well;
+    the rows over `tp` alone read no faster than the partitioner's program
+    (PERF.md section 6, PR 38)."""
     if (tp.axis_size(mesh) == 1 or seq % tp.axis_size(mesh)
             or not _exchanged_dims(cfg, mesh, batch)):
         return None
@@ -366,14 +345,13 @@ def norm_grad_reductions_in_layers(cfg: ModelConfig, mesh, batch: int) -> int:
     """How many of a layer's norm scales have their gradient summed over the
     ranks that split the residual's rows INSIDE the layers' backward, an
     all-reduce of the partitioner's on the compute stream: both on a mesh
-    that splits the rows (`dp`, `fsdp`, `sp`), but 0 where the layer's weights
+    that splits the rows (`dp`, `fsdp`), but 0 where the layer's weights
     come exchanged (`_exchanged_dims`): there the scales ride once a rank
     (`fsdp.scale_by_rank`) and the sums are taken once a step behind the
     scan (the train step's `xla.compile` spans carry it)."""
     if mesh is None or _exchanged_dims(cfg, mesh, batch):
         return 0
-    split = math.prod(mesh.shape.get(a, 1) for a in (*fsdp.BATCH_AXES, "sp"))
-    return len(_NORM_SCALES) if split > 1 else 0
+    return len(_NORM_SCALES) if math.prod(fsdp.batch_split(mesh)) > 1 else 0
 
 
 def maybe_remat(layer_fn, cfg: ModelConfig):
@@ -410,14 +388,12 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
                               positions: Optional[jax.Array] = None, mesh=None):
     """tokens [b, s] -> (features [b, s, d] after final norm, moe_aux scalar).
 
-    `mesh` is required when a sequence-parallel scheme is active
-    (`cfg.seq_parallel`: the sp shard_map needs it); everything else is
-    pure sharding-annotation-driven SPMD, but for the sum of the dense
+    Pure sharding-annotation-driven SPMD, but for the sum of the dense
     block's weight gradients over `fsdp` (`_exchanged_dims`), its gathers
     and scatters over `tp` (`_rows_mesh`) and, wherever the first holds, the
     sums of its two norm scales' gradients over the ranks that split the
     rows, taken once a step behind the scan
-    (`norm_grad_reductions_in_layers`): all take the mesh too and without
+    (`norm_grad_reductions_in_layers`): all three take `mesh` and without
     it are the partitioner's.
     """
     if positions is None:
@@ -433,8 +409,8 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
     if cfg.fused_attn and not cfg.fused_ffn:
         raise ValueError("fused_attn requires fused_ffn")
     if cfg.fused_ffn:
-        if cfg.n_experts > 0 or cfg.seq_parallel:
-            raise ValueError("fused_ffn supports the dense, non-sp path only")
+        if cfg.n_experts > 0:
+            raise ValueError("fused_ffn supports the dense path only")
         from ray_tpu.ops.pallas.fused_ffn import ffn_block
 
         if cfg.fused_attn:
@@ -559,8 +535,7 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
     """Next-token cross entropy.
 
     batch: either {"tokens": [b, s+1]} (shifted here) or pre-shifted
-    {"inputs": [b, s], "targets": [b, s]} — the latter keeps s divisible by
-    the sp axis for sequence parallelism. Optional {"loss_mask": [b, s]}.
+    {"inputs": [b, s], "targets": [b, s]}. Optional {"loss_mask": [b, s]}.
     """
     inputs, targets, mask = split_batch(batch)
     if cfg.loss_chunk and targets.shape[-1] % cfg.loss_chunk != 0:

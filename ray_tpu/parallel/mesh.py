@@ -8,7 +8,6 @@ worker group a `jax.sharding.Mesh` whose axes map onto the hardware:
     dp    — data parallel, outermost (across slices -> rides DCN)
     fsdp  — sharded data parallel (ZeRO-3 analog; within slice -> ICI)
     tp    — tensor parallel (within slice -> ICI, highest bandwidth)
-    sp    — sequence/context parallel (ring collectives over ICI)
     ep    — expert parallel for MoE layers (reuses fsdp axis by default)
 
 Collectives (`psum`, `all_gather`, `ppermute`, `reduce_scatter`) are then
@@ -28,7 +27,6 @@ products.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -38,49 +36,38 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 @dataclasses.dataclass(frozen=True)
 class MeshConfig:
-    """Sizes of each parallelism axis. -1 on `dp` means 'fill'.
-
-    `pp` (pipeline parallel) is manual-mode: the pp axis is only used by
-    `ray_tpu.parallel.pipeline` (shard_map over 'pp'); the auto-sharded
-    train step requires pp == 1.
-    """
+    """Sizes of each parallelism axis. -1 on `dp` means 'fill'."""
 
     dp: int = -1
     fsdp: int = 1
     tp: int = 1
-    sp: int = 1
-    pp: int = 1
 
     def resolve(self, n_devices: int) -> "MeshConfig":
-        fixed = self.pp * self.fsdp * self.tp * self.sp
+        fixed = self.fsdp * self.tp
         dp = self.dp
         if dp == -1:
             if n_devices % fixed != 0:
                 raise ValueError(
-                    f"{n_devices} devices not divisible by pp*fsdp*tp*sp={fixed}")
+                    f"{n_devices} devices not divisible by fsdp*tp={fixed}")
             dp = n_devices // fixed
         if dp * fixed != n_devices:
             raise ValueError(
-                f"mesh {dp}x{self.pp}x{self.fsdp}x{self.tp}x{self.sp} "
-                f"!= {n_devices} devices")
-        return MeshConfig(dp=dp, fsdp=self.fsdp, tp=self.tp, sp=self.sp,
-                          pp=self.pp)
+                f"mesh {dp}x{self.fsdp}x{self.tp} != {n_devices} devices")
+        return MeshConfig(dp=dp, fsdp=self.fsdp, tp=self.tp)
 
     @property
     def shape(self) -> Tuple[int, ...]:
-        return (self.dp, self.pp, self.fsdp, self.tp, self.sp)
+        return (self.dp, self.fsdp, self.tp)
 
 
-# pp sits between dp and fsdp: stage boundaries cross lower-bandwidth links
-# than tp/sp (which stay innermost on ICI neighbors).
-AXIS_NAMES = ("dp", "pp", "fsdp", "tp", "sp")
+AXIS_NAMES = ("dp", "fsdp", "tp")
 
 
 def make_mesh(config: MeshConfig, devices: Optional[Sequence[Any]] = None) -> Mesh:
-    """Build a Mesh with (dp, pp, fsdp, tp, sp) axes over the given devices.
+    """Build a Mesh with (dp, fsdp, tp) axes over the given devices.
 
     Axis order is chosen so the innermost (fastest-varying) axes hold the
-    highest-bandwidth collectives: tp/sp innermost map to adjacent chips on
+    highest-bandwidth collectives: tp innermost maps to adjacent chips on
     ICI; dp outermost maps across hosts/slices (DCN for multi-slice).
     """
     devices = list(devices if devices is not None else jax.devices())
@@ -95,7 +82,7 @@ def make_hybrid_mesh(config: MeshConfig, *, dcn_dp: int = 1,
     `config` parallelism within each slice (ICI).
 
     Uses `mesh_utils.create_hybrid_device_mesh` so device order guarantees
-    only the outermost dp axis crosses slice boundaries — tp/sp/fsdp
+    only the outermost dp axis crosses slice boundaries — tp/fsdp
     collectives stay on ICI (the scaling-book multislice recipe). Falls back
     to a plain reshape when devices carry no slice topology (CPU tests,
     single slice): semantics identical, placement guarantee vacuous.
@@ -111,7 +98,7 @@ def make_hybrid_mesh(config: MeshConfig, *, dcn_dp: int = 1,
 
         # real multislice topology: let genuine shape mismatches propagate
         arr = mesh_utils.create_hybrid_device_mesh(
-            per_slice.shape, (dcn_dp, 1, 1, 1, 1), devices=devices)
+            per_slice.shape, (dcn_dp, 1, 1), devices=devices)
     else:  # no slice topology (CPU tests, single slice): plain reshape
         arr = np.array(devices).reshape(
             (dcn_dp * per_slice.dp,) + per_slice.shape[1:])
@@ -162,10 +149,10 @@ class AxisRules:
 
 # Default rules for transformer LMs: FSDP shards the embed dim of weights,
 # TP shards heads/mlp, batch shards over (dp, fsdp) [fsdp acts as extra DP
-# for activations, ZeRO-style], sequence shards over sp.
+# for activations, ZeRO-style], the sequence is not sharded.
 DEFAULT_RULES = AxisRules({
     "batch": ("dp", "fsdp"),
-    "seq": "sp",
+    "seq": None,
     "embed": "fsdp",
     "heads": "tp",
     "kv": None,

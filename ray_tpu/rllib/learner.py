@@ -291,7 +291,7 @@ class LearnerGroup:
             if mesh is None:
                 from ray_tpu.parallel import MeshConfig, make_mesh
 
-                mesh = make_mesh(MeshConfig(dp=-1, fsdp=1, tp=1, sp=1))
+                mesh = make_mesh(MeshConfig(dp=-1, fsdp=1, tp=1))
             kwargs["mesh"] = mesh
             self.mesh = mesh
             self._learner = learner_cls(**kwargs)

@@ -4,9 +4,9 @@ Where the reference's TorchTrainer wraps user loops around torch DDP/FSDP
 (`python/ray/train/torch/config.py:69`, `train_loop_utils.py:92-101`), the
 TPU-native step is one jitted function whose parallelism is entirely in the
 in/out shardings: dp×fsdp shard the batch, fsdp shards parameters ZeRO-3
-style (XLA inserts the all-gathers), tp shards heads/mlp, sp runs ring
-attention. No collective calls appear below — the compiler emits them over
-ICI/DCN from the sharding annotations. Two the model spells itself, as
+style (XLA inserts the all-gathers), tp shards heads/mlp. No collective
+calls appear below — the compiler emits them over ICI/DCN from the
+sharding annotations. Two the model spells itself, as
 permutes that run behind matmuls where the partitioner's all-reduce blocks
 the compute stream: with fsdp > 1 the dense block's weight gradients are
 summed over fsdp by `parallel/fsdp.py`, and with tp > 1 its gathers and
@@ -116,8 +116,8 @@ def _shard_opt_like_params(opt_shape, params_shape, p_sh, replicated):
 
 
 def batch_sharding(mesh: Mesh) -> Dict[str, NamedSharding]:
-    """inputs/targets [b, s]: batch over (dp, fsdp), seq over sp."""
-    s = NamedSharding(mesh, P(("dp", "fsdp"), "sp"))
+    """inputs/targets [b, s]: batch over (dp, fsdp)."""
+    s = NamedSharding(mesh, P(("dp", "fsdp")))
     return {"inputs": s, "targets": s}
 
 

@@ -1,7 +1,7 @@
 import os
 
 # Force an 8-device virtual CPU mesh for all tests: multi-chip sharding paths
-# (dp/fsdp/tp/sp) run in CI without TPUs, per the driver's dryrun contract.
+# (dp/fsdp/tp) run in CI without TPUs, per the driver's dryrun contract.
 os.environ["JAX_PLATFORMS"] = "cpu"  # force: a chip machine exports tpu
 xla_flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in xla_flags:

@@ -21,7 +21,7 @@ def _sharded_params(mesh_cfg):
 
 
 def test_save_restore_same_mesh(tmp_path):
-    sharded, sh, orig = _sharded_params(MeshConfig(dp=2, fsdp=2, tp=2, sp=1))
+    sharded, sh, orig = _sharded_params(MeshConfig(dp=2, fsdp=2, tp=2))
     path = save_sharded(sharded, str(tmp_path / "ckpt1"))
     restored = restore_sharded(path, abstract_like(sharded))
     for a, b in zip(jax.tree_util.tree_leaves(orig),
@@ -32,11 +32,11 @@ def test_save_restore_same_mesh(tmp_path):
 def test_restore_onto_reshaped_mesh(tmp_path):
     """Save from an 8-device dp2/fsdp2/tp2 layout, restore onto dp1/fsdp4/
     tp2 — shards re-laid-out on read, values identical."""
-    sharded, _, orig = _sharded_params(MeshConfig(dp=2, fsdp=2, tp=2, sp=1))
+    sharded, _, orig = _sharded_params(MeshConfig(dp=2, fsdp=2, tp=2))
     path = save_sharded(sharded, str(tmp_path / "ckpt2"))
 
     cfg = ModelConfig.tiny()
-    new_mesh = make_virtual_mesh(8, MeshConfig(dp=1, fsdp=4, tp=2, sp=1))
+    new_mesh = make_virtual_mesh(8, MeshConfig(dp=1, fsdp=4, tp=2))
     new_sh = logical_sharding(new_mesh, param_logical_axes(cfg), DEFAULT_RULES)
     restored = restore_sharded(path, abstract_like(sharded, new_sh))
     for a, b in zip(jax.tree_util.tree_leaves(orig),

@@ -28,7 +28,7 @@ def _ppo_batch(n, obs_dim=4, num_actions=2, seed=0):
 def test_ppo_learner_mesh_matches_single_device():
     """The dp-sharded update must compute the same step as the unsharded
     one: params replicated, gradients globally averaged by GSPMD."""
-    mesh = make_mesh(MeshConfig(dp=8, fsdp=1, tp=1, sp=1))
+    mesh = make_mesh(MeshConfig(dp=8, fsdp=1, tp=1))
     batch = _ppo_batch(64)
     plain = PPOLearner(4, 2, lr=1e-3, seed=7)
     meshed = PPOLearner(4, 2, lr=1e-3, seed=7, mesh=mesh)
@@ -43,7 +43,7 @@ def test_ppo_learner_mesh_matches_single_device():
 
 
 def test_dqn_learner_mesh_update_and_target_sync():
-    mesh = make_mesh(MeshConfig(dp=8, fsdp=1, tp=1, sp=1))
+    mesh = make_mesh(MeshConfig(dp=8, fsdp=1, tp=1))
     learner = DQNLearner(4, 2, lr=1e-3, gamma=0.99, mesh=mesh)
     rng = np.random.default_rng(0)
     batch = {
@@ -63,7 +63,7 @@ def test_dqn_learner_mesh_update_and_target_sync():
 def test_learner_group_mesh_backend():
     group = LearnerGroup(
         PPOLearner, {"obs_dim": 4, "num_actions": 2, "lr": 1e-3},
-        backend="mesh", mesh=make_mesh(MeshConfig(dp=8, fsdp=1, tp=1, sp=1)))
+        backend="mesh", mesh=make_mesh(MeshConfig(dp=8, fsdp=1, tp=1)))
     stats = group.update(_ppo_batch(64))
     assert np.isfinite(stats["total_loss"])
     w = group.get_weights()
@@ -98,7 +98,7 @@ def test_ppo_algorithm_with_mesh_learner_group(ray_start_regular):
     (reference Algorithm.training_step -> LearnerGroup.update)."""
     from ray_tpu.rllib import PPOConfig
 
-    mesh = make_mesh(MeshConfig(dp=8, fsdp=1, tp=1, sp=1))
+    mesh = make_mesh(MeshConfig(dp=8, fsdp=1, tp=1))
     algo = (PPOConfig()
             .rollouts(num_rollout_workers=1, num_envs_per_worker=2,
                       rollout_fragment_length=34)  # 68 samples: ragged tail
@@ -148,7 +148,7 @@ def test_vtrace_family_mesh_matches_single_device():
     shards env trajectories; the sharded update equals the unsharded one."""
     from ray_tpu.rllib.impala import ImpalaLearner
 
-    mesh = make_mesh(MeshConfig(dp=8, fsdp=1, tp=1, sp=1))
+    mesh = make_mesh(MeshConfig(dp=8, fsdp=1, tp=1))
     batch = _traj_batch()
     plain = ImpalaLearner(4, 2, lr=1e-3, gamma=0.99, vf_coeff=0.5,
                           entropy_coeff=0.01, seed=5)
@@ -170,7 +170,7 @@ def test_continuous_family_mesh_matches_single_device():
     jitted polyak post_update all ride the dp-sharded update."""
     from ray_tpu.rllib.ddpg import DDPGLearner
 
-    mesh = make_mesh(MeshConfig(dp=8, fsdp=1, tp=1, sp=1))
+    mesh = make_mesh(MeshConfig(dp=8, fsdp=1, tp=1))
     rng = np.random.default_rng(1)
     batch = {
         "obs": rng.normal(size=(32, 3)).astype(np.float32),
@@ -202,7 +202,7 @@ def test_sac_learner_mesh_runs_and_polyak_targets_move():
     and the post_update polyak actually moves the target critics."""
     from ray_tpu.rllib.sac import SACLearner
 
-    mesh = make_mesh(MeshConfig(dp=8, fsdp=1, tp=1, sp=1))
+    mesh = make_mesh(MeshConfig(dp=8, fsdp=1, tp=1))
     learner = SACLearner(3, 1, 1.0, lr=3e-4, gamma=0.99, tau=0.05,
                          target_entropy=-1.0, seed=4, mesh=mesh)
     before = np.asarray(learner.extra["q1"]["w0"]).copy()

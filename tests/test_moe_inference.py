@@ -49,7 +49,7 @@ def test_moe_ffn_shapes_and_grads():
 @pytest.mark.slow
 def test_moe_model_trains_sharded():
     cfg = ModelConfig.tiny_moe()
-    mesh = make_virtual_mesh(8, MeshConfig(dp=2, fsdp=2, tp=2, sp=1))
+    mesh = make_virtual_mesh(8, MeshConfig(dp=2, fsdp=2, tp=2))
     step_fn, init_fn, _ = make_train_step(cfg, mesh, default_optimizer(1e-3))
     state = init_fn(jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 65), 0, cfg.vocab_size)

@@ -5,9 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops import attention, causal_attention_reference, ring_attention, rms_norm
+from ray_tpu.ops import attention, causal_attention_reference, rms_norm
+from ray_tpu.ops.attention import attention_sharded
 from ray_tpu.ops.layers import apply_rotary, rotary_embedding, swiglu
-from ray_tpu.ops.ring_attention import ring_attention_sharded
 from ray_tpu.parallel import MeshConfig, make_virtual_mesh
 
 
@@ -59,94 +59,35 @@ def test_attention_gqa():
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
 
 
+# the four-chip cell's attention: each device its own (batch, head) block
+SHARDED_MESHES = [MeshConfig(dp=-1, fsdp=2, tp=2), MeshConfig(dp=-1, fsdp=1, tp=4)]
+_mesh_id = lambda m: f"fsdp{m.fsdp}-tp{m.tp}"
+
+
+@pytest.mark.parametrize("mesh_cfg", SHARDED_MESHES, ids=_mesh_id)
 @pytest.mark.parametrize("causal", [True, False])
-def test_ring_attention_matches_reference(causal):
-    """Ring attention over an sp=4 virtual mesh == single-device attention."""
-    mesh = make_virtual_mesh(8, MeshConfig(dp=1, fsdp=2, tp=1, sp=4))
-    rng = jax.random.PRNGKey(0)
-    b, h, s, d = 2, 4, 64, 16
-    q, k, v = (jax.random.normal(r, (b, h, s, d), jnp.float32)
-               for r in jax.random.split(rng, 3))
-    out = ring_attention_sharded(mesh, q, k, v, causal=causal)
+def test_attention_sharded_matches_reference(causal, mesh_cfg):
+    mesh = make_virtual_mesh(8, mesh_cfg)
+    q, k, v = (jax.random.normal(r, (4, 8, 64, 16), jnp.float32)
+               for r in jax.random.split(jax.random.PRNGKey(0), 3))
+    out = attention_sharded(mesh, q, k, v, causal=causal)
     ref = causal_attention_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.slow
-def test_ring_attention_grads_match():
-    mesh = make_virtual_mesh(8, MeshConfig(dp=1, fsdp=1, tp=2, sp=4))
-    rng = jax.random.PRNGKey(7)
-    b, h, s, d = 1, 2, 32, 8
-    q, k, v = (jax.random.normal(r, (b, h, s, d), jnp.float32)
-               for r in jax.random.split(rng, 3))
-
-    def loss_ring(q, k, v):
-        return jnp.sum(ring_attention_sharded(mesh, q, k, v) ** 2)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(causal_attention_reference(q, k, v) ** 2)
-
-    g1 = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b_ in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=1e-3, atol=1e-3)
-
-
-@pytest.mark.parametrize("causal", [True, False])
-def test_ulysses_attention_matches_reference(causal):
-    """Ulysses all-to-all SP over sp=4 == single-device attention."""
-    from ray_tpu.ops.ulysses import ulysses_attention_sharded
-
-    mesh = make_virtual_mesh(8, MeshConfig(dp=1, fsdp=2, tp=1, sp=4))
-    rng = jax.random.PRNGKey(3)
-    b, h, s, d = 2, 4, 64, 16
-    q, k, v = (jax.random.normal(r, (b, h, s, d), jnp.float32)
-               for r in jax.random.split(rng, 3))
-    out = ulysses_attention_sharded(mesh, q, k, v, causal=causal)
-    ref = causal_attention_reference(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4,
-                               atol=2e-4)
-
-
-@pytest.mark.slow
-def test_ulysses_attention_grads_match():
-    from ray_tpu.ops.ulysses import ulysses_attention_sharded
-
-    mesh = make_virtual_mesh(8, MeshConfig(dp=1, fsdp=1, tp=2, sp=4))
-    rng = jax.random.PRNGKey(9)
-    b, h, s, d = 1, 8, 32, 8
-    q, k, v = (jax.random.normal(r, (b, h, s, d), jnp.float32)
-               for r in jax.random.split(rng, 3))
-
-    def loss_uly(q, k, v):
-        return jnp.sum(ulysses_attention_sharded(mesh, q, k, v) ** 2)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(causal_attention_reference(q, k, v) ** 2)
-
-    g1 = jax.grad(loss_uly, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b_ in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=1e-3,
-                                   atol=1e-3)
-
-
-def test_ulysses_attention_gqa():
-    """GQA KV heads cross the all-to-all unexpanded and still match."""
-    from ray_tpu.ops.ulysses import ulysses_attention_sharded
-
-    mesh = make_virtual_mesh(8, MeshConfig(dp=2, fsdp=1, tp=1, sp=4))
-    rng = jax.random.PRNGKey(5)
-    b, hq, hkv, s, d = 2, 8, 2, 64, 16
+@pytest.mark.parametrize("mesh_cfg", SHARDED_MESHES, ids=_mesh_id)
+def test_attention_sharded_gqa(mesh_cfg):
+    """4 : 1 grouped heads as Mistral's: q and kv heads split contiguously
+    over tp, so each rank's q heads meet their own kv heads."""
+    mesh = make_virtual_mesh(8, mesh_cfg)
+    b, hq, hkv, s, d = 4, 16, 4, 64, 16
     q = jax.random.normal(jax.random.PRNGKey(0), (b, hq, s, d), jnp.float32)
     k = jax.random.normal(jax.random.PRNGKey(1), (b, hkv, s, d), jnp.float32)
     v = jax.random.normal(jax.random.PRNGKey(2), (b, hkv, s, d), jnp.float32)
-    out = ulysses_attention_sharded(mesh, q, k, v, causal=True)
-    kr = jnp.repeat(k, hq // hkv, axis=1)
-    vr = jnp.repeat(v, hq // hkv, axis=1)
-    ref = causal_attention_reference(q, kr, vr)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4,
-                               atol=2e-4)
+    out = attention_sharded(mesh, q, k, v, causal=True)
+    ref = causal_attention_reference(q, jnp.repeat(k, hq // hkv, axis=1),
+                                     jnp.repeat(v, hq // hkv, axis=1))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("remat", ["none", "full", "dots"])

@@ -331,7 +331,7 @@ def test_fused_blocks_on_sharded_mesh():
     from ray_tpu.train import batch_sharding, make_train_step
     from ray_tpu.train.step import default_optimizer
 
-    mesh = make_virtual_mesh(8, MeshConfig(dp=2, fsdp=2, tp=2, sp=1))
+    mesh = make_virtual_mesh(8, MeshConfig(dp=2, fsdp=2, tp=2))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 65), 0, 512)
     losses = {}
     for name, kw in [("stock", {}),

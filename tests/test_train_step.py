@@ -44,9 +44,9 @@ def test_loss_decreases_single_device():
 
 
 @pytest.mark.parametrize("mesh_cfg", [
-    MeshConfig(dp=2, fsdp=2, tp=2, sp=1),
-    MeshConfig(dp=1, fsdp=4, tp=2, sp=1),
-    MeshConfig(dp=8, fsdp=1, tp=1, sp=1),
+    MeshConfig(dp=2, fsdp=2, tp=2),
+    MeshConfig(dp=1, fsdp=4, tp=2),
+    MeshConfig(dp=8, fsdp=1, tp=1),
 ])
 def test_train_step_sharded(mesh_cfg):
     cfg = ModelConfig.tiny()
@@ -239,15 +239,12 @@ def test_norm_scale_gradients_in_bf16_are_no_further_from_float32(
         assert far(ours).mean() <= far(theirs).mean(), k
 
 
-@pytest.mark.parametrize("why", ["seq_not_divisible", "ring", "experts", "fsdp1"])
+@pytest.mark.parametrize("why", ["seq_not_divisible", "experts", "fsdp1"])
 def test_tp_exchange_stays_off_where_the_block_is_not_the_plain_one(why, monkeypatch):
-    """Where `tp` does not divide the sequence, or a sequence-parallel
-    scheme or the expert layer runs, or the mesh has fsdp 1 (the weights do
-    not come exchanged), the program is the parent's to the
-    letter: the lowered text is the one with `parallel/tp.py`'s products made
-    unreachable, and it names none of them."""
-    import dataclasses
-
+    """Where `tp` does not divide the sequence, or the expert layer runs, or
+    the mesh has fsdp 1 (the weights do not come exchanged), the program is
+    the parent's to the letter: the lowered text is the one with
+    `parallel/tp.py`'s products made unreachable, and it names none of them."""
     from ray_tpu.models import transformer
     from ray_tpu.parallel import tp
 
@@ -255,9 +252,6 @@ def test_tp_exchange_stays_off_where_the_block_is_not_the_plain_one(why, monkeyp
     mesh_cfg = MeshConfig(dp=2, fsdp=2, tp=2)
     if why == "seq_not_divisible":
         seq = 63
-    elif why == "ring":
-        cfg = dataclasses.replace(cfg, seq_parallel="ring")
-        mesh_cfg = MeshConfig(dp=2, fsdp=1, tp=2, sp=2)
     elif why == "experts":
         cfg = ModelConfig.tiny_moe()
     else:
@@ -283,19 +277,13 @@ def test_tp_exchange_stays_off_where_the_block_is_not_the_plain_one(why, monkeyp
     assert ours == text()
 
 
-def test_train_step_with_sequence_parallel():
-    cfg = ModelConfig.tiny()
-    cfg = ModelConfig(**{**cfg.__dict__, "seq_parallel": "ring"})
-    mesh = make_virtual_mesh(8, MeshConfig(dp=2, fsdp=1, tp=2, sp=2))
-    step_fn, init_fn, sh = make_train_step(cfg, mesh, default_optimizer(1e-3))
-    state = init_fn(jax.random.PRNGKey(0))
-    batch = _batch(jax.random.PRNGKey(1), cfg, batch=4, seq=64)
-    batch = jax.device_put(batch, {k: batch_sharding(mesh)[k] for k in batch})
-    losses = []
-    for _ in range(5):
-        state, metrics = step_fn(state, batch)
-        losses.append(float(metrics["loss"]))
-    assert losses[-1] < losses[0], losses
+@pytest.mark.parametrize("field", ["sp", "pp", "seq_parallel"])
+def test_mesh_and_model_config_refuse_removed_fields(field):
+    """The mesh has the axes a chip has run (dp, fsdp, tp) and the dense
+    block one attention path: what PR 47 removed is an unknown field."""
+    config = ModelConfig if field == "seq_parallel" else MeshConfig
+    with pytest.raises(TypeError, match=field):
+        config(**{field: 2})
 
 
 def test_sharded_matches_unsharded():
@@ -306,7 +294,7 @@ def test_sharded_matches_unsharded():
     batch = _batch(jax.random.PRNGKey(1), cfg)
     loss_1dev, _ = loss_fn(params, batch, cfg)
 
-    mesh = make_virtual_mesh(8, MeshConfig(dp=2, fsdp=2, tp=2, sp=1))
+    mesh = make_virtual_mesh(8, MeshConfig(dp=2, fsdp=2, tp=2))
     from ray_tpu.parallel.mesh import logical_sharding, shard_pytree, DEFAULT_RULES
     from ray_tpu.models.transformer import param_logical_axes
 
@@ -381,30 +369,14 @@ def test_selective_remat_matches_full():
     np.testing.assert_allclose(float(loss_ref), float(loss_dots), rtol=2e-5)
 
 
-def test_train_step_with_ulysses_sequence_parallel():
-    import dataclasses
-
-    cfg = dataclasses.replace(ModelConfig.tiny(), seq_parallel="ulysses")
-    mesh = make_virtual_mesh(8, MeshConfig(dp=2, fsdp=1, tp=2, sp=2))
-    step_fn, init_fn, sh = make_train_step(cfg, mesh, default_optimizer(1e-3))
-    state = init_fn(jax.random.PRNGKey(0))
-    batch = _batch(jax.random.PRNGKey(1), cfg, batch=4, seq=64)
-    batch = jax.device_put(batch, {k: batch_sharding(mesh)[k] for k in batch})
-    losses = []
-    for _ in range(5):
-        state, metrics = step_fn(state, batch)
-        losses.append(float(metrics["loss"]))
-    assert losses[-1] < losses[0], losses
-
-
 @pytest.mark.slow
 def test_hybrid_dcn_mesh_train_step():
     """2 simulated slices x 4-chip ICI mesh: dp rides the dcn axis."""
     from ray_tpu.parallel import make_hybrid_mesh
 
     cfg = ModelConfig.tiny()
-    mesh = make_hybrid_mesh(MeshConfig(dp=1, fsdp=2, tp=2, sp=1), dcn_dp=2)
-    assert mesh.shape == {"dp": 2, "pp": 1, "fsdp": 2, "tp": 2, "sp": 1}
+    mesh = make_hybrid_mesh(MeshConfig(dp=1, fsdp=2, tp=2), dcn_dp=2)
+    assert mesh.shape == {"dp": 2, "fsdp": 2, "tp": 2}
     step_fn, init_fn, _ = make_train_step(cfg, mesh, default_optimizer(1e-3))
     state = init_fn(jax.random.PRNGKey(0))
     batch = _batch(jax.random.PRNGKey(1), cfg, batch=8, seq=64)
@@ -434,7 +406,7 @@ from ray_tpu.train.step import default_optimizer, state_shardings
 assert len(jax.devices()) == 64, jax.devices()
 cfg = dataclasses.replace(ModelConfig.llama3_8b(), max_seq_len=4096,
                           remat="dots", loss_chunk=512)
-mesh = make_virtual_mesh(64, MeshConfig(dp=1, fsdp=16, tp=4, sp=1))
+mesh = make_virtual_mesh(64, MeshConfig(dp=1, fsdp=16, tp=4))
 optimizer = default_optimizer()
 step_fn, init_fn, sh = make_train_step(cfg, mesh, optimizer)
 
