@@ -5,12 +5,15 @@ same around the FFN: four norms a layer. The mixers: KDA linear attention
 (`ops/kda.py`), latent attention (`ops/mla.py`) in two kinds, without
 positions and with a full-rank query (Kimi-Linear's) or rotary with a
 low-rank query (`rope_theta`, `q_lora_rank`: openPangu-Ultra-MoE's),
-Mamba-1's selective scan (`ops/mamba.py`), plain grouped-query attention
-without positions and EVA attention (`ops/eva.py`: the query's own window
-of `eva_window` positions exactly, beside one learned summary a chunk of
-`eva_chunk` positions of every earlier window; EvaByte's); the FFNs: dense
-SwiGLU and a dropless sigmoid-routed top-k expert layer with a shared expert
-(`ops/moe.py:dropless_moe`) that is told which experts it holds.
+Mamba-1's selective scan (`ops/mamba.py`), Mamba-2's SSD recurrence
+(`ops/ssd.py`: a scalar decay a head over a matrix state, one group, a gated
+RMSNorm over all channels behind it; Granite-4.0-H's), plain grouped-query
+attention without positions and EVA attention (`ops/eva.py`: the query's own
+window of `eva_window` positions exactly, beside one learned summary a chunk
+of `eva_chunk` positions of every earlier window; EvaByte's); the FFNs: dense
+SwiGLU and a dropless top-k expert layer with a shared expert
+(`ops/moe.py:dropless_moe`) that is told which experts it holds, routed by
+sigmoid scores + bias or by a softmax over the chosen logits (`router`).
 
 A list-form configuration with `n_predict` 1 carries a multi-token
 prediction module (DeepSeek-V3's form): `h' = W_p [RMSNorm(h_i) ;
@@ -26,14 +29,21 @@ Two ways to hold and run the stack, by what the configuration lists:
   per-layer dicts, `params["layers"]`, and the stack is unrolled (nine
   layers in the benchmark's cut); per-layer leaves let the decode step
   donate and rewrite each layer's state in place.
-- `mamba_layers` / `attn_layers` (the Jamba family: Mamba and attention
-  mixers, dense FFNs, head tied to the embedding): 28 layers unrolled would
-  compile for minutes, so the stack is held and run as RUNS of like layers,
-  `params["runs"]`, each run one `lax.scan` over weights stacked on a leading
-  axis. A run's recurrent state is stacked the same way and travels through
-  the decode step's scan as a CARRY that each layer reads and rewrites in
-  place (a scan that took it as xs and gave it back as ys would hold it
-  twice).
+- `mamba_layers` / `mamba2_layers` / `attn_layers` (the Jamba and Granite
+  families: Mamba-1 or Mamba-2 and attention mixers, each over a dense FFN
+  or an expert layer, head tied to the embedding): 28 layers unrolled would
+  compile for minutes, and ten expert layers are past what the list form's
+  nine compile in, so the stack is held and run as RUNS of like layers
+  (alike in mixer AND FFN), `params["runs"]`, each run one `lax.scan` over
+  weights stacked on a leading axis: a run of expert layers stacks its
+  router, held experts and shared MLP like the rest. A run's recurrent
+  state is stacked the same way and travels through the decode step's scan
+  as a CARRY that each layer reads and rewrites in place (a scan that took
+  it as xs and gave it back as ys would hold it twice); what an expert
+  layer counts and chooses comes back as the scan's ys. Four scalars
+  belong to this form (`embed_scale`, `residual_scale`, `attn_scale`,
+  `logit_divisor`): x_0 = emb E[token]; x += res Mixer(.), x += res FFN(.);
+  softmax(att q . k); logits = RMSNorm(x) E^T / lsc.
 - `eva_layers` (the EvaByte family: every mixer EVA attention with rotary
   positions, dense FFNs, norms that scale by 1 + w, an untied head of
   `n_pred_heads` x vocabulary columns of which serving reads the first
@@ -56,7 +66,10 @@ Three call modes over the same weights:
                 sampling on device, state DONATED and rewritten in place.
 
 A slot's state is of three kinds. An attention keeps a row a position for
-ever (K/V, or a latent row); a recurrent mixer keeps a state of fixed size;
+ever (K/V, or a latent row); a recurrent mixer keeps a state of fixed size
+(KDA's S [H, dk, dv], Mamba-1's [d_state, d_inner], 0.33 MB a layer at
+Jamba's widths, Mamba-2's [N, H P], a matrix a head, 4.19 MB a layer at
+Granite's; each beside the last K-1 inputs of its convolution);
 EVA keeps a table of two regions: the open window's K/V rows, which the
 slot REUSES every `eva_window` positions (row n % W; stale rows are masked
 by the length, never cleared), and a summary a closed chunk, a row every
@@ -78,14 +91,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.inference import _gqa_decode_attention
-from ray_tpu.ops import eva, kda, mamba, mla
-from ray_tpu.ops.attention import causal_attention_blocked
+from ray_tpu.ops import eva, kda, mamba, mla, ssd
+from ray_tpu.ops.attention import attention, causal_attention_blocked
 from ray_tpu.ops.cache import write_rows
 from ray_tpu.ops.layers import rms_norm, swiglu
-from ray_tpu.ops.moe import dropless_moe, route_top_k
+from ray_tpu.ops.moe import dropless_moe, route_softmax_top_k, route_top_k
 from ray_tpu.ops.pallas import decode_attention, eva_decode
 
 F32 = jnp.float32
+_FFN_BLOCK = 2048     # tokens a scanned expert layer takes at a time (`_run_ffn`)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,9 +139,11 @@ class HybridConfig:
     # the most tokens one prefill call takes (bounds its activations and the
     # number of (batch, bucket) programs): admission batches are split to it
     prefill_tokens: int = 4096
-    # the runs form: Mamba-1 and plain-attention mixers (layers counted from
-    # 1, as `kda_layers`); a configuration that lists any is held and run as
-    # scanned runs, its FFNs are all dense and its head is the embedding
+    # the runs form: Mamba-1 (Mamba-2: `mamba2_layers`, below) and
+    # plain-attention mixers (layers counted from 1, as `kda_layers`); a
+    # configuration that lists any is held and run as scanned runs, its FFNs
+    # dense up to `first_dense` and expert layers past it, its head the
+    # embedding
     mamba_layers: Tuple[int, ...] = ()
     attn_layers: Tuple[int, ...] = ()
     d_inner: int = 128
@@ -143,6 +159,24 @@ class HybridConfig:
     eva_chunk: int = 16
     n_pred_heads: int = 1                     # heads of vocab_size columns each
     norm_unit_offset: bool = False            # every RMSNorm scales by 1 + w
+    # Mamba-2 (SSD) mixers (the runs form): `ssd_heads` heads of
+    # `ssd_head_dim` channels over a state of `ssd_state` columns a channel,
+    # ONE group (every head shares B and C); they take `conv_kernel`
+    mamba2_layers: Tuple[int, ...] = ()
+    ssd_heads: int = 4
+    ssd_head_dim: int = 8
+    ssd_state: int = 16
+    ssd_chunk: int = 256
+    # how an expert layer routes: "sigmoid" (`ops.moe.route_top_k`: score +
+    # bias chooses, `route_scale`, `renormalize`) or "softmax" (top-k by
+    # logit, a softmax over the chosen: `route_softmax_top_k`)
+    router: str = "sigmoid"
+    # four scalars of the runs form; each default leaves the lowered program
+    # as it is without the field
+    embed_scale: float = 1.0                  # x_0 = embed_scale * E[token]
+    residual_scale: float = 1.0               # x += residual_scale * Mixer / FFN
+    attn_scale: float = 0.0                   # softmax(attn_scale q . k); 0: hd^-1/2
+    logit_divisor: float = 1.0                # logits = RMSNorm(x) E^T / logit_divisor
 
     @staticmethod
     def tiny_hybrid() -> "HybridConfig":
@@ -166,6 +200,20 @@ class HybridConfig:
                             n_heads=4, norm_eps=1e-6, mamba_chunk=16)
 
     @staticmethod
+    def tiny_granite() -> "HybridConfig":
+        """Two periods of Mamba-2 x 3, attention; every FFN 8 experts of
+        which the first 4 are held, top-3 by logit under a softmax over the
+        chosen, beside a shared MLP of two experts' width; the four scalars
+        away from 1."""
+        return HybridConfig(n_layers=8, kda_layers=(), first_dense=0,
+                            mamba2_layers=(1, 2, 3, 5, 6, 7), attn_layers=(4, 8),
+                            n_heads=4, n_kv_heads=2, ssd_heads=8, ssd_head_dim=16,
+                            ssd_chunk=16,
+                            experts_held=(0, 1, 2, 3), top_k=3, n_shared=2,
+                            router="softmax", embed_scale=3.0, residual_scale=0.5,
+                            attn_scale=0.125, logit_divisor=4.0)
+
+    @staticmethod
     def tiny_eva() -> "HybridConfig":
         """Three EVA layers, windows of 32 positions in chunks of 4, two
         prediction heads."""
@@ -178,6 +226,7 @@ class HybridConfig:
         def mixer(i):
             return ("kda" if i in self.kda_layers else
                     "mamba" if i in self.mamba_layers else
+                    "mamba2" if i in self.mamba2_layers else
                     "attn" if i in self.attn_layers else
                     "eva" if i in self.eva_layers else "mla")
         return tuple((mixer(i), "dense" if i <= self.first_dense else "moe")
@@ -185,10 +234,23 @@ class HybridConfig:
 
     @property
     def scanned(self) -> bool:
-        return bool(self.mamba_layers or self.attn_layers or self.eva_layers)
+        return bool(self.mamba_layers or self.mamba2_layers or self.attn_layers
+                    or self.eva_layers)
+
+    @property
+    def ssd_inner(self) -> int:
+        return self.ssd_heads * self.ssd_head_dim
 
     def runs(self) -> Tuple[Tuple[str, int], ...]:
-        """The runs form's stack: (mixer, how many like layers in a row)."""
+        """The runs form's stack: (mixer, how many like layers in a row). A
+        run's layers are alike in mixer AND FFN (`run_ffns`)."""
+        return tuple((mixer, k) for mixer, _, k in self._run_kinds())
+
+    def run_ffns(self) -> Tuple[str, ...]:
+        """"dense" or "moe" for each run of `runs()`."""
+        return tuple(ffn for _, ffn, _ in self._run_kinds())
+
+    def _run_kinds(self) -> Tuple[Tuple[str, str, int], ...]:
         kinds = self.layer_kinds()
         if self.eva_layers:
             # the prompt pass walks windows, each through the whole stack
@@ -199,15 +261,19 @@ class HybridConfig:
                     "query head, whole chunks a window; not "
                     f"{sorted(set(kinds))}, {self.n_heads}:{self.n_kv_heads} heads, "
                     f"{self.eva_window} / {self.eva_chunk}")
-        elif any(k not in (("mamba", "dense"), ("attn", "dense")) for k in kinds):
-            raise ValueError("a stack of scanned runs holds Mamba and attention "
-                             f"mixers over dense FFNs only, not {sorted(set(kinds))}")
-        out: List[Tuple[str, int]] = []
-        for mixer, _ in kinds:
-            if out and out[-1][0] == mixer:
-                out[-1] = (mixer, out[-1][1] + 1)
+        elif any(m not in ("mamba", "mamba2", "attn") for m, _ in kinds) \
+                or self.ssd_heads % 2:
+            raise ValueError(
+                "a stack of scanned runs holds Mamba-1, Mamba-2 (an even number "
+                "of heads, one group) and attention mixers, each over a dense "
+                f"FFN or an expert layer, not {sorted(set(kinds))} with "
+                f"{self.ssd_heads} SSD heads")
+        out: List[Tuple[str, str, int]] = []
+        for kind in kinds:
+            if out and out[-1][:2] == kind:
+                out[-1] = kind + (out[-1][2] + 1,)
             else:
-                out.append((mixer, 1))
+                out.append(kind + (1,))
         return tuple(out)
 
     @property
@@ -337,13 +403,39 @@ def _init_runs(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
             return jnp.ones(shape, dt)
         return jax.random.uniform(key(), shape, F32, -0.1, 0.1).astype(dt)
 
+    def swiglu_w(width, lead):
+        return {"w_gate": w(lead + (d, width), d), "w_up": w(lead + (d, width), d),
+                "w_down": w(lead + (width, d), width)}
+
     runs: List[Dict[str, Any]] = []
-    for mixer, k in cfg.runs():
-        p: Dict[str, Any] = {
-            "mixer_norm": norm((k, d)), "ffn_norm": norm((k, d)),
-            "ffn": {"w_gate": w((k, d, cfg.d_ff), d), "w_up": w((k, d, cfg.d_ff), d),
-                    "w_down": w((k, cfg.d_ff, d), cfg.d_ff)}}
-        if mixer == "mamba":
+    for (mixer, k), ffn in zip(cfg.runs(), cfg.run_ffns()):
+        p: Dict[str, Any] = {"mixer_norm": norm((k, d)), "ffn_norm": norm((k, d))}
+        if ffn == "dense":
+            p["ffn"] = swiglu_w(cfg.d_ff, (k,))
+        else:
+            if cfg.router != "softmax":
+                raise ValueError("a scanned expert layer routes by a softmax "
+                                 "over the chosen logits")
+            p["moe"] = {"router": w((k, d, cfg.n_experts), d),
+                        **swiglu_w(cfg.d_expert, (k, len(cfg.experts_held))),
+                        "shared": swiglu_w(cfg.d_expert * cfg.n_shared, (k,))}
+        if mixer == "mamba2":
+            H, ch = cfg.ssd_heads, cfg.ssd_inner + 2 * cfg.ssd_state
+            step = jnp.exp(jax.random.uniform(key(), (k, H), F32,
+                                              np.log(1e-3), np.log(1e-1)))
+            p["mamba2"] = {
+                # [z | x B C]; dt's H columns of the published in-projection
+                # are a matrix of their own, for its float32 sums
+                "w_in": w((k, d, cfg.ssd_inner + ch), d), "w_dt": w((k, d, H), d),
+                "conv": w((k, K, ch), K),
+                "conv_bias": jax.random.uniform(key(), (k, ch), F32,
+                                                -K ** -0.5, K ** -0.5),
+                "dt_bias": step + jnp.log(-jnp.expm1(-step)),  # softplus^-1
+                "A_log": jnp.log(jax.random.uniform(key(), (k, H), F32, 1.0, 16.0)),
+                "D": 1.0 + 0.1 * jax.random.normal(key(), (k, H), F32),
+                "norm": jnp.ones((k, cfg.ssd_inner), dt),
+                "w_out": w((k, cfg.ssd_inner, d), cfg.ssd_inner)}
+        elif mixer == "mamba":
             step = jnp.exp(jax.random.uniform(key(), (k, di), F32,
                                               np.log(1e-3), np.log(1e-1)))
             p["mamba"] = {
@@ -438,12 +530,14 @@ def _normed(cfg: HybridConfig, x, w):
     return h32, h32.astype(cfg.dtype)
 
 
-def _ffn(cfg: HybridConfig, p, h32, h, valid):
+def _ffn(cfg: HybridConfig, p, h32, h, valid, stacks=None, layer=None):
     """h [T, d] (and h32, the same in float32) -> (FFN output [T, d],
     assignments landed, experts touched, the experts each token chose
     [T, k] or None for a dense layer). `valid` [T] bool: tokens that are no
     padding and no idle slot; the others are routed nowhere (their rows of
-    the result are not used)."""
+    the result are not used). A scanned run hands its experts' weights as
+    `stacks` (w_gate, w_up, w_down [k, Eh, ...], the whole run's) and names
+    its `layer`, so that they are read in place (`dropless_moe`)."""
     if "ffn" in p:
         f = p["ffn"]
         zero = jnp.zeros((), jnp.int32)
@@ -452,11 +546,14 @@ def _ffn(cfg: HybridConfig, p, h32, h, valid):
     m = p["moe"]
     with jax.named_scope("moe"):
         # routed on the float32 activations, computed on the rounded ones
-        idx, w = route_top_k(h32, m["router"], m["bias"], cfg.top_k,
-                             cfg.route_scale, cfg.renormalize)
+        if cfg.router == "softmax":
+            idx, w = route_softmax_top_k(h32, m["router"], cfg.top_k)
+        else:
+            idx, w = route_top_k(h32, m["router"], m["bias"], cfg.top_k,
+                                 cfg.route_scale, cfg.renormalize)
         y, landed, touched = dropless_moe(
-            h, idx, w, m["w_gate"], m["w_up"], m["w_down"], cfg.experts_held,
-            cfg.n_experts, valid)
+            h, idx, w, *(stacks or (m["w_gate"], m["w_up"], m["w_down"])),
+            cfg.experts_held, cfg.n_experts, valid, layer)
     with jax.named_scope("shared_expert"):
         s = m["shared"]
         y = y + swiglu(h @ s["w_gate"], h @ s["w_up"]) @ s["w_down"]
@@ -477,12 +574,111 @@ def _ffn_half(cfg: HybridConfig, p, x, valid):
             touched, chosen)
 
 
+def _add(cfg: HybridConfig, x, y):
+    """x + residual_scale * y on the float32 stream of a run: the scalar
+    scales what the mixer or the FFN gives, never the stream."""
+    y = y.astype(F32)
+    if cfg.residual_scale != 1.0:
+        y = y * cfg.residual_scale
+    return x + y
+
+
 def _dense_ffn(cfg: HybridConfig, lp, x):
     """x += SwiGLU(RMSNorm(x)) of one layer of a run (float32 residual)."""
     with jax.named_scope("mlp"):
         _, h = _normed(cfg, x, lp["ffn_norm"])
         f = lp["ffn"]
-        return x + (swiglu(h @ f["w_gate"], h @ f["w_up"]) @ f["w_down"]).astype(F32)
+        return _add(cfg, x, swiglu(h @ f["w_gate"], h @ f["w_up"]) @ f["w_down"])
+
+
+def _run_ffn(cfg: HybridConfig, lp, x, valid, stacks=None, layer=None):
+    """The second half of one layer of a run: x [..., d] float32 -> (x +
+    FFN(RMSNorm(x)), what an expert layer adds to the scan's ys: ([assignments
+    landed, experts touched] int32, the experts chosen [..., k]); () for a
+    dense layer). `valid` [...] bool as `_ffn`: the tokens that are no
+    padding and no idle slot; `stacks`, `layer` as `_ffn` (`_expert_stacks`)."""
+    if "ffn" in lp:
+        return _dense_ffn(cfg, lp, x), ()
+    h32, h = _normed(cfg, x, lp["ffn_norm"])
+    d = x.shape[-1]
+    h32, h, ok = h32.reshape(-1, d), h.reshape(-1, d), valid.reshape(-1)
+    T = h.shape[0]
+    if T > _FFN_BLOCK and T % _FFN_BLOCK == 0:
+        # a long prompt's tokens go a block at a time: the layer gathers and
+        # weighs [block x k, d] rows, not [12288 x 10, 4096] (2 GB in float32)
+        blocks = lambda a: a.reshape((T // _FFN_BLOCK, _FFN_BLOCK) + a.shape[1:])
+        y, landed, touched, chosen = jax.lax.map(
+            lambda t: _ffn(cfg, lp, *t, stacks, layer),
+            (blocks(h32), blocks(h), blocks(ok)))
+        y, chosen = y.reshape(T, d), chosen.reshape(T, -1)
+        # an expert counts as touched once a block (the decode step, whose
+        # counters the engine reports, is one block)
+        landed, touched = jnp.sum(landed), jnp.sum(touched)
+    else:
+        y, landed, touched, chosen = _ffn(cfg, lp, h32, h, ok, stacks, layer)
+    return (_add(cfg, x, y.reshape(x.shape)),
+            (jnp.stack([landed, touched]), chosen.reshape(x.shape[:-1] + (-1,))))
+
+
+def _expert_stacks(rp):
+    """A run's weights split for its scan: (what the scan slices a layer at a
+    time, the experts' three stacks [k, Eh, ...] or None for a run of dense
+    FFNs). The stacks stay whole beside the scan and every layer's grouped
+    products read their own part in place (`dropless_moe`'s `layer`)."""
+    if "moe" not in rp:
+        return rp, None
+    names = ("w_gate", "w_up", "w_down")
+    return ({**rp, "moe": {n: a for n, a in rp["moe"].items() if n not in names}},
+            tuple(rp["moe"][n] for n in names))
+
+
+def _mamba2_dt(m, h):
+    """The step size dt [..., H] after its softplus, float32, from the
+    normed residual h [..., d]."""
+    # float32 sums: dt feeds an exponent that compounds over the positions
+    return jax.nn.softplus(jnp.dot(h, m["w_dt"], preferred_element_type=F32)
+                           + m["dt_bias"])
+
+
+def _mamba2_output(cfg: HybridConfig, m, y, z):
+    """y [..., H P] float32 and the gate's input z -> the mixer's output
+    [..., d]: the gate FIRST, then one RMSNorm over all the channels (one
+    group), then the output projection."""
+    with jax.named_scope("gated_norm"):
+        g = rms_norm(y * jax.nn.silu(z.astype(F32)), m["norm"], cfg.norm_eps)
+    return g.astype(cfg.dtype) @ m["w_out"]
+
+
+def _mamba2_seq(cfg: HybridConfig, m, h, valid, true_len):
+    """h [b, s, d] (normed) -> (the mixer's output [b, s, d], the state after
+    the last true position [b, N, H P] float32, the convolution tail
+    [b, K-1, H P + 2 N])."""
+    z, pre = jnp.split(h @ m["w_in"], (cfg.ssd_inner,), axis=-1)
+    with jax.named_scope("conv"):
+        xbc = jax.nn.silu(kda.short_conv(pre.astype(F32), m["conv"].astype(F32))
+                          + m["conv_bias"])
+    with jax.named_scope("scan"):
+        y, state = ssd.ssd_scan(xbc, _mamba2_dt(m, h), -jnp.exp(m["A_log"]), m["D"],
+                                cfg.ssd_state, None, valid, cfg.ssd_chunk)
+    return (_mamba2_output(cfg, m, y, z), state,
+            kda.conv_tail(pre, true_len, cfg.conv_kernel))
+
+
+def _mamba2_step(cfg: HybridConfig, m, h, ssm, layer, slots, busy, tail):
+    """One token: h [B, d]; ssm the run's stacked state [k, B, N, H P], of
+    which `layer` advances in place (`ssd.ssd_step_slots`); tail
+    [B, K-1, H P + 2 N] -> (output [B, d], ssm, tail)."""
+    z, pre = jnp.split(h @ m["w_in"], (cfg.ssd_inner,), axis=-1)
+    with jax.named_scope("conv"):
+        y, tail = kda.short_conv_step(pre, tail, m["conv"])
+        xbc = jax.nn.silu(y + m["conv_bias"])
+    x, B, C = jnp.split(xbc, (cfg.ssd_inner, cfg.ssd_inner + cfg.ssd_state), axis=-1)
+    with jax.named_scope("step"):
+        ssm, y = ssd.ssd_step_slots(
+            ssm, layer, slots, busy,
+            x.reshape(x.shape[:-1] + (cfg.ssd_heads, cfg.ssd_head_dim)),
+            _mamba2_dt(m, h), -jnp.exp(m["A_log"]), B, C, m["D"])
+    return _mamba2_output(cfg, m, y, z), ssm, tail
 
 
 def _mamba_inputs(cfg: HybridConfig, m, u):
@@ -634,46 +830,71 @@ def _mtp_sequence(params, hidden, following, true_len, cfg: HybridConfig):
 
 def _sequence_runs(params, tokens, true_len, cfg: HybridConfig):
     """`_sequence` for the runs form: every run one `lax.scan` over its
-    stacked weights. State rows: per Mamba run "ssm" [k, b, n, di] float32
-    and "conv" [k, b, K-1, di]; "k", "v" [attention layers, b, kvh, s, hd]."""
+    stacked weights. State rows: per Mamba run "ssm" ([k, b, n, di] float32,
+    Mamba-2: [k, b, N, H P]) and "conv" [k, b, K-1, channels]; "k", "v"
+    [attention layers, b, kvh, s, hd]. The experts chosen: per run of expert
+    layers [k, b, s, top_k]."""
     if cfg.eva_layers:
         return _sequence_eva(params, tokens, true_len, cfg)
     b, s = tokens.shape
     H, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     x = params["embed"][tokens].astype(F32)
+    if cfg.embed_scale != 1.0:
+        x = x * cfg.embed_scale
     valid = jnp.arange(s)[None, :] < true_len[:, None]               # [b, s]
 
-    def mamba_layer(x, lp):
-        with jax.named_scope("mamba"):
-            _, h = _normed(cfg, x, lp["mixer_norm"])
-            out, state, tail = _mamba_seq(cfg, lp["mamba"], h, valid, true_len)
-            x = x + out.astype(F32)
-        return _dense_ffn(cfg, lp, x), (state, tail)
+    def layer_of(mixer, stacks):
+        """The scan body of one run: xs is the run's sliced weights, beside
+        the layer's number in the run where it holds expert layers."""
+        def mamba_layer(x, xs):
+            lp, i = xs if stacks else (xs, None)
+            with jax.named_scope("mamba" if mixer == "mamba" else "ssd"):
+                _, h = _normed(cfg, x, lp["mixer_norm"])
+                out, state, tail = (_mamba_seq if mixer == "mamba" else _mamba2_seq)(
+                    cfg, lp[mixer], h, valid, true_len)
+                x = _add(cfg, x, out)
+            x, routed = _run_ffn(cfg, lp, x, valid, stacks, i)
+            return x, (state, tail) + routed
 
-    def attn_layer(x, lp):
-        with jax.named_scope("attention"):
-            _, h = _normed(cfg, x, lp["mixer_norm"])
-            q, k, v = _attn_qkv(cfg, lp["attn"], h)
-            attn = causal_attention_blocked(
-                q, jnp.repeat(k, H // kvh, axis=2), jnp.repeat(v, H // kvh, axis=2),
-                sm_scale=hd ** -0.5)
-            x = x + (attn.reshape(b, s, H * hd) @ lp["attn"]["wo"]).astype(F32)
-        return _dense_ffn(cfg, lp, x), (jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2))
+        def attn_layer(x, xs):
+            lp, i = xs if stacks else (xs, None)
+            with jax.named_scope("attention"):
+                _, h = _normed(cfg, x, lp["mixer_norm"])
+                q, k, v = _attn_qkv(cfg, lp["attn"], h)
+                scale = cfg.attn_scale or hd ** -0.5
+                if s % 1024 == 0:
+                    # whole blocks of the flash kernel (a prompt of thousands of
+                    # positions: the blocked form's scores alone are 0.8 GB at
+                    # 32 heads x 512 x 12288)
+                    attn = jnp.moveaxis(attention(
+                        *(jnp.moveaxis(t, 1, 2) for t in (q, k, v)), sm_scale=scale), 1, 2)
+                else:
+                    attn = causal_attention_blocked(
+                        q, jnp.repeat(k, H // kvh, axis=2),
+                        jnp.repeat(v, H // kvh, axis=2), sm_scale=scale)
+                x = _add(cfg, x, attn.reshape(b, s, H * hd) @ lp["attn"]["wo"])
+            x, routed = _run_ffn(cfg, lp, x, valid, stacks, i)
+            return x, (jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2)) + routed
+
+        return attn_layer if mixer == "attn" else mamba_layer
 
     rows = {"ssm": [], "conv": [], "k": [], "v": []}
-    for rp, (mixer, _) in zip(params["runs"], cfg.runs()):
-        if mixer == "mamba":
-            x, (state, tail) = jax.lax.scan(mamba_layer, x, rp)
-            rows["ssm"].append(state)
-            rows["conv"].append(tail)
-        else:
-            x, (k, v) = jax.lax.scan(attn_layer, x, rp)
-            rows["k"].append(k)
-            rows["v"].append(v)
+    routing = []
+    # ONE body a mixer for the runs of dense FFNs (jax then lowers a like
+    # run's scan, and its kernel, once)
+    dense = {mixer: layer_of(mixer, None) for mixer, _ in cfg.runs()}
+    for rp, (mixer, k) in zip(params["runs"], cfg.runs()):
+        rp, stacks = _expert_stacks(rp)
+        x, ys = jax.lax.scan(layer_of(mixer, stacks) if stacks else dense[mixer], x,
+                             (rp, jnp.arange(k)) if stacks else rp)
+        for name, a in zip(("k", "v") if mixer == "attn" else ("ssm", "conv"), ys):
+            rows[name].append(a)
+        if len(ys) > 2:
+            routing.append(ys[3])
     for name in ("k", "v"):
         rows[name] = jnp.concatenate(rows[name]) if rows[name] else \
             jnp.zeros((0, b, kvh, s, hd), cfg.dtype)
-    return x, rows, []
+    return x, rows, routing
 
 
 def _sequence_eva(params, tokens, true_len, cfg: HybridConfig,
@@ -771,7 +992,10 @@ def _head(params, x, cfg: HybridConfig = None, all_heads: bool = False):
         return logits.reshape(x.shape[:-1] + (-1, V)) if all_heads else logits
     if "lm_head" in params:
         return (x @ params["lm_head"]).astype(F32)
-    return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(F32)
+    logits = jnp.einsum("...d,vd->...v", x, params["embed"]).astype(F32)
+    if cfg is not None and cfg.logit_divisor != 1.0:
+        logits = logits / cfg.logit_divisor
+    return logits
 
 
 def _following(tokens, true_len, first):
@@ -839,8 +1063,9 @@ def prefill(params, tokens, true_len, cfg: HybridConfig,
         routing = routing + [chosen]
         if with_routing:
             rows["mtp_logits"] = mtp_logits
-    if with_routing:
-        rows["routing"] = jnp.stack(routing)
+    if with_routing:   # the runs form: a run's expert layers come stacked
+        rows["routing"] = jnp.concatenate(routing) if cfg.scanned \
+            else jnp.stack(routing)
     return logits, rows
 
 
@@ -950,17 +1175,22 @@ def _decode(params, state, lengths, tokens, active, cfg: HybridConfig,
 
 def _decode_runs(params, state, lengths, tokens, cfg: HybridConfig,
                  attn_len: int):
-    """`_decode` for the runs form -> (state, logits [B, vocab] float32). A
-    Mamba run's stacked state is the scan's CARRY: layer i reads its slice
-    and writes it back in place. The K/V cache is read-only inside the scans
-    (the current token's row joins the softmax as a term of its own, STRICT
-    mask) and every attention layer's row is written once, afterwards. A
-    slot of length 0 is idle: its attention reads nothing and, on the TPU,
-    its recurrent state is neither read nor written (elsewhere it runs on
-    over whatever token the slot holds); it is replaced at admission."""
+    """`_decode` for the runs form -> (state, logits [B, vocab] float32,
+    [assignments landed, experts touched] summed over the expert layers or
+    None where every FFN is dense, the experts chosen: per run of expert
+    layers [k, B, top_k]). A Mamba run's stacked state is the scan's CARRY:
+    layer i reads its slice and writes it back in place. The K/V cache is
+    read-only inside the scans (the current token's row joins the softmax as
+    a term of its own, STRICT mask) and every attention layer's row is
+    written once, afterwards. A slot of length 0 is idle: its attention
+    reads nothing, it is routed to no expert and, on the TPU, its recurrent
+    state is neither read nor written (elsewhere it runs on over whatever
+    token the slot holds); it is replaced at admission."""
     B = tokens.shape[0]
     H, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     x = params["embed"][tokens].astype(F32)
+    if cfg.embed_scale != 1.0:
+        x = x * cfg.embed_scale
     k_all, v_all = state["k"], state["v"]
     # as the dense step chooses (`models/serving.py:decode_step_fused`): on a
     # TPU at shapes that tile, the kernel over each slot's live rows
@@ -971,24 +1201,27 @@ def _decode_runs(params, state, lengths, tokens, cfg: HybridConfig,
         mask = jnp.arange(attn_len)[None, :] < lengths[:, None]
 
     busy = lengths > 0
-    slots = mamba.live_slots(lengths) \
-        if state["ssm"] and mamba.uses_step_kernel(state["ssm"][0]) else None
+    step_ops = ssd if cfg.mamba2_layers else mamba
+    slots = step_ops.live_slots(lengths) \
+        if state["ssm"] and step_ops.uses_step_kernel(state["ssm"][0]) else None
 
-    def mamba_layer(carry, inputs):
+    def mamba_layer(carry, inputs, stacks=None):
         x, ssm, conv = carry
         lp, i = inputs
-        with jax.named_scope("mamba"):
+        kind = "mamba2" if "mamba2" in lp else "mamba"
+        with jax.named_scope("ssd" if kind == "mamba2" else "mamba"):
             _, h = _normed(cfg, x, lp["mixer_norm"])
-            out, ssm, t_new = _mamba_step(
-                cfg, lp["mamba"], h, ssm, i, slots, busy,
+            out, ssm, t_new = (_mamba2_step if kind == "mamba2" else _mamba_step)(
+                cfg, lp[kind], h, ssm, i, slots, busy,
                 jax.lax.dynamic_index_in_dim(conv, i, 0, keepdims=False))
-            x = x + out.astype(F32)
+            x = _add(cfg, x, out)
         with jax.named_scope("state_write"):
             conv = jax.lax.dynamic_update_index_in_dim(conv, t_new, i, 0)
-        return (_dense_ffn(cfg, lp, x), ssm, conv), None
+        x, routed = _run_ffn(cfg, lp, x, busy, stacks, i)
+        return (x, ssm, conv), routed or None
 
-    def attn_layer(x, inputs):
-        lp, layer = inputs
+    def attn_layer(x, inputs, stacks=None, first=0):
+        lp, layer = inputs            # `layer` among the attention layers
         with jax.named_scope("attention"):
             _, h = _normed(cfg, x, lp["mixer_norm"])
             q, k_cur, v_cur = _attn_qkv(cfg, lp["attn"], h)
@@ -996,38 +1229,48 @@ def _decode_runs(params, state, lengths, tokens, cfg: HybridConfig,
             if kernel:
                 attn = decode_attention.gqa_decode_attention(
                     q.reshape(B, kvh, H // kvh, hd), k_cur, v_cur, k_all, v_all,
-                    layer, items, attn_len)
+                    layer, items, attn_len, cfg.attn_scale)
             else:
                 win = (1, B, kvh, attn_len, hd)
                 attn = _gqa_decode_attention(
                     q[:, :, None],
                     jax.lax.dynamic_slice(k_all, (layer, 0, 0, 0, 0), win)[0],
                     jax.lax.dynamic_slice(v_all, (layer, 0, 0, 0, 0), win)[0],
-                    k_cur, v_cur, mask)
-            x = x + (attn.reshape(B, H * hd) @ lp["attn"]["wo"]).astype(F32)
-        return _dense_ffn(cfg, lp, x), (k_cur, v_cur)
+                    k_cur, v_cur, mask, cfg.attn_scale)
+            x = _add(cfg, x, attn.reshape(B, H * hd) @ lp["attn"]["wo"])
+        x, routed = _run_ffn(cfg, lp, x, busy, stacks,
+                             layer - first if stacks else None)   # its number in the run
+        return x, (k_cur, v_cur) + routed
 
-    ssm_new, conv_new, k_cur, v_cur = [], [], [], []
+    ssm_new, conv_new, k_cur, v_cur, counts, routing = [], [], [], [], [], []
     held, n_attn = iter(zip(state["ssm"], state["conv"])), 0
     for rp, (mixer, k) in zip(params["runs"], cfg.runs()):
-        if mixer == "mamba":
-            (x, ssm, conv), _ = jax.lax.scan(mamba_layer, (x, *next(held)),
-                                             (rp, jnp.arange(k)))
+        rp, stacks = _expert_stacks(rp)
+        own = lambda fn, **at: functools.partial(fn, stacks=stacks, **at) \
+            if stacks else fn
+        if mixer != "attn":
+            (x, ssm, conv), routed = jax.lax.scan(
+                own(mamba_layer), (x, *next(held)), (rp, jnp.arange(k)))
             ssm_new.append(ssm)
             conv_new.append(conv)
         else:
-            x, (kc, vc) = jax.lax.scan(attn_layer, x, (rp, n_attn + jnp.arange(k)))
+            x, (kc, vc, *routed) = jax.lax.scan(
+                own(attn_layer, first=n_attn), x, (rp, n_attn + jnp.arange(k)))
             k_cur.append(kc)
             v_cur.append(vc)
             n_attn += k
+        if routed:
+            counts.append(jnp.sum(routed[0], axis=0))
+            routing.append(routed[1])
     if k_cur:
         with jax.named_scope("state_write"):
             k_all = write_rows(k_all, jnp.concatenate(k_cur), lengths)
             v_all = write_rows(v_all, jnp.concatenate(v_cur), lengths)
     with jax.named_scope("head"):
         _, x = _normed(cfg, x, params["final_norm"])
-        logits = _head(params, x)
-    return {"ssm": ssm_new, "conv": conv_new, "k": k_all, "v": v_all}, logits
+        logits = _head(params, x, cfg)
+    return ({"ssm": ssm_new, "conv": conv_new, "k": k_all, "v": v_all}, logits,
+            sum(counts) if counts else None, routing)
 
 
 def _decode_eva(params, state, lengths, tokens, cfg: HybridConfig,
@@ -1113,15 +1356,18 @@ def decode_logits(params, state, lengths, tokens, active, cfg: HybridConfig,
     `decode_step` over the same slot state (DONATED) and the same `active`
     mask, returning (state, logits [B, vocab], the experts every slot chose
     [expert layers, B, k]) instead of sampling. The runs form has no
-    routing ([0, B, 0]) and takes no `active`. A configuration that drafts
+    routing ([0, B, 0]) unless it holds expert layers, and takes no `active`
+    (a slot is busy iff its length is above 0). A configuration that drafts
     is checked through `verify_logits`."""
     if cfg.scanned:
         if cfg.eva_layers:
             state, logits, _ = _decode_eva(params, state, lengths, tokens, cfg,
                                            attn_len)
         else:
-            state, logits = _decode_runs(params, state, lengths, tokens, cfg,
-                                         attn_len)
+            state, logits, _, routing = _decode_runs(params, state, lengths, tokens,
+                                                     cfg, attn_len)
+            if routing:
+                return state, logits, jnp.concatenate(routing)
         return state, logits, jnp.zeros((0, tokens.shape[0], 0), jnp.int32)
     state, logits, _, _, routing = _decode(params, state, lengths, tokens[:, None],
                                            active, cfg, attn_len)
@@ -1167,17 +1413,21 @@ def decode_step(params, state, lengths, tokens, active, cfg: HybridConfig,
     proposed, drafts accepted].
 
     The runs form keeps the dense engine's rule for idle slots (length 0
-    stays 0) and has no counters: its report is the tokens. A stack of EVA
-    layers counts the [chunks, windows] its slots closed behind them."""
+    stays 0); over dense FFNs it has no counters and its report is the
+    tokens, with expert layers it counts [assignments landed, experts
+    touched] behind them as the list form does. A stack of EVA layers counts
+    the [chunks, windows] its slots closed behind them."""
     if cfg.eva_layers:
         state, logits, closed = _decode_eva(params, state, lengths, tokens, cfg,
                                             attn_len)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return state, lengths + (lengths > 0), nxt, jnp.concatenate([nxt, closed])
     if cfg.scanned:
-        state, logits = _decode_runs(params, state, lengths, tokens, cfg, attn_len)
+        state, logits, counters, _ = _decode_runs(params, state, lengths, tokens,
+                                                  cfg, attn_len)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return state, lengths + (lengths > 0), nxt, nxt
+        report = nxt if counters is None else jnp.concatenate([nxt, counters])
+        return state, lengths + (lengths > 0), nxt, report
     if not cfg.n_predict:
         state, logits, _, counters, _ = _decode(
             params, state, lengths, tokens[:, None], active, cfg, attn_len)
@@ -1320,31 +1570,52 @@ class HybridCache:
 
 class RunsCache(HybridCache):
     """Per-slot state of the runs form: per Mamba run the SSM state
-    [k, slots, d_state, d_inner] float32 (channels minor, `ops/mamba.py`) and
-    the convolution tail [k, slots, K-1, d_inner]; for the attention layers
-    K and V [layers, slots, kv_heads, max_len, head_dim], written by
-    `ops.cache.write_rows` and read by `ops.pallas.decode_attention` like the
-    dense cache. Every leaf is donated whole to each call. The entry points
-    are `HybridCache`'s: the jitted programs branch on the configuration.
-    Both kinds of state here are the old two (a row a position for ever, a
-    state of fixed size); a run of EVA layers keeps a third: `EvaCache`."""
+    (Mamba-1: [k, slots, d_state, d_inner], `ops/mamba.py`; Mamba-2:
+    [k, slots, N, H P], a matrix a head, `ops/ssd.py`; float32, channels
+    minor) and the convolution tail [k, slots, K-1, channels]; for the
+    attention layers K and V [layers, slots, kv_heads, max_len, head_dim],
+    written by `ops.cache.write_rows` and read by
+    `ops.pallas.decode_attention` like the dense cache. Every leaf is donated
+    whole to each call. The entry points are `HybridCache`'s: the jitted
+    programs branch on the configuration. Both kinds of state here are the
+    old two (a row a position for ever, a state of fixed size); a run of EVA
+    layers keeps a third: `EvaCache`.
 
-    counters = ()
+    A stack with expert layers reports the list form's two counters. One
+    with Mamba-2 mixers names its prompt buckets (`prompt_bucket`): its
+    prompts run to thousands of positions, where a power of two pads an
+    8200-token prompt to 16383."""
+
     step_tokens = 1
 
     def __init__(self, cfg: HybridConfig, num_slots: int, max_len: int):
         self.cfg, self.num_slots, self.max_len = cfg, num_slots, max_len
         runs = cfg.runs()
-        self.n_mamba = sum(k for m, k in runs if m == "mamba")
+        self.n_mamba = sum(k for m, k in runs if m in ("mamba", "mamba2"))
         self.n_attn = sum(k for m, k in runs if m == "attn")
         kv = (self.n_attn, num_slots, cfg.n_kv_heads, max_len, cfg.head_dim)
+        ssm = {"mamba": (cfg.d_state, cfg.d_inner),
+               "mamba2": (cfg.ssd_state, cfg.ssd_inner)}
+        conv = {"mamba": cfg.d_inner, "mamba2": cfg.ssd_inner + 2 * cfg.ssd_state}
         self.state = {
-            "ssm": [jnp.zeros((k, num_slots, cfg.d_state, cfg.d_inner), F32)
-                    for m, k in runs if m == "mamba"],
-            "conv": [jnp.zeros((k, num_slots, cfg.conv_kernel - 1, cfg.d_inner),
-                               cfg.dtype) for m, k in runs if m == "mamba"],
+            "ssm": [jnp.zeros((k, num_slots) + ssm[m], F32)
+                    for m, k in runs if m in ssm],
+            "conv": [jnp.zeros((k, num_slots, cfg.conv_kernel - 1, conv[m]),
+                               cfg.dtype) for m, k in runs if m in conv],
             "k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype)}
         self.prefill_args = {"state_layers": self.n_mamba, "kv_layers": self.n_attn}
+        self.counters: Tuple[str, ...] = ("expert_assignments", "experts_touched") \
+            if "moe" in cfg.run_ffns() else ()
+        if cfg.mamba2_layers:
+            self.prompt_bucket = self._whole_blocks
+
+    def _whole_blocks(self, n: int) -> int:
+        """Powers of two up to 2048 positions, whole multiples of 2048 past
+        them (eight chunks of the SSD scan, two blocks of the flash kernel)."""
+        b = 8
+        while b < min(n, 2048):
+            b *= 2
+        return min(b if n <= 2048 else -(-n // 2048) * 2048, self.max_len - 1)
 
     def max_prefill_batch(self, bucket: int) -> int:
         return max(1, min(8, self.cfg.prefill_tokens // bucket))

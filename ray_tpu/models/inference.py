@@ -21,7 +21,7 @@ from ray_tpu.models.transformer import (ModelConfig, _deq_tree,
 from ray_tpu.ops.layers import rms_norm, rotary_embedding
 
 
-def _gqa_decode_attention(q, k_cache, v_cache, k_cur, v_cur, mask):
+def _gqa_decode_attention(q, k_cache, v_cache, k_cur, v_cur, mask, sm_scale=0.0):
     """Single-token grouped-query attention over a cache window plus the
     current token's (not-yet-written) K/V row.
 
@@ -31,12 +31,12 @@ def _gqa_decode_attention(q, k_cache, v_cache, k_cur, v_cur, mask):
     the separate k_cur/v_cur term). Unlike `_masked_attention` this never
     materializes GQA-repeated K/V (those copies are cache-sized, per layer,
     per step): queries are grouped [b,kvh,rep,hd] and contracted against
-    the shared K/V heads directly.
+    the shared K/V heads directly. `sm_scale` 0: 1 / sqrt(hd).
     """
     b, h, _, hd = q.shape
     kvh = k_cache.shape[1]
     qg = q[:, :, 0].reshape(b, kvh, h // kvh, hd)
-    scale = hd ** -0.5
+    scale = sm_scale or hd ** -0.5
     lg = jnp.einsum("bgrd,bgld->bgrl", qg, k_cache).astype(jnp.float32) * scale
     lg = jnp.where(mask[:, None, None, :], lg, -1e30)
     self_lg = jnp.einsum("bgrd,bgd->bgr", qg, k_cur).astype(jnp.float32) * scale

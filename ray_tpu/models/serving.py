@@ -562,7 +562,8 @@ class ContinuousBatchingEngine:
                 did["waiting"] = len(self._waiting)  # left without a slot
             for bucket, reqs in admissions:
                 with tracing.span("engine.prefill_dispatch", "engine",
-                                  bucket=bucket, batch=len(reqs)):
+                                  bucket=bucket, batch=len(reqs),
+                                  tokens=sum(len(r.prompt) for r in reqs)):
                     self._dispatch_prefill(bucket, reqs)  # device enqueue only
             t_ask = clock()
             with self._lock:

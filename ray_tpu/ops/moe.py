@@ -11,9 +11,11 @@ collectives. Static capacity keeps every shape compile-time constant
 Two expert layers live here, SPLIT on purpose (PR 28): `moe_ffn` /
 `top2_gating` is the train path's gate (softmax, top-2, a capacity that
 drops, an auxiliary loss, dense dispatch tensors that shard over a mesh
-axis); `dropless_moe` is the serving path's layer (sigmoid scores, top-k by
-score + bias, no capacity, told which experts it holds). They share no
-tensor shape and no gate, so neither is written in terms of the other.
+axis); `dropless_moe` is the serving path's layer (no capacity, told which experts
+it holds; it takes the router's choice and weights: `route_top_k`, sigmoid
+scores and top-k by score + bias, or `route_softmax_top_k`, top-k by logit
+and a softmax over the chosen). They share no tensor shape and no gate, so
+neither is written in terms of the other.
 """
 
 from __future__ import annotations
@@ -22,6 +24,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def top2_gating(router_logits: jax.Array, capacity: int):
@@ -146,10 +149,24 @@ def route_top_k(x: jax.Array, router_w: jax.Array, bias: jax.Array, top_k: int,
     return idx.astype(jnp.int32), w * scale
 
 
+def route_softmax_top_k(x: jax.Array, router_w: jax.Array, top_k: int):
+    """Logit-scored top-k routing over ALL experts, weighed by a softmax
+    over the CHOSEN logits (no bias, no scale: the weights of a token sum to
+    1): x [T, d], router_w [d, E]. Returns (expert ids [T, k] int32, weights
+    [T, k] float32), as `route_top_k` does for `dropless_moe`."""
+    # float32 at full precision, as `route_top_k`: the k-th expert hangs on
+    # small differences between neighbouring logits
+    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision="highest")
+    best, idx = jax.lax.top_k(logits, top_k)
+    return idx.astype(jnp.int32), jax.nn.softmax(best, axis=-1)
+
+
 def dropless_moe(x: jax.Array, idx: jax.Array, w: jax.Array,
                  w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
                  held: Tuple[int, ...], n_experts: int,
-                 valid: Optional[jax.Array] = None):
+                 valid: Optional[jax.Array] = None,
+                 layer: Optional[jax.Array] = None):
     """The held experts' part of a dropless expert layer.
 
     x [T, d]; `idx`, `w` [T, k] are `route_top_k`'s choice over ALL
@@ -165,12 +182,30 @@ def dropless_moe(x: jax.Array, idx: jax.Array, w: jax.Array,
     bool, if given, marks the tokens that are real (no padding, no idle
     slot): the others are routed nowhere and their rows of y are zero.
 
+    `layer` (a traced scalar), if given, says that w_gate / w_up / w_down
+    are STACKS [L, Eh, ...] of which this call's layer is `layer`: the
+    grouped products then run over all L x Eh groups with every other
+    layer's group empty, so the layer's weights are read IN PLACE out of the
+    stack. A scanned run's expert layers call it so: a layer's slice handed
+    to the product inside the tiers' conditional is written down first,
+    0.68 GB a layer and decode step at 36 experts of 4096 x 768 (read on
+    the chip, PR 49: 13 ms of a 44 ms step).
+
     Returns (y [T, d] in x's dtype, assignments that landed here (int32),
     held experts with at least one token (int32))."""
     T, d = x.shape
     top_k, Eh = idx.shape[-1], len(held)
-    local = jnp.full((n_experts,), Eh, jnp.int32).at[jnp.asarray(held)].set(
-        jnp.arange(Eh, dtype=jnp.int32))
+    if layer is None:
+        local = jnp.full((n_experts,), Eh, jnp.int32).at[jnp.asarray(held)].set(
+            jnp.arange(Eh, dtype=jnp.int32))
+    else:
+        # the same table made at trace time (`held` is static): inside a
+        # `lax.scan` XLA:TPU's scatter emitter fails on the scatter of
+        # constants above (`operand_indices.size() == 1`); the unrolled
+        # callers keep it, and with it the text they always lowered to
+        table = np.full((n_experts,), Eh, np.int32)
+        table[list(held)] = np.arange(Eh, dtype=np.int32)
+        local = jnp.asarray(table)
     group = local[idx]                              # Eh = not held here
     if valid is not None:
         group = jnp.where(valid[:, None], group, Eh)
@@ -179,6 +214,13 @@ def dropless_moe(x: jax.Array, idx: jax.Array, w: jax.Array,
     token = (order // top_k).astype(jnp.int32)
     sizes = jnp.bincount(group, length=Eh + 1)[:Eh].astype(jnp.int32)
     landed = jnp.sum(sizes)
+    groups = sizes
+    if layer is not None:
+        L = w_gate.shape[0]
+        stacks = (w_gate, w_up, w_down)
+        w_gate, w_up, w_down = (a.reshape((L * Eh,) + a.shape[2:]) for a in stacks)
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((L * Eh,), jnp.int32), sizes, (layer * Eh,))
     # row j of token t sits at inverse[t, j] of the sorted assignments
     inverse = jnp.zeros((T * top_k,), jnp.int32).at[order].set(
         jnp.arange(T * top_k, dtype=jnp.int32)).reshape(T, top_k)
@@ -188,9 +230,9 @@ def dropless_moe(x: jax.Array, idx: jax.Array, w: jax.Array,
         (the landed ones come first), weighed and summed per token."""
         def run(_):
             head = x[token[:rows]]                  # [rows, d], sorted by expert
-            act = jax.nn.silu(jax.lax.ragged_dot(head, w_gate, sizes)) \
-                * jax.lax.ragged_dot(head, w_up, sizes)
-            out = jax.lax.ragged_dot(act.astype(x.dtype), w_down, sizes)
+            act = jax.nn.silu(jax.lax.ragged_dot(head, w_gate, groups)) \
+                * jax.lax.ragged_dot(head, w_up, groups)
+            out = jax.lax.ragged_dot(act.astype(x.dtype), w_down, groups)
             # back to token order by a gather; an assignment that did not
             # land here reads the zero row behind the last
             out = jnp.concatenate([out, jnp.zeros((1, d), out.dtype)])
@@ -210,8 +252,10 @@ def dropless_moe(x: jax.Array, idx: jax.Array, w: jax.Array,
             out = (jax.nn.silu(x @ gate) * (x @ up)).astype(x.dtype) @ down
             return y + out.astype(jnp.float32) * weight[:, None], None
 
+        mine = (w_gate, w_up, w_down) if layer is None else tuple(
+            jax.lax.dynamic_index_in_dim(a, layer, 0, False) for a in stacks)
         y, _ = jax.lax.scan(one, jnp.zeros((T, d), jnp.float32),
-                            (w_gate, w_up, w_down, jnp.arange(Eh)))
+                            mine + (jnp.arange(Eh),))
         return y
 
     # A grouped product costs each touched expert one row TILE of work, and

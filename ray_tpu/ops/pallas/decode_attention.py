@@ -193,12 +193,13 @@ def _kernel(layer_ref, slot_ref, block_ref, count_ref, held_ref,  # scalars
 
 def gqa_decode_attention(q: jax.Array, k_cur: jax.Array, v_cur: jax.Array,
                          k_all: jax.Array, v_all: jax.Array, layer: jax.Array,
-                         items, attn_len: int) -> jax.Array:
+                         items, attn_len: int, sm_scale: float = 0.0) -> jax.Array:
     """q [B, kvh, rep, hd]; k_cur / v_cur [B, kvh, hd] (the current token's
     row, not in the cache yet); k_all / v_all [L, B, kvh, max_len, hd];
     `layer` a scalar; `items` = `live_items(lengths, attn_len)` ->
     [B, kvh, rep, hd]: slot b attends rows [0, min(lengths[b], attn_len)) of
-    layer `layer` and its own row."""
+    layer `layer` and its own row, under a softmax of `sm_scale` q . k
+    (0: 1 / sqrt(hd))."""
     B, kvh, rep, hd = q.shape
     rows = block_rows(attn_len)
     # the query group fills whole sublanes of the float32 statistics
@@ -210,7 +211,7 @@ def gqa_decode_attention(q: jax.Array, k_cur: jax.Array, v_cur: jax.Array,
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
-        functools.partial(_kernel, rows=rows, scale=hd ** -0.5),
+        functools.partial(_kernel, rows=rows, scale=sm_scale or hd ** -0.5),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(),

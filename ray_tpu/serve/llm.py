@@ -23,7 +23,8 @@ class LLMReplica:
         """`preset` names a static constructor of `ModelConfig` (`tiny`,
         `b1`: the dense block) or of `HybridConfig` (`tiny_hybrid`: KDA and
         MLA mixers, dropless experts; `tiny_runs`: Mamba and attention mixers
-        as scanned runs); the engine is the same class."""
+        as scanned runs; `tiny_granite`: Mamba-2 and attention mixers over
+        scanned expert layers); the engine is the same class."""
         import jax
 
         from ray_tpu.models import ModelConfig, hybrid, init_params

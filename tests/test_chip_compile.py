@@ -494,6 +494,79 @@ def test_jamba_prompt_pass_runs_the_scan_kernel(chip):
     assert c.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+def _granite(chip):
+    """(cfg, parameter shapes, slot-state shapes, slots) of the Granite cell."""
+    import json
+    import sys
+
+    from ray_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from perfbench.lib import granite_model
+
+    with open(os.path.join(root, "perfbench", "configs",
+                           "granite-4.0-h-small.1of2.json")) as f:
+        conf = json.load(f)
+    cfg = granite_model.model_config(conf)
+    slots, max_len = conf["run"]["num_slots"], conf["run"]["max_len"]
+    as_shapes = lambda tree: jax.tree_util.tree_map(
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = as_shapes(jax.eval_shape(lambda k: hybrid.init_params(k, cfg),
+                                      jax.random.PRNGKey(0)))
+    state = as_shapes(jax.eval_shape(lambda: cfg.make_cache(slots, max_len).state))
+    return cfg, params, state, slots
+
+
+def test_granite_decode_step_walks_busy_slots_and_scans_expert_layers(chip):
+    """The runs form with expert layers at the benchmark cell's real shapes
+    (one period of Granite-4.0-H-Small: 5 Mamba-2, attention, 4 Mamba-2 as
+    three scanned runs, 36 of 72 experts held in each, 32 slots x 16384):
+    the stacked matrix state of both Mamba-2 runs (1.21 GB float32) rides
+    the scans as a carry through the `ssd_step` kernel that aliases it, the
+    attention layer reads K/V through the live-rows kernel under
+    `attention_multiplier`, and the expert layers' grouped products compile
+    INSIDE the scans (the routing table is made at trace time: as a scatter
+    of constants in a loop body XLA:TPU's scatter emitter fails on it)."""
+    from ray_tpu.models import hybrid
+
+    cfg, params, state, slots = _granite(chip)
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(params))
+    assert 9.4e9 < weights < 9.6e9                     # 4,757M parameters, bf16
+    state_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(state))
+    assert 3.3e9 < state_bytes < 3.45e9                # 32 x 105.3 MB
+    ints = chip((slots,), jnp.int32)
+    c = hybrid.decode_step.lower(params, state, ints, ints,
+                                 chip((slots,), jnp.bool_), cfg, 16384).compile()
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < 1.0e9
+    text = c.as_text()
+    assert "ssd_step" in text and "gqa_decode_attention" in text
+    assert "ragged" in text
+    for ssm in state["ssm"]:   # the kernel's aliased operand is not copied
+        assert _whole_cache_relayouts(c, ssm) == []
+
+
+def test_granite_prompt_pass_fits_beside_weights_and_slots(chip):
+    """The longest prompt bucket, 1 x 12288: Mosaic takes the `ssd_scan`
+    kernel at the published widths (groups of 8 heads of 64, chunks of 256,
+    x and C read in place out of the convolved [x | B | C]) and the flash
+    kernel for the one attention layer; the expert layers take their tokens
+    2048 at a time; what the pass needs beside 9.51 GB of weights and 3.37 GB
+    of slots stays under 2.6 GB (it was 5.3 with the expert layer over all
+    12288 tokens at once and dt x written down)."""
+    from ray_tpu.models import hybrid
+
+    cfg, params, _, _ = _granite(chip)
+    c = hybrid._prefill_first.lower(params, chip((1, 12288), jnp.int32),
+                                    chip((1,), jnp.int32), cfg).compile()
+    text = c.as_text()
+    assert text.count("ssd_scan") >= 2 and "flash" in text
+    assert c.memory_analysis().temp_size_in_bytes < 2.6e9
+
+
 def _pangu(chip):
     """(cfg, parameter shapes, slot-state shapes, slots) of the openPangu cell."""
     import json

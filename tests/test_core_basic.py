@@ -891,8 +891,13 @@ def test_lease_with_a_starting_worker_can_be_taken_back(
         assert freed == [(lease.proc, [0, 1], list(range(2, 8)))]
         with pytest.raises(Exception):  # its owner hears the worker died
             ray_tpu.get(ref, timeout=30)
-    _wait_for(lambda: not os.path.exists(f"/proc/{pid}")
-              or open(f"/proc/{pid}/stat").read().split()[2] == "Z")
+    def gone():   # reaped between the two looks: gone too
+        try:
+            return open(f"/proc/{pid}/stat").read().split()[2] == "Z"
+        except FileNotFoundError:
+            return True
+
+    _wait_for(gone)
     _all_chips_back(raylet)
 
 
