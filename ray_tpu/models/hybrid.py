@@ -28,7 +28,9 @@ Two ways to hold and run the stack, by what the configuration lists:
   layer, then expert layers; untied head): the layers are a LIST of
   per-layer dicts, `params["layers"]`, and the stack is unrolled (nine
   layers in the benchmark's cut); per-layer leaves let the decode step
-  donate and rewrite each layer's state in place.
+  donate and rewrite each layer's state in place. Each layer is two calls
+  of a jitted body chosen by KIND ("the list form's layer bodies", below):
+  a program traces and lowers one body a kind, not one a layer.
 - `mamba_layers` / `mamba2_layers` / `attn_layers` (the Jamba and Granite
   families: Mamba-1 or Mamba-2 and attention mixers, each over a dense FFN
   or an expert layer, head tied to the embedding): 28 layers unrolled would
@@ -84,6 +86,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import inspect
+import threading
 from typing import Any, Dict, List, Tuple
 
 import jax
@@ -97,6 +101,7 @@ from ray_tpu.ops.cache import write_rows
 from ray_tpu.ops.layers import rms_norm, swiglu
 from ray_tpu.ops.moe import dropless_moe, route_softmax_top_k, route_top_k
 from ray_tpu.ops.pallas import decode_attention, eva_decode
+from ray_tpu.util import tracing
 
 F32 = jnp.float32
 _FFN_BLOCK = 2048     # tokens a scanned expert layer takes at a time (`_run_ffn`)
@@ -560,18 +565,162 @@ def _ffn(cfg: HybridConfig, p, h32, h, valid, stacks=None, layer=None):
     return y, landed, touched, idx
 
 
+# ------------------------------------------- the list form's layer bodies
+#
+# A list-form layer is a mixer half and an FFN half, each ONE module-level
+# jitted body a kind: `_kda_seq` / `_kda_step`, `_mla_seq` / `_mla_step`,
+# `_ffn_rows` (dense, or experts + shared: two parameter trees). Static:
+# `cfg`, and `attn_len` for the MLA step alone; everything else is data (the
+# half's own parameters, x, its state rows, the MLA layer's index into the
+# latent table), so the layers of a kind, a prediction module's among them,
+# are the same call to jax: it traces a body once per (kind, shapes) in a
+# process, whichever program asks first, and lowers it once per program as
+# a private function that the kind's other layers call. XLA inlines the
+# calls: the compiled step holds none (`tests/test_chip_compile.py`).
+
+class _Bodies(threading.local):
+    traced = 0   # bodies this thread ever traced
+
+
+_bodies = _Bodies()
+
+
+def _layer_body(*static):
+    """`jax.jit` for a half-layer's body (`cfg` static, and `static`); the
+    Python body runs on a trace-cache miss only, and counts it."""
+    def jitted(fn):
+        @functools.wraps(fn)
+        def body(*args, **kwargs):
+            _bodies.traced += 1
+            return fn(*args, **kwargs)
+        return jax.jit(body, static_argnames=("cfg",) + static)
+    return jitted
+
+
+def _layered_program(fn):
+    """For a jitted function that runs the stack: its `xla.compile` spans
+    carry `layers` (the list form's, a prediction module's among them) and
+    `layer_bodies_traced`, how many bodies THIS program traced anew. A layer
+    whose parameters differ from its kind's in a shape, a type or a weak
+    type misses the cache in silence; the count says so."""
+    cfg_of = inspect.signature(fn).bind_partial
+
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        before = _bodies.traced
+        out = fn(*args, **kwargs)
+        cfg = cfg_of(*args, **kwargs).arguments["cfg"]
+        if not cfg.scanned:
+            tracing.note_compile(
+                fn.__name__, layers=cfg.n_layers + cfg.n_predict,
+                layer_bodies_traced=_bodies.traced - before)
+        return out
+    return program
+
+
+_FFN_HALF = ("ffn_norm", "ffn_post_norm", "ffn", "moe")
+
+
+def _halves(p):
+    """A list-form layer's parameters -> (what its mixer half reads, what its
+    FFN half reads): a body shares its trace among the layers that hand it
+    the same tree, so a KDA layer's FFN is an MLA layer's."""
+    ffn = {k: a for k, a in p.items() if k in _FFN_HALF}
+    return {k: a for k, a in p.items() if k not in ffn}, ffn
+
+
+@_layer_body()
+def _ffn_rows(cfg: HybridConfig, p, x, valid):
+    """The FFN half over rows: x [T, d] float32 -> (x + FFN(RMSNorm(x)),
+    assignments landed, experts touched, the experts chosen [T, k] or None
+    for a dense layer); `valid` [T] as `_ffn`."""
+    h32, h = _normed(cfg, x, p["ffn_norm"])
+    y, landed, touched, chosen = _ffn(cfg, p, h32, h, valid)
+    return _residual(cfg, p, "ffn_post_norm", x, y), landed, touched, chosen
+
+
 def _ffn_half(cfg: HybridConfig, p, x, valid):
     """The second half of a list-form block: x [..., d] float32 ->
     (x + FFN(RMSNorm(x)), assignments landed, experts touched, the experts
-    chosen [..., k] or None for a dense layer); `valid` [...] as `_ffn`."""
+    chosen [..., k] or None for a dense layer); `valid` [...] as `_ffn`. The
+    body sees rows: prompt passes of as many tokens (4 x 1024, 1 x 4096)
+    share its trace."""
     lead, d = x.shape[:-1], x.shape[-1]
-    h32, h = _normed(cfg, x, p["ffn_norm"])
-    y, landed, touched, chosen = _ffn(cfg, p, h32.reshape(-1, d), h.reshape(-1, d),
-                                      valid.reshape(-1))
+    y, landed, touched, chosen = _ffn_rows(cfg, p, x.reshape(-1, d),
+                                           valid.reshape(-1))
     if chosen is not None:
         chosen = chosen.reshape(lead + (-1,))
-    return (_residual(cfg, p, "ffn_post_norm", x, y.reshape(x.shape)), landed,
-            touched, chosen)
+    return y.reshape(x.shape), landed, touched, chosen
+
+
+@_layer_body()
+def _kda_seq(cfg: HybridConfig, p, x, valid, true_len):
+    """The KDA mixer half over a whole sequence: x [b, s, d] float32 -> (x,
+    the state after the last true position S [b, H, dk, dv] float32, the
+    convolution tail [b, K-1, 3 H dk])."""
+    _, h = _normed(cfg, x, p["mixer_norm"])
+    with jax.named_scope("kda"):
+        m = p["kda"]
+        qkv = h @ m["w_qkv"]
+        y = jax.nn.silu(kda.short_conv(qkv.astype(F32), m["conv"].astype(F32)))
+        q, k, v, g, beta = _kda_inputs(cfg, m, h, y)
+        # padding leaves the state alone: no decay, no write
+        g = jnp.where(valid[..., None, None], g, 0.0)
+        beta = jnp.where(valid[..., None], beta, 0.0)
+        o, S = kda.kda_chunked(q, k, v, g, beta, chunk=cfg.kda_chunk)
+        x = _residual(cfg, p, "mixer_post_norm", x, _kda_output(cfg, m, h, o))
+        return x, S, kda.conv_tail(qkv, true_len, cfg.conv_kernel)
+
+
+@_layer_body()
+def _kda_step(cfg: HybridConfig, p, x, S, tail):
+    """The KDA mixer half for one token a slot: x [B, 1, d] float32, the
+    layer's state S [B, H, dk, dv] and tail [B, K-1, 3 H dk] -> (x, S, tail)."""
+    _, h = _normed(cfg, x[:, 0], p["mixer_norm"])  # one position only
+    with jax.named_scope("kda"):
+        m = p["kda"]
+        y, tail = kda.short_conv_step(h @ m["w_qkv"], tail, m["conv"])
+        q, k, v, g, beta = _kda_inputs(cfg, m, h, jax.nn.silu(y))
+        S, o = kda.kda_step(S, q, k, v, g, beta)
+        x = _residual(cfg, p, "mixer_post_norm", x,
+                      _kda_output(cfg, m, h, o)[:, None])
+        return x, S, tail
+
+
+@_layer_body()
+def _mla_seq(cfg: HybridConfig, p, x, positions):
+    """The MLA mixer half over a whole sequence: x [b, s, d] float32 at
+    `positions` [s] -> (x, latent rows [b, s, latent_width])."""
+    b, s, _ = x.shape
+    _, h = _normed(cfg, x, p["mixer_norm"])
+    with jax.named_scope("mla"):
+        m = p["mla"]
+        q, latent = _mla_latent(cfg, m, h, positions)
+        attn = mla.mla_prefill_attention(q, latent, m["w_kvb"], cfg.kv_lora_rank,
+                                         cfg.qk_nope_dim, cfg.v_head_dim)
+        x = _residual(cfg, p, "mixer_post_norm", x, attn.reshape(b, s, -1) @ m["wo"])
+        return x, latent
+
+
+@_layer_body("attn_len")
+def _mla_step(cfg: HybridConfig, p, x, latent, layer, lengths, positions, walk,
+              attn_len: int):
+    """The MLA mixer half, Q new positions a slot: x [B, Q, d] float32
+    against `latent[layer]`, read-only, `layer` an int32 scalar (DATA: the
+    layers of a step, the module's too, are one body) -> (x, the positions'
+    own latent rows [B, Q, latent_width])."""
+    B, Q, _ = x.shape
+    _, h = _normed(cfg, x, p["mixer_norm"])
+    with jax.named_scope("mla"):
+        m = p["mla"]
+        q, cur = _mla_latent(cfg, m, h, positions)
+        cur = cur.astype(cfg.dtype)
+        attn = mla.mla_decode_absorbed(
+            q, latent, layer, cur, lengths, attn_len, m["w_kvb"],
+            cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.v_head_dim, walk)
+        x = _residual(cfg, p, "mixer_post_norm", x,
+                      attn.reshape(B, Q, -1).astype(cfg.dtype) @ m["wo"])
+        return x, cur
 
 
 def _add(cfg: HybridConfig, x, y):
@@ -755,15 +904,9 @@ def _eva_qkv(cfg: HybridConfig, a, h, positions):
 def _mla_seq_layer(cfg: HybridConfig, p, x, valid, positions):
     """One layer with an MLA mixer over a whole sequence: x [b, s, d] float32
     -> (x, latent rows [b, s, latent_width], the experts chosen or None)."""
-    b, s, _ = x.shape
-    _, h = _normed(cfg, x, p["mixer_norm"])
-    with jax.named_scope("mla"):
-        m = p["mla"]
-        q, latent = _mla_latent(cfg, m, h, positions)
-        attn = mla.mla_prefill_attention(q, latent, m["w_kvb"], cfg.kv_lora_rank,
-                                         cfg.qk_nope_dim, cfg.v_head_dim)
-        x = _residual(cfg, p, "mixer_post_norm", x, attn.reshape(b, s, -1) @ m["wo"])
-    x, _, _, chosen = _ffn_half(cfg, p, x, valid)
+    mixer, ffn = _halves(p)
+    x, latent = _mla_seq(cfg, mixer, x, positions)
+    x, _, _, chosen = _ffn_half(cfg, ffn, x, valid)
     return x, latent, chosen
 
 
@@ -774,7 +917,6 @@ def _sequence(params, tokens, true_len, cfg: HybridConfig):
     if cfg.scanned:
         return _sequence_runs(params, tokens, true_len, cfg)
     b, s = tokens.shape
-    K = cfg.conv_kernel
     # the residual stream is float32 (weights and matmul inputs keep the
     # configuration's type): in bf16 its rounding at every add reaches 1%
     # after a few layers, and a router with near-ties (8 of 256 by score)
@@ -788,20 +930,11 @@ def _sequence(params, tokens, true_len, cfg: HybridConfig):
             x, latent, chosen = _mla_seq_layer(cfg, p, x, valid, positions)
             latent_rows.append(latent)
         else:
-            _, h = _normed(cfg, x, p["mixer_norm"])
-            with jax.named_scope("kda"):
-                m = p["kda"]
-                qkv = h @ m["w_qkv"]
-                y = jax.nn.silu(kda.short_conv(qkv.astype(F32), m["conv"].astype(F32)))
-                q, k, v, g, beta = _kda_inputs(cfg, m, h, y)
-                # padding leaves the state alone: no decay, no write
-                g = jnp.where(valid[..., None, None], g, 0.0)
-                beta = jnp.where(valid[..., None], beta, 0.0)
-                o, S = kda.kda_chunked(q, k, v, g, beta, chunk=cfg.kda_chunk)
-                x = _residual(cfg, p, "mixer_post_norm", x, _kda_output(cfg, m, h, o))
-                S_rows.append(S)
-                conv_rows.append(kda.conv_tail(qkv, true_len, K))
-            x, _, _, chosen = _ffn_half(cfg, p, x, valid)
+            mixer, ffn = _halves(p)
+            x, S, tail = _kda_seq(cfg, mixer, x, valid, true_len)
+            S_rows.append(S)
+            conv_rows.append(tail)
+            x, _, _, chosen = _ffn_half(cfg, ffn, x, valid)
         if chosen is not None:
             routing.append(chosen)
     rows = {"S": S_rows, "conv": conv_rows,
@@ -1007,6 +1140,7 @@ def _following(tokens, true_len, first):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "with_mtp", "all_heads"))
+@_layered_program
 def forward(params, tokens, cfg: HybridConfig, with_mtp: bool = False,
             all_heads: bool = False):
     """tokens [b, s] -> logits [b, s, vocab] float32. `with_mtp`: beside
@@ -1026,6 +1160,7 @@ def forward(params, tokens, cfg: HybridConfig, with_mtp: bool = False,
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "with_routing"))
+@_layered_program
 def prefill(params, tokens, true_len, cfg: HybridConfig,
             with_routing: bool = False, first=None):
     """-> (logits at the last true position [nb, vocab] float32, state rows
@@ -1072,25 +1207,18 @@ def prefill(params, tokens, true_len, cfg: HybridConfig,
 # ---------------------------------------------------------------- decode
 
 
-def _mla_step_layer(cfg: HybridConfig, p, x, latent, layer, lengths, positions,
-                    active, attn_len, walk):
+def _mla_step_layer(cfg: HybridConfig, p, x, latent, layer: int, lengths,
+                    positions, active, attn_len, walk):
     """One layer with an MLA mixer, Q new positions a slot: x [B, Q, d]
     float32 against `latent[layer]`, read-only -> (x, the positions' own
     latent rows [B, Q, latent_width], assignments landed, experts touched,
     the experts chosen [B, Q, k] or None)."""
     B, Q, _ = x.shape
-    _, h = _normed(cfg, x, p["mixer_norm"])
-    with jax.named_scope("mla"):
-        m = p["mla"]
-        q, cur = _mla_latent(cfg, m, h, positions)
-        cur = cur.astype(cfg.dtype)
-        attn = mla.mla_decode_absorbed(
-            q, latent, layer, cur, lengths, attn_len, m["w_kvb"],
-            cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.v_head_dim, walk)
-        x = _residual(cfg, p, "mixer_post_norm", x,
-                      attn.reshape(B, Q, -1).astype(cfg.dtype) @ m["wo"])
+    mixer, ffn = _halves(p)
+    x, cur = _mla_step(cfg, mixer, x, latent, np.int32(layer), lengths, positions,
+                       walk, attn_len)
     x, landed, touched, chosen = _ffn_half(
-        cfg, p, x, jnp.broadcast_to(active[:, None], (B, Q)))
+        cfg, ffn, x, jnp.broadcast_to(active[:, None], (B, Q)))
     return x, cur, landed, touched, chosen
 
 
@@ -1117,7 +1245,6 @@ def _decode(params, state, lengths, tokens, active, cfg: HybridConfig,
         if latent.shape[0] else None
     S_new, conv_new, latent_cur, routing = [], [], [], []
     landed = touched = jnp.zeros((), jnp.int32)
-    i_kda = 0
     for p, (mixer, _) in zip(params["layers"], cfg.layer_kinds()):
         if mixer == "mla":
             x, cur, n_landed, n_touched, chosen = _mla_step_layer(
@@ -1125,19 +1252,12 @@ def _decode(params, state, lengths, tokens, active, cfg: HybridConfig,
                 attn_len, walk)
             latent_cur.append(cur)
         else:
-            _, h = _normed(cfg, x[:, 0], p["mixer_norm"])  # one position only
-            with jax.named_scope("kda"):
-                m = p["kda"]
-                y, tail = kda.short_conv_step(h @ m["w_qkv"],
-                                              state["conv"][i_kda], m["conv"])
-                q, k, v, g, beta = _kda_inputs(cfg, m, h, jax.nn.silu(y))
-                S, o = kda.kda_step(state["S"][i_kda], q, k, v, g, beta)
-                x = _residual(cfg, p, "mixer_post_norm", x,
-                              _kda_output(cfg, m, h, o)[:, None])
-                S_new.append(S)
-                conv_new.append(tail)
-                i_kda += 1
-            x, n_landed, n_touched, chosen = _ffn_half(cfg, p, x, active[:, None])
+            mixer, ffn = _halves(p)
+            x, S, tail = _kda_step(cfg, mixer, x, state["S"][len(S_new)],
+                                   state["conv"][len(S_new)])
+            S_new.append(S)
+            conv_new.append(tail)
+            x, n_landed, n_touched, chosen = _ffn_half(cfg, ffn, x, active[:, None])
         landed, touched = landed + n_landed, touched + n_touched
         if chosen is not None:
             routing.append(chosen)
@@ -1350,6 +1470,7 @@ def _decode_eva(params, state, lengths, tokens, cfg: HybridConfig,
 
 @functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
                    donate_argnums=(1,))
+@_layered_program
 def decode_logits(params, state, lengths, tokens, active, cfg: HybridConfig,
                   attn_len: int):
     """The decode step for callers that need logits: the body of
@@ -1376,6 +1497,7 @@ def decode_logits(params, state, lengths, tokens, active, cfg: HybridConfig,
 
 @functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
                    donate_argnums=(1,))
+@_layered_program
 def verify_logits(params, state, lengths, tokens, following, active,
                   cfg: HybridConfig, attn_len: int):
     """`decode_logits` for a configuration that drafts: the step's body over
@@ -1392,6 +1514,7 @@ def verify_logits(params, state, lengths, tokens, following, active,
 
 @functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
                    donate_argnums=(1, 2))
+@_layered_program
 def decode_step(params, state, lengths, tokens, active, cfg: HybridConfig,
                 attn_len: int):
     """The hot decode step: state and lengths DONATED, greedy sampling on
@@ -1451,6 +1574,7 @@ def decode_step(params, state, lengths, tokens, active, cfg: HybridConfig,
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
+@_layered_program
 def _prefill_first(params, tokens, true_len, cfg: HybridConfig):
     logits, rows = prefill(params, tokens, true_len, cfg)
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), rows

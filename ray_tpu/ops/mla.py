@@ -95,14 +95,14 @@ def decode_walk(cache: jax.Array, lengths: jax.Array, attn_len: int,
     return mla_decode.live_blocks(lengths, attn_len)
 
 
-def mla_decode_absorbed(q: jax.Array, cache: jax.Array, layer: int,
+def mla_decode_absorbed(q: jax.Array, cache: jax.Array, layer,
                         cur: jax.Array, lengths: jax.Array, attn_len: int,
                         w_kvb: jax.Array, rank: int, d_nope: int, d_v: int,
                         walk=None) -> jax.Array:
     """q [B, Q, H, d_nope + d_rope]: Q new positions a slot, the slot's
     `lengths[b] + 0 .. Q-1`; cache [L, B, 1, max_len, W] the latent rows of
-    every layer, of which `layer`'s first `attn_len` are read (with the
-    cache's one "kv head"); cur [B, Q, W] the new positions' own rows (not
+    every layer, of which `layer`'s (a scalar, traced or not) first
+    `attn_len` are read (with the cache's one "kv head"); cur [B, Q, W] the new positions' own rows (not
     written yet) -> [B, Q, H, d_v]. Position a attends the rows
     [0, lengths[b]) of the cache (STRICT) and cur[:, :a + 1]: causal among
     the new ones. `walk` = `decode_walk(...)`."""
@@ -122,7 +122,8 @@ def mla_decode_absorbed(q: jax.Array, cache: jax.Array, layer: int,
             q_lat.reshape(B, Q * H, W), cur, cache, layer, walk, attn_len,
             rank, scale).reshape(B, Q, H, rank)
         return jnp.einsum("bqhr,rhv->bqhv", ctx, w[..., d_nope:])
-    window = cache[layer, :, :, :attn_len]                 # [B, 1, Lw, W]
+    window = jax.lax.dynamic_slice(
+        cache, (layer, 0, 0, 0, 0), (1, B, 1, attn_len, W))[0]   # [B, 1, Lw, W]
     mask = jnp.arange(attn_len)[None, :] < lengths[:, None]
     # the einsum forms of `_gqa_decode_attention` (one shared "kv head" g,
     # the Q x H query rows as its group r): XLA:TPU reads the window in place

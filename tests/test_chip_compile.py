@@ -369,6 +369,31 @@ def test_write_slots_keeps_the_cache_layout(chip):
     _assert_cache_stays_put(c, kv)
 
 
+def _kimi(chip, **cut):
+    """(cfg, parameter shapes, slot-state shapes, slots) of the Kimi Linear
+    cell; `cut` lays other values over its `HybridConfig`."""
+    import json
+    import sys
+
+    from ray_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from perfbench.lib import hybrid_model
+
+    with open(os.path.join(root, "perfbench", "configs",
+                           "kimi-linear-48b-a3b.1of4.json")) as f:
+        conf = json.load(f)
+    cfg = hybrid_model.model_config(conf, **cut)
+    slots, max_len = conf["run"]["num_slots"], conf["run"]["max_len"]
+    as_shapes = lambda tree: jax.tree_util.tree_map(
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = as_shapes(jax.eval_shape(lambda k: hybrid.init_params(k, cfg),
+                                      jax.random.PRNGKey(0)))
+    state = as_shapes(jax.eval_shape(lambda: cfg.make_cache(slots, max_len).state))
+    return cfg, params, state, slots
+
+
 @pytest.mark.parametrize("attn_len", [1024, 8192])
 def test_hybrid_decode_step_keeps_its_state_in_place(chip, attn_len):
     """The hybrid model's decode step at the benchmark cell's real shapes
@@ -387,27 +412,10 @@ def test_hybrid_decode_step_keeps_its_state_in_place(chip, attn_len):
     and the step holds two Mosaic calls over it: the row write and the
     absorbed decode over live rows (`mla_decode_attention`), one each a
     layer."""
-    import json
-
     from ray_tpu.models import hybrid
     from ray_tpu.ops import cache as cache_ops
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    import sys
-    sys.path.insert(0, root)
-    from perfbench.lib import hybrid_model
-
-    with open(os.path.join(root, "perfbench", "configs",
-                           "kimi-linear-48b-a3b.1of4.json")) as f:
-        conf = json.load(f)
-    cfg = hybrid_model.model_config(conf)
-    slots, max_len = conf["run"]["num_slots"], conf["run"]["max_len"]
-    as_shapes = lambda tree: jax.tree_util.tree_map(
-        lambda a: chip(a.shape, a.dtype), tree)
-    params = as_shapes(jax.eval_shape(lambda k: hybrid.init_params(k, cfg),
-                                      jax.random.PRNGKey(0)))
-    state = as_shapes(jax.eval_shape(
-        lambda: hybrid.HybridCache(cfg, slots, max_len).state))
+    cfg, params, state, slots = _kimi(chip)
     ints = chip((slots,), jnp.int32)
     c = hybrid.decode_step.lower(params, state, ints, ints,
                                  chip((slots,), jnp.bool_), cfg, attn_len).compile()
@@ -416,11 +424,46 @@ def test_hybrid_decode_step_keeps_its_state_in_place(chip, attn_len):
     assert state_bytes > 2.3e9
     assert c.memory_analysis().alias_size_in_bytes >= state_bytes
     latent = state["latent"]
-    assert latent.shape == (2, slots, 1, max_len, 640)
+    assert latent.shape == (2, slots, 1, 8192, 640)
     assert cache_ops.uses_write_kernel(latent)
     assert "mla_decode_attention" in c.as_text()
     assert _count(c, latent, "dynamic-update-slice") == 0
     _assert_cache_stays_put(c, latent)
+
+
+def test_hybrid_layer_bodies_are_inlined(chip):
+    """The list form's layers are calls of one jitted body a kind
+    (`models/hybrid.py`, "the list form's layer bodies"). At Kimi Linear's
+    widths with two layers of each kind (KDA and MLA, each over a dense FFN
+    and over an expert layer): the lowered step holds one private function a
+    kind, two for the FFN half's two parameter trees; the COMPILED step
+    holds no `call` (XLA inlined every body: the state still aliases its
+    output) and the Mosaic calls its layers ask for: the absorbed decode once
+    an MLA layer, one row write, and twelve of XLA's own for the three
+    grouped products of an expert layer."""
+    from ray_tpu.models import hybrid
+
+    cfg, params, state, slots = _kimi(chip, n_layers=4, kda_layers=(1, 3),
+                                      first_dense=2)
+    assert cfg.layer_kinds() == (("kda", "dense"), ("mla", "dense"),
+                                 ("kda", "moe"), ("mla", "moe"))
+    ints = chip((slots,), jnp.int32)
+    lowered = hybrid.decode_step.lower(params, state, ints, ints,
+                                       chip((slots,), jnp.bool_), cfg, 1024)
+    text = lowered.as_text()
+    body = r"@(_kda_step|_mla_step|_ffn_rows)(?:_\d+)?\("
+    assert sorted(re.findall(r"func\.func private " + body, text)) == [
+        "_ffn_rows", "_ffn_rows", "_kda_step", "_mla_step"]
+    assert sorted(re.findall(r"call " + body, text)) == \
+        ["_ffn_rows"] * 4 + ["_kda_step"] * 2 + ["_mla_step"] * 2
+    c = lowered.compile()
+    assert not re.findall(r"^.* = .*\s(?:async-)?call(?:-start)?\(.*$",
+                          c.as_text(), re.M)
+    assert c.as_text().count("mla_decode_attention/pallas_call") >= 2
+    assert _kernels(c) == 2 + 1 + 2 * 12
+    state_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(state))
+    assert c.memory_analysis().alias_size_in_bytes >= state_bytes
 
 
 def _jamba(chip):

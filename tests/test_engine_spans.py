@@ -206,6 +206,30 @@ def test_a_programs_own_trace_survives_and_the_inner_ones_do_not():
     assert traces == ["outer_program", "inner_program"], traces
 
 
+def test_a_list_form_programs_spans_count_its_layer_bodies():
+    """The list form's layers run through jitted bodies, one a kind
+    (`models/hybrid.py`); jax reports a trace for each, and none becomes a
+    span: the prompt pass's and the step's own trace, lower and compile
+    spans carry `layers` and how many bodies the program traced anew
+    (`layer_bodies_traced`: here each its four, nothing being traced yet)."""
+    from ray_tpu.models import hybrid
+
+    cfg = hybrid.HybridConfig.tiny_hybrid()
+    params = hybrid.init_params(jax.random.PRNGKey(0), cfg)
+    jax.clear_caches()   # a body another test traced at these shapes is a hit
+    eng = ContinuousBatchingEngine(params, cfg, num_slots=3, max_len=32)
+    eng.generate([1, 2, 3], max_new_tokens=2)
+    compiles = [e["args"] for e in tracing.get_events() if e["name"] == "xla.compile"]
+    assert not [a for a in compiles if re.search(
+        r"_(kda|mla)_(seq|step)|_ffn_rows", a["fun_name"])], compiles
+    for program in ("_prefill_first", "decode_step"):
+        own = [a for a in compiles if a["fun_name"] in (program, f"jit({program})")]
+        assert sorted(a["event"] for a in own) == [
+            "backend_compile_duration", "jaxpr_to_mlir_module_duration",
+            "jaxpr_trace_duration"], (program, compiles)
+        assert all((a["layers"], a["layer_bodies_traced"]) == (4, 4) for a in own), own
+
+
 @pytest.mark.parametrize("mesh_cfg,n,seq,exchanges,tp_exchanges,norm_sums", [
     ({"dp": 1, "fsdp": 2, "tp": 2}, 4, 16, 7, 4, 0), ({"dp": 1}, 1, 16, 0, 0, 0),
     ({"dp": 2, "fsdp": 1, "tp": 2}, 4, 16, 0, 0, 2),

@@ -10,8 +10,10 @@ is to a few float32 roundings accumulated over four layers: relative errors
 of 1e-6 to 1e-5 were read; the limit 1e-4 leaves room and is still 40 times
 under what one bf16 rounding of any operand gives (4e-3)."""
 
+import collections
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,6 +99,47 @@ def test_prefill_then_decode_through_the_slot_state(params, tokens, want):
             jnp.asarray([False, True]), CFG, 128)
         lengths = lengths + 1
         assert rel(got[1], want[0, t]) < TOL, t
+
+
+def _layer_bodies(lowered):
+    """{body: (private functions, calls)} of the list form's layer bodies in
+    a lowering's StableHLO (a second function of a name is `name_<n>`)."""
+    text = lowered.as_text()
+    kind = r"@(_kda_seq|_kda_step|_mla_seq|_mla_step|_ffn_rows)(?:_\d+)?\("
+    defs = collections.Counter(re.findall(r"func\.func private " + kind, text))
+    calls = collections.Counter(re.findall(r"call " + kind, text))
+    return {k: (defs[k], calls[k]) for k in defs}
+
+
+def _traced_anew():
+    """(`layers`, `layer_bodies_traced`) on the newest `xla.compile` span."""
+    a = [e["args"] for e in tracing.get_events() if e["name"] == "xla.compile"][-1]
+    return a["layers"], a["layer_bodies_traced"]
+
+
+def test_a_program_holds_one_body_a_kind_of_layer(params):
+    """Three KDA layers call ONE private `_kda_step` (`_kda_seq` in the
+    prompt pass), the MLA layer its own, the four FFN halves two `_ffn_rows`
+    (dense; experts + shared). A second decode program that differs in
+    `attn_len` alone traces the MLA step, which reads it, and nothing else."""
+    jax.clear_caches()   # a body another test traced at these shapes is a hit
+    tracing.record_compiles()
+    i4 = jax.ShapeDtypeStruct((4,), jnp.int32)
+    state = CFG.make_cache(4, 64).state
+    step = lambda attn_len: hybrid.decode_step.lower(
+        params, state, i4, i4, jax.ShapeDtypeStruct((4,), jnp.bool_), CFG, attn_len)
+    assert _layer_bodies(step(32)) == {
+        "_kda_step": (1, 3), "_mla_step": (1, 1), "_ffn_rows": (2, 4)}
+    assert _traced_anew() == (4, 4)
+    assert _layer_bodies(step(64)) == {
+        "_kda_step": (1, 3), "_mla_step": (1, 1), "_ffn_rows": (2, 4)}
+    assert _traced_anew() == (4, 1)
+    prompt = hybrid._prefill_first.lower(
+        params, jax.ShapeDtypeStruct((2, 16), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.int32), CFG)
+    assert _layer_bodies(prompt) == {
+        "_kda_seq": (1, 3), "_mla_seq": (1, 1), "_ffn_rows": (2, 4)}
+    assert _traced_anew() == (4, 4)
 
 
 def _kda_inputs(seed, b, s, H, dk):

@@ -10,9 +10,11 @@ order their sums differently (absorbed against expanded, sorted groups
 against a loop over experts), so they agree to a few float32 roundings:
 1e-4 is 40 times under what one bf16 rounding of any operand gives."""
 
+import collections
 import dataclasses
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,7 @@ from ray_tpu.models.serving import ContinuousBatchingEngine
 from ray_tpu.ops import mla
 from ray_tpu.ops.moe import dropless_moe, route_top_k
 from ray_tpu.ops.pallas import mla_decode
+from ray_tpu.util import tracing
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _spec = importlib.util.spec_from_file_location(
@@ -161,6 +164,40 @@ def test_prefill_then_decode_through_the_slots_is_the_reference(
                 assert rel(module[at[j], a], want[1][j, pos[j] + a]) < TOL
             pos[j] += keep
         lengths = lengths + keep * jnp.asarray(active)
+
+
+def test_the_modules_layer_calls_the_main_layers_bodies(params):
+    """Three main layers and the prediction module's call ONE private
+    `_mla_step` (`_mla_seq` in the prompt pass): the layer's index into the
+    latent table is data. Their FFN halves are two `_ffn_rows` (the dense
+    layer's; experts + shared, the module's too). A second verify step that
+    differs in `attn_len` alone traces the MLA step and nothing else."""
+    def bodies(lowered):   # {body: (private functions, calls)}, `name_<n>` a second
+        text = lowered.as_text()
+        kind = r"@(_mla_seq|_mla_step|_ffn_rows)(?:_\d+)?\("
+        defs = collections.Counter(re.findall(r"func\.func private " + kind, text))
+        calls = collections.Counter(re.findall(r"call " + kind, text))
+        return {k: (defs[k], calls[k]) for k in defs}
+
+    def traced_anew():     # on the newest `xla.compile` span
+        a = [e["args"] for e in tracing.get_events() if e["name"] == "xla.compile"][-1]
+        return a["layers"], a["layer_bodies_traced"]
+
+    jax.clear_caches()   # a body another test traced at these shapes is a hit
+    tracing.record_compiles()
+    i4 = jax.ShapeDtypeStruct((4,), jnp.int32)
+    state = CFG.make_cache(4, 64).state
+    step = lambda attn_len: hybrid.decode_step.lower(
+        params, state, i4, i4, jax.ShapeDtypeStruct((4,), jnp.bool_), CFG, attn_len)
+    assert bodies(step(32)) == {"_mla_step": (1, 4), "_ffn_rows": (2, 4)}
+    assert traced_anew() == (4, 3)
+    assert bodies(step(64)) == {"_mla_step": (1, 4), "_ffn_rows": (2, 4)}
+    assert traced_anew() == (4, 1)
+    prompt = hybrid._prefill_first.lower(
+        params, jax.ShapeDtypeStruct((2, 16), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.int32), CFG)
+    assert bodies(prompt) == {"_mla_seq": (1, 4), "_ffn_rows": (2, 4)}
+    assert traced_anew() == (4, 3)
 
 
 def _generate(params, cfg, prompts):
