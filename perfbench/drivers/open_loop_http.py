@@ -13,11 +13,10 @@ replica to be compared with the float32 reference.
 from __future__ import annotations
 
 import asyncio
-import os
 import random
 import time
 
-from perfbench.lib import loadgen, traffic as traffic_mod
+from perfbench.lib import loadgen, manifest, traffic as traffic_mod
 
 
 def run(ctx) -> dict:
@@ -37,9 +36,10 @@ def run(ctx) -> dict:
     for r in schedule:
         r["timeout_s"] = tr["request_timeout_s"]
 
-    # the deployment's own Serve settings (RAY_TPU_SERVE_*), before any
-    # process of the cluster exists, so that proxy and router inherit them
-    os.environ.update(run_cfg.get("serve_env", {}))
+    # the deployment's Serve settings (RAY_TPU_SERVE_*: the configuration's,
+    # the traffic file's over them), before any process of the cluster
+    # exists, so that proxy and router inherit them
+    manifest.lay_serve_env(ctx)
     ray_tpu.init(num_cpus=8, resources={"TPU": cell["chips"]})
     try:
         t_ask = time.time()
@@ -75,18 +75,19 @@ async def _drive(host, port, schedule, ctx, seconds) -> dict:
     warm = float(tr["warm_s"])
     t_zero = time.perf_counter() + warm + 0.25   # the window opens here
     t_open = time.time() + warm + 0.25
-    side = []
-    if ctx["trace"]:
-        async def traced():
-            a, b = tr["trace_window_s"]
-            await asyncio.sleep(max(0.0, t_zero + a - time.perf_counter()))
-            await _call(host, port, "trace_start")
-            await asyncio.sleep(max(0.0, t_zero + b - time.perf_counter()))
-            await _call(host, port, "trace_stop")
-        side.append(traced())
+    if ctx["trace"]:  # the replica times its own trace: no call in the queue
+        a, b = tr["trace_window_s"]
+        await _call(host, port, "trace_between",
+                    {"start": t_open + a, "stop": t_open + b})
     rows = await loadgen.run_schedule(host, port, "/LLM/stream?stream=1",
-                                      schedule, t_zero, side)
+                                      schedule, t_zero)
     t_drained = time.perf_counter() - t_zero
+    if ctx["trace"]:
+        traced = await _call(host, port, "trace_stop")
+        print(f"[trace] the profiler ran from {traced['started'] - t_open:.2f}s to "
+              f"{traced['stopped'] - t_open:.2f}s after the window opened "
+              f"(asked: {a:g}s to {b:g}s) and had written its trace by "
+              f"{traced['written'] - t_open:.2f}s", flush=True)
     stats = await _call(host, port, "stats", {"trace": ctx["trace"]})
     t_stats = time.perf_counter() - t_zero
 

@@ -20,7 +20,7 @@ import os
 import time
 
 from perfbench.drivers.open_loop_http import _drive
-from perfbench.lib import traffic as traffic_mod
+from perfbench.lib import manifest, traffic as traffic_mod
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -47,7 +47,7 @@ def run(ctx) -> dict:
     for r in schedule:
         r["timeout_s"] = tr["request_timeout_s"]
 
-    os.environ.update(run_cfg.get("serve_env", {}))
+    manifest.lay_serve_env(ctx)
     ray_tpu.init(num_cpus=8, resources={"TPU": cell["chips"]})
     try:
         t_ask = time.time()

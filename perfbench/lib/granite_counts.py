@@ -7,8 +7,6 @@ from __future__ import annotations
 
 from typing import List, Optional
 
-SCAN_HEADS = 8     # heads a grid step of the `ssd_scan` kernel holds
-
 
 def n_layers_of(c: dict):
     """(Mamba-2 layers, attention layers) of the layers that are run."""
@@ -127,26 +125,6 @@ def step_kernel_bytes(c: dict, busy_slots: float) -> float:
     return 4.0 * busy_slots * (2 * n * di + 3 * di + 2 * n)
 
 
-def scan_kernel_bytes(c: dict, batch: int, positions: float) -> float:
-    """What ONE call of the `ssd_scan` kernel (one layer, `batch` prompts of
-    `positions` positions) has to move, all float32: x read and y written
-    once; C and B^T read once a group of `SCAN_HEADS` heads (they are shared
-    by every head and the grid walks the groups); dt and its decay sums a
-    row a head; the state read and written once."""
-    di, n, H = d_inner(c), c["mamba_d_state"], c["mamba_n_heads"]
-    groups = H // SCAN_HEADS
-    return 4.0 * batch * (positions * (2 * di + groups * 2 * n + 2 * H) + 2 * n * di)
-
-
-def scan_kernel_flops(c: dict, batch: int, positions: float) -> float:
-    """Matrix-product operations of the same call, per position: 2 Q N for
-    C B^T (once a layer: one group), and a head 2 Q P inside the chunk,
-    2 P N into and 2 P N out of the state."""
-    Q, n, H, P = (c["mamba_chunk_size"], c["mamba_d_state"], c["mamba_n_heads"],
-                  c["mamba_d_head"])
-    return float(batch) * positions * (2 * Q * n + H * (2 * Q * P + 4 * P * n))
-
-
 def decode_flops(c: dict) -> int:
     """Matmul operations of one token through the stack and the head, with
     the experts a token uses HERE on average (k x held / all)."""
@@ -167,21 +145,3 @@ def step_args(run, within: Optional[tuple] = None) -> List[dict]:
 
     return [a for a in hybrid_counts.step_args(run, "experts_touched", within)
             if "kv_rows" in a]
-
-
-def prefill_spans(run, within: Optional[tuple] = None) -> List[dict]:
-    """The arguments of the program's own `engine.prefill_dispatch` spans
-    (bucket, batch and the true `tokens` of a prompt pass) that start in the
-    window, or within (t0, t1) seconds after it opened. Empty where the
-    program keeps no such span or does not count `tokens` on it."""
-    from perfbench.lib import program_spans
-
-    if "_prefill_spans" not in run:
-        got = run.get("program_spans") or program_spans._fetch()
-        run["_prefill_spans"] = [
-            e for e in (got or {}).get("events", [])
-            if e.get("name") == "engine.prefill_dispatch"
-            and "tokens" in (e.get("args") or {})]
-    lo, hi = within if within is not None else (0.0, run["seconds"])
-    t0, t1 = (1e6 * (run["t_open"] + t) for t in (lo, hi))
-    return [e["args"] for e in run["_prefill_spans"] if t0 <= e["ts"] < t1]

@@ -6,18 +6,16 @@ warm-up through the engine's cache interface are
 built (`lib.granite_model`), what `check` compares (the reference follows
 the program's choice of experts, as the Kimi cell's does, and ONE reference
 pass a sample gives all three numbers: it is 13056 positions long), and that
-the trace's reduction keeps the `ssd_scan` and `ssd_step` kernels' calls."""
+the trace's reduction keeps the `ssd_step` kernel's calls."""
 
 from __future__ import annotations
 
-import re
 import threading
 import time
 
 from perfbench.lib.hybrid_replica import HybridBenchReplica
 from perfbench.lib.jamba_replica import _kernel_events
 
-SCAN_KERNEL = "ssd_scan"
 STEP_KERNEL = "ssd_step"
 
 
@@ -80,9 +78,7 @@ class GraniteBenchReplica(HybridBenchReplica):
 
     def stats(self, payload=None):
         """`HybridBenchReplica.stats`; after a traced run the reduction also
-        holds, under `kernel_calls`, every device event of the `ssd_scan`
-        kernel as [batch, positions, seconds] (its roofline metric prices
-        each call from its shape) and the `ssd_step` kernel's [events,
+        holds, under `kernel_calls`, the `ssd_step` kernel's [events,
         seconds]. Read before the parent's reduction, which removes the trace."""
         from perfbench.lib import xplane
 
@@ -90,7 +86,7 @@ class GraniteBenchReplica(HybridBenchReplica):
         if (payload or {}).get("trace"):
             path = xplane.find_xplane(self._trace_dir)
             planes = xplane.load(path)
-            calls = {SCAN_KERNEL: scan_calls(planes), STEP_KERNEL: step_calls(planes)}
+            calls = {STEP_KERNEL: step_calls(planes)}
             if not any(k.startswith("/device:") for k in planes):
                 print(f"[trace] no device plane in {path}: {_what_is_there(path)}",
                       flush=True)
@@ -139,15 +135,6 @@ def _what_is_there(path: str) -> str:
     lines = [f"{p.name}/{l.name}: {sum(1 for _ in l.events)}"
              for p in data.planes for l in p.lines]
     return f"{os.path.getsize(path)} bytes; " + "; ".join(lines[:40])
-
-
-def scan_calls(planes) -> list:
-    """[[batch, positions, seconds], ...], one entry per event of the
-    `ssd_scan` kernel; the shape is that of y, f32[batch, positions, H P],
-    the first such shape in the instruction."""
-    shape = re.compile(r"f32\[(\d+),(\d+),\d+\]")
-    found = ((shape.search(op), t) for op, t in _kernel_events(planes, SCAN_KERNEL))
-    return [[int(m.group(1)), int(m.group(2)), t] for m, t in found if m]
 
 
 def step_calls(planes) -> list:

@@ -81,7 +81,7 @@ async def _one(host, port, path, req: Dict, t_zero: float, row: Dict) -> None:
 
 
 async def run_schedule(host: str, port: int, path: str, schedule: List[Dict],
-                       t_zero: float, side_tasks=()) -> List[Dict]:
+                       t_zero: float) -> List[Dict]:
     """Send every request of `schedule` at its due time; returns one row per
     request (due_s, sent_s, arrivals_s, tokens, status, error)."""
     rows = [{"i": r["i"], "due_s": r["due_s"], "prompt_len": len(r["prompt"]),
@@ -89,6 +89,5 @@ async def run_schedule(host: str, port: int, path: str, schedule: List[Dict],
              "tokens": [], "status": None, "error": None} for r in schedule]
     tasks = [asyncio.ensure_future(_one(host, port, path, r, t_zero, row))
              for r, row in zip(schedule, rows)]
-    tasks += [asyncio.ensure_future(t) for t in side_tasks]
     await asyncio.gather(*tasks)
     return rows

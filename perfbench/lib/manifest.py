@@ -5,11 +5,20 @@ one reference or one metric is a file of its own, found by name in the
 directories under `paths` (and in this package): a later PR adds files and
 entries and edits none.
 
-    configs/<config>.json      the sizes as run, `reference` and `run` keys
+    configs/<config>.json      the sizes as run, `reference` and `run` keys:
+                               widths, depth, slots, the reference and the
+                               control belong to the configuration alone
     traffic/<traffic>.json     parameters for the one generator, `driver`
     drivers/<driver>.py        run(ctx) -> the run's record
     references/<name>.py       the plain float32 reference
     metrics/<metric>.py        read(run) -> number, or None if nothing to read
+
+What the router and the proxy are told (`serve_env`, the deployment's
+`RAY_TPU_SERVE_*` settings) belongs to either: `run.serve_env` of the
+configuration, and `serve_env` of a traffic file laid over it, the traffic
+file winning (`lay_serve_env`). Overload traffic comes with the queue that
+its deployment would run, and a cell that differs from another only there
+needs no configuration of its own.
 """
 
 from __future__ import annotations
@@ -91,6 +100,39 @@ def prepare_env(root: str, rehearsal: bool) -> None:
                           os.path.join(root, ".jax_compile_cache"))
     os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
+
+
+SERVE_ENV_PREFIX = "RAY_TPU_SERVE_"
+
+
+def lay_serve_env(ctx: dict) -> Dict[str, Dict[str, str]]:
+    """The deployment's Serve settings under this cell's traffic, put into
+    the environment before any process of the cluster exists, so that the
+    proxy, the router and the replica inherit them: the configuration's
+    `run.serve_env` first, the traffic file's `serve_env` over it. Says on
+    the run's `[traffic]` line which are in force and where each came from,
+    and leaves the same under `ctx["serve_env"]` for the run's record. A key
+    that is no Serve setting ends the run."""
+    cell = ctx["cell"]
+    overridden = "serve_env" in ctx.get("overridden", ())
+    layers = ((f"configuration {cell['config']}",
+               ctx["config"]["run"].get("serve_env", {})),
+              ("--override" if overridden else f"traffic {cell['traffic']}",
+               ctx["traffic"].get("serve_env", {})))
+    in_force: Dict[str, Dict[str, str]] = {}
+    for origin, settings in layers:
+        for key, value in settings.items():
+            if not key.startswith(SERVE_ENV_PREFIX):
+                raise SystemExit(
+                    f"perfbench: serve_env of {origin} names {key!r}: only "
+                    f"{SERVE_ENV_PREFIX}* settings may be stated there")
+            in_force[key] = {"value": str(value), "from": origin}
+    os.environ.update({k: v["value"] for k, v in in_force.items()})
+    print("[traffic] serve_env in force: " + ("; ".join(
+        f"{k}={v['value']} ({v['from']})" for k, v in in_force.items())
+        or "none stated"), flush=True)
+    ctx["serve_env"] = in_force
+    return in_force
 
 
 def load_py(path: str):

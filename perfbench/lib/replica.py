@@ -151,19 +151,39 @@ class BenchReplica:
                 "compile_setup": self.compile_setup}
 
     # --------------------------------------------------------------- trace
-    def trace_start(self, _payload=None):
-        import jax
-
+    def trace_between(self, payload):
+        """Trace from `start` to `stop` (wall clock) on a thread of the
+        replica's own. Asked for ONCE, before the warm traffic, so that
+        neither end of the trace queues behind the requests of a replica
+        that is driven past what it carries (as two calls of their own the
+        stop came 16 s late behind ~1,000 waiting requests: PERF.md section
+        6, PR 52)."""
         self._trace_dir = os.path.join(self.spec["out_dir"], "trace")
         shutil.rmtree(self._trace_dir, ignore_errors=True)
-        jax.profiler.start_trace(self._trace_dir)
-        return {"t": time.time()}
+        self._trace_times = {}
+
+        def trace():
+            import jax
+
+            time.sleep(max(0.0, payload["start"] - time.time()))
+            jax.profiler.start_trace(self._trace_dir)
+            self._trace_times["started"] = time.time()
+            time.sleep(max(0.0, payload["stop"] - time.time()))
+            self._trace_times["stopped"] = time.time()
+            jax.profiler.stop_trace()   # returns once the trace is written
+            self._trace_times["written"] = time.time()
+
+        self._tracer = threading.Thread(target=trace, daemon=True)
+        self._tracer.start()
+        return {}
 
     def trace_stop(self, _payload=None):
-        import jax
-
-        jax.profiler.stop_trace()
-        return {"t": time.time()}
+        """Called once the last answer is in: waits for the trace to be
+        written and says when it began, ended and was written."""
+        self._tracer.join(timeout=120.0)
+        if "written" not in self._trace_times:
+            raise RuntimeError(f"the trace did not end: {self._trace_times}")
+        return self._trace_times
 
     def stats(self, payload=None):
         """Everything the benchmark's clocks and counters kept, and, after a

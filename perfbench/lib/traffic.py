@@ -11,7 +11,13 @@ Two kinds of traffic, told apart by the file's `kind`:
                   and answer lengths (quantiles of a clipped log-normal), in
                   an order drawn from the seed: the work offered is equal
                   from seed to seed, only when each piece arrives differs.
-                  Prompt token ids are drawn from the seed.
+                  A file that states `order_seed` draws that order from IT,
+                  once for every seed, and the seed chooses only at which
+                  request of the period the window starts: then the same
+                  answers meet the same prompt passes under every seed, and
+                  a tail over requests reads the program, not the draw
+                  (PERF.md section 6, PR 52). Prompt token ids are drawn
+                  from the seed.
 """
 
 from __future__ import annotations
@@ -89,15 +95,27 @@ def open_loop(traffic: dict, seed: int, seconds: float, vocab: int) -> List[Dict
     it are the end of the period before, i.e. the window's last `warm_s`
     seconds shifted back by one period. What streams into the window from
     before it is then what streams out of its end, and a system that keeps up
-    delivers the period's tokens inside the window under every seed."""
+    delivers the period's tokens inside the window under every seed.
+
+    With `order_seed` in the file the three orders are drawn from it, and
+    the seed turns the period: the window starts at request r of n, r drawn
+    from the seed, and runs once round. Who follows whom at what distance,
+    and so which answers wait behind which prompt passes, is then the same
+    under every seed; only where the window's edges fall differs."""
     warm, rate = float(traffic["warm_s"]), float(traffic["rate_per_s"])
     n = int(round(rate * seconds))
     gaps = _gap_quantiles(n, n / seconds, float(traffic.get("arrival_cv", 1.0)))
     prompts = _lognormal_quantiles(n, traffic["prompt_tokens"])
     answers = _lognormal_quantiles(n, traffic["answer_tokens"])
     rnd, ids = random.Random(int(seed)), _rng(seed, 3)
+    fixed = traffic.get("order_seed")
+    order = rnd if fixed is None else random.Random(int(fixed))
     for seq in (gaps, prompts, answers):
-        rnd.shuffle(seq)
+        order.shuffle(seq)
+    if fixed is not None:
+        r = rnd.randrange(n)
+        gaps, prompts, answers = (seq[r:] + seq[:r]
+                                  for seq in (gaps, prompts, answers))
     due, t = [], 0.0
     for g in gaps:
         due.append(t)

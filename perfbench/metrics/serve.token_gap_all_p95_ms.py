@@ -1,6 +1,6 @@
 """95th percentile of EVERY gap between two tokens of every answer in the
 window: a prefill between two steps lengthens one gap of every running
-answer, which the per-answer mean (`tpot_p95_ms`) averages away."""
+answer, which the per-answer mean (`tpot_p50_ms`, `serve.tpot_p95_ms`) averages away."""
 
 from perfbench.lib.stats import percentile
 

@@ -4,7 +4,9 @@
 
 A new process every run; nothing outlives it but jax's compile cache. This
 process never touches jax: the cell's driver asks ray_tpu for the worker or
-replica that holds the chips. The last line of stdout is the result.
+replica that holds the chips. The last line of stdout is the result; its
+last key, and the last lines of stderr, are the numbers compared, each
+beside its limit.
 Without the chips the cell asks for there is no result and the exit code is
 not 0. `--cpu-rehearsal` runs the same control flow on the CPU (for tests,
 at tiny sizes); it tags every line, never prints a result line, exits 10.
@@ -79,23 +81,34 @@ def main() -> int:
 
     ticker = _Ticker()
     driver = man.load_module("drivers", traffic["driver"])
-    rec = driver.run({
+    ctx = {
         "cell": cell, "config": config, "traffic": traffic, "seed": args.seed,
         "seconds": args.seconds, "trace": bool(args.trace),
         "rehearsal": args.cpu_rehearsal, "out_dir": out_dir,
         "control": args.control, "t_start": T_START,
+        "overridden": [o.partition("=")[0] for o in args.override or []],
         "reference_file": man.find("references", config["reference"] + ".py"),
-    })
+    }
+    rec = driver.run(ctx)
+    # `serve_env`: the Serve settings a serving driver put in force, each
+    # with the file it came from (`manifest.lay_serve_env`): what deployment
+    # this row measured
     rec.update({"t_start": T_START, "config": config, "traffic": traffic,
-                "cell": cell, "seconds": args.seconds, "traced": bool(args.trace)})
+                "cell": cell, "seconds": args.seconds, "traced": bool(args.trace),
+                "serve_env": ctx.get("serve_env", {})})
 
     # what the metric readers read, kept beside the compile cache for whoever
     # wants to look closer (overwritten by the next run of this cell)
     with open(os.path.join(out_dir, "last_run.json"), "w") as f:
         json.dump({k: v for k, v in rec.items() if k != "rows"}, f, default=str)
 
-    correct = bool(rec.get("correct_extra", True)) and rec["failed"] == 0
-    for name, value, limit in rec["compared"]:
+    # every number that decides `correct`, each beside its limit: the
+    # driver's comparisons, the requests that failed, the driver's own yes / no
+    compared = list(rec["compared"]) + [
+        ("failed", rec["failed"], 0),
+        ("other_check_not_ok", int(not rec.get("correct_extra", True)), 0)]
+    correct = True
+    for name, value, limit in compared:
         ok = value <= limit
         correct = correct and ok
         print(f"{tag}[correct] {name} = {value:.6g}  limit {limit:g}  "
@@ -122,6 +135,10 @@ def main() -> int:
                              "idle_gaps": tr["idle_gaps"]}
     if args.override or args.control:
         line["not_the_cell"] = {"override": args.override, "control": args.control}
+    # the line's last key, and the last lines on standard error (what the
+    # driver's record keeps of a run that is not correct)
+    line["compared"] = {name: {"value": value, "limit": limit}
+                        for name, value, limit in compared}
     print(f"{tag}[setup] worker asked for at {rec['t_ask'] - T_START:.2f}s, "
           f"first device at {rec['t_device'] - T_START:.2f}s, window open at "
           f"{rec['t_open'] - T_START:.2f}s; run took {time.time() - T_START:.1f}s; "
@@ -134,6 +151,9 @@ def main() -> int:
     if "jax" in sys.modules:
         print("perfbench: the parent imported jax", file=sys.stderr)
         return 4
+    for name, c in line["compared"].items():
+        print(f"[correct] {name} = {c['value']:.6g}  limit {c['limit']:g}",
+              file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

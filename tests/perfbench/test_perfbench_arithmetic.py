@@ -76,3 +76,22 @@ def test_unknown_device_is_an_error():
         peaks("cpu")
     with pytest.raises(KeyError):
         peaks("_source")
+
+
+@pytest.mark.parametrize("stalled", [0, 3])
+def test_a_stall_moves_the_tail_and_leaves_the_middle(stalled):
+    """`tpot_p50_ms` beside `serve.tpot_p95_ms`: twenty answers of 11 tokens
+    5 ms apart; a stall of 90 ms inside `stalled` of them (what the chat
+    cell's replica does once a minute, PERF.md section 6, PR 52) lifts the
+    95th percentile by whole milliseconds and the median not at all; a run
+    without rows reads nothing."""
+    man = Manifest(ROOT)
+    p50, p95 = (man.load_module("metrics", n).read
+                for n in ("tpot_p50_ms", "serve.tpot_p95_ms"))
+    rows = [{"ok": True, "max_new_tokens": 11, "n_tokens": 11,
+             "arrivals_s": [i + 0.005 * k + (0.09 if i < stalled and k > 5 else 0.0)
+                            for k in range(11)]} for i in range(20)]
+    run = {"window_rows": rows, "seconds": 51}
+    assert p50(run) == pytest.approx(5.0)
+    assert p95(run) == pytest.approx(14.0 if stalled else 5.0)
+    assert p50({"window_rows": []}) is None

@@ -37,17 +37,22 @@ CHAT = {"kind": "open_loop", "driver": "open_loop_http", "rate_per_s": 4.0,
         "limits": {"token_gap_mean_spacings": 0.01, "prefill_logits_rel_err": 1e-4}}
 
 
-def _throw_away_root(tmp_path):
+def _throw_away_root(tmp_path, serve="toy-serve", chat=None, run=None):
     """A manifest of its own: the real metrics under toy cell names, plus a
-    configuration, two traffic mixes and one metric that exist only here."""
+    configuration, two traffic mixes and one metric that exist only here.
+    `chat` is laid over the serving traffic file and `run` over the
+    configuration's `run` keys; `serve` names the serving cell (its record
+    goes to a directory of that name under `.perfbench_out/`)."""
     real = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    rename = {"mistral7b-train-1chip": "toy-train", "internlm2-serve-chat": "toy-serve"}
+    rename = {"mistral7b-train-1chip": "toy-train", "internlm2-serve-chat": serve}
     extra = tmp_path / "extra"
     for sub in ("configs", "traffic", "metrics"):
         (extra / sub).mkdir(parents=True)
-    (extra / "configs" / "toy.json").write_text(json.dumps(TOY))
+    (extra / "configs" / "toy.json").write_text(
+        json.dumps(dict(TOY, run=dict(TOY["run"], **(run or {})))))
     (extra / "traffic" / "toy-steps.json").write_text(json.dumps(STEPS))
-    (extra / "traffic" / "toy-chat.json").write_text(json.dumps(CHAT))
+    (extra / "traffic" / "toy-chat.json").write_text(
+        json.dumps(dict(CHAT, **(chat or {}))))
     (extra / "metrics" / "toy.steps_done.py").write_text(
         'def read(run):\n    return float(run["steps"]) if "steps" in run else None\n')
     metrics = {"end_to_end": [], "per_layer": []}
@@ -70,7 +75,7 @@ def _throw_away_root(tmp_path):
         "workloads": [
             {"name": "toy-train", "config": "toy", "traffic": "toy-steps",
              "chips": 1, "why": "throw-away"},
-            {"name": "toy-serve", "config": "toy", "traffic": "toy-chat",
+            {"name": serve, "config": "toy", "traffic": "toy-chat",
              "chips": 1, "why": "throw-away"}],
         **metrics}))
     return str(tmp_path)
@@ -90,7 +95,7 @@ def _would_report(stdout):
 @pytest.mark.parametrize("cell,trace,expects", [
     ("toy-train", 1, {"toy.steps_done", "trainer.step_ms_p50", "compile.s",
                       "worker.spawn_to_device_s"}),
-    ("toy-serve", 0, {"tpot_p95_ms", "serve_tokens_per_s", "setup_s"}),
+    ("toy-serve", 0, {"tpot_p50_ms", "serve_tokens_per_s", "setup_s"}),
 ])
 def test_a_cell_added_as_files_runs_through_the_real_command(tmp_path, cell,
                                                              trace, expects):
@@ -108,6 +113,10 @@ def test_a_cell_added_as_files_runs_through_the_real_command(tmp_path, cell,
     assert not {"kernels.train_mxu_share", "device.idle_share.train",
                 "kernels.decode_hbm_share"} & set(rep["metrics"])
     assert "[correct]" in p.stdout and "limit" in p.stdout
+    # each number compared beside its limit: the line's LAST key
+    assert list(rep)[-1] == "compared"
+    assert {"failed", "other_check_not_ok"} < set(rep["compared"])
+    assert all(c["value"] <= c["limit"] for c in rep["compared"].values())
 
 
 @pytest.mark.parametrize("cell", ["mistral7b-train-1chip", "internlm2-serve-chat",
@@ -164,5 +173,94 @@ def test_the_control_comes_out_as_not_correct(tmp_path, cell, control, number):
               "--seed", "7", "--seconds", "1", "--trace", "0", "--control", control,
               "--cpu-rehearsal"])
     assert p.returncode == 10, p.stdout[-2000:] + p.stderr[-2000:]
-    assert _would_report(p.stdout)["correct"] is False
+    rep = _would_report(p.stdout)
+    assert rep["correct"] is False
     assert any(number in l and "NOT OK" in l for l in p.stdout.splitlines())
+    assert rep["compared"][number]["value"] > rep["compared"][number]["limit"]
+
+
+QUEUE, PROXY = "RAY_TPU_SERVE_MAX_QUEUE_PER_REPLICA", "RAY_TPU_SERVE_PROXY_MAX_INFLIGHT"
+
+
+@pytest.mark.parametrize("config_env,traffic_env,override,said,runs", [
+    # a traffic file without the key leaves the configuration's in force: a
+    # router whose loaded settings say 0 sheds the driver's first call
+    ({QUEUE: "0"}, None, None, "the in-flight cap (0)", False),
+    # the traffic file wins, and what it states reaches the router
+    ({QUEUE: "0"}, {QUEUE: "64"}, None, f"{QUEUE}=64 (traffic toy-chat)", True),
+    # ... and the proxy; the configuration's other setting stays in force
+    ({QUEUE: "64"}, {PROXY: "0"}, None, "proxy at in-flight cap (0)", False),
+    # `--override` lays it as it lays any key, and marks the line
+    ({QUEUE: "0"}, None, {QUEUE: "64"}, f"{QUEUE}=64 (--override)", True),
+])
+def test_a_traffic_files_serve_env_reaches_the_cluster(tmp_path, config_env,
+                                                       traffic_env, override,
+                                                       said, runs):
+    """The toy serving cell through the real command with `serve_env` stated
+    by the configuration, the traffic file or `--override`: the settings the
+    router and the proxy LOADED (`ray_tpu/serve/config.py`, read from their
+    processes' environment) are the ones the `[traffic]` line says are in
+    force. A cap of 0 sheds every request, the driver's own first call too,
+    with HTTP 503 and a body that names the cap the process holds."""
+    cell = "toy-serve-env"
+    root = _throw_away_root(
+        tmp_path, serve=cell, run={"serve_env": config_env},
+        chat={"serve_env": traffic_env} if traffic_env else None)
+    p = _run(["--root", root, "--workload", cell, "--seed", str(2**31 + 52),
+              "--seconds", "2", "--trace", "0", "--cpu-rehearsal"]
+             + (["--override", "serve_env=" + json.dumps(override)]
+                if override else []))
+    out = p.stdout + p.stderr
+    in_force = next(l for l in p.stdout.splitlines()
+                    if l.startswith("[traffic] serve_env in force: "))
+    for key, value in config_env.items():
+        if key not in (traffic_env or override or {}):
+            assert f"{key}={value} (configuration toy)" in in_force
+    assert said in out, out[-3000:]
+    if not runs:
+        assert p.returncode not in (0, 10) and "HTTP 503" in out
+        assert "would report" not in p.stdout
+        return
+    assert p.returncode == 10, out[-3000:]
+    rep = _would_report(p.stdout)
+    assert rep["correct"] is True and rep["failed"] == 0 and rep["attempted"] > 0
+    assert ("not_the_cell" in rep) is bool(override)
+    with open(os.path.join(ROOT, ".perfbench_out", cell, "last_run.json")) as f:
+        kept = json.load(f)["serve_env"]
+    assert kept == {QUEUE: {"value": "64", "from": "--override" if override
+                            else "traffic toy-chat"}}
+
+
+def test_a_serve_env_key_that_is_no_serve_setting_ends_the_run(tmp_path):
+    """Before any process of the cluster exists, naming the key and where
+    it was stated."""
+    root = _throw_away_root(tmp_path, chat={"serve_env": {"JAX_PLATFORMS": "tpu"}})
+    p = _run(["--root", root, "--workload", "toy-serve", "--seed", "1",
+              "--seconds", "1", "--trace", "0", "--cpu-rehearsal"], timeout=60)
+    assert p.returncode not in (0, 10)
+    assert "'JAX_PLATFORMS'" in p.stderr and "traffic toy-chat" in p.stderr
+    assert "[setup]" not in p.stdout and "would report" not in p.stdout
+
+
+def test_serve_env_is_laid_configuration_first_traffic_over_it(monkeypatch, capsys):
+    sys.path.insert(0, ROOT)
+    from perfbench.lib.manifest import lay_serve_env
+
+    drain = "RAY_TPU_SERVE_DRAIN_DEADLINE_S"
+    for key in (QUEUE, PROXY, drain):
+        monkeypatch.delenv(key, raising=False)
+    ctx = {"cell": {"config": "c", "traffic": "t"},
+           "config": {"run": {"serve_env": {QUEUE: "128", drain: "5"}}},
+           "traffic": {"serve_env": {QUEUE: 2048, PROXY: "4096"}}}
+    assert lay_serve_env(ctx) == ctx["serve_env"] == {
+        QUEUE: {"value": "2048", "from": "traffic t"},
+        drain: {"value": "5", "from": "configuration c"},
+        PROXY: {"value": "4096", "from": "traffic t"}}
+    assert [os.environ[k] for k in (QUEUE, drain, PROXY)] == ["2048", "5", "4096"]
+    assert capsys.readouterr().out.strip() == (
+        f"[traffic] serve_env in force: {QUEUE}=2048 (traffic t); {drain}=5 "
+        f"(configuration c); {PROXY}=4096 (traffic t)")
+    # neither file states any: nothing is set, and the line says so
+    assert lay_serve_env({"cell": ctx["cell"], "config": {"run": {}},
+                          "traffic": {}}) == {}
+    assert capsys.readouterr().out.strip() == "[traffic] serve_env in force: none stated"

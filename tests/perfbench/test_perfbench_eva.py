@@ -16,7 +16,7 @@ RUN = os.path.join(ROOT, "perfbench", "run.py")
 sys.path.insert(0, ROOT)
 
 from perfbench.lib import eva_counts as ec  # noqa: E402
-from perfbench.lib.manifest import Manifest  # noqa: E402
+from perfbench.lib.manifest import Manifest, load_py  # noqa: E402
 
 CELL = "evabyte-serve-longdoc"
 # the catalog row's `config` (model-configs guide, architectures.jsonl,
@@ -152,10 +152,11 @@ def test_the_manifest_takes_the_new_entries():
     assert e2e == {"serve_tokens_per_s", "setup_s"}
     per_layer = {m["name"] for m in man.metrics_for(CELL, "per_layer")}
     assert NEW_METRICS <= per_layer
-    # the list-less readers that move what this cell reports. (The twelve of
-    # the token's way out keep their four cells: `test_perfbench_token_path.py`
-    # pins their `workloads`, and that file is the benchmark's: PERF.md section 7)
-    assert per_layer == NEW_METRICS | {
+    # the list-less readers that move what this cell reports, and (PR 52)
+    # the twelve of the token's way out, which list every serving cell
+    token_path = load_py(os.path.join(ROOT, "tests", "perfbench",
+                                      "test_perfbench_token_path.py"))
+    assert per_layer == NEW_METRICS | set(token_path.NAMES) | {
         "engine.batch_occupancy", "device.peak_hbm_bytes.serve", "compile.s",
         "worker.spawn_to_device_s"}
     # the other models' counts are not read on this one, nor this one's on them

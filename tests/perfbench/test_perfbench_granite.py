@@ -21,8 +21,7 @@ from perfbench.lib.manifest import Manifest  # noqa: E402
 CELL = "granite4h-serve-ragsessions"
 NEW_METRICS = {"engine.ssd_step_ms_p50", "engine.ssd_state_bytes_per_step",
                "moe.ssd_tokens_per_held_expert", "moe.ssd_experts_touched_share",
-               "kernels.ssd_moe_decode_hbm_share", "kernels.ssd_step_hbm_share",
-               "kernels.ssd_scan_hbm_share"}
+               "kernels.ssd_moe_decode_hbm_share", "kernels.ssd_step_hbm_share"}
 # the catalog row's `config` (model-configs guide, architectures.jsonl,
 # granite-4.0-h-small), copied here so that the test needs no file outside
 # the repo
@@ -118,7 +117,7 @@ def _would_report(stdout):
 def test_the_granite_toy_runs_through_the_real_command(tmp_path, trace, control,
                                                        expects):
     """Untraced: the end-to-end metrics; traced: the new counters' metrics
-    read numbers (the three device-trace ones read nothing on the CPU and
+    read numbers (the two device-trace ones read nothing on the CPU and
     are left out); the int8 control comes out as not correct. Exit 10."""
     args = ["--root", _throw_away_root(tmp_path), "--workload", "toy-granite-serve",
             "--seed", str(2**31 + 11), "--seconds", "2", "--trace", str(trace),
@@ -130,7 +129,7 @@ def test_the_granite_toy_runs_through_the_real_command(tmp_path, trace, control,
     assert rep["correct"] is (control is None), p.stdout[-3000:]
     assert expects <= set(rep["metrics"]), rep["metrics"]
     for name in ("kernels.ssd_moe_decode_hbm_share", "kernels.ssd_step_hbm_share",
-                 "kernels.ssd_scan_hbm_share", "kernels.ssm_decode_hbm_share",
+                 "kernels.ssm_decode_hbm_share",
                  "kernels.hybrid_decode_hbm_share", "engine.ssm_step_ms_p50"):
         assert name not in rep["metrics"]
     if trace:
@@ -167,10 +166,12 @@ def test_the_manifest_takes_the_new_entries():
     per_layer = {m["name"] for m in man.metrics_for(CELL, "per_layer")}
     assert NEW_METRICS | {"engine.batch_occupancy",
                           "device.peak_hbm_bytes.serve"} <= per_layer
-    # the other models' counts, and PR 36's token-path metrics, are not read here
+    # the other models' counts are not read here; PR 36's token-path metrics
+    # are, since PR 52 (`test_perfbench_token_path.py` reads their lists)
     assert not {"kernels.decode_hbm_share", "kernels.hybrid_decode_hbm_share",
                 "moe.experts_touched_share", "kernels.ssm_step_hbm_share",
-                "engine.driver_device_wait_share"} & per_layer
+                "kernels.ssd_scan_hbm_share"} & per_layer
+    assert {"engine.driver_device_wait_share", "engine.wakes_per_token"} <= per_layer
     for name in NEW_METRICS:
         m = next(m for m in man.data["per_layer"] if m["name"] == name)
         assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
@@ -263,18 +264,6 @@ def test_state_and_step_bytes(c):
     assert gc.step_kernel_bytes(c, 1) == 4 * (2 * 128 * 8192 + 3 * 8192 + 256)
 
 
-def test_scan_kernel_counts(c):
-    # a position and layer: 2 x 256 x 128 once + 128 heads x (2 x 256 x 64 + 4 x 64 x 128)
-    assert gc.scan_kernel_flops(c, 1, 1) == 65536 + 128 * (32768 + 32768)
-    assert 9 * gc.scan_kernel_flops(c, 1, 1) / 1e6 == pytest.approx(76.1, abs=0.1)
-    # x read + y written 64 KB, C and B^T once a group of 8 heads 16 KB, dt + cum 1 KB
-    per_position = 4 * (2 * 8192 + 16 * 2 * 128 + 2 * 128)
-    assert gc.scan_kernel_bytes(c, 1, 4096) == 4096 * per_position + 2 * 4 * 128 * 8192
-    assert gc.scan_kernel_bytes(c, 2, 100.0) == 2 * gc.scan_kernel_bytes(c, 1, 100.0)
-    # operations a byte under the chip's ridge of 240: the memory binds
-    assert gc.scan_kernel_flops(c, 1, 4096) / gc.scan_kernel_bytes(c, 1, 4096) < 197e12 / 819e9
-
-
 def test_the_new_readers_read_nothing_on_another_cells_record():
     """A record of another model's cell (steps with `latent_rows`, or with
     `kv_rows` but no expert counters; no `ssd_*` kernel calls; no `tokens` on
@@ -300,4 +289,3 @@ def test_the_new_readers_read_nothing_on_another_cells_record():
     for name in sorted(NEW_METRICS):
         read = load_py(os.path.join(ROOT, "perfbench", "metrics", name + ".py")).read
         assert read(run) is None, name
-    assert gc.prefill_spans(run) == []

@@ -163,8 +163,9 @@ def test_serving_cell_rehearsal_reports_all_seven(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("RAY_TPU_TRACING_ENABLED", None)
     p = subprocess.run(
-        [sys.executable, cells.RUN, "--root", cells._throw_away_root(tmp_path),
-         "--workload", "toy-serve", "--seed", str(2**31 + 7), "--seconds", "2",
+        [sys.executable, cells.RUN, "--root",
+         cells._throw_away_root(tmp_path, serve="toy-serve-spans"),
+         "--workload", "toy-serve-spans", "--seed", str(2**31 + 7), "--seconds", "2",
          "--trace", "1", "--cpu-rehearsal"],
         capture_output=True, text=True, timeout=300, env=env, cwd=ROOT)
     assert p.returncode == 10, p.stdout[-3000:] + p.stderr[-3000:]

@@ -1,11 +1,13 @@
 """The twelve per-layer metrics of a token's way out of the engine
 (`perfbench/lib/token_path.py`): each reader on a fixed span list with the
 answer worked out by hand, the rule that a program without these spans (or
-a partial trace) gives None and never a number, which cells list them, and
+a partial trace) gives None and never a number, which cells list them (the
+serving cells are read from BENCHMARK.json, not pinned here), and
 the serving cell rehearsed on the CPU with `--trace 1`."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -23,9 +25,20 @@ NAMES = ["engine.driver_device_wait_share", "engine.driver_lock_wait_share",
          "serve.stream_report_us_per_token", "serve.relay_us_per_token",
          "serve.first_chunk_lag_ms_p50", "engine.driver_bookkeep_share",
          "engine.stream_lock_us_per_token", "serve.arrive_lag_ms_per_token"]
-SERVING = ["internlm2-serve-chat", "kimi-linear-serve-longgen",
-           "jamba2-serve-chat-burst", "openpangu-serve-longctx"]
-TRAINING = ["mistral7b-train-1chip", "mistral7b-train-4chip"]
+
+
+def _cells():
+    """BENCHMARK.json's cells, told apart by their traffic file's driver:
+    those served over HTTP (`open_loop_http*`), in the manifest's order, and
+    the rest. A cell that a later PR adds joins by an appended entry."""
+    man = Manifest(ROOT)
+    serving = [w["name"] for w in man.data["workloads"]
+               if man.load_traffic(w["traffic"])["driver"].startswith("open_loop_http")]
+    return serving, [w["name"] for w in man.data["workloads"]
+                     if w["name"] not in serving]
+
+
+SERVING, TRAINING = _cells()
 
 
 def _span(name, start_ms, dur_ms, trace=None, pid=2, tid=7, **args):
@@ -166,23 +179,35 @@ def test_without_every_span_there_is_no_number(how, capsys):
 
 
 @pytest.mark.parametrize("cell", SERVING + TRAINING)
-def test_the_four_serving_cells_list_all_twelve(cell):
-    """Each names its cells (`workloads`) and moves `serve_tokens_per_s`,
-    the one end-to-end metric all four serving cells report: without the
-    list they would be read in the chat cell only."""
+def test_the_serving_cells_list_all_twelve(cell):
+    """Each of the twelve names its cells (`workloads`), serving cells only,
+    and moves `serve_tokens_per_s`, the one end-to-end metric every serving
+    cell reports: without the list they would be read in the chat cell
+    only. A cell that comes later joins by an appended entry."""
     man = Manifest(ROOT)
-    listed = {m["name"]: m for m in man.metrics_for(cell, "per_layer")}
+    twelve = {m["name"]: m for m in man.data["per_layer"] if m["name"] in NAMES}
+    assert sorted(twelve) == sorted(NAMES)
+    listed = {m["name"] for m in man.metrics_for(cell, "per_layer")}
     if cell in TRAINING:
-        assert not set(NAMES) & set(listed)
+        assert not set(NAMES) & listed
         # and a record that is no serving run reads None without a fetch
         assert [_read(n, {"t_open": 0.0, "seconds": 1.0}) for n in NAMES] == \
             [None] * len(NAMES)
         return
-    assert set(NAMES) <= set(listed)
     for n in NAMES:
-        assert listed[n]["workloads"] == SERVING
-        assert listed[n]["moves"] == "serve_tokens_per_s"
+        assert set(twelve[n]["workloads"]) <= set(SERVING)
+        assert twelve[n]["moves"] == "serve_tokens_per_s"
         assert os.path.isfile(os.path.join(ROOT, "perfbench", "metrics", n + ".py"))
+
+
+def test_the_six_serving_cells_of_pr_52_list_all_twelve():
+    man = Manifest(ROOT)
+    assert len(SERVING) >= 6 and len(TRAINING) >= 2
+    for cell in ("internlm2-serve-chat", "kimi-linear-serve-longgen",
+                 "jamba2-serve-chat-burst", "openpangu-serve-longctx",
+                 "evabyte-serve-longdoc", "granite4h-serve-ragsessions"):
+        assert cell in SERVING
+        assert set(NAMES) <= {m["name"] for m in man.metrics_for(cell, "per_layer")}
 
 
 def test_serving_cell_rehearsal_reports_all_twelve(tmp_path):
@@ -193,8 +218,9 @@ def test_serving_cell_rehearsal_reports_all_twelve(tmp_path):
                                  "test_perfbench_cells.py"))
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
-        [sys.executable, cells.RUN, "--root", cells._throw_away_root(tmp_path),
-         "--workload", "toy-serve", "--seed", str(2**31 + 11), "--seconds", "2",
+        [sys.executable, cells.RUN, "--root",
+         cells._throw_away_root(tmp_path, serve="toy-serve-token-path"),
+         "--workload", "toy-serve-token-path", "--seed", str(2**31 + 11), "--seconds", "2",
          "--trace", "1", "--cpu-rehearsal"],
         capture_output=True, text=True, timeout=300, env=env, cwd=ROOT)
     assert p.returncode == 10, p.stdout[-3000:] + p.stderr[-3000:]
@@ -202,6 +228,11 @@ def test_serving_cell_rehearsal_reports_all_twelve(tmp_path):
     assert rep["correct"] is True and rep["failed"] == 0
     assert set(NAMES) <= set(rep["metrics"]), (sorted(rep["metrics"]),
                                                p.stdout[-2000:])
+    # the replica timed its own trace (`trace_between`), inside the window
+    ran = next(l for l in p.stdout.splitlines() if "[trace] the profiler ran" in l)
+    started, stopped, a, b, written = map(float, re.findall(r"(-?[\d.]+)s", ran))
+    assert (a, b) == (0.5, 1.5) and a <= started < b <= stopped < b + 0.5 <= 2.0
+    assert stopped <= written
     said = next(l for l in p.stdout.splitlines() if "[token_path]" in l)
     assert f"{rep['attempted']} streams with all of" in said
     m = {n: rep["metrics"][n]["value"] for n in NAMES}

@@ -63,6 +63,57 @@ def test_the_window_is_one_period_of_periodic_traffic(chat):
     assert before[0]["due_s"] >= -chat["warm_s"]
 
 
+def _window(rows, seconds=51):
+    return [r for r in rows if 0 <= r["due_s"] < seconds]
+
+
+@pytest.mark.parametrize("seeds", [(1, BIG), (BIG + 1, 7)])
+def test_an_order_of_the_files_own_is_turned_by_the_seed(chat, seeds):
+    """With `order_seed` every seed offers ONE cyclic sequence of (gap,
+    prompt length, answer length), started at a request of its own: who
+    follows whom at what distance is the same, so the same answers meet the
+    same prompt passes; the prompts' ids are still the seed's."""
+    tr = dict(chat, order_seed=9)
+    a, c = (_window(traffic.open_loop(tr, s, 51, 92544)) for s in seeds)
+    n = round(chat["rate_per_s"] * 51)
+    assert len(a) == len(c) == n
+
+    def cycle(rows):  # (prompt, answer, gap to the next, round the period's end)
+        due = [r["due_s"] for r in rows] + [rows[0]["due_s"] + 51]
+        return [(len(r["prompt"]), r["max_new_tokens"], round(due[k + 1] - due[k], 9))
+                for k, r in enumerate(rows)]
+
+    ca, cc = cycle(a), cycle(c)
+    turns = [k for k in range(n) if ca == cc[k:] + cc[:k]]
+    assert len(turns) == 1 and turns[0] != 0
+    assert a[0]["prompt"] != c[turns[0]]["prompt"]  # same length, other ids
+    # without the key the order is the seed's: no turn of one sequence
+    own = {k: v for k, v in chat.items() if k != "order_seed"}
+    pa, pc = (cycle(_window(traffic.open_loop(own, s, 51, 92544))) for s in seeds)
+    assert not any(pa == pc[k:] + pc[:k] for k in range(n))
+
+
+def test_the_chat_cell_states_one_order(chat):
+    """`serve.tpot_p95_ms` is a tail over 275 answers: the chat file fixes who
+    meets whom (PERF.md section 6, PR 52)."""
+    assert isinstance(chat.get("order_seed"), int) and chat["order_why"]
+
+
+def test_a_turned_period_is_still_one_period(chat):
+    """The requests before the window are its own end one period earlier,
+    with `order_seed` as without."""
+    tr = dict(chat, order_seed=9)
+    rows = traffic.open_loop(tr, BIG, 51, 92544)
+    assert rows == traffic.open_loop(tr, BIG, 51, 92544)
+    before = [r for r in rows if r["due_s"] < 0]
+    tail = [r for r in rows if r["due_s"] >= 51 - chat["warm_s"]]
+    assert len(before) == len(tail) > 20
+    for b, t in zip(before, tail):
+        assert b["due_s"] == pytest.approx(t["due_s"] - 51)
+        assert (len(b["prompt"]), b["max_new_tokens"]) == (len(t["prompt"]),
+                                                           t["max_new_tokens"])
+
+
 def test_chat_rate_obeys_the_slot_rule(chat):
     slots = Manifest(ROOT).load_config("internlm2-1.8b")["run"]["num_slots"]
     rule = traffic.slot_rule(chat, slots)
