@@ -334,6 +334,16 @@ def tp_exchanges_per_layer(cfg: ModelConfig, mesh, batch: int, seq: int) -> int:
     return 0 if _rows_mesh(cfg, mesh, batch, seq) is None else 4
 
 
+def ring_products_own_first(cfg: ModelConfig, mesh, batch: int, seq: int) -> int:
+    """How many of a layer's products by a weight whose shards ride fsdp's
+    ring have their order pinned, the rank's own shard's product before the
+    arrived shard's: 1 where `_rows_mesh` says the products are
+    parallel/tp.py's (the FFN's `w_down` in the backward, the first thing a
+    layer's backward can run: `tp._matmul_scatter_bwd`), else 0 (the train
+    step's `xla.compile` spans carry it)."""
+    return 0 if _rows_mesh(cfg, mesh, batch, seq) is None else 1
+
+
 def grad_exchanges_per_layer(cfg: ModelConfig, mesh, batch: int) -> int:
     """How many of a layer's weight gradients this program exchanges over
     `fsdp` itself: 7 for the dense block on a mesh with fsdp > 1, else 0

@@ -16,7 +16,9 @@ is ours. On a mesh with `tp` > 1 as well the products themselves are
 `weight_grad` for the `dw` and `ring_products` for the products: the
 weight's shards go round the same ring INSIDE the product, a rank
 multiplying by its own shard while its neighbour's travels (the partitioner
-gathers one weight at a time, each where the one before is first used).
+gathers one weight at a time, each where the one before is first used);
+where no earlier product of the loop body covers the transfer, the caller
+pins that order (`own_first`).
 
 A `shard_map` manual over `fsdp` alone (`tp`, `dp` and the rest stay the
 partitioner's) makes every rank's partial `dw`, of its own rows of the
@@ -165,7 +167,8 @@ def _region(body, mesh, in_specs, out_specs):
                          in_specs=in_specs, out_specs=out_specs, check_vma=False)
 
 
-def ring_products(groups, ws, dim: int, transposed: bool, mesh) -> list:
+def ring_products(groups, ws, dim: int, transposed: bool, mesh, *,
+                  own_first: bool = False) -> list:
     """For each group of operands (one a weight, [batch, ..., width], batch
     split over (dp, fsdp)): the sum over the weights of operand @ w, or of
     operand @ w^T with `transposed`, for weights [k, n] sharded over `fsdp`
@@ -177,10 +180,25 @@ def ring_products(groups, ws, dim: int, transposed: bool, mesh) -> list:
     operand's matching columns: the partials are taken and added in float32
     and rounded once, as the one product by the gathered weight is.
 
-    For the FIRST product of a layer's backward above all: the partitioner's
-    gather of a weight can start only inside an earlier product of the same
-    loop body, so there it runs alone on the compute stream (29 MB for
-    `w_down` at Mistral-7B widths)."""
+    That order is the program's; the compiled step's is the scheduler's,
+    which keeps it wherever an earlier product of the loop body stands
+    between a shard's start and its done anyway. At the FIRST product of a
+    layer's backward none does (the partitioner's gather of a weight can
+    start only inside an earlier product of the same loop body, so there it
+    runs alone on the compute stream: 29 MB for `w_down` at Mistral-7B
+    widths), and there the compiler turns the order round: `acc + part` of
+    two float32 partials commutes, it fuses the sum into the own shard's
+    product, the fused one runs last, and the arrived shard's product stands
+    straight behind the done, waiting for the transfer it was there to
+    cover (6.4 ms of a 311 ms step; PERF.md section 6, PR 54). `own_first`
+    pins the order for such a caller: a round's results are complete before
+    the arrived shards are taken (an `optimization_barrier` over both), so
+    the sum can fuse only into the arrived shard's product. The partials,
+    their one rounding, the order of the ranks and every bit of the result
+    stay. Not for every product that sums over the sharded dimension: the
+    other shards' transfers are covered by the products before them, and
+    pinned, the forward's sums come unfused (XLA's own estimate of the
+    forward body at those widths: 4.445 -> 5.126 ms a layer)."""
     n = axis_size(mesh)
     summed = 1 if transposed else 0   # the weight's dimension a product sums over
     ring = _ring(n)
@@ -206,6 +224,8 @@ def ring_products(groups, ws, dim: int, transposed: bool, mesh) -> list:
                         outs[t] = jnp.zeros((*part.shape[:2], n * size), part.dtype)
                     outs[t] = jax.lax.dynamic_update_slice_in_dim(
                         outs[t], part, at, 2)
+            if own_first and arriving is not None:
+                arriving, outs = jax.lax.optimization_barrier((arriving, outs))
             ws = arriving
         if dim == summed:
             outs = [sum(acc.astype(group[0].dtype) for acc in accs)

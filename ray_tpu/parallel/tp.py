@@ -32,10 +32,12 @@ The weights come as `fsdp.ExchangedWeight`s (the mesh has fsdp > 1 too:
 module's ring, once a layer in exact shards, and the weights' gathers over
 `fsdp` ride inside the products as well (`fsdp.ring_products`: the shards go
 round fsdp's ring, a product by a rank's own shard covering its neighbour's
-way). Left to the partitioner they come one at a time, each started where
-the one before is first used; the four all-reduces were when they caught
-up, and with those gone the step waited for weights instead (344 ms a step
-against the parent's 338: PERF.md section 6, PR 38).
+way; the FFN's `w_down` in the backward, the first product a layer's
+backward can run, pins that order, which the compiler turns round there:
+`_matmul_scatter_bwd`). Left to the partitioner they come one at a time,
+each started where the one before is first used; the four all-reduces were
+when they caught up, and with those gone the step waited for weights instead
+(344 ms a step against the parent's 338: PERF.md section 6, PR 38).
 
 `chunks`: the whole-sequence side of a product may stay a tuple of chunks,
 one a rank of the ring and in each rank's OWN order (its rows first, then
@@ -265,9 +267,14 @@ def _matmul_scatter_bwd(dim, mesh, res, dy):
         if isinstance(x, tuple):
             # the FFN's, first in a layer's backward: dy is there from the
             # start and travels behind what remat computes again, so ONE
-            # product over the whole sequence
+            # product over the whole sequence. No earlier product of the
+            # body covers `w_down`'s shard on its way, so the own shard's
+            # product is pinned before the arrived shard's; here alone
+            # (every other shard has products before it, and a pinned sum
+            # comes unfused: `fsdp.ring_products`)
             whole_dy = _one_array(rows)
-            dx, = fsdp.ring_products([[whole_dy]], [w], dim, True, mesh)
+            dx, = fsdp.ring_products([[whole_dy]], [w], dim, True, mesh,
+                                       own_first=True)
             dx = tuple(jnp.split(dx, len(x), axis=1))
         else:
             whole_dy = _in_sequence(rows)

@@ -15,7 +15,8 @@ model moves: there the two norm scales' gradients leave the layers' scan as
 each rank's partial sums and are all-reduced once a step, where the
 partitioner's reduction waits for every chip once a layer. The step's
 `xla.compile` spans say which forms it has (`grad_exchanges_per_layer`,
-`tp_exchanges_per_layer`, `norm_grad_reductions_in_layers`).
+`tp_exchanges_per_layer`, `norm_grad_reductions_in_layers`,
+`ring_products_own_first`).
 """
 
 from __future__ import annotations
@@ -36,6 +37,7 @@ from ray_tpu.models.transformer import (
     loss_fn,
     norm_grad_reductions_in_layers,
     param_logical_axes,
+    ring_products_own_first,
     split_batch,
     tp_exchanges_per_layer,
 )
@@ -157,8 +159,9 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh,
 
     def step(state: TrainState, batch: Dict[str, jax.Array]):
         # which form of the gradients' reduction over fsdp, of the block's
-        # reductions over tp and of the norm scales' this program has is a
-        # fact of its compile: on its `xla.compile` spans
+        # reductions over tp and of the norm scales' this program has, and
+        # how many ring products run own shard first by a pin, is a fact of
+        # its compile: on its `xla.compile` spans
         inputs = split_batch(batch)[0]
         tracing.note_compile(
             "step", fsdp=mesh.shape.get("fsdp", 1), tp=mesh.shape.get("tp", 1),
@@ -167,7 +170,9 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh,
             tp_exchanges_per_layer=tp_exchanges_per_layer(
                 cfg, mesh, *inputs.shape),
             norm_grad_reductions_in_layers=norm_grad_reductions_in_layers(
-                cfg, mesh, inputs.shape[0]))
+                cfg, mesh, inputs.shape[0]),
+            ring_products_own_first=ring_products_own_first(
+                cfg, mesh, *inputs.shape))
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, batch, cfg, mesh)
         # (the phases before this one are named in models/transformer.py:

@@ -888,18 +888,53 @@ def _assert_weights_ride_the_fsdp_ring(comps):
     over fsdp too (`fsdp.ring_products`): each scan body sends the seven
     weights' shards ([2048,512] x 2, [2048,2048] x 2, [2048,7168] x 2,
     [7168,2048]) round the fsdp pairs as permutes, started at the head of the
-    body with matmul fusions between start and done (the backward's first,
-    `w_down`'s, behind the attention kernel that remat runs again), and NO
+    body, every one of the fourteen with a matmul fusion between its start
+    and its done (the backward's first, `w_down`'s, behind the product by
+    the rank's own shard since PR 54: the case below), and NO
     `all-gather` is left in either body: the partitioner gathered them one at
     a time, each started where the one before was first used, and once the tp
     all-reduces had left the compute stream the step waited for them."""
-    for body, first in zip(_scan_bodies(comps), ("matmul", "kernel")):
+    for body in _scan_bodies(comps):
         assert not [l for l in body if " all-gather(" in l]
         shards = [v for v in _permutes(comps, body, _FSDP_PAIRS).values()
                   if v[0].count(",") == 1]
         assert sorted(dims for dims, _ in shards) == _WEIGHT_SHARDS, shards
-        covers = [cover for _, cover in shards]
-        assert covers.count("matmul") >= 6 and set(covers) <= {"matmul", first}
+        assert [cover for _, cover in shards] == ["matmul"] * 7, shards
+
+
+def _operands(line: str) -> list:
+    """The names an instruction takes (what it calls is no operand)."""
+    return re.findall(r"%([\w.\-]+)",
+                      re.search(r" [a-z][a-z0-9\-]*\(([^)]*)\)", line).group(1))
+
+
+def _assert_own_shard_first_in_the_backward(comps):
+    """`w_down`'s shard [7168,2048] is the first thing a layer's backward
+    sends, and no earlier product of the body hides its way. By OPERANDS,
+    not by name: the matmul fusion that multiplies by the rank's own shard
+    (the permute's operand) is scheduled between the permute's start and its
+    done, and the first matmul fusion behind the done is the one that takes
+    the done, the arrived shard's product, with none by the own shard left
+    behind it. The compiler had it the other way round before PR 54: the sum
+    of the two float32 products fused into the own shard's, which then ran
+    last (`fsdp.ring_products`, `own_first`)."""
+    _, body = _scan_bodies(comps)
+    (start,) = [i for i, l in enumerate(body)
+                if " collective-permute-start(" in l and _FSDP_PAIRS in l
+                and re.search(r"= \(\w+\[7168,2048\]", l)]
+    name = lambda l: re.match(r"\s*%([\w.\-]+) =", l).group(1)
+    own, = _operands(body[start])
+    done = next(i for i, l in enumerate(body)
+                if f"collective-permute-done(%{name(body[start])})" in l)
+    arrived = name(body[done])
+    products = [(i, own in _operands(l), arrived in _operands(l))
+                for i, l in enumerate(body) if _is_matmul(comps, l)]
+    by_own = [i for i, o, a in products if o and not a]
+    by_arrived = [i for i, o, a in products if a]
+    assert by_own and by_arrived, (by_own, by_arrived)
+    assert all(start < i < done for i in by_own), (start, by_own, done)
+    behind = [i for i, _, _ in products if i > done]
+    assert behind[0] == by_arrived[0], (behind[:2], by_arrived)
 
 
 def _assert_no_tp_all_reduce_in_the_layers(comps):
@@ -962,6 +997,7 @@ _CELL_STEP_ASSERTIONS = {
     "tp_exchanges_behind_the_products": _assert_tp_exchanges_run_behind_the_products,
     "weights_ride_the_fsdp_ring": _assert_weights_ride_the_fsdp_ring,
     "no_all_reduce_in_the_layers": _assert_no_all_reduce_in_the_layers,
+    "own_shard_first_in_the_backward": _assert_own_shard_first_in_the_backward,
 }
 _TWO_LAYERS = {}  # the compiled text of one compile, for the cases below
 
@@ -969,7 +1005,7 @@ _TWO_LAYERS = {}  # the compiled text of one compile, for the cases below
 @pytest.mark.parametrize("what", list(_CELL_STEP_ASSERTIONS))
 def test_four_chip_cell_step_exchanges_behind_matmuls(topo, chip, what):
     """Two layers of the 4-chip cell's step (twenty seconds, compiled once
-    for the five cases; the whole 22 are the slow case below)."""
+    for the six cases; the whole 22 are the slow case below)."""
     from ray_tpu.train.step import default_optimizer
 
     if not _TWO_LAYERS:

@@ -96,6 +96,7 @@ def test_grad_exchange_over_fsdp_matches_one_device(mesh_cfg, n, monkeypatch):
     cfg = ModelConfig.tiny()
     mesh = make_virtual_mesh(n, mesh_cfg)
     assert transformer.grad_exchanges_per_layer(cfg, mesh, 8) == 7
+    assert transformer.ring_products_own_first(cfg, mesh, 8, 64) == 1
     params = init_params(jax.random.PRNGKey(0), cfg)
     batch = _batch(jax.random.PRNGKey(1), cfg, batch=8, seq=64)
     grad = lambda m: jax.jit(jax.value_and_grad(
@@ -150,6 +151,7 @@ def test_tp_exchange_matches_one_device(mesh_cfg, n, monkeypatch):
     ours = mesh_cfg.fsdp > 1
     assert transformer.tp_exchanges_per_layer(cfg, mesh, 8, 64) == 4 * ours
     assert transformer.grad_exchanges_per_layer(cfg, mesh, 8) == 7 * ours
+    assert transformer.ring_products_own_first(cfg, mesh, 8, 64) == ours
     params = init_params(jax.random.PRNGKey(0), cfg)
     batch = _batch(jax.random.PRNGKey(1), cfg, batch=8, seq=64)
     grad = lambda m: jax.jit(jax.value_and_grad(
@@ -179,6 +181,64 @@ def test_tp_exchange_matches_one_device(mesh_cfg, n, monkeypatch):
     monkeypatch.setattr(transformer, "_rows_mesh", lambda *a: None)
     np.testing.assert_allclose(losses, _five_losses(cfg, mesh, on_mesh), rtol=1e-5)
     assert losses[-1] < losses[0]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("mesh_cfg,n", [
+    (MeshConfig(dp=1, fsdp=2, tp=2), 4),
+    (MeshConfig(dp=1, fsdp=4, tp=2), 8),
+    (MeshConfig(dp=2, fsdp=2, tp=2), 8),
+], ids=["fsdp2xtp2", "fsdp4xtp2", "dp2xfsdp2xtp2"])
+def test_own_shard_first_changes_no_bit(mesh_cfg, n, dtype, monkeypatch):
+    """The FFN's backward asks `fsdp.ring_products` to finish the product by
+    the rank's own `w_down` shard before it takes the shard that arrives
+    (`own_first`: one `optimization_barrier` a round of the ring, fsdp - 1
+    of them, at that one call of the layer and no other). The same float32
+    partials are added, a + b for b + a, and rounded once: the loss and
+    EVERY gradient leaf are bit for bit those of the program that never pins
+    (the parent's form, `own_first` dropped here), in float32 and in
+    bfloat16, as the four-chip cell runs it."""
+    import dataclasses
+
+    from ray_tpu.models import transformer
+    from ray_tpu.parallel import fsdp
+    from ray_tpu.train.step import state_shardings
+
+    cfg = dataclasses.replace(ModelConfig.tiny(), n_kv_heads=2,
+                              dtype=jnp.dtype(dtype))
+    mesh = make_virtual_mesh(n, mesh_cfg)
+    assert transformer.ring_products_own_first(cfg, mesh, 8, 64) == 1
+    params = jax.device_put(
+        init_params(jax.random.PRNGKey(0), cfg),
+        state_shardings(cfg, mesh, default_optimizer()).params)
+    batch = _batch(jax.random.PRNGKey(1), cfg, batch=8, seq=64)
+    batch = jax.device_put(batch, {k: batch_sharding(mesh)[k] for k in batch})
+
+    def run():
+        lowered = jax.jit(jax.value_and_grad(
+            lambda p, b: loss_fn(p, b, cfg, mesh)[0])).lower(params, batch)
+        return (lowered.as_text().count("optimization_barrier"),
+                *lowered.compile()(params, batch))
+
+    pins, loss, grads = run()
+    pinned = fsdp.ring_products
+    asked = []
+
+    def never_pinned(*args, own_first=False, **kw):
+        asked.append(own_first)
+        return pinned(*args, **kw)
+
+    monkeypatch.setattr(fsdp, "ring_products", never_pinned)
+    parent_pins, parent_loss, parent_grads = run()
+    # of a layer's ring products (forward, remat's, backward) ONE asks
+    assert asked.count(True) == 1 and asked.count(False) >= 8, asked
+    assert pins - parent_pins == mesh_cfg.fsdp - 1
+    bits = lambda a: np.asarray(a).view(f"u{a.dtype.itemsize}")
+    np.testing.assert_array_equal(bits(loss), bits(parent_loss))
+    jax.tree_util.tree_map_with_path(
+        lambda path, g, w: np.testing.assert_array_equal(
+            bits(g), bits(w), err_msg=jax.tree_util.keystr(path)),
+        grads, parent_grads)
 
 
 @pytest.mark.parametrize("dtype", ["bf16_scales", "bf16"])
