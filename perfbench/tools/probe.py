@@ -5,9 +5,15 @@ traffic files written beside copies of the configurations, for
 queue. A probe file (`tools/probes/<cell>.json`) holds exactly what a later
 PR adds as data: `traffic` (name -> the traffic file), `workloads` (the
 cells' entries) and `metric_workloads` (metric -> the cells appended to its
-`workloads` list). No configuration: a probe runs on one that is there.
+`workloads` list). A probe runs on a configuration that is there, or BRINGS
+one as a `model_config` PR would, under an optional `configs`: a list of
+`{entry, file_body, published}` (the `BENCHMARK.json` entry, the content of
+the configuration's file, the content of its published row's file,
+`tests/perfbench/published/<name>.json`). `check` runs the manifest's tests
+on such a root, before any of it reaches `BENCHMARK.json`.
 
     python3 perfbench/tools/probe.py root perfbench/tools/probes/<cell>.json _check/<dir>
+    python3 perfbench/tools/probe.py check _check/<dir>
     python3 perfbench/run.py --root _check/<dir> --workload <cell> --seed 7 --seconds 51 --trace 0
     python3 perfbench/tools/probe.py read .perfbench_out/<cell>/last_run.json
 """
@@ -22,18 +28,35 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from perfbench.lib.manifest import Manifest  # noqa: E402
+from perfbench.lib.manifest import Manifest, load_py  # noqa: E402
+
+PUBLISHED = os.path.join("tests", "perfbench", "published")
 
 
 def make_root(probe_file: str, out_dir: str, root: str = ROOT) -> dict:
     """Write `<out_dir>/BENCHMARK.json` and the files it names that
     `Manifest` looks for under its own root (configurations, the probe's
-    traffic); everything else is found in this package. Returns the
-    manifest written."""
+    traffic) and the manifest's tests beside it (the published rows);
+    everything else is found in this package. Returns the manifest
+    written."""
     with open(probe_file) as f:
         probe = json.load(f)
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    copied = [c["file"] for c in bench["configs"]]
+    brought = {}  # path under the root -> content
+    for c in probe.get("configs", []):
+        entry, file = c["entry"], c["entry"]["file"]
+        if entry["name"] in {e["name"] for e in bench["configs"]} or file in copied:
+            raise SystemExit(f"probe: configuration {entry['name']!r} or its file "
+                             f"{file!r} is taken: a probe appends, it replaces none")
+        if os.path.normpath(file) != file or not any(
+                file.startswith(p + "/") for p in bench["paths"]):
+            raise SystemExit(f"probe: configuration {entry['name']!r} keeps its file "
+                             f"at {file!r}, which lies under none of {bench['paths']}")
+        bench["configs"].append(entry)
+        brought[file] = c["file_body"]
+        brought[os.path.join(PUBLISHED, entry["name"] + ".json")] = c["published"]
     configs = {c["name"] for c in bench["configs"]}
     for cell in probe["workloads"]:
         if cell["config"] not in configs:
@@ -46,13 +69,17 @@ def make_root(probe_file: str, out_dir: str, root: str = ROOT) -> dict:
             raise SystemExit(f"probe: no metric {name!r} with a `workloads` list")
         metrics[name]["workloads"] = metrics[name]["workloads"] + cells
     bench["paths"] = ["perfbench"]
-    for c in bench["configs"]:
-        os.makedirs(os.path.dirname(os.path.join(out_dir, c["file"])), exist_ok=True)
-        shutil.copy(os.path.join(root, c["file"]), os.path.join(out_dir, c["file"]))
-    os.makedirs(os.path.join(out_dir, "perfbench", "traffic"), exist_ok=True)
+    for file in copied:
+        os.makedirs(os.path.dirname(os.path.join(out_dir, file)), exist_ok=True)
+        shutil.copy(os.path.join(root, file), os.path.join(out_dir, file))
+    shutil.copytree(os.path.join(root, PUBLISHED), os.path.join(out_dir, PUBLISHED),
+                    dirs_exist_ok=True)
     for name, traffic in probe["traffic"].items():
-        with open(os.path.join(out_dir, "perfbench", "traffic", name + ".json"), "w") as f:
-            json.dump(traffic, f, indent=2)
+        brought[os.path.join("perfbench", "traffic", name + ".json")] = traffic
+    for file, content in brought.items():
+        os.makedirs(os.path.dirname(os.path.join(out_dir, file)), exist_ok=True)
+        with open(os.path.join(out_dir, file), "w") as f:
+            json.dump(content, f, indent=2)
     with open(os.path.join(out_dir, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f, indent=1)
     return bench
@@ -106,5 +133,13 @@ if __name__ == "__main__":
               f"{len(made['workloads'])} cells")
     elif len(sys.argv) == 3 and sys.argv[1] == "read":
         print(json.dumps(read_run(sys.argv[2])))
+    elif len(sys.argv) == 3 and sys.argv[1] == "check":
+        failed = load_py(os.path.join(
+            ROOT, "tests", "perfbench", "test_perfbench_manifest.py")).check_root(sys.argv[2])
+        for check, said in failed:
+            print(f"probe: FAILED {check}: {said}")
+        if failed:
+            raise SystemExit(1)
+        print(f"probe: {sys.argv[2]} passes every check of test_perfbench_manifest.py")
     else:
         raise SystemExit(__doc__)

@@ -123,3 +123,44 @@ def test_reading_a_saturated_runs_record(tmp_path):
     assert got["first_token_wait_s_max"] == 3.0 and got["setup_s"] == 40.0
     assert got["decode_step_ms_p50"] == pytest.approx(6.0)
     assert got["serve_env"] == {QUEUE: "2048"} and got["memory_peak_GB"] == 13.5
+
+
+@pytest.fixture(scope="module")
+def bringing():
+    """A probe that brings a configuration (`test_perfbench_manifest.py` builds
+    it from the manifest's last one), and the cell it copies."""
+    rows = load_py(os.path.join(ROOT, "tests", "perfbench", "test_perfbench_manifest.py"))
+    return rows.a_further_configuration(Manifest(ROOT), rows.published_dir(ROOT))
+
+
+def test_a_probe_root_loads_the_configuration_it_brings(tmp_path, bringing):
+    spec, like = bringing
+    (tmp_path / "p.json").write_text(json.dumps(spec))
+    probe.make_root(str(tmp_path / "p.json"), str(tmp_path / "out"))
+    made, real = Manifest(str(tmp_path / "out")), Manifest(ROOT)
+    brought = spec["configs"][0]
+    assert made.data["configs"] == real.data["configs"] + [brought["entry"]]
+    assert made.load_config("brought-config") == brought["file_body"] == \
+        real.load_config(like["config"])
+    rows = tmp_path / "out" / "tests" / "perfbench" / "published"
+    assert sorted(os.listdir(rows)) == sorted(
+        os.listdir(os.path.join(ROOT, probe.PUBLISHED)) + ["brought-config.json"])
+    assert json.loads((rows / "brought-config.json").read_text()) == brought["published"]
+    assert made.cell("brought-cell")["config"] == "brought-config"
+    assert made.load_traffic("brought-traffic") == real.load_traffic(like["traffic"])
+
+
+@pytest.mark.parametrize("change,said", [
+    (lambda real: {"name": real["name"]}, "is taken"),
+    (lambda real: {"file": real["file"]}, "is taken"),
+    (lambda real: {"file": "perfbench/../brought.json"}, "lies under none of"),
+    (lambda real: {"file": "ray_tpu/brought.json"}, "lies under none of"),
+], ids=["name_taken", "file_taken", "file_leads_out", "file_outside_paths"])
+def test_a_brought_configuration_that_replaces_or_strays_is_refused(
+        tmp_path, bringing, change, said):
+    spec = json.loads(json.dumps(bringing[0]))
+    spec["configs"][0]["entry"].update(change(Manifest(ROOT).data["configs"][0]))
+    (tmp_path / "p.json").write_text(json.dumps(spec))
+    with pytest.raises(SystemExit, match=said):
+        probe.make_root(str(tmp_path / "p.json"), str(tmp_path / "out"))
+    assert not (tmp_path / "out").exists()
