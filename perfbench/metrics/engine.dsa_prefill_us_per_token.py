@@ -1,0 +1,13 @@
+"""What a prompt token costs the engine's driver in the Keye cell: the summed
+duration of the window's `engine.prefill_dispatch` spans / their summed
+`tokens` (the TRUE tokens of each pass, the program's own argument). The
+span covers the dispatch of the pass and of the write of its rows; the
+device's part shows where the device is the bound (the next step's wait)."""
+
+from perfbench.lib import keye_counts
+
+
+def read(run):
+    spans = keye_counts.prefill_dispatches(run)
+    tokens = sum(e["args"]["tokens"] for e in spans)
+    return sum(e["dur"] for e in spans) / tokens if tokens else None

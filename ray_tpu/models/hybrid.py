@@ -8,12 +8,16 @@ low-rank query (`rope_theta`, `q_lora_rank`: openPangu-Ultra-MoE's),
 Mamba-1's selective scan (`ops/mamba.py`), Mamba-2's SSD recurrence
 (`ops/ssd.py`: a scalar decay a head over a matrix state, one group, a gated
 RMSNorm over all channels behind it; Granite-4.0-H's), plain grouped-query
-attention without positions and EVA attention (`ops/eva.py`: the query's own
+attention without positions, EVA attention (`ops/eva.py`: the query's own
 window of `eva_window` positions exactly, beside one learned summary a chunk
-of `eva_chunk` positions of every earlier window; EvaByte's); the FFNs: dense
-SwiGLU and a dropless top-k expert layer with a shared expert
-(`ops/moe.py:dropless_moe`) that is told which experts it holds, routed by
-sigmoid scores + bias or by a softmax over the chosen logits (`router`).
+of `eva_chunk` positions of every earlier window; EvaByte's) and learned
+sparse attention (`ops/dsa.py`: rotary grouped-query attention with an
+RMSNorm a head on q and k, whose every query attends to the `dsa_topk` rows
+an indexer of `dsa_heads` small heads scores highest, exactly;
+Keye-VL-2.0's); the FFNs: dense SwiGLU and a dropless top-k expert layer
+(`ops/moe.py:dropless_moe`) that is told which experts it holds, beside a
+shared MLP or (`n_shared` 0) none, routed by sigmoid scores + bias or by a
+softmax over the chosen logits (`router`).
 
 A list-form configuration with `n_predict` 1 carries a multi-token
 prediction module (DeepSeek-V3's form): `h' = W_p [RMSNorm(h_i) ;
@@ -54,6 +58,11 @@ Two ways to hold and run the stack, by what the configuration lists:
   window w needs only the summaries the same layer left for the windows
   before w), so its activations are those of `eva_window` positions
   whatever the prompt's length.
+- `dsa_layers` (the Keye-VL-2.0 family: every mixer sparse attention over a
+  scanned expert layer without a shared MLP, an untied head,
+  `untied_head`): the runs form with ONE run. A prompt pass of more than
+  `dsa_topk` positions scores, selects and attends by two kernels that never
+  hold an [n, n] score in HBM; its prompts come in whole chunks.
 
 Three call modes over the same weights:
 
@@ -67,7 +76,7 @@ Three call modes over the same weights:
 `decode_step`   one token for every slot from the slots' state, greedy
                 sampling on device, state DONATED and rewritten in place.
 
-A slot's state is of three kinds. An attention keeps a row a position for
+A slot's state is of four kinds. An attention keeps a row a position for
 ever (K/V, or a latent row); a recurrent mixer keeps a state of fixed size
 (KDA's S [H, dk, dv], Mamba-1's [d_state, d_inner], 0.33 MB a layer at
 Jamba's widths, Mamba-2's [N, H P], a matrix a head, 4.19 MB a layer at
@@ -76,8 +85,12 @@ EVA keeps a table of two regions: the open window's K/V rows, which the
 slot REUSES every `eva_window` positions (row n % W; stale rows are masked
 by the length, never cleared), and a summary a closed chunk, a row every
 `eva_chunk` positions, of which a query sees those of closed windows only.
+Sparse attention keeps TWO rows a position for ever: the K/V block and,
+beside it, a key for the selector (the indexer) that decides which K/V
+blocks a later query reads: a decode step scores all n of a slot's indexer
+keys and reads `dsa_topk` of its n K/V blocks.
 
-`HybridCache` (list form), `RunsCache` and `EvaCache` (runs form) are this
+`HybridCache` (list form), `RunsCache`, `EvaCache` and `DsaCache` (runs form) are this
 family's implementations of the engine's per-slot state interface
 (`models/serving.py`, "the cache interface").
 """
@@ -95,7 +108,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.inference import _gqa_decode_attention
-from ray_tpu.ops import eva, kda, mamba, mla, ssd
+from ray_tpu.ops import dsa, eva, kda, mamba, mla, ssd
 from ray_tpu.ops.attention import attention, causal_attention_blocked
 from ray_tpu.ops.cache import write_rows
 from ray_tpu.ops.layers import rms_norm, swiglu
@@ -182,6 +195,18 @@ class HybridConfig:
     residual_scale: float = 1.0               # x += residual_scale * Mixer / FFN
     attn_scale: float = 0.0                   # softmax(attn_scale q . k); 0: hd^-1/2
     logit_divisor: float = 1.0                # logits = RMSNorm(x) E^T / logit_divisor
+    # sparse-attention mixers (the runs form, one run: every layer is
+    # listed): grouped-query attention (`n_heads`, `n_kv_heads`, `head_dim`,
+    # `rope_theta` over the whole head, an RMSNorm a head on q and k) whose
+    # queries attend to the `dsa_topk` rows an indexer of `dsa_heads` heads
+    # of `dsa_head_dim` lanes scores highest (`ops/dsa.py`); the prompt pass
+    # scores `dsa_chunk` queries at a time where no kernel runs
+    dsa_layers: Tuple[int, ...] = ()
+    dsa_topk: int = 2048
+    dsa_heads: int = 16
+    dsa_head_dim: int = 64
+    dsa_chunk: int = 512
+    untied_head: bool = False                 # the runs form: a head of its own
 
     @staticmethod
     def tiny_hybrid() -> "HybridConfig":
@@ -227,20 +252,32 @@ class HybridConfig:
                             n_heads=4, n_kv_heads=4, rope_theta=1e5,
                             n_pred_heads=2, norm_unit_offset=True)
 
+    @staticmethod
+    def tiny_dsa() -> "HybridConfig":
+        """Three sparse-attention layers (8 query heads on 2 kv heads, an
+        indexer of 4 heads of 8 lanes choosing 16 rows), every FFN 8 experts,
+        top-2 by logit, NO shared MLP; an untied head."""
+        return HybridConfig(vocab_size=96, n_layers=3, kda_layers=(), first_dense=0,
+                            dsa_layers=(1, 2, 3), dsa_topk=16, dsa_heads=4,
+                            dsa_head_dim=8, dsa_chunk=8, n_heads=8, n_kv_heads=2,
+                            rope_theta=1e4, n_shared=0, router="softmax",
+                            untied_head=True, norm_eps=1e-6)
+
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
         def mixer(i):
             return ("kda" if i in self.kda_layers else
                     "mamba" if i in self.mamba_layers else
                     "mamba2" if i in self.mamba2_layers else
                     "attn" if i in self.attn_layers else
-                    "eva" if i in self.eva_layers else "mla")
+                    "eva" if i in self.eva_layers else
+                    "dsa" if i in self.dsa_layers else "mla")
         return tuple((mixer(i), "dense" if i <= self.first_dense else "moe")
                      for i in range(1, self.n_layers + 1))
 
     @property
     def scanned(self) -> bool:
         return bool(self.mamba_layers or self.mamba2_layers or self.attn_layers
-                    or self.eva_layers)
+                    or self.eva_layers or self.dsa_layers)
 
     @property
     def ssd_inner(self) -> int:
@@ -266,12 +303,22 @@ class HybridConfig:
                     "query head, whole chunks a window; not "
                     f"{sorted(set(kinds))}, {self.n_heads}:{self.n_kv_heads} heads, "
                     f"{self.eva_window} / {self.eva_chunk}")
+        elif self.dsa_layers:
+            # one cache of `[k ; v]` blocks and indexer keys, one scan
+            if len(set(kinds)) != 1 or kinds[0][0] != "dsa" or not self.rope_theta \
+                    or self.n_heads % self.n_kv_heads:
+                raise ValueError(
+                    "sparse-attention (dsa) mixers make ONE run, every layer "
+                    "over the same kind of FFN, with rotary positions and whole "
+                    f"query groups; not {sorted(set(kinds))}, rope_theta "
+                    f"{self.rope_theta}, {self.n_heads}:{self.n_kv_heads} heads")
         elif any(m not in ("mamba", "mamba2", "attn") for m, _ in kinds) \
                 or self.ssd_heads % 2:
             raise ValueError(
                 "a stack of scanned runs holds Mamba-1, Mamba-2 (an even number "
                 "of heads, one group) and attention mixers, each over a dense "
-                f"FFN or an expert layer, not {sorted(set(kinds))} with "
+                "FFN or an expert layer, or ONE run of EVA or of "
+                f"sparse-attention (dsa) mixers, not {sorted(set(kinds))} with "
                 f"{self.ssd_heads} SSD heads")
         out: List[Tuple[str, str, int]] = []
         for kind in kinds:
@@ -290,8 +337,8 @@ class HybridConfig:
 
     def make_cache(self, num_slots: int, max_len: int):
         """This model's per-slot state for `ContinuousBatchingEngine`."""
-        kind = EvaCache if self.eva_layers else RunsCache if self.scanned \
-            else HybridCache
+        kind = EvaCache if self.eva_layers else DsaCache if self.dsa_layers \
+            else RunsCache if self.scanned else HybridCache
         return kind(self, num_slots, max_len)
 
 
@@ -358,8 +405,9 @@ def init_params(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
             p["moe"] = {
                 "router": w((d, cfg.n_experts), d),
                 "bias": uniform((cfg.n_experts,), -0.05, 0.05),
-                **swiglu_w(cfg.d_expert, (len(cfg.experts_held),)),
-                "shared": swiglu_w(cfg.d_expert * cfg.n_shared)}
+                **swiglu_w(cfg.d_expert, (len(cfg.experts_held),))}
+            if cfg.n_shared:
+                p["moe"]["shared"] = swiglu_w(cfg.d_expert * cfg.n_shared)
         return p
 
     layers = [layer(mixer, ffn) for mixer, ffn in cfg.layer_kinds()]
@@ -422,8 +470,9 @@ def _init_runs(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
                 raise ValueError("a scanned expert layer routes by a softmax "
                                  "over the chosen logits")
             p["moe"] = {"router": w((k, d, cfg.n_experts), d),
-                        **swiglu_w(cfg.d_expert, (k, len(cfg.experts_held))),
-                        "shared": swiglu_w(cfg.d_expert * cfg.n_shared, (k,))}
+                        **swiglu_w(cfg.d_expert, (k, len(cfg.experts_held)))}
+            if cfg.n_shared:
+                p["moe"]["shared"] = swiglu_w(cfg.d_expert * cfg.n_shared, (k,))
         if mixer == "mamba2":
             H, ch = cfg.ssd_heads, cfg.ssd_inner + 2 * cfg.ssd_state
             step = jnp.exp(jax.random.uniform(key(), (k, H), F32,
@@ -461,6 +510,15 @@ def _init_runs(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
             H, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
             p[mixer] = {"wq": w((k, d, H * hd), d), "wk": w((k, d, kvh * hd), d),
                         "wv": w((k, d, kvh * hd), d), "wo": w((k, H * hd, d), H * hd)}
+            if mixer == "dsa":
+                J, di = cfg.dsa_heads, cfg.dsa_head_dim
+                around = lambda shape, mid: (mid + 0.1 * jax.random.normal(
+                    key(), shape, F32)).astype(dt)
+                p["dsa"].update({
+                    "q_norm": around((k, hd), 1.0), "k_norm": around((k, hd), 1.0),
+                    "w_qi": w((k, d, J * di), d), "w_ki": w((k, d, di), d),
+                    "ki_norm": around((k, di), 1.0), "ki_bias": around((k, di), 0.0),
+                    "w_wi": w((k, d, J), d)})
             if mixer == "eva":
                 p["eva"]["phi"] = jax.random.uniform(key(), (k, H, hd), F32, -1.0, 1.0)
                 p["eva"]["mu"] = jax.random.uniform(key(), (k, H, hd), F32, -0.5, 0.5)
@@ -468,7 +526,7 @@ def _init_runs(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
     params = {"embed": (jax.random.normal(key(), (cfg.vocab_size, d), F32)
                         * 0.02).astype(dt),
               "final_norm": norm((d,)), "runs": runs}
-    if cfg.eva_layers:
+    if cfg.eva_layers or cfg.untied_head:
         params["lm_head"] = (jax.random.normal(
             key(), (d, cfg.n_pred_heads * cfg.vocab_size), F32) * 0.02).astype(dt)
     return params
@@ -559,9 +617,10 @@ def _ffn(cfg: HybridConfig, p, h32, h, valid, stacks=None, layer=None):
         y, landed, touched = dropless_moe(
             h, idx, w, *(stacks or (m["w_gate"], m["w_up"], m["w_down"])),
             cfg.experts_held, cfg.n_experts, valid, layer)
-    with jax.named_scope("shared_expert"):
-        s = m["shared"]
-        y = y + swiglu(h @ s["w_gate"], h @ s["w_up"]) @ s["w_down"]
+    if "shared" in m:
+        with jax.named_scope("shared_expert"):
+            s = m["shared"]
+            y = y + swiglu(h @ s["w_gate"], h @ s["w_up"]) @ s["w_down"]
     return y, landed, touched, idx
 
 
@@ -883,6 +942,21 @@ def _attn_qkv(cfg: HybridConfig, a, h):
             (h @ a["wv"]).reshape(lead + (cfg.n_kv_heads, hd)))
 
 
+def _causal_gqa(cfg: HybridConfig, q, k, v):
+    """Causal grouped-query attention over EVERY row of a whole prompt:
+    q [b, s, H, hd], k, v [b, s, kvh, hd] -> [b, s, H, hd]."""
+    s, scale = q.shape[1], cfg.attn_scale or cfg.head_dim ** -0.5
+    if s % 1024 == 0:
+        # whole blocks of the flash kernel (a prompt of thousands of
+        # positions: the blocked form's scores alone are 0.8 GB at
+        # 32 heads x 512 x 12288)
+        return jnp.moveaxis(attention(
+            *(jnp.moveaxis(t, 1, 2) for t in (q, k, v)), sm_scale=scale), 1, 2)
+    rep = cfg.n_heads // cfg.n_kv_heads
+    return causal_attention_blocked(q, jnp.repeat(k, rep, axis=2),
+                                    jnp.repeat(v, rep, axis=2), sm_scale=scale)
+
+
 def _eva_qkv(cfg: HybridConfig, a, h, positions):
     """h [..., s, d] at positions [..., s] -> q, k, v [..., s, H, hd] in the
     configuration's type, q and k rotated over the whole head width."""
@@ -896,6 +970,39 @@ def _eva_qkv(cfg: HybridConfig, a, h, positions):
     return (mla.rotate(q, positions, cfg.rope_theta),
             mla.rotate(k, positions, cfg.rope_theta).astype(cfg.dtype),
             v.astype(cfg.dtype))
+
+
+def _dsa_inputs(cfg: HybridConfig, a, h, positions):
+    """h [..., s, d] at positions [..., s] -> q [..., s, H, hd], k, v
+    [..., s, kvh, hd] (q and k through an RMSNorm a head, then rotated over
+    the whole head) and the indexer's qi [..., s, J, di] (rotated), wi
+    [..., s, J] float32 and ki [..., s, key_width]: ONE key a position
+    through a LayerNorm, rotated, zero lanes behind its di."""
+    lead, hd, eps = h.shape[:-1], cfg.head_dim, cfg.norm_eps
+    J, di, theta = cfg.dsa_heads, cfg.dsa_head_dim, cfg.rope_theta
+    # flat products that read their weight in place (`_eva_qkv` says why)
+    q, k, v, qi, ki = jax.lax.optimization_barrier(
+        tuple(h @ a[n] for n in ("wq", "wk", "wv", "w_qi", "w_ki")))
+    q = mla.rotate(rms_norm(q.reshape(lead + (cfg.n_heads, hd)), a["q_norm"], eps),
+                   positions, theta)
+    k = mla.rotate(rms_norm(k.reshape(lead + (cfg.n_kv_heads, hd)), a["k_norm"], eps),
+                   positions, theta)
+    with jax.named_scope("index"):
+        qi = mla.rotate(qi.reshape(lead + (J, di)), positions, theta)
+        kf = ki.astype(F32)
+        kf = kf - jnp.mean(kf, axis=-1, keepdims=True)
+        kf = kf * jax.lax.rsqrt(jnp.mean(kf * kf, axis=-1, keepdims=True) + eps)
+        kf = kf * a["ki_norm"].astype(F32) + a["ki_bias"].astype(F32)
+        ki = mla.rotate(kf.astype(cfg.dtype)[..., None, :], positions, theta)[..., 0, :]
+        ki = jnp.pad(ki, [(0, 0)] * len(lead) + [(0, dsa.key_width(di) - di)])
+        # float32 sums: the weights scale scores whose order is the choice
+        wi = jnp.dot(h, a["w_wi"], preferred_element_type=F32)
+    return q, k, v.reshape(lead + (cfg.n_kv_heads, hd)), qi, wi, ki
+
+
+def _kv_row(k, v):
+    """k, v [..., kvh, hd] -> the position's cache block [..., 2 kvh, hd]."""
+    return jnp.concatenate([k, v], axis=-2)
 
 
 # ---------------------------------------------------------------- sequence
@@ -969,6 +1076,8 @@ def _sequence_runs(params, tokens, true_len, cfg: HybridConfig):
     layers [k, b, s, top_k]."""
     if cfg.eva_layers:
         return _sequence_eva(params, tokens, true_len, cfg)
+    if cfg.dsa_layers:
+        return _sequence_dsa(params, tokens, true_len, cfg)
     b, s = tokens.shape
     H, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     x = params["embed"][tokens].astype(F32)
@@ -994,17 +1103,7 @@ def _sequence_runs(params, tokens, true_len, cfg: HybridConfig):
             with jax.named_scope("attention"):
                 _, h = _normed(cfg, x, lp["mixer_norm"])
                 q, k, v = _attn_qkv(cfg, lp["attn"], h)
-                scale = cfg.attn_scale or hd ** -0.5
-                if s % 1024 == 0:
-                    # whole blocks of the flash kernel (a prompt of thousands of
-                    # positions: the blocked form's scores alone are 0.8 GB at
-                    # 32 heads x 512 x 12288)
-                    attn = jnp.moveaxis(attention(
-                        *(jnp.moveaxis(t, 1, 2) for t in (q, k, v)), sm_scale=scale), 1, 2)
-                else:
-                    attn = causal_attention_blocked(
-                        q, jnp.repeat(k, H // kvh, axis=2),
-                        jnp.repeat(v, H // kvh, axis=2), sm_scale=scale)
+                attn = _causal_gqa(cfg, q, k, v)
                 x = _add(cfg, x, attn.reshape(b, s, H * hd) @ lp["attn"]["wo"])
             x, routed = _run_ffn(cfg, lp, x, valid, stacks, i)
             return x, (jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2)) + routed
@@ -1028,6 +1127,50 @@ def _sequence_runs(params, tokens, true_len, cfg: HybridConfig):
         rows[name] = jnp.concatenate(rows[name]) if rows[name] else \
             jnp.zeros((0, b, kvh, s, hd), cfg.dtype)
     return x, rows, routing
+
+
+def _sequence_dsa(params, tokens, true_len, cfg: HybridConfig,
+                  with_rows: bool = False):
+    """`_sequence` for a stack of sparse-attention layers: ONE `lax.scan`
+    over the stacked weights. A prompt of more than `dsa_topk` positions
+    scores, selects and attends (`ops.dsa.prompt_attention`); a shorter one
+    has every causal row chosen and runs plain causal attention. State rows:
+    "kv" [layers, b, s, 2 kvh, hd], a position's `[k ; v]`, and "ik"
+    [layers, b, 1, s, key_width], its indexer key; with `with_rows` also
+    "chosen" [layers, b, ceil(s / 32), s] int32, the rows every query chose
+    (`ops.dsa.pack_rows`; absent where every row is chosen)."""
+    b, s = tokens.shape
+    H, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    x = params["embed"][tokens].astype(F32)
+    valid = jnp.arange(s)[None, :] < true_len[:, None]               # [b, s]
+    positions = jnp.arange(s)
+    scale = cfg.attn_scale or hd ** -0.5
+    sparse = s > cfg.dsa_topk
+    rp, stacks = _expert_stacks(params["runs"][0])
+
+    def layer(x, xs):
+        lp, i = xs
+        with jax.named_scope("dsa"):
+            _, h = _normed(cfg, x, lp["mixer_norm"])
+            a = lp["dsa"]
+            q, k, v, qi, wi, ki = _dsa_inputs(cfg, a, h, positions)
+            chosen = None
+            if sparse:
+                attn, chosen = dsa.prompt_attention(
+                    q, k, v, qi, wi, ki, cfg.dsa_topk, cfg.dsa_chunk, scale, with_rows)
+            else:
+                with jax.named_scope("attend"):
+                    attn = _causal_gqa(cfg, q, k, v)
+            x = _add(cfg, x, attn.reshape(b, s, H * hd) @ a["wo"])
+        x, routed = _run_ffn(cfg, lp, x, valid, stacks, i if stacks else None)
+        return x, (_kv_row(k, v), ki[:, None]) + routed \
+            + (() if chosen is None else (chosen,))
+
+    x, ys = jax.lax.scan(layer, x, (rp, jnp.arange(cfg.n_layers)))
+    rows = {"kv": ys[0], "ik": ys[1]}
+    if sparse and with_rows:
+        rows["chosen"] = ys[-1]
+    return x, rows, [ys[3]] if stacks else []
 
 
 def _sequence_eva(params, tokens, true_len, cfg: HybridConfig,
@@ -1181,6 +1324,9 @@ def prefill(params, tokens, true_len, cfg: HybridConfig,
     if cfg.eva_layers:
         x, rows, routing = _sequence_eva(params, tokens, true_len, cfg, last_only=True)
         last = x[:, 0]
+    elif cfg.dsa_layers:
+        x, rows, routing = _sequence_dsa(params, tokens, true_len, cfg, with_routing)
+        last = pick(x)
     else:
         x, rows, routing = _sequence(params, tokens, true_len, cfg)
         last = pick(x)
@@ -1468,6 +1614,57 @@ def _decode_eva(params, state, lengths, tokens, cfg: HybridConfig,
             closed.astype(jnp.int32))
 
 
+def _decode_dsa(params, state, lengths, tokens, cfg: HybridConfig,
+                attn_len: int, with_rows: bool = False):
+    """`_decode_runs` for a stack of sparse-attention layers -> (state,
+    logits [B, vocab] float32, [assignments landed, experts touched] or
+    None, the experts chosen [[layers, B, top_k]] or [], and with
+    `with_rows` the lists (rows [layers, B, K], count [layers, B], own
+    [layers, B]) of `ops.dsa.decode_select`). A busy slot's token stands at
+    position n = its length. Inside the scan both tables are read-only:
+    each layer scores the slot's n live indexer keys, lists the best
+    `dsa_topk` of them (all n below that) and attends to the listed `[k ; v]`
+    positions alone, its own row a term of its own where its score belongs to the
+    best. Then every layer's row and key are written once, at row n. A slot
+    of length 0 is idle: it scores and reads nothing."""
+    B = tokens.shape[0]
+    H, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    x = params["embed"][tokens].astype(F32)
+    kv_all, ik_all = state["kv"], state["ik"]
+    busy = lengths > 0
+    scale = cfg.attn_scale or hd ** -0.5
+    rp, stacks = _expert_stacks(params["runs"][0])
+
+    def layer(x, xs):
+        lp, i = xs
+        with jax.named_scope("dsa"):
+            _, h = _normed(cfg, x, lp["mixer_norm"])
+            a = lp["dsa"]
+            q, k, v, qi, wi, ki = (t[:, 0] for t in _dsa_inputs(
+                cfg, a, h[:, None], lengths[:, None]))
+            with jax.named_scope("select"):
+                rows, count, own = dsa.decode_select(
+                    qi, wi, ki, ik_all, i, lengths, attn_len, cfg.dsa_topk)
+            with jax.named_scope("attend"):
+                attn = dsa.decode_attention(
+                    q.reshape(B, kvh, H // kvh, hd), k, v, kv_all, i, rows, count,
+                    own, scale)
+            x = _add(cfg, x, attn.reshape(B, H * hd).astype(cfg.dtype) @ a["wo"])
+        x, routed = _run_ffn(cfg, lp, x, busy, stacks, i if stacks else None)
+        return x, (_kv_row(k, v), ki[:, None]) + routed \
+            + ((rows, count, own) if with_rows else ())
+
+    x, ys = jax.lax.scan(layer, x, (rp, jnp.arange(cfg.n_layers)))
+    with jax.named_scope("state_write"):
+        kv_all = dsa.write_positions(kv_all, ys[0], lengths)
+        ik_all = write_rows(ik_all, ys[1], lengths)
+    with jax.named_scope("head"):
+        logits = _head(params, _normed(cfg, x, params["final_norm"])[1], cfg)
+    return ({"kv": kv_all, "ik": ik_all}, logits,
+            jnp.sum(ys[2], axis=0) if stacks else None,
+            [ys[3]] if stacks else [], ys[-3:] if with_rows else None)
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
                    donate_argnums=(1,))
 @_layered_program
@@ -1480,6 +1677,12 @@ def decode_logits(params, state, lengths, tokens, active, cfg: HybridConfig,
     routing ([0, B, 0]) unless it holds expert layers, and takes no `active`
     (a slot is busy iff its length is above 0). A configuration that drafts
     is checked through `verify_logits`."""
+    if cfg.dsa_layers:
+        state, logits, _, routing, lists = _decode_dsa(
+            params, state, lengths, tokens, cfg, attn_len, with_rows=True)
+        routing = jnp.concatenate(routing) if routing else \
+            jnp.zeros((0, tokens.shape[0], 0), jnp.int32)
+        return state, logits, routing, lists
     if cfg.scanned:
         if cfg.eva_layers:
             state, logits, _ = _decode_eva(params, state, lengths, tokens, cfg,
@@ -1546,8 +1749,8 @@ def decode_step(params, state, lengths, tokens, active, cfg: HybridConfig,
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return state, lengths + (lengths > 0), nxt, jnp.concatenate([nxt, closed])
     if cfg.scanned:
-        state, logits, counters, _ = _decode_runs(params, state, lengths, tokens,
-                                                  cfg, attn_len)
+        state, logits, counters, *_ = (_decode_dsa if cfg.dsa_layers else _decode_runs)(
+            params, state, lengths, tokens, cfg, attn_len)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         report = nxt if counters is None else jnp.concatenate([nxt, counters])
         return state, lengths + (lengths > 0), nxt, report
@@ -1586,6 +1789,8 @@ def _write_state(state, lengths, tokens, slots, rows, true_len, first):
     slot's recurrent state is REPLACED, which is its reset). `slots`
     entries equal to the number of slots are batch padding and are
     dropped. `tokens` is not donated (the step in flight reads it)."""
+    if "ik" in state:
+        return _write_dsa(state, lengths, tokens, slots, rows, true_len, first)
     if "ssm" in state:
         return _write_runs(state, lengths, tokens, slots, rows, true_len, first)
     if "ek" in state:
@@ -1612,6 +1817,19 @@ def _write_runs(state, lengths, tokens, slots, rows, true_len, first):
         state = {"ssm": [put(a, r) for a, r in zip(state["ssm"], rows["ssm"])],
                  "conv": [put(a, r) for a, r in zip(state["conv"], rows["conv"])],
                  "k": kv(state["k"], rows["k"]), "v": kv(state["v"], rows["v"])}
+    return (state, lengths.at[slots].set(true_len, mode="drop"),
+            tokens.at[slots].set(first, mode="drop"))
+
+
+def _write_dsa(state, lengths, tokens, slots, rows, true_len, first):
+    """`_write_state` for a stack of sparse-attention layers: the prompt's
+    `[k ; v]` blocks and indexer keys over the first rows of the slots'
+    tables. What the last occupant left behind them stays, masked by the
+    length: a key is scored only below it."""
+    with jax.named_scope("state_write"):
+        kv, ik = rows["kv"], rows["ik"]
+        state = {"kv": state["kv"].at[:, slots, :kv.shape[2]].set(kv, mode="drop"),
+                 "ik": state["ik"].at[:, slots, :, :ik.shape[3]].set(ik, mode="drop")}
     return (state, lengths.at[slots].set(true_len, mode="drop"),
             tokens.at[slots].set(first, mode="drop"))
 
@@ -1793,3 +2011,46 @@ class EvaCache(RunsCache):
         W, C = self.cfg.eva_window, self.cfg.eva_chunk
         return {"window_rows": sum(n % W for n in positions),
                 "summary_rows": sum(n // W * (W // C) for n in positions)}
+
+
+class DsaCache(RunsCache):
+    """Per-slot state of a stack of sparse-attention layers, the fourth
+    kind: TWO rows a position for ever, one for the attention and one for
+    the selector that decides which of the first are read. "kv"
+    [layers, slots, max_len, 2 kvh, hd] holds a position's keys and values
+    as ONE block `[k ; v]` (at 4 kv heads of 128 an (8, 128) tile of bf16),
+    so that a chosen position is one contiguous read a DMA can name; "ik"
+    [layers, slots, 1, max_len, key_width] its indexer key in whole tiles
+    of lanes. A decode step scores a slot's live keys (all n of them) and
+    reads `dsa_topk` of its n K/V positions. Written at position n by
+    `ops.dsa.write_positions` and `ops.cache.write_rows`, read by
+    `ops.dsa.decode_select` / `decode_attention`. Prompts come in whole
+    chunks (`prompt_bucket`)."""
+
+    def __init__(self, cfg: HybridConfig, num_slots: int, max_len: int):
+        self.cfg, self.num_slots, self.max_len = cfg, num_slots, max_len
+        cfg.runs()    # one run of sparse-attention layers, or an error
+        L = cfg.n_layers
+        kv = (L, num_slots, max_len, 2 * cfg.n_kv_heads, cfg.head_dim)
+        ik = (L, num_slots, 1, max_len, dsa.key_width(cfg.dsa_head_dim))
+        self.state = {"kv": jnp.zeros(kv, cfg.dtype), "ik": jnp.zeros(ik, cfg.dtype)}
+        self.prefill_args = {"dsa_layers": L}
+        self.counters: Tuple[str, ...] = ("expert_assignments", "experts_touched") \
+            if "moe" in cfg.run_ffns() else ()
+
+    def prompt_bucket(self, n: int) -> int:
+        """Powers of two up to eight chunks of `dsa_chunk` queries, whole
+        multiples of eight chunks past them (4096 positions at the published
+        512), up to the slot's whole length."""
+        unit, b = 8 * self.cfg.dsa_chunk, 8
+        while b < min(n, unit):
+            b *= 2
+        return min(b if n <= unit else -(-n // unit) * unit, self.max_len)
+
+    def step_args(self, positions: List[int], attn_len: int) -> Dict[str, int]:
+        """`kv_rows`: the positions the busy slots hold, what a step that
+        read every row would read; `index_rows`: the indexer keys the step
+        scores, a layer (the same positions); `selected_rows`: the K/V rows
+        it reads, a layer: min(n, `dsa_topk`) a slot."""
+        return {"kv_rows": sum(positions), "index_rows": sum(positions),
+                "selected_rows": sum(min(n, self.cfg.dsa_topk) for n in positions)}

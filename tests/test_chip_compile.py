@@ -765,6 +765,90 @@ def test_eva_prompt_pass_fits_beside_the_tables(chip):
     assert _whole_cache_relayouts(w, state["ek"]) == []
 
 
+def _keye(chip):
+    """(cfg, parameter shapes, slot-state shapes, slots) of the Keye cell."""
+    import json
+    import sys
+
+    from ray_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from perfbench.lib import keye_model
+
+    with open(os.path.join(root, "perfbench", "configs",
+                           "keye-vl-2.0-30b-a3b.1of8.json")) as f:
+        conf = json.load(f)
+    cfg = keye_model.model_config(conf)
+    slots, max_len = conf["run"]["num_slots"], conf["run"]["max_len"]
+    as_shapes = lambda tree: jax.tree_util.tree_map(
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = as_shapes(jax.eval_shape(lambda k: hybrid.init_params(k, cfg),
+                                      jax.random.PRNGKey(0)))
+    state = as_shapes(jax.eval_shape(lambda: cfg.make_cache(slots, max_len).state))
+    return cfg, params, state, slots
+
+
+@pytest.mark.parametrize("attn_len", [16384, 32768])
+def test_keye_decode_step_reads_indexer_keys_and_listed_rows(chip, attn_len):
+    """The sparse-attention stack at the benchmark cell's real shapes (six
+    layers of Keye-VL-2.0's decoder, all 128 experts, 8 slots x 32768): a
+    layer's attention is TWO kernels and a sort, `dsa_scores` over the busy
+    slots' live indexer keys and `dsa_rows` over each busy slot's list of
+    2,048 positions, a DMA a position: no instruction of the step reads a
+    layer's K/V cache whole (no copy, no slice, no gather of its size; the
+    two tables, 3.62 GB, are aliased and stay put), and what the step holds
+    beside them is a few MB."""
+    from ray_tpu.models import hybrid
+
+    cfg, params, state, slots = _keye(chip)
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(params))
+    assert 8.74e9 < weights < 8.76e9                   # 4,374.6M parameters, bf16
+    state_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(state))
+    assert state_bytes == 8 * 32768 * 13824            # 3.62 GB
+    ints = chip((slots,), jnp.int32)
+    c = hybrid.decode_step.lower(params, state, ints, ints,
+                                 chip((slots,), jnp.bool_), cfg, attn_len).compile()
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < 32 * 2**20
+    text = c.as_text()
+    assert "dsa_scores" in text and "dsa_rows" in text and "ragged" in text
+    assert "write_rows" in text                        # the indexer keys' row
+    assert f"f32[{slots},{attn_len}]" in text and " sort(" in text   # the exact top-k
+    for table in state.values():
+        assert _whole_cache_relayouts(c, table) == []
+    # nothing of the shape of ONE layer's K/V table, or of its window, is
+    # made, read out or gathered
+    made = {dims for _, dims, _ in _INSTRUCTION.findall(text)}
+    for rows in {attn_len, 32768}:
+        assert not {f"{slots},{rows},8,128", f"1,{slots},{rows},8,128"} & made
+
+
+def test_keye_prompt_pass_fits_beside_weights_and_slots(chip):
+    """The longest prompt bucket, 1 x 32768: Mosaic takes `dsa_select` (a
+    scratch of 16 MB of keys, the block's whole causal score row) and
+    `dsa_attention` at the published widths; no [n, n] array exists outside
+    them; what the pass needs beside 8.75 GB of weights and 3.62 GB of slots
+    stays under 2 GB. With the chosen rows returned (the comparison's
+    program) the words are n x n / 32, 0.8 GB over six layers."""
+    from ray_tpu.models import hybrid
+
+    cfg, params, _, _ = _keye(chip)
+    n = 32768
+    c = hybrid._prefill_first.lower(params, chip((1, n), jnp.int32),
+                                    chip((1,), jnp.int32), cfg).compile()
+    text = c.as_text()
+    assert "dsa_select" in text and "dsa_attention" in text and "flash" not in text
+    assert c.memory_analysis().temp_size_in_bytes < 2.0e9
+    assert not re.search(rf"\[(\d+,)*{n},{n}\]", text)
+    rows = hybrid.prefill.lower(params, chip((1, n), jnp.int32), chip((1,), jnp.int32),
+                                cfg, with_routing=True).compile()
+    assert f"s32[6,1,{n // 32},{n}]" in rows.as_text()
+    assert rows.memory_analysis().temp_size_in_bytes < 2.1e9
+
+
 def test_prefill_slots_compiles_at_b1(chip):
     from ray_tpu.models.serving import prefill_slots
 
