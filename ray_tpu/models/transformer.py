@@ -344,6 +344,15 @@ def ring_products_own_first(cfg: ModelConfig, mesh, batch: int, seq: int) -> int
     return 0 if _rows_mesh(cfg, mesh, batch, seq) is None else 1
 
 
+def dw_rings_ordered(cfg: ModelConfig, mesh, batch: int, seq: int) -> int:
+    """1 where a layer's weight-gradient rings are taken off the `fsdp` link
+    in the order of their starts, each ring's kept product between
+    (`fsdp.RingOrder`, handed from product to product): where `_rows_mesh`
+    says the products are parallel/tp.py's, else 0 (the train step's
+    `xla.compile` spans carry it)."""
+    return 0 if _rows_mesh(cfg, mesh, batch, seq) is None else 1
+
+
 def grad_exchanges_per_layer(cfg: ModelConfig, mesh, batch: int) -> int:
     """How many of a layer's weight gradients this program exchanges over
     `fsdp` itself: 7 for the dense block on a mesh with fsdp > 1, else 0
@@ -450,7 +459,9 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
                       if k in _NORM_SCALES else v for k, v in layers.items()}
 
             def layer(x, lp, cos, sin):
-                lp = {k: fsdp.ExchangedWeight(v, dims[k], mesh) if k in dims
+                # (one a trace of the layer: its products hand it on)
+                order = None if rows_mesh is None else fsdp.RingOrder()
+                lp = {k: fsdp.ExchangedWeight(v, dims[k], mesh, order) if k in dims
                       else fsdp.UnreducedScale(v, mesh, seq_axis)
                       if k in _NORM_SCALES else v for k, v in lp.items()}
                 return _layer(cfg, mesh, x, lp, cos, sin)

@@ -28,6 +28,15 @@ gradient's dtype over the same ranks as the partitioner's. A product's sum
 over a dimension that `fsdp` shards is taken whole in float32 and rounded
 once, as the product by the gathered weight is (`ring_products`).
 
+A layer's seven rings share ONE link in one direction, and the link serves
+them in the order of their starts. The order rule, where the products are
+parallel/tp.py's: the rings are taken off the link in the order they were
+put on it, the backward's own (`w_down`, gate, up, `wo`, `wq`, `wk`, `wv`),
+and each ring's kept product stands between its arrival and the next
+ring's (`RingOrder`, `_reduce_scatter_dws`): the scheduler, which prices
+each permute as if it had the link to itself, took the smallest first and
+the step waited at 2 MB for the 58 MB started before it.
+
 The two norm scales of a layer are replicated leaves, and each rank makes a
 partial `dw` of its own rows: left to the partitioner that is one more
 all-reduce in the backward's scan body, of 8 KB and on the compute stream,
@@ -66,13 +75,26 @@ def sharded_dim(spec: P):
     return next((i for i, a in enumerate(spec) if a == AXIS), None)
 
 
+class RingOrder:
+    """One layer's weight-gradient rings in the order the one `fsdp` link
+    carries them: `taken` is 0.0 in float32, read off the last sum a ring
+    of the backward made (`weight_grads`). Every product of the layer is
+    handed it and hands it on (parallel/tp.py), so in the backward it comes
+    back from product to product, last product first, as a cotangent: the
+    only way from one `custom_vjp`'s backward body into the next one's."""
+
+    def __init__(self):
+        self.taken = jnp.zeros((), jnp.float32)
+
+
 class ExchangedWeight:
     """Stands where a weight [k, n] stands in `x @ w` (x [batch, ..., k],
     batch split over (dp, fsdp)); `dim` is the weight's dimension that is
-    sharded over `fsdp`."""
+    sharded over `fsdp`; `order` is the layer's `RingOrder` where its
+    products are parallel/tp.py's."""
 
-    def __init__(self, w: jax.Array, dim: int, mesh):
-        self.w, self.dim, self.mesh = w, dim, mesh
+    def __init__(self, w: jax.Array, dim: int, mesh, order: RingOrder = None):
+        self.w, self.dim, self.mesh, self.order = w, dim, mesh, order
 
     def __rmatmul__(self, x: jax.Array) -> jax.Array:
         return _matmul(x, self.w, self.dim, self.mesh)
@@ -131,14 +153,24 @@ def _matmul_bwd(dim, mesh, res, dy):
 def weight_grad(x: jax.Array, dy: jax.Array, dim: int, mesh) -> jax.Array:
     """dw [k, n] of `x @ w` (x [batch, ..., k], dy [batch, ..., n], batch
     split over (dp, fsdp)), summed over `fsdp` by the ring below and left
-    sharded over it on `dim`. Also called from inside `parallel/tp.py`'s
-    products (`_region`)."""
-    dw = _region(
-        lambda x, dy, r: _reduce_scatter_dw(x[:, 0], dy[:, 0], dim, r[0]),
-        mesh, (P(None, AXIS), P(None, AXIS), P(AXIS)),
-        P(*[None] * (1 + dim), AXIS))(
-            _by_rank(x, mesh), _by_rank(dy, mesh), jnp.arange(axis_size(mesh)))
-    return dw.sum(0)
+    sharded over it on `dim`."""
+    return weight_grads([x], [dy], dim, mesh)[0][0]
+
+
+def weight_grads(xs, dys, dim: int, mesh, taken=None):
+    """`weight_grad` of each pair of `xs` and `dys`, one region for all of
+    them, and with `taken` (a `RingOrder`'s, as the backward body was handed
+    it) what it is after the last of them: `_reduce_scatter_dws`. Called
+    from inside parallel/tp.py's products (`_region`)."""
+    rows, whole = [P(None, AXIS)] * len(xs), None if taken is None else P()
+    dws, taken = _region(
+        lambda xs, dys, r, taken: _reduce_scatter_dws(
+            [x[:, 0] for x in xs], [dy[:, 0] for dy in dys], dim, r[0], taken),
+        mesh, (rows, rows, P(AXIS), whole),
+        ([P(*[None] * (1 + dim), AXIS)] * len(xs), whole))(
+            [_by_rank(x, mesh) for x in xs], [_by_rank(dy, mesh) for dy in dys],
+            jnp.arange(axis_size(mesh)), taken)
+    return [dw.sum(0) for dw in dws], taken
 
 
 _matmul.defvjp(_matmul_fwd, _matmul_bwd)
@@ -249,27 +281,63 @@ def _dot(x: jax.Array, w: jax.Array, summed: int, dtype=None) -> jax.Array:
                                preferred_element_type=dtype)
 
 
-def _reduce_scatter_dw(x: jax.Array, dy: jax.Array, dim: int, r) -> jax.Array:
-    """Sum over `fsdp` of dw = x^T dy (x [dp, rows, k], dy [dp, rows, n],
-    this rank's rows), rank r keeping chunk r of dw's dimension `dim`: a
-    ring of n - 1 steps. In step t rank r hands the running sum of chunk
-    r - t - 1 to rank r + 1 and adds its own part of the chunk that arrives;
-    at n = 2 that is one exchange of the partner's half. A chunk of dw is
-    the product of a slice of x (or of dy), so no whole dw is made and cut:
-    the compiler sends the first product off, and fuses the sum (and the
-    write into the scan's stacked gradient) into the product of the chunk it
-    keeps. (A whole dw cut in two read 374 ms a step, and a sum kept out of
-    the product 358, against 338 this way.)"""
+def _reduce_scatter_dws(xs, dys, dim: int, r, taken=None):
+    """For each pair: the sum over `fsdp` of dw = x^T dy (x [dp, rows, k],
+    dy [dp, rows, n], this rank's rows), rank r keeping chunk r of dw's
+    dimension `dim`: a ring of n - 1 steps. In step t rank r hands the
+    running sum of chunk r - t - 1 to rank r + 1 and adds its own part of
+    the chunk that arrives; at n = 2 that is one exchange of the partner's
+    half. A chunk of dw is the product of a slice of x (or of dy), so no
+    whole dw is made and cut: the compiler sends the first product off, and
+    fuses the sum (and the write into the scan's stacked gradient) into the
+    product of the chunk it keeps. (A whole dw cut in two read 374 ms a
+    step, and a sum kept out of the product 358, against 338 this way.)
+
+    With `taken`, the rings are taken off the link in the order they are
+    listed, here and from one backward body of the layer to the next
+    (`RingOrder`): a ring's kept product reads its slice at an offset that
+    adds `taken`, and `taken` is read off the sum the ring before made
+    (`_zero_read_off`). It is always 0, so no product, sum, rounding or byte
+    sent changes; but the compiler cannot know, so the kept product stands
+    behind the one before it, and each ring's arrival is taken straight
+    before its own kept product, with the earlier rings' products between
+    its start and there. Left alone, the scheduler takes the SMALLEST
+    transfer's arrival first and the largest's last (it gives each permute
+    the time IT needs, as if it had the link to itself), and all of a
+    layer's rings share one link in one direction, which serves them in the
+    order of their starts: the 2 MB of `wk` waited behind the 58 MB of
+    `w_gate` and `w_up`, started before it and taken after it, with 1 ms of
+    kept products that needed neither standing behind the wait (5 ms of a
+    308 ms step at Mistral-7B widths on fsdp 2 x tp 2; PERF.md section 6,
+    PR 57). An `optimization_barrier` does not do: it steers what fuses
+    and is gone before the scheduler runs."""
     n = jax.lax.axis_size(AXIS)
-    cut = (x, dy)[dim]
-    size = cut.shape[-1] // n
     ring = _ring(n)
 
-    def chunk(j):
-        part = jax.lax.dynamic_slice_in_dim(cut, (j % n) * size, size, 2)
+    def chunk(x, dy, j, behind=None):
+        cut = (x, dy)[dim]
+        size = cut.shape[-1] // n
+        at = (j % n) * size
+        if behind is not None:
+            at = at + behind.astype(at.dtype)
+        part = jax.lax.dynamic_slice_in_dim(cut, at, size, 2)
         return jnp.einsum("prk,prn->pkn", *((part, dy), (x, part))[dim])
 
-    acc = chunk(r - 1)
-    for t in range(n - 1):
-        acc = jax.lax.ppermute(acc, AXIS, ring) + chunk(r - t - 2)
-    return acc
+    accs = []
+    for x, dy in zip(xs, dys):
+        acc = chunk(x, dy, r - 1)
+        for t in range(n - 1):
+            acc = jax.lax.ppermute(acc, AXIS, ring) + chunk(x, dy, r - t - 2, taken)
+            if taken is not None:
+                taken = _zero_read_off(acc)
+        accs.append(acc)
+    return accs, taken
+
+
+def _zero_read_off(a: jax.Array) -> jax.Array:
+    """0.0 in float32, computed from `a`'s first element in a way no
+    simplification sees through (its bits with the lowest set are never 0):
+    whatever uses it stands behind whatever makes `a`."""
+    bits = jax.lax.bitcast_convert_type(
+        a.reshape(-1)[0], jnp.dtype(f"uint{8 * a.dtype.itemsize}"))
+    return ((bits | 1) == 0).astype(jnp.float32)

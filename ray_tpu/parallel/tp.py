@@ -24,8 +24,9 @@ whose body is a `shard_map` manual over `tp` (`dp` stays the partitioner's):
                   arrives is added: [b, s over tp, d].
 
 Each is the other's transpose, so the backward of one is the ring of the
-other; the `dw` products wait for no transfer. Sums over `tp` are taken in
-the activations' dtype over the same ranks as the partitioner's all-reduce.
+other; the `dw` products wait for no transfer of `tp`'s. Sums over `tp` are
+taken in the activations' dtype over the same ranks as the partitioner's
+all-reduce.
 
 The weights come as `fsdp.ExchangedWeight`s (the mesh has fsdp > 1 too:
 `models/transformer._rows_mesh`): a `dw` is still summed over `fsdp` by that
@@ -38,6 +39,14 @@ backward can run, pins that order, which the compiler turns round there:
 each started where the one before is first used; the four all-reduces were
 when they caught up, and with those gone the step waited for weights instead
 (344 ms a step against the parent's 338: PERF.md section 6, PR 38).
+
+The seven `dw` rings of a layer live in four backward bodies and share one
+`fsdp` link. Every product takes the layer's `fsdp.RingOrder` beside its
+operands and hands it on, so each backward body gets, as a cotangent, what
+the body before it left (the backward runs the products last first) and its
+rings stand behind that one's: the arrivals are taken in the order of the
+starts, `w_down`'s to `wv`'s, each ring's kept product between
+(`fsdp._reduce_scatter_dws`; PERF.md section 6, PR 57).
 
 `chunks`: the whole-sequence side of a product may stay a tuple of chunks,
 one a rank of the ring and in each rank's OWN order (its rows first, then
@@ -97,14 +106,18 @@ def gather_matmul(x: jax.Array, ws: Sequence, mesh, *,
     sharded over fsdp on the same dimension) -> [b, s, n over tp] each, or
     with `chunks` a tuple of tp chunks [b, s / tp, n over tp] each."""
     dim, = {w.dim for w in ws}
-    return _gather_matmul(x, tuple(w.w for w in ws), dim, mesh, chunks)
+    order, = {w.order for w in ws}
+    ys, order.taken = _gather_matmul(x, tuple(w.w for w in ws), order.taken,
+                                     dim, mesh, chunks)
+    return ys
 
 
 def matmul_scatter(x, w, mesh) -> jax.Array:
     """x [b, s, k over tp], or the tuple of chunks `gather_matmul` gave, @
     w [k over tp, d] (an `fsdp.ExchangedWeight`), summed over `tp` ->
     [b, s over tp, d]."""
-    return _matmul_scatter(x, w.w, w.dim, mesh)
+    y, w.order.taken = _matmul_scatter(x, w.w, w.order.taken, w.dim, mesh)
+    return y
 
 
 # ------------------------------------------------ inside the manual region
@@ -176,10 +189,13 @@ def _one_array(a) -> jax.Array:
     return jnp.concatenate(a, axis=1) if isinstance(a, (tuple, list)) else a
 
 
-def _weight_grad(x, dy, dim: int, mesh, dtype) -> jax.Array:
-    """dw of `x @ w` from x [b, s, k] and dy [b, s, n] over the whole
-    sequence, summed over `fsdp` by fsdp.py's ring."""
-    return fsdp.weight_grad(_one_array(x), _one_array(dy), dim, mesh).astype(dtype)
+def _weight_grads(x, dys, ws, dim: int, mesh, taken) -> Tuple:
+    """dw of each `x @ w` from x [b, s, k] and its dy [b, s, n] over the
+    whole sequence, summed over `fsdp` by fsdp.py's rings, and `taken` behind
+    the last of them (`fsdp.RingOrder`)."""
+    dws, taken = fsdp.weight_grads(
+        [_one_array(x)] * len(dys), list(map(_one_array, dys)), dim, mesh, taken)
+    return tuple(dw.astype(w.dtype) for dw, w in zip(dws, ws)), taken
 
 
 def _manual(body, mesh, in_specs, out_specs):
@@ -212,30 +228,30 @@ def _gathered_products(x, ws, dim, mesh, chunks):
     return jax.tree_util.tree_map(lambda y: checkpoint_name(y, SAVED), ys)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _gather_matmul(x, ws, dim, mesh, chunks):
-    return _gathered_products(x, ws, dim, mesh, chunks)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _gather_matmul(x, ws, taken, dim, mesh, chunks):
+    return _gathered_products(x, ws, dim, mesh, chunks), taken
 
 
-def _gather_matmul_fwd(x, ws, dim, mesh, chunks):
+def _gather_matmul_fwd(x, ws, taken, dim, mesh, chunks):
     # (the products themselves, not `_gather_matmul`: a remat policy sees
     # through a `shard_map` to the dots it may keep, not through a custom_vjp)
-    return _gathered_products(x, ws, dim, mesh, chunks), (x, ws)
+    return (_gathered_products(x, ws, dim, mesh, chunks), taken), (x, ws)
 
 
-def _gather_matmul_bwd(dim, mesh, chunks, res, dys):
-    def body(x, ws, dys):
+def _gather_matmul_bwd(dim, mesh, chunks, res, cts):
+    def body(x, ws, dys, taken):
         parts = fsdp.ring_products(
             [[_rows_at(dy, t) for dy in dys]
              for t in range(jax.lax.axis_size(AXIS))], ws, dim, True, mesh)
         dx = _ring_sum(lambda t: parts[t])
         rows = _like(dys[0], _gather(x))  # in flight behind the dx products
-        return dx, tuple(_weight_grad(rows, dy, dim, mesh, w.dtype)
-                         for dy, w in zip(dys, ws))
+        return dx, *_weight_grads(rows, dys, ws, dim, mesh, taken)
 
+    dys, taken = cts
     each = (_ROWS,) * len(dys)
-    return _manual(body, mesh, (_ROWS, each, tuple(map(_cols, dys))),
-                   (_ROWS, each))(*res, tuple(dys))
+    return _manual(body, mesh, (_ROWS, each, tuple(map(_cols, dys)), P()),
+                   (_ROWS, each, P()))(*res, tuple(dys), taken)
 
 
 _gather_matmul.defvjp(_gather_matmul_fwd, _gather_matmul_bwd)
@@ -252,17 +268,17 @@ def _scattered_product(x, w, dim, mesh):
         _manual(body, mesh, (_cols(x), _W_ROWS), _ROWS)(x, w), SAVED)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _matmul_scatter(x, w, dim, mesh):
-    return _scattered_product(x, w, dim, mesh)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _matmul_scatter(x, w, taken, dim, mesh):
+    return _scattered_product(x, w, dim, mesh), taken
 
 
-def _matmul_scatter_fwd(x, w, dim, mesh):
-    return _scattered_product(x, w, dim, mesh), (x, w)
+def _matmul_scatter_fwd(x, w, taken, dim, mesh):
+    return (_scattered_product(x, w, dim, mesh), taken), (x, w)
 
 
-def _matmul_scatter_bwd(dim, mesh, res, dy):
-    def body(x, w, dy):
+def _matmul_scatter_bwd(dim, mesh, res, cts):
+    def body(x, w, dy, taken):
         rows = _gather(dy)
         if isinstance(x, tuple):
             # the FFN's, first in a layer's backward: dy is there from the
@@ -280,11 +296,13 @@ def _matmul_scatter_bwd(dim, mesh, res, dy):
             whole_dy = _in_sequence(rows)
             dx = _in_sequence(fsdp.ring_products(
                 [[c] for c in rows], [w], dim, True, mesh))
-        return dx, _weight_grad(x, whole_dy, dim, mesh, w.dtype)
+        (dw,), taken = _weight_grads(x, [whole_dy], [w], dim, mesh, taken)
+        return dx, dw, taken
 
     x, w = res
-    return _manual(body, mesh, (_cols(x), _W_ROWS, _ROWS),
-                   (_cols(x), _W_ROWS))(x, w, dy)
+    dy, taken = cts
+    return _manual(body, mesh, (_cols(x), _W_ROWS, _ROWS, P()),
+                   (_cols(x), _W_ROWS, P()))(x, w, dy, taken)
 
 
 _matmul_scatter.defvjp(_matmul_scatter_fwd, _matmul_scatter_bwd)

@@ -16,7 +16,7 @@ each rank's partial sums and are all-reduced once a step, where the
 partitioner's reduction waits for every chip once a layer. The step's
 `xla.compile` spans say which forms it has (`grad_exchanges_per_layer`,
 `tp_exchanges_per_layer`, `norm_grad_reductions_in_layers`,
-`ring_products_own_first`).
+`ring_products_own_first`, `dw_rings_ordered`).
 """
 
 from __future__ import annotations
@@ -32,6 +32,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.transformer import (
     ModelConfig,
+    dw_rings_ordered,
     grad_exchanges_per_layer,
     init_params,
     loss_fn,
@@ -160,8 +161,9 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh,
     def step(state: TrainState, batch: Dict[str, jax.Array]):
         # which form of the gradients' reduction over fsdp, of the block's
         # reductions over tp and of the norm scales' this program has, and
-        # how many ring products run own shard first by a pin, is a fact of
-        # its compile: on its `xla.compile` spans
+        # how many ring products run own shard first by a pin and whether the
+        # weight gradients' rings are taken in order, is a fact of its
+        # compile: on its `xla.compile` spans
         inputs = split_batch(batch)[0]
         tracing.note_compile(
             "step", fsdp=mesh.shape.get("fsdp", 1), tp=mesh.shape.get("tp", 1),
@@ -172,7 +174,8 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh,
             norm_grad_reductions_in_layers=norm_grad_reductions_in_layers(
                 cfg, mesh, inputs.shape[0]),
             ring_products_own_first=ring_products_own_first(
-                cfg, mesh, *inputs.shape))
+                cfg, mesh, *inputs.shape),
+            dw_rings_ordered=dw_rings_ordered(cfg, mesh, *inputs.shape))
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, batch, cfg, mesh)
         # (the phases before this one are named in models/transformer.py:

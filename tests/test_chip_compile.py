@@ -1021,6 +1021,49 @@ def _assert_own_shard_first_in_the_backward(comps):
     assert behind[0] == by_arrived[0], (behind[:2], by_arrived)
 
 
+_CYCLES = re.compile(r'"estimated_cycles":"(\d+)"')
+
+
+def _assert_dw_rings_taken_in_start_order(comps):
+    """A layer's seven weight-gradient rings share the one `fsdp` link in one
+    direction, and the link serves them in the order of their starts. So in
+    the backward body, among the `fsdp` permutes whose operand is a chunk of
+    a `dw` ([1, k, n]): no `-done` of a LATER start stands before the fusion
+    that takes an EARLIER start's done (the kept half's product with the sum
+    and the stack write fused in; for the 2 MB rings the sum and the write,
+    behind the kept product), five of the seven are such products, and at
+    every such done the bytes started no later
+    than its own start and not yet taken, over XLA's own estimate of what
+    stands between the oldest of those starts and the done (the cycles in
+    each fusion's `backend_config`, at the v5e's 1.5 GHz), stay under
+    35 GB/s, what PR 54 read the link carry. Left to the scheduler the
+    smallest ring's done came first: `wk`'s 2 MB behind 67 MB, 45.6 GB/s
+    (PERF.md section 6, PR 57: `fsdp.RingOrder`)."""
+    _, body = _scan_bodies(comps)
+    name = lambda l: re.match(r"\s*(?:ROOT )?%([\w.\-]+) =", l).group(1)
+    ms = lambda l: int((_CYCLES.search(l) or [0, 0])[1]) / 1.5e6
+    rings = []
+    for at, l in enumerate(body):
+        chunk = re.search(r"= \((\w+)\[1,(\d+),(\d+)\]", l)
+        if " collective-permute-start(" in l and _FSDP_PAIRS in l and chunk:
+            done = next(i for i, d in enumerate(body)
+                        if f"collective-permute-done(%{name(l)})" in d)
+            taken = next(i for i, d in enumerate(body) if i > done
+                         and name(body[done]) in _operands(d))
+            rings.append({"start": at, "done": done, "taken": taken,
+                          "product": _is_matmul(comps, body[taken]),
+                          "mb": 2 * int(chunk[2]) * int(chunk[3]) / 1e6})
+    assert len(rings) == 7 and sum(r["product"] for r in rings) >= 5, rings
+    for r in rings:
+        later = [o for o in rings if o["start"] > r["start"]]
+        assert all(o["done"] > r["taken"] for o in later), (r, later)
+        ahead = [o for o in rings
+                 if o["start"] <= r["start"] and o["done"] >= r["done"]]
+        between = sum(ms(l) for l in
+                      body[min(o["start"] for o in ahead) + 1:r["done"]])
+        assert sum(o["mb"] for o in ahead) / between < 35, (r, ahead, between)
+
+
 def _assert_no_tp_all_reduce_in_the_layers(comps):
     """Neither scan body is left a blocking `all-reduce` of the residual
     [1,2048,4096] (the partitioner's Megatron form had two in each, over the
@@ -1082,6 +1125,7 @@ _CELL_STEP_ASSERTIONS = {
     "weights_ride_the_fsdp_ring": _assert_weights_ride_the_fsdp_ring,
     "no_all_reduce_in_the_layers": _assert_no_all_reduce_in_the_layers,
     "own_shard_first_in_the_backward": _assert_own_shard_first_in_the_backward,
+    "dw_rings_taken_in_start_order": _assert_dw_rings_taken_in_start_order,
 }
 _TWO_LAYERS = {}  # the compiled text of one compile, for the cases below
 
@@ -1089,7 +1133,7 @@ _TWO_LAYERS = {}  # the compiled text of one compile, for the cases below
 @pytest.mark.parametrize("what", list(_CELL_STEP_ASSERTIONS))
 def test_four_chip_cell_step_exchanges_behind_matmuls(topo, chip, what):
     """Two layers of the 4-chip cell's step (twenty seconds, compiled once
-    for the six cases; the whole 22 are the slow case below)."""
+    for the seven cases; the whole 22 are the slow case below)."""
     from ray_tpu.train.step import default_optimizer
 
     if not _TWO_LAYERS:

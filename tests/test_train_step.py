@@ -152,6 +152,7 @@ def test_tp_exchange_matches_one_device(mesh_cfg, n, monkeypatch):
     assert transformer.tp_exchanges_per_layer(cfg, mesh, 8, 64) == 4 * ours
     assert transformer.grad_exchanges_per_layer(cfg, mesh, 8) == 7 * ours
     assert transformer.ring_products_own_first(cfg, mesh, 8, 64) == ours
+    assert transformer.dw_rings_ordered(cfg, mesh, 8, 64) == ours
     params = init_params(jax.random.PRNGKey(0), cfg)
     batch = _batch(jax.random.PRNGKey(1), cfg, batch=8, seq=64)
     grad = lambda m: jax.jit(jax.value_and_grad(
@@ -183,6 +184,40 @@ def test_tp_exchange_matches_one_device(mesh_cfg, n, monkeypatch):
     assert losses[-1] < losses[0]
 
 
+def _program_at_dtype(mesh_cfg, n, dtype):
+    """(cfg, mesh, run) for the bit-for-bit comparisons below: `run()` lowers
+    and runs `value_and_grad(loss_fn)` under the mesh, the same weights and
+    batch each time -> (lowered text, loss, gradients)."""
+    import dataclasses
+
+    from ray_tpu.train.step import state_shardings
+
+    cfg = dataclasses.replace(ModelConfig.tiny(), n_kv_heads=2,
+                              dtype=jnp.dtype(dtype))
+    mesh = make_virtual_mesh(n, mesh_cfg)
+    params = jax.device_put(
+        init_params(jax.random.PRNGKey(0), cfg),
+        state_shardings(cfg, mesh, default_optimizer()).params)
+    batch = _batch(jax.random.PRNGKey(1), cfg, batch=8, seq=64)
+    batch = jax.device_put(batch, {k: batch_sharding(mesh)[k] for k in batch})
+
+    def run():
+        lowered = jax.jit(jax.value_and_grad(
+            lambda p, b: loss_fn(p, b, cfg, mesh)[0])).lower(params, batch)
+        return lowered.as_text(), *lowered.compile()(params, batch)
+
+    return cfg, mesh, run
+
+
+def _assert_same_bits(loss, grads, parent_loss, parent_grads):
+    bits = lambda a: np.asarray(a).view(f"u{a.dtype.itemsize}")
+    np.testing.assert_array_equal(bits(loss), bits(parent_loss))
+    jax.tree_util.tree_map_with_path(
+        lambda path, g, w: np.testing.assert_array_equal(
+            bits(g), bits(w), err_msg=jax.tree_util.keystr(path)),
+        grads, parent_grads)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("mesh_cfg,n", [
     (MeshConfig(dp=1, fsdp=2, tp=2), 4),
@@ -198,29 +233,12 @@ def test_own_shard_first_changes_no_bit(mesh_cfg, n, dtype, monkeypatch):
     EVERY gradient leaf are bit for bit those of the program that never pins
     (the parent's form, `own_first` dropped here), in float32 and in
     bfloat16, as the four-chip cell runs it."""
-    import dataclasses
-
     from ray_tpu.models import transformer
     from ray_tpu.parallel import fsdp
-    from ray_tpu.train.step import state_shardings
 
-    cfg = dataclasses.replace(ModelConfig.tiny(), n_kv_heads=2,
-                              dtype=jnp.dtype(dtype))
-    mesh = make_virtual_mesh(n, mesh_cfg)
+    cfg, mesh, run = _program_at_dtype(mesh_cfg, n, dtype)
     assert transformer.ring_products_own_first(cfg, mesh, 8, 64) == 1
-    params = jax.device_put(
-        init_params(jax.random.PRNGKey(0), cfg),
-        state_shardings(cfg, mesh, default_optimizer()).params)
-    batch = _batch(jax.random.PRNGKey(1), cfg, batch=8, seq=64)
-    batch = jax.device_put(batch, {k: batch_sharding(mesh)[k] for k in batch})
-
-    def run():
-        lowered = jax.jit(jax.value_and_grad(
-            lambda p, b: loss_fn(p, b, cfg, mesh)[0])).lower(params, batch)
-        return (lowered.as_text().count("optimization_barrier"),
-                *lowered.compile()(params, batch))
-
-    pins, loss, grads = run()
+    text, loss, grads = run()
     pinned = fsdp.ring_products
     asked = []
 
@@ -229,16 +247,52 @@ def test_own_shard_first_changes_no_bit(mesh_cfg, n, dtype, monkeypatch):
         return pinned(*args, **kw)
 
     monkeypatch.setattr(fsdp, "ring_products", never_pinned)
-    parent_pins, parent_loss, parent_grads = run()
+    parent_text, parent_loss, parent_grads = run()
     # of a layer's ring products (forward, remat's, backward) ONE asks
     assert asked.count(True) == 1 and asked.count(False) >= 8, asked
-    assert pins - parent_pins == mesh_cfg.fsdp - 1
-    bits = lambda a: np.asarray(a).view(f"u{a.dtype.itemsize}")
-    np.testing.assert_array_equal(bits(loss), bits(parent_loss))
-    jax.tree_util.tree_map_with_path(
-        lambda path, g, w: np.testing.assert_array_equal(
-            bits(g), bits(w), err_msg=jax.tree_util.keystr(path)),
-        grads, parent_grads)
+    pins = lambda t: t.count("optimization_barrier")
+    assert pins(text) - pins(parent_text) == mesh_cfg.fsdp - 1
+    _assert_same_bits(loss, grads, parent_loss, parent_grads)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("mesh_cfg,n", [
+    (MeshConfig(dp=1, fsdp=2, tp=2), 4),
+    (MeshConfig(dp=1, fsdp=4, tp=2), 8),
+    (MeshConfig(dp=1, fsdp=2, tp=1), 2),
+], ids=["fsdp2xtp2", "fsdp4xtp2", "fsdp2xtp1"])
+def test_ordered_dw_rings_change_no_bit(mesh_cfg, n, dtype, monkeypatch):
+    """Where the products are parallel/tp.py's, a layer's seven weight
+    gradients' rings are taken off the `fsdp` link in the order of their
+    starts: each ring's kept product reads its slice at an offset that adds
+    a zero read off the sum the ring before made, in the same backward body
+    or the one before (`fsdp.RingOrder`, handed from product to product and
+    back as a cotangent). The zero is a zero: the loss and EVERY gradient
+    leaf are bit for bit those of the program whose rings know of no order
+    (the parent's form: `weight_grads` never handed `taken` here), in
+    float32 and in bfloat16, at fsdp 2 and round a ring of three steps. With
+    `tp` 1 the products are the partitioner's, every ring is alone in its
+    backward body, and none is handed an order at all."""
+    from ray_tpu.models import transformer
+    from ray_tpu.parallel import fsdp
+
+    cfg, mesh, run = _program_at_dtype(mesh_cfg, n, dtype)
+    ours = int(mesh_cfg.tp > 1)
+    assert transformer.dw_rings_ordered(cfg, mesh, 8, 64) == ours
+    text, loss, grads = run()
+    ordered = fsdp.weight_grads
+    handed = []
+
+    def unordered(xs, dys, dim, mesh, taken=None):
+        handed.append(taken is not None)
+        return ordered(xs, dys, dim, mesh)[0], taken
+
+    monkeypatch.setattr(fsdp, "weight_grads", unordered)
+    parent_text, parent_loss, parent_grads = run()
+    # the four backward bodies of a layer's products, or none of the seven
+    assert handed.count(True) == 4 * ours and len(handed) == (4 if ours else 7)
+    assert (text != parent_text) == bool(ours)
+    _assert_same_bits(loss, grads, parent_loss, parent_grads)
 
 
 @pytest.mark.parametrize("dtype", ["bf16_scales", "bf16"])
@@ -318,6 +372,7 @@ def test_tp_exchange_stays_off_where_the_block_is_not_the_plain_one(why, monkeyp
         mesh_cfg = MeshConfig(dp=4, fsdp=1, tp=2)
     mesh = make_virtual_mesh(8, mesh_cfg)
     assert transformer.tp_exchanges_per_layer(cfg, mesh, 8, seq) == 0
+    assert transformer.dw_rings_ordered(cfg, mesh, 8, seq) == 0
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     tokens = jax.ShapeDtypeStruct((8, seq), jnp.int32)
     batch = {"inputs": tokens, "targets": tokens}
