@@ -1,12 +1,16 @@
 """What a worker that was spawned for a chip grant is told about the chip:
-which chips its libtpu may open, and where its compiled programs are kept.
+which chips its libtpu may open, and where its compiled programs are kept;
+and how long the backend took to open them (`time_chip_open`).
 Imports nothing heavy — the raylet and `chip_smoke.py`'s parent
 (which must stay off jax) all call it."""
 
 from __future__ import annotations
 
 import errno
+import importlib.abc
 import os
+import sys
+import threading
 from typing import Container, Dict, List
 
 # libtpu's shape of n chips of one host as one process sees them
@@ -107,6 +111,120 @@ def chip_holders(tpu_ids: List[int], ours: Container[int] = ()) -> Dict[str, int
                 and _refuses_to_open(node):
             held[node] = 0
     return held
+
+
+_XLA_BRIDGE = "jax._src.xla_bridge"
+_open_lock = threading.Lock()
+_open_armed = False
+
+
+def time_chip_open(granted: int) -> None:
+    """Arm, once in a worker that holds a grant of `granted` chips, the timing
+    of jax's backend opening: ONE `chip.open` span (`platform`, `device_kind`,
+    `devices`, `granted`) around the call of `xla_bridge.backends()` that
+    initialises the backends, WHOEVER makes it (the user's `jax.devices()`, a
+    `jit`, `jax.distributed.initialize` before either). The program opens
+    nothing itself and imports no jax: where jax is not loaded yet, a finder
+    at the head of `sys.meta_path` waits for `jax._src.xla_bridge` alone,
+    wraps that module's `backends` once it has executed and takes itself off
+    the path. The wrapper puts the original back as soon as a call returns
+    with the backends initialised: nothing of this is on any later call's
+    path. From then on the process's programs record their `xla.compile`
+    spans (`tracing.record_compiles`)."""
+    global _open_armed
+    if _open_armed:
+        return
+    with _open_lock:
+        if _open_armed:
+            return
+        _open_armed = True
+    bridge = sys.modules.get(_XLA_BRIDGE)
+    if bridge is None:
+        sys.meta_path.insert(0, _BridgeFinder(granted))
+    elif hasattr(bridge, "backends"):
+        _wrap_backends(bridge, granted)
+    # else another thread is half-way through importing it: a finder would
+    # never fire and the module is not whole yet, so this worker records no
+    # `chip.open` (its readers then give None) rather than race the import
+
+
+def _wrap_backends(bridge, granted: int) -> None:
+    """Tracing never fails what it traces: a jax whose `xla_bridge` lacks
+    what is read here (`tests/test_setup_spans.py` pins the four names)
+    is left as it is, and a span that cannot be recorded is said on stderr
+    once, behind the backends' own result or error."""
+    from ray_tpu.util import tracing
+
+    try:
+        original = bridge.backends
+        if bridge.backends_are_initialized():
+            return  # opened before the grant was known: nothing left to time
+    except Exception as e:
+        return _not_timed(e)
+
+    def backends():
+        start = tracing.now_us()
+        try:
+            return original()
+        finally:
+            try:
+                with _open_lock:
+                    mine = bridge.backends is backends and bool(bridge._backends)
+                    if mine:
+                        bridge.backends = original
+                if mine:
+                    client = bridge._default_backend
+                    tracing.add_complete(
+                        "chip.open", "chip", start, tracing.now_us() - start,
+                        platform=client.platform,
+                        device_kind=client.local_devices()[0].device_kind,
+                        devices=client.device_count(), granted=granted)
+                    tracing.record_compiles()
+            except Exception as e:
+                if bridge.backends is backends:
+                    bridge.backends = original
+                _not_timed(e)
+
+    bridge.backends = backends
+
+
+def _not_timed(e: Exception) -> None:
+    print(f"[chips] `chip.open` not recorded: {type(e).__name__}: {e}",
+          file=sys.stderr, flush=True)
+
+
+class _BridgeFinder(importlib.abc.MetaPathFinder):
+    """Finds nothing but `jax._src.xla_bridge`, once: the spec is the next
+    finders' own, its loader's `exec_module` followed by `_wrap_backends`."""
+
+    def __init__(self, granted: int):
+        self._granted = granted
+
+    def find_spec(self, fullname, path, target=None):
+        if fullname != _XLA_BRIDGE:
+            return None
+        sys.meta_path.remove(self)
+        for finder in sys.meta_path:
+            find = getattr(finder, "find_spec", None)
+            spec = find(fullname, path, target) if find else None
+            if spec is not None and spec.loader is not None:
+                spec.loader = _WrappingLoader(spec.loader, self._granted)
+                return spec
+        return None
+
+
+class _WrappingLoader(importlib.abc.Loader):
+    def __init__(self, loader, granted: int):
+        self._loader, self._granted = loader, granted
+
+    def create_module(self, spec):
+        return self._loader.create_module(spec)
+
+    def exec_module(self, module):
+        # the module is its own loader's again before its code runs
+        module.__loader__ = module.__spec__.loader = self._loader
+        self._loader.exec_module(module)
+        _wrap_backends(module, self._granted)
 
 
 def default_compile_cache_dir() -> str:

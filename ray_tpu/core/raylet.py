@@ -77,8 +77,14 @@ class _TpuLease:
     tpu_ids: List[int]
     # an actor's (bundle_key | None, demand), as WorkerHandle.actor_charge
     actor_charge: Optional[Tuple[Optional[Tuple], Dict[str, float]]] = None
-    queued_us: float = 0.0         # lease-span start for traced tasks
+    # the demand's arrival at this raylet (tracing epoch-us): where the
+    # `lease.tpu` span starts and, for a traced task, its `lease::` span
+    queued_us: float = 0.0
     proc: Optional[subprocess.Popen] = None  # None until it is spawned
+    # of `lease.tpu`: the grant of the chips, and what of the time since
+    # then was the wait for a foreign holder (`_start_once_chips_open`)
+    granted_us: float = 0.0
+    holders_wait_us: float = 0.0
 
 
 @dataclass
@@ -86,7 +92,9 @@ class _QueuedTask:
     spec: TaskSpec
     spillback_count: int = 0
     # enqueue stamp (tracing epoch-us) for the lease span: submit-arrival to
-    # worker-grant is the queueing stage of the critical path. 0.0 = untraced.
+    # worker-grant is the queueing stage of the critical path. 0.0 = an
+    # untraced task that asks for no chip (a TPU demand's `lease.tpu` span
+    # starts here, traced or not).
     queued_us: float = 0.0
 
 
@@ -254,8 +262,12 @@ class Raylet:
         # every process spawned for a chip grant, until it has been seen
         # gone: `stop()` returns only once none of them holds a chip
         self._chip_procs: List[subprocess.Popen] = []
-        # TPU actor specs the GCS placed here, waiting for free chips
+        # TPU actor specs the GCS placed here, waiting for free chips, each
+        # with when it arrived: (spec, tracing epoch-us)
         self._tpu_waiting_actors: deque = deque()
+        # when each cold-spawned worker's Popen began (pid -> tracing
+        # epoch-us), until it registers or is reaped: `worker.spawn`'s start
+        self._spawn_us: Dict[int, float] = {}
         self._start_time = time.time()
         # workers we SIGKILLed for memory pressure: their death notification
         # carries reason="oom" so exhausted retries surface OutOfMemoryError
@@ -1002,6 +1014,8 @@ class Raylet:
             handle.env_key = payload.get("env_key") or spawned_env
             self._workers[wid] = handle
             envfile = self._starting_envfile.pop(payload["pid"], None)
+            # a cold spawn of this raylet's (not a driver, not a fork)
+            t_spawn = self._spawn_us.pop(payload["pid"], None)
         if envfile is not None:
             # the worker booted: its {ENVFILE} env file has been consumed
             try:
@@ -1057,6 +1071,10 @@ class Raylet:
             # the spawn lease handed off to the worker's own reference
             self._env_manager.release(spawned_env)
         self._schedule()
+        if t_spawn is not None:
+            tracing.add_complete(
+                "worker.spawn", "worker", t_spawn, tracing.now_us() - t_spawn,
+                pid=handle.pid, chips=len(handle.tpu_grant or ()))
         return {"node_id": self.node_id.binary(), "gcs_address": self.gcs_address}
 
     def _build_worker_env(self, env_key: Optional[str] = None,
@@ -1150,6 +1168,10 @@ class Raylet:
         argv = [python, "-m", "ray_tpu.core.worker_main",
                 "--raylet", self._server.address, "--gcs", self.gcs_address,
                 "--node-id", self.node_id.hex()]
+        # where `lease.tpu` ends and `worker.spawn` begins; the worker reads
+        # the stamp as the start of its `worker.boot` (one host, one epoch)
+        t_spawn = tracing.now_us()
+        env = dict(env, RAY_TPU_SPAWN_US=repr(t_spawn))
         envfile = None
         if command_prefix:
             prefix = list(command_prefix)
@@ -1167,7 +1189,15 @@ class Raylet:
                 prefix = [envfile if a == "{ENVFILE}" else a for a in prefix]
             argv = prefix + argv
         proc = subprocess.Popen(argv, env=env)
+        if tpu_lease is not None:
+            start = tpu_lease.queued_us
+            tracing.add_complete(
+                "lease.tpu", "lease", start, t_spawn - start, pid=proc.pid,
+                chips=len(tpu_lease.tpu_ids), tpu_ids=list(tpu_lease.tpu_ids),
+                queued_us=tpu_lease.granted_us - start,
+                holders_wait_us=tpu_lease.holders_wait_us)
         with self._lock:
+            self._spawn_us[proc.pid] = t_spawn
             if tpu_lease is not None:
                 self._chip_procs = [p for p in self._chip_procs
                                     if p.poll() is None] + [proc]
@@ -1429,11 +1459,11 @@ class Raylet:
                 and s.job_id.binary() == job_id]
             for s in doomed_specs:
                 self._pending_actor_specs.remove(s)
-            for s in [s for s in self._tpu_waiting_actors
-                      if getattr(s, "job_id", None) is not None
-                      and s.job_id.binary() == job_id]:
-                self._tpu_waiting_actors.remove(s)
-                doomed_specs.append(s)
+            for e in [e for e in self._tpu_waiting_actors
+                      if getattr(e[0], "job_id", None) is not None
+                      and e[0].job_id.binary() == job_id]:
+                self._tpu_waiting_actors.remove(e)
+                doomed_specs.append(e[0])
             victims = [h for h in self._workers.values()
                        if h.actor_id is None
                        and h.current_task is not None
@@ -1650,6 +1680,7 @@ class Raylet:
                             pass
                         dead_env = self._starting_env.pop(p.pid, None)
                         dead_envfile = self._starting_envfile.pop(p.pid, None)
+                        self._spawn_us.pop(p.pid, None)
                         dead_lease = next((l for l in self._tpu_leases
                                            if l.proc is p), None)
                     if dead_lease is not None:
@@ -1810,7 +1841,7 @@ class Raylet:
 
     def _submit(self, spec: TaskSpec, spillback_count: int) -> None:
         qt = _QueuedTask(spec, spillback_count)
-        if spec.trace_ctx is not None:
+        if spec.trace_ctx is not None or spec.resources.get("TPU"):
             qt.queued_us = tracing.now_us()
         with self._lock:
             self._queue.append(qt)
@@ -1912,6 +1943,7 @@ class Raylet:
         The lease is on record from here on; its worker is spawned by
         `_start_tpu_lease` once the lock is released."""
         lease = _TpuLease(spec, tpu_ids, **kw)
+        lease.granted_us = tracing.now_us()
         self._tpu_leases.append(lease)
         return lease
 
@@ -1980,6 +2012,7 @@ class Raylet:
             return
         print(f"[chips] waited {waited:.1f} s for {who} to release {node}",
               file=sys.stderr, flush=True)
+        lease.holders_wait_us = waited * 1e6  # the same number, on `lease.tpu`
         self._start_tpu_lease(lease)
 
     def _take_tpu_leases(self, match) -> List[_TpuLease]:
@@ -2510,7 +2543,7 @@ class Raylet:
                 return True
         if spec.resources.get("TPU", 0.0) > 0:
             with self._lock:
-                self._tpu_waiting_actors.append(spec)
+                self._tpu_waiting_actors.append((spec, tracing.now_us()))
             self._schedule()  # grants the chips and spawns its worker
             return True
         with self._lock:
@@ -2540,13 +2573,15 @@ class Raylet:
         """Caller holds self._lock. Every waiting TPU actor whose chips are
         free gets them and its charge; returns the leases to start."""
         granted = []
-        for spec in list(self._tpu_waiting_actors):
+        for entry in list(self._tpu_waiting_actors):
+            spec, arrived_us = entry
             tpu_ids = self._assign_tpus(spec.resources["TPU"])
             if tpu_ids is None:
                 continue
-            self._tpu_waiting_actors.remove(spec)
+            self._tpu_waiting_actors.remove(entry)
             granted.append(self._grant_tpu_lease(
-                spec, tpu_ids, actor_charge=self._charge_actor(spec)))
+                spec, tpu_ids, actor_charge=self._charge_actor(spec),
+                queued_us=arrived_us))
         return granted
 
     def _charge_actor(self, spec) -> Tuple[Optional[Tuple], Dict[str, float]]:
@@ -2603,9 +2638,9 @@ class Raylet:
                 if w.actor_id == actor_id:
                     target = w
                     break
-            for s in [s for s in self._tpu_waiting_actors
-                      if s.actor_id == actor_id]:
-                self._tpu_waiting_actors.remove(s)  # never got its chips
+            for e in [e for e in self._tpu_waiting_actors
+                      if e[0].actor_id == actor_id]:
+                self._tpu_waiting_actors.remove(e)  # never got its chips
         for lease in self._take_tpu_leases(
                 lambda l: l.actor_charge is not None
                 and l.spec.actor_id == actor_id):

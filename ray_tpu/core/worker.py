@@ -34,6 +34,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ray_tpu.core import rpc, serialization
+from ray_tpu.core.chips import time_chip_open
 from ray_tpu.core.config import get_config
 from ray_tpu.core.exceptions import (
     ActorDiedError,
@@ -3084,6 +3085,7 @@ class CoreWorker:
             ids = payload.get("tpu_ids")
             if ids:
                 self._task_tpu_ids[spec.task_id] = list(ids)
+                time_chip_open(len(ids))
             d_us = payload.get("dispatch_us")
             if d_us is not None and spec.trace_ctx is not None:
                 # raylet's dispatch stamp: _execute_task turns it into the
@@ -3092,6 +3094,8 @@ class CoreWorker:
             self._task_queue.put(spec)
         elif method == "become_actor":
             self._actor_tpu_ids = list(payload.get("tpu_ids") or [])
+            if self._actor_tpu_ids:
+                time_chip_open(len(self._actor_tpu_ids))
             self._become_actor(payload["spec"],
                                payload.get("incarnation"))
         elif method == "cancel_task":
@@ -3316,7 +3320,12 @@ class CoreWorker:
             args, kwargs = self._deserialize_args(spec.init_args, spec.init_kwargs_blob)
             if spec.runtime_env:
                 self._apply_runtime_env(spec.runtime_env)
-            self._actor_instance = cls(*args, **kwargs)
+            # the user's constructor (a Serve replica's: weights, engine,
+            # warm-up), once in the worker's life
+            with tracing.span(
+                    f"actor.create::{getattr(cls, '__name__', cls)}", "actor",
+                    chips=len(self._actor_tpu_ids)):
+                self._actor_instance = cls(*args, **kwargs)
             # dedicated pools BEFORE creation_done: callers only learn our
             # address afterwards, so no task can race an unrouted group
             for gname, gsize in (spec.concurrency_groups or {}).items():

@@ -104,7 +104,9 @@ def run_worker(raylet_address: str, gcs_address: str,
     _sys.stderr = err_tee
 
     from ray_tpu.core.worker import CoreWorker, set_current_worker
+    from ray_tpu.util import tracing
 
+    t_imported = tracing.now_us()
     try:
         worker = CoreWorker(
             mode="worker", raylet_address=raylet_address,
@@ -112,6 +114,16 @@ def run_worker(raylet_address: str, gcs_address: str,
     except ConnectionError:
         return  # raylet is gone (e.g. shut down while we were starting)
     set_current_worker(worker)
+    # `worker.boot`, inside the raylet's `worker.spawn`: from the stamp the
+    # raylet put in this process's environment just before its `Popen` (a
+    # forked child inherited its template's, and records none) to the
+    # registration acknowledged; `imports_us` of it were the interpreter and
+    # the imports, the rest the connections and the registration
+    t_spawn = float(os.environ.get("RAY_TPU_SPAWN_US") or 0.0)
+    if t_spawn and os.environ.get("RAY_TPU_WORKER_FORKED") != "1":
+        tracing.add_complete(
+            "worker.boot", "worker", t_spawn, tracing.now_us() - t_spawn,
+            imports_us=t_imported - t_spawn)
     out_tee.raylet = err_tee.raylet = worker.raylet
     out_tee._drain()
     err_tee._drain()

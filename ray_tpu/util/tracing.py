@@ -41,10 +41,31 @@ thread that consumes the engine's tokens emits `engine.stream`
 (models/serving.py), and the proxy's loop that writes them `relay::`
 (serve/http_proxy.py).
 
+Before `ingress::` / `task::`, a worker's set-up is a chain of its own:
+`lease.tpu -> worker.spawn > worker.boot -> actor.create::<Class> >
+chip.open, xla.compile`. The raylet records `lease.tpu` (a TPU demand's
+arrival -> its worker's `Popen`; `queued_us` for free chips,
+`holders_wait_us` for a foreign holder, `pid`, `chips`, `tpu_ids`) and
+`worker.spawn` (`Popen` -> that pid's registration handled; `pid`, `chips`);
+the worker `worker.boot` (the raylet's spawn stamp -> registration
+acknowledged; `imports_us` of it the interpreter and the imports),
+`actor.create::<Class>` around the user's constructor (`chips`), `chip.open`
+around the call that initialises jax's backends, whoever makes it
+(`core/chips.py:time_chip_open`; `platform`, `device_kind`, `devices`,
+`granted`), and a program's `xla.compile` spans, whose backend-compile span
+says how the persistent cache answered (`cache`, `retrieval_us`:
+`record_compiles`). `perfbench/lib/setup_spans.py` reads them all.
+
 THE COST RULE: never a span per token. What a token costs on its way is
 counted on a frame that already exists (clock reads and integer adds) and
 leaves as arguments of ONE span when its stream ends; a step gets one span
-beside its own (`engine.between_steps`), a request three.
+beside its own (`engine.between_steps`), a request three. Set-up spans are
+once a worker's life (once a program's, for `xla.compile`): nothing of them
+is on a step's, a token's or a task's path. Every one of them has a metric
+that reads it; an argument feeds a metric or the reader's choice and checks
+of the chip holder, or is an operator's fact that PERF.md section 3 lists as
+such (`queued_us`, `holders_wait_us`, `tpu_ids`: how `lease.tpu` splits, and
+over which chips). Add none without one of the three.
 
 One clock with the device trace: `span()` also enters
 `jax.profiler.TraceAnnotation(name)` once jax is loaded in the process (it
@@ -263,18 +284,50 @@ def note_compile(fun_name: str, **args) -> None:
 
 
 def record_compiles() -> None:
-    """Install, once per process, the `jax.monitoring` listener that turns
+    """Install, once per process, the `jax.monitoring` listeners that turn
     every program's trace / lower / backend-compile event into an `xla.compile` span
     named by the jitted function (`fun_name`; else the innermost open
     program span), so `ray_tpu timeline` says which step recompiled.
-    Called by code that imports jax anyway (the engine, `make_train_step`)."""
+    Called by code that imports jax anyway (the engine, `make_train_step`)
+    and, in a worker that holds a chip grant, when the backend has opened
+    (`core/chips.py:time_chip_open`), so that a chip holder's every program
+    has its spans, the first included.
+
+    The backend-compile span also says how the persistent cache answered:
+    `cache` `hit` (with `retrieval_us`, what the read cost), `miss` (looked
+    up, not found, compiled) or `off` (no cache directory: never looked up).
+    jax reports those as `/jax/compilation_cache/` events on the compiling thread BEFORE the
+    backend-compile duration of the same program; they are held per thread
+    and dropped at the next program's lowering, so that they never ride
+    another program's span."""
     global _compiles_recorded
     if _compiles_recorded:
         return
     _compiles_recorded = True
-    from jax import monitoring
+    from jax import config, monitoring
+
+    def cache_facts() -> dict:
+        facts = getattr(_tls, "cache_facts", None)
+        if facts is None:
+            facts = _tls.cache_facts = {}
+        return facts
+
+    def on_event(event: str, **kw) -> None:
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            # jax computes a key wherever caching is not switched off; it is
+            # a lookup only where a cache directory is set. A miss that is
+            # not written (under the cache's thresholds) reports no more
+            if config.jax_compilation_cache_dir:
+                cache_facts().setdefault("cache", "miss")  # until a hit says so
+        elif event == "/jax/compilation_cache/cache_hits":
+            cache_facts()["cache"] = "hit"
+        elif event == "/jax/compilation_cache/cache_misses":
+            cache_facts()["cache"] = "miss"
 
     def on_duration(event: str, secs: float, **kw) -> None:
+        if event == "/jax/compilation_cache/cache_retrieval_time_sec":
+            cache_facts()["retrieval_us"] = secs * 1e6
+            return
         if not event.startswith("/jax/core/compile/"):
             return
         ctx = getattr(_tls, "ctx", None) or (None, None)
@@ -300,10 +353,16 @@ def record_compiles() -> None:
         if span["event"] == "jaxpr_to_mlir_module_duration":
             own = pending.get(bare)
             pending.clear()
+            cache_facts().clear()  # a compile that raised left its own behind
             if own is not None:
                 add_complete(**own)
+        elif span["event"] == "backend_compile_duration":
+            facts = cache_facts()
+            span.update({"cache": "off", **facts})
+            facts.clear()
         add_complete(**span)
 
+    monitoring.register_event_listener(on_event)
     monitoring.register_event_duration_secs_listener(on_duration)
 
 

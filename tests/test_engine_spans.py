@@ -4,6 +4,7 @@ from the HTTP ingress into the engine with default settings, compile spans
 by program name, and the named scopes of the train and serve programs."""
 
 import json
+import os
 import re
 import threading
 import time
@@ -204,6 +205,75 @@ def test_a_programs_own_trace_survives_and_the_inner_ones_do_not():
               if e["name"] == "xla.compile"
               and e["args"]["event"] == "jaxpr_trace_duration"]
     assert traces == ["outer_program", "inner_program"], traces
+
+
+_CACHE_CHILD = """
+import json, sys
+import jax, jax.numpy as jnp
+from ray_tpu.util import tracing
+tracing.record_compiles()
+x = jnp.ones((5,))   # its own small programs: before the two that count
+tracing.clear()
+for name in sys.argv[1:]:
+    jax.jit(lambda a: jnp.tanh(a) * len(name), inline=False).lower(x)  # traced, not compiled
+    fn = lambda a: jnp.cos(a) + len(name)
+    fn.__name__ = name
+    jax.jit(fn)(x).block_until_ready()
+print(json.dumps([e["args"] for e in tracing.get_events()
+                  if e["name"] == "xla.compile"
+                  and e["args"]["event"] == "backend_compile_duration"]))
+"""
+
+
+@pytest.fixture(scope="module")
+def cache_runs(tmp_path_factory):
+    """Three fresh processes over one temporary persistent cache that keeps
+    every program: `first_program` and `second_program` compiled back to
+    back; then `first_program` and a program the cache has never seen; then
+    `first_program` with no cache."""
+    import subprocess
+    import sys
+
+    cache = str(tmp_path_factory.mktemp("jax_cache"))
+    env = {k: v for k, v in os.environ.items() if not k.startswith("JAX_")}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(sys.path))
+    kept = dict(env, JAX_COMPILATION_CACHE_DIR=cache,
+                JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+                JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1")
+
+    def child(env, *programs):
+        p = subprocess.run([sys.executable, "-c", _CACHE_CHILD, *programs],
+                           capture_output=True, text=True, timeout=120, env=env)
+        assert p.returncode == 0, p.stderr[-2000:]
+        return {a["fun_name"]: a for a in json.loads(p.stdout.splitlines()[-1])}
+
+    return {"cold": child(kept, "first_program", "second_program"),
+            "warm": child(kept, "first_program", "third__program"),
+            "off": child(env, "first_program")}
+
+
+@pytest.mark.parametrize("run,program,cache", [
+    ("cold", "first_program", "miss"), ("cold", "second_program", "miss"),
+    ("warm", "first_program", "hit"), ("warm", "third__program", "miss"),
+    ("off", "first_program", "off")])
+def test_a_compile_span_says_how_the_persistent_cache_answered(
+        cache_runs, run, program, cache):
+    """`cache` on a program's backend-compile span: `miss` at its first
+    compile under a persistent cache, `hit` in a fresh process with what the
+    read cost, `off` without a cache; and the facts of one program never
+    ride the next one's span: the miss that follows a hit back to back
+    carries no `retrieval_us`."""
+    spans = cache_runs[run]
+    assert sorted(spans) == sorted(f"jit({p})" for p in (
+        {"cold": ("first_program", "second_program"),
+         "warm": ("first_program", "third__program"),
+         "off": ("first_program",)}[run])), spans
+    args = spans[f"jit({program})"]
+    assert args["cache"] == cache, spans
+    if cache == "hit":
+        assert args["retrieval_us"] > 0
+    else:
+        assert "retrieval_us" not in args, args
 
 
 def test_a_list_form_programs_spans_count_its_layer_bodies():

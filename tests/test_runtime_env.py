@@ -338,6 +338,7 @@ def test_envfile_materialized_at_spawn(tmp_path, monkeypatch):
             self._starting = []
             self._starting_env = {}
             self._starting_envfile = {}
+            self._spawn_us = {}
 
     sh = Shell()
     sh._launch_worker("python3", {"A": "1", "PATH": "/bin"},
