@@ -1,0 +1,18 @@
+# PR 59 call 2 (four chips): the tree as git would commit it (_check/final = git archive $(git write-tree)) against _check/parent
+# (git archive 62ba75d): `mistral7b-train-4chip` untraced at fresh seeds parent, final, final, parent (the first of a tree compiles
+# cold unless the machine's cache has it), then a traced pair; then two layers at the cell's widths, the committed program beside
+# `parent` (`fsdp._staged` handing its slice on as it is), loss and every gradient leaf bit for bit on the chip.
+OUT=/root/repo/chiprun_out/pr59/call2; mkdir -p $OUT
+run() { # tree label seed trace
+  (cd _check/$1 && timeout 900 python3 perfbench/run.py --workload mistral7b-train-4chip --seed $3 --seconds 51 --trace $4 > $OUT/$2.log 2>&1; echo "rc=$? $2 $(date +%T)"
+   cp .perfbench_out/mistral7b-train-4chip/last_run.json $OUT/last_run_$2.json 2>/dev/null
+   grep -a "^{" $OUT/$2.log | tail -1 | cut -c 1-900; grep -a "^\[setup\]\|^\[chips\]" $OUT/$2.log | cut -c 1-200)
+}
+run parent p1 5900000017 0
+run final f1 5900000017 0
+run final f2 5900000029 0
+run parent p2 5900000029 0
+run parent p_traced 5900000041 1
+run final f_traced 5900000041 1
+for f in p_traced f_traced; do grep -a "^{" $OUT/$f.log | tail -1 > $OUT/line_$f.json; done
+(cd _check/final && python3 ci/chip_calls/pr59/step_forms.py --forms change --steps 2 --same-bits --out $OUT/bits > $OUT/bits.log 2>&1; grep -a '^{' $OUT/bits.log | cut -c 1-600)

@@ -310,7 +310,22 @@ def _reduce_scatter_dws(xs, dys, dim: int, r, taken=None):
     kept products that needed neither standing behind the wait (5 ms of a
     308 ms step at Mistral-7B widths on fsdp 2 x tp 2; PERF.md section 6,
     PR 57). An `optimization_barrier` does not do: it steers what fuses
-    and is gone before the scheduler runs."""
+    and is gone before the scheduler runs.
+
+    What a barrier does do: a kept product that stands in that order reads
+    its slice from an array of its own (`_staged`), made straight before it
+    (the offset's `taken` keeps the slice, too, behind the ring before).
+    Fused into the product, gate's and up's slice is of two chunks of x laid
+    end to end, and the product wants BOTH whole in fast memory to read half
+    their columns; the one that arrived over `tp` at the body's head the
+    compiler has evicted to HBM by the tail, it fetches it back for the
+    first of the twins and lets that copy die there, and the tiler halves
+    the second's output window for an operand in HBM: 10.23 ms a step where
+    its twin takes 7.28. A staged slice is half the bytes, born in fast
+    memory and dead one instruction on, and the arrived chunk is never
+    evicted: 7.12 and 7.13 (PERF.md section 6, PR 59). Every ring stages,
+    not those two alone: the five other slices cost 0.95 ms a step and
+    their products run 0.8 faster for an operand that is one array."""
     n = jax.lax.axis_size(AXIS)
     ring = _ring(n)
 
@@ -321,6 +336,8 @@ def _reduce_scatter_dws(xs, dys, dim: int, r, taken=None):
         if behind is not None:
             at = at + behind.astype(at.dtype)
         part = jax.lax.dynamic_slice_in_dim(cut, at, size, 2)
+        if behind is not None:
+            part = _staged(part)
         return jnp.einsum("prk,prn->pkn", *((part, dy), (x, part))[dim])
 
     accs = []
@@ -332,6 +349,12 @@ def _reduce_scatter_dws(xs, dys, dim: int, r, taken=None):
                 taken = _zero_read_off(acc)
         accs.append(acc)
     return accs, taken
+
+
+def _staged(part: jax.Array) -> jax.Array:
+    """`part` as an array of its own: the barrier keeps what makes it out of
+    the fusion of what reads it."""
+    return jax.lax.optimization_barrier(part)
 
 
 def _zero_read_off(a: jax.Array) -> jax.Array:

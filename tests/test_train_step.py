@@ -270,7 +270,10 @@ def test_ordered_dw_rings_change_no_bit(mesh_cfg, n, dtype, monkeypatch):
     back as a cotangent). The zero is a zero: the loss and EVERY gradient
     leaf are bit for bit those of the program whose rings know of no order
     (the parent's form: `weight_grads` never handed `taken` here), in
-    float32 and in bfloat16, at fsdp 2 and round a ring of three steps. With
+    float32 and in bfloat16, at fsdp 2 and round a ring of three steps. A
+    kept product that stands in the order reads its slice from an array of
+    its own (`fsdp._staged`: one `optimization_barrier` for each of the
+    seven rings' fsdp - 1 kept products); the slice is the slice. With
     `tp` 1 the products are the partitioner's, every ring is alone in its
     backward body, and none is handed an order at all."""
     from ray_tpu.models import transformer
@@ -292,6 +295,8 @@ def test_ordered_dw_rings_change_no_bit(mesh_cfg, n, dtype, monkeypatch):
     # the four backward bodies of a layer's products, or none of the seven
     assert handed.count(True) == 4 * ours and len(handed) == (4 if ours else 7)
     assert (text != parent_text) == bool(ours)
+    pins = lambda t: t.count("optimization_barrier")
+    assert pins(text) - pins(parent_text) == ours * 7 * (mesh_cfg.fsdp - 1)
     _assert_same_bits(loss, grads, parent_loss, parent_grads)
 
 
