@@ -14,7 +14,10 @@ of `eva_chunk` positions of every earlier window; EvaByte's) and learned
 sparse attention (`ops/dsa.py`: rotary grouped-query attention with an
 RMSNorm a head on q and k, whose every query attends to the `dsa_topk` rows
 an indexer of `dsa_heads` small heads scores highest, exactly;
-Keye-VL-2.0's); the FFNs: dense SwiGLU and a dropless top-k expert layer
+Keye-VL-2.0's), and sliding-window beside full attention (grouped-query,
+a window layer rotary over interleaved pairs under a band of `swa_window`
+positions, a full layer without positions over every row; Command A+'s);
+the FFNs: dense SwiGLU and a dropless top-k expert layer
 (`ops/moe.py:dropless_moe`) that is told which experts it holds, beside a
 shared MLP or (`n_shared` 0) none, routed by sigmoid scores + bias or by a
 softmax over the chosen logits (`router`).
@@ -63,6 +66,16 @@ Two ways to hold and run the stack, by what the configuration lists:
   `untied_head`): the runs form with ONE run. A prompt pass of more than
   `dsa_topk` positions scores, selects and attends by two kernels that never
   hold an [n, n] score in HBM; its prompts come in whole chunks.
+- `swa_layers` / `full_layers` (the Command A+ family, the fifth: window and
+  full attention mixers 3 : 1, each over a scanned expert layer routed by
+  sigmoid scores beside the MEAN of the shared experts, the head tied): the
+  runs form with a run a stretch of like layers, and a block of its own:
+  ONE LayerNorm a layer (mean-centred, no bias) whose rows mixer and FFN
+  both read, `x += Attn(h) + FFN(h)`. Its prompt pass walks the sequence a
+  CHUNK of one window at a time, every chunk through all layers, the rows
+  the chunks leave as its carry (`_sequence_swa`); every prompt past a
+  window passes by ONE program whose loop walks the chunks that hold a
+  token.
 
 Three call modes over the same weights:
 
@@ -76,7 +89,7 @@ Three call modes over the same weights:
 `decode_step`   one token for every slot from the slots' state, greedy
                 sampling on device, state DONATED and rewritten in place.
 
-A slot's state is of four kinds. An attention keeps a row a position for
+A slot's state is of five kinds. An attention keeps a row a position for
 ever (K/V, or a latent row); a recurrent mixer keeps a state of fixed size
 (KDA's S [H, dk, dv], Mamba-1's [d_state, d_inner], 0.33 MB a layer at
 Jamba's widths, Mamba-2's [N, H P], a matrix a head, 4.19 MB a layer at
@@ -88,9 +101,13 @@ by the length, never cleared), and a summary a closed chunk, a row every
 Sparse attention keeps TWO rows a position for ever: the K/V block and,
 beside it, a key for the selector (the indexer) that decides which K/V
 blocks a later query reads: a decode step scores all n of a slot's indexer
-keys and reads `dsa_topk` of its n K/V blocks.
+keys and reads `dsa_topk` of its n K/V blocks. A stack of window and full
+attention layers keeps rows that differ BY LAYER KIND: a full layer a row a
+position for ever, a window layer a RING of `swa_window` rows (position n
+at row n % W, written over the row that left), read ACROSS its wrap: the
+min(n + 1, W) keys of the window whatever their places in the ring.
 
-`HybridCache` (list form), `RunsCache`, `EvaCache` and `DsaCache` (runs form) are this
+`HybridCache` (list form), `RunsCache`, `EvaCache`, `DsaCache` and `SwaCache` (runs form) are this
 family's implementations of the engine's per-slot state interface
 (`models/serving.py`, "the cache interface").
 """
@@ -109,9 +126,10 @@ import numpy as np
 
 from ray_tpu.models.inference import _gqa_decode_attention
 from ray_tpu.ops import dsa, eva, kda, mamba, mla, ssd
-from ray_tpu.ops.attention import attention, causal_attention_blocked
+from ray_tpu.ops.attention import (attention, banded_attention,
+                                   causal_attention_blocked)
 from ray_tpu.ops.cache import write_rows
-from ray_tpu.ops.layers import rms_norm, swiglu
+from ray_tpu.ops.layers import layer_norm, rms_norm, rotate_interleaved, swiglu
 from ray_tpu.ops.moe import dropless_moe, route_softmax_top_k, route_top_k
 from ray_tpu.ops.pallas import decode_attention, eva_decode
 from ray_tpu.util import tracing
@@ -207,6 +225,21 @@ class HybridConfig:
     dsa_head_dim: int = 64
     dsa_chunk: int = 512
     untied_head: bool = False                 # the runs form: a head of its own
+    # sliding-window and full attention mixers in ONE stack (the runs form;
+    # a configuration that lists `swa_layers` or `full_layers` is of
+    # Command A+'s family):
+    # grouped-query attention (`n_heads`, `n_kv_heads`, `head_dim`), the
+    # `swa_layers` rotary over INTERLEAVED pairs (`rope_theta`) under a
+    # window of `swa_window` positions that counts the query's own, the
+    # `full_layers` without positions over every row. ONE LayerNorm a layer
+    # (mean-centred, no bias) that mixer and FFN both read: `x += Attn(h) +
+    # FFN(h)`. Every FFN is an expert layer routed by sigmoid scores without
+    # a bias, beside a shared MLP of `n_shared` experts whose MEAN is added;
+    # the head is the embedding behind a LayerNorm. The prompt pass walks
+    # one window at a time
+    swa_layers: Tuple[int, ...] = ()
+    full_layers: Tuple[int, ...] = ()
+    swa_window: int = 4096
 
     @staticmethod
     def tiny_hybrid() -> "HybridConfig":
@@ -263,6 +296,16 @@ class HybridConfig:
                             rope_theta=1e4, n_shared=0, router="softmax",
                             untied_head=True, norm_eps=1e-6)
 
+    @staticmethod
+    def tiny_swa() -> "HybridConfig":
+        """Two periods of window x 3, full: a window of 8 positions, 8 query
+        heads on 2 key heads, every FFN 8 experts (top-2 by sigmoid score,
+        renormalised) beside the mean of 2 shared experts."""
+        return HybridConfig(vocab_size=96, n_layers=8, kda_layers=(), first_dense=0,
+                            swa_layers=(1, 2, 3, 5, 6, 7), full_layers=(4, 8),
+                            swa_window=8, n_heads=8, n_kv_heads=2, rope_theta=5e4,
+                            n_shared=2, route_scale=1.0)
+
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
         def mixer(i):
             return ("kda" if i in self.kda_layers else
@@ -270,14 +313,21 @@ class HybridConfig:
                     "mamba2" if i in self.mamba2_layers else
                     "attn" if i in self.attn_layers else
                     "eva" if i in self.eva_layers else
-                    "dsa" if i in self.dsa_layers else "mla")
+                    "dsa" if i in self.dsa_layers else
+                    "swa" if i in self.swa_layers else
+                    "full" if i in self.full_layers else "mla")
         return tuple((mixer(i), "dense" if i <= self.first_dense else "moe")
                      for i in range(1, self.n_layers + 1))
 
     @property
     def scanned(self) -> bool:
         return bool(self.mamba_layers or self.mamba2_layers or self.attn_layers
-                    or self.eva_layers or self.dsa_layers)
+                    or self.eva_layers or self.dsa_layers or self.windowed)
+
+    @property
+    def windowed(self) -> bool:
+        """A stack of window and full attention layers (Command A+'s family)."""
+        return bool(self.swa_layers or self.full_layers)
 
     @property
     def ssd_inner(self) -> int:
@@ -312,13 +362,26 @@ class HybridConfig:
                     "over the same kind of FFN, with rotary positions and whole "
                     f"query groups; not {sorted(set(kinds))}, rope_theta "
                     f"{self.rope_theta}, {self.n_heads}:{self.n_kv_heads} heads")
+        elif self.windowed:
+            # two row caches (a ring a window layer, a row a position a full
+            # layer) that the prompt pass walks a chunk at a time
+            if not set(kinds) <= {("swa", "moe"), ("full", "moe")} \
+                    or not self.rope_theta or self.n_heads % self.n_kv_heads \
+                    or self.router != "sigmoid":
+                raise ValueError(
+                    "window (swa) and full attention mixers stand each over an "
+                    "expert layer routed by sigmoid scores, with rotary "
+                    "positions and whole query groups; not "
+                    f"{sorted(set(kinds))}, rope_theta {self.rope_theta}, "
+                    f"{self.n_heads}:{self.n_kv_heads} heads, router {self.router}")
         elif any(m not in ("mamba", "mamba2", "attn") for m, _ in kinds) \
                 or self.ssd_heads % 2:
             raise ValueError(
                 "a stack of scanned runs holds Mamba-1, Mamba-2 (an even number "
                 "of heads, one group) and attention mixers, each over a dense "
                 "FFN or an expert layer, or ONE run of EVA or of "
-                f"sparse-attention (dsa) mixers, not {sorted(set(kinds))} with "
+                "sparse-attention (dsa) mixers, or window (swa) and full "
+                f"attention mixers, not {sorted(set(kinds))} with "
                 f"{self.ssd_heads} SSD heads")
         out: List[Tuple[str, str, int]] = []
         for kind in kinds:
@@ -338,6 +401,7 @@ class HybridConfig:
     def make_cache(self, num_slots: int, max_len: int):
         """This model's per-slot state for `ContinuousBatchingEngine`."""
         kind = EvaCache if self.eva_layers else DsaCache if self.dsa_layers \
+            else SwaCache if self.windowed \
             else RunsCache if self.scanned else HybridCache
         return kind(self, num_slots, max_len)
 
@@ -452,6 +516,8 @@ def _init_runs(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
         return (jax.random.normal(key(), shape, F32) * fan_in ** -0.5).astype(dt)
 
     def norm(shape):
+        if cfg.windowed:   # around 1: a LayerNorm that drops its weight shows
+            return (1.0 + 0.1 * jax.random.normal(key(), shape, F32)).astype(dt)
         if not cfg.norm_unit_offset:
             return jnp.ones(shape, dt)
         return jax.random.uniform(key(), shape, F32, -0.1, 0.1).astype(dt)
@@ -462,13 +528,17 @@ def _init_runs(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
 
     runs: List[Dict[str, Any]] = []
     for (mixer, k), ffn in zip(cfg.runs(), cfg.run_ffns()):
-        p: Dict[str, Any] = {"mixer_norm": norm((k, d)), "ffn_norm": norm((k, d))}
+        p: Dict[str, Any] = {"mixer_norm": norm((k, d))}
+        if not cfg.windowed:   # whose ONE norm a layer both halves read
+            p["ffn_norm"] = norm((k, d))
         if ffn == "dense":
             p["ffn"] = swiglu_w(cfg.d_ff, (k,))
         else:
-            if cfg.router != "softmax":
+            if cfg.router != "softmax" and not cfg.windowed:
                 raise ValueError("a scanned expert layer routes by a softmax "
-                                 "over the chosen logits")
+                                 "over the chosen logits, or (window and full "
+                                 "attention mixers) by sigmoid scores without "
+                                 "a bias")
             p["moe"] = {"router": w((k, d, cfg.n_experts), d),
                         **swiglu_w(cfg.d_expert, (k, len(cfg.experts_held)))}
             if cfg.n_shared:
@@ -588,8 +658,10 @@ def _residual(cfg: HybridConfig, p, post_norm: str, x, y):
 
 def _normed(cfg: HybridConfig, x, w):
     """The float32 residual x, normed: (in float32 for the router, in the
-    weights' type for the matrix products)."""
-    h32 = rms_norm(x, w, cfg.norm_eps, cfg.norm_unit_offset)
+    weights' type for the matrix products). A stack of window and full
+    attention layers norms by a LayerNorm."""
+    h32 = layer_norm(x, w, cfg.norm_eps) if cfg.windowed \
+        else rms_norm(x, w, cfg.norm_eps, cfg.norm_unit_offset)
     return h32, h32.astype(cfg.dtype)
 
 
@@ -612,15 +684,21 @@ def _ffn(cfg: HybridConfig, p, h32, h, valid, stacks=None, layer=None):
         if cfg.router == "softmax":
             idx, w = route_softmax_top_k(h32, m["router"], cfg.top_k)
         else:
-            idx, w = route_top_k(h32, m["router"], m["bias"], cfg.top_k,
-                                 cfg.route_scale, cfg.renormalize)
+            # (a router that holds no bias chooses by its scores alone)
+            idx, w = route_top_k(
+                h32, m["router"],
+                m["bias"] if "bias" in m else jnp.zeros((cfg.n_experts,), F32),
+                cfg.top_k, cfg.route_scale, cfg.renormalize)
         y, landed, touched = dropless_moe(
             h, idx, w, *(stacks or (m["w_gate"], m["w_up"], m["w_down"])),
             cfg.experts_held, cfg.n_experts, valid, layer)
     if "shared" in m:
         with jax.named_scope("shared_expert"):
             s = m["shared"]
-            y = y + swiglu(h @ s["w_gate"], h @ s["w_up"]) @ s["w_down"]
+            shared = swiglu(h @ s["w_gate"], h @ s["w_up"]) @ s["w_down"]
+            if cfg.windowed:   # the MEAN of the shared experts, held as one MLP
+                shared = shared * (1.0 / cfg.n_shared)
+            y = y + shared
     return y, landed, touched, idx
 
 
@@ -807,8 +885,22 @@ def _run_ffn(cfg: HybridConfig, lp, x, valid, stacks=None, layer=None):
     padding and no idle slot; `stacks`, `layer` as `_ffn` (`_expert_stacks`)."""
     if "ffn" in lp:
         return _dense_ffn(cfg, lp, x), ()
-    h32, h = _normed(cfg, x, lp["ffn_norm"])
-    d = x.shape[-1]
+    y, *counted = _routed_ffn(cfg, lp, *_normed(cfg, x, lp["ffn_norm"]), valid,
+                              stacks, layer)
+    return _add(cfg, x, y), _routed_ys(x.shape[:-1], *counted)
+
+
+def _routed_ys(lead, landed, touched, chosen):
+    """What an expert layer adds to its scan's ys (`_run_ffn`)."""
+    return jnp.stack([landed, touched]), chosen.reshape(lead + (-1,))
+
+
+def _routed_ffn(cfg: HybridConfig, lp, h32, h, valid, stacks=None, layer=None):
+    """A run's expert layer over the normed residual h [..., d] (h32: the
+    same in float32) -> (its output [..., d], assignments landed, experts
+    touched, the experts chosen [tokens, k]: `_routed_ys` makes the scan's
+    ys of the three)."""
+    lead, d = h.shape[:-1], h.shape[-1]
     h32, h, ok = h32.reshape(-1, d), h.reshape(-1, d), valid.reshape(-1)
     T = h.shape[0]
     if T > _FFN_BLOCK and T % _FFN_BLOCK == 0:
@@ -824,8 +916,7 @@ def _run_ffn(cfg: HybridConfig, lp, x, valid, stacks=None, layer=None):
         landed, touched = jnp.sum(landed), jnp.sum(touched)
     else:
         y, landed, touched, chosen = _ffn(cfg, lp, h32, h, ok, stacks, layer)
-    return (_add(cfg, x, y.reshape(x.shape)),
-            (jnp.stack([landed, touched]), chosen.reshape(x.shape[:-1] + (-1,))))
+    return y.reshape(lead + (d,)), landed, touched, chosen
 
 
 def _expert_stacks(rp):
@@ -1000,6 +1091,22 @@ def _dsa_inputs(cfg: HybridConfig, a, h, positions):
     return q, k, v.reshape(lead + (cfg.n_kv_heads, hd)), qi, wi, ki
 
 
+def _swa_qkv(cfg: HybridConfig, a, h, positions):
+    """h [..., s, d] -> q [..., s, H, hd], k, v [..., s, kvh, hd] in the
+    configuration's type. A window layer hands its `positions` [..., s] and
+    has q and k rotated there over interleaved pairs; a full layer hands
+    None and has none."""
+    # flat products that read their weight in place (`_eva_qkv` says why)
+    lead, hd = h.shape[:-1], cfg.head_dim
+    q, k, v = jax.lax.optimization_barrier((h @ a["wq"], h @ a["wk"], h @ a["wv"]))
+    q = q.reshape(lead + (cfg.n_heads, hd))
+    k = k.reshape(lead + (cfg.n_kv_heads, hd))
+    if positions is not None:
+        q = rotate_interleaved(q, positions, cfg.rope_theta)
+        k = rotate_interleaved(k, positions, cfg.rope_theta)
+    return q, k.astype(cfg.dtype), v.reshape(lead + (cfg.n_kv_heads, hd)).astype(cfg.dtype)
+
+
 def _kv_row(k, v):
     """k, v [..., kvh, hd] -> the position's cache block [..., 2 kvh, hd]."""
     return jnp.concatenate([k, v], axis=-2)
@@ -1078,6 +1185,8 @@ def _sequence_runs(params, tokens, true_len, cfg: HybridConfig):
         return _sequence_eva(params, tokens, true_len, cfg)
     if cfg.dsa_layers:
         return _sequence_dsa(params, tokens, true_len, cfg)
+    if cfg.windowed:
+        return _sequence_swa(params, tokens, true_len, cfg)
     b, s = tokens.shape
     H, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     x = params["embed"][tokens].astype(F32)
@@ -1255,6 +1364,139 @@ def _sequence_eva(params, tokens, true_len, cfg: HybridConfig,
     return x, state, []
 
 
+def _sequence_swa(params, tokens, true_len, cfg: HybridConfig,
+                  last_only: bool = False, with_routing: bool = False):
+    """`_sequence` for a stack of window and full attention layers. The
+    sequence goes a CHUNK of one window (`swa_window` positions) at a time,
+    each chunk through every layer,
+    the rows the chunks leave as the walk's carry: a full layer's chunk
+    writes its rows into "k", "v" [full layers, b, kvh, s, hd] and attends
+    every row up to its own; a window layer's chunk keeps the chunk before
+    it beside itself, [window layers, b, kvh, 2 chunk, hd], and attends both
+    under the band. Activations are those of one chunk whatever `s`. A
+    sequence of no more than a chunk is one chunk and walks nothing.
+
+    Without `last_only` (`forward`) every chunk of `s` is walked by a scan
+    and the hidden rows come back [b, s, d]. With it (the prompt pass, ONE
+    prompt a call where it walks) the walk is a loop over the chunks that
+    hold a true position, so one program serves every prompt length of a
+    bucket, and the hidden row at `true_len - 1` comes back alone [b, 1, d].
+
+    State rows: "k", "v" as above (rows past `true_len` hold whatever the
+    padding left: masked by the length); "wk", "wv" [window layers, b, kvh,
+    W, hd]: the last W positions before `true_len`, position p at row p % W
+    (a prompt shorter than W leaves the rows from `true_len` on stale).
+    `with_routing` (with `last_only`): the experts every position chose,
+    [layers, b, s, k]."""
+    b, s = tokens.shape
+    W, H, kvh, hd = cfg.swa_window, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    scale = cfg.attn_scale or hd ** -0.5
+    runs = cfg.runs()
+    n_swa = sum(k for m, k in runs if m == "swa")
+    one = s <= W
+    L = s if one else W                           # positions a chunk
+    if s % L or (not one and last_only and b != 1):
+        raise ValueError(f"a prompt pass of {b} x {s} positions walks whole "
+                         f"chunks of {L}, one prompt a call")
+    split = [_expert_stacks(rp) for rp in params["runs"]]
+
+    def chunk(c, toks, caches):
+        """Chunk c (tokens [b, L]) through every layer -> (hidden rows
+        [b, L, d], the caches with its rows, the experts chosen
+        [layers, b, L, k])."""
+        positions = c * L + jnp.arange(L)
+        valid = positions[None, :] < true_len[:, None]
+        if not one:   # the chunk before moves to the first half
+            caches = dict(caches, **{n: jnp.concatenate(
+                [caches[n][:, :, :, L:], caches[n][:, :, :, :L]], axis=3)
+                for n in ("wk", "wv")})
+
+        def layer_of(mixer, stacks, first):
+            window = mixer == "swa"
+            # where the chunk's rows go in its cache = its first query's column
+            at = 0 if one else L if window else c * L
+            k_lo = jnp.where(c == 0, at, 0) if window else 0
+
+            def layer(carry, xs):
+                x, ck, cv = carry
+                lp, i = xs
+                with jax.named_scope("swa"), \
+                        jax.named_scope("window" if window else "full"):
+                    h32, h = _normed(cfg, x, lp["mixer_norm"])
+                    a = lp[mixer]
+                    q, k, v = _swa_qkv(cfg, a, h, positions if window else None)
+                    put = lambda rows, new: jax.lax.dynamic_update_slice(
+                        rows, jnp.moveaxis(new, 1, 2)[None], (first + i, 0, 0, at, 0))
+                    ck, cv = put(ck, k), put(cv, v)
+                    attn = banded_attention(
+                        jnp.moveaxis(q, 1, 2), ck, cv, first + i, at, k_lo,
+                        window=W if window else None, sm_scale=scale)
+                    mixed = jnp.moveaxis(attn, 1, 2).reshape(b, L, H * hd) @ a["wo"]
+                routed, *counted = _routed_ffn(cfg, lp, h32, h, valid, stacks, i)
+                return ((x + mixed.astype(F32) + routed.astype(F32), ck, cv),
+                        _routed_ys(x.shape[:-1], *counted))
+            return layer
+
+        x = params["embed"][toks].astype(F32)
+        seen = {"swa": 0, "full": 0}
+        chosen = []
+        for (rp, stacks), (mixer, k) in zip(split, runs):
+            names = ("wk", "wv") if mixer == "swa" else ("k", "v")
+            (x, *rows), ys = jax.lax.scan(
+                layer_of(mixer, stacks, seen[mixer]),
+                (x, *(caches[n] for n in names)), (rp, jnp.arange(k)))
+            caches = dict(caches, **dict(zip(names, rows)))
+            seen[mixer] += k
+            chosen.append(ys[1])
+        return x, caches, jnp.concatenate(chosen)
+
+    zeros = lambda layers, rows: jnp.zeros((layers, b, kvh, rows, hd), cfg.dtype)
+    caches = {"k": zeros(cfg.n_layers - n_swa, s), "v": zeros(cfg.n_layers - n_swa, s),
+              "wk": zeros(n_swa, L if one else 2 * L),
+              "wv": zeros(n_swa, L if one else 2 * L)}
+    routing = None
+    if one:
+        x, caches, routing = chunk(0, tokens, caches)
+        if last_only:
+            x = jnp.take_along_axis(x, (true_len - 1)[:, None, None], axis=1)
+    elif not last_only:
+        toks = jnp.moveaxis(tokens.reshape(b, s // L, L), 1, 0)
+        caches, x = jax.lax.scan(
+            lambda caches, t: chunk(t[1], t[0], caches)[1::-1], caches,
+            (toks, jnp.arange(s // L)))
+        x = jnp.moveaxis(x, 0, 1).reshape(b, s, -1)
+        n_chunks = s // L
+    else:
+        n_chunks = jnp.max(-(-true_len // L))
+
+        def walk(c, carry):
+            caches, last, routing = carry
+            x, caches, chosen = chunk(
+                c, jax.lax.dynamic_slice(tokens, (0, c * L), (b, L)), caches)
+            here = jnp.take_along_axis(
+                x, jnp.clip(true_len - 1 - c * L, 0, L - 1)[:, None, None], axis=1)
+            last = jnp.where(((true_len - 1) // L == c)[:, None, None], here, last)
+            if with_routing:
+                routing = jax.lax.dynamic_update_slice(routing, chosen, (0, 0, c * L, 0))
+            return caches, last, routing
+
+        caches, x, routing = jax.lax.fori_loop(
+            0, n_chunks, walk,
+            (caches, jnp.zeros((b, 1, cfg.d_model), F32),
+             jnp.zeros((cfg.n_layers, b, s, cfg.top_k), jnp.int32)
+             if with_routing else None))
+    # the ring: row r takes the last position before `true_len` that is r
+    # mod W; the window rows held are positions base, base + 1, ...
+    base = 0 if one else (n_chunks - 2) * L
+    r = jnp.arange(W)[None, :]
+    p_of = true_len[:, None] - 1 - (true_len[:, None] - 1 - r) % W       # [b, W]
+    at = jnp.clip(p_of - base, 0, caches["wk"].shape[3] - 1)[None, :, None, :, None]
+    rows = {"k": caches["k"], "v": caches["v"],
+            "wk": jnp.take_along_axis(caches["wk"], at, axis=3),
+            "wv": jnp.take_along_axis(caches["wv"], at, axis=3)}
+    return x, rows, [] if routing is None else [routing]
+
+
 def _head(params, x, cfg: HybridConfig = None, all_heads: bool = False):
     """Features -> logits float32: the untied head, or the embedding. A head
     of several predictions (`n_pred_heads`: head j at position i scores the
@@ -1327,6 +1569,10 @@ def prefill(params, tokens, true_len, cfg: HybridConfig,
     elif cfg.dsa_layers:
         x, rows, routing = _sequence_dsa(params, tokens, true_len, cfg, with_routing)
         last = pick(x)
+    elif cfg.windowed:
+        x, rows, routing = _sequence_swa(params, tokens, true_len, cfg,
+                                         last_only=True, with_routing=with_routing)
+        last = x[:, 0]
     else:
         x, rows, routing = _sequence(params, tokens, true_len, cfg)
         last = pick(x)
@@ -1665,6 +1911,90 @@ def _decode_dsa(params, state, lengths, tokens, cfg: HybridConfig,
             [ys[3]] if stacks else [], ys[-3:] if with_rows else None)
 
 
+def _decode_swa(params, state, lengths, tokens, cfg: HybridConfig,
+                attn_len: int):
+    """`_decode_runs` for a stack of window and full attention layers ->
+    (state, logits [B, vocab] float32, [assignments landed, experts
+    touched], the experts chosen [[layers, B, top_k]]). A busy slot's token
+    stands at position n = its length. Inside the scans both caches are
+    read-only and the token's own row is a term of its own: a full layer
+    attends the slot's n rows; a window layer attends the min(n, W) live
+    rows of the slot's RING but the one at n % W once the ring has wrapped
+    (it holds position n - W, which leaves the window as n enters), so
+    min(n + 1, W) keys in all. Rotated keys carry their positions, so the
+    ring's order is free. Then every layer's row is written once: a full
+    layer's at row n, a window layer's at row n % W, over the row that left.
+    A slot of length 0 is idle: it reads and writes nothing."""
+    B = tokens.shape[0]
+    W, H, kvh, hd = cfg.swa_window, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    x = params["embed"][tokens].astype(F32)
+    busy = lengths > 0
+    held = jnp.minimum(lengths, W)
+    left = jnp.where(lengths >= W, lengths % W, -1)     # the row that left
+    kernel = decode_attention.uses_decode_kernel(state["k"], attn_len) \
+        and decode_attention.uses_decode_kernel(state["wk"], W)
+    if kernel:
+        walks = {"full": decode_attention.live_items(lengths, attn_len),
+                 "swa": decode_attention.live_items(held, W)}
+    else:
+        at = lambda n: jnp.arange(n)[None, :]
+        masks = {"full": at(attn_len) < lengths[:, None],
+                 "swa": (at(W) < held[:, None]) & (at(W) != left[:, None])}
+
+    def layer_of(mixer, stacks, first):
+        window = mixer == "swa"
+        k_all, v_all = (state["wk"], state["wv"]) if window else (state["k"], state["v"])
+        rows = W if window else attn_len
+
+        def layer(x, xs):
+            lp, i = xs
+            with jax.named_scope("swa"), jax.named_scope("window" if window else "full"):
+                h32, h = _normed(cfg, x, lp["mixer_norm"])
+                a = lp[mixer]
+                q, k_cur, v_cur = (t[:, 0] for t in _swa_qkv(
+                    cfg, a, h[:, None], lengths[:, None] if window else None))
+                if kernel:
+                    attn = decode_attention.gqa_decode_attention(
+                        q.reshape(B, kvh, H // kvh, hd), k_cur, v_cur, k_all, v_all,
+                        first + i, walks[mixer], rows, cfg.attn_scale,
+                        skip=left if window else None)
+                else:
+                    read = (1, B, kvh, rows, hd)
+                    attn = _gqa_decode_attention(
+                        q[:, :, None],
+                        jax.lax.dynamic_slice(k_all, (first + i, 0, 0, 0, 0), read)[0],
+                        jax.lax.dynamic_slice(v_all, (first + i, 0, 0, 0, 0), read)[0],
+                        k_cur, v_cur, masks[mixer], cfg.attn_scale)
+                mixed = attn.reshape(B, H * hd).astype(cfg.dtype) @ a["wo"]
+            routed, *counted = _routed_ffn(cfg, lp, h32, h, busy, stacks, i)
+            return (x + mixed.astype(F32) + routed.astype(F32),
+                    (k_cur, v_cur) + _routed_ys(x.shape[:-1], *counted))
+        return layer
+
+    cur = {"swa": ([], []), "full": ([], [])}
+    seen = {"swa": 0, "full": 0}
+    counts, routing = [], []
+    for rp, (mixer, k) in zip(params["runs"], cfg.runs()):
+        rp, stacks = _expert_stacks(rp)
+        x, (k_cur, v_cur, landed, chosen) = jax.lax.scan(
+            layer_of(mixer, stacks, seen[mixer]), x, (rp, jnp.arange(k)))
+        seen[mixer] += k
+        cur[mixer][0].append(k_cur)
+        cur[mixer][1].append(v_cur)
+        counts.append(jnp.sum(landed, axis=0))
+        routing.append(chosen)
+    with jax.named_scope("state_write"):
+        new = {name: write_rows(state[name], jnp.concatenate(cur[mixer][j]), at, writes)
+               if cur[mixer][j] else state[name]     # a kind without a layer
+               for mixer, at, writes, names in (
+                   ("full", lengths, None, ("k", "v")),
+                   ("swa", lengths % W, busy, ("wk", "wv")))
+               for j, name in enumerate(names)}
+    with jax.named_scope("head"):
+        logits = _head(params, _normed(cfg, x, params["final_norm"])[1], cfg)
+    return new, logits, sum(counts), [jnp.concatenate(routing)]
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "attn_len"),
                    donate_argnums=(1,))
 @_layered_program
@@ -1688,8 +2018,9 @@ def decode_logits(params, state, lengths, tokens, active, cfg: HybridConfig,
             state, logits, _ = _decode_eva(params, state, lengths, tokens, cfg,
                                            attn_len)
         else:
-            state, logits, _, routing = _decode_runs(params, state, lengths, tokens,
-                                                     cfg, attn_len)
+            state, logits, _, routing = (
+                _decode_swa if cfg.windowed else _decode_runs)(
+                    params, state, lengths, tokens, cfg, attn_len)
             if routing:
                 return state, logits, jnp.concatenate(routing)
         return state, logits, jnp.zeros((0, tokens.shape[0], 0), jnp.int32)
@@ -1749,8 +2080,9 @@ def decode_step(params, state, lengths, tokens, active, cfg: HybridConfig,
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return state, lengths + (lengths > 0), nxt, jnp.concatenate([nxt, closed])
     if cfg.scanned:
-        state, logits, counters, *_ = (_decode_dsa if cfg.dsa_layers else _decode_runs)(
-            params, state, lengths, tokens, cfg, attn_len)
+        state, logits, counters, *_ = (
+            _decode_dsa if cfg.dsa_layers else _decode_swa if cfg.windowed
+            else _decode_runs)(params, state, lengths, tokens, cfg, attn_len)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         report = nxt if counters is None else jnp.concatenate([nxt, counters])
         return state, lengths + (lengths > 0), nxt, report
@@ -1791,6 +2123,8 @@ def _write_state(state, lengths, tokens, slots, rows, true_len, first):
     dropped. `tokens` is not donated (the step in flight reads it)."""
     if "ik" in state:
         return _write_dsa(state, lengths, tokens, slots, rows, true_len, first)
+    if "wk" in state:
+        return _write_swa(state, lengths, tokens, slots, rows, true_len, first)
     if "ssm" in state:
         return _write_runs(state, lengths, tokens, slots, rows, true_len, first)
     if "ek" in state:
@@ -1830,6 +2164,22 @@ def _write_dsa(state, lengths, tokens, slots, rows, true_len, first):
         kv, ik = rows["kv"], rows["ik"]
         state = {"kv": state["kv"].at[:, slots, :kv.shape[2]].set(kv, mode="drop"),
                  "ik": state["ik"].at[:, slots, :, :ik.shape[3]].set(ik, mode="drop")}
+    return (state, lengths.at[slots].set(true_len, mode="drop"),
+            tokens.at[slots].set(first, mode="drop"))
+
+
+def _write_swa(state, lengths, tokens, slots, rows, true_len, first):
+    """`_write_state` for a stack of window and full attention layers: a
+    full layer's rows over the first rows of the slots' tables, a window
+    layer's ring whole. What the last occupant left behind a short prompt
+    stays, masked by the length until the ring has wrapped over it."""
+    with jax.named_scope("state_write"):
+        bucket = rows["k"].shape[3]
+        kv = lambda whole, part: whole.at[:, slots, :, :bucket].set(part, mode="drop")
+        ring = lambda whole, part: whole.at[:, slots].set(part, mode="drop")
+        state = {"k": kv(state["k"], rows["k"]), "v": kv(state["v"], rows["v"]),
+                 "wk": ring(state["wk"], rows["wk"]),
+                 "wv": ring(state["wv"], rows["wv"])}
     return (state, lengths.at[slots].set(true_len, mode="drop"),
             tokens.at[slots].set(first, mode="drop"))
 
@@ -2054,3 +2404,70 @@ class DsaCache(RunsCache):
         it reads, a layer: min(n, `dsa_topk`) a slot."""
         return {"kv_rows": sum(positions), "index_rows": sum(positions),
                 "selected_rows": sum(min(n, self.cfg.dsa_topk) for n in positions)}
+
+
+class SwaCache(RunsCache):
+    """Per-slot state of a stack of window and full attention layers, the
+    fifth kind: a slot's live rows differ BY LAYER KIND inside one step. A
+    full layer keeps a row a position for ever, "k", "v" [full layers,
+    slots, kvh, max_len, hd]; a window layer keeps a RING of `swa_window`
+    rows, "wk", "wv" [window layers, slots, kvh, W, hd], position n at row
+    n % W: the step writes row n over row n - W and reads the min(n + 1, W)
+    rows of the window, ACROSS the wrap (rotated keys carry their positions,
+    so the ring's order is free). A new occupant's prompt leaves its last
+    min(n, W) rows at their ring places; what lies past a short prompt's
+    rows is stale and masked by the length until the ring has wrapped over
+    it. `max_len` positions cost a window layer W rows. Written by
+    `ops.cache.write_rows`, read by `ops.pallas.decode_attention` (a window
+    layer with the row that left named, `skip`).
+
+    Prompts of more than one window all pass at ONE bucket, the slot's
+    whole length, one prompt a call: the pass walks only the windows that
+    hold a token (`_sequence_swa`), so one program serves them all. Shorter
+    prompts take powers of two from 512. Where the decode kernel runs, the
+    step's one program is that of the slot's whole length (the kernel walks
+    live rows, whatever the bucket)."""
+
+    def __init__(self, cfg: HybridConfig, num_slots: int, max_len: int):
+        self.cfg, self.num_slots, self.max_len = cfg, num_slots, max_len
+        runs = cfg.runs()
+        self.n_swa = sum(k for m, k in runs if m == "swa")
+        self.n_full = cfg.n_layers - self.n_swa
+        rows = lambda layers, n: jnp.zeros(
+            (layers, num_slots, cfg.n_kv_heads, n, cfg.head_dim), cfg.dtype)
+        self.state = {"k": rows(self.n_full, max_len), "v": rows(self.n_full, max_len),
+                      "wk": rows(self.n_swa, cfg.swa_window),
+                      "wv": rows(self.n_swa, cfg.swa_window)}
+        self.prefill_args = {"window_layers": self.n_swa, "full_layers": self.n_full,
+                             "chunk": cfg.swa_window}
+        self.counters = ("expert_assignments", "experts_touched")
+
+    def prompt_bucket(self, n: int) -> int:
+        W = self.cfg.swa_window
+        if n > W:   # walked: whole windows of the slot's whole length
+            return self.max_len // W * W
+        b = min(512, W)
+        while b < n:
+            b *= 2
+        return min(b, W)
+
+    def max_prefill_batch(self, bucket: int) -> int:
+        return 1
+
+    def step_len(self, attn_len: int) -> int:
+        """The attention length the step's program is built for: the slot's
+        whole length where the decode kernel runs, else the bucket."""
+        if decode_attention.uses_decode_kernel(self.state["k"], self.max_len):
+            return self.max_len
+        return attn_len
+
+    def decode(self, params, lengths, tokens, attn_len, active_slots):
+        return super().decode(params, lengths, tokens, self.step_len(attn_len),
+                              active_slots)
+
+    def step_args(self, positions: List[int], attn_len: int) -> Dict[str, int]:
+        """The rows ONE layer of each kind reads for the busy slots, summed:
+        `window_rows` min(n, W) a slot, `full_rows` n a slot (a stack of
+        full layers would read `full_rows` in every layer)."""
+        return {"window_rows": sum(min(n, self.cfg.swa_window) for n in positions),
+                "full_rows": sum(positions)}

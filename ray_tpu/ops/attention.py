@@ -117,3 +117,38 @@ def causal_attention_blocked(q: jax.Array, k: jax.Array, v: jax.Array, *,
         pr = jax.nn.softmax(jnp.where(ok[None, None], sc, -1e30), axis=-1)
         outs.append(jnp.einsum("bhqk,bkhd->bqhd", pr.astype(v.dtype), v[:, :end]))
     return jnp.concatenate(outs, axis=1)
+
+
+def banded_attention(q: jax.Array, k: jax.Array, v: jax.Array, layer, q_start,
+                     k_lo, *, window: Optional[int], sm_scale: float) -> jax.Array:
+    """Causal grouped-query attention of a chunk's queries against one layer
+    of a row cache, under a band: q [b, H, sq, d]; k, v [layers, b, kvh, sk,
+    d]; `layer`, `q_start`, `k_lo` int32 scalars (data: one program serves
+    every chunk of a prompt). Query row r stands at key column c = r +
+    q_start and attends columns max(k_lo, c - window + 1) .. c of `layer`
+    (`window` None: from k_lo; columns below k_lo hold nothing). -> [b, H,
+    sq, d]. On a TPU at shapes that tile, the flash kernel
+    (`flash_attention_banded`: blocks outside the band are skipped, grouped
+    heads read through the index map); elsewhere a masked einsum over the
+    grouped heads. Neither copies K or V to the query heads."""
+    b, H, sq, d = q.shape
+    layers, _, kvh, sk, _ = k.shape
+    if uses_flash_kernel(q):
+        from ray_tpu.ops.pallas.flash_attention import flash_attention_banded
+
+        bounds = jnp.stack([jnp.asarray(a, jnp.int32) for a in (q_start, k_lo, layer)])
+        out = flash_attention_banded(
+            q.reshape(b * H, sq, d), k.reshape(layers, b * kvh, sk, d),
+            v.reshape(layers, b * kvh, sk, d), bounds, sm_scale, window)
+        return out.reshape(b, H, sq, d)
+    kl = jax.lax.dynamic_index_in_dim(k, layer, 0, keepdims=False)
+    vl = jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
+    qg = q.reshape(b, kvh, H // kvh, sq, d)
+    s = jnp.einsum("bgrqd,bgkd->bgrqk", qg, kl).astype(jnp.float32) * sm_scale
+    at = jnp.arange(sq)[:, None] + q_start
+    cols = jnp.arange(sk)[None, :]
+    ok = (cols <= at) & (cols >= k_lo)
+    if window is not None:
+        ok &= cols > at - window
+    p = jax.nn.softmax(jnp.where(ok, s, -1e30), axis=-1).astype(q.dtype)
+    return jnp.einsum("bgrqk,bgkd->bgrqd", p, vl).reshape(b, H, sq, d)

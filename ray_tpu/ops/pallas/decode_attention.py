@@ -119,7 +119,10 @@ def live_items(lengths: jax.Array, attn_len: int, rows: int = 0):
 
 def _kernel(layer_ref, slot_ref, block_ref, count_ref, held_ref,  # scalars
             q_ref, kc_ref, vc_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sem, acc_ref, m_ref, l_ref, *, rows: int, scale: float):
+            k_buf, v_buf, sem, acc_ref, m_ref, l_ref, *, rows: int, scale: float,
+            skip_ref=None):
+    """`skip_ref` [B] (a RING of rows that has wrapped, `gqa_decode_attention`'s
+    `skip`): the one row of each slot that is not attended, -1 for none."""
     layer, count = layer_ref[0], count_ref[0]
 
     def copies(item, buf):
@@ -168,9 +171,15 @@ def _kernel(layer_ref, slot_ref, block_ref, count_ref, held_ref,  # scalars
                 # the block holds the slot's last row: mask the scores past
                 # it, and zero V there (0 x whatever the row holds stays 0)
                 cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) + j * rows
-                s = jnp.where(cols < n, s, _NEG_INF)
+                live = cols < n
+                if skip_ref is not None:
+                    live = jnp.logical_and(live, cols != skip_ref[b])
+                s = jnp.where(live, s, _NEG_INF)
                 v_rows = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1) + j * rows
-                v = jnp.where(v_rows < n, v, jnp.zeros_like(v))
+                kept = v_rows < n
+                if skip_ref is not None:
+                    kept = jnp.logical_and(kept, v_rows != skip_ref[b])
+                v = jnp.where(kept, v, jnp.zeros_like(v))
             m_prev = m_ref[...]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -181,8 +190,11 @@ def _kernel(layer_ref, slot_ref, block_ref, count_ref, held_ref,  # scalars
                 preferred_element_type=jnp.float32)
             m_ref[...] = m_new
 
-        pl.when((j + 1) * rows <= n)(functools.partial(accumulate, False))
-        pl.when((j + 1) * rows > n)(functools.partial(accumulate, True))
+        if skip_ref is None:
+            pl.when((j + 1) * rows <= n)(functools.partial(accumulate, False))
+            pl.when((j + 1) * rows > n)(functools.partial(accumulate, True))
+        else:   # any block may hold the row that has left the window
+            accumulate(True)
 
         @pl.when((j + 1) * rows >= n)
         def _close():
@@ -193,13 +205,17 @@ def _kernel(layer_ref, slot_ref, block_ref, count_ref, held_ref,  # scalars
 
 def gqa_decode_attention(q: jax.Array, k_cur: jax.Array, v_cur: jax.Array,
                          k_all: jax.Array, v_all: jax.Array, layer: jax.Array,
-                         items, attn_len: int, sm_scale: float = 0.0) -> jax.Array:
+                         items, attn_len: int, sm_scale: float = 0.0,
+                         skip: jax.Array = None) -> jax.Array:
     """q [B, kvh, rep, hd]; k_cur / v_cur [B, kvh, hd] (the current token's
     row, not in the cache yet); k_all / v_all [L, B, kvh, max_len, hd];
     `layer` a scalar; `items` = `live_items(lengths, attn_len)` ->
     [B, kvh, rep, hd]: slot b attends rows [0, min(lengths[b], attn_len)) of
     layer `layer` and its own row, under a softmax of `sm_scale` q . k
-    (0: 1 / sqrt(hd))."""
+    (0: 1 / sqrt(hd)). `skip` [B] int32, for a cache whose rows are a RING
+    (`attn_len` rows a slot, position n at row n % attn_len): the row of
+    each slot that has left the window as the current one enters (n %
+    attn_len once the ring has wrapped), -1 where none has."""
     B, kvh, rep, hd = q.shape
     rows = block_rows(attn_len)
     # the query group fills whole sublanes of the float32 statistics
@@ -210,10 +226,15 @@ def gqa_decode_attention(q: jax.Array, k_cur: jax.Array, v_cur: jax.Array,
     # each for 32 slots where 128 KB do (0.02 ms a step, call 4)
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    kernel = functools.partial(_kernel, rows=rows, scale=sm_scale or hd ** -0.5)
+    if skip is not None:
+        items = tuple(items) + (skip.astype(jnp.int32),)
+        ring = kernel
+        kernel = lambda *refs: ring(*refs[:5], *refs[6:], skip_ref=refs[5])
     out = pl.pallas_call(
-        functools.partial(_kernel, rows=rows, scale=sm_scale or hd ** -0.5),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=len(items) + 1,
             grid=(),
             in_specs=[whole, whole, whole, in_hbm, in_hbm],
             out_specs=whole,

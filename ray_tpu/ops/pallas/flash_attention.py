@@ -37,7 +37,11 @@ _NEG_INF = -1e30
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
-                sq: int, sk: int):
+                sq: int, sk: int, bounds_ref=None, window: int | None = None):
+    """`bounds_ref` (`flash_attention_banded`): int32 scalars [q_start, k_lo,
+    ...]: query row r stands at key column r + q_start (in the static
+    `sk - sq`'s place), key columns below k_lo do not exist, and with
+    `window` a query sees the `window` columns that end at its own."""
     i_q = pl.program_id(1)
     i_k = pl.program_id(2)
     n_k = pl.num_programs(2)
@@ -57,6 +61,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     if causal:
         should_compute = (
             i_k * block_k <= i_q * block_q + block_q - 1 + offset)
+    if bounds_ref is not None:
+        offset, k_lo = bounds_ref[0], bounds_ref[1]
+        # the band's lower edge: a key block wholly below the first query
+        # row's lowest column is skipped like one above the causal edge
+        should_compute = jnp.logical_and(
+            i_k * block_k <= i_q * block_q + block_q - 1 + offset,
+            i_k * block_k + block_k - 1 >= _band_lo(i_q * block_q + offset, k_lo,
+                                                    window))
 
     @pl.when(should_compute)
     def _compute():
@@ -77,6 +89,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         if causal:
             rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + i_q * block_q
             s = jnp.where(cols <= rows + offset, s, _NEG_INF)
+        if bounds_ref is not None:
+            s = jnp.where(cols >= _band_lo(rows + offset, k_lo, window), s, _NEG_INF)
         # mask the padded key tail of the last block (sk % block_k != 0)
         s = jnp.where(cols < sk, s, _NEG_INF)
 
@@ -99,6 +113,60 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         # trailing dims satisfy Mosaic's (8,128) tiling; see _flash_fwd.
         lse_ref[0] = jnp.broadcast_to(
             (m_ref[:] + jnp.log(l))[:, 0][None, :], lse_ref.shape[1:])
+
+
+def _band_lo(at, k_lo, window):
+    """The lowest key column a query at column `at` sees."""
+    return k_lo if window is None else jnp.maximum(k_lo, at - window + 1)
+
+
+def flash_attention_banded(q, k, v, bounds, sm_scale: float,
+                           window: int | None = None,
+                           block_q: int = 1024, block_k: int = 1024):
+    """The forward kernel for a prompt pass that walks its prompt a chunk at
+    a time: causal attention of q [b H, sq, d] (a chunk's queries, every
+    head) against k, v [layers, b kvh, sk, d] (a layer's rows of a cache
+    that holds the chunks so far), no gradient. `bounds` int32 [3] =
+    (q_start, k_lo, layer): query row r stands at key column r + q_start and
+    attends columns max(k_lo, that - window + 1) .. that of layer `layer`
+    (`window` None: from k_lo). Grouped heads are read through the index
+    map: query head h reads key head h // (H / kvh), no copy of K or V to
+    the query heads. Key blocks wholly outside the band are neither computed
+    nor fetched: the index map holds the block at the band's nearest."""
+    bh, sq, d = q.shape
+    layers, bkv, sk, _ = k.shape
+    rep = bh // bkv
+    bq, bk = min(block_q, sq), min(block_k, sk)
+    n_k = cdiv(sk, bk)
+
+    def kv_block(b, i, j, bounds):
+        at = i * bq + bounds[0]              # the block's first query's column
+        lo = jnp.maximum(_band_lo(at, bounds[1], window), 0) // bk
+        hi = jnp.minimum((at + bq - 1) // bk, n_k - 1)
+        return (bounds[2], b // rep, jnp.clip(j, lo, hi), 0)
+
+    kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=True,
+                               block_q=bq, block_k=bk, sq=sq, sk=sk, window=window)
+    qspec = pl.BlockSpec((1, bq, d), lambda b, i, j, bounds: (b, i, 0),
+                         memory_space=pltpu.VMEM)
+    kvspec = pl.BlockSpec((None, 1, bk, d), kv_block, memory_space=pltpu.VMEM)
+    out, _ = pl.pallas_call(
+        lambda bounds_ref, *refs: kernel(*refs, bounds_ref=bounds_ref),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(bh, cdiv(sq, bq), n_k),
+            in_specs=[qspec, kvspec, kvspec],
+            out_specs=[qspec,
+                       pl.BlockSpec((1, 8, bq), lambda b, i, j, bounds: (b, 0, i),
+                                    memory_space=pltpu.VMEM)],
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                            pltpu.VMEM((bq, 1), jnp.float32),
+                            pltpu.VMEM((bq, 1), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+                   jax.ShapeDtypeStruct((bh, 8, sq), jnp.float32)],
+        name="flash_attention_banded",
+        interpret=interpret_mode(),
+    )(bounds.astype(jnp.int32), q, k, v)
+    return out
 
 
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):

@@ -849,6 +849,101 @@ def test_keye_prompt_pass_fits_beside_weights_and_slots(chip):
     assert rows.memory_analysis().temp_size_in_bytes < 2.1e9
 
 
+def _cmda(chip):
+    """(cfg, parameter shapes, slot-state shapes, slots, max_len) of the
+    Command A+ cell."""
+    import json
+    import sys
+
+    from ray_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from perfbench.lib import cmda_model
+
+    with open(os.path.join(root, "perfbench", "configs",
+                           "command-a-plus-05-2026.1of8.json")) as f:
+        conf = json.load(f)
+    cfg = cmda_model.model_config(conf)
+    slots, max_len = conf["run"]["num_slots"], conf["run"]["max_len"]
+    as_shapes = lambda tree: jax.tree_util.tree_map(
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = as_shapes(jax.eval_shape(lambda k: hybrid.init_params(k, cfg),
+                                      jax.random.PRNGKey(0)))
+    state = as_shapes(jax.eval_shape(lambda: cfg.make_cache(slots, max_len).state))
+    return cfg, params, state, slots, max_len
+
+
+def test_cmda_decode_step_walks_ring_and_full_rows_in_place(chip):
+    """The window-and-full stack at the benchmark cell's real shapes (one
+    period of Command A+'s layers, 16 of 128 experts, 12 slots x 49,152): ONE
+    step program whatever the deepest slot (the kernel walks live rows), a
+    decode kernel a layer over the ring (3 layers) or the full rows (1), both
+    caches aliased and written by `write_rows`; nothing of the size of a
+    layer's cache, or of its window, is copied, sliced or transposed. The
+    same body with the logits returned is what the benchmark's check replays."""
+    from ray_tpu.models import hybrid
+
+    cfg, params, state, slots, max_len = _cmda(chip)
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(params))
+    assert 9.46e9 < weights < 9.47e9                   # 4,733.3M parameters, bf16
+    state_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(state))
+    assert state_bytes == 12 * (49152 + 3 * 4096) * 4096            # 3.02 GB
+    ints = chip((slots,), jnp.int32)
+    c = hybrid.decode_step.lower(params, state, ints, ints,
+                                 chip((slots,), jnp.bool_), cfg, max_len).compile()
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < 32 * 2**20
+    text = c.as_text()
+    assert "gqa_decode_attention" in text and "write_rows" in text and "ragged" in text
+    for table in state.values():
+        assert _whole_cache_relayouts(c, table) == []
+    # nothing of the shape of ONE window layer's ring is made or read out (the
+    # one full layer's cache IS a layer's: the relayouts above speak for it)
+    made = {dims for _, dims, _ in _INSTRUCTION.findall(text)}
+    assert not {f"{slots},8,4096,128", f"1,{slots},8,4096,128"} & made
+    # the comparison's program: the step's body at the SAME attention length
+    # (`SwaCache.step_len`), logits and every slot's choice of experts returned
+    rows = hybrid.decode_logits.lower(params, state, ints, ints, None, cfg,
+                                      max_len).compile()
+    assert rows.memory_analysis().alias_size_in_bytes >= state_bytes
+    assert rows.memory_analysis().temp_size_in_bytes < 32 * 2**20
+    assert "gqa_decode_attention" in rows.as_text()
+
+
+def test_cmda_prompt_pass_walks_its_chunks_beside_weights_and_slots(chip):
+    """The ONE program of every prompt past a window, 1 x 49,152: Mosaic takes
+    the banded flash kernel at 128 query heads on 8 key heads (the window
+    layers' over the chunk before and the chunk, the full layer's over the
+    whole cache, its chunk's place a scalar), the walk is a `while` whose trip
+    count is data, no [chunk, keys] score exists outside the kernel, and what
+    the pass needs beside 9.47 GB of weights and 3.02 GB of slots stays under
+    2 GB. The same with every position's choice of experts returned (the
+    comparison's program), and the shortest bucket's."""
+    from ray_tpu.models import hybrid
+
+    cfg, params, _, _, max_len = _cmda(chip)
+    one = chip((1,), jnp.int32)
+    c = hybrid._prefill_first.lower(params, chip((1, max_len), jnp.int32), one,
+                                    cfg).compile()
+    text = c.as_text()
+    assert "flash_attention_banded" in text and " while(" in text
+    assert c.memory_analysis().temp_size_in_bytes < 2.0e9
+    assert not re.search(r"f32\[(\d+,)*4096,(8192|49152)\]", text)   # no scores in HBM
+    assert not re.search(rf"\[(\d+,)*128,{max_len},128\]", text)      # no K / V a query head
+    rows = hybrid.prefill.lower(params, chip((1, max_len), jnp.int32), one, cfg,
+                                with_routing=True).compile()
+    assert f"s32[4,1,{max_len},8]" in rows.as_text()
+    assert rows.memory_analysis().temp_size_in_bytes < 2.1e9
+    short = hybrid._prefill_first.lower(params, chip((1, 512), jnp.int32), one,
+                                        cfg).compile()
+    assert "flash_attention_banded" in short.as_text()
+    assert " while(" in short.as_text()            # the runs' scans; no walk
+    assert short.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
 def test_prefill_slots_compiles_at_b1(chip):
     from ray_tpu.models.serving import prefill_slots
 
