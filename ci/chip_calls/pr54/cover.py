@@ -77,10 +77,14 @@ def ms(line: str) -> float:
 
 def scan_bodies(comps: dict) -> dict:
     """{"forward": lines, "backward": lines}: the backward's holds more
-    permutes (its own exchanges and the weight gradients')."""
+    permutes (its own exchanges and the weight gradients'). Of the `while`
+    bodies (since PR 61 the entry holds the head's permutes too); of a kept
+    excerpt, which has no `while`, every computation is looked at."""
+    called = {name for lines in comps.values() for l in lines
+              for name in re.findall(r" while\(.*body=%([\w.\-]+)", l)}
     bodies = sorted(
-        (lines for lines in comps.values()
-         if sum(" collective-permute-start(" in l for l in lines) >= 4),
+        (comps[name] for name in sorted(called or comps)
+         if sum(" collective-permute-start(" in l for l in comps[name]) >= 4),
         key=lambda lines: sum(" collective-permute-start(" in l for l in lines))
     if len(bodies) != 2:
         raise ValueError(f"{len(bodies)} scan bodies with permutes, not 2")

@@ -316,7 +316,8 @@ def _rows_mesh(cfg: ModelConfig, mesh, batch: int, seq: int):
     the `tp` reductions are the partitioner's all-reduces: without a mesh or
     with `tp` 1, where `tp` does not divide the sequence, and wherever the
     layer's weights do not come exchanged (`_exchanged_dims`: fsdp 1, the
-    expert layer, the fused blocks). The only form read on the chip is the
+    expert layer, the fused blocks). The head's product follows the layers'
+    (`head_exchanged`). The only form read on the chip is the
     one whose products carry the weights' shards round fsdp's ring as well;
     the rows over `tp` alone read no faster than the partitioner's program
     (PERF.md section 6, PR 38)."""
@@ -351,6 +352,22 @@ def dw_rings_ordered(cfg: ModelConfig, mesh, batch: int, seq: int) -> int:
     says the products are parallel/tp.py's, else 0 (the train step's
     `xla.compile` spans carry it)."""
     return 0 if _rows_mesh(cfg, mesh, batch, seq) is None else 1
+
+
+# the head [d, vocab]'s dimension that the rules shard over `fsdp`
+_HEAD_DIM = fsdp.sharded_dim(DEFAULT_RULES.spec(("embed", "vocab")))
+
+
+def head_exchanged(cfg: ModelConfig, mesh, batch: int, seq: int) -> int:
+    """1 where the head's product carries its exchanges as the layers'
+    products do (the rows' other `tp` chunks and `lm_head`'s other `fsdp`
+    shards arrive by permutes behind its own matmuls, its gradient leaves by
+    fsdp.py's ring: `tp.gather_matmul_alone`): where `_rows_mesh` says the
+    features come with their rows over `tp`; else 0, the partitioner's
+    program, as under `loss_chunk` (the train step's `xla.compile` spans
+    carry it)."""
+    return int(_rows_mesh(cfg, mesh, batch, seq) is not None
+               and not cfg.loss_chunk)
 
 
 def grad_exchanges_per_layer(cfg: ModelConfig, mesh, batch: int) -> int:
@@ -413,7 +430,9 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
     sums of its two norm scales' gradients over the ranks that split the
     rows, taken once a step behind the scan
     (`norm_grad_reductions_in_layers`): all three take `mesh` and without
-    it are the partitioner's.
+    it are the partitioner's. Where `_rows_mesh` holds the features come as
+    the layers leave them, rows over `tp`: the head's product takes them so
+    (`_head_logits`).
     """
     if positions is None:
         positions = jnp.arange(tokens.shape[1])
@@ -474,18 +493,25 @@ def forward_features_with_aux(params: Dict[str, Any], tokens: jax.Array,
 
     (x, aux_total), _ = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), layers)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if rows_mesh is not None:
-        x = tp.whole_rows(x, rows_mesh)  # the head's program is the partitioner's
-    return x, aux_total
+    return rms_norm(x, params["final_norm"], cfg.norm_eps), aux_total
+
+
+def _head_logits(params: Dict[str, Any], x: jax.Array, cfg: ModelConfig, mesh):
+    """features [b, s, d] -> logits [b, s, vocab] in float32. Where the
+    layers' products carry their exchanges (`_rows_mesh`: x comes with its
+    rows over `tp`) the head's does too: it is gate's and up's by shape."""
+    head = lm_head_weights(params, cfg)
+    if not head_exchanged(cfg, mesh, *x.shape[:2]):
+        return (x @ head).astype(jnp.float32)
+    w = fsdp.ExchangedWeight(head, _HEAD_DIM, mesh)
+    return tp.gather_matmul_alone(x, w, mesh).astype(jnp.float32)
 
 
 def forward_with_aux(params: Dict[str, Any], tokens: jax.Array, cfg: ModelConfig,
                      positions: Optional[jax.Array] = None, mesh=None):
     """tokens [b, s] -> (logits [b, s, vocab] fp32, moe_aux_loss scalar)."""
     x, aux_total = forward_features_with_aux(params, tokens, cfg, positions, mesh)
-    logits = (x @ lm_head_weights(params, cfg)).astype(jnp.float32)
-    return logits, aux_total
+    return _head_logits(params, x, cfg, mesh), aux_total
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array, cfg: ModelConfig,
@@ -570,8 +596,7 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
             loss = chunked_token_nll(x, lm_head_weights(params, cfg),
                                      targets, mask, cfg.loss_chunk)
         else:
-            logits = (x @ lm_head_weights(params, cfg)).astype(jnp.float32)
-            loss = token_nll(logits, targets, mask)
+            loss = token_nll(_head_logits(params, x, cfg, mesh), targets, mask)
     if cfg.n_experts > 0:
         loss = loss + cfg.moe_aux_weight * moe_aux
     return loss, {"loss": loss, "ntokens": targets.size, "moe_aux": moe_aux}

@@ -157,15 +157,17 @@ def weight_grad(x: jax.Array, dy: jax.Array, dim: int, mesh) -> jax.Array:
     return weight_grads([x], [dy], dim, mesh)[0][0]
 
 
-def weight_grads(xs, dys, dim: int, mesh, taken=None):
+def weight_grads(xs, dys, dim: int, mesh, taken=None, alone: bool = False):
     """`weight_grad` of each pair of `xs` and `dys`, one region for all of
     them, and with `taken` (a `RingOrder`'s, as the backward body was handed
     it) what it is after the last of them: `_reduce_scatter_dws`. Called
-    from inside parallel/tp.py's products (`_region`)."""
+    from inside parallel/tp.py's products (`_region`). `alone`: for a
+    product outside the layers' loops (`_reduce_scatter_dws`)."""
     rows, whole = [P(None, AXIS)] * len(xs), None if taken is None else P()
     dws, taken = _region(
         lambda xs, dys, r, taken: _reduce_scatter_dws(
-            [x[:, 0] for x in xs], [dy[:, 0] for dy in dys], dim, r[0], taken),
+            [x[:, 0] for x in xs], [dy[:, 0] for dy in dys], dim, r[0], taken,
+            alone),
         mesh, (rows, rows, P(AXIS), whole),
         ([P(*[None] * (1 + dim), AXIS)] * len(xs), whole))(
             [_by_rank(x, mesh) for x in xs], [_by_rank(dy, mesh) for dy in dys],
@@ -200,7 +202,7 @@ def _region(body, mesh, in_specs, out_specs):
 
 
 def ring_products(groups, ws, dim: int, transposed: bool, mesh, *,
-                  own_first: bool = False) -> list:
+                  own_first: bool = False, keep: bool = False, kept=None):
     """For each group of operands (one a weight, [batch, ..., width], batch
     split over (dp, fsdp)): the sum over the weights of operand @ w, or of
     operand @ w^T with `transposed`, for weights [k, n] sharded over `fsdp`
@@ -230,18 +232,24 @@ def ring_products(groups, ws, dim: int, transposed: bool, mesh, *,
     stay. Not for every product that sums over the sharded dimension: the
     other shards' transfers are covered by the products before them, and
     pinned, the forward's sums come unfused (XLA's own estimate of the
-    forward body at those widths: 4.445 -> 5.126 ms a layer)."""
+    forward body at those widths: 4.445 -> 5.126 ms a layer).
+
+    `keep`: also hands back the shards that arrived, a list a round of the
+    ring (n - 1 of them) of one shard a weight -> (results, rounds). `kept`:
+    such rounds, taken where this call would send the shards round again
+    (a backward that follows its forward at once: the head's)."""
     n = axis_size(mesh)
     summed = 1 if transposed else 0   # the weight's dimension a product sums over
     ring = _ring(n)
 
-    def body(groups, ws, r):
+    def body(groups, ws, r, kept):
         groups, r = [[x[:, 0] for x in group] for group in groups], r[0]
         size = ws[0].shape[dim]
         outs = [None] * len(groups)
+        rounds = []
         for j in range(n):
-            arriving = ([jax.lax.ppermute(w, AXIS, ring) for w in ws]
-                        if j < n - 1 else None)
+            arriving = (None if j == n - 1 else kept[j] if kept
+                        else [jax.lax.ppermute(w, AXIS, ring) for w in ws])
             at = ((r - j) % n) * size
             for t, group in enumerate(groups):
                 if dim == summed:
@@ -259,20 +267,22 @@ def ring_products(groups, ws, dim: int, transposed: bool, mesh, *,
             if own_first and arriving is not None:
                 arriving, outs = jax.lax.optimization_barrier((arriving, outs))
             ws = arriving
+            rounds.append(arriving)
         if dim == summed:
             outs = [sum(acc.astype(group[0].dtype) for acc in accs)
                     for accs, group in zip(outs, groups)]
-        return [out[:, None] for out in outs]
+        return [out[:, None] for out in outs], rounds[:-1] if keep else None
 
-    rows = P(None, AXIS)
-    outs = _region(
-        body, mesh, ([[rows] * len(ws)] * len(groups),
-                     [P(*[None] * dim, AXIS)] * len(ws), P(AXIS)),
-        [rows] * len(groups))(
+    rows, shards = P(None, AXIS), [P(*[None] * dim, AXIS)] * len(ws)
+    outs, rounds = _region(
+        body, mesh, ([[rows] * len(ws)] * len(groups), shards, P(AXIS),
+                     kept and [shards] * (n - 1)),
+        ([rows] * len(groups), [shards] * (n - 1) if keep else None))(
             [[_by_rank(x, mesh) for x in group] for group in groups], list(ws),
-            jnp.arange(n))
-    return [out.reshape(*group[0].shape[:-1], out.shape[-1])
+            jnp.arange(n), kept)
+    outs = [out.reshape(*group[0].shape[:-1], out.shape[-1])
             for out, group in zip(outs, groups)]
+    return (outs, rounds) if keep else outs
 
 
 def _dot(x: jax.Array, w: jax.Array, summed: int, dtype=None) -> jax.Array:
@@ -281,7 +291,7 @@ def _dot(x: jax.Array, w: jax.Array, summed: int, dtype=None) -> jax.Array:
                                preferred_element_type=dtype)
 
 
-def _reduce_scatter_dws(xs, dys, dim: int, r, taken=None):
+def _reduce_scatter_dws(xs, dys, dim: int, r, taken=None, alone: bool = False):
     """For each pair: the sum over `fsdp` of dw = x^T dy (x [dp, rows, k],
     dy [dp, rows, n], this rank's rows), rank r keeping chunk r of dw's
     dimension `dim`: a ring of n - 1 steps. In step t rank r hands the
@@ -325,7 +335,18 @@ def _reduce_scatter_dws(xs, dys, dim: int, r, taken=None):
     memory and dead one instruction on, and the arrived chunk is never
     evicted: 7.12 and 7.13 (PERF.md section 6, PR 59). Every ring stages,
     not those two alone: the five other slices cost 0.95 ms a step and
-    their products run 0.8 faster for an operand that is one array."""
+    their products run 0.8 faster for an operand that is one array.
+
+    `alone`: the ring of a product outside the layers' loops (the head's:
+    67 MB, once a step), where nothing but the caller's own products is
+    there to cover its way. The scheduler keeps a permute open only as long
+    as ITS estimate of the transfer asks (0.9 ms for those 67 MB, which take
+    1.46 on the link) and starts it no earlier; fused with the sum, the kept
+    product stands behind the done, and one 0.73 ms product of the caller's
+    is all that is left between. So the kept product is an array of its own
+    too, the sum a pass of its own (0.3 ms), and the kept product runs
+    under way with the caller's: 1.49 ms of matmul between start and done
+    by XLA's estimate, and no wait on the chip (PERF.md section 6, PR 61)."""
     n = jax.lax.axis_size(AXIS)
     ring = _ring(n)
 
@@ -344,11 +365,17 @@ def _reduce_scatter_dws(xs, dys, dim: int, r, taken=None):
     for x, dy in zip(xs, dys):
         acc = chunk(x, dy, r - 1)
         for t in range(n - 1):
-            acc = jax.lax.ppermute(acc, AXIS, ring) + chunk(x, dy, r - t - 2, taken)
+            own = chunk(x, dy, r - t - 2, taken)
+            acc = jax.lax.ppermute(acc, AXIS, ring) + (_staged(own) if alone else own)
             if taken is not None:
                 taken = _zero_read_off(acc)
         accs.append(acc)
     return accs, taken
+
+
+def behind(a: jax.Array, b: jax.Array) -> jax.Array:
+    """`a`, there no earlier than `b` is (a zero read off `b` is added)."""
+    return a + _zero_read_off(b).astype(a.dtype)
 
 
 def _staged(part: jax.Array) -> jax.Array:

@@ -1,5 +1,5 @@
-"""The dense block's products over `tp`, with the residual stream's rows
-riding sharded over `tp` between them.
+"""The dense block's products over `tp`, and the head's, with the residual
+stream's rows riding sharded over `tp` between them.
 
 Left to the partitioner (the Megatron form), a row-parallel product (`wo`,
 `w_down`) yields a partial sum of the WHOLE residual [batch, seq, d], and a
@@ -48,6 +48,21 @@ rings stand behind that one's: the arrivals are taken in the order of the
 starts, `w_down`'s to `wv`'s, each ring's kept product between
 (`fsdp._reduce_scatter_dws`; PERF.md section 6, PR 57).
 
+The head's product is gate's and up's by shape (rows [b, s over tp, d] by
+`lm_head` [d over fsdp, vocab over tp]) but stands ALONE between the
+layers' two loops, once a step (`gather_matmul_alone`). No product before
+it covers its shard's way: a permute spans no loop whose body holds
+permutes, so the shard starts behind the forward loop; the own shard's
+product is pinned first and covers two thirds of its way, and the rows' gather over
+`tp` runs inside what is left, so the product is ONE over the whole
+sequence in the sequence's order and the logits need no placing by rank.
+Its backward follows its forward at once: the shard that arrived is kept
+and none is sent again, and `lm_head`'s gradient ring is taken before
+`dx` is handed to the backward loop, behind the kept half's product and a
+`dx` product. The partitioner's head gathered `lm_head` whole and
+reduce-scattered its gradient on the compute stream, 1.44 and 1.81 ms of a
+297 ms step (PERF.md section 6, PR 61).
+
 `chunks`: the whole-sequence side of a product may stay a tuple of chunks,
 one a rank of the ring and in each rank's OWN order (its rows first, then
 the ones that arrived first, ...), which costs no placement by rank and no
@@ -93,13 +108,6 @@ def shard_rows(x: jax.Array, mesh) -> jax.Array:
         x, NamedSharding(mesh, P(fsdp.BATCH_AXES, AXIS, None)))
 
 
-def whole_rows(x: jax.Array, mesh) -> jax.Array:
-    """x [b, s over tp, d] gathered over `tp` by the partitioner (once a
-    step, for the head)."""
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(fsdp.BATCH_AXES, None, None)))
-
-
 def gather_matmul(x: jax.Array, ws: Sequence, mesh, *,
                   chunks: bool = False) -> Tuple:
     """x [b, s over tp, k] @ each w [k, n over tp] (`fsdp.ExchangedWeight`s,
@@ -110,6 +118,13 @@ def gather_matmul(x: jax.Array, ws: Sequence, mesh, *,
     ys, order.taken = _gather_matmul(x, tuple(w.w for w in ws), order.taken,
                                      dim, mesh, chunks)
     return ys
+
+
+def gather_matmul_alone(x: jax.Array, w, mesh) -> jax.Array:
+    """x [b, s over tp, k] @ w [k, n over tp] (an `fsdp.ExchangedWeight`)
+    -> [b, s, n over tp], for a product that stands alone between the
+    layers' loops with its backward straight behind it (the head's)."""
+    return _lone_matmul(x, w.w, w.dim, mesh)
 
 
 def matmul_scatter(x, w, mesh) -> jax.Array:
@@ -306,3 +321,49 @@ def _matmul_scatter_bwd(dim, mesh, res, cts):
 
 
 _matmul_scatter.defvjp(_matmul_scatter_fwd, _matmul_scatter_bwd)
+
+
+# The product that stands alone (the head's): whole over the sequence.
+
+
+def _lone_products(x, w, dim, mesh):
+    """(x @ w, x whole over `tp`, the shards of w that arrived: one a round
+    of fsdp's ring)."""
+    def body(x, w):
+        whole = _in_sequence(_gather(x))
+        (y,), rounds = fsdp.ring_products([[whole]], [w], dim, False, mesh,
+                                          own_first=True, keep=True)
+        return y, whole, tuple(shard for shard, in rounds)
+
+    kept = (_ROWS,) * (fsdp.axis_size(mesh) - 1)
+    return _manual(body, mesh, (_ROWS, _ROWS), (_COLS, P(), kept))(x, w)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _lone_matmul(x, w, dim, mesh):
+    return _lone_products(x, w, dim, mesh)[0]
+
+
+def _lone_matmul_fwd(x, w, dim, mesh):
+    y, whole, kept = _lone_products(x, w, dim, mesh)
+    return y, (whole, w, kept)
+
+
+def _lone_matmul_bwd(dim, mesh, res, dy):
+    def body(whole, w, kept, dy):
+        (dw,), _ = fsdp.weight_grads([whole], [dy], dim, mesh, alone=True)
+        dx, = fsdp.ring_products([[dy]], [w], dim, True, mesh,
+                                 kept=[[shard] for shard in kept])
+        dx = _ring_sum(lambda t: _rows_at(dx, t))
+        # the gradient's ring is taken before dx is handed on: what follows
+        # is the layers' backward loop, and a ring left open would start
+        # behind it, where the partitioner's next all-reduce waits on the
+        # link for the 67 MB in flight
+        return fsdp.behind(dx, dw), dw.astype(w.dtype)
+
+    whole, w, kept = res
+    return _manual(body, mesh, (P(), _ROWS, (_ROWS,) * len(kept), _COLS),
+                   (_ROWS, _ROWS))(whole, w, kept, dy)
+
+
+_lone_matmul.defvjp(_lone_matmul_fwd, _lone_matmul_bwd)

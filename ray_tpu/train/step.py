@@ -10,13 +10,14 @@ sharding annotations. Two the model spells itself, as
 permutes that run behind matmuls where the partitioner's all-reduce blocks
 the compute stream: with fsdp > 1 the dense block's weight gradients are
 summed over fsdp by `parallel/fsdp.py`, and with tp > 1 its gathers and
-scatters over tp ride inside the products (`parallel/tp.py`). A third the
+scatters over tp ride inside the products (`parallel/tp.py`), the head's
+product with the block's. A third the
 model moves: there the two norm scales' gradients leave the layers' scan as
 each rank's partial sums and are all-reduced once a step, where the
 partitioner's reduction waits for every chip once a layer. The step's
 `xla.compile` spans say which forms it has (`grad_exchanges_per_layer`,
 `tp_exchanges_per_layer`, `norm_grad_reductions_in_layers`,
-`ring_products_own_first`, `dw_rings_ordered`).
+`ring_products_own_first`, `dw_rings_ordered`, `head_exchanged`).
 """
 
 from __future__ import annotations
@@ -34,6 +35,7 @@ from ray_tpu.models.transformer import (
     ModelConfig,
     dw_rings_ordered,
     grad_exchanges_per_layer,
+    head_exchanged,
     init_params,
     loss_fn,
     norm_grad_reductions_in_layers,
@@ -161,9 +163,10 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh,
     def step(state: TrainState, batch: Dict[str, jax.Array]):
         # which form of the gradients' reduction over fsdp, of the block's
         # reductions over tp and of the norm scales' this program has, and
-        # how many ring products run own shard first by a pin and whether the
-        # weight gradients' rings are taken in order, is a fact of its
-        # compile: on its `xla.compile` spans
+        # how many ring products run own shard first by a pin, whether the
+        # weight gradients' rings are taken in order and whether the head's
+        # product carries its exchanges, is a fact of its compile: on its
+        # `xla.compile` spans
         inputs = split_batch(batch)[0]
         tracing.note_compile(
             "step", fsdp=mesh.shape.get("fsdp", 1), tp=mesh.shape.get("tp", 1),
@@ -175,7 +178,8 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh,
                 cfg, mesh, inputs.shape[0]),
             ring_products_own_first=ring_products_own_first(
                 cfg, mesh, *inputs.shape),
-            dw_rings_ordered=dw_rings_ordered(cfg, mesh, *inputs.shape))
+            dw_rings_ordered=dw_rings_ordered(cfg, mesh, *inputs.shape),
+            head_exchanged=head_exchanged(cfg, mesh, *inputs.shape))
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, batch, cfg, mesh)
         # (the phases before this one are named in models/transformer.py:

@@ -1010,9 +1010,13 @@ _TP_PAIRS = "{{0,1},{1,0},{2,3},{3,2}}"     # ... and those that share fsdp
 
 def _scan_bodies(comps: dict) -> tuple:
     """(forward, backward): the two `while` bodies of the layers' scan; the
-    backward's holds more exchanges (its own and the weight gradients')."""
-    bodies = [lines for lines in comps.values()
-              if sum(" collective-permute-start(" in l for l in lines) >= 4]
+    backward's holds more exchanges (its own and the weight gradients').
+    (Of a kept excerpt, which has the bodies and no `while`: every
+    computation is looked at.)"""
+    called = {name for lines in comps.values() for l in lines
+              for name in re.findall(r" while\(.*body=%([\w.\-]+)", l)}
+    bodies = [comps[name] for name in sorted(called or comps)
+              if sum(" collective-permute-start(" in l for l in comps[name]) >= 4]
     assert len(bodies) == 2, len(bodies)
     return tuple(sorted(bodies, key=lambda lines: sum(
         " collective-permute-start(" in l for l in lines)))
@@ -1162,13 +1166,11 @@ def _assert_dw_rings_taken_in_start_order(comps):
 def _assert_no_tp_all_reduce_in_the_layers(comps):
     """Neither scan body is left a blocking `all-reduce` of the residual
     [1,2048,4096] (the partitioner's Megatron form had two in each, over the
-    tp pairs, on the compute stream); the head's own, once a step outside
-    the scan, stay the partitioner's."""
-    for body in _scan_bodies(comps):
-        assert not [l for l in body
-                    if " all-reduce(" in l and "[1,2048,4096]" in l]
-    assert sum(" all-reduce(" in l and "[1,2048,4096]" in l
-               for lines in comps.values() for l in lines) <= 2
+    tp pairs, on the compute stream), and since PR 61 none stands outside
+    them either: the head's `dx` was the last, once a step, and comes back
+    rows-over-tp through the ring now (`_assert_head_rides_the_rings`)."""
+    assert not [l for lines in comps.values() for l in lines
+                if " all-reduce(" in l and "[1,2048,4096]" in l]
 
 
 def _assert_tp_exchanges_run_behind_the_products(comps):
@@ -1252,6 +1254,68 @@ def _assert_dw_twins_read_fast_memory(comps):
     assert max(cycles) <= 1.05 * min(cycles), (windows, cycles)
 
 
+def _assert_head_rides_the_rings(comps):
+    """The head's product is gate's and up's by shape, and since PR 61 it
+    carries its exchanges as they do (`tp.gather_matmul_alone`). In the
+    whole module: no `all-gather` yields `lm_head` whole over fsdp
+    (`bf16[4096,16384]`: 67 MB over the link with nothing beside it, 1.44 ms
+    a step) and no reduce-scatter of any spelling is left at its width (the
+    partitioner's `all-reduce-scatter` fusion of the head's `[4096,16384]`
+    gradient, 1.81 ms, blocking). In the computation that holds the two
+    `while`s, between them: `lm_head`'s shard `bf16[2048,16384]` goes round
+    the fsdp pairs ONCE a step, started behind the forward `while` (a
+    permute spans no loop whose body holds permutes), the matmul fusion
+    between its start and its done is the product by the rank's OWN shard
+    over the whole sequence, 0.7 ms by XLA's estimate, and the first one
+    behind the done takes the done; the backward sends no shard again (the
+    arrived one is kept). The gradient's half for the neighbour,
+    `bf16[1,2048,16384]`, is a product's result sent as it is, and its done
+    stands before the backward `while` (`dx` is handed on behind it), with
+    the kept half's product and a `dx` product between, 1.4 ms of matmul by
+    XLA's estimate for 67 MB (the scheduler, left alone, keeps a permute
+    open for the 0.9 ms IT gives the transfer, one `dx` product:
+    `fsdp.weight_grads`, `alone`). An all-reduce, all-gather or all-to-all
+    of the partitioner's that stands in that window waits on the link for
+    what is still in flight (at the window's head the loss's sum over the
+    chips waited 1.44 ms a step, PR 61's first tree): none stands there
+    with less than those 1.4 ms of matmul behind the start."""
+    forward, backward = _scan_bodies(comps)
+    lines = [l for body in comps.values() for l in body]
+    assert not [l for l in lines if " all-gather(" in l and "bf16[4096,16384]" in l]
+    assert not [l for l in lines if "reduce-scatter" in l and "16384" in l]
+    (main,) = [body for body in comps.values()
+               if sum(" while(" in l for l in body) == 2]
+    name = lambda l: re.match(r"\s*(?:ROOT )?%([\w.\-]+) =", l).group(1)
+    body_of = lambda l: comps[re.search(r"body=%([\w.\-]+)", l).group(1)]
+    whiles = {id(body_of(l)): i for i, l in enumerate(main) if " while(" in l}
+    fwd_at, bwd_at = whiles[id(forward)], whiles[id(backward)]
+    est_ms = lambda ls: sum(int(_CYCLES.search(l).group(1)) for l in ls
+                            if _is_matmul(comps, l)) / 1.5e6
+
+    def ring(shape):
+        (start,) = [i for i, l in enumerate(main) if _FSDP_PAIRS in l
+                    and " collective-permute-start(" in l and f"= ({shape}" in l]
+        done = next(i for i, l in enumerate(main)
+                    if f"collective-permute-done(%{name(main[start])})" in l)
+        assert fwd_at < start < done < bwd_at, (shape, fwd_at, start, done, bwd_at)
+        return start, done
+
+    start, done = ring("bf16[2048,16384]")
+    assert est_ms(main[start:done]) > 0.7, main[start:done]
+    behind = next(l for l in main[done:] if _is_matmul(comps, l))
+    assert name(main[done]) in _operands(behind), behind[:160]
+    start, done = ring("bf16[1,2048,16384]")
+    by_name = {name(l): l for l in main[:start] if re.match(r"\s*%", l)}
+    sent = by_name[_operands(main[start])[0]]
+    if " bitcast(" in sent:
+        sent = by_name[_operands(sent)[0]]
+    assert _is_matmul(comps, sent), sent[:160]
+    assert est_ms(main[start:done]) > 1.4, main[start:done]
+    for i in range(start, done):
+        if re.search(r" all-(reduce|gather|to-all)(-start)?\(", main[i]):
+            assert est_ms(main[start:i]) > 1.4, main[i][:160]
+
+
 _CELL_STEP_ASSERTIONS = {
     "grad_exchange_behind_the_backward": _assert_grad_exchange_runs_behind_the_backward,
     "no_tp_all_reduce_in_the_layers": _assert_no_tp_all_reduce_in_the_layers,
@@ -1261,6 +1325,7 @@ _CELL_STEP_ASSERTIONS = {
     "own_shard_first_in_the_backward": _assert_own_shard_first_in_the_backward,
     "dw_rings_taken_in_start_order": _assert_dw_rings_taken_in_start_order,
     "dw_twins_read_fast_memory": _assert_dw_twins_read_fast_memory,
+    "head_rides_the_rings": _assert_head_rides_the_rings,
 }
 _TWO_LAYERS = {}  # the compiled text of one compile, for the cases below
 
@@ -1268,7 +1333,7 @@ _TWO_LAYERS = {}  # the compiled text of one compile, for the cases below
 @pytest.mark.parametrize("what", list(_CELL_STEP_ASSERTIONS))
 def test_four_chip_cell_step_exchanges_behind_matmuls(topo, chip, what):
     """Two layers of the 4-chip cell's step (twenty seconds, compiled once
-    for the eight cases; the whole 22 are the slow case below)."""
+    for the nine cases; the whole 22 are the slow case below)."""
     from ray_tpu.train.step import default_optimizer
 
     if not _TWO_LAYERS:

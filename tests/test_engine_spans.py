@@ -301,15 +301,16 @@ def test_a_list_form_programs_spans_count_its_layer_bodies():
 
 
 @pytest.mark.parametrize(
-    "mesh_cfg,n,seq,exchanges,tp_exchanges,norm_sums,pinned,ordered", [
-        ({"dp": 1, "fsdp": 2, "tp": 2}, 4, 16, 7, 4, 0, 1, 1),
-        ({"dp": 1}, 1, 16, 0, 0, 0, 0, 0),
-        ({"dp": 2, "fsdp": 1, "tp": 2}, 4, 16, 0, 0, 2, 0, 0),
-        ({"dp": 1, "fsdp": 2, "tp": 2}, 4, 15, 7, 0, 0, 0, 0),
-        ({"dp": 2, "fsdp": 2, "tp": 1}, 4, 16, 7, 0, 0, 0, 0)],
+    "mesh_cfg,n,seq,exchanges,tp_exchanges,norm_sums,pinned,ordered,head", [
+        ({"dp": 1, "fsdp": 2, "tp": 2}, 4, 16, 7, 4, 0, 1, 1, 1),
+        ({"dp": 1}, 1, 16, 0, 0, 0, 0, 0, 0),
+        ({"dp": 2, "fsdp": 1, "tp": 2}, 4, 16, 0, 0, 2, 0, 0, 0),
+        ({"dp": 1, "fsdp": 2, "tp": 2}, 4, 15, 7, 0, 0, 0, 0, 0),
+        ({"dp": 2, "fsdp": 2, "tp": 1}, 4, 16, 7, 0, 0, 0, 0, 0)],
     ids=["fsdp2xtp2", "one_device", "dp2xtp2", "fsdp2xtp2_odd_seq", "dp2xfsdp2"])
 def test_train_steps_compile_spans_say_which_reduction_ran(
-        mesh_cfg, n, seq, exchanges, tp_exchanges, norm_sums, pinned, ordered):
+        mesh_cfg, n, seq, exchanges, tp_exchanges, norm_sums, pinned, ordered,
+        head):
     """Whether the program spells the weight gradients' exchange over fsdp
     itself (parallel/fsdp.py), and the block's gathers and scatters over tp
     (parallel/tp.py), is a fact of its compile: the train step's trace, lower
@@ -326,7 +327,9 @@ def test_train_steps_compile_spans_say_which_reduction_ran(
     0 wherever they are the partitioner's), and whether the seven weight
     gradients' rings are taken off the `fsdp` link in the order of their
     starts (1 on the same mesh: the order goes from product to product;
-    0 where the products are not parallel/tp.py's)."""
+    0 where the products are not parallel/tp.py's), and whether the head's
+    product carries its exchanges with them (`head_exchanged`: 1 on the
+    same mesh, 0 wherever the head is the partitioner's)."""
     from ray_tpu.models import ModelConfig
     from ray_tpu.parallel.mesh import MeshConfig, make_mesh
     from ray_tpu.train import batch_sharding, make_train_step
@@ -352,9 +355,10 @@ def test_train_steps_compile_spans_say_which_reduction_ran(
         assert (a["fsdp"], a["tp"], a["grad_exchanges_per_layer"],
                 a["tp_exchanges_per_layer"],
                 a["norm_grad_reductions_in_layers"],
-                a["ring_products_own_first"], a["dw_rings_ordered"]) == (
+                a["ring_products_own_first"], a["dw_rings_ordered"],
+                a["head_exchanged"]) == (
             mesh_cfg.get("fsdp", 1), mesh_cfg.get("tp", 1), exchanges,
-            tp_exchanges, norm_sums, pinned, ordered), a
+            tp_exchanges, norm_sums, pinned, ordered, head), a
 
 
 @pytest.mark.parametrize("name", ["engine.step", "engine.between_steps"])

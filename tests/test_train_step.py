@@ -192,7 +192,7 @@ def _program_at_dtype(mesh_cfg, n, dtype):
 
     from ray_tpu.train.step import state_shardings
 
-    cfg = dataclasses.replace(ModelConfig.tiny(), n_kv_heads=2,
+    cfg = dataclasses.replace(ModelConfig.tiny(), n_kv_heads=max(2, mesh_cfg.tp),
                               dtype=jnp.dtype(dtype))
     mesh = make_virtual_mesh(n, mesh_cfg)
     params = jax.device_put(
@@ -248,10 +248,11 @@ def test_own_shard_first_changes_no_bit(mesh_cfg, n, dtype, monkeypatch):
 
     monkeypatch.setattr(fsdp, "ring_products", never_pinned)
     parent_text, parent_loss, parent_grads = run()
-    # of a layer's ring products (forward, remat's, backward) ONE asks
-    assert asked.count(True) == 1 and asked.count(False) >= 8, asked
+    # of a layer's ring products (forward, remat's, backward) ONE asks, and
+    # the head's forward (nothing before it covers `lm_head`'s shard either)
+    assert asked.count(True) == 2 and asked.count(False) >= 8, asked
     pins = lambda t: t.count("optimization_barrier")
-    assert pins(text) - pins(parent_text) == mesh_cfg.fsdp - 1
+    assert pins(text) - pins(parent_text) == 2 * (mesh_cfg.fsdp - 1)
     _assert_same_bits(loss, grads, parent_loss, parent_grads)
 
 
@@ -286,18 +287,123 @@ def test_ordered_dw_rings_change_no_bit(mesh_cfg, n, dtype, monkeypatch):
     ordered = fsdp.weight_grads
     handed = []
 
-    def unordered(xs, dys, dim, mesh, taken=None):
+    def unordered(xs, dys, dim, mesh, taken=None, **alone):
         handed.append(taken is not None)
-        return ordered(xs, dys, dim, mesh)[0], taken
+        return ordered(xs, dys, dim, mesh, **alone)[0], taken
 
     monkeypatch.setattr(fsdp, "weight_grads", unordered)
     parent_text, parent_loss, parent_grads = run()
-    # the four backward bodies of a layer's products, or none of the seven
-    assert handed.count(True) == 4 * ours and len(handed) == (4 if ours else 7)
+    # the four backward bodies of a layer's products (and the head's ring,
+    # alone in its own and handed no order), or none of the seven
+    assert handed.count(True) == 4 * ours and len(handed) == (5 if ours else 7)
     assert (text != parent_text) == bool(ours)
     pins = lambda t: t.count("optimization_barrier")
     assert pins(text) - pins(parent_text) == ours * 7 * (mesh_cfg.fsdp - 1)
     _assert_same_bits(loss, grads, parent_loss, parent_grads)
+
+
+@pytest.mark.parametrize("mesh_cfg,n,dtype", [
+    (MeshConfig(dp=1, fsdp=2, tp=2), 4, "float32"),
+    (MeshConfig(dp=1, fsdp=4, tp=2), 8, "float32"),
+    (MeshConfig(dp=2, fsdp=2, tp=2), 8, "float32"),
+    (MeshConfig(dp=1, fsdp=2, tp=4), 8, "float32"),
+    (MeshConfig(dp=1, fsdp=2, tp=2), 4, "bfloat16"),
+], ids=["fsdp2xtp2", "fsdp4xtp2", "dp2xfsdp2xtp2", "fsdp2xtp4", "fsdp2xtp2_bf16"])
+def test_head_exchange_matches_the_partitioners_head(mesh_cfg, n, dtype, monkeypatch):
+    """Where the layers' products carry their exchanges the head's does too
+    (`tp.gather_matmul_alone`: the rows' other `tp` chunks and
+    `lm_head`'s other `fsdp` shards arrive by permutes behind its own
+    matmuls, the arrived shards are kept for the backward, which sends none
+    again, and `lm_head`'s gradient leaves by fsdp.py's ring). In float32
+    the loss and EVERY gradient leaf (`lm_head`, `final_norm`, `embed`, the
+    layers') agree with the partitioner's form of the same mesh
+    (`_rows_mesh` patched to None) within the tolerances the layers' ring
+    tests hold against one device. In bfloat16, as the four-chip cell runs
+    it, the partials of the K shards are summed in float32 and rounded once,
+    as the one product is: against the float32 program (its weights the
+    ones these were rounded from), no leaf is further on average than with the head ALONE left to the partitioner
+    (`head_exchanged` patched to 0), to the tenth or so by which rounding
+    moves single elements either way; a coarser sum or a shard left out
+    would read many times further."""
+    from ray_tpu.models import transformer
+    from ray_tpu.train.step import state_shardings
+
+    cfg, mesh, run = _program_at_dtype(mesh_cfg, n, dtype)
+    assert transformer.head_exchanged(cfg, mesh, 8, 64) == 1
+    text, loss, grads = run()
+    as32 = lambda tree: jax.tree_util.tree_map(
+        lambda a: np.asarray(a.astype(jnp.float32)), tree)
+    if dtype == "float32":
+        monkeypatch.setattr(transformer, "_rows_mesh", lambda *a: None)
+    else:
+        want = as32(_program_at_dtype(mesh_cfg, n, "float32")[2]()[2])
+        monkeypatch.setattr(transformer, "head_exchanged", lambda *a: 0)
+    assert transformer.head_exchanged(cfg, mesh, 8, 64) == 0
+    parent_text, parent_loss, parent = run()
+    assert text.count("collective_permute") > parent_text.count("collective_permute")
+    if dtype == "float32":
+        np.testing.assert_allclose(float(loss), float(parent_loss), rtol=2e-6)
+        jax.tree_util.tree_map_with_path(
+            lambda path, g, w: np.testing.assert_allclose(
+                g, w, rtol=2e-4, atol=2e-6 * float(np.abs(w).max()),
+                err_msg=jax.tree_util.keystr(path)), as32(grads), as32(parent))
+    else:
+        np.testing.assert_allclose(float(loss), float(parent_loss), rtol=2.0 ** -8)
+        jax.tree_util.tree_map_with_path(
+            lambda path, g, p, w: np.testing.assert_array_less(
+                np.abs(g - w).mean(), 1.25 * np.abs(p - w).mean(),
+                err_msg=jax.tree_util.keystr(path)),
+            as32(grads), as32(parent), want)
+    # (the ring leaves each rank its own shard: the leaf's own sharding)
+    assert grads["lm_head"].sharding.is_equivalent_to(
+        state_shardings(cfg, mesh, default_optimizer()).params["lm_head"], 2)
+
+
+@pytest.mark.parametrize("why,want", [
+    ("fsdp2xtp2", 1), ("dp2xfsdp2xtp2", 1), ("dp_only", 0), ("tp1", 0),
+    ("fsdp1", 0), ("experts", 0), ("fused", 0), ("seq_not_divisible", 0),
+    ("loss_chunk", 0)])
+def test_head_exchanged_says_where_the_heads_product_carries_its_exchanges(why, want):
+    """`head_exchanged` is 1 exactly where `_rows_mesh` hands the head its
+    features with their rows over `tp` and the loss takes whole logits: 0
+    on a `dp`-only mesh, with `tp` 1, with `fsdp` 1, for the expert layer and
+    the fused blocks, where `tp` does not divide the sequence and under
+    `loss_chunk` (the chunked loss is the partitioner's); the train step's
+    `xla.compile` spans carry it, and the head's permutes over `tp` and
+    `fsdp` are in the lowered step where it says 1: with the layers' scan
+    taken away (`n_layers` 0) the text names permutes at 1 and none at 0."""
+    import dataclasses
+
+    from ray_tpu.models import transformer
+    from ray_tpu.util import tracing
+
+    cfg, seq = ModelConfig.tiny(), 64
+    mesh_cfg = {"dp_only": MeshConfig(dp=8), "tp1": MeshConfig(dp=2, fsdp=4, tp=1),
+                "fsdp1": MeshConfig(dp=4, fsdp=1, tp=2),
+                "dp2xfsdp2xtp2": MeshConfig(dp=2, fsdp=2, tp=2)}.get(
+                    why, MeshConfig(dp=1, fsdp=2, tp=2))
+    if why == "experts":
+        cfg = ModelConfig.tiny_moe()
+    elif why == "fused":
+        cfg = dataclasses.replace(cfg, fused_ffn=True)
+    elif why == "loss_chunk":
+        cfg = dataclasses.replace(cfg, loss_chunk=16)
+    elif why == "seq_not_divisible":
+        seq = 63
+    mesh = make_virtual_mesh(mesh_cfg.dp * mesh_cfg.fsdp * mesh_cfg.tp, mesh_cfg)
+    assert transformer.head_exchanged(cfg, mesh, 8, seq) == want
+    if why == "fused":  # (one chip only: nothing to lower on a mesh)
+        return
+    cfg = dataclasses.replace(cfg, n_layers=0)
+    step_fn, init_fn, _ = make_train_step(cfg, mesh, default_optimizer())
+    tokens = jax.ShapeDtypeStruct((8, seq), jnp.int32)
+    tracing.clear()
+    lowered = step_fn.lower(jax.eval_shape(init_fn, jax.random.PRNGKey(0)),
+                            {"inputs": tokens, "targets": tokens})
+    assert ("collective_permute" in lowered.as_text()) == bool(want)
+    spans = [e["args"] for e in tracing.get_events()
+             if e["name"] == "xla.compile" and "step" in e["args"]["fun_name"]]
+    assert spans and all(a["head_exchanged"] == want for a in spans), spans
 
 
 @pytest.mark.parametrize("dtype", ["bf16_scales", "bf16"])
@@ -391,7 +497,8 @@ def test_tp_exchange_stays_off_where_the_block_is_not_the_plain_one(why, monkeyp
     def unreachable(*a, **k):
         raise AssertionError("the tp route was taken")
 
-    for name in ("gather_matmul", "matmul_scatter", "shard_rows", "whole_rows"):
+    for name in ("gather_matmul", "matmul_scatter", "shard_rows",
+                 "gather_matmul_alone"):
         monkeypatch.setattr(tp, name, unreachable)
     monkeypatch.setattr(transformer, "_rows_mesh", lambda *a: None)
     assert ours == text()
