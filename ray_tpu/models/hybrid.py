@@ -15,12 +15,13 @@ sparse attention (`ops/dsa.py`: rotary grouped-query attention with an
 RMSNorm a head on q and k, whose every query attends to the `dsa_topk` rows
 an indexer of `dsa_heads` small heads scores highest, exactly;
 Keye-VL-2.0's), and sliding-window beside full attention (grouped-query,
-a window layer rotary over interleaved pairs under a band of `swa_window`
-positions, a full layer without positions over every row; Command A+'s);
+a window layer rotary under a band of `swa_window` positions, a full layer
+without positions over every row; Command A+'s and SmallThinker's);
 the FFNs: dense SwiGLU and a dropless top-k expert layer
-(`ops/moe.py:dropless_moe`) that is told which experts it holds, beside a
-shared MLP or (`n_shared` 0) none, routed by sigmoid scores + bias or by a
-softmax over the chosen logits (`router`).
+(`ops/moe.py:dropless_moe`) that is told which experts it holds and how
+their gate is activated (`gate_act`), beside a shared MLP or (`n_shared` 0)
+none, routed by sigmoid scores + bias or by a softmax over the chosen
+logits (`router`).
 
 A list-form configuration with `n_predict` 1 carries a multi-token
 prediction module (DeepSeek-V3's form): `h' = W_p [RMSNorm(h_i) ;
@@ -66,16 +67,23 @@ Two ways to hold and run the stack, by what the configuration lists:
   `untied_head`): the runs form with ONE run. A prompt pass of more than
   `dsa_topk` positions scores, selects and attends by two kernels that never
   hold an [n, n] score in HBM; its prompts come in whole chunks.
-- `swa_layers` / `full_layers` (the Command A+ family, the fifth: window and
-  full attention mixers 3 : 1, each over a scanned expert layer routed by
-  sigmoid scores beside the MEAN of the shared experts, the head tied): the
-  runs form with a run a stretch of like layers, and a block of its own:
-  ONE LayerNorm a layer (mean-centred, no bias) whose rows mixer and FFN
-  both read, `x += Attn(h) + FFN(h)`. Its prompt pass walks the sequence a
-  CHUNK of one window at a time, every chunk through all layers, the rows
-  the chunks leave as its carry (`_sequence_swa`); every prompt past a
-  window passes by ONE program whose loop walks the chunks that hold a
-  token.
+- `swa_layers` / `full_layers` (the window form, the fifth: window and full
+  attention mixers in any order, 3 : 1 in both models that have it, each
+  over a scanned expert layer): the runs form with a run a stretch of like
+  layers. What the window form owns is the cache, the walk and the ring; the
+  BLOCK is the configuration's (`swa_block`, `swa_norm`, `swa_rotary`,
+  `route_from`, `gate_act`). Command A+'s, the defaults: ONE LayerNorm a
+  layer (mean-centred, no bias) whose rows mixer and FFN both read, `x +=
+  Attn(h) + FFN(h)`, sigmoid scores beside the MEAN of the shared experts,
+  interleaved rotary pairs, the head tied. SmallThinker's: the sequential
+  RMSNorm block of every other form, `x += Attn(h); x += FFN(RMSNorm(x))`,
+  whose router reads the ATTENTION's input h (`route_from` "mixer": the
+  route is made before the attention and used behind it), sparse ReGLU
+  experts and no shared one, half-rotation rotary, an untied head. Its
+  prompt pass walks the sequence a CHUNK of one window at a time, every
+  chunk through all layers, the rows the chunks leave as its carry
+  (`_sequence_swa`); every prompt past a window passes by ONE program whose
+  loop walks the chunks that hold a token.
 
 Three call modes over the same weights:
 
@@ -114,6 +122,7 @@ family's implementations of the engine's per-slot state interface
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import inspect
@@ -225,21 +234,40 @@ class HybridConfig:
     dsa_head_dim: int = 64
     dsa_chunk: int = 512
     untied_head: bool = False                 # the runs form: a head of its own
-    # sliding-window and full attention mixers in ONE stack (the runs form;
-    # a configuration that lists `swa_layers` or `full_layers` is of
-    # Command A+'s family):
-    # grouped-query attention (`n_heads`, `n_kv_heads`, `head_dim`), the
-    # `swa_layers` rotary over INTERLEAVED pairs (`rope_theta`) under a
-    # window of `swa_window` positions that counts the query's own, the
-    # `full_layers` without positions over every row. ONE LayerNorm a layer
-    # (mean-centred, no bias) that mixer and FFN both read: `x += Attn(h) +
-    # FFN(h)`. Every FFN is an expert layer routed by sigmoid scores without
-    # a bias, beside a shared MLP of `n_shared` experts whose MEAN is added;
-    # the head is the embedding behind a LayerNorm. The prompt pass walks
-    # one window at a time
+    # sliding-window and full attention mixers in ONE stack (the runs form's
+    # window form: a configuration that lists `swa_layers` or `full_layers`
+    # keeps a ring a window layer and a row a position a full layer, and its
+    # prompt pass walks one window at a time): grouped-query attention
+    # (`n_heads`, `n_kv_heads`, `head_dim`), the `swa_layers` rotary
+    # (`rope_theta`) under a window of `swa_window` positions that counts
+    # the query's own, the `full_layers` without positions over every row.
+    # Every FFN is an expert layer (`router`: sigmoid scores without a bias,
+    # or a softmax over the chosen logits)
     swa_layers: Tuple[int, ...] = ()
     full_layers: Tuple[int, ...] = ()
     swa_window: int = 4096
+    # the window form's BLOCK, a model's own; each default is Command A+'s.
+    # "parallel": ONE norm a layer that mixer and FFN both read, `x +=
+    # Attn(h) + FFN(h)`, the shared MLP the MEAN of its `n_shared` experts;
+    # "sequential": `x += Attn(N1(x)); x += FFN(N2(x))`, two norms a layer
+    swa_block: str = "parallel"
+    swa_norm: str = "layer"      # "layer": mean-centred, no bias; or "rms"
+    # a window layer's rotary lanes: "interleaved" pairs (2i, 2i + 1) or
+    # "half" (i, i + d/2; SmallThinker's by an ASSUMED reading of its
+    # config, which seeded weights cannot tell from the other)
+    swa_rotary: str = "interleaved"
+    # what a sequential block's router reads: "ffn", the expert layer's own
+    # normed input, or "mixer", the ATTENTION's normed input (the route is
+    # made before the attention and used behind it; SmallThinker's)
+    route_from: str = "ffn"
+    # a routed expert's gate: silu (SwiGLU) or relu (ReGLU; the window
+    # form's alone, and only where no shared MLP, which is SwiGLU, stands by)
+    gate_act: str = "silu"
+
+    def __post_init__(self):
+        if self.gate_act != "silu" and not self.windowed:
+            raise ValueError(f"gate_act {self.gate_act!r} is the window form's "
+                             "routed experts'; every other FFN is SwiGLU")
 
     @staticmethod
     def tiny_hybrid() -> "HybridConfig":
@@ -306,6 +334,21 @@ class HybridConfig:
                             swa_window=8, n_heads=8, n_kv_heads=2, rope_theta=5e4,
                             n_shared=2, route_scale=1.0)
 
+    @staticmethod
+    def tiny_smallthinker() -> "HybridConfig":
+        """Two periods of full, window x 3 (the global layer FIRST): a window
+        of 8 positions, 14 query heads on 2 key heads (a group of 7), the
+        sequential RMSNorm block whose router reads the attention's input,
+        8 ReGLU experts all held, top-3 by a softmax, no shared expert,
+        half-rotation rotary, an untied head."""
+        return HybridConfig(vocab_size=96, n_layers=8, kda_layers=(), first_dense=0,
+                            swa_layers=(2, 3, 4, 6, 7, 8), full_layers=(1, 5),
+                            swa_window=8, n_heads=14, n_kv_heads=2, rope_theta=1.5e6,
+                            top_k=3, n_shared=0, router="softmax", norm_eps=1e-6,
+                            untied_head=True, swa_block="sequential",
+                            swa_norm="rms", swa_rotary="half",
+                            route_from="mixer", gate_act="relu")
+
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
         def mixer(i):
             return ("kda" if i in self.kda_layers else
@@ -326,8 +369,18 @@ class HybridConfig:
 
     @property
     def windowed(self) -> bool:
-        """A stack of window and full attention layers (Command A+'s family)."""
+        """A stack of window and full attention layers: the window form's
+        cache, walk and ring, whatever the block."""
         return bool(self.swa_layers or self.full_layers)
+
+    @property
+    def parallel(self) -> bool:
+        """The window form's parallel block (Command A+'s)."""
+        return self.windowed and self.swa_block == "parallel"
+
+    @property
+    def layer_normed(self) -> bool:
+        return self.windowed and self.swa_norm == "layer"
 
     @property
     def ssd_inner(self) -> int:
@@ -367,13 +420,28 @@ class HybridConfig:
             # layer) that the prompt pass walks a chunk at a time
             if not set(kinds) <= {("swa", "moe"), ("full", "moe")} \
                     or not self.rope_theta or self.n_heads % self.n_kv_heads \
-                    or self.router != "sigmoid":
+                    or self.router not in ("sigmoid", "softmax"):
                 raise ValueError(
                     "window (swa) and full attention mixers stand each over an "
-                    "expert layer routed by sigmoid scores, with rotary "
-                    "positions and whole query groups; not "
+                    "expert layer routed by sigmoid scores or a softmax, with "
+                    "rotary positions and whole query groups; not "
                     f"{sorted(set(kinds))}, rope_theta {self.rope_theta}, "
                     f"{self.n_heads}:{self.n_kv_heads} heads, router {self.router}")
+            form = (self.swa_block, self.swa_norm, self.swa_rotary, self.route_from,
+                    self.gate_act)
+            if self.swa_block not in ("parallel", "sequential") \
+                    or self.swa_norm not in ("layer", "rms") \
+                    or self.swa_rotary not in ("interleaved", "half") \
+                    or self.route_from not in ("ffn", "mixer") \
+                    or (self.parallel and self.route_from == "mixer") \
+                    or self.gate_act not in ("silu", "relu") \
+                    or (self.gate_act != "silu" and self.n_shared):
+                raise ValueError(
+                    "the window form's block is parallel or sequential, its norm "
+                    "layer or rms, its rotary lanes interleaved or half, its "
+                    "route read from the ffn's input or (sequential) the "
+                    "mixer's, its routed experts' gate silu or (with no shared "
+                    f"MLP, which is SwiGLU) relu; not {form}")
         elif any(m not in ("mamba", "mamba2", "attn") for m, _ in kinds) \
                 or self.ssd_heads % 2:
             raise ValueError(
@@ -516,7 +584,7 @@ def _init_runs(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
         return (jax.random.normal(key(), shape, F32) * fan_in ** -0.5).astype(dt)
 
     def norm(shape):
-        if cfg.windowed:   # around 1: a LayerNorm that drops its weight shows
+        if cfg.windowed:   # around 1: a norm that drops its weight shows
             return (1.0 + 0.1 * jax.random.normal(key(), shape, F32)).astype(dt)
         if not cfg.norm_unit_offset:
             return jnp.ones(shape, dt)
@@ -529,7 +597,7 @@ def _init_runs(rng: jax.Array, cfg: HybridConfig) -> Dict[str, Any]:
     runs: List[Dict[str, Any]] = []
     for (mixer, k), ffn in zip(cfg.runs(), cfg.run_ffns()):
         p: Dict[str, Any] = {"mixer_norm": norm((k, d))}
-        if not cfg.windowed:   # whose ONE norm a layer both halves read
+        if not cfg.parallel:   # whose ONE norm a layer both halves read
             p["ffn_norm"] = norm((k, d))
         if ffn == "dense":
             p["ffn"] = swiglu_w(cfg.d_ff, (k,))
@@ -658,21 +726,36 @@ def _residual(cfg: HybridConfig, p, post_norm: str, x, y):
 
 def _normed(cfg: HybridConfig, x, w):
     """The float32 residual x, normed: (in float32 for the router, in the
-    weights' type for the matrix products). A stack of window and full
-    attention layers norms by a LayerNorm."""
-    h32 = layer_norm(x, w, cfg.norm_eps) if cfg.windowed \
+    weights' type for the matrix products). The window form norms by a
+    LayerNorm where its configuration says so (`swa_norm`)."""
+    h32 = layer_norm(x, w, cfg.norm_eps) if cfg.layer_normed \
         else rms_norm(x, w, cfg.norm_eps, cfg.norm_unit_offset)
     return h32, h32.astype(cfg.dtype)
 
 
-def _ffn(cfg: HybridConfig, p, h32, h, valid, stacks=None, layer=None):
+def _route(cfg: HybridConfig, m, h32):
+    """An expert layer's route from the float32 rows h32 [T, d] it reads:
+    (the experts each token chose [T, k] int32, their weights [T, k]
+    float32), by `cfg.router`."""
+    if cfg.router == "softmax":
+        return route_softmax_top_k(h32, m["router"], cfg.top_k)
+    # (a router that holds no bias chooses by its scores alone)
+    return route_top_k(
+        h32, m["router"],
+        m["bias"] if "bias" in m else jnp.zeros((cfg.n_experts,), F32),
+        cfg.top_k, cfg.route_scale, cfg.renormalize)
+
+
+def _ffn(cfg: HybridConfig, p, h32, h, valid, stacks=None, layer=None, route=None):
     """h [T, d] (and h32, the same in float32) -> (FFN output [T, d],
     assignments landed, experts touched, the experts each token chose
     [T, k] or None for a dense layer). `valid` [T] bool: tokens that are no
     padding and no idle slot; the others are routed nowhere (their rows of
     the result are not used). A scanned run hands its experts' weights as
     `stacks` (w_gate, w_up, w_down [k, Eh, ...], the whole run's) and names
-    its `layer`, so that they are read in place (`dropless_moe`)."""
+    its `layer`, so that they are read in place (`dropless_moe`). `route`:
+    (`_route`'s idx, w) made elsewhere, from rows that are not h32 (a block
+    whose router reads the mixer's input); None: routed here, from h32."""
     if "ffn" in p:
         f = p["ffn"]
         zero = jnp.zeros((), jnp.int32)
@@ -681,22 +764,17 @@ def _ffn(cfg: HybridConfig, p, h32, h, valid, stacks=None, layer=None):
     m = p["moe"]
     with jax.named_scope("moe"):
         # routed on the float32 activations, computed on the rounded ones
-        if cfg.router == "softmax":
-            idx, w = route_softmax_top_k(h32, m["router"], cfg.top_k)
-        else:
-            # (a router that holds no bias chooses by its scores alone)
-            idx, w = route_top_k(
-                h32, m["router"],
-                m["bias"] if "bias" in m else jnp.zeros((cfg.n_experts,), F32),
-                cfg.top_k, cfg.route_scale, cfg.renormalize)
-        y, landed, touched = dropless_moe(
-            h, idx, w, *(stacks or (m["w_gate"], m["w_up"], m["w_down"])),
-            cfg.experts_held, cfg.n_experts, valid, layer)
+        idx, w = route or _route(cfg, m, h32)
+        with jax.named_scope("experts") if route else contextlib.nullcontext():
+            y, landed, touched = dropless_moe(
+                h, idx, w, *(stacks or (m["w_gate"], m["w_up"], m["w_down"])),
+                cfg.experts_held, cfg.n_experts, valid, layer,
+                gate_act=cfg.gate_act)
     if "shared" in m:
         with jax.named_scope("shared_expert"):
             s = m["shared"]
             shared = swiglu(h @ s["w_gate"], h @ s["w_up"]) @ s["w_down"]
-            if cfg.windowed:   # the MEAN of the shared experts, held as one MLP
+            if cfg.parallel:   # the MEAN of the shared experts, held as one MLP
                 shared = shared * (1.0 / cfg.n_shared)
             y = y + shared
     return y, landed, touched, idx
@@ -895,11 +973,12 @@ def _routed_ys(lead, landed, touched, chosen):
     return jnp.stack([landed, touched]), chosen.reshape(lead + (-1,))
 
 
-def _routed_ffn(cfg: HybridConfig, lp, h32, h, valid, stacks=None, layer=None):
+def _routed_ffn(cfg: HybridConfig, lp, h32, h, valid, stacks=None, layer=None,
+                route=None):
     """A run's expert layer over the normed residual h [..., d] (h32: the
     same in float32) -> (its output [..., d], assignments landed, experts
     touched, the experts chosen [tokens, k]: `_routed_ys` makes the scan's
-    ys of the three)."""
+    ys of the three). `route`: `_route_ahead`'s, or None (`_ffn`)."""
     lead, d = h.shape[:-1], h.shape[-1]
     h32, h, ok = h32.reshape(-1, d), h.reshape(-1, d), valid.reshape(-1)
     T = h.shape[0]
@@ -908,15 +987,44 @@ def _routed_ffn(cfg: HybridConfig, lp, h32, h, valid, stacks=None, layer=None):
         # weighs [block x k, d] rows, not [12288 x 10, 4096] (2 GB in float32)
         blocks = lambda a: a.reshape((T // _FFN_BLOCK, _FFN_BLOCK) + a.shape[1:])
         y, landed, touched, chosen = jax.lax.map(
-            lambda t: _ffn(cfg, lp, *t, stacks, layer),
-            (blocks(h32), blocks(h), blocks(ok)))
+            lambda t: _ffn(cfg, lp, *t[:3], stacks, layer, t[3]),
+            (blocks(h32), blocks(h), blocks(ok),
+             tuple(blocks(a) for a in route) if route else None))
         y, chosen = y.reshape(T, d), chosen.reshape(T, -1)
         # an expert counts as touched once a block (the decode step, whose
         # counters the engine reports, is one block)
         landed, touched = jnp.sum(landed), jnp.sum(touched)
     else:
-        y, landed, touched, chosen = _ffn(cfg, lp, h32, h, ok, stacks, layer)
+        y, landed, touched, chosen = _ffn(cfg, lp, h32, h, ok, stacks, layer, route)
     return y.reshape(lead + (d,)), landed, touched, chosen
+
+
+def _route_ahead(cfg: HybridConfig, lp, h32):
+    """The route of a block whose router reads the MIXER's input (`route_from`
+    "mixer"), from those rows h32 [..., d] float32, before the mixer runs:
+    (idx, w [tokens, k]) for `_routed_ffn` behind it; None for every other
+    block, whose expert layer routes from its own input."""
+    if cfg.route_from != "mixer":
+        return None
+    with jax.named_scope("moe"), jax.named_scope("route_ahead"):
+        return _route(cfg, lp["moe"], h32.reshape(-1, h32.shape[-1]))
+
+
+def _swa_block(cfg: HybridConfig, lp, x, h32, h, mixed, route, valid, stacks, layer):
+    """The window form's block behind its attention: the residual x [..., d]
+    float32, its normed rows (h32, h) that the attention read, the
+    attention's output `mixed` -> (x of the next layer, the scan's ys of the
+    expert layer). Parallel: `x + mixed + FFN(h)`. Sequential: `x1 = x +
+    mixed; x1 + FFN(N2(x1))`, by `route` where the router read h."""
+    if cfg.parallel:
+        routed, *counted = _routed_ffn(cfg, lp, h32, h, valid, stacks, layer)
+        x = x + mixed.astype(F32) + routed.astype(F32)
+    else:
+        x = x + mixed.astype(F32)
+        routed, *counted = _routed_ffn(cfg, lp, *_normed(cfg, x, lp["ffn_norm"]),
+                                       valid, stacks, layer, route)
+        x = x + routed.astype(F32)
+    return x, _routed_ys(x.shape[:-1], *counted)
 
 
 def _expert_stacks(rp):
@@ -1094,16 +1202,17 @@ def _dsa_inputs(cfg: HybridConfig, a, h, positions):
 def _swa_qkv(cfg: HybridConfig, a, h, positions):
     """h [..., s, d] -> q [..., s, H, hd], k, v [..., s, kvh, hd] in the
     configuration's type. A window layer hands its `positions` [..., s] and
-    has q and k rotated there over interleaved pairs; a full layer hands
-    None and has none."""
+    has q and k rotated there (`swa_rotary`: over interleaved pairs, or by
+    halves); a full layer hands None and has none."""
     # flat products that read their weight in place (`_eva_qkv` says why)
     lead, hd = h.shape[:-1], cfg.head_dim
     q, k, v = jax.lax.optimization_barrier((h @ a["wq"], h @ a["wk"], h @ a["wv"]))
     q = q.reshape(lead + (cfg.n_heads, hd))
     k = k.reshape(lead + (cfg.n_kv_heads, hd))
     if positions is not None:
-        q = rotate_interleaved(q, positions, cfg.rope_theta)
-        k = rotate_interleaved(k, positions, cfg.rope_theta)
+        rotate = rotate_interleaved if cfg.swa_rotary == "interleaved" else mla.rotate
+        q = rotate(q, positions, cfg.rope_theta)
+        k = rotate(k, positions, cfg.rope_theta)
     return q, k.astype(cfg.dtype), v.reshape(lead + (cfg.n_kv_heads, hd)).astype(cfg.dtype)
 
 
@@ -1423,6 +1532,7 @@ def _sequence_swa(params, tokens, true_len, cfg: HybridConfig,
                 with jax.named_scope("swa"), \
                         jax.named_scope("window" if window else "full"):
                     h32, h = _normed(cfg, x, lp["mixer_norm"])
+                    route = _route_ahead(cfg, lp, h32)
                     a = lp[mixer]
                     q, k, v = _swa_qkv(cfg, a, h, positions if window else None)
                     put = lambda rows, new: jax.lax.dynamic_update_slice(
@@ -1432,9 +1542,8 @@ def _sequence_swa(params, tokens, true_len, cfg: HybridConfig,
                         jnp.moveaxis(q, 1, 2), ck, cv, first + i, at, k_lo,
                         window=W if window else None, sm_scale=scale)
                     mixed = jnp.moveaxis(attn, 1, 2).reshape(b, L, H * hd) @ a["wo"]
-                routed, *counted = _routed_ffn(cfg, lp, h32, h, valid, stacks, i)
-                return ((x + mixed.astype(F32) + routed.astype(F32), ck, cv),
-                        _routed_ys(x.shape[:-1], *counted))
+                x, ys = _swa_block(cfg, lp, x, h32, h, mixed, route, valid, stacks, i)
+                return (x, ck, cv), ys
             return layer
 
         x = params["embed"][toks].astype(F32)
@@ -1950,6 +2059,7 @@ def _decode_swa(params, state, lengths, tokens, cfg: HybridConfig,
             lp, i = xs
             with jax.named_scope("swa"), jax.named_scope("window" if window else "full"):
                 h32, h = _normed(cfg, x, lp["mixer_norm"])
+                route = _route_ahead(cfg, lp, h32)
                 a = lp[mixer]
                 q, k_cur, v_cur = (t[:, 0] for t in _swa_qkv(
                     cfg, a, h[:, None], lengths[:, None] if window else None))
@@ -1966,9 +2076,8 @@ def _decode_swa(params, state, lengths, tokens, cfg: HybridConfig,
                         jax.lax.dynamic_slice(v_all, (first + i, 0, 0, 0, 0), read)[0],
                         k_cur, v_cur, masks[mixer], cfg.attn_scale)
                 mixed = attn.reshape(B, H * hd).astype(cfg.dtype) @ a["wo"]
-            routed, *counted = _routed_ffn(cfg, lp, h32, h, busy, stacks, i)
-            return (x + mixed.astype(F32) + routed.astype(F32),
-                    (k_cur, v_cur) + _routed_ys(x.shape[:-1], *counted))
+            x, ys = _swa_block(cfg, lp, x, h32, h, mixed, route, busy, stacks, i)
+            return x, (k_cur, v_cur) + ys
         return layer
 
     cur = {"swa": ([], []), "full": ([], [])}
@@ -2468,6 +2577,10 @@ class SwaCache(RunsCache):
     def step_args(self, positions: List[int], attn_len: int) -> Dict[str, int]:
         """The rows ONE layer of each kind reads for the busy slots, summed:
         `window_rows` min(n, W) a slot, `full_rows` n a slot (a stack of
-        full layers would read `full_rows` in every layer)."""
-        return {"window_rows": sum(min(n, self.cfg.swa_window) for n in positions),
-                "full_rows": sum(positions)}
+        full layers would read `full_rows` in every layer); `wrapped_slots`:
+        the busy slots whose length has reached the window, so that the row
+        at n % W leaves the ring this step."""
+        W = self.cfg.swa_window
+        return {"window_rows": sum(min(n, W) for n in positions),
+                "full_rows": sum(positions),
+                "wrapped_slots": sum(n >= W for n in positions)}

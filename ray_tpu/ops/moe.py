@@ -162,16 +162,29 @@ def route_softmax_top_k(x: jax.Array, router_w: jax.Array, top_k: int):
     return idx.astype(jnp.int32), jax.nn.softmax(best, axis=-1)
 
 
+_GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _gated(gate_act: str, product, x, w_gate, w_up):
+    """The gated product of a held expert, act(x W_gate) * (x W_up), before
+    its down projection: `product(x, w)` is the tier's own (one grouped
+    product over the sorted rows, or a plain one over all tokens), the
+    gate's activation the caller's ("silu": SwiGLU; "relu": ReGLU)."""
+    return _GATE_ACTS[gate_act](product(x, w_gate)) * product(x, w_up)
+
+
 def dropless_moe(x: jax.Array, idx: jax.Array, w: jax.Array,
                  w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
                  held: Tuple[int, ...], n_experts: int,
                  valid: Optional[jax.Array] = None,
-                 layer: Optional[jax.Array] = None):
+                 layer: Optional[jax.Array] = None, gate_act: str = "silu"):
     """The held experts' part of a dropless expert layer.
 
-    x [T, d]; `idx`, `w` [T, k] are `route_top_k`'s choice over ALL
-    `n_experts` experts and its weights; `held` names the global ids of the
-    experts whose SwiGLU weights are stacked in w_gate / w_up [Eh, d, f] and
+    x [T, d]; `idx`, `w` [T, k] are the router's choice over ALL
+    `n_experts` experts and its weights (`route_top_k`,
+    `route_softmax_top_k`); `held` names the global ids of the experts whose
+    gated MLPs (`gate_act`: `_gated`; all of one width f) are stacked in
+    w_gate / w_up [Eh, d, f] and
     w_down [Eh, f, d] (Eh = len(held)). Every (token, expert) assignment
     that lands on a held expert is computed, however skewed the routing: the
     assignments are sorted by held expert and go through one grouped matrix
@@ -226,12 +239,12 @@ def dropless_moe(x: jax.Array, idx: jax.Array, w: jax.Array,
         jnp.arange(T * top_k, dtype=jnp.int32)).reshape(T, top_k)
 
     def experts(rows: int):
-        """The held experts' SwiGLU over the first `rows` sorted assignments
+        """The held experts' MLPs over the first `rows` sorted assignments
         (the landed ones come first), weighed and summed per token."""
         def run(_):
             head = x[token[:rows]]                  # [rows, d], sorted by expert
-            act = jax.nn.silu(jax.lax.ragged_dot(head, w_gate, groups)) \
-                * jax.lax.ragged_dot(head, w_up, groups)
+            act = _gated(gate_act, lambda a, m: jax.lax.ragged_dot(a, m, groups),
+                         head, w_gate, w_up)
             out = jax.lax.ragged_dot(act.astype(x.dtype), w_down, groups)
             # back to token order by a gather; an assignment that did not
             # land here reads the zero row behind the last
@@ -242,14 +255,14 @@ def dropless_moe(x: jax.Array, idx: jax.Array, w: jax.Array,
 
     def every_expert(_):
         """The same sums for any routing at all, without gathering a row:
-        each held expert's SwiGLU over ALL T tokens, one expert after
+        each held expert's MLP over ALL T tokens, one expert after
         another, weighed by what the token gave that expert (mostly 0)."""
         chose = group.reshape(T, top_k)
 
         def one(y, e):
             gate, up, down, j = e
             weight = jnp.sum(jnp.where(chose == j, w, 0.0), axis=1)
-            out = (jax.nn.silu(x @ gate) * (x @ up)).astype(x.dtype) @ down
+            out = _gated(gate_act, jnp.matmul, x, gate, up).astype(x.dtype) @ down
             return y + out.astype(jnp.float32) * weight[:, None], None
 
         mine = (w_gate, w_up, w_down) if layer is None else tuple(

@@ -944,6 +944,94 @@ def test_cmda_prompt_pass_walks_its_chunks_beside_weights_and_slots(chip):
     assert short.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
+def _sthink(chip):
+    """(cfg, parameter shapes, slot-state shapes, slots, max_len) of the
+    SmallThinker cell."""
+    import json
+    import sys
+
+    from ray_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from perfbench.lib import sthink_model
+
+    with open(os.path.join(root, "perfbench", "configs",
+                           "smallthinker-21b-a3b.12of52.json")) as f:
+        conf = json.load(f)
+    cfg = sthink_model.model_config(conf)
+    slots, max_len = conf["run"]["num_slots"], conf["run"]["max_len"]
+    as_shapes = lambda tree: jax.tree_util.tree_map(
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = as_shapes(jax.eval_shape(lambda k: hybrid.init_params(k, cfg),
+                                      jax.random.PRNGKey(0)))
+    state = as_shapes(jax.eval_shape(lambda: cfg.make_cache(slots, max_len).state))
+    return cfg, params, state, slots, max_len
+
+
+def test_sthink_decode_step_routes_ahead_over_six_runs_in_place(chip):
+    """SmallThinker's 12-layer stage at the benchmark cell's real shapes (six
+    runs, the global layer first, 64 of 64 experts, 16 slots x 16,384): ONE
+    step program; Mosaic takes the decode kernel at SEVEN query heads a key
+    head (the wrapper pads the group to 8 sublanes) over the ring (9 layers)
+    and the full rows (3); both caches aliased and written by `write_rows`;
+    the experts' stacks are read in place (no copy of a layer's 755 MB of
+    experts, nor of a run's); nothing of a layer's cache is relaid."""
+    from ray_tpu.models import hybrid
+
+    cfg, params, state, slots, max_len = _sthink(chip)
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(params))
+    assert 11.12e9 < weights < 11.13e9                 # 5,561.4M parameters, bf16
+    state_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(state))
+    assert state_bytes == 16 * (3 * 16384 + 9 * 4096) * 2048          # 2.82 GB
+    ints = chip((slots,), jnp.int32)
+    c = hybrid.decode_step.lower(params, state, ints, ints,
+                                 chip((slots,), jnp.bool_), cfg, max_len).compile()
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < 64 * 2**20
+    text = c.as_text()
+    assert "gqa_decode_attention" in text and "write_rows" in text and "ragged" in text
+    assert f"bf16[{slots},4,8,128]" in text            # the group of 7, padded to 8
+    for table in state.values():
+        assert _whole_cache_relayouts(c, table) == []
+    assert not re.search(r"= bf16\[(3,|1,)?64,2560,768\]\S* copy\(", text)
+    rows = hybrid.decode_logits.lower(params, state, ints, ints, None, cfg,
+                                      max_len).compile()
+    assert rows.memory_analysis().alias_size_in_bytes >= state_bytes
+    assert rows.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_sthink_prompt_pass_walks_its_chunks_beside_weights_and_slots(chip):
+    """The ONE program of every prompt past a window, 1 x 16,384: Mosaic takes
+    the banded flash kernel at 28 query heads on 4 key heads (a group of 7
+    through the index map), the walk is a `while` whose trip count is data,
+    no [chunk, keys] score exists outside the kernel, and what the pass needs
+    beside 11.12 GB of weights and 2.82 GB of slots stays under 1.2 GB. The
+    same with every position's choice of experts returned (the comparison's
+    program), and a bucket inside one window."""
+    from ray_tpu.models import hybrid
+
+    cfg, params, _, _, max_len = _sthink(chip)
+    one = chip((1,), jnp.int32)
+    c = hybrid._prefill_first.lower(params, chip((1, max_len), jnp.int32), one,
+                                    cfg).compile()
+    text = c.as_text()
+    assert "flash_attention_banded" in text and " while(" in text
+    assert c.memory_analysis().temp_size_in_bytes < 1.2e9
+    assert not re.search(r"f32\[(\d+,)*4096,(8192|16384)\]", text)   # no scores in HBM
+    assert not re.search(rf"\[(\d+,)*28,{max_len},128\]", text)      # no K / V a query head
+    rows = hybrid.prefill.lower(params, chip((1, max_len), jnp.int32), one, cfg,
+                                with_routing=True).compile()
+    assert f"s32[12,1,{max_len},6]" in rows.as_text()
+    assert rows.memory_analysis().temp_size_in_bytes < 1.3e9
+    short = hybrid._prefill_first.lower(params, chip((1, 2048), jnp.int32), one,
+                                        cfg).compile()
+    assert "flash_attention_banded" in short.as_text()
+    assert short.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
 def test_prefill_slots_compiles_at_b1(chip):
     from ray_tpu.models.serving import prefill_slots
 

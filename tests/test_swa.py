@@ -89,7 +89,7 @@ def test_the_stack_is_runs_of_window_and_full_layers_with_one_norm_a_layer(param
     assert "lm_head" not in params                  # the head is the embedding
     assert isinstance(CFG.make_cache(2, 64), hybrid.SwaCache)
     with pytest.raises(ValueError, match="window .swa. and full attention mixers"):
-        dataclasses.replace(CFG, router="softmax").runs()
+        dataclasses.replace(CFG, router="argmax").runs()
     with pytest.raises(ValueError, match="window .swa. and full attention mixers"):
         dataclasses.replace(CFG, attn_layers=(4,), full_layers=(8,)).runs()
     # the other families' stacks are what they were
@@ -250,7 +250,7 @@ def test_cache_shapes_buckets_and_arguments():
     assert cache.step_tokens == 1 and cache.max_prefill_batch(8) == 1
     assert [cache.prompt_bucket(n) for n in (1, 8, 9, 40, 63)] == [8, 8, 64, 64, 64]
     assert cache.step_args([3, 20, 50], 64) == {"window_rows": 3 + 8 + 8,
-                                                "full_rows": 73}
+                                                "full_rows": 73, "wrapped_slots": 2}
     big = dataclasses.replace(CFG, swa_window=4096).make_cache(1, 49152)
     assert [big.prompt_bucket(n) for n in (1, 512, 513, 3072, 4096, 4097, 48640)] == \
         [512, 512, 1024, 4096, 4096, 49152, 49152]
