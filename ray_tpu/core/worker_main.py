@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import argparse
 import logging
-import time
 
 
 def run_worker(raylet_address: str, gcs_address: str,
@@ -128,10 +127,12 @@ def run_worker(raylet_address: str, gcs_address: str,
     out_tee._drain()
     err_tee._drain()
 
-    # Serve until the raylet connection drops (raylet died or killed us).
+    # Until the raylet connection drops (raylet died or killed us) the main
+    # thread has nothing of its own to do: it is lent (`util/main_thread.py`)
+    from ray_tpu.util import main_thread
+
     try:
-        while not worker.raylet.closed:
-            time.sleep(0.5)
+        main_thread.serve(lambda: worker.raylet.closed)
     except KeyboardInterrupt:
         pass
 

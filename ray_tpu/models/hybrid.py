@@ -133,6 +133,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.models import programs as programs_ahead
 from ray_tpu.models.inference import _gqa_decode_attention
 from ray_tpu.ops import dsa, eva, kda, mamba, mla, ssd
 from ray_tpu.ops.attention import (attention, banded_attention,
@@ -2318,6 +2319,8 @@ class HybridCache:
     last, the latent rows [layers, slots, 1, max_len, latent_width]; with a
     module, the token it drafted for each slot [slots]."""
 
+    programs = programs_ahead.Direct   # the engine's list, if it keeps one
+
     def __init__(self, cfg: HybridConfig, num_slots: int, max_len: int):
         self.cfg, self.num_slots, self.max_len = cfg, num_slots, max_len
         kinds = [m for m, _ in cfg.layer_kinds()]
@@ -2344,7 +2347,8 @@ class HybridCache:
         return max(1, min(4, self.cfg.prefill_tokens // bucket))
 
     def prefill(self, params, tokens, lens):
-        return _prefill_first(params, tokens, lens, self.cfg)
+        return self.programs.run(("admit",) + tokens.shape, _prefill_first,
+                                 params, tokens, lens, self.cfg)
 
     def write(self, lengths, tokens, slots, rows, lens, first):
         self.state, lengths, tokens = _write_state(
@@ -2354,9 +2358,25 @@ class HybridCache:
     def decode(self, params, lengths, tokens, attn_len, active_slots):
         active = np.zeros((self.num_slots,), bool)
         active[list(active_slots)] = True
-        self.state, lengths, nxt, report = decode_step(
+        self.state, lengths, nxt, report = self.programs.run(
+            ("decode", attn_len), decode_step,
             params, self.state, lengths, tokens, active, self.cfg, attn_len)
         return lengths, nxt, report
+
+    def lowered(self, key, params, state, lengths, tokens):
+        """The programs of a key of the engine's list, lowered from abstract
+        arguments (`models/programs.py`): a step's one, or an admission's
+        prompt pass and the write of its rows."""
+        int32 = programs_ahead.int32
+        if key[0] == "decode":
+            active = jax.ShapeDtypeStruct((self.num_slots,), np.bool_)
+            return [decode_step.lower(params, state, lengths, tokens, active,
+                                      self.cfg, key[1])]
+        nb, bucket = key[1:]
+        prefill = _prefill_first.lower(params, int32(nb, bucket), int32(nb), self.cfg)
+        first, rows = programs_ahead.outputs(prefill, params)
+        return [prefill, _write_state.lower(state, lengths, tokens, int32(nb), rows,
+                                            int32(nb), first)]
 
     def step_args(self, positions: List[int], attn_len: int) -> Dict[str, int]:
         """What one decode step moved, known on the host at dispatch, from

@@ -75,6 +75,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.models import programs as programs_ahead
 from ray_tpu.models.inference import _gqa_decode_attention
 from ray_tpu.models.transformer import (ModelConfig, _deq_tree,
                                         _embed_lookup, _mlp, lm_head_weights)
@@ -333,6 +334,14 @@ class DenseKVCache:
                                     span arguments (of a step that
                                     dispatched a decode, from the busy
                                     slots' positions; of a prompt pass)
+        programs                    the engine's list (`models/programs.py`):
+                                    `prefill` and `decode` call their jitted
+                                    program through it, under the key that
+                                    tells it apart
+        lowered(key, params, state, lengths, tokens)
+                                    the programs of a key (an admission's
+                                    two, a step's one), lowered from abstract
+                                    arguments
 
     It calls the module's own jitted `prefill_slots`, `_write_slots` and
     `decode_step_fused`, so the dense model compiles to the programs it
@@ -341,6 +350,7 @@ class DenseKVCache:
     counters: Tuple[str, ...] = ()
     step_tokens = 1
     prefill_args: Dict[str, int] = {}
+    programs = programs_ahead.Direct
 
     def __init__(self, cfg: ModelConfig, num_slots: int, max_len: int):
         self.cfg, self.max_len = cfg, max_len
@@ -352,8 +362,9 @@ class DenseKVCache:
         return None
 
     def prefill(self, params, tokens, lens):
-        first, k_rows, v_rows = prefill_slots(params, tokens, lens, self.cfg,
-                                              self.max_len)
+        first, k_rows, v_rows = self.programs.run(
+            ("admit",) + tokens.shape, prefill_slots, params, tokens, lens,
+            self.cfg, self.max_len)
         return first, (k_rows, v_rows)
 
     def write(self, lengths, tokens, slots, rows, lens, first):
@@ -364,9 +375,22 @@ class DenseKVCache:
 
     def decode(self, params, lengths, tokens, attn_len, active_slots):
         s = self.state
-        s["k"], s["v"], lengths, nxt = decode_step_fused(
+        s["k"], s["v"], lengths, nxt = self.programs.run(
+            ("decode", attn_len), decode_step_fused,
             params, s["k"], s["v"], lengths, tokens, self.cfg, attn_len)
         return lengths, nxt, nxt
+
+    def lowered(self, key, params, state, lengths, tokens):
+        if key[0] == "decode":
+            return [decode_step_fused.lower(params, state["k"], state["v"], lengths,
+                                            tokens, self.cfg, key[1])]
+        int32, (nb, bucket) = programs_ahead.int32, key[1:]
+        prefill = prefill_slots.lower(params, int32(nb, bucket), int32(nb),
+                                      self.cfg, self.max_len)
+        first, k_rows, v_rows = programs_ahead.outputs(prefill, params)
+        return [prefill, _write_slots.lower(
+            state["k"], state["v"], lengths, tokens, int32(nb), k_rows, v_rows,
+            int32(nb), first)]
 
     def step_args(self, positions: List[int], attn_len: int) -> Dict[str, int]:
         """The rows that hold a token, which the step has to read (of the
@@ -467,6 +491,14 @@ class ContinuousBatchingEngine:
         self._retired: List[int] = []
         self._zero_lengths = jax.jit(_zero_lengths).lower(
             self.lengths, jax.ShapeDtypeStruct((num_slots,), jnp.bool_)).compile()
+        # the programs this engine's last life called, loaded from here on
+        # by threads of their own; this life's calls listed for the next
+        path, header = programs_ahead.list_path(self.cache, cfg, num_slots, max_len)
+        if path:
+            cache, avals = self.cache, programs_ahead.abstract(
+                (self.params, self.cache.state, self.lengths, self.tokens))
+            cache.programs = programs_ahead.Programs(
+                path, header, lambda key: cache.lowered(key, *avals))
 
     # the dense cache's two arrays by their old names (callers that warm or
     # inspect them: the benchmark's replica, tests)
@@ -760,6 +792,7 @@ class ContinuousBatchingEngine:
             self._driver.start()
 
     def stop_driver(self, timeout: float = 5.0) -> None:
+        self.cache.programs.close()   # the loading threads, if any are left
         with self._lock:
             t = self._driver
             if t is None:

@@ -283,6 +283,23 @@ def note_compile(fun_name: str, **args) -> None:
     _compile_args[fun_name] = args
 
 
+def compile_notes() -> Dict[str, dict]:
+    """The noted facts as they stand: what a program lowered on this thread
+    just now hands, through `thread_compiles`, to the thread that compiles
+    it, while this one goes on to trace the next program of the same name."""
+    return dict(_compile_args)
+
+
+def thread_compiles(notes: Optional[Dict[str, dict]] = None, **args) -> None:
+    """From here on this thread's `xla.compile` spans carry `args` (a thread
+    that loads programs ahead of their first call: `ahead=True`) and take a
+    program's noted facts from `notes` (`compile_notes()` of the thread
+    that lowered it). What a compile that raised left behind on this thread
+    is dropped, so that it rides no other program's span."""
+    _tls.compile_args, _tls.compile_notes = args, notes
+    _tls.cache_facts = {}
+
+
 def record_compiles() -> None:
     """Install, once per process, the `jax.monitoring` listeners that turn
     every program's trace / lower / backend-compile event into an `xla.compile` span
@@ -334,8 +351,10 @@ def record_compiles() -> None:
         dur = secs * 1e6
         fun_name = str(kw.get("fun_name") or open_span_name() or "")
         bare = fun_name[4:-1] if fun_name.startswith("jit(") else fun_name
+        notes = getattr(_tls, "compile_notes", None)
         span = dict(
-            _compile_args.get(bare, {}),
+            (_compile_args if notes is None else notes).get(bare, {}),
+            **getattr(_tls, "compile_args", {}),
             name="xla.compile", category="compile", start_us=_now_us() - dur,
             dur_us=dur, trace_id=ctx[0], parent_id=ctx[1],
             event=event.rsplit("/", 1)[-1], fun_name=fun_name)

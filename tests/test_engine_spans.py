@@ -797,3 +797,55 @@ def test_bare_handle_call_roots_its_own_trace(ray_start_regular):
         assert route["parent_id"] == ""   # no ingress: the route span roots it
     finally:
         serve.shutdown()
+
+
+# ---- programs loaded ahead of their first call (models/programs.py)
+
+from test_programs_ahead import cache_dir, events, life, make  # noqa: E402,F401 - fixtures
+
+
+def test_spans_of_programs_loaded_ahead_say_so_and_carry_their_own_facts(cache_dir, events):
+    """A replica's second life: the `xla.compile` spans of its listed
+    programs come from the thread that traces and the lent main thread that
+    reads, carry `ahead: true`, and each its OWN facts: the cache's answer
+    and the read's cost of that program, the layers of the program that
+    noted them (a prompt pass's never on the write that is lowered next).
+    `programs.ahead` once a life, with its six arguments; a first life
+    records neither."""
+    own = ("_prefill_first", "_write_state", "decode_step")
+    bare = lambda e: e["args"]["fun_name"].removeprefix("jit(").removesuffix(")")
+    of = lambda name: [e for e in tracing.get_events() if e["name"] == name]
+    cfg, params = make("HybridCache")
+    life(cfg, params, as_actor=True)
+    assert not of("programs.ahead")
+    assert not [e for e in of("xla.compile") if "ahead" in e["args"]]
+    callers = {e["tid"] for e in of("xla.compile") if bare(e) in own}
+    assert len(callers) == 1
+
+    life(cfg, params, as_actor=True)
+    (said,) = of("programs.ahead")
+    assert set(said["args"]) == {"listed", "loaded", "ready_at_first_call",
+                                 "waited_us", "failed", "wall_us"}
+    assert said["args"]["listed"] == said["args"]["loaded"] == 3
+    assert int(said["dur"]) == said["args"]["wall_us"] > 0
+    spans = [e for e in of("xla.compile") if bare(e) in own]
+    assert sorted((bare(e), e["args"]["event"]) for e in spans) == sorted(
+        (p, ev) for p in own for ev in ("jaxpr_trace_duration",
+                                        "jaxpr_to_mlir_module_duration",
+                                        "backend_compile_duration"))
+    assert all(e["args"]["ahead"] is True for e in spans)
+    reads = [e for e in spans if e["args"]["event"] == "backend_compile_duration"]
+    tracer, reader = ({e["tid"] for e in spans} - {e["tid"] for e in reads},
+                      {e["tid"] for e in reads})
+    assert len(tracer) == len(reader) == 1 and tracer != reader
+    assert reader == {threading.get_ident() % 100000}     # the lent main thread
+    assert all(e["args"]["cache"] == "hit" and e["args"]["retrieval_us"] > 0
+               for e in reads)
+    for e in spans:   # a layered program's notes, on all three of its spans
+        assert ("layers" in e["args"]) == (bare(e) != "_write_state"), e
+    # and the main thread's own next program is not marked
+    tracing.clear()
+    jax.jit(lambda x: x * 3 + 1)(jnp.ones((3,)))
+    mine = [e["args"] for e in of("xla.compile")]
+    assert mine and not [a for a in mine if "ahead" in a or "layers" in a
+                         or "retrieval_us" in a], mine
